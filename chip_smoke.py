@@ -1,0 +1,621 @@
+#!/usr/bin/env python3
+"""First light on the chip: the main serving path, end to end, once.
+
+    python chip_smoke.py          # on a machine with one TPU (or four:
+                                  # TPU_SHARDING=tp=4 python chip_smoke.py)
+
+One process — it holds the chip and starts no child. Phases:
+
+  device   JAX finds a TPU; versions, device and compile-cache directory.
+  kernels  every Pallas entry point the serving path can reach, compiled
+           NON-interpreted at the Llama-3-8B head geometry and compared
+           with its jax.numpy reference on valid rows.
+  server   the token-streaming example exactly as a user starts it
+           (examples/tpu-token-streaming/main.py + its configs/.env; the
+           process environment overrides the file): Llama-3-8B at full
+           width, random int8 weights from a seed, int8 KV, 48 slots.
+           Health, concurrent HTTP and gRPC streams, a prefix-pool hit
+           with equal greedy output, /metrics, graceful stop, no
+           framework thread left behind.
+
+A failed phase is named, the phases after it still run where they can,
+and the exit code is 1. On success — and only then — stdout carries two
+lines: the full JSON summary (phases, compile and serve seconds, tokens,
+HBM, arbiter, native), then as the LAST line the result and nothing but
+the result: {"ok": true, "device": {"platform": "tpu", "kind": "...",
+"count": 1}}. Everything else, the failure summary included, goes to
+stderr.
+
+Rehearsal without a chip (the device and kernel phases then fail, the
+exit code is 1, the server phase exercises the request logic):
+
+    JAX_PLATFORMS=cpu TPU_MODEL=tiny TPU_SEQ_BUCKETS=32,64 \
+        python chip_smoke.py
+
+(the tiny preset holds 128 positions, so its prompt buckets stop at 64.)
+"""
+
+from __future__ import annotations
+
+import http.client
+import importlib.util
+import json
+import os
+import sys
+import threading
+import time
+import traceback
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+EXAMPLE = os.path.join(REPO, "examples", "tpu-token-streaming")
+# Llama-3-8B attention geometry: 32 query heads over 8 KV heads of 128
+H, KV, D = 32, 8, 128
+# max abs error on valid rows — bench.flash_smoke's bound: bf16 outputs up
+# to ~5 in magnitude sit an ulp (0.03) apart, a broken kernel is off by O(1)
+KERNEL_TOL = 0.1
+NEW_TOKENS = 16
+
+
+def log(*a) -> None:
+    print(*a, file=sys.stderr, flush=True)
+
+
+def run_phase(summary: dict, name: str, fn) -> None:
+    rec = {"ok": False}
+    summary["phases"][name] = rec
+    t0 = time.monotonic()
+    try:
+        fn(summary, rec)
+        rec["ok"] = True
+    except Exception as e:  # the phase boundary: record, report, go on
+        traceback.print_exc()
+        rec["error"] = f"{type(e).__name__}: {e}"[:2000]
+    rec["seconds"] = round(time.monotonic() - t0, 1)
+    log(f"== phase {name}: {'ok' if rec['ok'] else 'FAILED'} "
+        f"in {rec['seconds']}s"
+        + ("" if rec["ok"] else f" — {rec['error']}"))
+
+
+# -- phase 1: device ---------------------------------------------------------
+
+def phase_device(summary: dict, rec: dict) -> None:
+    import importlib.metadata
+
+    import jax
+    import jaxlib
+
+    from gofr_tpu import compile_cache
+
+    summary["cache_dir"] = compile_cache.configure()
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = None
+    rec["versions"] = {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                       "libtpu": libtpu,
+                       "python": sys.version.split()[0]}
+    devices = jax.devices()
+    summary["device"] = {"platform": devices[0].platform,
+                         "kind": devices[0].device_kind,
+                         "count": len(devices)}
+    log(f"versions={rec['versions']} device={summary['device']} "
+        f"cache_dir={summary['cache_dir']}")
+    if devices[0].platform != "tpu":
+        raise RuntimeError(
+            f"platform is {devices[0].platform!r}, not 'tpu'")
+
+
+# -- phase 2: kernels --------------------------------------------------------
+
+def _max_err(got, ref, valid=None) -> float:
+    import numpy as np
+
+    d = np.abs(np.asarray(got, np.float32) - np.asarray(ref, np.float32))
+    if valid is not None:
+        d = d * np.asarray(valid, np.float32)
+    return float(d.max())
+
+
+def _clamped_table(lengths, mb: int, block_t: int):
+    """Slot b owns blocks [1 + b*mb, 1 + (b+1)*mb) (block 0 is the trash
+    block); entries past a slot's last live block repeat it — the layout
+    the engine's host side maintains for the paged kernels."""
+    import numpy as np
+
+    table = np.zeros((len(lengths), mb), np.int32)
+    for b, n in enumerate(lengths):
+        last = max(-(-int(n) // block_t) - 1, 0)
+        table[b] = 1 + b * mb + np.minimum(np.arange(mb), last)
+    return table
+
+
+def kernel_cases(interpret: bool = False):
+    """(name, thunk) per kernel entry point; each thunk compiles and runs
+    the kernel and returns its max abs error against the jnp reference
+    on valid rows. ``interpret`` exists for rehearsing the harness on a
+    CPU; the smoke itself never sets it."""
+    import jax
+    import jax.numpy as jnp
+
+    from gofr_tpu.ops import attention, flash, flash_decode, paged_attention
+    from gofr_tpu.ops.quant import quantize_kv
+
+    def rand(i, shape):
+        return jax.random.normal(jax.random.PRNGKey(i), shape, jnp.bfloat16)
+
+    def prefill(s):
+        def run():
+            q, k, v = (rand(1, (2, s, H, D)), rand(2, (2, s, KV, D)),
+                       rand(3, (2, s, KV, D)))
+            lengths = jnp.asarray([s, s * 5 // 8], jnp.int32)
+            mask = jnp.arange(s)[None, :] < lengths[:, None]
+            got = flash.flash_causal_prefill(q, k, v, lengths,
+                                             interpret=interpret)
+            ref = attention.causal_attention(q, k, v, mask=mask)
+            return _max_err(got, ref, mask[:, :, None, None])
+        return run
+
+    # decode-side fixtures: 8 slots, capacity 1024 = 8 blocks of 128
+    b, smax, block_t = 8, 1024, 128
+    mb = smax // block_t
+
+    def decode(quant):
+        def run():
+            lengths = jnp.asarray([0, 1, 127, 128, 129, 500, 1000, 1023],
+                                  jnp.int32)
+            q, kn, vn = (rand(4, (b, 1, H, D)), rand(5, (b, 1, KV, D)),
+                         rand(6, (b, 1, KV, D)))
+            kc, vc = rand(7, (b, smax, KV, D)), rand(8, (b, smax, KV, D))
+            ks = vs = None
+            if quant:
+                (kc, ks), (vc, vs) = quantize_kv(kc), quantize_kv(vc)
+            got = flash_decode.flash_decode_appended(
+                q, kc, vc, kn, vn, lengths, ks, vs, interpret=interpret)
+            ref = attention.decode_attention_appended(
+                q, kc, vc, kn, vn, lengths, ks, vs)
+            return _max_err(got, ref)
+        return run
+
+    def paged(w):
+        def run():
+            # lengths EXCLUDE the w fresh tokens and leave room for them
+            lengths = [0, 1, 127, 128, 129, 500, 1000, smax - w]
+            table = jnp.asarray(_clamped_table(lengths, mb, block_t))
+            lengths = jnp.asarray(lengths, jnp.int32)
+            n = 1 + b * mb
+            q, kn, vn = (rand(9, (b, w, H, D)), rand(10, (b, w, KV, D)),
+                         rand(11, (b, w, KV, D)))
+            (kp, ks), (vp, vs) = (quantize_kv(rand(12, (n, block_t, KV, D))),
+                                  quantize_kv(rand(13, (n, block_t, KV, D))))
+            if w == 1:
+                kern = paged_attention.paged_decode_attention
+                refn = paged_attention.paged_attention_reference
+            else:
+                kern = paged_attention.paged_window_attention
+                refn = paged_attention.paged_window_reference
+            got = kern(q, kp, vp, kn, vn, table, lengths, ks, vs,
+                       interpret=interpret)
+            ref = refn(q, kp, vp, kn, vn, table, lengths, ks, vs)
+            return _max_err(got, ref)
+        return run
+
+    return [("flash_causal_prefill[S=256]", prefill(256)),
+            ("flash_causal_prefill[S=512]", prefill(512)),
+            ("paged_decode_attention[int8,T=128]", paged(1)),
+            ("paged_window_attention[int8,T=128,W=5]", paged(5)),
+            ("flash_decode_appended[int8]", decode(True)),
+            ("flash_decode_appended[bf16]", decode(False))]
+
+
+def phase_kernels(summary: dict, rec: dict) -> None:
+    platform = (summary.get("device") or {}).get("platform")
+    if platform != "tpu":
+        raise RuntimeError(f"needs a TPU: Mosaic compiles these kernels "
+                           f"only there (platform is {platform!r})")
+    rec["kernels"] = {}
+    failed = []
+    for name, run in kernel_cases():
+        t0 = time.monotonic()
+        try:
+            err = run()
+            ok = err <= KERNEL_TOL
+            rec["kernels"][name] = {"ok": ok, "max_err": round(err, 5)}
+        except Exception as e:  # report every kernel, not the first
+            traceback.print_exc()
+            ok = False
+            rec["kernels"][name] = {"ok": False,
+                                    "error": f"{type(e).__name__}: {e}"[:600]}
+        log(f"  kernel {name}: {rec['kernels'][name]} "
+            f"({time.monotonic() - t0:.1f}s)")
+        if not ok:
+            failed.append(name)
+    if failed:
+        raise AssertionError(f"kernels failed: {failed}")
+
+
+# -- phase 3: server ---------------------------------------------------------
+
+def load_example_app():
+    """The example's module, imported the way ``python main.py`` from its
+    directory would build it: ``App()`` reads ./configs/.env."""
+    cwd = os.getcwd()
+    os.chdir(EXAMPLE)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "tpu_token_streaming_main", os.path.join(EXAMPLE, "main.py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+    finally:
+        os.chdir(cwd)
+    return mod.app
+
+
+def http_get(port: int, path: str):
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=60)
+    try:
+        conn.request("GET", path)
+        r = conn.getresponse()
+        return r.status, r.read()
+    finally:
+        conn.close()
+
+
+def http_generate(port: int, tokens: list[int], max_new: int) -> list[int]:
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=300)
+    try:
+        conn.request("POST", "/generate",
+                     json.dumps({"tokens": tokens,
+                                 "max_new_tokens": max_new}),
+                     {"Content-Type": "application/json"})
+        r = conn.getresponse()
+        body = r.read()  # http.client de-chunks
+        if r.status != 200:
+            raise RuntimeError(f"POST /generate -> {r.status}: {body[:300]!r}")
+        return [json.loads(line)["token"]
+                for line in body.splitlines() if line]
+    finally:
+        conn.close()
+
+
+def grpc_generate(port: int, tokens: list[int], max_new: int) -> list[int]:
+    from gofr_tpu.grpcx import dial
+
+    ch = dial(f"127.0.0.1:{port}")
+    try:
+        return [m["token"] for m in ch.server_stream(
+            "/llm.Generation/Generate",
+            {"tokens": tokens, "max_new_tokens": max_new}, timeout=300)]
+    finally:
+        ch.close()
+
+
+def scrape(port: int) -> dict[str, float]:
+    """Prometheus text -> {metric name: sum over its label sets}."""
+    status, body = http_get(port, "/metrics")
+    if status != 200:
+        raise RuntimeError(f"GET /metrics -> {status}")
+    out: dict[str, float] = {}
+    for line in body.decode().splitlines():
+        if line and not line.startswith("#"):
+            series, _, value = line.rpartition(" ")
+            name = series.split("{", 1)[0]
+            out[name] = out.get(name, 0.0) + float(value)
+    return out
+
+
+class CompileClock:
+    """Seconds JAX spent in backend compiles (persistent-cache loads
+    included) and the cache's hit/miss counts, from jax.monitoring."""
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.seconds = 0.0
+        self.programs = 0
+        self.hits = 0
+        self.misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, seconds: float, **_) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += seconds
+            self.programs += 1
+
+    def _event(self, event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+
+def count_kernel_traces() -> dict[str, int]:
+    """Count how often each Pallas entry point is traced into a serving
+    program from here on. Token checks cannot tell a kernel from its jnp
+    fallback; the dispatchers resolve these names at call time, so a
+    counting wrapper on the module attribute sees every trace (cached
+    executables still trace)."""
+    import functools
+
+    from gofr_tpu.ops import flash, flash_decode, paged_attention
+
+    counts: dict[str, int] = {}
+    for mod, names in ((flash, ("flash_causal_prefill",
+                                "flash_prefill_sharded")),
+                       (flash_decode, ("flash_decode_appended",
+                                       "flash_decode_sharded")),
+                       (paged_attention, ("paged_decode_attention",
+                                          "paged_window_attention",
+                                          "paged_decode_sharded",
+                                          "paged_window_sharded"))):
+        for name in names:
+            fn = getattr(mod, name)
+            counts[name] = 0
+
+            def counted(*a, _fn=fn, _name=name, **kw):
+                counts[_name] += 1
+                return _fn(*a, **kw)
+
+            setattr(mod, name, functools.wraps(fn)(counted))
+    return counts
+
+
+def phase_server(summary: dict, rec: dict) -> None:
+    import random
+
+    from gofr_tpu.testutil import framework_threads
+
+    platform = (summary.get("device") or {}).get("platform")
+    if platform != "tpu" and "TPU_MODEL" not in os.environ:
+        raise RuntimeError(
+            f"not run: platform is {platform!r} and the example's model "
+            "needs the chip (set TPU_MODEL=tiny to rehearse the request "
+            "logic)")
+    # ephemeral ports: deployment settings, not model settings
+    for key in ("HTTP_PORT", "GRPC_PORT", "METRICS_PORT"):
+        os.environ.setdefault(key, "0")
+
+    clock = CompileClock()
+    rec["kernels_traced"] = count_kernel_traces()
+    t0 = time.monotonic()
+    app = load_example_app()  # App(): weights from the seed + warm-up
+    rec["startup_s"] = round(time.monotonic() - t0, 1)
+    summary["compile_s"] = round(clock.seconds, 1)
+    summary["compiled_programs"] = clock.programs
+    summary["cache_hits"], summary["cache_misses"] = clock.hits, clock.misses
+    log(f"  app built in {rec['startup_s']}s: {clock.programs} programs, "
+        f"{summary['compile_s']}s compiling, cache hits/misses "
+        f"{clock.hits}/{clock.misses}")
+    app.run(block=False)
+    try:
+        _drive(app, summary, rec, random.Random(0))
+    except BaseException:
+        app.stop()
+        raise
+    t0 = time.monotonic()
+    app.stop(grace_s=30.0)
+    rec["stop_s"] = round(time.monotonic() - t0, 1)
+    deadline = time.monotonic() + 15.0
+    while framework_threads() and time.monotonic() < deadline:
+        time.sleep(0.2)
+    if framework_threads():
+        raise AssertionError(
+            "framework threads outlived app.stop(): "
+            f"{sorted(t.name for t in framework_threads())}")
+    rec["programs_compiled_while_serving"] = \
+        clock.programs - summary["compiled_programs"]
+
+
+def _drive(app, summary: dict, rec: dict, rng) -> None:
+    import jax
+
+    from gofr_tpu import native
+    from gofr_tpu.tpu import hbm
+
+    engine = app.container.tpu
+    model = engine.generator.cfg
+    rec["model"] = {"name": model.name, "layers": model.n_layers,
+                    "dim": model.dim, "heads": model.n_heads,
+                    "kv_heads": model.n_kv_heads, "ffn": model.ffn_dim,
+                    "vocab": model.vocab_size,
+                    "quant": app.config.get("TPU_QUANT"),
+                    "kv_dtype": app.config.get("TPU_KV_DTYPE")}
+
+    # health: the tpu datasource is UP on the platform JAX reported
+    status, body = http_get(app.http_port, "/.well-known/health")
+    health = json.loads(body)["data"]
+    tpu = health.get("tpu") or {}
+    gen = tpu.get("details", {}).get("generator", {})
+    rec["health"] = {"status": health.get("status"),
+                     "tpu": tpu.get("status"),
+                     "platform": tpu.get("details", {}).get("platform"),
+                     "slots": gen.get("slots"),
+                     "max_seq": gen.get("max_seq"),
+                     "prompt_buckets": gen.get("prompt_buckets")}
+    log(f"  health: {rec['health']}")
+    if status != 200 or tpu.get("status") != "UP" or \
+            rec["health"]["platform"] != summary["device"]["platform"]:
+        raise AssertionError(f"health: {status} {rec['health']}")
+
+    vocab, max_seq = model.vocab_size, gen["max_seq"]
+    buckets = gen["prompt_buckets"]
+
+    def prompt(n: int) -> list[int]:
+        return [rng.randrange(1, vocab) for _ in range(n)]
+
+    def checked(tokens: list[int], want: int, what: str) -> list[int]:
+        if len(tokens) != want or not all(
+                isinstance(t, int) and 0 <= t < vocab for t in tokens):
+            raise AssertionError(
+                f"{what}: expected {want} in-vocabulary tokens, got "
+                f"{len(tokens)}: {tokens[:8]}…")
+        return tokens
+
+    before = scrape(app.metrics_port)
+    t_serve = time.monotonic()
+
+    # one prompt twice, nothing else in flight: the second admission hits
+    # the prefix pool and must stream the same greedy tokens. Long enough
+    # to be stored (>= TPU_PREFIX_MIN, by default the largest bucket), so
+    # it also admits through the chunk lattice.
+    store_min = app.config.get_int("TPU_PREFIX_MIN", 0) or buckets[-1]
+    repeat = prompt(store_min + max(1, buckets[0] // 2))
+    if len(repeat) + NEW_TOKENS >= max_seq:
+        raise AssertionError(f"repeat prompt {len(repeat)} does not fit "
+                             f"max_seq {max_seq}")
+    first = checked(http_generate(app.http_port, repeat, NEW_TOKENS),
+                    NEW_TOKENS, "repeat#1 (http)")
+    second = checked(grpc_generate(app.grpc_port, repeat, NEW_TOKENS),
+                     NEW_TOKENS, "repeat#2 (grpc)")
+    if first != second:
+        raise AssertionError(f"prefix hit changed greedy output: "
+                             f"{first} != {second}")
+    tokens_returned = 2 * NEW_TOKENS
+
+    # six streams at once over both transports; the two long prompts land
+    # in the 256 and 512 buckets, whose prefill carries the flash kernel
+    long_a = min(300, max_seq * 3 // 4 - NEW_TOKENS)
+    lens = [20, long_a, 64, 40, max(2, long_a * 2 // 3), 12]
+    results: list = [None] * len(lens)
+    spans: list = [None] * len(lens)
+    barrier = threading.Barrier(len(lens))
+
+    def client(i: int) -> None:
+        send = http_generate if i % 2 == 0 else grpc_generate
+        port = app.http_port if i % 2 == 0 else app.grpc_port
+        toks = prompt(lens[i])
+        barrier.wait(timeout=60)
+        t0 = time.monotonic()
+        try:
+            results[i] = send(port, toks, NEW_TOKENS)
+        except Exception as e:  # surfaces in the main thread below
+            results[i] = e
+        spans[i] = (t0, time.monotonic())
+
+    threads = [threading.Thread(target=client, args=(i,),
+                                name=f"smoke-client-{i}")
+               for i in range(len(lens))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=600)
+    if any(t.is_alive() for t in threads):
+        raise AssertionError("a client stream did not finish in 600s")
+    for i, r in enumerate(results):
+        if isinstance(r, Exception):
+            raise AssertionError(f"stream {i} (prompt {lens[i]}): {r!r}")
+        checked(r, NEW_TOKENS, f"stream {i} (prompt {lens[i]})")
+        tokens_returned += len(r)
+    in_flight = max(sum(1 for a, b in spans if a <= t < b)
+                    for t, _ in spans)
+    rec["streams"] = 2 + len(lens)
+    rec["prompt_lens"] = [len(repeat), len(repeat)] + lens
+    rec["max_in_flight"] = in_flight
+    if in_flight < 4:
+        raise AssertionError(f"only {in_flight} streams were in flight "
+                             "at once")
+    summary["serve_s"] = round(time.monotonic() - t_serve, 1)
+    summary["tokens"] = tokens_returned
+
+    # the prefix pool was hit, and the engine's own counters moved
+    _, body = http_get(app.http_port, "/.well-known/health")
+    gen = json.loads(body)["data"]["tpu"]["details"]["generator"]
+    rec["prefix_cache"] = {k: gen.get("prefix_cache", {}).get(k)
+                           for k in ("hits", "misses", "entries")}
+    rec["spec_decode"] = gen.get("spec_decode")
+    if not rec["prefix_cache"]["hits"]:
+        raise AssertionError(f"prefix pool never hit: {rec['prefix_cache']}")
+    after = scrape(app.metrics_port)
+    moved = {k: after.get(k, 0.0) - before.get(k, 0.0) for k in after}
+    rec["metrics"] = {k: moved.get(k, 0.0) for k in (
+        "app_tpu_tokens_generated_total", "app_tpu_ttft_duration_count",
+        "app_tpu_prefill_chunks_total", "app_tpu_kvcache_hits_total",
+        "app_tpu_shed_total", "app_tpu_hbm_shed_total",
+        "app_tpu_expired_dropped_total", "app_tpu_paged_evictions_total")}
+    log(f"  metrics moved: {rec['metrics']}")
+    if rec["metrics"]["app_tpu_tokens_generated_total"] < tokens_returned \
+            or rec["metrics"]["app_tpu_ttft_duration_count"] < rec["streams"]:
+        raise AssertionError(f"app_tpu_* counters did not move: "
+                             f"{rec['metrics']}")
+    bad = {k: v for k, v in rec["metrics"].items()
+           if v and ("shed" in k or "expired" in k or "evictions" in k)}
+    if bad:
+        raise AssertionError(f"shed/OOM counters moved: {bad}")
+
+    # on a TPU the serving programs carry the kernels, not their jnp
+    # fallbacks: flash prefill from bucket 256 up, the paged kernels on
+    # a block pool, each in its shard_map'd form on a mesh
+    traced = rec["kernels_traced"]
+    log(f"  kernels traced into serving programs: {traced}")
+    if summary["device"]["platform"] == "tpu":
+        want = ["flash_causal_prefill"] if buckets[-1] >= 256 else []
+        if "paged" in gen:
+            want.append("paged_decode_attention")
+            if gen.get("spec_decode"):
+                want.append("paged_window_attention")
+        if "mesh" in gen:
+            want += [{"flash_causal_prefill": "flash_prefill_sharded",
+                      "paged_decode_attention": "paged_decode_sharded",
+                      "paged_window_attention": "paged_window_sharded"}[k]
+                     for k in list(want)]
+        missing = [k for k in want if not traced[k]]
+        if missing:
+            raise AssertionError(f"serving never traced {missing}: a jnp "
+                                 f"fallback ran instead ({traced})")
+
+    # memory after serving: what the backend reports, per device, and
+    # what the arbiter thinks it leased
+    stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    summary["hbm"] = [{"device": d.id,
+                       "bytes_in_use": s.get("bytes_in_use"),
+                       "peak_bytes_in_use": s.get("peak_bytes_in_use"),
+                       "bytes_limit": s.get("bytes_limit")}
+                      for d, s in zip(jax.local_devices(), stats)]
+    arb = hbm.arbiter_stats()
+    summary["arbiter"] = {k: arb.get(k) for k in (
+        "budget_bytes", "device_budget_bytes", "in_use_bytes",
+        "headroom_bytes", "sheds", "oom_retries", "devices")}
+    summary["native"] = {"loaded": native.available(),
+                         "error": native.load_error()}
+    log(f"  hbm: {summary['hbm']}\n  arbiter: {summary['arbiter']}\n"
+        f"  native: {summary['native']}")
+    if not summary["native"]["loaded"]:
+        raise AssertionError(f"native runtime not loaded: "
+                             f"{summary['native']['error']}")
+
+
+# -- summary -----------------------------------------------------------------
+
+def result_line(summary: dict) -> str:
+    """The last stdout line: exactly ``ok`` and the device as JAX reports
+    it. Whoever runs the smoke parses this line and accepts no other key;
+    the detail is the summary line before it."""
+    device = summary["device"]
+    return json.dumps({"ok": summary["ok"],
+                       "device": {"platform": device["platform"],
+                                  "kind": device["kind"],
+                                  "count": device["count"]}})
+
+
+def main() -> int:
+    # stdout carries the summary and the result line and nothing else: the
+    # framework's logger (bound to sys.stdout at construction) goes to stderr
+    result_out, sys.stdout = sys.stdout, sys.stderr
+    summary: dict = {"ok": False, "device": None, "phases": {}}
+    run_phase(summary, "device", phase_device)
+    run_phase(summary, "kernels", phase_kernels)
+    run_phase(summary, "server", phase_server)
+    summary["ok"] = all(p["ok"] for p in summary["phases"].values())
+    summary["claim"] = None
+    line = json.dumps(summary)
+    if not summary["ok"]:
+        failed = [n for n, p in summary["phases"].items() if not p["ok"]]
+        log(f"chip_smoke FAILED in {failed}: {line}")
+        return 1
+    print(line, file=result_out)
+    print(result_line(summary), file=result_out, flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -62,8 +62,7 @@ def _data(ctx, cfg, batch: int, seq: int):
 
 def _run(ctx, resume: bool) -> str:
     # -platform=cpu -devices=8: force a virtual host mesh for local dev
-    # BEFORE first backend use (env vars are too late on boxes whose
-    # sitecustomize pins a TPU platform at interpreter boot).
+    # BEFORE first backend use.
     platform = ctx.param("platform", "")
     if platform:
         jax.config.update("jax_platforms", platform)

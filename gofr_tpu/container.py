@@ -5,7 +5,9 @@ Logger, Services, metricsManager, PubSub, Redis, SQL) and :44-126
 (``NewContainer(conf)`` wiring everything from config with graceful
 degradation — a down datasource logs and stays None instead of failing
 startup). Health aggregation: container/health.go:5-25. The TPU engine is a
-first-class datasource here — the whole point of the framework.
+first-class datasource here — the whole point of the framework — and the
+one exception to the degradation rule: a configured model that cannot be
+built fails startup.
 """
 
 from __future__ import annotations
@@ -30,6 +32,14 @@ class Container:
         )
         self.metrics = gmetrics.Manager(logger=self.logger)
         gmetrics.register_framework_metrics(self.metrics)
+        from . import native
+
+        native_error = native.load_error()
+        if native_error:
+            # the Python batcher and histograms serve correctly but hold
+            # the GIL where the C runtime would not — never silently
+            self.logger.error({"event": "native runtime unavailable",
+                               "error": native_error})
         # tail-sampled when exporting (TPU_TRACE_SAMPLE); the metrics
         # handle feeds app_tpu_spans_dropped_total from the bounded
         # export buffer
@@ -85,13 +95,17 @@ class Container:
             except Exception as e:
                 log.error({"event": "pubsub connect failed", "backend": backend, "error": repr(e)})
         if cfg.get("TPU_MODEL") or cfg.get_bool("TPU_ENABLED"):
-            try:
-                from .tpu import new_engine_from_config
+            # NOT degraded like the datasources above: a server asked
+            # for a model that serves none must not start, answer
+            # health UP and exit 0
+            from .tpu import new_engine_from_config
 
+            try:
                 self.tpu = new_engine_from_config(cfg, log, self.metrics,
                                                   observe=self.observe)
             except Exception as e:
                 log.error({"event": "tpu engine init failed", "error": repr(e)})
+                raise
 
     def _wire_remote_log_level(self) -> None:
         """Reference: logging/dynamicLevelLogger.go wired at

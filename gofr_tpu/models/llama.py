@@ -424,19 +424,25 @@ def _causal_scan(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 def forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             lengths: jnp.ndarray | None = None, rope_tables=None,
             constrain=None, attend_override=None,
-            return_router_probs: bool = False, adapter=None):
+            return_router_probs: bool = False, adapter=None,
+            logit_pos: jnp.ndarray | None = None):
     """Cache-free causal forward over [B, S] tokens -> [B, S, V] f32 logits.
     The training/scoring path: no KV-cache allocation or writes.
     ``attend_override``: see _causal_scan (ring attention hook).
     ``return_router_probs``: also return the per-layer MoE router
     probabilities [L, B, S, E] (the load-balancing aux-loss input);
-    returns (logits, probs) — probs is None for dense models."""
+    returns (logits, probs) — probs is None for dense models.
+    ``logit_pos`` [B]: project ONE position per row -> [B, 1, V] (the
+    gather precedes lm_head — see prefill_kv)."""
     x, _, _, probs = _causal_scan(params, cfg, tokens, lengths,
                                   tokens.shape[1], rope_tables, constrain,
                                   collect_kv=False,
                                   attend_override=attend_override,
                                   collect_router=return_router_probs,
                                   adapter=adapter)
+    if logit_pos is not None:
+        x = jnp.take_along_axis(x, logit_pos[:, None, None]
+                                .astype(jnp.int32), axis=1)  # [B, 1, D]
     logits = _logits(params, cfg, x)
     if return_router_probs:
         return logits, probs

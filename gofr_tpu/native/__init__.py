@@ -1,11 +1,13 @@
 """ctypes loader for the native runtime (gofr_runtime.cc).
 
-Build model: the shared library is compiled on first import (g++ -O2
--shared, ~1s) and cached next to the source; environments without a
-toolchain fall back to pure-Python equivalents — every native consumer
-(batcher, metrics) keeps a fallback path, mirroring how the reference
-degrades gracefully when a datasource is absent
-(container/container.go:55-126).
+Build model: the shared library is compiled on first load (g++ -O2
+-shared, ~1s) and cached next to the source under a name carrying the
+source's content hash, so only a binary built from exactly this source
+is ever loaded — a stale or copied-in ``.so`` has the wrong name.
+Environments without a toolchain fall back to pure-Python equivalents —
+every native consumer (batcher, metrics) keeps a fallback path — but not
+silently: ``load_error()`` says why, and the container logs it at
+startup.
 
 Set GOFR_NATIVE=0 to force the Python paths (useful for debugging).
 """
@@ -13,28 +15,48 @@ Set GOFR_NATIVE=0 to force the Python paths (useful for debugging).
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "gofr_runtime.cc")
-_SO = os.path.join(_DIR, "libgofr_runtime.so")
 
 _lib = None
 _load_lock = threading.Lock()
 _load_attempted = False
+_load_error: str | None = None
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libgofr_runtime.{digest}.so")
+
+
+def _build(so: str) -> None:
+    """Compile to a scratch name, then rename: a concurrent process never
+    loads a half-written library. Raises with the compiler's stderr."""
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-             "-o", _SO, _SRC],
+             "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120)
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
+        os.replace(tmp, so)
+    except subprocess.CalledProcessError as e:
+        raise OSError(f"g++ failed (rc={e.returncode}): "
+                      f"{e.stderr.decode(errors='replace')[-400:]}") from e
+    except subprocess.TimeoutExpired as e:
+        raise OSError("g++ timed out after 120s") from e
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    for stale in glob.glob(os.path.join(_DIR, "libgofr_runtime*.so")):
+        if stale != so:
+            os.unlink(stale)
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -61,8 +83,9 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def load():
-    """The native library, or None when unavailable."""
-    global _lib, _load_attempted
+    """The native library, or None when unavailable (``load_error()``
+    then says why, unless GOFR_NATIVE=0 asked for it)."""
+    global _lib, _load_attempted, _load_error
     if _lib is not None or _load_attempted:
         return _lib
     with _load_lock:
@@ -72,18 +95,25 @@ def load():
         if os.environ.get("GOFR_NATIVE", "1") == "0":
             return None
         try:
-            if not os.path.exists(_SO) or (
-                    os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-                if not _build():
-                    return None
-            _lib = _bind(ctypes.CDLL(_SO))
-        except OSError:
+            so = _so_path()
+            if not os.path.exists(so):
+                _build(so)
+            _lib = _bind(ctypes.CDLL(so))
+        except OSError as e:  # no toolchain, failed build, unloadable .so
             _lib = None
+            _load_error = f"{type(e).__name__}: {e}"
         return _lib
 
 
 def available() -> bool:
     return load() is not None
+
+
+def load_error() -> str | None:
+    """Why the library failed to build or load; None when it loaded or
+    was switched off with GOFR_NATIVE=0."""
+    load()
+    return _load_error
 
 
 class NativeBatchQueue:

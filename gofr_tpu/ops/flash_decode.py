@@ -53,8 +53,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 from .attention import NEG_INF, decode_attention_appended
 
 _LANES = 128
@@ -199,7 +197,7 @@ def _flash_decode_cache(q, k_cache, v_cache, lengths, k_scale, v_scale,
             jax.ShapeDtypeStruct((b, h, _LANES), jnp.float32),
             jax.ShapeDtypeStruct((b, h, _LANES), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), q_bd, k_cache, v_cache, ks_t, vs_t)
@@ -255,10 +253,8 @@ def flash_decode_sharded(q, k_cache, v_cache, k_new, v_new, lengths,
     data-parallel meshes. The specs mirror parallel.kv_cache_specs so
     GSPMD never gathers the cache at the shard_map boundary; no
     collectives inside attention (the o-proj psum downstream is
-    unchanged). check_rep off: pallas_call has no replication rule."""
+    unchanged). check_vma off: pallas_call has no replication rule."""
     from jax.sharding import PartitionSpec as P
-
-    from .flash import shard_map
 
     bax = tuple(batch_axes) or None
     qspec = P(bax, None, head_axis, None)      # q/k_new/v_new [B,1,·,D]
@@ -271,10 +267,10 @@ def flash_decode_sharded(q, k_cache, v_cache, k_new, v_new, lengths,
                                          block_s=block_s,
                                          interpret=interpret)
 
-        fn = shard_map(run, mesh=mesh,
-                       in_specs=(qspec, cspec, cspec, cspec, cspec, lspec,
-                                 sspec, sspec),
-                       out_specs=qspec, check_rep=False)
+        fn = jax.shard_map(run, mesh=mesh,
+                           in_specs=(qspec, cspec, cspec, cspec, cspec,
+                                     lspec, sspec, sspec),
+                           out_specs=qspec, check_vma=False)
         return fn(q, k_cache, v_cache, k_new, v_new, lengths,
                   k_scale, v_scale)
 
@@ -282,9 +278,9 @@ def flash_decode_sharded(q, k_cache, v_cache, k_new, v_new, lengths,
         return flash_decode_appended(q, kc, vc, kn, vn, ln,
                                      block_s=block_s, interpret=interpret)
 
-    fn = shard_map(run, mesh=mesh,
-                   in_specs=(qspec, cspec, cspec, cspec, cspec, lspec),
-                   out_specs=qspec, check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(qspec, cspec, cspec, cspec, cspec, lspec),
+                       out_specs=qspec, check_vma=False)
     return fn(q, k_cache, v_cache, k_new, v_new, lengths)
 
 

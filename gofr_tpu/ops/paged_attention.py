@@ -52,8 +52,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 from .attention import NEG_INF, decode_attention_appended
 from .flash_decode import _LANES, _decode_kernel
 
@@ -127,7 +125,7 @@ def _paged_decode_cache(q, k_pool, v_pool, table, lengths, k_scale, v_scale,
             jax.ShapeDtypeStruct((b, h, _LANES), jnp.float32),
             jax.ShapeDtypeStruct((b, h, _LANES), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), table.astype(jnp.int32),
@@ -174,11 +172,9 @@ def _paged_sharded(inner, mesh, head_axis, args, scales):
     q/k_new/v_new shard KV-heads (the paged mesh layout is tp-only —
     parallel.paged_cache_specs replicates batch, table, and lengths).
     Each device streams its local [KV/tp] pane of every block; no dense
-    gather, no collectives. check_rep off: pallas_call has no
+    gather, no collectives. check_vma off: pallas_call has no
     replication rule."""
     from jax.sharding import PartitionSpec as P
-
-    from .flash import shard_map
 
     hspec = P(None, None, head_axis, None)   # q/k_new/v_new and pools
     sspec = P(None, None, head_axis)         # pool scales [N, T, KV]
@@ -186,8 +182,8 @@ def _paged_sharded(inner, mesh, head_axis, args, scales):
     if scales is not None:
         in_specs = in_specs + (sspec, sspec)
         args = args + scales
-    fn = shard_map(inner, mesh=mesh, in_specs=in_specs, out_specs=hspec,
-                   check_rep=False)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                       out_specs=hspec, check_vma=False)
     return fn(*args)
 
 

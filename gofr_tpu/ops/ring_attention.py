@@ -35,11 +35,6 @@ from jax.sharding import PartitionSpec as P
 
 from .attention import NEG_INF, causal_attention
 
-try:  # jax >= 0.4.35 exposes shard_map at the top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
-
 
 def ring_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           lengths: jnp.ndarray | None, *,
@@ -144,13 +139,13 @@ def make_ring_attention(mesh, *, axis_name: str = "sp",
         inner = functools.partial(ring_causal_attention,
                                   axis_name=axis_name)
         if lengths is None:
-            fn = shard_map(lambda q_, k_, v_: inner(q_, k_, v_, None),
-                           mesh=mesh, in_specs=(qspec, qspec, qspec),
-                           out_specs=qspec)
+            fn = jax.shard_map(lambda q_, k_, v_: inner(q_, k_, v_, None),
+                               mesh=mesh, in_specs=(qspec, qspec, qspec),
+                               out_specs=qspec)
             return fn(q, k, v)
-        fn = shard_map(inner, mesh=mesh,
-                       in_specs=(qspec, qspec, qspec, P(bspec)),
-                       out_specs=qspec)
+        fn = jax.shard_map(inner, mesh=mesh,
+                           in_specs=(qspec, qspec, qspec, P(bspec)),
+                           out_specs=qspec)
         return fn(q, k, v, lengths)
 
     return attend

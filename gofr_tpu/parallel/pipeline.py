@@ -55,25 +55,6 @@ from .mesh import AXIS_PP, AXIS_SP, Mesh
 from .train import loss_parts_local
 
 
-def _manual_shard_map(f, mesh, *, in_specs, out_specs, manual):
-    """shard_map manual over ``manual`` axes, auto everywhere else —
-    bridging the new top-level API (axis_names/check_vma) and the
-    pre-0.4.35 experimental one (auto/check_rep)."""
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=manual,
-                             check_vma=False)
-    except (AttributeError, TypeError):
-        # older jax: either no top-level shard_map at all, or a top-level
-        # alias that still has the experimental signature (auto/check_rep
-        # instead of axis_names/check_vma) and rejects the kwargs above
-        from jax.experimental.shard_map import shard_map
-
-        auto = frozenset(mesh.axis_names) - set(manual)
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, auto=auto, check_rep=False)
-
-
 def _stage_apply(layers_local: Any, x: jnp.ndarray, cfg: ModelConfig,
                  cos, sin, positions, valid, attend) -> jnp.ndarray:
     """Run this stage's local layer stack over one microbatch (shard)."""
@@ -230,9 +211,10 @@ def make_pp_loss_fn(cfg: ModelConfig, mesh: Mesh, *, n_microbatches: int,
         param_specs = {k: (P(AXIS_PP) if k == "layers" else P())
                        for k in params}
         manual = {AXIS_PP} | ({AXIS_SP} if n_sp > 1 else set())
-        fn = _manual_shard_map(pp_body, mesh,
-                               in_specs=(param_specs, P(), P()),
-                               out_specs=(P(), P()), manual=manual)
+        fn = jax.shard_map(pp_body, mesh=mesh,
+                           in_specs=(param_specs, P(), P()),
+                           out_specs=(P(), P()), axis_names=manual,
+                           check_vma=False)
         return fn(params, tokens, lengths)
 
     return loss_fn

@@ -5,6 +5,7 @@ os.go:8-36)."""
 from __future__ import annotations
 
 import io
+import threading
 from contextlib import redirect_stderr, redirect_stdout
 from typing import Callable
 
@@ -49,3 +50,16 @@ def stderr_output_for(fn: Callable[[], None]) -> str:
     with redirect_stderr(buf):
         fn()
     return buf.getvalue()
+
+
+def framework_threads() -> list[threading.Thread]:
+    """Live threads the framework started. Every one — engine loops,
+    breaker probes, JWKS refreshers, pollers — is named and must be
+    stopped by its owner's close()/stop(); the test session and
+    chip_smoke.py both fail on any that outlive their owner."""
+    return [
+        t for t in threading.enumerate()
+        if t is not threading.main_thread() and t.is_alive()
+        and (t.name.startswith(("cb-probe-", "gofr-", "jwks-refresh",
+                                "zipkin-exporter", "remote-log-level"))
+             or "probe" in t.name or "poller" in t.name)]

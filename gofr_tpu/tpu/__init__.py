@@ -1,17 +1,20 @@
 """TPU datasource: engine, continuous batching, checkpoint loading.
 
 Wired into the container the way Redis/SQL are in the reference
-(pkg/gofr/container/container.go:55-126 builds each datasource from config
-with graceful degradation): ``new_engine_from_config`` reads ``TPU_*``
-config keys, builds the engine, registers the model family's programs, and
-hands back a health-checkable datasource reachable as ``ctx.tpu``.
+(pkg/gofr/container/container.go:55-126 builds each datasource from
+config): ``new_engine_from_config`` reads ``TPU_*`` config keys, builds the
+engine, registers the model family's programs, and hands back a
+health-checkable datasource reachable as ``ctx.tpu``. Unlike Redis/SQL, a
+configured model that cannot be built is a start-up failure, not a
+degraded start.
 
 Config keys (reference config style, pkg/gofr/config/config.go:3):
   TPU_MODEL           model name: llama family (llama3-8b, llama-1b, tiny),
                       bert family (bert/bert-base, bert-tiny), or
                       vit family (vit/vit-l-14, vit-tiny)
   TPU_WEIGHTS         checkpoint path (.npz or orbax dir); absent = random
-                      init (smoke/serving-bringup mode)
+                      init from a seed, built leaf by leaf at the serving
+                      dtype and sharding (smoke/serving-bringup mode)
   TPU_QUANT           "int8" to quantize projection weights on load
   TPU_KV_DTYPE        KV-cache dtype for generation: "int8" (default —
                       halves decode's cache HBM stream; quantize-on-write,
@@ -217,14 +220,14 @@ import jax.numpy as jnp
 
 from .batcher import BatcherClosed, ClassPolicy, CoalescingBatcher, pad_bucket
 from .checkpoint import (load_npz, load_orbax, load_params, maybe_quantize,
-                         placed, save_npz, save_orbax)
+                         placed, random_params, save_npz, save_orbax)
 from .engine import DEFAULT_BATCH_BUCKETS, DEFAULT_SEQ_BUCKETS, Program, TPUEngine
 from .generator import GenerationEngine, GenerationError, GenStream
 
 __all__ = [
     "BatcherClosed", "ClassPolicy", "CoalescingBatcher", "pad_bucket",
     "load_npz", "load_orbax", "load_params", "maybe_quantize", "placed",
-    "save_npz", "save_orbax",
+    "random_params", "save_npz", "save_orbax",
     "DEFAULT_BATCH_BUCKETS", "DEFAULT_SEQ_BUCKETS", "Program", "TPUEngine",
     "GenerationEngine", "GenerationError", "GenStream",
     "new_engine_from_config", "parse_mesh",
@@ -267,7 +270,10 @@ def parse_mesh(spec: str | None):
 
 def new_engine_from_config(cfg, logger=None, metrics=None,
                            observe=None) -> TPUEngine:
+    from .. import compile_cache
     from ..models import BERT_CONFIGS, LLAMA_CONFIGS, VIT_CONFIGS
+
+    compile_cache.configure()
 
     if (cfg.get("TPU_SERVING_ROLE") or "").strip().lower() == "gateway":
         # the gateway role (gofr_tpu/gateway) is an APP mode, not an
@@ -291,11 +297,14 @@ def new_engine_from_config(cfg, logger=None, metrics=None,
 
     # the HBM arbiter budget (one per process — subsystems of every
     # engine built after this lease from it; mesh engines additionally
-    # settle PER-DEVICE leases checked against the per-device budget)
+    # settle PER-DEVICE leases checked against the per-device budget).
+    # Sized to the chips THIS engine occupies: its mesh's, or one.
     hbm.configure(budget_mb=cfg.get_int("TPU_HBM_BUDGET_MB", 0) or None,
                   headroom=cfg.get_float("TPU_HBM_HEADROOM", 0.1),
                   device_budget_mb=cfg.get_int("TPU_HBM_DEVICE_BUDGET_MB",
-                                               0) or None)
+                                               0) or None,
+                  n_devices=len(mesh.local_devices) if mesh is not None
+                  else 1)
 
     tracer = getattr(observe, "tracer", None)
     batch_share = cfg.get_float("TPU_SLO_BATCH_SHARE", 0.0)
@@ -316,10 +325,8 @@ def new_engine_from_config(cfg, logger=None, metrics=None,
 
     def params_for(model_cfg, init_fn):
         if weights:
-            params = load_params(weights)
-        else:
-            params = init_fn(model_cfg, jax.random.PRNGKey(0))
-        return placed(maybe_quantize(params, quant), mesh)
+            return placed(maybe_quantize(load_params(weights), quant), mesh)
+        return random_params(init_fn, model_cfg, quant=quant, mesh=mesh)
 
     if name.startswith("bert"):
         from ..models import bert
@@ -407,10 +414,10 @@ def new_engine_from_config(cfg, logger=None, metrics=None,
         score_mc = llama.multi_request_serving_config(mc)
 
         def score_fn(p, tokens, lengths):
-            logits = llama.forward(p, score_mc, tokens, lengths)
-            idx = jnp.maximum(lengths - 1, 0)
-            return jnp.take_along_axis(
-                logits, idx[:, None, None], axis=1)[:, 0]
+            # gather the prompt-end hidden state BEFORE lm_head: full
+            # [B, S, V] f32 logits are 2.1 GB at (8, 512) x 128k vocab
+            return llama.forward(p, score_mc, tokens, lengths,
+                                 logit_pos=jnp.maximum(lengths - 1, 0))[:, 0]
 
         seq_b = tuple(b for b in seq_buckets if b <= max_seq) or (max_seq,)
         engine.register("score", score_fn, params, kind="tokens",

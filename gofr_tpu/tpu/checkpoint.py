@@ -130,6 +130,63 @@ def maybe_quantize(params: Any, enabled: bool) -> Any:
     return walk(params)
 
 
+def random_params(init_fn, cfg, *, quant: bool = False, mesh=None,
+                  seed: int = 0) -> Any:
+    """Random-init weights in the SERVING layout, built leaf by leaf at
+    each leaf's final dtype and sharding — the ``TPU_WEIGHTS``-unset
+    bring-up path, and the only one a machine without a checkpoint has.
+
+    Peak device memory during init is the serving footprint plus one
+    leaf's temporaries: ``maybe_quantize(init_fn(cfg, key))`` would hold
+    the whole bf16 model plus float32 temporaries (7.5 GB for ONE
+    Llama-3-8B FFN leaf) before the first int8 byte exists, which a
+    16 GB chip cannot. Each leaf is its own jitted program with
+    ``out_shardings`` from parallel.shardings_for, so on a mesh every
+    leaf is born sharded (no full copy on the first chip).
+
+    Dense leaves take exactly the values ``init_fn(cfg, key)`` gives
+    them (XLA dead-code-eliminates the other leaves from each program).
+    Quantized projections are drawn directly as uniform int8 with the
+    constant per-channel scale that gives fan-in variance — there is no
+    bf16 original to quantize."""
+    key = jax.random.PRNGKey(seed)
+
+    def build(k):
+        return maybe_quantize(init_fn(cfg, k), quant)
+
+    def is_q(x):
+        return isinstance(x, QuantizedLinear)
+
+    abstract = jax.eval_shape(build, key)
+    structs, treedef = jax.tree_util.tree_flatten(abstract, is_leaf=is_q)
+    if mesh is not None:
+        from ..parallel import shardings_for
+
+        shardings = treedef.flatten_up_to(shardings_for(abstract, mesh))
+    else:
+        shardings = [None] * len(structs)
+
+    def leaf(i, struct, sharding):
+        if is_q(struct):
+            shape = struct.w.shape
+            # uniform int8 has std 127/sqrt(3); fan-in variance overall
+            scale = (shape[-2] ** -0.5) * (3.0 ** 0.5) / 127.0
+
+            def make(k):
+                return QuantizedLinear(
+                    w=jax.random.randint(jax.random.fold_in(k, i), shape,
+                                         -127, 128, jnp.int8),
+                    scale=jnp.full(struct.scale.shape, scale, jnp.float32))
+        else:
+            def make(k):
+                return jax.tree_util.tree_leaves(build(k), is_leaf=is_q)[i]
+        return jax.jit(make, out_shardings=sharding)(key)
+
+    return treedef.unflatten(
+        [leaf(i, st, sh) for i, (st, sh) in enumerate(zip(structs,
+                                                          shardings))])
+
+
 def placed(params: Any, mesh=None) -> Any:
     """Move a host param tree onto device — sharded over ``mesh`` when
     given (specs from parallel.param_specs), else default placement."""

@@ -2,8 +2,9 @@
 
 No reference equivalent (SURVEY §2 last rows): GoFr's container carries
 Redis/SQL/PubSub clients (pkg/gofr/container/container.go:26-38); here the
-accelerator is wired the same way — constructed from config with graceful
-degradation, health-checked into ``/.well-known/health``, observable through
+accelerator is wired the same way — constructed from config (a model that
+cannot be built fails startup: it is what the server is for),
+health-checked into ``/.well-known/health``, observable through
 ``app_tpu_*`` metrics, reachable from handlers as ``ctx.tpu``.
 
 TPU-first design:

@@ -438,51 +438,58 @@ class _Registry:
 
     def configure(self, budget_mb: int | None = None,
                   headroom: float = 0.1,
-                  device_budget_mb: int | None = None) -> int | None:
+                  device_budget_mb: int | None = None,
+                  n_devices: int | None = None) -> int | None:
         """Resolve and install the budgets. An explicit ``budget_mb``
         / ``device_budget_mb`` wins its OWN axis; any axis left unset
-        resolves, on accelerator backends, from each local device's
+        resolves, on accelerator backends, from the local device's
         reported ``bytes_limit`` minus the ``headroom`` fraction (XLA
         keeps workspace the registry can't see): that figure is the
-        PER-DEVICE budget and the process budget is it times the
-        LOCAL device count — a mesh process honestly owns its own
-        chips' HBM, not the pod's. Setting TPU_HBM_BUDGET_MB alone
-        therefore still arms per-device arbitration. The CPU backend
-        leaves unset axes off — there is no meaningful device limit
-        to enforce, and every existing test would suddenly arbitrate
-        against host RAM. Returns the active budget."""
+        PER-DEVICE budget and the process budget is it times
+        ``n_devices`` — the chips the engine being built will occupy
+        (its mesh's local devices, or one), defaulting to every LOCAL
+        device: a mesh process honestly owns its own chips' HBM, not
+        the pod's, and a single-device engine on a four-chip host owns
+        one chip's. Setting TPU_HBM_BUDGET_MB alone therefore still
+        arms per-device arbitration. The CPU backend leaves unset axes
+        off — there is no meaningful device limit to enforce, and every
+        existing test would suddenly arbitrate against host RAM. An
+        accelerator that reports no ``bytes_limit`` is an error: the
+        arbiter silently off is how a 16 GB chip OOMs mid-serving.
+        Returns the active budget."""
         if device_budget_mb:
             self.set_device_budget(int(device_budget_mb) << 20)
         if budget_mb:
             self.set_budget(int(budget_mb) << 20)
         if budget_mb and device_budget_mb:
             return self._budget
-        try:
-            import jax
+        import jax
 
-            # LOCAL devices: under the distributed runtime
-            # jax.devices() is the global pod list, but this process
-            # only owns (and only accounts) its local chips' HBM — a
-            # pod-wide budget would never bind.
-            devices = jax.local_devices()
-            dev = devices[0]
-            if dev.platform != "cpu":
-                stats = dev.memory_stats() or {}
-                limit = stats.get("bytes_limit")
-                if limit:
-                    frac = min(max(float(headroom), 0.0), 0.9)
-                    per_dev = int(limit * (1.0 - frac))
-                    # an explicit knob wins its own axis, but never
-                    # disables the OTHER one: TPU_HBM_BUDGET_MB alone
-                    # still resolves the per-device bound (and vice
-                    # versa) — per-device arbitration must not turn
-                    # off because the global knob predates it
-                    if not device_budget_mb:
-                        self.set_device_budget(per_dev)
-                    if not budget_mb:
-                        self.set_budget(per_dev * len(devices))
-        except Exception:
-            pass  # no backend yet / stats unsupported: budget stays off
+        # LOCAL devices: under the distributed runtime jax.devices() is
+        # the global pod list, but this process only owns (and only
+        # accounts) its local chips' HBM — a pod-wide budget would
+        # never bind.
+        devices = jax.local_devices()
+        dev = devices[0]
+        if dev.platform == "cpu":
+            return self._budget
+        limit = (dev.memory_stats() or {}).get("bytes_limit")
+        if not limit:
+            raise RuntimeError(
+                f"{dev.platform} device {dev.device_kind!r} reports no "
+                "memory_stats()['bytes_limit']: the HBM budget cannot be "
+                "resolved — set TPU_HBM_BUDGET_MB and "
+                "TPU_HBM_DEVICE_BUDGET_MB explicitly")
+        frac = min(max(float(headroom), 0.0), 0.9)
+        per_dev = int(limit * (1.0 - frac))
+        # an explicit knob wins its own axis, but never disables the
+        # OTHER one: TPU_HBM_BUDGET_MB alone still resolves the
+        # per-device bound (and vice versa) — per-device arbitration
+        # must not turn off because the global knob predates it
+        if not device_budget_mb:
+            self.set_device_budget(per_dev)
+        if not budget_mb:
+            self.set_budget(per_dev * (n_devices or len(devices)))
         return self._budget
 
     def _in_use_locked(self) -> int:

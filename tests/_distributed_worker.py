@@ -15,21 +15,16 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 pid, nprocs, port = int(sys.argv[1]), int(sys.argv[2]), sys.argv[3]
 
-# Force 4 local devices BEFORE backend init, on old and new JAX alike.
-# The XLA flag must REPLACE any inherited force-count (conftest exports
-# an 8-wide one into the test process env on old JAX).
-_flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
-          if not f.startswith("--xla_force_host_platform_device_count")]
+# 4 local devices per process, set before backend init; a force-count
+# inherited through XLA_FLAGS would fight it.
 os.environ["XLA_FLAGS"] = " ".join(
-    _flags + ["--xla_force_host_platform_device_count=4"])
+    f for f in os.environ.get("XLA_FLAGS", "").split()
+    if not f.startswith("--xla_force_host_platform_device_count"))
 
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 4)
-except AttributeError:
-    pass  # older JAX: the XLA_FLAGS override above covers it
+jax.config.update("jax_num_cpu_devices", 4)
 
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402

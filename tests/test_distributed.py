@@ -13,9 +13,6 @@ import socket
 import subprocess
 import sys
 
-import jax
-import pytest
-
 from gofr_tpu.config import MapConfig
 from gofr_tpu.parallel import distributed
 
@@ -36,12 +33,6 @@ def test_maybe_initialize_noop_without_coordinator():
     assert distributed.is_initialized() is False
 
 
-@pytest.mark.skipif(
-    jax.__version_info__ < (0, 5),
-    reason="multiprocess computations are unimplemented on the CPU "
-           "backend before jax 0.5 (XlaRuntimeError INVALID_ARGUMENT); "
-           "the workers join the runtime fine but the first sharded "
-           "jit over the global mesh aborts")
 def test_two_process_sharded_train_and_generate():
     port = _free_port()
     env = {**os.environ, "JAX_PLATFORMS": ""}

@@ -157,7 +157,7 @@ def test_is_oom_error_classification():
     assert hbm.is_oom_error(hbm.HBMExhausted("engine", 4))
     assert hbm.is_oom_error(RuntimeError("RESOURCE_EXHAUSTED: alloc"))
     assert hbm.is_oom_error(RuntimeError("Out of memory while trying"))
-    assert not hbm.is_oom_error(RuntimeError("device tunnel dropped"))
+    assert not hbm.is_oom_error(RuntimeError("device link dropped"))
     assert not hbm.is_oom_error(ValueError("RESOURCE_EXHAUSTED"))
     assert not hbm.is_oom_error(chaos.DeviceLost("gone"))
 

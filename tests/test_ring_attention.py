@@ -82,12 +82,6 @@ def test_ring_non_dividing_shapes_fall_back_dense():
                                rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.skipif(
-    jax.__version_info__ < (0, 5),
-    reason="the pre-0.4.35 experimental shard_map diverges numerically "
-           "on the sp-mesh ring train step (loss off by ~4e-2 vs dp-only "
-           "on identical data); the layout-invariance contract can only "
-           "be asserted where the modern implementation exists")
 def test_train_step_sp_mesh_ring_matches_dp_only():
     """An sp>1 mesh trains through ring attention (seq_parallel='auto')
     and must produce the same loss/gradient step as a dp-only mesh on

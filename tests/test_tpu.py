@@ -642,6 +642,28 @@ def test_npz_roundtrip_with_quantized_leaves(tmp_path, tiny_llama):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_random_params_serving_layout_leaf_by_leaf():
+    """TPU_WEIGHTS unset: dense leaves are init's own values, projections
+    are born int8 with fan-in variance, every leaf born in its shards."""
+    from gofr_tpu.parallel import make_mesh
+    from gofr_tpu.tpu import random_params
+
+    ref = llama.init(TINY, jax.random.PRNGKey(0))
+    q = random_params(llama.init, TINY, quant=True,
+                      mesh=make_mesh(tp=2, dp=4))
+    for name in ("embedding", "final_norm"):
+        np.testing.assert_array_equal(np.asarray(q[name]),
+                                      np.asarray(ref[name]))
+    wg = q["layers"]["w_gate"]
+    assert wg.w.dtype == jnp.int8
+    assert wg.w.shape == ref["layers"]["w_gate"].shape
+    assert wg.scale.shape == (TINY.n_layers, TINY.ffn_dim)
+    assert wg.w.sharding.spec[-1] == wg.scale.sharding.spec[-1] == "tp"
+    std = float((np.asarray(wg.w, np.float32)
+                 * np.asarray(wg.scale)[:, None, :]).std())
+    assert abs(std - TINY.dim ** -0.5) < 0.05 * TINY.dim ** -0.5
+
+
 def test_quantized_generation_close_to_fp(tiny_llama):
     """int8 weights change numerics but not the serving contract."""
     eng = GenerationEngine(TINY, maybe_quantize(tiny_llama, True), slots=2,

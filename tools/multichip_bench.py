@@ -66,14 +66,7 @@ def _init_devices() -> int:
 
     if not os.environ.get("GOFR_BENCH_TPU"):
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", 8)
-        except AttributeError:
-            flags = os.environ.get("XLA_FLAGS", "")
-            if "--xla_force_host_platform_device_count" not in flags:
-                os.environ["XLA_FLAGS"] = (
-                    flags + " --xla_force_host_platform_device_count=8"
-                ).strip()
+        jax.config.update("jax_num_cpu_devices", 8)
         jax.config.update("jax_default_matmul_precision", "float32")
     return jax.device_count()
 
