@@ -290,43 +290,13 @@ def grpc_generate(port: int, tokens: list[int], max_new: int) -> list[int]:
 
 
 def scrape(port: int) -> dict[str, float]:
-    """Prometheus text -> {metric name: sum over its label sets}."""
+    """GET /metrics -> {metric name: sum over its label sets}."""
+    from gofr_tpu.metrics import parse_prometheus
+
     status, body = http_get(port, "/metrics")
     if status != 200:
         raise RuntimeError(f"GET /metrics -> {status}")
-    out: dict[str, float] = {}
-    for line in body.decode().splitlines():
-        if line and not line.startswith("#"):
-            series, _, value = line.rpartition(" ")
-            name = series.split("{", 1)[0]
-            out[name] = out.get(name, 0.0) + float(value)
-    return out
-
-
-class CompileClock:
-    """Seconds JAX spent in backend compiles (persistent-cache loads
-    included) and the cache's hit/miss counts, from jax.monitoring."""
-
-    def __init__(self):
-        import jax.monitoring
-
-        self.seconds = 0.0
-        self.programs = 0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, seconds: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += seconds
-            self.programs += 1
-
-    def _event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
+    return parse_prometheus(body.decode())
 
 
 def count_kernel_traces() -> dict[str, int]:
@@ -375,17 +345,24 @@ def phase_server(summary: dict, rec: dict) -> None:
     for key in ("HTTP_PORT", "GRPC_PORT", "METRICS_PORT"):
         os.environ.setdefault(key, "0")
 
-    clock = CompileClock()
+    from gofr_tpu import compile_cache
+
+    # the process's one compile listener; the kernel phase compiled
+    # before this one, so the app's share is the difference
+    clock = compile_cache.clock()
+    before = clock.snapshot()
     rec["kernels_traced"] = count_kernel_traces()
     t0 = time.monotonic()
     app = load_example_app()  # App(): weights from the seed + warm-up
     rec["startup_s"] = round(time.monotonic() - t0, 1)
-    summary["compile_s"] = round(clock.seconds, 1)
-    summary["compiled_programs"] = clock.programs
-    summary["cache_hits"], summary["cache_misses"] = clock.hits, clock.misses
-    log(f"  app built in {rec['startup_s']}s: {clock.programs} programs, "
+    built = {k: v - before[k] for k, v in clock.snapshot().items()}
+    summary["compile_s"] = round(built["seconds"], 1)
+    summary["compiled_programs"] = built["programs"]
+    summary["cache_hits"] = built["hits"]
+    summary["cache_misses"] = built["misses"]
+    log(f"  app built in {rec['startup_s']}s: {built['programs']} programs, "
         f"{summary['compile_s']}s compiling, cache hits/misses "
-        f"{clock.hits}/{clock.misses}")
+        f"{built['hits']}/{built['misses']}")
     app.run(block=False)
     try:
         _drive(app, summary, rec, random.Random(0))
@@ -403,7 +380,7 @@ def phase_server(summary: dict, rec: dict) -> None:
             "framework threads outlived app.stop(): "
             f"{sorted(t.name for t in framework_threads())}")
     rec["programs_compiled_while_serving"] = \
-        clock.programs - summary["compiled_programs"]
+        clock.programs - before["programs"] - built["programs"]
 
 
 def _drive(app, summary: dict, rec: dict, rng) -> None:

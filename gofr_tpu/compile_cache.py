@@ -10,13 +10,22 @@ directory is part of the cache key, so it must never move:
     server, ``bench.py``, ``chip_smoke.py`` and the tests.
 
 Call before the first compile.
+
+The same module owns the one compile listener: ``clock()`` counts the
+seconds JAX spends in backend compiles (persistent-cache loads
+included), the programs, and the cache's hits and misses, and marks
+each compile on the serving timeline so "which step recompiled" is a
+mark on the host loop's track.
 """
 
 from __future__ import annotations
 
 import os
+import threading
+import time
 
 import jax
+import jax.monitoring
 
 _CHECKOUT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
@@ -35,3 +44,53 @@ def configure() -> str:
         return env_dir
     jax.config.update("jax_compilation_cache_dir", _CHECKOUT_DIR)
     return _CHECKOUT_DIR
+
+
+class CompileClock:
+    """Totals since the process's first ``clock()`` call. ``log`` keeps
+    (monotonic time, seconds) of every compile; ``timeline`` is the
+    serving timeline the marks go to (the engine attaches its own)."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.programs = 0
+        self.hits = 0
+        self.misses = 0
+        self.log: list[tuple[float, float]] = []
+        self.timeline = None
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, seconds: float, **_) -> None:
+        if event != "/jax/core/compile/backend_compile_duration":
+            return
+        self.seconds += seconds
+        self.programs += 1
+        self.log.append((time.monotonic(), seconds))
+        tl = self.timeline
+        if tl is not None:
+            tl.compile(seconds)
+
+    def _event(self, event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def snapshot(self) -> dict:
+        return {"seconds": self.seconds, "programs": self.programs,
+                "hits": self.hits, "misses": self.misses}
+
+
+_clock: CompileClock | None = None
+_clock_lock = threading.Lock()
+
+
+def clock() -> CompileClock:
+    """The process's compile listener (JAX has no way to unregister
+    one, so there is exactly one, made on first use)."""
+    global _clock
+    with _clock_lock:
+        if _clock is None:
+            _clock = CompileClock()
+        return _clock

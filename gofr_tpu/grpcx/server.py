@@ -46,7 +46,7 @@ def parse_grpc_timeout(val: str | None) -> float | None:
 class _Stream:
     __slots__ = ("id", "headers", "recv_q", "buffer", "send_window",
                  "cancelled", "end_received", "headers_sent", "worker",
-                 "recv_debt")
+                 "recv_debt", "t_open", "trace_id")
 
     def __init__(self, sid: int, headers: dict[str, str], initial_window: int):
         self.id = sid
@@ -59,6 +59,10 @@ class _Stream:
         self.headers_sent = False
         self.worker: threading.Thread | None = None
         self.recv_debt = 0  # bytes received since the last WINDOW_UPDATE
+        # for the timeline's ``first`` event: when the RPC's handling
+        # began (request HEADERS received) and the RPC span's trace id
+        self.t_open: float | None = None
+        self.trace_id = ""
 
 
 class _Connection:
@@ -511,6 +515,8 @@ class GRPCServer:
         self.container = container
         self.logger = container.logger if container is not None else None
         self.tracer = getattr(container, "tracer", None)
+        tl = getattr(getattr(container, "observe", None), "timeline", None)
+        self._tl = tl if tl is not None and tl.enabled else None
         self.options = options or h2.TransportOptions()
         # the static response header block, pre-encoded ONCE per server:
         # stateless (see hpack.encode_stateless), so it is valid on
@@ -615,7 +621,7 @@ class GRPCServer:
     # -- RPC dispatch --------------------------------------------------------
     def _handle_stream(self, conn: _Connection, st: _Stream) -> None:
         path = st.headers.get(":path", "")
-        start = time.monotonic()
+        start = st.t_open = time.monotonic()
         status, message = svc.OK, ""
         retry_after: float | None = None
         span = None
@@ -623,6 +629,7 @@ class GRPCServer:
             span = self.tracer.start_span(
                 f"grpc{path}", traceparent=st.headers.get("traceparent"),
                 attributes={"rpc.system": "grpc", "rpc.method": path})
+            st.trace_id = span.trace_id
         try:
             chaos.fire(chaos.GRPC_STREAM)
             status, message = self._invoke(conn, st, path)
@@ -843,22 +850,33 @@ class GRPCServer:
 
     def _first_send_spans(self, st: _Stream, source, got: float,
                           stages: dict) -> None:
-        """TTFT decomposition spans for the FIRST streamed message:
-        grpc.handoff (producer _deliver -> transport), grpc.hpack
-        (header block encode) and grpc.frame-write (the coalesced
-        HEADERS+DATA write). Exported once per stream; bench.py's TTFT
-        section and tools/transport_bench.py aggregate them."""
+        """The first streamed message reached the socket: its path's
+        stamps, taken once, go to the timeline as one ``first`` event
+        (request HEADERS received, engine submit and first_put,
+        transport got it, header encode, the coalesced HEADERS+DATA
+        write) and, only when a tracer exports, to the TTFT
+        decomposition spans grpc.handoff (producer _deliver ->
+        transport), grpc.hpack and grpc.frame-write. Once per stream;
+        bench.py's TTFT section and tools/transport_bench.py aggregate
+        the spans."""
+        trace = getattr(source, "trace", None)
+        if not isinstance(trace, dict):
+            trace = {}
+        first_put = trace.get("first_put")
+        if self._tl is not None and "write1" in stages:
+            self._tl.first(
+                trace.get("request_id"), st.trace_id,
+                (st.t_open, trace.get("submit"), first_put, got),
+                (stages.get("enc0"), stages.get("enc1"), stages["write0"],
+                 stages["write1"]))
         tracer = self.tracer
-        if tracer is None:
+        if tracer is None or tracer.exporter is None:
             return
         tp = st.headers.get("traceparent")
-        trace = getattr(source, "trace", None)
-        if isinstance(trace, dict):
-            first_put = trace.get("first_put")
-            if first_put is not None and first_put <= got:
-                tracer.record_span("grpc.handoff", first_put, got,
-                                   traceparent=tp,
-                                   attributes={"stream": st.id})
+        if first_put is not None and first_put <= got:
+            tracer.record_span("grpc.handoff", first_put, got,
+                               traceparent=tp,
+                               attributes={"stream": st.id})
         if "enc0" in stages:
             tracer.record_span("grpc.hpack", stages["enc0"], stages["enc1"],
                                traceparent=tp,

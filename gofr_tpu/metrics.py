@@ -280,6 +280,22 @@ class Manager:
         return "\n".join(lines) + "\n"
 
 
+def parse_prometheus(text: str) -> dict[str, float]:
+    """The inverse of ``render_prometheus`` as far as a totals check
+    needs it: {metric name: sum over its label sets} (a histogram's
+    ``_sum``, ``_count`` and ``_bucket`` lines under those names)."""
+    out: dict[str, float] = {}
+    for line in text.splitlines():
+        if line and not line.startswith("#"):
+            series, _, value = line.rpartition(" ")
+            name = series.split("{", 1)[0]
+            try:
+                out[name] = out.get(name, 0.0) + float(value)
+            except ValueError:
+                pass
+    return out
+
+
 def _hist_snapshot(val) -> tuple[list, float, int]:
     """One histogram series -> (cumulative bucket counts, sum, count),
     shared by both exposition renderers so they can never disagree."""

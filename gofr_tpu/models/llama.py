@@ -189,6 +189,7 @@ def _expert_mm(h, w, pattern: str, scale_expand=(None, None)):
     return jnp.einsum(pattern, h, w)
 
 
+@jax.named_scope("moe_route")
 def _route(hf, router, k: int):
     """The ONE routing definition both dispatch layouts share: f32
     softmax over expert logits, top-k selection, renormalized weights.
@@ -203,6 +204,7 @@ def _route(hf, router, k: int):
     return probs, topv, topi
 
 
+@jax.named_scope("moe_experts_grouped")
 def _moe_ffn_grouped(h, layer_w, cfg: ModelConfig, valid=None):
     """Capacity-based grouped MoE dispatch — the at-scale sibling of the
     dense-dispatch path: tokens scatter into per-expert buffers
@@ -285,16 +287,18 @@ def _moe_ffn(h, layer_w, cfg: ModelConfig, valid=None):
     probs = probs.reshape(B, S, -1)
     topv = topv.reshape(B, S, -1)
     topi = topi.reshape(B, S, -1)
-    # combine weights: zero everywhere except the chosen experts
-    combine = jnp.sum(
-        jax.nn.one_hot(topi, cfg.n_experts, dtype=topv.dtype)
-        * topv[..., None], axis=2)                             # [B,S,E]
+    with jax.named_scope("moe_experts"):
+        # combine weights: zero everywhere except the chosen experts
+        combine = jnp.sum(
+            jax.nn.one_hot(topi, cfg.n_experts, dtype=topv.dtype)
+            * topv[..., None], axis=2)                         # [B,S,E]
 
-    gated = jax.nn.silu(_expert_mm(h, layer_w["w_gate"], "bsd,edf->bsef")) \
-        * _expert_mm(h, layer_w["w_up"], "bsd,edf->bsef")
-    out = _expert_mm(gated, layer_w["w_down"], "bsef,efd->bsed")
-    return (jnp.einsum("bsed,bse->bsd", out,
-                       combine.astype(out.dtype)), probs)
+        gated = jax.nn.silu(
+            _expert_mm(h, layer_w["w_gate"], "bsd,edf->bsef")) \
+            * _expert_mm(h, layer_w["w_up"], "bsd,edf->bsef")
+        out = _expert_mm(gated, layer_w["w_down"], "bsef,efd->bsed")
+        return (jnp.einsum("bsed,bse->bsd", out,
+                           combine.astype(out.dtype)), probs)
 
 
 def _lora(h, layer_w, name: str, adapter):
@@ -321,32 +325,43 @@ def _layer(x, layer_w, cfg: ModelConfig, cos, sin, positions,
     B, S = x.shape[0], x.shape[1]
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
-    h = rms_norm(x, layer_w["attn_norm"], cfg.norm_eps)
-    q = (qmatmul(h, layer_w["wq"])
-         + _lora(h, layer_w, "wq", adapter)).reshape(B, S, H, hd)
-    k = (qmatmul(h, layer_w["wk"])
-         + _lora(h, layer_w, "wk", adapter)).reshape(B, S, KV, hd)
-    v = (qmatmul(h, layer_w["wv"])
-         + _lora(h, layer_w, "wv", adapter)).reshape(B, S, KV, hd)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
+    # named scopes are metadata: they put the block's name into every
+    # operation's op_name, which is what a device trace shows
+    with jax.named_scope("attn_qkv"):
+        h = rms_norm(x, layer_w["attn_norm"], cfg.norm_eps)
+        q = (qmatmul(h, layer_w["wq"])
+             + _lora(h, layer_w, "wq", adapter)).reshape(B, S, H, hd)
+        k = (qmatmul(h, layer_w["wk"])
+             + _lora(h, layer_w, "wk", adapter)).reshape(B, S, KV, hd)
+        v = (qmatmul(h, layer_w["wv"])
+             + _lora(h, layer_w, "wv", adapter)).reshape(B, S, KV, hd)
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
 
-    k_all, v_all = kv_write(k, v)
-    attn = attend(q, k_all, v_all).reshape(B, S, H * hd)
-    x = x + qmatmul(attn, layer_w["wo"]) + _lora(attn, layer_w, "wo",
-                                                 adapter)
+    with jax.named_scope("kv_write"):
+        k_all, v_all = kv_write(k, v)
+    with jax.named_scope("attn"):
+        attn = attend(q, k_all, v_all).reshape(B, S, H * hd)
+    with jax.named_scope("attn_out"):
+        x = x + qmatmul(attn, layer_w["wo"]) + _lora(attn, layer_w, "wo",
+                                                     adapter)
 
-    h = rms_norm(x, layer_w["ffn_norm"], cfg.norm_eps)
     router_probs = None
     if cfg.n_experts > 0:
-        ffn, router_probs = _moe_ffn(h, layer_w, cfg, valid)
-        x = x + ffn
+        with jax.named_scope("moe"):
+            h = rms_norm(x, layer_w["ffn_norm"], cfg.norm_eps)
+            ffn, router_probs = _moe_ffn(h, layer_w, cfg, valid)
+            x = x + ffn
     else:
-        gated = jax.nn.silu(qmatmul(h, layer_w["w_gate"])) * qmatmul(h, layer_w["w_up"])
-        x = x + qmatmul(gated, layer_w["w_down"])
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, layer_w["ffn_norm"], cfg.norm_eps)
+            gated = jax.nn.silu(qmatmul(h, layer_w["w_gate"])) \
+                * qmatmul(h, layer_w["w_up"])
+            x = x + qmatmul(gated, layer_w["w_down"])
     return x, (k_all, v_all), router_probs
 
 
+@jax.named_scope("lm_head")
 def _logits(params, cfg: ModelConfig, x):
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if cfg.tie_embeddings:
@@ -406,7 +421,8 @@ def _causal_scan(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
         def attend(q, k, v):
             return causal_attention(q, k, v, mask=valid)
 
-    x = constrain(params["embedding"][tokens].astype(cfg.jdtype))
+    with jax.named_scope("embed"):
+        x = constrain(params["embedding"][tokens].astype(cfg.jdtype))
 
     def body(x, layer_w):
         x, kv, probs = _layer(x, layer_w, cfg, cos_g, sin_g, None,
@@ -475,6 +491,7 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     return _logits(params, cfg, x), cache
 
 
+@jax.named_scope("kv_write")
 def write_kv(cache: KVCache, k_stack, v_stack, index5, lengths) -> KVCache:
     """Write bf16 KV stacks [L, B', S', KV, hd] into the cache at ``index5``
     (a 5-tuple of start indices), quantizing on write for int8 caches.
@@ -554,7 +571,9 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     positions = start + jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32),
                                          (B, C))
 
-    x = params["embedding"][tokens].astype(cfg.jdtype)
+    with jax.named_scope("embed"):
+
+        x = params["embedding"][tokens].astype(cfg.jdtype)
 
     def body(x, xs):
         layer_w, k_layer, v_layer, ks_layer, vs_layer = xs
@@ -612,7 +631,9 @@ def verify_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     positions = cache.lengths[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
     lengths = cache.lengths
 
-    x = params["embedding"][tokens].astype(cfg.jdtype)  # [B, W, D]
+    with jax.named_scope("embed"):
+
+        x = params["embedding"][tokens].astype(cfg.jdtype)  # [B, W, D]
 
     def body(x, xs):
         layer_w, k_layer, v_layer, ks_layer, vs_layer = xs
@@ -632,23 +653,24 @@ def verify_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                   cache.k_scale, cache.v_scale))
     # one scatter for all layers and window rows: [L, B, W, KV, hd] ->
     # cache[:, b, lengths[b] + j] (adjacent advanced indices broadcast)
-    b_idx = jnp.arange(B)[:, None]                       # [B, 1]
-    if cache.quantized:
-        qk, sk = quantize_kv(k_w)
-        qv, sv = quantize_kv(v_w)
-        new = KVCache(
-            k=cache.k.at[:, b_idx, positions].set(qk, mode="drop"),
-            v=cache.v.at[:, b_idx, positions].set(qv, mode="drop"),
-            lengths=lengths,
-            k_scale=cache.k_scale.at[:, b_idx, positions].set(sk, mode="drop"),
-            v_scale=cache.v_scale.at[:, b_idx, positions].set(sv, mode="drop"))
-    else:
-        new = KVCache(
-            k=cache.k.at[:, b_idx, positions].set(
-                k_w.astype(cache.k.dtype), mode="drop"),
-            v=cache.v.at[:, b_idx, positions].set(
-                v_w.astype(cache.v.dtype), mode="drop"),
-            lengths=lengths)
+    with jax.named_scope("kv_write"):
+        b_idx = jnp.arange(B)[:, None]                       # [B, 1]
+        if cache.quantized:
+            qk, sk = quantize_kv(k_w)
+            qv, sv = quantize_kv(v_w)
+            new = KVCache(
+                k=cache.k.at[:, b_idx, positions].set(qk, mode="drop"),
+                v=cache.v.at[:, b_idx, positions].set(qv, mode="drop"),
+                lengths=lengths,
+                k_scale=cache.k_scale.at[:, b_idx, positions].set(sk, mode="drop"),
+                v_scale=cache.v_scale.at[:, b_idx, positions].set(sv, mode="drop"))
+        else:
+            new = KVCache(
+                k=cache.k.at[:, b_idx, positions].set(
+                    k_w.astype(cache.k.dtype), mode="drop"),
+                v=cache.v.at[:, b_idx, positions].set(
+                    v_w.astype(cache.v.dtype), mode="drop"),
+                lengths=lengths)
     return _logits(params, cfg, x), new
 
 
@@ -733,7 +755,8 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     positions = cache.lengths[:, None]  # [B,1] — this token's position
     lengths = cache.lengths
 
-    x = params["embedding"][tokens[:, None]].astype(cfg.jdtype)  # [B,1,D]
+    with jax.named_scope("embed"):
+        x = params["embedding"][tokens[:, None]].astype(cfg.jdtype)  # [B,1,D]
 
     if flash:
         import functools
@@ -758,23 +781,28 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     x, (k_toks, v_toks) = jax.lax.scan(
         body, x, (params["layers"], cache.k, cache.v,
                   cache.k_scale, cache.v_scale))
-    # one scatter for all layers: [L, B, 1, KV, hd] -> cache[:, b, lengths[b]]
-    slots = jnp.arange(B)
+    with jax.named_scope("kv_write"):
+        new = _scatter_step_kv(cache, k_toks, v_toks, lengths)
+    return _logits(params, cfg, x[:, 0]), new
+
+
+def _scatter_step_kv(cache: KVCache, k_toks, v_toks, lengths) -> KVCache:
+    """One scatter for all layers: the step's [L, B, 1, KV, hd] k/v go
+    to cache[:, b, lengths[b]]."""
+    slots = jnp.arange(k_toks.shape[1])
     k_tok, v_tok = k_toks[:, :, 0], v_toks[:, :, 0]  # [L, B, KV, hd]
     if cache.quantized:
         qk, sk = quantize_kv(k_tok)
         qv, sv = quantize_kv(v_tok)
-        new = KVCache(
+        return KVCache(
             k=cache.k.at[:, slots, lengths].set(qk, mode="drop"),
             v=cache.v.at[:, slots, lengths].set(qv, mode="drop"),
             lengths=lengths + 1,
             k_scale=cache.k_scale.at[:, slots, lengths].set(sk, mode="drop"),
             v_scale=cache.v_scale.at[:, slots, lengths].set(sv, mode="drop"))
-    else:
-        new = KVCache(
-            k=cache.k.at[:, slots, lengths].set(
-                k_tok.astype(cache.k.dtype), mode="drop"),
-            v=cache.v.at[:, slots, lengths].set(
-                v_tok.astype(cache.v.dtype), mode="drop"),
-            lengths=lengths + 1)
-    return _logits(params, cfg, x[:, 0]), new
+    return KVCache(
+        k=cache.k.at[:, slots, lengths].set(
+            k_tok.astype(cache.k.dtype), mode="drop"),
+        v=cache.v.at[:, slots, lengths].set(
+            v_tok.astype(cache.v.dtype), mode="drop"),
+        lengths=lengths + 1)

@@ -22,12 +22,18 @@ vLLM/SGLang-class schedulers became debuggable with:
     chunk-lattice slices (chunk index + length), predict batch
     dispatches, admission / shed / expiry decisions, kvcache tier
     hits, and ``app_tpu_device_bytes`` counter samples fanned out by
-    ``tpu/hbm.py``.
+    ``tpu/hbm.py``. The generation thread also accounts for its own
+    time: back-to-back ``loop`` phases (admit / dispatch / wait / fetch
+    / deliver / park / other), ``gap`` intervals in which it knew the
+    device dry, one ``store`` per prefix-cache store, one ``first`` per
+    stream when its first message reaches the socket, and a ``compile``
+    mark per backend compile (``compile_cache.py``).
   - ``chrome_trace()`` renders the ring as Chrome-trace JSON ("JSON
     Array Format" with ``traceEvents``) that Perfetto / chrome://tracing
     load directly: one track per decode slot, a scheduler track for
-    instant decisions, a predict track per program, and one counter
-    track per HBM subsystem. ``/debug/timeline?last_ms=N`` serves it
+    instant decisions, a device-stream track of dry intervals, a host
+    loop track of the generation thread's phases, a predict track per
+    program, and one counter track per HBM subsystem. ``/debug/timeline?last_ms=N`` serves it
     from the metrics port; ``tools/timeline_dump.py`` fetches or
     self-hosts it.
 
@@ -52,6 +58,8 @@ __all__ = ["Timeline", "timeline_from_config"]
 # track ids inside the single "serving" process of the exported trace
 _TID_SCHED = 1          # admission / shed / expiry decisions
 _TID_DEVICE = 2         # device-stream dispatch gaps (idle windows)
+_TID_LOOP = 3           # the generation thread's phases (+ compile marks)
+_TID_TRANSPORT = 4      # first message of each stream reaching the socket
 _TID_SLOT0 = 10         # decode slot i -> tid 10 + i
 _TID_PREDICT0 = 1000    # predict program tracks, assigned in export order
 
@@ -109,10 +117,13 @@ class Timeline:
         self._buf[i & self._mask] = (i, ts, dur, kind, a, b, c, d)
 
     # -- typed emitters (one writer for the payload conventions) -------------
-    def decode_block(self, t0: float, t1: float, slots, steps: int) -> None:
+    def decode_block(self, t0: float, t1: float, slots, steps: int,
+                     live: int | None = None) -> None:
         """One fused decode dispatch->reap: ``slots`` is the tuple of
-        active slot indices as dispatched, ``steps`` the block size."""
-        self.append("decode", t0, t1 - t0, slots, steps)
+        active slot indices as dispatched, ``steps`` the block size,
+        ``live`` the KV positions those slots held at dispatch (what
+        the step's attention has to read of the reserved pool)."""
+        self.append("decode", t0, t1 - t0, slots, steps, live)
 
     def verify_block(self, t0: float, t1: float, slots, window: int) -> None:
         self.append("verify", t0, t1 - t0, slots, window)
@@ -131,14 +142,44 @@ class Timeline:
     def predict(self, t0: float, t1: float, program: str, size: int) -> None:
         self.append("predict", t0, t1 - t0, program, size)
 
-    def dispatch_gap(self, t0: float, t1: float) -> None:
-        """One inter-block host-dispatch gap: the device stream ran dry
-        at ``t0`` (a fused block's outputs came ready with no successor
-        queued) and the next dispatch landed at ``t1``. The pipelined
-        loop's whole job is keeping this track EMPTY during steady
-        decode — a Perfetto window makes the overlap (or its absence)
-        visible at a glance."""
-        self.append("gap", t0, t1 - t0)
+    def dispatch_gap(self, t0: float, t1: float, slack: float = 0.0) -> None:
+        """One device-dry interval: at ``t0`` the generation thread saw
+        the last program it had queued finished with nothing behind it,
+        and at ``t1`` it dispatched the next program of any kind. The
+        stream may have run dry up to ``slack`` seconds before ``t0``
+        (the thread last saw it busy then and did not look between).
+        The pipelined loop's whole job is keeping this track EMPTY
+        during steady decode — a Perfetto window makes the overlap (or
+        its absence) visible at a glance."""
+        self.append("gap", t0, t1 - t0, slack)
+
+    def loop(self, t0: float, t1: float, phase: str, n: int = 0) -> None:
+        """One phase of the generation thread (admit / dispatch / wait /
+        fetch / deliver / park / other). The thread writes them back to
+        back, so they never nest and cover its whole time; ``n`` is the
+        phase's count (requests an ``admit`` pass started)."""
+        self.append("loop", t0, t1 - t0, phase, n)
+
+    def store(self, t0: float, t1: float, slot: int, tokens: int,
+              tier: str) -> None:
+        """One prefix-cache store after an admission (inside the loop's
+        ``admit`` phase): ``tier`` says how far it went — ``t0`` a pool
+        row copy, ``t0+host`` with a victim spilled to the host tier
+        first, ``+shared`` with the write-through to the shared tier."""
+        self.append("store", t0, t1 - t0, slot, tokens, tier)
+
+    def first(self, request_id, trace_id: str, path: tuple,
+              send: tuple) -> None:
+        """A stream's first message reached the socket. ``path`` is
+        (request HEADERS received, engine submit, engine first_put,
+        transport got it), ``send`` (enc0, enc1, write0, write1); a
+        stamp the stream does not have is None. The event's time is
+        ``write1``."""
+        self.append("first", send[3], None, request_id, trace_id, path, send)
+
+    def compile(self, seconds: float) -> None:
+        """One backend compile (or persistent-cache load) just ended."""
+        self.append("compile", time.monotonic(), None, round(seconds, 6))
 
     def pipeline_depth(self, depth: int) -> None:
         """Counter sample: fused decode blocks in flight after a
@@ -224,6 +265,14 @@ class Timeline:
              "args": {"name": "device stream"}},
             {"ph": "M", "pid": 1, "tid": _TID_DEVICE,
              "name": "thread_sort_index", "args": {"sort_index": 1}},
+            {"ph": "M", "pid": 1, "tid": _TID_LOOP, "name": "thread_name",
+             "args": {"name": "host loop"}},
+            {"ph": "M", "pid": 1, "tid": _TID_LOOP,
+             "name": "thread_sort_index", "args": {"sort_index": 2}},
+            {"ph": "M", "pid": 1, "tid": _TID_TRANSPORT,
+             "name": "thread_name", "args": {"name": "transport"}},
+            {"ph": "M", "pid": 1, "tid": _TID_TRANSPORT,
+             "name": "thread_sort_index", "args": {"sort_index": 3}},
         ]
         named_slots: set[int] = set()
         predict_tids: dict[str, int] = {}
@@ -263,7 +312,8 @@ class Timeline:
                                  "name": label, "cat": kind, "ts": us,
                                  "dur": max(dur, 0.0) * 1e6,
                                  "args": {"slots": len(a or ()),
-                                          "steps": b, "seq": seq}})
+                                          "steps": b, "live_tokens": c,
+                                          "seq": seq}})
             elif kind == "prefill":
                 body.append({"ph": "X", "pid": 1, "tid": slot_tid(a),
                              "name": f"prefill L={b}", "cat": "prefill",
@@ -310,7 +360,31 @@ class Timeline:
                 body.append({"ph": "X", "pid": 1, "tid": _TID_DEVICE,
                              "name": "dispatch gap", "cat": "gap",
                              "ts": us, "dur": max(dur, 0.0) * 1e6,
-                             "args": {"seq": seq}})
+                             "args": {"slack_s": a, "seq": seq}})
+            elif kind == "loop":
+                body.append({"ph": "X", "pid": 1, "tid": _TID_LOOP,
+                             "name": f"loop:{a}", "cat": "loop", "ts": us,
+                             "dur": max(dur, 0.0) * 1e6,
+                             "args": {"n": b, "seq": seq}})
+            elif kind == "store":
+                body.append({"ph": "X", "pid": 1, "tid": slot_tid(a),
+                             "name": f"store {c} ({b} tok)", "cat": "store",
+                             "ts": us, "dur": max(dur, 0.0) * 1e6,
+                             "args": {"tokens": b, "tier": c, "seq": seq}})
+            elif kind == "first":
+                stamps = dict(zip(("headers", "submit", "first_put", "got"),
+                                  c))
+                stamps.update(zip(("enc0", "enc1", "write0", "write1"), d))
+                body.append({"ph": "i", "s": "t", "pid": 1,
+                             "tid": _TID_TRANSPORT, "name": "first write",
+                             "cat": "transport", "ts": us,
+                             "args": {"request_id": a, "trace_id": b,
+                                      **stamps, "seq": seq}})
+            elif kind == "compile":
+                body.append({"ph": "i", "s": "t", "pid": 1,
+                             "tid": _TID_LOOP, "name": f"compile {a:.3f}s",
+                             "cat": "compile", "ts": us,
+                             "args": {"seconds": a, "seq": seq}})
             elif kind == "depth":
                 body.append({"ph": "C", "pid": 1, "name": "pipeline_depth",
                              "ts": us, "args": {"depth": a}})

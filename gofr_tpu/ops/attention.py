@@ -26,6 +26,7 @@ def _repeat_kv_shape(q: jnp.ndarray, n_kv: int) -> jnp.ndarray:
     return q.reshape(b, s, n_kv, h // n_kv, d)
 
 
+@jax.named_scope("causal_attention")
 def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      mask: jnp.ndarray | None = None) -> jnp.ndarray:
     """Causal self-attention for prefill.
@@ -51,6 +52,7 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.reshape(b, s, h, d)
 
 
+@jax.named_scope("decode_attention")
 def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                      lengths: jnp.ndarray) -> jnp.ndarray:
     """Single-token decode attention against a preallocated KV cache.
@@ -75,6 +77,7 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     return out.reshape(b, 1, h, d)
 
 
+@jax.named_scope("decode_attention_appended")
 def decode_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
                               v_cache: jnp.ndarray, k_new: jnp.ndarray,
                               v_new: jnp.ndarray, lengths: jnp.ndarray,
@@ -130,6 +133,7 @@ def decode_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
     return out.reshape(b, 1, h, d)
 
 
+@jax.named_scope("window_attention_appended")
 def window_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
                               v_cache: jnp.ndarray, k_new: jnp.ndarray,
                               v_new: jnp.ndarray, lengths: jnp.ndarray,
@@ -178,6 +182,7 @@ def window_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
     return out.reshape(b, w, h, d)
 
 
+@jax.named_scope("chunk_attention")
 def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                     k_new: jnp.ndarray, v_new: jnp.ndarray,
                     start: jnp.ndarray,
@@ -225,6 +230,7 @@ def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     return out.reshape(b, c, h, d)
 
 
+@jax.named_scope("full_attention")
 def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    mask: jnp.ndarray | None = None) -> jnp.ndarray:
     """Bidirectional attention (BERT/ViT encoders). Shapes as causal_attention."""

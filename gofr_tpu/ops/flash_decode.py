@@ -208,6 +208,7 @@ def _flash_decode_cache(q, k_cache, v_cache, lengths, k_scale, v_scale,
     return acc, m, l
 
 
+@jax.named_scope("flash_decode_appended")
 def flash_decode_appended(q, k_cache, v_cache, k_new, v_new, lengths,
                           k_scale=None, v_scale=None, *,
                           block_s: int = 128,

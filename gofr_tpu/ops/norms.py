@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("rms_norm")
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     """RMSNorm (Llama-style): x * w / rms(x)."""
     dtype = x.dtype
@@ -20,6 +21,7 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-5) -> jnp.ndar
     return (normed * weight.astype(jnp.float32)).astype(dtype)
 
 
+@jax.named_scope("layer_norm")
 def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray,
                eps: float = 1e-12) -> jnp.ndarray:
     """LayerNorm (BERT/ViT-style)."""

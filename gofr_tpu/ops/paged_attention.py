@@ -136,6 +136,7 @@ def _paged_decode_cache(q, k_pool, v_pool, table, lengths, k_scale, v_scale,
     return acc, m, l
 
 
+@jax.named_scope("paged_decode_attention")
 def paged_decode_attention(q, k_pool, v_pool, k_new, v_new, table, lengths,
                            k_scale=None, v_scale=None, *,
                            interpret: bool = False) -> jnp.ndarray:
@@ -249,6 +250,7 @@ def paged_attention_reference(q, k_pool, v_pool, k_new, v_new, table,
                                      lengths, ks, vs)
 
 
+@jax.named_scope("paged_window_attention")
 def paged_window_attention(q, k_pool, v_pool, k_new, v_new, table,
                            lengths, k_scale=None, v_scale=None, *,
                            interpret: bool = False) -> jnp.ndarray:

@@ -31,6 +31,7 @@ def quantize_int8(w: jnp.ndarray, axis: int = 0) -> QuantizedLinear:
     return QuantizedLinear(w=q, scale=scale.squeeze(axis).astype(jnp.float32))
 
 
+@jax.named_scope("qmatmul")
 def qmatmul(x: jnp.ndarray, qw: "QuantizedLinear | jnp.ndarray") -> jnp.ndarray:
     """x @ w for quantized or plain weights.
 
@@ -52,6 +53,7 @@ def dequantize(qw: QuantizedLinear, dtype=jnp.bfloat16) -> jnp.ndarray:
     return (qw.w.astype(jnp.float32) * qw.scale).astype(dtype)
 
 
+@jax.named_scope("quantize_kv")
 def quantize_kv(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Per-vector symmetric int8 over the LAST axis (the head_dim of a
     K/V tensor): x [..., hd] -> (int8 [..., hd], f32 scale [...]).
@@ -71,6 +73,7 @@ def quantize_kv(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     return q, scale.astype(jnp.float32)
 
 
+@jax.named_scope("dequantize_kv")
 def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray,
                   dtype=jnp.bfloat16) -> jnp.ndarray:
     """Inverse of quantize_kv (test oracle / slow path)."""

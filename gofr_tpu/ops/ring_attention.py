@@ -36,6 +36,7 @@ from jax.sharding import PartitionSpec as P
 from .attention import NEG_INF, causal_attention
 
 
+@jax.named_scope("ring_causal_attention")
 def ring_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           lengths: jnp.ndarray | None, *,
                           axis_name: str) -> jnp.ndarray:

@@ -7,6 +7,7 @@ into the surrounding attention computation.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -36,6 +37,7 @@ def rope_frequencies(head_dim: int, max_seq: int, theta: float = 500000.0,
     return jnp.cos(freqs), jnp.sin(freqs)
 
 
+@jax.named_scope("apply_rope")
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
                positions: jnp.ndarray | None) -> jnp.ndarray:
     """Rotate ``x`` [..., seq, heads, head_dim] by per-token positions.

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import chaos
+from .. import chaos, compile_cache
 from ..errors import DeadlineExceeded
 from ..models import llama
 from ..models.common import ModelConfig
@@ -369,16 +369,126 @@ class _Request:
 class _Inflight:
     """A dispatched-but-unreaped device tick. ``arrays``: the dispatch's
     output futures (readiness probe); ``reap``: fetch results and
-    deliver tokens — must run under the engine's device lock.
-    ``ready_t``: when the loop observed the outputs ready (None until
-    then) — the instant the device stream ran dry unless another block
-    was already queued behind this one, i.e. the dispatch-gap anchor."""
-    __slots__ = ("arrays", "reap", "ready_t")
+    deliver tokens — must run under the engine's device lock (through
+    ``GenerationEngine._reap``, which accounts the loop's time)."""
+    __slots__ = ("arrays", "reap")
 
     def __init__(self, arrays, reap):
         self.arrays = arrays
         self.reap = reap
-        self.ready_t: float | None = None
+
+
+class _LoopAccount:
+    """The generation thread's account of its own time and of the device
+    stream, written to the serving timeline. Its state belongs to that
+    one thread: no other thread calls in, so nothing here locks.
+
+    Phases (``phase``): the thread is always in exactly one of admit /
+    dispatch / wait / fetch / deliver / park / other. One call ends a
+    phase and begins the next, so phases never nest and leave no time
+    out. The phase that ends is written twice by the one helper: as the
+    timeline's ``loop`` event on the monotonic clock, and as a profiler
+    annotation ``gofr.<phase>``, so that any device profile of the
+    process shows the host phases beside the device's operations on the
+    profiler's own clock.
+
+    Dry intervals (``dispatch`` / ``probe``): ``last_out`` is an output
+    of the last program the thread queued. When a probe finds it ready
+    nothing is queued behind it, so the stream is dry: ``idle_from``
+    opens, and the next dispatch of ANY program closes the interval
+    into the histogram and the timeline's ``gap`` track. ``busy_seen``
+    is when the thread last knew the stream busy (a dispatch, or a
+    probe that found the output not ready): the stream may have run
+    dry that much before ``idle_from``, which the gap event carries as
+    its slack."""
+    __slots__ = ("tl", "metrics", "ph", "ph_t0", "ph_n", "ph_mark",
+                 "idle_from", "idle_slack", "busy_seen", "last_out",
+                 "gap_samples", "dry_total")
+
+    MARK = {p: "gofr." + p for p in (
+        "admit", "dispatch", "wait", "fetch", "deliver", "park", "other")}
+
+    def __init__(self, timeline, metrics):
+        self.tl = timeline  # None with TPU_TIMELINE=0: no event, no mark
+        self.metrics = metrics
+        self.ph = "other"
+        self.ph_t0 = time.monotonic()
+        self.ph_n = 0           # the phase's count (requests admitted)
+        self.ph_mark: Any = None
+        self.idle_from: float | None = None
+        self.idle_slack = 0.0
+        self.busy_seen = 0.0
+        self.last_out: Any = None
+        self.gap_samples: "deque[float]" = deque(maxlen=2048)
+        self.dry_total = 0.0    # seconds in all dry intervals so far
+
+    def phase(self, name: str) -> str:
+        """Move to phase ``name``; returns the phase left, so that a
+        region entered from inside another (admission between polls, a
+        decode block between chunks) can put it back. Every boundary
+        also asks whether the device ran dry."""
+        prev = self.ph
+        if prev == name:
+            return prev
+        now = time.monotonic()
+        if prev == "fetch":
+            # the thread was blocked on the device until now: if the
+            # stream is dry, it has only just become so
+            self.busy_seen = now
+        self.probe(now)
+        if self.tl is not None:
+            self.tl.loop(self.ph_t0, now, prev, self.ph_n)
+            if self.ph_mark is not None:
+                self.ph_mark.__exit__(None, None, None)
+            # a TraceAnnotation starts when it is made
+            self.ph_mark = jax.profiler.TraceAnnotation(self.MARK[name])
+        self.ph, self.ph_t0, self.ph_n = name, now, 0
+        return prev
+
+    def dispatch(self, now: float, out) -> None:
+        """A program was queued (its jitted call returned at ``now``
+        with ``out``): the stream is busy from here, and an open dry
+        interval ends. An interval that the probe just before the call
+        opened was dry for a time the thread did not see: that is a
+        ``gap`` event of next to no length whose slack says how long it
+        may have been."""
+        self.busy_seen = now
+        self.last_out = out
+        if self.idle_from is None:
+            return
+        gap, self.idle_from = max(0.0, now - self.idle_from), None
+        if gap > 0.0:
+            self.gap_samples.append(gap)
+            self.dry_total += gap
+            if self.metrics is not None:
+                self.metrics.record_histogram(
+                    "app_tpu_dispatch_gap_duration", gap, program="generate")
+        if self.tl is not None:
+            self.tl.dispatch_gap(now - gap, now, self.idle_slack)
+
+    def probe(self, now: float) -> None:
+        """Has the stream run dry? It has once the last program queued
+        is done."""
+        out = self.last_out
+        if out is None or self.idle_from is not None:
+            return
+        try:
+            if not isinstance(out, jax.Array):
+                # the outputs of one program become ready together
+                out = self.last_out = jax.tree_util.tree_leaves(out)[0]
+            ready = out.is_ready()
+        except Exception:  # donated elsewhere, or no probe: cannot know
+            self.last_out = None
+            return
+        if ready:
+            self.idle_from, self.last_out = now, None
+            self.idle_slack = now - self.busy_seen
+        else:
+            self.busy_seen = now
+
+    def reset(self) -> None:
+        """After a failed dispatch nothing is known about the stream."""
+        self.idle_from = self.last_out = None
 
 
 class _Slot:
@@ -489,13 +599,8 @@ class GenerationEngine:
         self._pipeline = DecodePipelinePolicy(decode_pipeline)
         self._lattice_deferred = False
         self._depth_now = 0
-        # inter-block host-gap instrumentation: _idle_from marks when
-        # the device stream ran dry (reap with no successor queued);
-        # the next dispatch closes the gap into the histogram/timeline.
-        # Overlapped reaps (a block still queued at reap) record 0.0 —
-        # the pipelined steady state the A/B bench gates on.
-        self._idle_from: float | None = None
-        self._gap_samples: "deque[float]" = deque(maxlen=2048)
+        # overlapped reaps (a block still queued at reap) are counted;
+        # the stream's dry intervals are the loop account's (_LoopAccount)
         self._reaps = 0
         self._overlapped_reaps = 0
         # In-flight admission poll cadence (seconds). While a decode
@@ -603,6 +708,11 @@ class GenerationEngine:
                                                      self._block_t)
                 self._store_min = int(prefix_store_min
                                       or self.prompt_buckets[-1])
+        else:
+            # contiguous engine: the host's view of each slot's KV
+            # positions (prompt at admission, + a block's steps at each
+            # dispatch), for the decode event's live-token count
+            self._cursors = np.zeros((slots,), np.int64)
         self.logger = logger
         self.metrics = metrics
         if metrics is not None:
@@ -626,6 +736,10 @@ class GenerationEngine:
             # device-byte accounting changes land HBM counter samples
             # on the exported Perfetto trace (one track per subsystem)
             hbm.set_timeline(self._tl)
+            # and every backend compile leaves a mark on the loop's track
+            compile_cache.clock().timeline = self._tl
+        # the loop thread's account of its own time and of the stream
+        self._acct = _LoopAccount(self._tl, metrics)
         self.mesh = mesh
         self.rope_tables = llama.get_rope_tables(cfg, self.max_seq)
 
@@ -940,8 +1054,6 @@ class GenerationEngine:
         self._tenant_leased: set[str] = set()   # live tenant:{id} leases
         self._gauge_tenants: set[str] = set()   # tenants ever gauged
 
-        self._chunk_mid = functools.partial(self._chunk_fn, sample=False)
-        self._chunk_final = functools.partial(self._chunk_fn, sample=True)
         if self._paged and (self.max_seq - 1 > self._chunk
                             or self._prefix_idx is not None):
             # Long-prompt admission AND prefix-hit resume both run the
@@ -1255,6 +1367,7 @@ class GenerationEngine:
             lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
         )(seeds, pos)
 
+    @jax.named_scope("sampling")
     def _sample(self, logits, temps, keys, top_ks):
         """Greedy where temp==0; categorical(logits/temp) otherwise,
         truncated to the request's top-k logits when top_k > 0 — all
@@ -1353,6 +1466,14 @@ class GenerationEngine:
                                top_k[None])
         return (tok[0], lp[0], key,
                 llama.KVCache(k_new, v_new, lengths, ks, vs))
+
+    # methods, not functools.partial: jit names a program after its
+    # function, and a partial has no name (jit__unknown in a device trace)
+    def _chunk_mid(self, *args):
+        return self._chunk_fn(*args, sample=False)
+
+    def _chunk_final(self, *args):
+        return self._chunk_fn(*args, sample=True)
 
     def _fused_decode_scan(self, cache, pack, carry, key, step_model):
         """K fused decode steps over all slots (K = decode_block); one
@@ -1744,6 +1865,7 @@ class GenerationEngine:
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         stream = GenStream(next(_REQ_IDS), self, logprobs=logprobs)
         stream.trace["submit"] = time.monotonic()
+        stream.trace["request_id"] = stream.request_id  # for the transport
         stream.prompt_len = len(prompt)
         stream.slo_class = slo_class
         stream.cursor_base = pos_base
@@ -1926,7 +2048,7 @@ class GenerationEngine:
         samples: list = []
         for _ in range(4):
             try:
-                samples = list(self._gap_samples)
+                samples = list(self._acct.gap_samples)
                 break
             except RuntimeError:
                 continue
@@ -1939,6 +2061,10 @@ class GenerationEngine:
             "gap_p50_ms": (round(float(np.median(samples)) * 1e3, 4)
                            if samples else None),
             "gap_samples": len(samples),
+            # what pipelining is for: the stream's dry time a reap
+            "dry_ms_per_reap": (
+                round(self._acct.dry_total / self._reaps * 1e3, 4)
+                if self._reaps else None),
         }
 
     def warmup(self) -> None:
@@ -2290,6 +2416,22 @@ class GenerationEngine:
         return jnp.asarray([0 if req is None else req.adapter], jnp.int32)
 
     def _admit(self, defer_lattice: bool = False) -> int:
+        """One admission pass, accounted as the loop's ``admit`` phase
+        (with the requests it started as the phase's count). A pass
+        that could start nothing — no free slot, or nobody waiting —
+        returns before the phase changes: in-flight admission polls
+        every millisecond behind a full batch."""
+        if self._pending.empty() or not any(s.free for s in self._slots):
+            return 0
+        prev = self._acct.phase("admit")
+        try:
+            started = self._admit_pass(defer_lattice)
+            self._acct.ph_n += started
+            return started
+        finally:
+            self._acct.phase(prev)
+
+    def _admit_pass(self, defer_lattice: bool) -> int:
         """Admit pending requests into free slots; returns the number
         started. ``defer_lattice``: in-flight admission (see
         _admit_inflight) must NOT start a chunk-lattice admission — the
@@ -2493,13 +2635,27 @@ class GenerationEngine:
             Sb = pad_bucket(L, self.prompt_buckets)
             padded = np.zeros((1, Sb), np.int32)
             padded[0, :L] = req.prompt
-            tok, lp, self._key, self.cache = self._prefill_jit(
+            tok, lp, self._key, self.cache = self._run(
+                self._prefill_jit,
                 self.cache, self.params, jnp.asarray(padded), jnp.int32(L),
                 jnp.int32(idx), jnp.float32(req.temperature),
                 jnp.int32(req.top_k), self._key, jnp.int32(req.seed),
                 jnp.int32(req.pos_base), self._adapter1(req))
-            return int(tok), float(lp)
+            return self._first_token(tok, lp)
         return self._chunk_lattice("cache", idx, req, pos)
+
+    def _first_token(self, tok, lp) -> tuple[int, float]:
+        """Fetch the token an admission's last program sampled. The
+        copy blocks until every program queued so far is done (the
+        decode block in flight, then the prefill): the thread is
+        blocked on the device, which is the loop's ``fetch`` phase, not
+        host work of admission."""
+        prev = self._acct.phase("fetch")
+        try:
+            tok, lp = jax.device_get((tok, lp))  # one round trip, not two
+            return int(tok), float(lp)
+        finally:
+            self._acct.phase(prev)
 
     def _lattice_resume_valid(self, L: int, m: int) -> bool:
         """Can the chunk lattice resume at position ``m`` of an L-token
@@ -2561,8 +2717,8 @@ class GenerationEngine:
             chaos.fire(chaos.GENERATOR_CHUNK)
             chunk = req.prompt[pos:pos + T]
             t0c = time.monotonic() if self._tl is not None else 0.0
-            setattr(self, attr, self._chunk_mid_jit(
-                getattr(self, attr), self.params,
+            setattr(self, attr, self._run(
+                self._chunk_mid_jit, getattr(self, attr), self.params,
                 jnp.asarray(chunk[None, :]), jnp.int32(pos),
                 jnp.int32(slot), jnp.int32(0), jnp.int32(0),
                 jnp.float32(0.0), jnp.int32(0), self._key,
@@ -2602,9 +2758,9 @@ class GenerationEngine:
             #      synchronously so its tokens deliver before the
             #      next chunk occupies the device.
             self._admit(defer_lattice=True)
-            inflight = self._decode_tick()
+            inflight = self._tick(decode_only=True)
             if inflight is not None:
-                inflight.reap()
+                self._reap(inflight)
         if req.stream.cancelled.is_set():
             return 0, 0.0
         if self._expire_mid_lattice(req, pos):
@@ -2612,14 +2768,15 @@ class GenerationEngine:
         rem = L - pos
         Sb = pad_bucket(rem, self.prompt_buckets)
         final = req.prompt[L - Sb:]
-        tok, lp, self._key, new_cache = self._chunk_final_jit(
+        tok, lp, self._key, new_cache = self._run(
+            self._chunk_final_jit,
             getattr(self, attr), self.params, jnp.asarray(final[None, :]),
             jnp.int32(L - Sb), jnp.int32(slot), jnp.int32(L),
             jnp.int32(Sb - 1), jnp.float32(req.temperature),
             jnp.int32(req.top_k), self._key, jnp.int32(req.seed),
             jnp.int32(req.pos_base), self._adapter1(req))
         setattr(self, attr, new_cache)
-        return int(tok), float(lp)
+        return self._first_token(tok, lp)
 
     def _expire_mid_lattice(self, req: _Request, pos: int) -> bool:
         """Deadline check between chunk dispatches: a half-prefilled
@@ -2709,19 +2866,20 @@ class GenerationEngine:
             write_blocks = blocks + [0] * (n_wr - len(blocks))
             padded = np.zeros((1, Sb), np.int32)
             padded[0, :L] = req.prompt
-            tok, lp, self._key, self.cache = self._prefill_jit(
+            tok, lp, self._key, self.cache = self._run(
+                self._prefill_jit,
                 self.cache, self.params, jnp.asarray(padded), jnp.int32(L),
                 jnp.asarray(write_blocks, jnp.int32), jnp.int32(idx),
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
                 self._key, jnp.int32(req.seed), jnp.int32(req.pos_base),
                 self._adapter1(req))
             self._write_table_row(idx)
-            return int(tok), float(lp)
+            return self._first_token(tok, lp)
         if m > 0:
             # restore: shared blocks -> scratch positions [0, m)
             read_blocks = shared + [0] * (self._mb - len(shared))
-            self._scratch = self._blocks_to_row_jit(
-                self._scratch, self.cache,
+            self._scratch = self._run(
+                self._blocks_to_row_jit, self._scratch, self.cache,
                 jnp.asarray(read_blocks, jnp.int32))
             # zero-copy block-share hit: the wide event and timeline
             # call it tier "paged" (the paged engine has no t0/t1/t2)
@@ -2737,8 +2895,8 @@ class GenerationEngine:
         # blocks (identical data) route to the trash block
         write_blocks = [0] * len(shared) + fresh \
             + [0] * (self._mb - len(blocks))
-        self.cache = self._row_to_blocks_jit(
-            self.cache, self._scratch,
+        self.cache = self._run(
+            self._row_to_blocks_jit, self.cache, self._scratch,
             jnp.asarray(write_blocks, jnp.int32))
         self.cache = self.cache._replace(
             lengths=self.cache.lengths.at[idx].set(L))
@@ -2922,7 +3080,8 @@ class GenerationEngine:
             out[:, 0, :kv.plen] = a
             return jnp.asarray(out)
 
-        self._pool = self._host_write_jit(
+        self._pool = self._run(
+            self._host_write_jit,
             self._pool, pad(kv.k, self._pool.k), pad(kv.v, self._pool.v),
             pad(kv.k_scale, self._pool.k_scale) if quant else None,
             pad(kv.v_scale, self._pool.v_scale) if quant else None,
@@ -3072,7 +3231,8 @@ class GenerationEngine:
             if self._ingest_write_jit is None:
                 self._ingest_write_jit = jax.jit(_write_row_from_host,
                                                  donate_argnums=(0,))
-            installed = self._ingest_write_jit(
+            installed = self._run(
+                self._ingest_write_jit,
                 target, jnp.asarray(k_p), jnp.asarray(v_p),
                 jnp.asarray(ks_p) if quant else None,
                 jnp.asarray(vs_p) if quant else None, jnp.int32(row))
@@ -3081,8 +3241,8 @@ class GenerationEngine:
                 self._slot_blocks[idx] = list(fresh)
                 self._cursors[idx] = L
                 write_blocks = list(fresh) + [0] * (self._mb - len(fresh))
-                self.cache = self._row_to_blocks_jit(
-                    self.cache, self._scratch,
+                self.cache = self._run(
+                    self._row_to_blocks_jit, self.cache, self._scratch,
                     jnp.asarray(write_blocks, jnp.int32))
                 self._write_table_row(idx)
             self.cache = self.cache._replace(
@@ -3162,8 +3322,8 @@ class GenerationEngine:
             if row is None:
                 self._kvc.reject(mt)
                 return 0
-        self.cache = self._pool_load_jit(self.cache, self._pool,
-                                         jnp.int32(idx), jnp.int32(row))
+        self.cache = self._run(self._pool_load_jit, self.cache, self._pool,
+                               jnp.int32(idx), jnp.int32(row))
         restore_s = time.monotonic() - t_start
         self._kvc.accept(mt, restore_s,
                          tenant=req.tenant if self.tenancy is not None
@@ -3191,6 +3351,7 @@ class GenerationEngine:
         if req.stream.cancelled.is_set():
             return
         prompt = np.asarray(req.prompt, np.int32)
+        t0 = time.monotonic()
         if self._paged:
             if self._prefix_idx is None or len(prompt) < self._store_min \
                     or self._prefix_idx.covered(prompt, req.adapter):
@@ -3202,6 +3363,9 @@ class GenerationEngine:
             # prefill can never store an entry over garbage KV.
             self._prefix_idx.store(prompt, self._slot_blocks[idx],
                                    req.adapter)
+            if self._tl is not None:
+                self._tl.store(t0, time.monotonic(), idx, len(prompt),
+                               "paged")
             return
         if self._kvc is None or len(prompt) < self._store_min \
                 or self._kvc.covered(prompt, req.adapter):
@@ -3209,9 +3373,12 @@ class GenerationEngine:
         row, victim = self._kvc.store(prompt, req.adapter,
                                       tenant=req.tenant
                                       if self.tenancy is not None else None)
+        tier = "t0"
+        if victim is not None and self._kvc.wants_offload:
+            tier += "+host"  # the victim's row is fetched to the host first
         self._offload_victim(victim)
-        self._pool = self._pool_store_jit(self._pool, self.cache,
-                                          jnp.int32(row), jnp.int32(idx))
+        self._pool = self._run(self._pool_store_jit, self._pool, self.cache,
+                               jnp.int32(row), jnp.int32(idx))
         if self.tenancy is not None:
             self._tenant_cache_sync()
         if self._kvc.shares:
@@ -3222,9 +3389,12 @@ class GenerationEngine:
             # partial block has no chain hash and never transfers)
             want = self._kvc.redis.pending_put_len(prompt, req.adapter)
             if want > 0:
+                tier += "+shared"
                 self._kvc.store_shared(prompt, req.adapter,
                                        self._kv_row_get(self.cache, idx,
                                                         want))
+        if self._tl is not None:
+            self._tl.store(t0, time.monotonic(), idx, len(prompt), tier)
 
     def _shed_oom(self, req: _Request, e: "hbm.HBMExhausted") -> None:
         """OOM-shed a popped admission: the arbiter could not cover a
@@ -3455,7 +3625,29 @@ class GenerationEngine:
             self._observe.requests.remove(stream.obs_entry)
             self._observe.recorder.record(event, request_id=stream.request_id,
                                           trace_id=stream.trace_id, **fields)
+            self._stage_spans(stream, fields.get("tokens", 0))
         self._wide_event(stream, event, fields)
+
+    def _stage_spans(self, stream: GenStream, tokens: int) -> None:
+        """The request's stage spans (tpu.admit-wait / tpu.prefill /
+        tpu.decode), made at its terminal from the stamps the loop took
+        once in ``stream.trace`` — and only when somebody exports."""
+        tracer = self._observe.tracer
+        if tracer is None or tracer.exporter is None:
+            return
+        t = stream.trace
+        now = time.monotonic()
+        slot, cls = t.get("slot"), stream.slo_class
+        for name, a, b, attrs in (
+                ("tpu.admit-wait", t.get("submit"), t.get("admit"),
+                 {"slot": slot, "slo_class": cls}),
+                ("tpu.prefill", t.get("admit"), t.get("prefill_done"),
+                 {"slot": slot, "prompt_len": stream.prompt_len,
+                  "slo_class": cls}),
+                ("tpu.decode", t.get("first_put") if tokens else None, now,
+                 {"slot": slot, "tokens": tokens, "slo_class": cls})):
+            if a is not None and b is not None:
+                self._obs_span(name, a, b, stream, attrs)
 
     def _wide_fields(self, outcome: str, trace_id: str,
                      slo_class: str, tenant: str | None = None) -> dict:
@@ -3502,6 +3694,8 @@ class GenerationEngine:
             "cache_tier": stream.cache_tier,
             "cache_tokens": stream.cache_tokens,
         })
+        if trace.get("first_put") is not None and submit is not None:
+            wide["ttft_s"] = round(trace["first_put"] - submit, 6)
         if stream.cursor_base:
             # durable-streams resume: where the continuation picked up
             # and how much prefix it actually had to recompute (a warm
@@ -3596,10 +3790,10 @@ class GenerationEngine:
 
     def _obs_span(self, name: str, start_s: float, end_s: float,
                   stream: GenStream, attrs: dict | None = None) -> None:
-        """Export one per-stage serving span (admit wait / prefill /
-        decode), parented by the request's inbound trace context."""
+        """Export one serving span, parented by the request's inbound
+        trace context. Without an exporter nothing is built."""
         obs = self._observe
-        if obs is None or obs.tracer is None:
+        if obs is None or obs.tracer is None or obs.tracer.exporter is None:
             return
         try:
             obs.tracer.record_span(name, start_s, end_s,
@@ -3656,19 +3850,17 @@ class GenerationEngine:
     def _start(self, idx: int, slot: _Slot, req: _Request,
                blocks: "tuple | None" = None) -> None:
         t0 = time.monotonic()
+        # the stage stamps are taken once, here and in _deliver; the wide
+        # event, the segment histogram, the flight recorder's request
+        # row and the stage spans are all written from them in _obs_end
         req.stream.trace["admit"] = t0
+        req.stream.trace["slot"] = idx
         if self.gate is not None:
             self.gate.note_wait(t0 - req.enqueued_at)
         if self._tl is not None:
             self._tl.admit(idx, req.slo_class, t0 - req.enqueued_at,
                            req.stream.request_id, req.stream.trace_id)
         self._obs_stage(req.stream, "prefill")
-        if self._observe is not None:
-            self._observe.recorder.record(
-                "admitted", request_id=req.stream.request_id,
-                trace_id=req.stream.trace_id, slot=idx,
-                slo_class=req.slo_class,
-                wait_s=round(t0 - req.enqueued_at, 6))
         # CLAIM the slot before any dispatch: a chunk-lattice admission
         # runs nested admission passes between chunks, and an unclaimed
         # slot (request still None until the old post-prefill
@@ -3735,11 +3927,8 @@ class GenerationEngine:
         if self._tl is not None:
             self._tl.prefill(t0, prefill_done, idx, len(req.prompt),
                              req.stream.request_id, req.stream.trace_id)
-        self._obs_span("tpu.admit-wait", req.enqueued_at, t0, req.stream,
-                       {"slot": idx, "slo_class": req.slo_class})
-        self._obs_span("tpu.prefill", t0, prefill_done, req.stream,
-                       {"slot": idx, "prompt_len": len(req.prompt),
-                        "slo_class": req.slo_class})
+        if not self._paged:
+            self._cursors[idx] = len(req.prompt)
         if req.kv_sink is not None:
             # prefill-only: ship the tail the chunk hooks haven't sent
             # (the whole row for bucket prompts) BEFORE the first-token
@@ -3829,12 +4018,6 @@ class GenerationEngine:
                     tenant=(req.tenant or ""
                             if self.tenancy is not None else ""))
             self._obs_stage(req.stream, "decode")
-            if self._observe is not None:
-                self._observe.recorder.record(
-                    "first_token", request_id=req.stream.request_id,
-                    trace_id=req.stream.trace_id, slot=idx,
-                    slo_class=req.slo_class,
-                    ttft_s=round(ttft, 6))
         # inter-token latency is recorded at the REAP level (_record_itl),
         # not here: a fused decode block delivers its K tokens back-to-back
         # in one host loop, and per-delivery gaps would report microsecond
@@ -3884,10 +4067,6 @@ class GenerationEngine:
             # throughput needs at least one inter-token interval
             self.metrics.set_gauge("app_tpu_tokens_per_second", tps,
                                    program="generate")
-        if first is not None and slot.generated > 0:
-            self._obs_span("tpu.decode", first, now, stream,
-                           {"slot": idx, "tokens": slot.generated,
-                            "slo_class": slot.request.slo_class})
         event = ("failed" if stream.failed is not None
                  else "cancelled" if stream.cancelled.is_set()
                  else "finished")
@@ -3927,9 +4106,9 @@ class GenerationEngine:
                 self._alloc.free(self._slot_blocks[idx])
                 self._slot_blocks[idx] = []
             self._table[idx, :] = 0
-            self._cursors[idx] = 0
             self._stop_cursors[idx] = 0
             self._touch("table")
+        self._cursors[idx] = 0
         self._obs_gauges()
 
     def _loop(self) -> None:
@@ -3944,6 +4123,10 @@ class GenerationEngine:
         while not self._closed:
             try:
                 if pipe or self._active.any() or not self._pending.empty():
+                    # whatever is not inside one of the loop's named
+                    # phases — this glue, a wait for the device lock —
+                    # is "other", and has to stay near zero
+                    self._acct.phase("other")
                     with self._device_lock:
                         if not pipe:
                             # synchronous admission pass — the only one
@@ -3971,19 +4154,11 @@ class GenerationEngine:
                         self._reaps += 1
                         if pipe:
                             # >= 1 block still queued on-device: the
-                            # inter-block host gap is zero by
-                            # construction — record it so the A/B gap
-                            # p50 reflects the pipelining win
+                            # stream cannot be dry behind this reap
                             self._overlapped_reaps += 1
-                            self._record_gap(0.0)
-                        else:
-                            # the stream ran dry when this block's
-                            # outputs came ready; the next dispatch
-                            # closes the gap
-                            self._idle_from = (inflight.ready_t
-                                               or time.monotonic())
-                        inflight.reap()
+                        self._reap(inflight)
                 else:
+                    self._acct.phase("park")
                     self._work.wait(timeout=0.05)
                     self._work.clear()
             except BaseException as e:  # noqa: BLE001 — waiters must not hang
@@ -3993,6 +4168,7 @@ class GenerationEngine:
                 # re-raise the same error; recovery below reseeds ONCE
                 # for however many dispatches were in flight
                 pipe.clear()
+                self._acct.phase("other")  # the recovery path
                 if self._closed:
                     return
                 if self.logger is not None:
@@ -4019,7 +4195,7 @@ class GenerationEngine:
                     self._pack = None
                     self._pack_dirty = True
                     self._last_dev = None
-                    self._idle_from = None
+                    self._acct.reset()
                     self._host_wins[:] = True
                     self._recoveries += 1
                     if self._prefix_idx is not None:
@@ -4200,26 +4376,58 @@ class GenerationEngine:
         a silent spin."""
         deadline = time.monotonic() + 60.0
         poll = self._admit_window or 1e-3
-        while not self._closed and time.monotonic() < deadline:
-            try:
-                if all(a.is_ready() for a in inflight.arrays):
-                    inflight.ready_t = time.monotonic()
+        prev = self._acct.phase("wait")
+        try:
+            while not self._closed:
+                now = time.monotonic()
+                if now >= deadline:
                     return
-            except Exception:  # no readiness probe on this backend
-                return
-            started = 0
-            if not self._pending.empty():
-                with self._device_lock:
-                    started = self._admit(defer_lattice=True)
-            if started:
-                continue  # more may be queued behind the ones admitted
-            # nothing admitted (queue empty, no free slot, pool
-            # pressure, or a lattice request deferred to the reap):
-            # WAIT — looping straight back would busy-spin on the GIL
-            # and the device lock for the whole block, starving the
-            # very submitter/consumer threads this loop exists to serve
-            self._work.clear()
-            self._work.wait(poll)
+                try:
+                    # the last program queued may be a prefill behind
+                    # this block: the stream is dry when THAT is done
+                    self._acct.probe(now)
+                    if all(a.is_ready() for a in inflight.arrays):
+                        return
+                except Exception:  # no readiness probe on this backend
+                    return
+                started = 0
+                if not self._pending.empty():
+                    with self._device_lock:
+                        started = self._admit(defer_lattice=True)
+                if started:
+                    continue  # more may be queued behind the ones admitted
+                # nothing admitted (queue empty, no free slot, pool
+                # pressure, or a lattice request deferred to the reap):
+                # WAIT — looping straight back would busy-spin on the GIL
+                # and the device lock for the whole block, starving the
+                # very submitter/consumer threads this loop exists to serve
+                self._work.clear()
+                self._work.wait(poll)
+        finally:
+            self._acct.phase(prev)
+
+    def _reap(self, inflight: _Inflight) -> None:
+        """Fetch a dispatched tick's results and deliver them: the
+        loop's ``fetch`` phase up to the device->host copy's return,
+        ``deliver`` from there (the reap itself moves the phase on)."""
+        prev = self._acct.phase("fetch")
+        try:
+            inflight.reap()
+        finally:
+            self._acct.phase(prev)
+
+    def _run(self, fn, *args):
+        """Dispatch one compiled program from the loop thread. Every
+        program goes through here, so that a dry interval ends at the
+        next dispatch of any kind and the dry probe always asks about
+        the last program queued."""
+        acct = self._acct
+        if acct.idle_from is None:
+            acct.probe(time.monotonic())  # dry already, and nobody saw?
+        out = fn(*args)
+        # the device cannot start before the call has queued the program
+        acct.dispatch(time.monotonic(), out)
+        return out
 
     def _target_depth(self) -> int:
         """Pipeline depth for the next top-up — the engine-side facts
@@ -4241,23 +4449,6 @@ class GenerationEngine:
         if self._tl is not None:
             self._tl.pipeline_depth(depth)
 
-    def _note_dispatch(self, now: float) -> None:
-        """Close an open inter-block gap: the device stream ran dry at
-        ``_idle_from`` and this dispatch is the first work queued
-        since."""
-        if self._idle_from is None:
-            return
-        gap, self._idle_from = max(0.0, now - self._idle_from), None
-        self._record_gap(gap, now)
-
-    def _record_gap(self, gap: float, now: float | None = None) -> None:
-        self._gap_samples.append(gap)
-        if self.metrics is not None:
-            self.metrics.record_histogram("app_tpu_dispatch_gap_duration",
-                                          gap, program="generate")
-        if self._tl is not None and now is not None and gap > 0.0:
-            self._tl.dispatch_gap(now - gap, now)
-
     def _tick(self, decode_only: bool = False) -> "_Inflight | None":
         """Dispatch one serving tick: a speculative verify pass when the
         engine can use one (spec enabled, every active slot greedy and
@@ -4266,19 +4457,26 @@ class GenerationEngine:
         ``decode_only``: a pipeline top-up behind an un-reaped block —
         verify windows are built from host-delivered history, which
         does not exist yet (the depth policy already pins spec engines
-        to depth 1; this is the structural guard)."""
-        if not decode_only and self._spec_k and self._spec_eligible():
-            drafts = {idx: self._draft(idx)
-                      for idx in range(self.n_slots) if self._active[idx]}
-            drafted = sum(d is not None for d in drafts.values())
-            # Coverage gate: slots WITHOUT drafts emit 1 token per verify
-            # pass vs decode_block per decode dispatch — one repetitive
-            # stream must not drag a batch of non-repetitive ones into
-            # K-times-slower cadence. Verify only when at least half the
-            # active slots would actually speculate.
-            if drafted > 0 and 2 * drafted >= len(drafts):
-                return self._verify_tick(drafts)
-        return self._decode_tick()
+        to depth 1; this is the structural guard) — or the chunk
+        lattice's decode block between two chunks. The loop's
+        ``dispatch`` phase, from entry to the jitted call's return."""
+        prev = self._acct.phase("dispatch")
+        try:
+            if not decode_only and self._spec_k and self._spec_eligible():
+                drafts = {idx: self._draft(idx)
+                          for idx in range(self.n_slots) if self._active[idx]}
+                drafted = sum(d is not None for d in drafts.values())
+                # Coverage gate: slots WITHOUT drafts emit 1 token per
+                # verify pass vs decode_block per decode dispatch — one
+                # repetitive stream must not drag a batch of
+                # non-repetitive ones into K-times-slower cadence. Verify
+                # only when at least half the active slots would
+                # actually speculate.
+                if drafted > 0 and 2 * drafted >= len(drafts):
+                    return self._verify_tick(drafts)
+            return self._decode_tick()
+        finally:
+            self._acct.phase(prev)
 
     def _spec_eligible(self) -> bool:
         W = self._spec_k + 1
@@ -4313,12 +4511,14 @@ class GenerationEngine:
             self._ensure_blocks(W)  # window rows span up to W positions
             if not self._active.any():
                 return None
-            toks, lps, emit, self.cache = self._verify_jit(
+            toks, lps, emit, self.cache = self._run(
+                self._verify_jit,
                 self.cache, self.params, jnp.asarray(window),
                 self._dev("active", self._active), self._key,
                 self._dev("table", self._table), self._adapters())
         else:
-            toks, lps, emit, self.cache = self._verify_jit(
+            toks, lps, emit, self.cache = self._run(
+                self._verify_jit,
                 self.cache, self.params, jnp.asarray(window),
                 self._dev("active", self._active), self._key,
                 self._adapters())
@@ -4338,6 +4538,7 @@ class GenerationEngine:
     def _verify_reap(self, toks, lps, emit, snap_active, snap_reqs,
                      t0: float = 0.0) -> None:
         toks_np, lps_np, emit_np = jax.device_get((toks, lps, emit))
+        self._acct.phase("deliver")
         if self._tl is not None:
             self._tl.verify_block(
                 t0, time.monotonic(),
@@ -4346,12 +4547,11 @@ class GenerationEngine:
         self._spec_windows += int(snap_active.sum())
         self._spec_emitted += int(emit_np.sum())
         emit_l = emit_np.tolist()
-        if self._paged:
-            # device cursors advanced by emit (accepted tokens only;
-            # zero for slots outside the dispatch mask, so in-flight
-            # admissions — cursor set by their own prefill — are safe)
-            for idx in range(self.n_slots):
-                self._cursors[idx] += emit_l[idx]
+        # device cursors advanced by emit (accepted tokens only; zero
+        # for slots outside the dispatch mask, so in-flight admissions
+        # — cursor set by their own prefill — are safe)
+        for idx in range(self.n_slots):
+            self._cursors[idx] += emit_l[idx]
         toks_l, lps_l = toks_np.tolist(), lps_np.tolist()
         for idx, slot in enumerate(self._slots):
             if not snap_active[idx] or slot.request is not snap_reqs[idx]:
@@ -4403,12 +4603,16 @@ class GenerationEngine:
             # no previous dispatch to chain from — build the slot-state
             # carry from the host arrays
             self._last_dev = self._host_carry()
+        # KV positions the block's attention has to read, as dispatched
+        live = int(self._cursors[self._active].sum())
         t_dispatch = time.monotonic()
-        self._note_dispatch(t_dispatch)
+        pack = self._dispatch_pack()
         toks, lps, emitted, self._last_dev, self._key, self.cache = \
-            self._step_jit(self.cache, self.params, self._dispatch_pack(),
-                           self._last_dev, self._key)
-        if self._paged:
+            self._run(self._step_jit, self.cache, self.params, pack,
+                      self._last_dev, self._key)
+        if not self._paged:
+            self._cursors[self._active] += self.decode_block
+        else:
             # advance bounded by each slot's device stop cursor: the
             # scan freezes a slot there (budget/capacity), so the host
             # view must not run past it while un-reaped blocks pile up
@@ -4428,20 +4632,21 @@ class GenerationEngine:
         snap_reqs = [s.request for s in self._slots]
         return _Inflight((toks, lps, emitted), functools.partial(
             self._decode_reap, toks, lps, emitted, snap_active, snap_reqs,
-            t_dispatch))
+            t_dispatch, live))
 
     # invoked through _Inflight.reap, always under the engine's device
     # lock (see _loop)  # gl: holds self._device_lock
     def _decode_reap(self, toks, lps, emitted, snap_active, snap_reqs,
-                     t0: float = 0.0) -> None:
+                     t0: float = 0.0, live: int | None = None) -> None:
         toks_np, lps_np, emit_np = jax.device_get((toks, lps, emitted))
+        self._acct.phase("deliver")
         if self._tl is not None:
             # one ring event per fused block, fanned out to per-slot
             # slices only at export time — the hot path pays one append
             self._tl.decode_block(
                 t0, time.monotonic(),
                 tuple(int(i) for i in np.flatnonzero(snap_active)),
-                self.decode_block)
+                self.decode_block, live)
         if self.metrics is not None:
             self.metrics.set_gauge("app_tpu_batch_fill",
                                    float(self._active.sum()) / self.n_slots,

@@ -17,7 +17,8 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import json
-import secrets
+import os
+import random
 import threading
 import time
 import urllib.request
@@ -30,12 +31,27 @@ _current: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
 )
 
 
+# Ids come from a generator seeded once per process (and again in a
+# forked child), not from the system's entropy pool: ``os.urandom`` is a
+# system call that drops the GIL, and the serving loop draws ids in
+# front of every busy stream thread. An id has to be unique, not secret.
+_ids = random.Random()
+
+
+def _seed_ids() -> None:
+    _ids.seed(os.urandom(16))
+
+
+_seed_ids()
+os.register_at_fork(after_in_child=_seed_ids)
+
+
 def _new_trace_id() -> str:
-    return secrets.token_hex(16)
+    return f"{_ids.getrandbits(128) or 1:032x}"
 
 
 def _new_span_id() -> str:
-    return secrets.token_hex(8)
+    return f"{_ids.getrandbits(64) or 1:016x}"
 
 
 @dataclass
@@ -166,16 +182,20 @@ class Tracer:
         traceparent: str | None = None,
         trace_id: str | None = None,
         attributes: dict[str, Any] | None = None,
-    ) -> Span:
+    ) -> Span | None:
         """Export a span for an interval measured elsewhere (serving-loop
         stage timings: admit wait, prefill, decode). Unlike start_span
         this never touches the contextvar stack — the serving loop is
         one thread multiplexing every request, so "current span" is
         meaningless there — and the span arrives already finished.
+        Without an exporter nobody could read it: nothing is built and
+        None is returned.
 
         ``trace_id`` correlates spans without claiming a parent: when no
         valid ``traceparent`` exists, the span joins that trace as a
         root instead of pointing at a phantom parent span id."""
+        if self.exporter is None:
+            return None
         ctx = parse_traceparent(traceparent)
         if ctx is not None:
             trace_id, parent_id = ctx
@@ -192,8 +212,7 @@ class Tracer:
             attributes=dict(attributes or {}),
         )
         span.end_ns = int(end_monotonic * 1e9)
-        if self.exporter is not None:
-            self.exporter.export(span, self.service_name)
+        self.exporter.export(span, self.service_name)
         return span
 
 

@@ -7,6 +7,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import jax
 import pytest
@@ -69,3 +70,48 @@ def test_compile_cache_dir(monkeypatch):
 def test_unbuildable_model_fails_startup():
     with pytest.raises(KeyError, match="no-such-model"):
         App(MapConfig({"TPU_MODEL": "no-such-model", "LOG_LEVEL": "FATAL"}))
+
+
+def test_one_compile_listener_counts_and_marks_the_timeline():
+    """compile_cache.clock() is the process's one compile listener:
+    seconds, programs, hits and misses, a log of (time, seconds), and a
+    ``compile`` instant on the serving timeline it is attached to."""
+    import jax
+    import jax.numpy as jnp
+
+    from gofr_tpu.observe import Timeline
+
+    clock = compile_cache.clock()
+    assert compile_cache.clock() is clock  # one, however often asked
+    was = clock.timeline
+    tl = clock.timeline = Timeline(capacity=64)
+    try:
+        before = clock.snapshot()
+        assert set(before) == {"seconds", "programs", "hits", "misses"}
+        t0 = time.monotonic()
+
+        def fresh(x):  # a program nobody compiled before
+            return jnp.tanh(x * 3.25 + 0.125).sum()
+
+        jax.jit(fresh)(jnp.arange(7.0)).block_until_ready()
+        after = clock.snapshot()
+    finally:
+        clock.timeline = was
+    assert after["programs"] >= before["programs"] + 1
+    assert after["seconds"] > before["seconds"]
+    new = [(t, s) for t, s in clock.log if t >= t0]
+    assert new and all(s > 0 for _, s in new)
+    marks = [e for e in tl.events() if e[3] == "compile"]
+    assert len(marks) == after["programs"] - before["programs"]
+    assert sum(e[4] for e in marks) == pytest.approx(
+        after["seconds"] - before["seconds"], abs=1e-4)
+    row = next(e for e in tl.chrome_trace()["traceEvents"]
+               if e.get("cat") == "compile")
+    assert row["name"].startswith("compile ") and row["tid"] == 3
+
+
+def test_chip_smoke_keeps_no_copy_of_the_clock_or_the_parser():
+    with open(os.path.join(REPO, "chip_smoke.py")) as f:
+        src = f.read()
+    assert "class CompileClock" not in src
+    assert "compile_cache.clock()" in src and "parse_prometheus" in src

@@ -458,3 +458,58 @@ def test_app_grpc_bidi_generation_cancel_releases_slot():
         ch.close()
     finally:
         app.stop()
+
+
+# -- the first message's path, once, on the serving timeline ------------------
+
+@pytest.mark.parametrize("path", ["iterator", "push"])
+def test_one_first_event_a_stream_with_ordered_stamps(path):
+    """Where a stream's first message reaches the socket the transport
+    writes ONE timeline ``first`` event with the stamps the code already
+    takes, in order: request HEADERS received, (engine submit and
+    first_put, which only the push path can see: it holds the GenStream),
+    transport got it, header encode, the coalesced write."""
+    from gofr_tpu import App
+    from gofr_tpu.config import MapConfig
+    from gofr_tpu.grpcx import ServerStream
+
+    app = App(MapConfig({"GRPC_PORT": "0", "METRICS_PORT": "0",
+                         "TPU_MODEL": "tiny", "TPU_MAX_SEQ": "64",
+                         "TPU_SLOTS": "2", "TPU_SEQ_BUCKETS": "8,16"}))
+    llm = GRPCService("llm.Generation")
+
+    @llm.server_stream("Generate")
+    def generate(ctx, req):
+        stream = ctx.tpu.generate(req["tokens"], max_new_tokens=5)
+        if path == "push":
+            return ServerStream(stream, lambda tok: {"token": tok})
+        return ({"token": tok} for tok in stream)
+
+    app.register_grpc_service(llm)
+    app.run(block=False)
+    try:
+        ch = dial(f"127.0.0.1:{app.grpc_port}")
+        for prompt in ([5, 17, 42], [7, 8, 9, 10]):
+            toks = list(ch.server_stream("/llm.Generation/Generate",
+                                         {"tokens": prompt}, timeout=240.0))
+            assert len(toks) == 5
+        ch.close()
+        firsts = [e for e in app.container.observe.timeline.events()
+                  if e[3] == "first"]
+    finally:
+        app.stop()
+    assert len(firsts) == 2  # one a stream, not one a token
+    for _, ts, dur, _, rid, trace_id, (headers, submit, first_put, got), \
+            (enc0, enc1, write0, write1) in firsts:
+        assert dur is None and ts == write1
+        assert len(trace_id) == 32  # the RPC span's: joins logs and spans
+        if path == "push":
+            assert isinstance(rid, int)
+            order = [headers, submit, first_put, got, enc0, enc1, write0,
+                     write1]
+        else:
+            assert rid is None and submit is None and first_put is None
+            order = [headers, got, enc0, enc1, write0, write1]
+        assert all(isinstance(t, float) for t in order)
+        assert order == sorted(order)
+    assert firsts[0][4] != firsts[1][4] or path == "iterator"

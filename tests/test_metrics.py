@@ -175,3 +175,21 @@ def test_framework_metrics_register_and_system_update():
     assert "app_tpu_predict_duration" in text
     # system gauges got real values
     assert "app_sys_memory_alloc 0.0" not in text
+
+
+def test_parse_prometheus_sums_label_sets_of_the_rendered_text():
+    from gofr_tpu.metrics import parse_prometheus
+
+    m = Manager()
+    m.new_counter("reqs", "requests")
+    m.new_histogram("lat", "latency", buckets=(0.1, 1.0))
+    m.increment_counter("reqs", path="/a")
+    m.increment_counter("reqs", path="/a")
+    m.increment_counter("reqs", path="/b")
+    m.record_histogram("lat", 0.05, program="x")
+    m.record_histogram("lat", 0.5, program="y")
+    totals = parse_prometheus(m.render_prometheus())
+    assert totals["reqs"] == 3.0
+    assert totals["lat_count"] == 2.0
+    assert totals["lat_sum"] == pytest.approx(0.55)
+    assert parse_prometheus("# HELP x\nbroken line\n\n") == {}

@@ -1,0 +1,74 @@
+"""jax.named_scope on the model's blocks and on the ops/ kernels is
+metadata: it puts the block's name into every operation's op_name, which
+is what a device trace shows beside the operation. These tests lower the
+serving engine's decode and prefill programs on the CPU and look for the
+scopes in the lowered text's locations."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from gofr_tpu.models import LLAMA_CONFIGS, llama
+from gofr_tpu.tpu import GenerationEngine
+
+DENSE_BLOCKS = ("embed", "attn_qkv", "kv_write", "attn", "attn_out", "mlp",
+                "lm_head", "sampling")
+KERNELS = ("rms_norm", "qmatmul", "apply_rope")
+
+
+@pytest.fixture(scope="module")
+def engine():
+    cfg = LLAMA_CONFIGS["tiny"]
+    eng = GenerationEngine(cfg, llama.init(cfg, jax.random.PRNGKey(0)),
+                           slots=2, max_seq=64, prompt_buckets=(8, 16),
+                           decode_block=2, kv_dtype=jnp.int8)
+    yield eng
+    eng.close()
+
+
+def _lowered(eng, which: str) -> str:
+    i32 = jnp.int32
+    if which == "decode":
+        low = eng._step_jit.lower(eng.cache, eng.params, eng._warm_pack(),
+                                  eng._host_carry(), eng._key)
+    elif which == "prefill":
+        low = eng._prefill_jit.lower(
+            eng.cache, eng.params, jnp.zeros((1, 8), i32), i32(5), i32(0),
+            jnp.float32(0.0), i32(0), eng._key, i32(0), i32(0), None)
+    else:
+        low = eng._chunk_final_jit.lower(
+            eng.cache, eng.params, jnp.zeros((1, 8), i32), i32(16), i32(0),
+            i32(24), i32(7), jnp.float32(0.0), i32(0), eng._key, i32(0),
+            i32(0), None)
+    return low.as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill", "chunk"])
+@pytest.mark.parametrize("scope", DENSE_BLOCKS + KERNELS)
+def test_scope_is_in_the_lowered_programs_op_names(engine, which, scope):
+    text = _lowered(engine, which)
+    names = [line for line in text.splitlines() if "loc(" in line]
+    assert any(f'"{scope}"' in line or f"/{scope}/" in line
+               or f"{scope}/" in line for line in names), scope
+
+
+@pytest.mark.parametrize("which, kernel", [
+    ("decode", "decode_attention_appended"), ("decode", "quantize_kv"),
+    ("chunk", "chunk_attention"), ("chunk", "quantize_kv")])
+def test_attention_kernels_name_themselves(engine, which, kernel):
+    assert kernel in _lowered(engine, which)
+
+
+def test_moe_blocks_are_scoped():
+    cfg = LLAMA_CONFIGS["tiny-moe"] if "tiny-moe" in LLAMA_CONFIGS else None
+    if cfg is None:
+        import dataclasses
+        cfg = dataclasses.replace(LLAMA_CONFIGS["tiny"], n_experts=4,
+                                  experts_per_token=2)
+    params = llama.init(cfg, jax.random.PRNGKey(0))
+    cache = llama.init_cache(cfg, 2, 32)
+    text = jax.jit(lambda p, t, c: llama.decode_step(p, cfg, t, c)).lower(
+        params, jnp.zeros((2,), jnp.int32), cache).as_text(debug_info=True)
+    for scope in ("moe", "moe_route", "moe_experts"):
+        assert f'"{scope}"' in text or f"{scope}/" in text, scope
+    assert '"mlp"' not in text

@@ -424,13 +424,20 @@ def test_full_app_generation_flight_recorder_and_telemetry():
         mine = [e for e in events
                 if e.get("trace_id") == gen_entry["trace_id"]]
         kinds = [e["event"] for e in mine]
-        for expected in ("submitted", "admitted", "first_token", "finished"):
+        for expected in ("submitted", "finished", "request"):
             assert expected in kinds, f"missing {expected} in {kinds}"
         finished = next(e for e in mine if e["event"] == "finished")
         assert finished["tokens"] == 100
         assert finished["duration_s"] > 0
-        first_token = next(e for e in mine if e["event"] == "first_token")
-        assert first_token["ttft_s"] > 0
+        # admission and the first token are stamped once and reported by
+        # the request's one wide row (the recorder's own "admitted" and
+        # "first_token" rows repeated the same intervals and are gone)
+        row = next(e for e in mine if e["event"] == "request")
+        assert row["ttft_s"] > 0
+        assert row["queue_wait_s"] >= 0
+        assert set(row["breakdown"]) == {"queue_wait_s", "prefill_s",
+                                         "handoff_s", "decode_s"}
+        assert "admitted" not in kinds and "first_token" not in kinds
         del rid
 
         # -- wide event: one canonical row reconstructs the request -----------

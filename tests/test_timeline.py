@@ -387,3 +387,180 @@ def test_append_cost_is_sub_microsecond_scale():
         off.append("decode", 0.0, 0.001, (0, 1), 4)
     off_us = (time.perf_counter() - t0) / n * 1e6
     assert off_us < 5.0, f"disabled append cost {off_us:.2f}µs"
+
+
+# -- the generation thread's own account of its time --------------------------
+
+@pytest.mark.parametrize("kind", ["loop", "store", "first", "compile", "gap",
+                                  "decode"])
+def test_loop_account_events_round_trip(kind):
+    """loop, store, first and compile events (and the gap's slack, the
+    decode block's live tokens) come back from events() as written and
+    render on their tracks in chrome_trace()."""
+    tl = Timeline(capacity=64)
+    t = 200.0
+    tl.loop(t, t + 0.004, "admit", 2)
+    tl.store(t + 0.001, t + 0.003, 1, 600, "t0+host")
+    tl.first(7, "ab" * 16, (t, t + 0.001, t + 0.2, t + 0.2001),
+             (t + 0.2001, t + 0.2002, t + 0.2002, t + 0.2005))
+    tl.compile(1.25)
+    tl.dispatch_gap(t + 0.010, t + 0.012, 0.0005)
+    tl.decode_block(t + 0.020, t + 0.030, (0, 1), 4, 1234)
+    ev = next(e for e in tl.events() if e[3] == kind)
+    tr = tl.chrome_trace()
+    json.dumps(tr)
+    rows = tr["traceEvents"]
+    tracks = {e["tid"]: e["args"]["name"] for e in rows
+              if e.get("ph") == "M" and e["name"] == "thread_name"}
+    if kind == "loop":
+        assert ev[1:3] == (t, pytest.approx(0.004))
+        assert ev[4:6] == ("admit", 2)
+        row = next(e for e in rows if e.get("cat") == "loop")
+        assert row["name"] == "loop:admit" and row["ph"] == "X"
+        assert tracks[row["tid"]] == "host loop"
+        assert row["args"]["n"] == 2
+        assert row["dur"] == pytest.approx(4000.0)
+    elif kind == "store":
+        assert ev[4:7] == (1, 600, "t0+host")
+        row = next(e for e in rows if e.get("cat") == "store")
+        assert tracks[row["tid"]] == "slot 1"
+        assert row["args"]["tier"] == "t0+host"
+        assert row["args"]["tokens"] == 600
+    elif kind == "first":
+        assert ev[1] == t + 0.2005 and ev[2] is None  # instant at write1
+        assert ev[4:6] == (7, "ab" * 16)
+        assert ev[6][2] == t + 0.2 and ev[7][3] == t + 0.2005
+        row = next(e for e in rows if e.get("cat") == "transport")
+        assert tracks[row["tid"]] == "transport" and row["ph"] == "i"
+        a = row["args"]
+        assert a["request_id"] == 7 and a["trace_id"] == "ab" * 16
+        assert a["headers"] <= a["submit"] <= a["first_put"] <= a["got"] \
+            <= a["enc0"] <= a["enc1"] <= a["write0"] <= a["write1"]
+    elif kind == "compile":
+        assert ev[2] is None and ev[4] == 1.25
+        row = next(e for e in rows if e.get("cat") == "compile")
+        assert tracks[row["tid"]] == "host loop" and row["ph"] == "i"
+        assert row["args"]["seconds"] == 1.25
+    elif kind == "gap":
+        assert ev[4] == 0.0005
+        row = next(e for e in rows if e.get("cat") == "gap")
+        assert tracks[row["tid"]] == "device stream"
+        assert row["args"]["slack_s"] == 0.0005
+    else:
+        assert ev[4:7] == ((0, 1), 4, 1234)
+        row = next(e for e in rows if e.get("cat") == "decode")
+        assert row["args"]["live_tokens"] == 1234
+
+
+def _burst(eng, n=6, new=12):
+    rng = np.random.default_rng(5)
+    streams = [eng.generate(rng.integers(1, eng.cfg.vocab_size, k).tolist(),
+                            max_new_tokens=new)
+               for k in (5, 12, 60, 7, 30, 3)[:n]]
+    return [s.tokens() for s in streams]
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+def test_loop_events_cover_the_generation_threads_time(tiny, paged):
+    """Over a served burst (bucket prefills, a chunked prompt, pipelined
+    decode) the loop events are written back to back: no two overlap,
+    they leave out under 2% of the thread's time, and every phase name
+    is one of the documented seven."""
+    obs = Observe(timeline=Timeline(capacity=16384))
+    kw = dict(paged_blocks=64) if paged else {}
+    eng = _engine(tiny, observe=obs, slots=3, prefill_chunk=16,
+                  decode_pipeline=2, **kw)
+    try:
+        _burst(eng)  # warm: compiles land in this one
+        t0 = time.monotonic()
+        outs = _burst(eng)
+        t1 = time.monotonic()
+        assert all(len(o) == 12 for o in outs)
+        time.sleep(0.12)  # let the thread park, which closes its last phase
+    finally:
+        eng.close()
+    loops = sorted((e for e in obs.timeline.events() if e[3] == "loop"),
+                   key=lambda e: e[1])
+    assert {e[4] for e in loops} <= {"admit", "dispatch", "wait", "fetch",
+                                     "deliver", "park", "other"}
+    assert {"admit", "dispatch", "fetch", "deliver"} <= {e[4] for e in loops}
+    for a, b in zip(loops, loops[1:]):
+        assert a[1] + a[2] <= b[1] + 1e-9, (a, b)  # never nested
+    covered = sum(max(0.0, min(e[1] + e[2], t1) - max(e[1], t0))
+                  for e in loops)
+    assert covered >= 0.98 * (t1 - t0)
+    # admit phases carry the requests they started: six in the burst
+    # (an admission that ran a chunk lattice splits into several slices)
+    assert sum(e[5] for e in loops
+               if e[4] == "admit" and t0 <= e[1] < t1) == 6
+    # the decode events carry the live tokens of the block as dispatched
+    decodes = [e for e in obs.timeline.events() if e[3] == "decode"]
+    assert decodes and all(isinstance(e[6], int) and e[6] > 0
+                           for e in decodes)
+    assert max(e[6] for e in decodes) <= 3 * 128
+
+
+def test_a_gap_closes_at_a_prefill_dispatch(tiny):
+    """Device-dry intervals end at the next dispatch of ANY program. A
+    request that arrives at an idle engine finds the stream dry since
+    the last block's reap, and its prefill, not a decode block, is the
+    dispatch that closes the gap: the gap event ends inside that
+    request's prefill slice, before any decode block starts."""
+    m = Manager()
+    register_framework_metrics(m)
+    obs = Observe(metrics=m, timeline=Timeline(capacity=8192))
+    eng = _engine(tiny, observe=obs, metrics=m)
+    try:
+        eng.generate([1, 2, 3], max_new_tokens=6).tokens()
+        time.sleep(0.15)  # idle: the stream is dry, a gap is open
+        t_second = time.monotonic()
+        eng.generate([4, 5, 6, 7], max_new_tokens=6).tokens()
+    finally:
+        eng.close()
+    ev = obs.timeline.events()
+    prefill = next(e for e in ev if e[3] == "prefill" and e[1] >= t_second)
+    decode = next(e for e in ev if e[3] == "decode" and e[1] >= t_second)
+    gaps = [e for e in ev if e[3] == "gap"
+            and e[1] < t_second <= e[1] + e[2]]
+    assert len(gaps) == 1, gaps  # the idle interval, as one event
+    end = gaps[0][1] + gaps[0][2]
+    assert prefill[1] <= end <= prefill[1] + prefill[2]
+    assert end < decode[1]
+    assert gaps[0][2] >= 0.1 and gaps[0][4] >= 0.0  # duration, slack
+    # and the histogram holds the same interval (no zero "by construction")
+    text = m.render_prometheus()
+    assert 'app_tpu_dispatch_gap_duration_count{program="generate"}' in text
+    assert all(g > 0.0 for g in eng._acct.gap_samples)
+
+
+def test_prefix_store_writes_one_store_event(tiny):
+    obs = Observe(timeline=Timeline(capacity=4096))
+    eng = _engine(tiny, observe=obs, prefix_cache_slots=2,
+                  prefix_store_min=8)
+    try:
+        rng = np.random.default_rng(2)
+        prompt = rng.integers(1, eng.cfg.vocab_size, 24).tolist()
+        eng.generate(prompt, max_new_tokens=4).tokens()
+    finally:
+        eng.close()
+    ev = obs.timeline.events()
+    stores = [e for e in ev if e[3] == "store"]
+    assert len(stores) == 1
+    _, t0, dur, _, slot, tokens, tier, _ = stores[0]
+    assert tokens == 24 and tier == "t0" and dur >= 0.0
+    # it lies inside an admit phase of the loop
+    assert any(e[3] == "loop" and e[4] == "admit"
+               and e[1] <= t0 and t0 + dur <= e[1] + e[2] + 1e-9 for e in ev)
+
+
+def test_chunk_programs_are_named_in_the_device_trace(tiny):
+    """A jitted functools.partial is jit__unknown in a device trace; the
+    chunk programs carry the names the benchmark's readers look for."""
+    eng = _engine(tiny)
+    try:
+        assert eng._chunk_mid_jit.__name__ == "_chunk_mid"
+        assert eng._chunk_final_jit.__name__ == "_chunk_final"
+        assert eng._step_jit.__name__ == "_step_fn"
+        assert eng._prefill_jit.__name__ == "_prefill_fn"
+    finally:
+        eng.close()

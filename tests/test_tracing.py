@@ -306,3 +306,164 @@ def test_zipkin_shutdown_joins_thread_and_flushes(monkeypatch):
     exp.shutdown()
     assert [z["name"] for z in posted] == ["buffered"]
     assert not exp._thread.is_alive()  # clean exits must not strand it
+
+
+# -- nothing is built when nobody exports -------------------------------------
+
+def test_ids_are_drawn_without_a_system_call(monkeypatch):
+    """start_span gives every request a trace id (logs, exemplars and the
+    timeline use it) from a generator seeded once per process: neither
+    ``secrets`` nor ``os.urandom`` is called per span."""
+    import os
+    import secrets
+
+    from gofr_tpu import tracing
+
+    def forbidden(*a, **k):
+        raise AssertionError("a per-span call into the entropy pool")
+
+    monkeypatch.setattr(os, "urandom", forbidden)
+    for name in ("token_hex", "token_bytes", "randbits"):
+        monkeypatch.setattr(secrets, name, forbidden)
+    assert not hasattr(tracing, "secrets")
+    for exporter in (None, InMemoryExporter()):
+        t = Tracer("svc", exporter=exporter)
+        seen = set()
+        for _ in range(200):
+            s = t.start_span("inbound")
+            s.end()
+            assert len(s.trace_id) == 32 and len(s.span_id) == 16
+            assert int(s.trace_id, 16) and int(s.span_id, 16)
+            seen.add(s.trace_id)
+            seen.add(s.span_id)
+            t.record_span("tpu.prefill", 1.0, 2.0, trace_id=s.trace_id)
+        assert len(seen) == 400  # unique, if not secret
+
+
+def test_record_span_without_an_exporter_builds_nothing(monkeypatch):
+    from gofr_tpu import tracing
+
+    built = []
+    real = tracing.Span
+
+    class Counted(real):
+        def __init__(self, *a, **k):
+            built.append(1)
+            super().__init__(*a, **k)
+
+    monkeypatch.setattr(tracing, "Span", Counted)
+    t = Tracer("svc")  # TRACER_HOST unset: no exporter
+    assert t.record_span("tpu.decode", 1.0, 2.0, trace_id="ab" * 16,
+                         attributes={"slot": 0}) is None
+    assert built == []
+    t.exporter = InMemoryExporter()
+    s = t.record_span("tpu.decode", 1.0, 2.0, trace_id="ab" * 16,
+                      attributes={"slot": 0})
+    assert built == [1] and t.exporter.spans == [s]
+    assert (s.name, s.trace_id, s.parent_id) == ("tpu.decode", "ab" * 16, None)
+    assert s.duration_us == 1_000_000 and s.attributes == {"slot": 0}
+
+
+def test_a_forked_child_seeds_the_ids_again(monkeypatch):
+    """Two workers forked from one parent must not hand out the same
+    trace ids: the hook registered for the child draws a new seed (run
+    here by hand: forking a process that holds JAX's threads is unsafe)."""
+    import os
+
+    from gofr_tpu import tracing
+
+    tracing._ids.seed(1234)
+    parent_next = tracing._new_trace_id()
+    tracing._ids.seed(1234)  # the state a fork would copy
+    monkeypatch.setattr(os, "urandom", lambda n: b"\x07" * n)
+    tracing._seed_ids()      # what os.register_at_fork runs in the child
+    assert tracing._new_trace_id() != parent_next
+    monkeypatch.undo()
+    tracing._seed_ids()
+
+
+# -- the serving loop's spans: as before with an exporter, none without -------
+
+def _serve_three(observe):
+    import jax
+
+    from gofr_tpu.models import LLAMA_CONFIGS, llama
+    from gofr_tpu.tpu import GenerationEngine
+
+    cfg = LLAMA_CONFIGS["tiny"]
+    eng = GenerationEngine(cfg, llama.init(cfg, jax.random.PRNGKey(0)),
+                           slots=2, max_seq=64, prompt_buckets=(8, 16),
+                           observe=observe)
+    try:
+        streams = [eng.generate([3, 1, 4, 1 + i], max_new_tokens=6)
+                   for i in range(3)]
+        assert all(len(s.tokens()) == 6 for s in streams)
+        # the terminal (and the spans made there) follows the last token
+        deadline = time.monotonic() + 5.0
+        while observe.requests.snapshot() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        return streams
+    finally:
+        eng.close()
+
+
+def test_stage_spans_with_an_exporter_are_as_before():
+    """tpu.admit-wait, tpu.prefill and tpu.decode: one each a request, in
+    the request's trace, back to back, with the attributes they always
+    had. They are made at the request's terminal from the stamps in
+    stream.trace, which is also what the wide event reports."""
+    from gofr_tpu.observe import Observe
+
+    exp = InMemoryExporter()
+    streams = _serve_three(Observe(tracer=Tracer("svc", exporter=exp)))
+    for s in streams:
+        mine = {sp.name: sp for sp in exp.spans if sp.trace_id == s.trace_id}
+        assert set(mine) == {"tpu.admit-wait", "tpu.prefill", "tpu.decode"}
+        wait, prefill, decode = (mine["tpu.admit-wait"], mine["tpu.prefill"],
+                                 mine["tpu.decode"])
+        assert set(wait.attributes) == {"slot", "slo_class"}
+        assert set(prefill.attributes) == {"slot", "prompt_len", "slo_class"}
+        assert set(decode.attributes) == {"slot", "tokens", "slo_class"}
+        assert prefill.attributes["prompt_len"] == 4
+        assert decode.attributes["tokens"] == 6
+        assert decode.attributes["slot"] in (0, 1)
+        assert wait.attributes["slo_class"] == "latency"
+        t = s.trace
+        assert wait.start_ns == int(t["submit"] * 1e9)
+        assert wait.end_ns == prefill.start_ns == int(t["admit"] * 1e9)
+        assert prefill.end_ns == int(t["prefill_done"] * 1e9)
+        assert decode.start_ns == int(t["first_put"] * 1e9)
+        assert all(sp.parent_id is None and not sp.root
+                   for sp in mine.values())
+
+
+def test_no_tracing_work_on_the_generation_thread_without_an_exporter(
+        monkeypatch):
+    """TRACER_HOST unset: the tracer exists (requests still get trace
+    ids) but exports to nobody, and the generation thread never enters
+    tracing.py: no Span, no id, no record_span."""
+    import threading
+
+    from gofr_tpu import tracing
+    from gofr_tpu.observe import Observe
+
+    on_loop = []
+
+    def watched(fn):
+        def inner(*a, **k):
+            if threading.current_thread().name == "gofr-tpu-gen":
+                on_loop.append(fn.__name__)
+            return fn(*a, **k)
+        return inner
+
+    for name in ("_new_trace_id", "_new_span_id"):
+        monkeypatch.setattr(tracing, name, watched(getattr(tracing, name)))
+    for name in ("record_span", "start_span", "_on_end"):
+        monkeypatch.setattr(Tracer, name, watched(getattr(Tracer, name)))
+    monkeypatch.setattr(tracing.Span, "__init__",
+                        watched(tracing.Span.__init__))
+    streams = _serve_three(Observe(tracer=Tracer("svc")))
+    assert on_loop == []
+    # every request still has a trace id of its own
+    assert len({s.trace_id for s in streams}) == 3
+    assert all(len(s.trace_id) == 32 for s in streams)
