@@ -110,10 +110,9 @@ def bench_decode(cfg, batch: int, cache_len: int, steps: int = 64,
     params = random_params(llama.init, cfg, quant=True)
     cache = llama.init_cache(cfg, batch, cache_len, dtype=kv_dtype)
     rope = llama.get_rope_tables(cfg, cache_len)
-    # simulate prefill at the HALF-FULL point — the representative
-    # serving state. The flash-decode kernel's v3 DMA-skip streams only
-    # live tokens, so a nearly-empty cache would flatter it; the jnp
-    # path reads the full padded cache either way.
+    # simulate prefill at the HALF-FULL point: decode attention reads
+    # what is live (ops.flash_decode), so a nearly-empty cache would
+    # flatter the step
     cache = cache._replace(lengths=jnp.full((batch,), cache_len // 2,
                                             jnp.int32))
     tokens = jnp.zeros((batch,), jnp.int32)
@@ -125,23 +124,18 @@ def bench_decode(cfg, batch: int, cache_len: int, steps: int = 64,
         logits, cache = llama.decode_step(params, cfg, tokens, cache, rope)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
-    def make_multistep(flash: bool):
-        @functools.partial(jax.jit, donate_argnums=(3,))
-        def multistep(params, rope, tokens, cache):
-            def body(carry, _):
-                tokens, cache = carry
-                logits, cache = llama.decode_step(params, cfg, tokens,
-                                                  cache, rope, flash=flash)
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return (tok, cache), tok
+    @functools.partial(jax.jit, donate_argnums=(3,))
+    def multistep(params, rope, tokens, cache):
+        def body(carry, _):
+            tokens, cache = carry
+            logits, cache = llama.decode_step(params, cfg, tokens, cache,
+                                              rope)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (tok, cache), tok
 
-            (tokens, cache), toks = jax.lax.scan(body, (tokens, cache),
-                                                 None, length=decode_block)
-            return tokens, cache, toks
-
-        return multistep
-
-    multistep = make_multistep(flash=False)
+        (tokens, cache), toks = jax.lax.scan(body, (tokens, cache), None,
+                                             length=decode_block)
+        return tokens, cache, toks
 
     # np.asarray fetches the final tokens inside the timed region, which
     # transitively requires every step to have run.
@@ -180,38 +174,6 @@ def bench_decode(cfg, batch: int, cache_len: int, steps: int = 64,
     out = {"tok_s": tok_s, "fused_step_ms": fused_step_ms,
            "dispatch_step_ms": dispatch_step_ms, "batch": batch}
 
-    # A/B the flash-decode kernel (ops.flash_decode) on TPU backends:
-    # reuses the live params/cache, one extra compile. Failures report —
-    # the kernel is opt-in in serving until this number wins. The gate
-    # must be the KERNEL's own (decode_attention_auto silently falls
-    # back on disabled/odd shapes — numbers from the fallback would be
-    # baseline timings mislabeled as kernel timings).
-    from gofr_tpu.ops.flash_decode import _kernel_ok as _flash_decode_ok
-
-    q_probe = jax.ShapeDtypeStruct((batch, 1, cfg.n_heads, cfg.head_dim),
-                                   jnp.bfloat16)
-    k_probe = jax.ShapeDtypeStruct(
-        (batch, cache_len, cfg.n_kv_heads, cfg.head_dim), jnp.int8)
-    if not _flash_decode_ok(q_probe, k_probe, 128):
-        out["flash_decode_skipped"] = "kernel gate rejected backend/shapes"
-    else:
-        try:
-            ms_flash = make_multistep(flash=True)
-            tokens, cache, toks = ms_flash(params, rope, tokens, cache)
-            np.asarray(toks)
-            t0 = time.perf_counter()
-            for _ in range(max(1, blocks // 2)):
-                tokens, cache, toks = ms_flash(params, rope, tokens, cache)
-            np.asarray(toks)
-            fdt = time.perf_counter() - t0
-            n = max(1, blocks // 2) * decode_block
-            out["flash_decode_tok_s"] = batch * n / fdt
-            out["flash_decode_step_ms"] = fdt / n * 1e3
-            log(f"  flash-decode kernel: {out['flash_decode_tok_s']:.0f} "
-                f"tok/s ({out['flash_decode_step_ms']:.2f} ms/step)")
-        except Exception as e:
-            out["flash_decode_error"] = f"{type(e).__name__}: {str(e)[:160]}"
-            log(f"  flash-decode A/B failed: {out['flash_decode_error']}")
     return out
 
 
@@ -981,17 +943,8 @@ def main() -> int:
     if "fused_step_ms" in res:
         payload["fused_step_ms"] = round(res["fused_step_ms"], 2)
         payload["dispatch_step_ms"] = round(res["dispatch_step_ms"], 2)
-    # flash-decode numbers ride along as separate fields — the headline
-    # stays the path the DEFAULT engine actually runs (jnp reference);
-    # promoting the kernel to headline requires flipping the engine
-    # default first (it is opt-in via GOFR_FLASH_DECODE until hardware
-    # timings validate it).
-    for k in ("flash_decode_tok_s", "flash_decode_step_ms"):
-        if k in res:
-            payload[k] = round(res[k], 2)
-    for k in ("flash_decode_error", "flash_smoke"):
-        if k in res:
-            payload[k] = res[k]
+    if "flash_smoke" in res:
+        payload["flash_smoke"] = res["flash_smoke"]
     # snapshot: if a runner kills the remaining (slower) sections, the
     # stream still ends with a parsable headline line; the complete
     # payload re-emits at the end and supersedes this one.

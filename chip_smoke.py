@@ -161,18 +161,23 @@ def kernel_cases(interpret: bool = False):
 
     def decode(quant):
         def run():
-            lengths = jnp.asarray([0, 1, 127, 128, 129, 500, 1000, 1023],
+            # read through a stacked cache [L, B, Smax, KV, D] at its last
+            # layer, as the serving step does; two dead slots
+            lengths = jnp.asarray([0, 1, 127, 128, 129, 500, 0, 1023],
                                   jnp.int32)
             q, kn, vn = (rand(4, (b, 1, H, D)), rand(5, (b, 1, KV, D)),
                          rand(6, (b, 1, KV, D)))
-            kc, vc = rand(7, (b, smax, KV, D)), rand(8, (b, smax, KV, D))
+            kc, vc = (rand(7, (2, b, smax, KV, D)),
+                      rand(8, (2, b, smax, KV, D)))
             ks = vs = None
             if quant:
                 (kc, ks), (vc, vs) = quantize_kv(kc), quantize_kv(vc)
-            got = flash_decode.flash_decode_appended(
-                q, kc, vc, kn, vn, lengths, ks, vs, interpret=interpret)
+            got = flash_decode.flash_decode_stacked(
+                q, kc, vc, kn, vn, lengths, jnp.int32(1), ks, vs,
+                block_s=flash_decode.block_size(smax), interpret=interpret)
             ref = attention.decode_attention_appended(
-                q, kc, vc, kn, vn, lengths, ks, vs)
+                q, kc[1], vc[1], kn, vn, lengths,
+                None if ks is None else ks[1], None if vs is None else vs[1])
             return _max_err(got, ref)
         return run
 
@@ -203,8 +208,8 @@ def kernel_cases(interpret: bool = False):
             ("flash_causal_prefill[S=512]", prefill(512)),
             ("paged_decode_attention[int8,T=128]", paged(1)),
             ("paged_window_attention[int8,T=128,W=5]", paged(5)),
-            ("flash_decode_appended[int8]", decode(True)),
-            ("flash_decode_appended[bf16]", decode(False))]
+            ("flash_decode_stacked[int8]", decode(True)),
+            ("flash_decode_stacked[bf16]", decode(False))]
 
 
 def phase_kernels(summary: dict, rec: dict) -> None:
@@ -312,7 +317,7 @@ def count_kernel_traces() -> dict[str, int]:
     counts: dict[str, int] = {}
     for mod, names in ((flash, ("flash_causal_prefill",
                                 "flash_prefill_sharded")),
-                       (flash_decode, ("flash_decode_appended",
+                       (flash_decode, ("flash_decode_stacked",
                                        "flash_decode_sharded")),
                        (paged_attention, ("paged_decode_attention",
                                           "paged_window_attention",
@@ -521,7 +526,8 @@ def _drive(app, summary: dict, rec: dict, rng) -> None:
 
     # on a TPU the serving programs carry the kernels, not their jnp
     # fallbacks: flash prefill from bucket 256 up, the paged kernels on
-    # a block pool, each in its shard_map'd form on a mesh
+    # a block pool, flash decode where the engine says its shapes take
+    # it, each in its shard_map'd form on a mesh
     traced = rec["kernels_traced"]
     log(f"  kernels traced into serving programs: {traced}")
     if summary["device"]["platform"] == "tpu":
@@ -535,6 +541,9 @@ def _drive(app, summary: dict, rec: dict, rng) -> None:
                       "paged_decode_attention": "paged_decode_sharded",
                       "paged_window_attention": "paged_window_sharded"}[k]
                      for k in list(want)]
+        if gen.get("decode_kv_block"):
+            want.append("flash_decode_sharded" if "mesh" in gen
+                        else "flash_decode_stacked")
         missing = [k for k in want if not traced[k]]
         if missing:
             raise AssertionError(f"serving never traced {missing}: a jnp "

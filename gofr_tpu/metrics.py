@@ -414,6 +414,8 @@ def register_framework_metrics(m: Manager) -> None:
                     "time a request waits for a batch in seconds",
                     TPU_BUCKETS)
     m.new_gauge("app_tpu_batch_fill", "fraction of batch slots occupied at dispatch")
+    m.new_gauge("app_tpu_decode_kv_read_ratio",
+                "KV positions a decode step's attention fetches / positions the slots reserve")
     m.new_counter("app_tpu_requests_total", "total TPU predict requests")
     m.new_counter("app_tpu_tokens_generated_total", "total generated tokens")
     m.new_counter("app_tpu_prefix_cache_hits_total",

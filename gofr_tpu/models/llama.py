@@ -720,67 +720,85 @@ def multi_request_serving_config(cfg: ModelConfig) -> ModelConfig:
 
 
 def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
-                cache: KVCache, rope_tables=None, flash: bool = False,
-                adapter=None, mesh=None) -> tuple[jnp.ndarray, KVCache]:
+                cache: KVCache, rope_tables=None, adapter=None, mesh=None,
+                active: jnp.ndarray | None = None
+                ) -> tuple[jnp.ndarray, KVCache]:
     """One decode step for tokens [B] against the cache.
 
     Returns (logits [B, V] f32, updated cache with lengths+1).
 
-    ``flash=True`` routes attention through the Pallas flash-decode
-    kernel (ops.flash_decode) when backend+shapes allow — the cache
-    streams from HBM exactly once, int8 on the wire. On sharded jits
-    pass ``mesh`` as well: the kernel then runs under shard_map per
-    head/batch shard (a bare pallas_call does not partition under
-    GSPMD); the jnp reference stays the default and the fallback.
+    Decode is HBM-bound, so the cache is READ-ONLY inside the layer loop
+    (the current token's k/v ride alongside, see
+    ``decode_attention_appended``), and the per-layer new-token k/v, the
+    only novel data, [L, B, KV, hd], is written by ONE scatter into the
+    donated buffers after the loop. Emitting updated cache slices as scan
+    outputs instead would rewrite the entire cache every token.
 
-    Decode is HBM-bound, so the cache is READ-ONLY inside the layer scan
-    (scan ``xs`` slicing reads each layer's [B, Smax, KV, hd] in place; the
-    current token's k/v ride alongside via ``decode_attention_appended``),
-    and the per-layer new-token k/v — the only novel data, [L, B, KV, hd] —
-    is written by ONE scatter into the donated buffers after the scan.
-    Emitting updated cache slices as scan outputs instead would rewrite the
-    entire cache every token and dominate the step's HBM traffic.
+    Which attention reads the cache is chosen from what can be observed,
+    with no setting (ops.flash_decode.kernel_block): on a TPU, for shapes
+    the kernel takes, the loop hands the flash-decode kernel the whole
+    stacked cache and the layer index, and it fetches only each slot's
+    live blocks, in place; pass ``mesh`` on sharded jits, where the
+    kernel runs under shard_map per head/batch shard. Otherwise
+    (another backend, a head_dim that is not whole lanes, a tp that
+    splits a KV head) the scan slices each layer's [B, Smax, KV, hd]
+    for ``decode_attention_appended``; on the chip XLA materialises
+    that slice as a copy and attention then reads all Smax positions of
+    it (PERF.md, Findings PR 25), which is why it is the fallback.
+
+    ``active`` [B] bool: slots that are decoding. The kernel reads
+    nothing of a slot that is not (its output is discarded by the
+    caller), whatever its cursor holds: a retired slot's cursor stays
+    frozen at its old value in the cache. None means all are.
 
     CAPACITY CONTRACT: callers must ensure ``lengths < cache capacity``
-    before stepping — at capacity the scatter index is out of range and the
-    write is dropped (JAX scatter OOB semantics; no data-dependent errors
-    are possible under jit). The serving engine retires slots before they
-    hit capacity.
+    before stepping: at capacity the scatter index is out of range and
+    the write is dropped (JAX scatter OOB semantics; no data-dependent
+    errors are possible under jit). The serving engine retires slots
+    before they hit capacity.
     """
+    from ..ops import flash_decode
+
     # slot isolation: grouped MoE dispatch would couple batch slots
-    # (see multi_request_serving_config) — force dense at decode
+    # (see multi_request_serving_config), so decode is forced dense
     cfg = multi_request_serving_config(cfg)
-    B = tokens.shape[0]
     cos, sin = rope_tables or get_rope_tables(cfg, cache.k.shape[2])
-    positions = cache.lengths[:, None]  # [B,1] — this token's position
+    positions = cache.lengths[:, None]  # [B,1], this token's position
     lengths = cache.lengths
 
     with jax.named_scope("embed"):
         x = params["embedding"][tokens[:, None]].astype(cfg.jdtype)  # [B,1,D]
 
-    if flash:
-        import functools
-
-        from ..ops.flash_decode import decode_attention_auto
-        _decode_attn = functools.partial(decode_attention_auto, mesh=mesh)
-    else:
-        _decode_attn = decode_attention_appended
-
-    def body(x, xs):
-        layer_w, k_layer, v_layer, ks_layer, vs_layer = xs
-
-        def attend(q, k_new, v_new):
-            return _decode_attn(q, k_layer, v_layer, k_new, v_new,
-                                lengths, ks_layer, vs_layer)
-
+    def layer(x, layer_w, attend):
         x, kv_tok, _ = _layer(x, layer_w, cfg, cos, sin, positions,
                               kv_write=lambda k, v: (k, v), attend=attend,
                               adapter=adapter)
         return x, kv_tok
 
-    x, (k_toks, v_toks) = jax.lax.scan(
-        body, x, (params["layers"], cache.k, cache.v,
-                  cache.k_scale, cache.v_scale))
+    block_s = flash_decode.kernel_block(cfg.n_heads, cache.k, mesh)
+    if block_s:
+        live = lengths if active is None else jnp.where(active, lengths, 0)
+
+        def body(x, xs):
+            layer_w, li = xs
+            return layer(x, layer_w, lambda q, k_new, v_new:
+                         flash_decode.decode_attention_auto(
+                             q, cache.k, cache.v, k_new, v_new, live, li,
+                             cache.k_scale, cache.v_scale, block_s=block_s,
+                             mesh=mesh))
+
+        xs = (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32))
+    else:
+        def body(x, xs):
+            layer_w, k_layer, v_layer, ks_layer, vs_layer = xs
+            return layer(x, layer_w, lambda q, k_new, v_new:
+                         decode_attention_appended(
+                             q, k_layer, v_layer, k_new, v_new, lengths,
+                             ks_layer, vs_layer))
+
+        xs = (params["layers"], cache.k, cache.v, cache.k_scale,
+              cache.v_scale)
+    x, (k_toks, v_toks) = jax.lax.scan(body, x, xs)
     with jax.named_scope("kv_write"):
         new = _scatter_step_kv(cache, k_toks, v_toks, lengths)
     return _logits(params, cfg, x[:, 0]), new
@@ -788,18 +806,27 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
 def _scatter_step_kv(cache: KVCache, k_toks, v_toks, lengths) -> KVCache:
     """One scatter for all layers: the step's [L, B, 1, KV, hd] k/v go
-    to cache[:, b, lengths[b]]."""
+    to cache[:, b, lengths[b]]. The scales [L, B, KV] go there by a
+    select over the scale arrays, not a scatter: on the chip a scatter
+    wants its window (L, KV) minor, which for [L, B, Smax, KV] float32 is
+    a layout padded sixteenfold, and XLA then converts both arrays
+    between that and the compact one the attention reads, every step
+    (two copies of 0.6 ms each at 32 x 40 x 2,048 x 8, where the select
+    takes 0.5; PERF.md, Findings PR 25). A length at capacity matches no
+    position, so that write is dropped like the scatter's."""
     slots = jnp.arange(k_toks.shape[1])
     k_tok, v_tok = k_toks[:, :, 0], v_toks[:, :, 0]  # [L, B, KV, hd]
     if cache.quantized:
         qk, sk = quantize_kv(k_tok)
         qv, sv = quantize_kv(v_tok)
+        here = (jnp.arange(cache.k.shape[2])[None, :, None]
+                == lengths[:, None, None])[None]          # [1, B, Smax, 1]
         return KVCache(
             k=cache.k.at[:, slots, lengths].set(qk, mode="drop"),
             v=cache.v.at[:, slots, lengths].set(qv, mode="drop"),
             lengths=lengths + 1,
-            k_scale=cache.k_scale.at[:, slots, lengths].set(sk, mode="drop"),
-            v_scale=cache.v_scale.at[:, slots, lengths].set(sv, mode="drop"))
+            k_scale=jnp.where(here, sk[:, :, None, :], cache.k_scale),
+            v_scale=jnp.where(here, sv[:, :, None, :], cache.v_scale))
     return KVCache(
         k=cache.k.at[:, slots, lengths].set(
             k_tok.astype(cache.k.dtype), mode="drop"),

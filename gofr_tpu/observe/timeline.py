@@ -118,12 +118,16 @@ class Timeline:
 
     # -- typed emitters (one writer for the payload conventions) -------------
     def decode_block(self, t0: float, t1: float, slots, steps: int,
-                     live: int | None = None) -> None:
+                     live: int | None = None,
+                     fetched: int | None = None) -> None:
         """One fused decode dispatch->reap: ``slots`` is the tuple of
         active slot indices as dispatched, ``steps`` the block size,
         ``live`` the KV positions those slots held at dispatch (what
-        the step's attention has to read of the reserved pool)."""
-        self.append("decode", t0, t1 - t0, slots, steps, live)
+        the step's attention has to read of the reserved pool),
+        ``fetched`` the positions it does fetch a step (each cursor
+        rounded up to the kernel's block, or every reserved position on
+        the reference path)."""
+        self.append("decode", t0, t1 - t0, slots, steps, live, fetched)
 
     def verify_block(self, t0: float, t1: float, slots, window: int) -> None:
         self.append("verify", t0, t1 - t0, slots, window)
@@ -313,7 +317,7 @@ class Timeline:
                                  "dur": max(dur, 0.0) * 1e6,
                                  "args": {"slots": len(a or ()),
                                           "steps": b, "live_tokens": c,
-                                          "seq": seq}})
+                                          "kv_fetched": d, "seq": seq}})
             elif kind == "prefill":
                 body.append({"ph": "X", "pid": 1, "tid": slot_tid(a),
                              "name": f"prefill L={b}", "cat": "prefill",

@@ -88,10 +88,17 @@ def decode_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
 
     Mathematically identical to writing the token at position ``lengths``
     and calling ``decode_attention`` with lengths+1, but lets the serving
-    step keep the cache read-only inside the layer scan (XLA slices it per
-    layer with zero copies) and defer all writes to one post-scan scatter
-    on the donated buffer — the difference between ~roofline decode and
+    step keep the cache read-only inside the layer scan and defer all
+    writes to one post-scan scatter on the donated buffer, instead of
     rewriting the whole cache every token.
+
+    This is the reference, and the path for backends and shapes the
+    flash-decode kernel does not take (ops.flash_decode.kernel_block). It
+    attends over all ``Smax`` positions whatever ``lengths`` says, and
+    handed a per-layer slice of the stacked cache by ``lax.scan`` it is
+    preceded, on the chip, by a copy of that slice: XLA materialises each
+    layer's [B, Smax, KV, D] K and V (0.5 s of a 2.95 s trace, PERF.md
+    Findings PR 25), it does not read them in place.
 
     q: [B, 1, H, D]; k_cache/v_cache: [B, Smax, KV, D];
     k_new/v_new: [B, 1, KV, D]; lengths: [B] valid entries (EXCLUDING the

@@ -8,10 +8,9 @@ per-slot block TABLE (vLLM's design, rebuilt TPU-first): shapes stay
 static, the pool is sized to the expected TOTAL live tokens instead of
 batch x max_seq, and slots grow/free blocks host-side.
 
-The kernel is ops.flash_decode's v2 kernel (block-diagonal GQA, online
-softmax, int8 tiles upcast in-register) with ONE change: the K/V/scale
-index maps look the next tile up in a scalar-prefetched block table
-instead of walking the sequence linearly. Two properties the engine's
+The kernel (block-diagonal GQA, online softmax, int8 tiles upcast
+in-register) is a (slot, block) grid whose K/V/scale index maps look the
+next tile up in a scalar-prefetched block table. Two properties the engine's
 host side maintains make this fast and safe:
 
   - table rows are CLAMPED: entries past a slot's last live block repeat
@@ -53,7 +52,75 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import NEG_INF, decode_attention_appended
-from .flash_decode import _LANES, _decode_kernel
+
+_LANES = 128
+
+
+def _decode_kernel(lengths_ref, qbd_ref, k_ref, v_ref, ks_ref, vs_ref,
+                   acc_ref, m_ref, l_ref, *,
+                   block_s: int, n_kv: int, quant: bool):
+    """One (batch, block) step of the block-diagonal GQA recurrence (the
+    dense-cache kernel's second design, kept here where its grid is the
+    block table's). Scratchless: acc/m/l ARE the outputs,
+    revisited across the sequential s dimension (the output block index
+    map ignores si, so the tiles stay resident in VMEM until the last
+    s-block flushes them)."""
+    si = pl.program_id(1)
+    length = lengths_ref[pl.program_id(0)]
+    h = qbd_ref.shape[1]
+    g = h // n_kv
+
+    @pl.when(si == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    # blocks entirely past the valid prefix skip compute (the runtime
+    # still streams them; skipping the math is the available win)
+    @pl.when(si * block_s < length)
+    def _compute():
+        qbd = qbd_ref[0]                                   # [H, KV*D]
+        k_flat = k_ref[0].reshape(block_s, -1)             # [BS, KV*D]
+        v_flat = v_ref[0].reshape(block_s, -1)
+        # scores: block-diagonal q rows zero out every kv plane but kv(h),
+        # so the dense contraction equals the per-head dot
+        s = jax.lax.dot_general(
+            qbd, k_flat.astype(qbd.dtype),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [H, BS]
+        if quant:
+            ks = ks_ref[0]                                  # [KV, BS]
+            ks_h = jnp.broadcast_to(ks[:, None, :],
+                                    (n_kv, g, block_s)).reshape(h, block_s)
+            s = s * ks_h
+        pos = si * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_s), 1)                     # [1, BS]
+        s = jnp.where(pos < length, s, NEG_INF)
+
+        m_prev = m_ref[0, :, :1]                            # [H, 1]
+        l_prev = l_ref[0, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)                              # [H, BS]
+        # fully-masked blocks never reach here (pl.when), and within a
+        # reached block masked positions give exp(NEG_INF - m) = 0
+        corr = jnp.exp(m_prev - m_new)                      # [H, 1]
+        l_ref[0] = jnp.broadcast_to(
+            l_prev * corr + jnp.sum(p, axis=-1, keepdims=True), (h, _LANES))
+        m_ref[0] = jnp.broadcast_to(m_new, (h, _LANES))
+        if quant:
+            vs = vs_ref[0]                                  # [KV, BS]
+            vs_h = jnp.broadcast_to(vs[:, None, :],
+                                    (n_kv, g, block_s)).reshape(h, block_s)
+            p = p * vs_h
+        # pv contraction in q's dtype (bf16 in serving, f32 in the
+        # numerics tests) — matches decode_attention_appended's vdt.
+        # acc is [H, KV*D]; only the kv(h) slice is meaningful per row
+        # (selected after the kernel), the rest is harmless extra MACs.
+        acc_ref[0] = acc_ref[0] * corr + jax.lax.dot_general(
+            p.astype(qbd.dtype), v_flat.astype(qbd.dtype),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [H, KV*D]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -77,7 +144,7 @@ def _paged_decode_cache(q, k_pool, v_pool, table, lengths, k_scale, v_scale,
     # along sublanes for free inside the kernel
     ks_t = jnp.swapaxes(k_scale, 1, 2).astype(jnp.float32)
     vs_t = jnp.swapaxes(v_scale, 1, 2).astype(jnp.float32)
-    # block-diagonal query expansion (see ops.flash_decode docstring)
+    # block-diagonal query expansion (see _decode_kernel)
     qh = (q * (d ** -0.5)).reshape(b, n_kv, g, d)
     eye = jnp.eye(n_kv, dtype=q.dtype)
     q_bd = jnp.einsum("bkgd,kK->bgkKd", qh, eye,
@@ -153,7 +220,7 @@ def paged_decode_attention(q, k_pool, v_pool, k_new, v_new, table, lengths,
                                     k_scale, v_scale, interpret=interpret)
     m = m[..., 0]
     l = l[..., 0]
-    # fold the appended token (exact flash combination; see flash_decode)
+    # fold the appended token (the exact flash combination)
     qh = (q[:, 0] * (d ** -0.5)).reshape(b, n_kv, g, d)
     s_new = jnp.einsum("bkgd,bkd->bkg", qh,
                        k_new[:, 0].astype(qh.dtype),

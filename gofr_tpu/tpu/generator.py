@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import functools
 import itertools
-import os
 import queue
 import threading
 import time
@@ -44,6 +43,7 @@ from .. import chaos, compile_cache
 from ..errors import DeadlineExceeded
 from ..models import llama
 from ..models.common import ModelConfig
+from ..ops import flash_decode
 from ..resilience import (SLO_LATENCY, SLO_THROUGHPUT, DecodePipelinePolicy,
                           current_deadline, current_slo_class)
 from ..tenancy.fair import WeightedFairLine
@@ -611,25 +611,6 @@ class GenerationEngine:
         # post-block GIL-yield sleep ("admit window"); the env knob
         # TPU_ADMIT_WINDOW_MS keeps the name. 0 falls back to 1 ms.
         self._admit_window = max(0.0, float(admit_window_ms)) / 1e3
-        # flash-decode kernel (ops.flash_decode). FENCED, not just
-        # opt-in: the 2026-07-31 device capture (BENCH_CANDIDATE.json)
-        # measured the kernel SLOWER than the fused XLA step inside the
-        # K-step scan (2309 vs 2709 tok/s — see PERF.md "flash-decode
-        # regression"), so GOFR_FLASH_DECODE=1 alone now logs the
-        # recorded regression and stays on the XLA path;
-        # GOFR_FLASH_DECODE_FORCE=1 runs the kernel anyway (the
-        # A/B-profiling escape hatch). Mesh engines run it shard_map'd
-        # per head/batch shard (ops.flash_decode.flash_decode_sharded)
-        # under the same env gating.
-        self._flash_decode = False
-        if os.environ.get("GOFR_FLASH_DECODE") == "1":
-            if os.environ.get("GOFR_FLASH_DECODE_FORCE") == "1":
-                self._flash_decode = True
-            elif logger is not None:
-                logger.warn({"event": "GOFR_FLASH_DECODE ignored: known "
-                             "regression vs the fused XLA step (PERF.md "
-                             "2026-07-31: 2309 vs 2709 tok/s); set "
-                             "GOFR_FLASH_DECODE_FORCE=1 to run it anyway"})
         self.max_seq = min(max_seq or cfg.max_seq, cfg.max_seq)
         self.prompt_buckets = tuple(sorted(b for b in prompt_buckets
                                            if b <= self.max_seq)) or (self.max_seq,)
@@ -854,6 +835,12 @@ class GenerationEngine:
             self.cache = hbm.alloc(
                 "engine", _init_cache, owner=self, tag="cache",
                 priority=hbm.PRI_SERVING, reclaim=cache_reclaim)
+        # what a decode step's attention fetches of a slot at cursor c: c
+        # rounded up to this block where the flash-decode kernel takes
+        # these shapes, every reserved position (None) on the reference
+        # path; the paged pool has its own account
+        self._kv_block = None if self._paged else flash_decode.kernel_block(
+            cfg.n_heads, self.cache.k, mesh)
         self._slots = [_Slot() for _ in range(slots)]
         self._last_tokens = np.zeros((slots,), np.int32)
         self._active = np.zeros((slots,), bool)
@@ -1482,9 +1469,10 @@ class GenerationEngine:
         the per-token critical path entirely. Inactive cursors stay
         frozen every step (their garbage KV scatter lands at the frozen
         position, which admission either overwrites or — for parked
-        slots — drops). ``step_model(tokens, cache) -> (logits,
-        stepped)`` is the only thing that differs between the
-        contiguous and paged engines.
+        slots — drops), and attention is told which slots are active so
+        that it reads nothing of the others. ``step_model(tokens, cache,
+        active) -> (logits, stepped)`` is the only thing that differs
+        between the contiguous and paged engines.
 
         ``pack`` [B, W] int32 is the coalesced host dispatch state (one
         h2d when dirty — see _dispatch_pack); ``carry`` is the device
@@ -1534,7 +1522,7 @@ class GenerationEngine:
 
         def body(carry, _):
             tokens, active, budget, pos, cache = carry
-            logits, stepped = step_model(tokens, cache)
+            logits, stepped = step_model(tokens, cache, active)
             lengths = jnp.where(active, stepped.lengths, cache.lengths)
             stepped = stepped._replace(lengths=lengths)
             toks, lps = self._sample(logits, temps,
@@ -1575,11 +1563,11 @@ class GenerationEngine:
     def _step_fn(self, cache, params, pack, carry, key):
         adapter = pack[:, 5] if self._n_adapters else None
 
-        def step_model(tokens, cache):
+        def step_model(tokens, cache, active):
             return llama.decode_step(
                 params, self.cfg, tokens, cache,
-                rope_tables=self.rope_tables, flash=self._flash_decode,
-                adapter=adapter, mesh=self.mesh)
+                rope_tables=self.rope_tables, adapter=adapter,
+                mesh=self.mesh, active=active)
 
         return self._fused_decode_scan(cache, pack, carry, key, step_model)
 
@@ -1632,7 +1620,7 @@ class GenerationEngine:
         table = pack[:, lo:lo + self._mb]
         adapter = pack[:, 5] if self._n_adapters else None
 
-        def step_model(tokens, cache):
+        def step_model(tokens, cache, active):
             return paged_llama.paged_decode_step(
                 params, self.cfg, tokens, cache, table,
                 rope_tables=self.rope_tables, adapter=adapter,
@@ -1978,6 +1966,9 @@ class GenerationEngine:
             "queued": self._pending.qsize(),
             "draining": self._draining,
             "max_seq": self.max_seq,
+            # cache positions a flash-decode work item covers, None on
+            # the reference path (ops.flash_decode.kernel_block)
+            "decode_kv_block": self._kv_block,
             "prompt_buckets": list(self.prompt_buckets),
             "total_requests": self.total_requests,
             "total_tokens": self.total_tokens,
@@ -4603,8 +4594,15 @@ class GenerationEngine:
             # no previous dispatch to chain from — build the slot-state
             # carry from the host arrays
             self._last_dev = self._host_carry()
-        # KV positions the block's attention has to read, as dispatched
-        live = int(self._cursors[self._active].sum())
+        # KV positions the block's attention has to read, as dispatched,
+        # and those it fetches: each active cursor rounded up to the
+        # kernel's block, or all that the slots reserve
+        cursors = self._cursors[self._active]
+        live = int(cursors.sum())
+        bs = self._kv_block
+        fetched = (None if self._paged
+                   else int((-(-cursors // bs) * bs).sum()) if bs
+                   else self.n_slots * self.max_seq)
         t_dispatch = time.monotonic()
         pack = self._dispatch_pack()
         toks, lps, emitted, self._last_dev, self._key, self.cache = \
@@ -4632,12 +4630,13 @@ class GenerationEngine:
         snap_reqs = [s.request for s in self._slots]
         return _Inflight((toks, lps, emitted), functools.partial(
             self._decode_reap, toks, lps, emitted, snap_active, snap_reqs,
-            t_dispatch, live))
+            t_dispatch, live, fetched))
 
     # invoked through _Inflight.reap, always under the engine's device
     # lock (see _loop)  # gl: holds self._device_lock
     def _decode_reap(self, toks, lps, emitted, snap_active, snap_reqs,
-                     t0: float = 0.0, live: int | None = None) -> None:
+                     t0: float = 0.0, live: int | None = None,
+                     fetched: int | None = None) -> None:
         toks_np, lps_np, emit_np = jax.device_get((toks, lps, emitted))
         self._acct.phase("deliver")
         if self._tl is not None:
@@ -4646,11 +4645,15 @@ class GenerationEngine:
             self._tl.decode_block(
                 t0, time.monotonic(),
                 tuple(int(i) for i in np.flatnonzero(snap_active)),
-                self.decode_block, live)
+                self.decode_block, live, fetched)
         if self.metrics is not None:
             self.metrics.set_gauge("app_tpu_batch_fill",
                                    float(self._active.sum()) / self.n_slots,
                                    program="generate")
+            if fetched is not None:
+                self.metrics.set_gauge(
+                    "app_tpu_decode_kv_read_ratio",
+                    fetched / (self.n_slots * self.max_seq))
         # bulk-convert once: per-element int()/float() on numpy scalars
         # costs real milliseconds per reap at high slot counts
         toks_l, lps_l = toks_np.tolist(), lps_np.tolist()

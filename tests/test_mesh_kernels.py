@@ -81,13 +81,48 @@ def _counted(monkeypatch, module, name):
     return calls
 
 
-def _interpret_on(monkeypatch, flash_decode_env=False):
+def _interpret_on(monkeypatch):
     monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
-    if flash_decode_env:
-        # the contiguous decode kernel stays env-fenced (recorded device
-        # regression, PERF.md) — opt in explicitly for the kernel path
-        monkeypatch.setenv("GOFR_FLASH_DECODE", "1")
-        monkeypatch.setenv("GOFR_FLASH_DECODE_FORCE", "1")
+
+
+# -- the decode kernel's sharded arm against the reference ---------------------
+
+@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("tp,dp", [(2, 1), (4, 1), (2, 4)])
+def test_flash_decode_sharded_matches_reference(tp, dp, quant):
+    """Each device walks its own KV-head (and batch) shard of the stacked
+    cache, ragged cursors and dead slots included, and the gathered
+    output is the single-device reference's."""
+    import numpy as np
+
+    from gofr_tpu.ops.attention import decode_attention_appended
+    from gofr_tpu.ops.quant import quantize_kv
+    from gofr_tpu.parallel.sharding import attention_shard_axes
+
+    n_l, b, s, h, kv, d = 2, 8, 128, 8, 4, 128
+    ks = jax.random.split(jax.random.PRNGKey(tp), 5)
+    q = jax.random.normal(ks[0], (b, 1, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (n_l, b, s, kv, d), jnp.float32)
+    v = jax.random.normal(ks[2], (n_l, b, s, kv, d), jnp.float32)
+    k_new = jax.random.normal(ks[3], (b, 1, kv, d), jnp.float32)
+    v_new = jax.random.normal(ks[4], (b, 1, kv, d), jnp.float32)
+    sk = sv = None
+    if quant:
+        k, sk = quantize_kv(k)
+        v, sv = quantize_kv(v)
+    lens = jnp.asarray([0, 1, 31, 32, 33, 126, 0, 64], jnp.int32)
+    mesh = make_mesh(tp=tp, dp=dp, devices=jax.devices()[:tp * dp])
+    batch_axes, head_axis = attention_shard_axes(mesh, b, h, kv)
+    assert head_axis is not None and bool(batch_axes) == (dp > 1)
+    got = flash_decode.flash_decode_sharded(
+        q, k, v, k_new, v_new, lens, jnp.int32(1), sk, sv, mesh=mesh,
+        batch_axes=batch_axes, head_axis=head_axis, block_s=32,
+        interpret=True)
+    want = decode_attention_appended(
+        q, k[1], v[1], k_new, v_new, lens,
+        None if sk is None else sk[1], None if sv is None else sv[1])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
 
 
 # -- token exactness: contiguous engine ---------------------------------------
@@ -96,13 +131,14 @@ def _interpret_on(monkeypatch, flash_decode_env=False):
 @pytest.mark.parametrize("tp", [2, 4])
 def test_mesh_contiguous_token_exact(tp, kv_dtype, tiny_params, tiny4_params,
                                      monkeypatch):
-    """shard_map'd flash prefill + flash-decode v3 on a dp x tp mesh are
-    token-exact vs the single-device jnp-reference engine, fp and int8
-    KV, and the sharded kernel forms actually dispatch."""
+    """shard_map'd flash prefill + flash-decode (each device walking its
+    own KV-head and batch shard of the stacked cache) on a dp x tp mesh
+    are token-exact vs the single-device jnp-reference engine, fp and
+    int8 KV, and the sharded kernel forms actually dispatch."""
     cfg, params = _cfg_params(tp, tiny_params, tiny4_params)
     want = _tokens(_engine(cfg, params, kv_dtype=kv_dtype))
 
-    _interpret_on(monkeypatch, flash_decode_env=True)
+    _interpret_on(monkeypatch)
     prefills = _counted(monkeypatch, flash, "flash_prefill_sharded")
     decodes = _counted(monkeypatch, flash_decode, "flash_decode_sharded")
     mesh = make_mesh(tp=tp, dp=8 // tp)
