@@ -1,6 +1,16 @@
-"""The plain reference: the decoder's forward pass in straightforward
-float32 ``jax.numpy``, with no cache, no batching and no kernel, and the
-comparison that decides the numerical half of ``correct``.
+"""The comparison that decides the numerical half of ``correct``, which is
+the same code for every configuration (``compare``, ``judge``), and the
+plain reference of the DEFAULT family: the forward pass of a decoder of
+Mistral's and Mixtral's shape in straightforward float32 ``jax.numpy``,
+with no cache, no batching and no kernel.
+
+Another family's forward pass (latent attention, a router that is not
+softmax top-k, a shared expert, layers of two kinds, window layers) is a
+file of its own, ``benchmarks/references/<family>.py``, which gives
+``forward_logprobs(params, cfg, tokens, rows)`` with this file's
+signature and contract; its configuration names it under ``reference``
+as ``"module": "references/<family>.py"``, and ``run.py`` hands it to
+``compare``. A configuration that names none gets this file's.
 
 Equations as published (Mistral-7B: Jiang et al. 2023; Mixtral: Jiang et
 al. 2024, and the models' Hugging Face implementations): token embedding;
@@ -179,11 +189,13 @@ def forward_logprobs(params, cfg, tokens, rows):
                          tied=cfg.tie_embeddings), min_gap
 
 
-def compare(generator, seed: int, spec: dict) -> dict:
+def compare(generator, seed: int, spec: dict,
+            forward=forward_logprobs) -> dict:
     """Serve the seeded prompts of ``spec`` (a configuration's
     ``reference`` entry) through the engine and hold each generated
-    token's log-probability against the reference. Returns ``judge``'s
-    verdict, with every position's record under ``positions``."""
+    token's log-probability against ``forward``, the reference of the
+    configuration's family. Returns ``judge``'s verdict, with every
+    position's record under ``positions``."""
     cfg = generator.cfg
     new = int(spec["new_tokens"])
     rng = random.Random(f"{seed}/reference")
@@ -197,8 +209,8 @@ def compare(generator, seed: int, spec: dict) -> dict:
                                  f"for {new}")
         seq = prompt + [t for t, _ in served[:-1]]
         seq += [0] * (math.ceil(len(seq) / PAD) * PAD - len(seq))
-        ref, gaps = forward_logprobs(generator.params, cfg, seq,
-                                     range(n - 1, n - 1 + new))
+        ref, gaps = forward(generator.params, cfg, seq,
+                            range(n - 1, n - 1 + new))
         ref = np.asarray(ref)
         gaps = None if gaps is None else np.asarray(gaps)
         for j, (tok, lp) in enumerate(served):

@@ -7,7 +7,9 @@ One process holds the chip: it registers the cell's model configuration
 (``benchmarks/configs/<config>.json``), builds the App the way
 ``examples/tpu-token-streaming`` does (that example's ``main.py`` and
 ``configs/.env``, with the configuration file's ``env`` on top), warms the
-generation programs, holds the engine against the plain float32 reference,
+generation programs, holds the engine against the plain float32 reference
+(the forward pass the configuration names under ``reference.module``, a
+file of its family under ``benchmarks/references/``, or ``reference.py``'s),
 and then starts ``benchmarks/loadgen.py`` as a process of its own, which
 never imports JAX, to offer the cell's traffic
 (``benchmarks/traffic/<traffic>.json``) over gRPC. Every metric named in
@@ -31,6 +33,7 @@ import time
 T0 = time.monotonic()  # process start, as near as Python lets us see it
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -161,16 +164,36 @@ def metric_file(name: str, traffic: str) -> str:
     return path
 
 
+def load_file(path: str, prefix: str):
+    """The module in the file at ``path``, found by name and not by
+    import: what one metric or one model family brings is a file of its
+    own, which no file that is here has to name."""
+    spec = importlib.util.spec_from_file_location(
+        prefix + "".join(c if c.isalnum() else "_"
+                         for c in os.path.relpath(path, HERE)), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def read_metric(name: str, ctx) -> float | None:
     """The metric's reader's ``read(ctx)``: a number, or None where it
     finds nothing to read (the metric is then left out)."""
-    path = metric_file(name, ctx.traffic_name)
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + "".join(c if c.isalnum() else "_" for c in name),
-        path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return load_file(metric_file(name, ctx.traffic_name),
+                     "bench_metric_").read(ctx)
+
+
+def reference_forward(spec: dict):
+    """The float32 forward pass a configuration's ``reference`` entry
+    names: ``forward_logprobs`` of the file ``module`` says, a path under
+    ``benchmarks/`` (``references/<family>.py``), or the default family's
+    in ``reference.py`` where it names none."""
+    from benchmarks import reference
+
+    if "module" not in spec:
+        return reference.forward_logprobs
+    return load_file(os.path.join(HERE, spec["module"]),
+                     "bench_reference_").forward_logprobs
 
 
 # -- host stack samples, to name the device's idle gaps -----------------------
@@ -312,6 +335,13 @@ def main() -> int:
         os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
 
+    from gofr_tpu.models import LLAMA_CONFIGS, ModelConfig
+
+    # before JAX touches the chip: a configuration with a field this
+    # program lacks (the driver runs a new cell on the parent commit too)
+    # ends here, at once, and not in a process that holds the device
+    model_config = ModelConfig(**cfg["model_config"])
+
     if rehearsal:
         jax.config.update("jax_num_cpu_devices", 4)
     devices = jax.devices()
@@ -325,14 +355,12 @@ def main() -> int:
         return EXIT_NO_DEVICE
 
     import gofr_tpu.tpu as tpu_pkg
-    from gofr_tpu.models import LLAMA_CONFIGS, ModelConfig
 
     from benchmarks import reference
 
     if not rehearsal:
         # the program has no entry for this model and is not edited
-        LLAMA_CONFIGS[cfg["model_config"]["name"]] = \
-            ModelConfig(**cfg["model_config"])
+        LLAMA_CONFIGS[model_config.name] = model_config
     # weights from --seed: new_engine_from_config has no setting for it
     tpu_pkg.random_params = partial(tpu_pkg.random_params,
                                     seed=args.seed % (2 ** 31 - 1))
@@ -343,8 +371,10 @@ def main() -> int:
     app.run(block=False)
     proc = None
     try:
-        ref = reference.compare(gen, args.seed, dict(
-            cfg["reference"], **(rehearsal or {}).get("reference", {})))
+        ref = reference.compare(
+            gen, args.seed, dict(cfg["reference"],
+                                 **(rehearsal or {}).get("reference", {})),
+            reference_forward(cfg["reference"]))
         with open(os.path.join(out_dir, "reference.json"), "w") as f:
             json.dump(ref, f)
         del ref["positions"]  # every position's record stays in the file
@@ -390,6 +420,10 @@ def main() -> int:
         done = json.loads(rest.strip().splitlines()[-1])
         timeline = [e for e in app.container.observe.timeline.events()
                     if t_open <= e[1] < t_close]
+        if args.trace:  # beside the profiler's trace, for whoever reads it
+            with open(os.path.join(out_dir, "timeline.json"), "w") as f:
+                json.dump({"t_open": t_open, "events": timeline}, f,
+                          default=str)
     finally:
         if proc is not None and proc.poll() is None:
             proc.kill()
@@ -427,9 +461,7 @@ def main() -> int:
         timeline=timeline, trace=trace, compile_open=compile_open,
         compile_close=compile_close, prom_open=prom_open,
         prom_close=prom_close, memory=memory, engine_stats=engine_stats,
-        model={k: getattr(model, k) for k in (
-            "dim", "n_layers", "n_heads", "n_kv_heads", "ffn_dim",
-            "vocab_size", "n_experts", "tie_embeddings")},
+        model=dataclasses.asdict(model),
         slots=gen.n_slots,
         decode_block=app.config.get_int("TPU_DECODE_BLOCK", 4),
         peaks=None if rehearsal else roofline.load_peaks(device["kind"]))
@@ -447,6 +479,15 @@ def main() -> int:
         "window_compiles": [round(sec, 4) for t, sec in clock.log
                             if t_open <= t < t_close]}
     line = json.dumps(result)
+    # every number compared, beside its limit, as the last lines of stderr
+    held = ref[ref["statistic"]]
+    log(f"compared: {ref['statistic']} |logprob - reference| "
+        f"{held['logprob_err_nats']:.6f} and top-1 margin "
+        f"{held['top1_margin_nats']:.6f} nats (limit "
+        f"{ref['tolerance_nats']}); streams not as asked {len(bad)} of "
+        f"{len(window)} (limit 0); probe after the drain equals one before "
+        f"it: {probes_ok}; list exhausted: {done['exhausted']} -> correct "
+        f"{correct}")
     if rehearsal:
         log("rehearsal on", result["device"],
             "- not a measurement, no result line:")

@@ -66,6 +66,78 @@ def test_open_loop_window_holds_rate_times_seconds_due_inside_it():
     assert [r["due"] for r in reqs] == sorted(r["due"] for r in reqs)
 
 
+def _chat_rate(**over):
+    return dict(traffic.load(os.path.join(BENCH, "traffic",
+                                          "chat-rate.json")), **over)
+
+
+@pytest.mark.parametrize("seed", [7, 3_000_000_019])
+def test_strata_keep_the_whole_windows_quantiles(seed):
+    """Dealing a phase into strata changes the order and nothing else: the
+    multiset is the phase's whole quantiles of lengths and gaps, which the
+    whole-window shuffle sent before PR 27, so the offered tokens and rate
+    are what they were; two seeds differ in order."""
+    params = _chat_rate()
+    dealt = traffic.build(params, seed, 50)["requests"]
+    other = traffic.build(params, seed + 1, 50)["requests"]
+    for phase, start, length in (("ramp", -params["ramp_s"],
+                                  params["ramp_s"]), ("window", 0.0, 50.0)):
+        a = [r for r in dealt if r["phase"] == phase]
+        n = round(params["rate_req_s"] * length)
+        for key in ("prompt", "output"):
+            assert sorted(r[key] for r in a) == \
+                traffic.lognormal_quantiles(params[key + "_tokens"], n)
+        dues = [r["due"] for r in a]
+        assert sorted(y - x for x, y in zip([start] + dues, dues)) == \
+            pytest.approx(traffic.exponential_gaps(n, length), abs=1e-6)
+        b = [r for r in other if r["phase"] == phase]
+        assert [r["prompt"] for r in a] != [r["prompt"] for r in b]
+
+
+def _within_5_percent(sums):
+    return (max(sums) - min(sums)) / (sum(sums) / len(sums)) < 0.05
+
+
+@pytest.mark.parametrize("rate", [4.0, 5.5, 7.0])
+def test_the_deal_gives_every_stratum_the_same_load(rate):
+    """Prompt tokens, output tokens and seconds of every stratum agree
+    within 5%, also where the window's requests do not divide by the
+    block (some strata then hold one more)."""
+    params = _chat_rate(rate_req_s=rate)
+    n, block = round(rate * 50), params["block"]
+    for values in (traffic.lognormal_quantiles(params["prompt_tokens"], n),
+                   traffic.lognormal_quantiles(params["output_tokens"], n),
+                   traffic.exponential_gaps(n, 50.0)):
+        dealt = traffic.strata(values, block)
+        assert sorted(v for s in dealt for v in s) == sorted(values)
+        assert {len(s) for s in dealt} <= {n // len(dealt),
+                                           n // len(dealt) + 1}
+        assert _within_5_percent([sum(s) for s in dealt])
+
+
+def test_the_schedule_is_its_strata_back_to_back():
+    """At the cell's own rate every ``block`` consecutive requests of the
+    window, on any seed, are one stratum of each list."""
+    params = _chat_rate()
+    block = params["block"]
+    window = [r for r in traffic.build(params, 11, 50)["requests"]
+              if r["phase"] == "window"]
+    assert len(window) % block == 0
+    ends = [0.0] + [r["due"] for r in window[block - 1::block]]
+    assert _within_5_percent([b - a for a, b in zip(ends, ends[1:])])
+    for key in ("prompt", "output"):
+        assert _within_5_percent([sum(r[key] for r in window[i:i + block])
+                                  for i in range(0, len(window), block)])
+
+
+def test_a_block_as_long_as_the_phase_is_one_stratum():
+    assert sorted(traffic._dealt([3, 1, 2], 8, 5, "x")) == [1, 2, 3]
+    assert traffic.strata([3, 1, 2], 8) == [[3, 2, 1]]
+    dealt = traffic.strata([1, 2, 3, 4, 5, 6, 7], 3)
+    assert sorted(len(s) for s in dealt) == [3, 4]
+    assert sorted(v for s in dealt for v in s) == [1, 2, 3, 4, 5, 6, 7]
+
+
 def test_lengths_stay_inside_their_clip_and_the_cache():
     for mix in MIXES:
         params = traffic.load(os.path.join(BENCH, "traffic", mix + ".json"))
@@ -226,13 +298,42 @@ def test_every_name_in_benchmark_json_has_its_file():
     assert len(four) <= max(1, len(b["workloads"]) // 4)
 
 
-def test_config_files_state_what_the_model_config_runs():
+def _configs():
     for c in _bench()["configs"]:
         with open(os.path.join(REPO, c["file"])) as f:
-            cfg = json.load(f)
+            yield c, json.load(f)
+
+
+def test_config_files_state_what_any_configuration_states():
+    """What holds whatever the model's family."""
+    for c, cfg in _configs():
         mc = cfg["model_config"]
         assert cfg["source"] == c["source"]
         assert cfg["reduced"] == c["reduced"]
+        assert set(cfg["reduced"]) == set(cfg["reduced_why"]), c["name"]
+        assert mc["max_seq"] == int(cfg["env"]["TPU_MAX_SEQ"])
+        assert cfg["chips"] in (1, 4)
+        ref = cfg["reference"]
+        assert 0 < ref["tolerance_nats"] <= 0.5
+        assert ref["statistic"] in ("worst", "median")
+        # one prompt past the largest prefill bucket: the chunked path
+        assert max(ref["prompt_tokens"]) > 512
+        assert max(ref["prompt_tokens"]) + ref["new_tokens"] < mc["max_seq"]
+        module = ref.get("module")
+        if module is not None:
+            assert module.startswith("references/") and \
+                os.path.isfile(os.path.join(BENCH, module)), c["name"]
+
+
+def test_default_family_config_files_state_what_the_model_config_runs():
+    """The identities of Mistral's and Mixtral's shape, for the
+    configurations on the default reference (``reference.py``). One with a
+    ``reference.module`` of its own maps its published keys to its
+    ``model_config`` in its own test file (below)."""
+    for c, cfg in _configs():
+        if "module" in cfg["reference"]:
+            continue
+        mc = cfg["model_config"]
         assert mc["dim"] == cfg["hidden_size"]
         assert mc["ffn_dim"] == cfg["intermediate_size"]
         assert mc["n_layers"] == cfg["num_hidden_layers"]
@@ -241,16 +342,67 @@ def test_config_files_state_what_the_model_config_runs():
         assert mc["vocab_size"] == cfg["vocab_size"]
         assert mc["dim"] // mc["n_heads"] == cfg["head_dim"]
         assert mc["n_experts"] == cfg.get("num_local_experts", 0)
-        assert mc["max_seq"] == cfg["max_position_embeddings"] == \
-            int(cfg["env"]["TPU_MAX_SEQ"])
-        assert cfg["chips"] in (1, 4)
-        ref = cfg["reference"]
-        assert 0 < ref["tolerance_nats"] <= 0.5
-        # one prompt past the largest prefill bucket: the chunked path
-        assert max(ref["prompt_tokens"]) > 512
-        assert max(ref["prompt_tokens"]) + ref["new_tokens"] < mc["max_seq"]
+        assert mc["max_seq"] == cfg["max_position_embeddings"]
         # a dense model is held to its worst position (reference.py)
-        assert ref["statistic"] == ("median" if mc["n_experts"] else "worst")
+        assert cfg["reference"]["statistic"] == \
+            ("median" if mc["n_experts"] else "worst")
+
+
+def test_a_configuration_with_its_own_reference_has_its_own_test_file():
+    """``tests/test_<config>.py`` (held counts against published counts,
+    the deployment's chips a layer): the file-per-name rule the metrics
+    have, for a family whose identities this file cannot know."""
+    for c, cfg in _configs():
+        if "module" in cfg["reference"]:
+            assert os.path.isfile(os.path.join(
+                HERE, "test_" + c["name"].replace("-", "_").replace(".", "_")
+                + ".py")), c["name"]
+
+
+PINNED = {  # the names each cell reports on the tree PR 27 started from
+    "mistral-7b-int8.batch-sat": (
+        ["out_tok_s", "setup_s"],
+        ["sched.occupancy_pct", "hbm.in_use_gb", "kv.live_gb",
+         "decode.step_ms", "decode_step_roofline", "device.idle_pct",
+         "setup.compile_s", "window.compiles", "sched.dry_pct",
+         "sched.dry_admit_pct", "sched.host_busy_pct", "kv.pool_fill_pct",
+         "attn.kv_read_pct"]),
+    "mistral-7b-int8.chat-rate": (
+        ["tpot_p50_ms", "setup_s"],
+        ["gen.late_p99_ms.chat-rate", "client.ttft_p50_ms.chat-rate",
+         "client.ttft_p95_ms.chat-rate",
+         "transport.ttft_overhead_ms.chat-rate",
+         "sched.admit_wait_ms.chat-rate", "decode.step_ms.chat-rate",
+         "prefill.ms_per_ktok.chat-rate", "device.idle_pct.chat-rate",
+         "setup.compile_s", "window.compiles", "sched.dry_pct.chat-rate",
+         "sched.dry_admit_pct.chat-rate", "sched.host_busy_pct.chat-rate",
+         "transport.first_write_ms.chat-rate",
+         "transport.ingress_ms.chat-rate", "attn.kv_read_pct.chat-rate"]),
+    "mixtral-8x7b-int8-tp4.batch-sat": (
+        ["out_tok_s", "setup_s"],
+        ["sched.occupancy_pct", "hbm.in_use_gb", "kv.live_gb",
+         "decode.step_ms", "decode_step_roofline", "collective.share_pct.tp4",
+         "device.idle_pct", "setup.compile_s", "window.compiles",
+         "sched.dry_pct", "sched.dry_admit_pct", "sched.host_busy_pct",
+         "kv.pool_fill_pct", "attn.kv_read_pct"]),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PINNED))
+def test_a_cell_reports_the_metrics_it_reported(cell):
+    """Which metrics a cell reports is ``load_cell``'s rule and nothing
+    else. A change to how a cell finds its metrics that drops one is a
+    benchmark weakened (the driver's word; PR 26 lost twelve this way)."""
+    sys.path.insert(0, BENCH)
+    import run  # benchmarks/run.py
+
+    got = run.load_cell(cell)
+    end_to_end, per_layer = PINNED[cell]
+    assert [m["name"] for m in got.end_to_end] == end_to_end
+    # a later PR may append a per-layer metric to a cell that is here (PRs
+    # 24 and 25 did) and may not edit this file: nothing lost, in order
+    names = [m["name"] for m in got.per_layer]
+    assert [n for n in names if n in per_layer] == per_layer
 
 
 @pytest.mark.parametrize("statistic,ok", [("worst", False), ("median", True)])

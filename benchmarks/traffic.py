@@ -5,7 +5,9 @@ decides their order. Lengths are the quantiles of a clipped lognormal at
 (i + 1/2) / n, inter-arrival gaps the quantiles of an exponential at the
 same points, scaled so that they sum to the phase's length. The ramp and
 the window are multisets of their own, each permuted within itself, so
-the requests due inside the window are the same set on every seed.
+the requests due inside the window are the same set on every seed. Both
+loops permute in strata (``block``), so that every stretch of a run
+carries nearly the same load on every seed, and not only the run.
 
 Stdlib only: the load generator imports this and must never import JAX.
 
@@ -18,6 +20,14 @@ A traffic file (``benchmarks/traffic/<mix>.json``) holds:
   clients         closed loop: concurrent callers
   ramp_s          seconds of the same traffic before the window opens
                   (warm-up, not part of ``--seconds``)
+  block           open loop: the phase's multiset is dealt into consecutive
+                  strata of about ``block`` requests (12 is two seconds at
+                  6 req/s), each taking lengths and gaps from across the
+                  whole distribution so that the strata's sums of prompt
+                  tokens, of output tokens and of gaps agree; the seed
+                  permutes within a stratum and the order of the strata
+                  (one stratum as long as the phase lets long prompts
+                  clump in one run and spread in another)
   prompt_tokens,
   output_tokens   {"median", "sigma", "min", "max"} of a clipped lognormal
   block, blocks   closed loop: the list is ``blocks`` copies of one
@@ -66,14 +76,46 @@ def _permuted(values: list, seed: int, what: str) -> list:
     return out
 
 
+def strata(values: list, block: int) -> list[list]:
+    """``values`` dealt into ``len(values) // block`` strata of ``block``
+    values, or one more where they do not divide, with sums as equal as
+    the greedy deal makes them: largest first, each to the stratum that
+    holds least and still has room. A stratum that drew from the tail is
+    filled up from the small end, so each holds values from across the
+    whole distribution (an exponential's longest gap is three quarters of
+    a stratum by itself: a deal in plain rounds cannot balance it)."""
+    n_strata = max(1, len(values) // block)
+    base, extra = divmod(len(values), n_strata)
+    room = [base + (s < extra) for s in range(n_strata)]
+    out: list[list] = [[] for _ in range(n_strata)]
+    sums = [0.0] * n_strata
+    for v in sorted(values, reverse=True):
+        s = min((s for s in range(n_strata) if len(out[s]) < room[s]),
+                key=lambda s: (sums[s], s))
+        out[s].append(v)
+        sums[s] += v
+    return out
+
+
+def _dealt(values: list, block: int, seed: int, what: str) -> list:
+    """The phase's order of one quantity: its strata in an order drawn
+    from the seed, each permuted by itself (a ``block`` as long as the
+    phase makes one stratum: the whole phase permuted)."""
+    return [v for s, stratum in enumerate(_permuted(strata(values, block),
+                                                    seed, what))
+            for v in _permuted(stratum, seed, f"{what}/{s}")]
+
+
 def _open_phase(params: dict, seed: int, length_s: float, phase: str,
                 t0: float) -> list[dict]:
     n = max(1, round(params["rate_req_s"] * length_s))
-    gaps = _permuted(exponential_gaps(n, length_s), seed, phase + "/gaps")
-    prompts = _permuted(lognormal_quantiles(params["prompt_tokens"], n),
-                        seed, phase + "/prompts")
-    outputs = _permuted(lognormal_quantiles(params["output_tokens"], n),
-                        seed, phase + "/outputs")
+    block = int(params["block"])
+    gaps = _dealt(exponential_gaps(n, length_s), block, seed,
+                  phase + "/gaps")
+    prompts = _dealt(lognormal_quantiles(params["prompt_tokens"], n), block,
+                     seed, phase + "/prompts")
+    outputs = _dealt(lognormal_quantiles(params["output_tokens"], n), block,
+                     seed, phase + "/outputs")
     reqs, t = [], t0
     for g, p, o in zip(gaps, prompts, outputs):
         t += g
