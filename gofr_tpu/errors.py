@@ -120,6 +120,19 @@ class ShardingConfigError(GofrError, ValueError):
         self.sharding_row = sharding_row
 
 
+class UnsupportedOptions(GofrError, ValueError):
+    """Serving options the model's family does not run, refused at engine
+    construction. ``refused`` holds (engine option, reason) pairs in the
+    constructor's names; ``new_engine_from_config`` says them again in
+    the configuration's (``TPU_*``)."""
+
+    def __init__(self, refused: list[tuple[str, str]], who: str = ""):
+        super().__init__(
+            f"{who or 'this model family'} does not run: "
+            + "; ".join(f"{opt}: {why}" for opt, why in refused))
+        self.refused = refused
+
+
 class InternalServerError(HTTPError):
     status_code = 500
 

@@ -416,6 +416,13 @@ def register_framework_metrics(m: Manager) -> None:
     m.new_gauge("app_tpu_batch_fill", "fraction of batch slots occupied at dispatch")
     m.new_gauge("app_tpu_decode_kv_read_ratio",
                 "KV positions a decode step's attention fetches / positions the slots reserve")
+    m.new_updown_counter(
+        "app_tpu_moe_expert_tokens",
+        "(token, held expert) assignments the decode steps' expert layers "
+        "made, all routed layers")
+    m.new_gauge("app_tpu_moe_experts_idle_ratio",
+                "share of (step, routed layer, held expert) cells of the "
+                "last decode block that got no token")
     m.new_counter("app_tpu_requests_total", "total TPU predict requests")
     m.new_counter("app_tpu_tokens_generated_total", "total generated tokens")
     m.new_counter("app_tpu_prefix_cache_hits_total",

@@ -9,7 +9,19 @@ layer axis. No torch, no module classes — params are data, which is what
 """
 
 from .common import ModelConfig, LLAMA_CONFIGS, BERT_CONFIGS, VIT_CONFIGS
-from . import llama, bert, vit
+from . import llama, bert, vit, deepseek_v3
+
+
+def family(cfg: ModelConfig):
+    """The module that holds a decoder configuration's programs and its
+    cache row layout, chosen from what the configuration says (a name
+    tells nothing: the benchmark registers configurations the program
+    has never heard of). Every family gives the generator the same entry
+    points: ``init``, ``init_cache``, ``get_rope_tables``, ``prefill_kv``,
+    ``write_kv``, ``prefill_chunk``, ``decode_step``, ``decode_kv_block``,
+    ``kv_layout``, ``unsupported_options``, ``serving_stats``."""
+    return deepseek_v3 if cfg.kv_lora_rank > 0 else llama
+
 
 __all__ = ["ModelConfig", "LLAMA_CONFIGS", "BERT_CONFIGS", "VIT_CONFIGS",
-           "llama", "bert", "vit"]
+           "llama", "bert", "vit", "deepseek_v3", "family"]

@@ -36,6 +36,29 @@ class ModelConfig:
     # per-expert buffer capacity factor*T*k/E (tokens over capacity drop
     # — the standard Switch/Mixtral trade at scale)
     moe_capacity_factor: float = 0.0
+    # latent attention (models/deepseek_v3.py; kv_lora_rank > 0 selects
+    # that family): the cache holds one row of kv_lora_rank +
+    # qk_rope_head_dim values a token a layer, shared by every head.
+    # Query/key width (nope + rope) and value width are separate keys
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # that family's expert layer: a sigmoid router over n_experts, kept
+    # to the topk_groups best of n_expert_groups groups, its weights
+    # renormalised and scaled by routed_scaling; experts of width
+    # moe_ffn_dim, n_shared_experts of them always on; the first
+    # n_dense_layers layers are dense SwiGLU of width ffn_dim.
+    # n_experts_held: the experts THIS chip holds (ids 0..n-1; 0 = all)
+    # of an expert-parallel deployment; the router stays n_experts wide
+    n_expert_groups: int = 1
+    topk_groups: int = 1
+    routed_scaling: float = 1.0
+    n_shared_experts: int = 0
+    moe_ffn_dim: int = 0
+    n_dense_layers: int = 0
+    n_experts_held: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -75,6 +98,21 @@ LLAMA_CONFIGS = {
                             ffn_dim=128, max_seq=128, rope_theta=10000.0,
                             dtype="float32", n_experts=4,
                             experts_per_token=2),
+    # the latent-attention family at test size: every rank and head width
+    # differs from every other, so a swapped width fails a shape check
+    "tiny-mla-moe": ModelConfig(
+        name="tiny-mla-moe", vocab_size=256, dim=64, n_layers=3, n_heads=4,
+        n_kv_heads=4, ffn_dim=160, max_seq=128, rope_theta=10000.0,
+        norm_eps=1e-6, dtype="float32",
+        rope_scaling={"rope_type": "yarn", "factor": 4.0,
+                      "original_max_position_embeddings": 32,
+                      "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                      "mscale_all_dim": 1},
+        q_lora_rank=48, kv_lora_rank=36, qk_nope_head_dim=24,
+        qk_rope_head_dim=8, v_head_dim=20, n_experts=16,
+        experts_per_token=4, n_expert_groups=4, topk_groups=2,
+        routed_scaling=2.5, n_shared_experts=1, moe_ffn_dim=40,
+        n_dense_layers=1, n_experts_held=4),
 }
 
 BERT_CONFIGS = {

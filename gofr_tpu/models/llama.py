@@ -85,6 +85,29 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int | None = None,
     )
 
 
+def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
+    """(heads, values a head) of a cached token's K (and V)."""
+    return cfg.n_kv_heads, cfg.head_dim
+
+
+def decode_kv_block(cfg: ModelConfig, cache: KVCache, mesh=None):
+    """Cache positions a decode work item covers, None on the reference
+    path (ops.flash_decode.kernel_block)."""
+    from ..ops import flash_decode
+
+    return flash_decode.kernel_block(cfg.n_heads, cache.k, mesh)
+
+
+def unsupported_options(**_) -> list:
+    """This family runs every serving option (models.family)."""
+    return []
+
+
+def serving_stats(cfg: ModelConfig, slots: int) -> dict:
+    """Nothing of these programs is said in the engine's stats."""
+    return {}
+
+
 def init(cfg: ModelConfig, key) -> dict:
     """Random-init params; same pytree layout a checkpoint loader fills."""
     dt = cfg.jdtype

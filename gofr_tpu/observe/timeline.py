@@ -110,24 +110,33 @@ class Timeline:
 
     # -- the hot append ------------------------------------------------------
     def append(self, kind: str, ts: float, dur, a=None, b=None, c=None,
-               d=None) -> None:
+               d=None, *more) -> None:
+        """``more``: fields a kind appends after its four (an event is
+        then longer than eight; readers index, and test the length)."""
         if not self.enabled:
             return
         i = next(self._seq)
-        self._buf[i & self._mask] = (i, ts, dur, kind, a, b, c, d)
+        self._buf[i & self._mask] = (i, ts, dur, kind, a, b, c, d, *more)
 
     # -- typed emitters (one writer for the payload conventions) -------------
     def decode_block(self, t0: float, t1: float, slots, steps: int,
                      live: int | None = None,
-                     fetched: int | None = None) -> None:
+                     fetched: int | None = None,
+                     assigned: int | None = None,
+                     touched: int | None = None) -> None:
         """One fused decode dispatch->reap: ``slots`` is the tuple of
         active slot indices as dispatched, ``steps`` the block size,
         ``live`` the KV positions those slots held at dispatch (what
         the step's attention has to read of the reserved pool),
         ``fetched`` the positions it does fetch a step (each cursor
         rounded up to the kernel's block, or every reserved position on
-        the reference path)."""
-        self.append("decode", t0, t1 - t0, slots, steps, live, fetched)
+        the reference path). Appended where the model has an expert
+        layer that counts them: ``assigned``, the (token, held expert)
+        assignments the block's steps made over all routed layers, and
+        ``touched``, the (step, layer, expert) cells that got at least
+        one (each is one expert's weights read)."""
+        self.append("decode", t0, t1 - t0, slots, steps, live, fetched,
+                    *(() if assigned is None else (assigned, touched)))
 
     def verify_block(self, t0: float, t1: float, slots, window: int) -> None:
         self.append("verify", t0, t1 - t0, slots, window)
@@ -304,7 +313,7 @@ class Timeline:
             return tid
 
         body: list[dict] = []
-        for seq, ts, dur, kind, a, b, c, d in events:
+        for seq, ts, dur, kind, a, b, c, d, *more in events:
             us = ts * 1e6
             if kind in ("decode", "verify"):
                 # fan one dispatch out to a slice per active slot — the
@@ -317,7 +326,10 @@ class Timeline:
                                  "dur": max(dur, 0.0) * 1e6,
                                  "args": {"slots": len(a or ()),
                                           "steps": b, "live_tokens": c,
-                                          "kv_fetched": d, "seq": seq}})
+                                          "kv_fetched": d, "seq": seq,
+                                          **dict(zip(("moe_assigned",
+                                                      "moe_touched"),
+                                                     more))}})
             elif kind == "prefill":
                 body.append({"ph": "X", "pid": 1, "tid": slot_tid(a),
                              "name": f"prefill L={b}", "cat": "prefill",

@@ -1,0 +1,259 @@
+"""Latent (MLA) attention over a cache of one row a token a layer.
+
+The cache row is ``[c_kv | k_pe]``: the ``rank`` values of the normed
+latent and the rotated key that all heads share. Keys and values a head
+are never stored: the query is *absorbed* (``q_nope . W_UK^T``, done by
+the caller) so that a head's score against a cached row is one dot
+product over the row, ``[q_abs | q_pe] . [c_kv | k_pe]``, and its output
+is the probability-weighted sum of the rows' first ``rank`` values, which
+the caller takes through ``W_UV``. Every head reads the same row, so the
+step streams ``width`` values a token a layer whatever the head count.
+
+Three shapes of it, all float32 scores and softmax:
+
+``prefill_attention``  expanded, causal, a whole prompt (keys and values
+    a head materialised over the prompt only): the reference form.
+``chunk_attention``    a chunk attends absorbed to the rows cached
+    before it and expanded to its own tokens, one softmax over both.
+``decode_attention``   one token a slot, absorbed, the appended row
+    riding beside the cache. On a TPU, for shapes the kernel takes
+    (``decode_block``), the Pallas kernel below is handed the whole
+    stacked cache ``[L, B, Smax, width]`` and the layer index and
+    fetches each slot's rows from 0 to its cursor rounded up to the
+    block, once and in place: nothing past it, nothing for a slot of
+    length 0, no layer copy (ops/flash_decode.py's work list and
+    double-buffered DMA; one row serves scores and values).
+
+Query scale: the caller multiplies the queries by the softmax scale,
+``(nope + rope)^-1/2`` times YaRN's factor; nothing here knows it.
+
+Stored width: the HBM tile is 128 lanes wide, so XLA stores a minor
+dimension of 576 in 640 lanes whatever the program says, and Mosaic
+cannot slice a tile-unaligned minor dimension of an HBM array at all.
+The caller therefore stores rows ``width`` wide with ``width`` a whole
+number of lanes (576 values, then zeros to 640) and pads ``q_cat`` and
+the appended row with zeros to match; a zero lane adds nothing to a
+score, and the values are the row's first ``rank`` lanes.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .attention import NEG_INF
+from .flash_decode import (_LANES, _N_BUF, _SUBLANES, _work_list,
+                           block_size)
+
+
+@jax.named_scope("mla/prefill_attn")
+def prefill_attention(q, k_nope, k_pe, v, mask=None):
+    """Expanded causal attention. q [B, S, H, dn + dr] (scaled);
+    k_nope [B, S, H, dn]; k_pe [B, S, dr], one for all heads;
+    v [B, S, H, dv]; mask [B, S] valid tokens. Returns [B, S, H, dv]."""
+    s = q.shape[1]
+    dn = k_nope.shape[-1]
+    scores = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :dn], k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bqhd,bkd->bhqk", q[..., dn:], k_pe,
+                           preferred_element_type=jnp.float32))
+    keep = jnp.tril(jnp.ones((s, s), bool))[None, None]
+    if mask is not None:
+        keep = keep & mask[:, None, None, :]
+    probs = jax.nn.softmax(jnp.where(keep, scores, NEG_INF), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
+
+
+@jax.named_scope("mla/chunk_attn")
+def chunk_attention(q_cat, q, rows, start, k_nope, k_pe, v, rank: int):
+    """A chunk of C tokens at [start, start + C): absorbed over the rows
+    cached before it, expanded and causal within itself.
+
+    q_cat [B, C, H, width]: [q_abs | q_pe], scaled; q [B, C, H, dn + dr],
+    scaled; rows [B, Smax, width]; k_nope/k_pe/v: the chunk's own, as in
+    ``prefill_attention``. Returns (o_lat [B, C, H, rank] float32: the
+    cached rows' part, still latent; o_new [B, C, H, dv]: the chunk's
+    part). The caller adds ``o_lat . W_UV`` and ``o_new``."""
+    c = q.shape[1]
+    smax = rows.shape[1]
+    dn = k_nope.shape[-1]
+    rows = rows.astype(q_cat.dtype)
+    s_cache = jnp.einsum("bqhw,btw->bhqt", q_cat, rows,
+                         preferred_element_type=jnp.float32)
+    s_cache = jnp.where((jnp.arange(smax) < start)[None, None, None],
+                        s_cache, NEG_INF)
+    s_new = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :dn], k_nope,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bqhd,bkd->bhqk", q[..., dn:], k_pe,
+                          preferred_element_type=jnp.float32))
+    s_new = jnp.where(jnp.tril(jnp.ones((c, c), bool))[None, None], s_new,
+                      NEG_INF)
+    probs = jax.nn.softmax(jnp.concatenate([s_cache, s_new], -1), axis=-1)
+    o_lat = jnp.einsum("bhqt,btr->bqhr", probs[..., :smax].astype(rows.dtype),
+                       rows[..., :rank], preferred_element_type=jnp.float32)
+    o_new = jnp.einsum("bhqk,bkhd->bqhd", probs[..., smax:].astype(v.dtype),
+                       v)
+    return o_lat, o_new
+
+
+def decode_attention_reference(q_cat, rows, row_new, lengths, rank: int):
+    """One token a slot over every reserved position, masked by the
+    cursor. q_cat [B, H, width] scaled; rows [B, Smax, width];
+    row_new [B, width]: this token's row, not in the cache yet;
+    lengths [B] excluding it. Returns o_lat [B, H, rank] in q's dtype."""
+    smax = rows.shape[1]
+    rows = rows.astype(q_cat.dtype)
+    s_cache = jnp.einsum("bhw,btw->bht", q_cat, rows,
+                         preferred_element_type=jnp.float32)
+    s_cache = jnp.where(jnp.arange(smax)[None, None] < lengths[:, None, None],
+                        s_cache, NEG_INF)
+    s_new = jnp.einsum("bhw,bw->bh", q_cat, row_new,
+                       preferred_element_type=jnp.float32)[..., None]
+    probs = jax.nn.softmax(jnp.concatenate([s_cache, s_new], -1), axis=-1)
+    o = (jnp.einsum("bht,btr->bhr", probs[..., :smax].astype(rows.dtype),
+                    rows[..., :rank], preferred_element_type=jnp.float32)
+         + probs[..., smax:] * row_new[:, None, :rank].astype(jnp.float32))
+    return o.astype(q_cat.dtype)
+
+
+def _decode_kernel(layer_ref, n_ref, slot_ref, blk_ref, len_ref, q_ref,
+                   new_ref, rows_hbm, o_ref, buf, m_ref, l_ref, acc_ref, sem,
+                   *, block_s: int, rank: int):
+    """One layer: walk the (slot, block) work list, fold each item. The
+    row tile serves the score matmul whole and the value matmul by its
+    first ``rank`` lanes."""
+    layer = layer_ref[0]
+    n = n_ref[0]
+
+    def copy(w, b):
+        start = pl.multiple_of(blk_ref[w] * block_s, block_s)
+        return pltpu.make_async_copy(
+            rows_hbm.at[layer, slot_ref[w], pl.ds(start, block_s)],
+            buf.at[b], sem.at[b])
+
+    @pl.when(n > 0)
+    def _first():
+        copy(0, 0).start()
+
+    def item(w, _):
+        b = w % _N_BUF
+
+        @pl.when(w + 1 < n)
+        def _next():
+            copy(w + 1, (w + 1) % _N_BUF).start()
+
+        copy(w, b).wait()
+        slot = slot_ref[w]
+        blk = blk_ref[w]
+        length = len_ref[slot]
+        q = q_ref[slot]                                      # [H, width]
+
+        @pl.when(blk == 0)
+        def _init():
+            # the appended row is the recurrence's first element
+            new = new_ref[slot].astype(jnp.float32)          # [1, width]
+            s_new = jnp.sum(q.astype(jnp.float32) * new, axis=-1,
+                            keepdims=True)                   # [H, 1]
+            m_ref[...] = jnp.broadcast_to(s_new, m_ref.shape)
+            l_ref[...] = jnp.ones_like(l_ref)
+            acc_ref[...] = jnp.broadcast_to(new[:, :rank], acc_ref.shape)
+
+        tile = buf[b]                                        # [BS, width]
+        s = jax.lax.dot_general(q, tile, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        pos = blk * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_s), 1)
+        s = jnp.where(pos < length, s, NEG_INF)              # [H, BS]
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = jnp.broadcast_to(
+            l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
+            l_ref.shape)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(tile.dtype), tile[:, :rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when((blk + 1) * block_s >= length)
+        def _done():
+            o_ref[slot] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+
+    jax.lax.fori_loop(0, n, item, None)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "block_s", "interpret"))
+def decode_attention_stacked(q_cat, rows, row_new, lengths, layer, *,
+                             rank: int, block_s: int,
+                             interpret: bool = False):
+    """``decode_attention_reference`` over layer ``layer`` of the stacked
+    cache ``rows`` [L, B, Smax, width], reading only what ``lengths``
+    (0 for a slot whose cache must not be read) says is live."""
+    b, h, width = q_cat.shape
+    smax = rows.shape[2]
+    h_pad = -(-h // _SUBLANES) * _SUBLANES
+    lengths = lengths.astype(jnp.int32)
+    n, slot, blk = _work_list(lengths, smax, block_s)
+    qp = jnp.pad(q_cat, ((0, 0), (0, h_pad - h), (0, 0)))
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block_s=block_s, rank=rank),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(1,),
+            in_specs=[vmem, vmem, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=vmem,
+            scratch_shapes=[
+                pltpu.VMEM((_N_BUF, block_s, width), rows.dtype),
+                pltpu.VMEM((h_pad, _LANES), jnp.float32),
+                pltpu.VMEM((h_pad, _LANES), jnp.float32),
+                pltpu.VMEM((h_pad, rank), jnp.float32),
+                pltpu.SemaphoreType.DMA((_N_BUF,))]),
+        out_shape=jax.ShapeDtypeStruct((b, h_pad, rank), q_cat.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=96 * 1024 * 1024),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), n, slot, blk, lengths,
+      qp.astype(rows.dtype), row_new[:, None, :].astype(rows.dtype), rows)
+    # a slot with no item never reached the kernel's write: its answer is
+    # the softmax of one element, the appended row's latent
+    alone = jnp.broadcast_to(row_new[:, None, :rank], (b, h, rank))
+    return jnp.where((lengths > 0)[:, None, None], out[:, :h],
+                     alone.astype(out.dtype)).astype(q_cat.dtype)
+
+
+def decode_block(rows, rank: int) -> int | None:
+    """The kernel's block where backend and shapes allow it, None where
+    decode attention stays on the reference: not a TPU, a latent that is
+    not whole lanes, a cache shorter than a block.
+    ``GOFR_FLASH_INTERPRET=1`` runs the kernel interpreted anywhere."""
+    from .flash import interpret_env, tpu_backend_ok
+
+    smax = rows.shape[2]
+    block_s = block_size(smax)
+    if interpret_env():
+        return block_s
+    if rank % _LANES or smax % _LANES or not tpu_backend_ok():
+        return None
+    return block_s
+
+
+@jax.named_scope("mla/decode_attn")
+def decode_attention(q_cat, rows, row_new, lengths, layer, *, rank: int,
+                     block_s: int | None):
+    """Absorbed decode attention over layer ``layer`` of the stacked
+    cache: the kernel where ``block_s`` (``decode_block``'s answer) says
+    so, the reference over the layer's slice otherwise."""
+    if block_s:
+        from .flash import interpret_env
+
+        return decode_attention_stacked(q_cat, rows, row_new, lengths, layer,
+                                        rank=rank, block_s=block_s,
+                                        interpret=interpret_env())
+    layer_rows = jax.lax.dynamic_index_in_dim(rows, layer, 0, keepdims=False)
+    return decode_attention_reference(q_cat, layer_rows, row_new, lengths,
+                                      rank)

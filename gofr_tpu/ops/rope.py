@@ -1,4 +1,4 @@
-"""Rotary position embeddings (RoPE), Llama-3 style with NTK scaling hook.
+"""Rotary position embeddings (RoPE): Llama-3 frequency scaling, or YaRN.
 
 Frequencies are precomputed once per model (static shapes — nothing here
 re-traces per step); application is a fused elementwise op that XLA folds
@@ -6,6 +6,8 @@ into the surrounding attention computation.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -16,8 +18,16 @@ def rope_frequencies(head_dim: int, max_seq: int, theta: float = 500000.0,
     """Precompute (cos, sin) tables of shape [max_seq, head_dim//2].
 
     ``scaling`` supports the Llama-3 frequency-scaling dict
-    {factor, low_freq_factor, high_freq_factor, original_max_position}.
+    {factor, low_freq_factor, high_freq_factor, original_max_position},
+    and with ``rope_type: "yarn"`` the YaRN dict (``yarn_inv_freq``).
     """
+    if is_yarn(scaling):
+        inv_freq = yarn_inv_freq(head_dim, theta, scaling)
+        mscale = (yarn_mscale(scaling["factor"], scaling.get("mscale", 1))
+                  / yarn_mscale(scaling["factor"],
+                                scaling.get("mscale_all_dim", 0)))
+        freqs = jnp.outer(jnp.arange(max_seq, dtype=jnp.float32), inv_freq)
+        return jnp.cos(freqs) * mscale, jnp.sin(freqs) * mscale
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
     if scaling:
         factor = scaling.get("factor", 8.0)
@@ -35,6 +45,48 @@ def rope_frequencies(head_dim: int, max_seq: int, theta: float = 500000.0,
     t = jnp.arange(max_seq, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)  # [max_seq, head_dim//2]
     return jnp.cos(freqs), jnp.sin(freqs)
+
+
+def is_yarn(scaling: dict | None) -> bool:
+    return bool(scaling) and scaling.get("rope_type") == "yarn"
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_softmax_scale(scaling: dict | None) -> float:
+    """What YaRN multiplies the softmax scale by: yarn_mscale(factor,
+    mscale_all_dim) squared (1 without YaRN or without mscale_all_dim)."""
+    if not is_yarn(scaling) or not scaling.get("mscale_all_dim"):
+        return 1.0
+    return yarn_mscale(scaling["factor"], scaling["mscale_all_dim"]) ** 2
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling: dict) -> jnp.ndarray:
+    """YaRN inverse frequencies [dim // 2], as the published
+    DeepseekV3YarnRotaryEmbedding computes them: theta^(-2i/dim), and
+    that over ``factor``, blended by a linear ramp between the dims whose
+    rotations over ``original_max_position_embeddings`` positions are
+    ``beta_fast`` and ``beta_slow``: fast dims keep their frequency,
+    slow dims are interpolated."""
+    factor = float(scaling["factor"])
+    orig = scaling.get("original_max_position_embeddings", 4096)
+
+    def correction_dim(rotations: float) -> float:
+        return dim * math.log(orig / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction_dim(scaling.get("beta_slow", 1))),
+               dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
 
 
 @jax.named_scope("apply_rope")

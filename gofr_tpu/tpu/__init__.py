@@ -10,6 +10,11 @@ degraded start.
 
 Config keys (reference config style, pkg/gofr/config/config.go:3):
   TPU_MODEL           model name: llama family (llama3-8b, llama-1b, tiny),
+                      the latent-attention family (tiny-mla-moe; any
+                      configuration with kv_lora_rank > 0: it refuses
+                      TPU_SHARDING, TPU_PAGED_BLOCKS, the host and Redis
+                      cache tiers, TPU_SPEC_DECODE, TPU_LORA_ADAPTERS,
+                      P/D roles and an int8 cache at start-up),
                       bert family (bert/bert-base, bert-tiny), or
                       vit family (vit/vit-l-14, vit-tiny)
   TPU_WEIGHTS         checkpoint path (.npz or orbax dir); absent = random
@@ -268,6 +273,27 @@ def parse_mesh(spec: str | None):
     return make_mesh(**axes)
 
 
+# the engine constructor's options in the configuration's names, for
+# the refusals a model family answers with (errors.UnsupportedOptions)
+_OPTION_KEYS = {
+    "mesh": "TPU_SHARDING", "paged_blocks": "TPU_PAGED_BLOCKS",
+    "kvcache": "TPU_KVCACHE_HOST_MB / TPU_KVCACHE_REDIS",
+    "spec_decode_k": "TPU_SPEC_DECODE", "lora_adapters": "TPU_LORA_ADAPTERS",
+    "kv_dtype": "TPU_KV_DTYPE", "serving_role": "TPU_SERVING_ROLE"}
+
+
+def _generation_engine(name: str, mc, params, **options) -> GenerationEngine:
+    """The engine, or what its family refuses said by configuration key."""
+    from ..errors import UnsupportedOptions
+
+    try:
+        return GenerationEngine(mc, params, **options)
+    except UnsupportedOptions as e:
+        raise UnsupportedOptions(
+            [(_OPTION_KEYS.get(opt, opt), why) for opt, why in e.refused],
+            f"TPU_MODEL={name}") from None
+
+
 def new_engine_from_config(cfg, logger=None, metrics=None,
                            observe=None) -> TPUEngine:
     from .. import compile_cache
@@ -359,13 +385,16 @@ def new_engine_from_config(cfg, logger=None, metrics=None,
                         example_item=np.zeros(
                             (mc.image_size, mc.image_size, 3), np.float32))
     else:
-        from ..models import llama
+        from ..models import family, llama
 
         mc = LLAMA_CONFIGS.get(name)
         if mc is None:
             raise KeyError(f"unknown TPU_MODEL {name!r}; known: "
                            f"{sorted(LLAMA_CONFIGS) + sorted(BERT_CONFIGS) + sorted(VIT_CONFIGS)}")
-        params = params_for(mc, llama.init)
+        # the decoder family (models.family: by what the configuration
+        # says, e.g. a latent cache row)
+        fam = family(mc)
+        params = params_for(mc, fam.init)
         max_seq = cfg.get_int("TPU_MAX_SEQ", min(mc.max_seq, 2048))
         slots = cfg.get_int("TPU_SLOTS", 48)
         kv_choice = (cfg.get("TPU_KV_DTYPE") or "int8").lower()
@@ -383,8 +412,9 @@ def new_engine_from_config(cfg, logger=None, metrics=None,
 
             kv_opts = options_from_config(cfg, logger=logger,
                                           metrics=metrics)
-        engine.generator = GenerationEngine(
-            mc, params, slots=slots, max_seq=max_seq, prompt_buckets=prompt_b,
+        engine.generator = _generation_engine(
+            name, mc, params, slots=slots, max_seq=max_seq,
+            prompt_buckets=prompt_b,
             logger=logger, metrics=metrics, observe=observe, mesh=mesh,
             gate=gate_from_config(cfg, "generate", metrics=metrics,
                                   tracer=tracer, logger=logger),
@@ -403,7 +433,8 @@ def new_engine_from_config(cfg, logger=None, metrics=None,
             lora_adapters=cfg.get_int("TPU_LORA_ADAPTERS", 0),
             lora_rank=cfg.get_int("TPU_LORA_RANK", 16),
             paged_blocks=cfg.get_int("TPU_PAGED_BLOCKS", 0),
-            paged_block_size=cfg.get_int("TPU_PAGED_BLOCK", 128))
+            paged_block_size=cfg.get_int("TPU_PAGED_BLOCK", 128),
+            serving_role=(cfg.get("TPU_SERVING_ROLE") or "").strip().lower())
 
         # scoring program: next-token logits at the prompt end (the
         # non-streaming sibling of generate, e.g. for classification
@@ -416,8 +447,8 @@ def new_engine_from_config(cfg, logger=None, metrics=None,
         def score_fn(p, tokens, lengths):
             # gather the prompt-end hidden state BEFORE lm_head: full
             # [B, S, V] f32 logits are 2.1 GB at (8, 512) x 128k vocab
-            return llama.forward(p, score_mc, tokens, lengths,
-                                 logit_pos=jnp.maximum(lengths - 1, 0))[:, 0]
+            return fam.forward(p, score_mc, tokens, lengths,
+                               logit_pos=jnp.maximum(lengths - 1, 0))[:, 0]
 
         seq_b = tuple(b for b in seq_buckets if b <= max_seq) or (max_seq,)
         engine.register("score", score_fn, params, kind="tokens",

@@ -27,7 +27,10 @@ import numpy as np
 from ..ops.quant import QuantizedLinear, quantize_int8
 
 # Llama projection leaves worth int8-quantizing (stacked [L, in, out]).
-_QUANT_LEAVES = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head"}
+_QUANT_LEAVES = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
+                 # the latent-attention family (models/deepseek_v3.py)
+                 "w_qa", "w_qb", "w_kva", "w_kvb",
+                 "ws_gate", "ws_up", "ws_down"}
 
 
 def _flatten(tree: Any, prefix: str = "") -> dict[str, np.ndarray]:

@@ -40,10 +40,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import chaos, compile_cache
-from ..errors import DeadlineExceeded
-from ..models import llama
+from ..errors import DeadlineExceeded, UnsupportedOptions
+from ..models import family, llama
 from ..models.common import ModelConfig
-from ..ops import flash_decode
 from ..resilience import (SLO_LATENCY, SLO_THROUGHPUT, DecodePipelinePolicy,
                           current_deadline, current_slo_class)
 from ..tenancy.fair import WeightedFairLine
@@ -159,11 +158,11 @@ def _copy_row(dst, src, dst_idx, src_idx):
         r = lax.dynamic_slice_in_dim(s, src_idx, 1, axis=1)
         return lax.dynamic_update_slice_in_dim(d, r, dst_idx, axis=1)
 
-    quant = dst.k_scale is not None
-    return dst._replace(
-        k=cp(dst.k, src.k), v=cp(dst.v, src.v),
-        k_scale=cp(dst.k_scale, src.k_scale) if quant else None,
-        v_scale=cp(dst.v_scale, src.v_scale) if quant else None)
+    # every array of a cache but ``lengths`` is [L, B, Smax, ...]: K, V
+    # and their scale planes, or a family's latent rows
+    return jax.tree_util.tree_map(
+        cp, dst._replace(lengths=None),
+        src._replace(lengths=None))._replace(lengths=dst.lengths)
 
 
 def _write_row_from_host(pool, k, v, ks, vs, row):
@@ -522,8 +521,21 @@ class GenerationEngine:
                  paged_blocks: int = 0, paged_block_size: int = 128,
                  prefill_chunk: int | None = None,
                  slo_throughput_share: float = 0.25,
-                 slo_latency_slots: int = 1):
+                 slo_latency_slots: int = 1,
+                 serving_role: str | None = None):
         self.cfg = cfg
+        # the model family: its programs and its cache row layout. This
+        # is the one place the engine learns it; every call below goes
+        # through ``self._fam`` and every cache helper maps over the
+        # cache's arrays whatever they are
+        self._fam = family(cfg)
+        refused = self._fam.unsupported_options(
+            mesh=mesh, paged_blocks=paged_blocks, kvcache=kvcache,
+            spec_decode_k=spec_decode_k, lora_adapters=lora_adapters,
+            kv_dtype=kv_dtype, serving_role=serving_role)
+        if refused:
+            raise UnsupportedOptions(
+                refused, f"the model family of {cfg.name!r}")
         self.params = params
         self.n_slots = slots
         # serializes device-state mutation (the loop thread vs warmup/
@@ -603,6 +615,10 @@ class GenerationEngine:
         # the stream's dry intervals are the loop account's (_LoopAccount)
         self._reaps = 0
         self._overlapped_reaps = 0
+        # an expert layer's decode account (families whose step counts
+        # its assignments): assignments made, (step, layer, expert) cells
+        # that got one, cells in all
+        self._moe_assigned = self._moe_touched = self._moe_cells = 0
         # In-flight admission poll cadence (seconds). While a decode
         # block runs on device, the serving loop waits on the submit
         # event in slices of this length and admits new arrivals
@@ -722,7 +738,7 @@ class GenerationEngine:
         # the loop thread's account of its own time and of the stream
         self._acct = _LoopAccount(self._tl, metrics)
         self.mesh = mesh
-        self.rope_tables = llama.get_rope_tables(cfg, self.max_seq)
+        self.rope_tables = self._fam.get_rope_tables(cfg, self.max_seq)
 
         # kv_dtype=jnp.int8 halves decode's cache HBM stream (quantize on
         # write, dequant fused into attention) — the default for serving
@@ -764,7 +780,7 @@ class GenerationEngine:
         else:
             def _init_cache():
                 return self._born_sharded(
-                    lambda: llama.init_cache(cfg, slots, self.max_seq,
+                    lambda: self._fam.init_cache(cfg, slots, self.max_seq,
                                              dtype=kv_dtype),
                     self._cache_sh)
 
@@ -839,8 +855,8 @@ class GenerationEngine:
         # rounded up to this block where the flash-decode kernel takes
         # these shapes, every reserved position (None) on the reference
         # path; the paged pool has its own account
-        self._kv_block = None if self._paged else flash_decode.kernel_block(
-            cfg.n_heads, self.cache.k, mesh)
+        self._kv_block = None if self._paged else self._fam.decode_kv_block(
+            cfg, self.cache, mesh)
         self._slots = [_Slot() for _ in range(slots)]
         self._last_tokens = np.zeros((slots,), np.int32)
         self._active = np.zeros((slots,), bool)
@@ -937,7 +953,7 @@ class GenerationEngine:
 
                 def _init_pool():
                     return self._born_sharded(
-                        lambda: llama.init_cache(cfg, prefix_cache_slots,
+                        lambda: self._fam.init_cache(cfg, prefix_cache_slots,
                                                  self.max_seq,
                                                  dtype=kv_dtype),
                         self._pool_sh)
@@ -965,9 +981,9 @@ class GenerationEngine:
                         "kvcache-t0", _init_pool,
                         owner=self, tag="pool", priority=hbm.PRI_CACHE,
                         reclaim=self._hbm_pool_reclaim)
-                layout = KVLayout(cfg.n_layers, cfg.n_kv_heads,
-                                  cfg.head_dim, self._pool.quantized,
-                                  np.dtype(str(self._pool.k.dtype)),
+                layout = KVLayout(cfg.n_layers, *self._fam.kv_layout(cfg),
+                                  self._pool.quantized,
+                                  np.dtype(str(self._pool[0].dtype)),
                                   self.max_seq)
                 self._kvc = CacheManager(
                     prefix_cache_slots, layout, block=opts.block,
@@ -1086,7 +1102,7 @@ class GenerationEngine:
         per shard."""
         def _init_scratch():
             return self._born_sharded(
-                lambda: llama.init_cache(self.cfg, 1, self.max_seq,
+                lambda: self._fam.init_cache(self.cfg, 1, self.max_seq,
                                          dtype=self._kv_dtype),
                 self._scratch_sh)
 
@@ -1131,7 +1147,7 @@ class GenerationEngine:
             self._step_jit = jax.jit(step_fn, donate_argnums=(0,),
                                      out_shardings=(rep, rep, rep,
                                                     (rep, rep, rep, rep),
-                                                    rep, cache_sh))
+                                                    rep, cache_sh, rep))
             if self._spec_k:
                 verify_fn = (self._paged_verify_fn if self._paged
                              else self._verify_fn)
@@ -1393,13 +1409,15 @@ class GenerationEngine:
         # under GSPMD, so on mesh engines ops.flash wraps the kernel in
         # shard_map per head shard (jnp reference when tp would split a
         # KV head) — the mesh= plumbing picks the form.
-        logits, k, v, _ = llama.prefill_kv(
+        logits, *kv, _ = self._fam.prefill_kv(
             params, self.cfg, tokens, jnp.asarray([length]),
             rope_max=self.max_seq, rope_tables=self.rope_tables,
             flash=True, mesh=self.mesh, adapter=adapter,
             logit_pos=jnp.asarray([length - 1]))
         lengths = cache.lengths.at[slot].set(length)
-        cache = llama.write_kv(cache, k, v, (0, slot, 0, 0, 0), lengths)
+        # the slot's row from position 0, every layer: (0, slot, 0, ...)
+        cache = self._fam.write_kv(
+            cache, *kv, (0, slot) + (0,) * (cache[0].ndim - 2), lengths)
         last = logits[0, 0]  # [V] at the true prompt end (logit_pos)
         tok, lp = self._sample(last[None, :], temp[None],
                                self._resume_keys(seed[None], pos[None]),
@@ -1413,30 +1431,22 @@ class GenerationEngine:
         slice the slot's cache view, run one chunk against it, write back.
         The final chunk (``sample=True``) also sets the slot's cursor to
         ``total_len`` and samples the first token at ``pos_in_chunk``."""
-        L, _, Smax, KV, hd = cache.k.shape
-        quant = cache.quantized
-
-        def slot_view(a, rank5: bool):
-            size = (L, 1, Smax, KV, hd) if rank5 else (L, 1, Smax, KV)
-            idx = (0, slot, 0, 0, 0)[: len(size)]
-            return jax.lax.dynamic_slice(a, idx, size)
-
-        small = llama.KVCache(
-            slot_view(cache.k, True), slot_view(cache.v, True),
-            jnp.zeros((1,), jnp.int32),
-            slot_view(cache.k_scale, False) if quant else None,
-            slot_view(cache.v_scale, False) if quant else None)
-        logits, small = llama.prefill_chunk(
+        Smax = cache[0].shape[2]
+        # the slot's view of every cache array ([L, 1, Smax, ...]: K, V
+        # and scale planes, or latent rows), and its write-back
+        arrays = cache._replace(lengths=None)
+        small = jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1),
+            arrays)._replace(lengths=jnp.zeros((1,), jnp.int32))
+        logits, small = self._fam.prefill_chunk(
             params, self.cfg, tokens, small, start,
             rope_tables=self.rope_tables, compute_logits=sample,
             adapter=adapter,
             logit_pos=jnp.asarray(pos_in_chunk)[None] if sample else None)
-        k_new = jax.lax.dynamic_update_slice(cache.k, small.k, (0, slot, 0, 0, 0))
-        v_new = jax.lax.dynamic_update_slice(cache.v, small.v, (0, slot, 0, 0, 0))
-        ks, vs = cache.k_scale, cache.v_scale
-        if quant:
-            ks = jax.lax.dynamic_update_slice(ks, small.k_scale, (0, slot, 0, 0))
-            vs = jax.lax.dynamic_update_slice(vs, small.v_scale, (0, slot, 0, 0))
+        written = jax.tree_util.tree_map(
+            lambda a, s: jax.lax.dynamic_update_slice_in_dim(a, s, slot,
+                                                             axis=1),
+            arrays, small._replace(lengths=None))
         if not sample:
             # PARK the slot while its prompt is chunk-written: decode
             # blocks interleave with mid-chunks, and every decode step
@@ -1444,15 +1454,14 @@ class GenerationEngine:
             # cursor inside [0, prompt_len) would corrupt KV this chunk
             # just wrote. Cursor = capacity makes those writes land out
             # of range, where mode="drop" discards them.
-            lengths = cache.lengths.at[slot].set(Smax)
-            return llama.KVCache(k_new, v_new, lengths, ks, vs)
+            return written._replace(
+                lengths=cache.lengths.at[slot].set(Smax))
         lengths = cache.lengths.at[slot].set(total_len)
         last = logits[0, 0]  # [V] at pos_in_chunk (logit_pos)
         tok, lp = self._sample(last[None, :], temp[None],
                                self._resume_keys(seed[None], pos[None]),
                                top_k[None])
-        return (tok[0], lp[0], key,
-                llama.KVCache(k_new, v_new, lengths, ks, vs))
+        return tok[0], lp[0], key, written._replace(lengths=lengths)
 
     # methods, not functools.partial: jit names a program after its
     # function, and a partial has no name (jit__unknown in a device trace)
@@ -1471,8 +1480,11 @@ class GenerationEngine:
         position, which admission either overwrites or — for parked
         slots — drops), and attention is told which slots are active so
         that it reads nothing of the others. ``step_model(tokens, cache,
-        active) -> (logits, stepped)`` is the only thing that differs
-        between the contiguous and paged engines.
+        active) -> (logits, stepped[, counters])`` is the only thing that
+        differs between the contiguous and paged engines and between
+        model families; what a family's step counts beside its logits
+        (the expert layer's assignments) comes back stacked a step as
+        the program's last output, for the reap's one fetch.
 
         ``pack`` [B, W] int32 is the coalesced host dispatch state (one
         h2d when dirty — see _dispatch_pack); ``carry`` is the device
@@ -1522,7 +1534,7 @@ class GenerationEngine:
 
         def body(carry, _):
             tokens, active, budget, pos, cache = carry
-            logits, stepped = step_model(tokens, cache, active)
+            logits, stepped, *counters = step_model(tokens, cache, active)
             lengths = jnp.where(active, stepped.lengths, cache.lengths)
             stepped = stepped._replace(lengths=lengths)
             toks, lps = self._sample(logits, temps,
@@ -1538,13 +1550,13 @@ class GenerationEngine:
             stop = active & llama.decode_stop_mask(toks, lengths, budget,
                                                    eos_ids, cap)
             return (toks, active & ~stop, budget, pos, stepped), \
-                (toks, lps, emitted)
+                (toks, lps, emitted, counters)
 
-        (last, active, budget, pos, cache), (toks, lps, emitted) = \
-            jax.lax.scan(body, (tokens0, active0, budget0, pos0, cache),
-                         None, length=self.decode_block)
+        (last, active, budget, pos, cache), (toks, lps, emitted, counters) \
+            = jax.lax.scan(body, (tokens0, active0, budget0, pos0, cache),
+                           None, length=self.decode_block)
         return (toks, lps, emitted, (last, active, budget, pos), key,
-                cache)
+                cache, counters)
 
     def _verify_epilogue(self, logits, window, active, stepped):
         """Shared verify-pass tail: greedy tokens + their logprobs, the
@@ -1564,7 +1576,7 @@ class GenerationEngine:
         adapter = pack[:, 5] if self._n_adapters else None
 
         def step_model(tokens, cache, active):
-            return llama.decode_step(
+            return self._fam.decode_step(
                 params, self.cfg, tokens, cache,
                 rope_tables=self.rope_tables, adapter=adapter,
                 mesh=self.mesh, active=active)
@@ -1967,7 +1979,7 @@ class GenerationEngine:
             "draining": self._draining,
             "max_seq": self.max_seq,
             # cache positions a flash-decode work item covers, None on
-            # the reference path (ops.flash_decode.kernel_block)
+            # the reference path (the family's decode_kv_block)
             "decode_kv_block": self._kv_block,
             "prompt_buckets": list(self.prompt_buckets),
             "total_requests": self.total_requests,
@@ -1981,6 +1993,7 @@ class GenerationEngine:
                     self._pending.qsize_class(SLO_THROUGHPUT),
                 "pipeline": self._pipeline_stats(),
             },
+            **self._fam.serving_stats(self.cfg, self.n_slots),
         }
         if self.tenancy is not None:
             out["scheduler"]["queued_by_tenant"] = \
@@ -2011,6 +2024,13 @@ class GenerationEngine:
                                      / max(1, n_usable), 3),
                 "evictions": self._paged_evictions,
             }
+        if self._moe_cells:
+            out["moe"] = {
+                "expert_tokens": self._moe_assigned,
+                "tokens_per_expert": round(
+                    self._moe_assigned / self._moe_cells, 4),
+                "experts_idle_ratio": round(
+                    1.0 - self._moe_touched / self._moe_cells, 4)}
         if self._n_adapters:
             out["lora"] = {"adapters": self._n_adapters,
                            "rank": int(self.params["layers"]
@@ -2181,11 +2201,11 @@ class GenerationEngine:
             # Warming only one would re-lower the big fused scan
             # mid-serving.
             warm_pack = self._warm_pack()
-            _, _, _, carry_w, self._key, self.cache = \
+            _, _, _, carry_w, self._key, self.cache, _ = \
                 jax.block_until_ready(self._step_jit(
                     self.cache, self.params, warm_pack,
                     self._host_carry(), self._key))
-            _, _, _, _, self._key, self.cache = jax.block_until_ready(
+            _, _, _, _, self._key, self.cache, _ = jax.block_until_ready(
                 self._step_jit(self.cache, self.params, warm_pack,
                                carry_w, self._key))
             if self._spec_k:
@@ -3462,13 +3482,13 @@ class GenerationEngine:
                     # the new placement actually is
                     self._pool_sh = kv_cache_specs(
                         self.mesh, jax.eval_shape(
-                            lambda: llama.init_cache(
+                            lambda: self._fam.init_cache(
                                 self.cfg, new_slots, self.max_seq,
                                 dtype=self._kv_dtype)))
 
                 def _smaller_pool():
                     return self._born_sharded(
-                        lambda: llama.init_cache(self.cfg, new_slots,
+                        lambda: self._fam.init_cache(self.cfg, new_slots,
                                                  self.max_seq,
                                                  dtype=self._kv_dtype),
                         self._pool_sh)
@@ -4239,7 +4259,7 @@ class GenerationEngine:
                             def _realloc_pool():
                                 return jax.block_until_ready(
                                     self._born_sharded(
-                                        lambda: llama.init_cache(
+                                        lambda: self._fam.init_cache(
                                             self.cfg, self._kvc.slots,
                                             self.max_seq,
                                             dtype=self._kv_dtype),
@@ -4284,7 +4304,7 @@ class GenerationEngine:
                                 def _realloc_scratch():
                                     return jax.block_until_ready(
                                         self._born_sharded(
-                                            lambda: llama.init_cache(
+                                            lambda: self._fam.init_cache(
                                                 self.cfg, 1, self.max_seq,
                                                 dtype=self._kv_dtype),
                                             self._scratch_sh))
@@ -4302,7 +4322,7 @@ class GenerationEngine:
                                         priority=hbm.PRI_SCRATCH)
                         else:
                             def _realloc_cache():
-                                return llama.init_cache(self.cfg,
+                                return self._fam.init_cache(self.cfg,
                                                         self.n_slots,
                                                         self.max_seq,
                                                         dtype=self._kv_dtype)
@@ -4605,9 +4625,9 @@ class GenerationEngine:
                    else self.n_slots * self.max_seq)
         t_dispatch = time.monotonic()
         pack = self._dispatch_pack()
-        toks, lps, emitted, self._last_dev, self._key, self.cache = \
-            self._run(self._step_jit, self.cache, self.params, pack,
-                      self._last_dev, self._key)
+        toks, lps, emitted, self._last_dev, self._key, self.cache, \
+            counters = self._run(self._step_jit, self.cache, self.params,
+                                 pack, self._last_dev, self._key)
         if not self._paged:
             self._cursors[self._active] += self.decode_block
         else:
@@ -4630,22 +4650,39 @@ class GenerationEngine:
         snap_reqs = [s.request for s in self._slots]
         return _Inflight((toks, lps, emitted), functools.partial(
             self._decode_reap, toks, lps, emitted, snap_active, snap_reqs,
-            t_dispatch, live, fetched))
+            t_dispatch, live, fetched, counters))
 
     # invoked through _Inflight.reap, always under the engine's device
     # lock (see _loop)  # gl: holds self._device_lock
     def _decode_reap(self, toks, lps, emitted, snap_active, snap_reqs,
                      t0: float = 0.0, live: int | None = None,
-                     fetched: int | None = None) -> None:
-        toks_np, lps_np, emit_np = jax.device_get((toks, lps, emitted))
+                     fetched: int | None = None, counters=()) -> None:
+        # one fetch: what the family's step counted rides with the tokens
+        toks_np, lps_np, emit_np, counters = jax.device_get(
+            (toks, lps, emitted, counters))
         self._acct.phase("deliver")
+        # an expert layer's assignments [steps, routed layers, held
+        # experts]: how many the block made, and how many (step, layer,
+        # expert) cells got at least one (each is an expert's weights read)
+        moe = counters[0] if counters else None
+        assigned = None if moe is None else int(moe.sum())
+        touched = None if moe is None else int(np.count_nonzero(moe))
         if self._tl is not None:
             # one ring event per fused block, fanned out to per-slot
             # slices only at export time — the hot path pays one append
             self._tl.decode_block(
                 t0, time.monotonic(),
                 tuple(int(i) for i in np.flatnonzero(snap_active)),
-                self.decode_block, live, fetched)
+                self.decode_block, live, fetched, assigned, touched)
+        if moe is not None:
+            self._moe_assigned += assigned
+            self._moe_touched += touched
+            self._moe_cells += moe.size
+            if self.metrics is not None:
+                self.metrics.delta_updown_counter(
+                    "app_tpu_moe_expert_tokens", float(assigned))
+                self.metrics.set_gauge("app_tpu_moe_experts_idle_ratio",
+                                       1.0 - touched / moe.size)
         if self.metrics is not None:
             self.metrics.set_gauge("app_tpu_batch_fill",
                                    float(self._active.sum()) / self.n_slots,
