@@ -75,6 +75,14 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.hist_new.restype = ctypes.c_void_p
     lib.hist_new.argtypes = [ctypes.POINTER(ctypes.c_double), ctypes.c_int]
     lib.hist_free.argtypes = [ctypes.c_void_p]
+    # hist_record is wait-free, a few relaxed atomics. Through CDLL the
+    # call lets go of the interpreter lock for those nanoseconds, and a
+    # thread that records in a loop (the generation loop delivering a
+    # block's tokens, one sample a token) then queues for the lock
+    # behind every consumer it has just woken: a delivery of 512 tokens
+    # took 7-112 ms on the chip, longer than the decode block behind it
+    # (PERF.md, Findings PR 29). PyDLL keeps the lock across the call.
+    lib.hist_record = ctypes.PyDLL(lib._name).hist_record
     lib.hist_record.argtypes = [ctypes.c_void_p, ctypes.c_double]
     lib.hist_snapshot.argtypes = [ctypes.c_void_p, ctypes.POINTER(u64),
                                   ctypes.POINTER(ctypes.c_double),

@@ -137,9 +137,18 @@ class DecodePipelinePolicy:
     compute. ``target()`` is consulted before every pipeline top-up and
     collapses to 1 exactly when that wait would cost an SLO:
 
-      - a latency-class request is waiting for admission (its prefill
-        must queue behind at most ONE in-flight block, keeping TTFT at
-        the SLO_BENCH floor);
+      - a latency-class request is waiting AND a slot is free for it
+        (``latency_admittable``: its prefill, dispatched now, must queue
+        behind at most ONE in-flight block). A waiter alone does not
+        collapse the depth: with every slot busy there is no prefill to
+        queue, and untagged traffic is latency class, so any standing
+        queue on a full batch would pin the loop at depth 1 and leave
+        the device dry behind every reap. What the rule costs at depth
+        2: a slot that finishes inside block N is seen free at N's
+        reap, when N+1 is already queued, so the waiter's prefill runs
+        one block later than it would at depth 1 (and its first token
+        comes that block later); the bound above still holds, because
+        the reap that frees the slot is the moment the policy answers 1;
       - a chunk-lattice admission was deferred by the in-flight pass
         (the lattice needs a fully reaped loop — its interleaved decode
         blocks re-decode from host token state);
@@ -156,10 +165,10 @@ class DecodePipelinePolicy:
     def __init__(self, depth: int = 2):
         self.depth = max(1, int(depth))
 
-    def target(self, *, latency_waiting: bool = False,
+    def target(self, *, latency_admittable: bool = False,
                lattice_deferred: bool = False,
                spec_decode: bool = False) -> int:
-        if latency_waiting or lattice_deferred or spec_decode:
+        if latency_admittable or lattice_deferred or spec_decode:
             return 1
         return self.depth
 

@@ -30,13 +30,19 @@ Two ways to use it:
 
 Snapshots ``gc.collect()`` first: donated/dropped buffers are freed at
 object collection, and without the collect a snapshot would read
-garbage-pending bytes as leaks.
+garbage-pending bytes as leaks. Then they wait for the process to go
+quiet: a serving loop still holds the outputs of the decode blocks it
+had in flight when the caller's stream ended, for the milliseconds it
+takes to reap them, and a read in that window is a few hundred bytes
+off in either direction. A snapshot is the first reading that three
+reads in a row, 4 ms apart, agree on.
 """
 
 from __future__ import annotations
 
 import gc
 import os
+import time
 from typing import Any, Callable
 
 __all__ = ["HBMLeak", "HBMWatch", "attribution", "live_device_bytes"]
@@ -90,7 +96,13 @@ class HBMWatch:
 
     def snapshot(self) -> int:
         gc.collect()
-        return live_device_bytes()
+        seen, agree = live_device_bytes(), 1
+        deadline = time.monotonic() + 2.0  # a busy process never agrees
+        while agree < 3 and time.monotonic() < deadline:
+            time.sleep(0.004)
+            now = live_device_bytes()
+            seen, agree = now, agree + 1 if now == seen else 1
+        return seen
 
     def assert_flat(self, fn: Callable[[], Any], *, warmup: int = 2,
                     iters: int = 3, tol_bytes: int = 0,

@@ -605,11 +605,16 @@ class GenerationEngine:
         # futures chained from N's outputs, so the dispatch queues with
         # zero host feedback and the host overlaps N's reap/delivery/
         # admission with N+1's compute. The policy collapses to 1 when
-        # queueing a second block would cost an SLO (latency admission
-        # waiting, chunk lattice deferred, spec decode) — see
-        # resilience.DecodePipelinePolicy.
+        # queueing a second block would cost an SLO (a latency-class
+        # waiter that a free slot can take now, chunk lattice deferred,
+        # spec decode) — see resilience.DecodePipelinePolicy.
         self._pipeline = DecodePipelinePolicy(decode_pipeline)
+        # the dispatched, un-reaped blocks, oldest first. Only the loop
+        # thread touches it, under the device lock
+        self._pipe: "deque[_Inflight]" = deque()
         self._lattice_deferred = False
+        # inside the admission pass a chunk lattice runs between chunks
+        self._in_lattice = False
         self._depth_now = 0
         # overlapped reaps (a block still queued at reap) are counted;
         # the stream's dry intervals are the loop account's (_LoopAccount)
@@ -2066,6 +2071,7 @@ class GenerationEngine:
         return {
             "depth": self._pipeline.depth,
             "target_depth": self._target_depth(),
+            "latency_admittable": self._latency_admittable(),
             "depth_now": self._depth_now,
             "reaps": self._reaps,
             "overlapped_reaps": self._overlapped_reaps,
@@ -2426,7 +2432,7 @@ class GenerationEngine:
             return None
         return jnp.asarray([0 if req is None else req.adapter], jnp.int32)
 
-    def _admit(self, defer_lattice: bool = False) -> int:
+    def _admit(self) -> int:
         """One admission pass, accounted as the loop's ``admit`` phase
         (with the requests it started as the phase's count). A pass
         that could start nothing — no free slot, or nobody waiting —
@@ -2436,20 +2442,24 @@ class GenerationEngine:
             return 0
         prev = self._acct.phase("admit")
         try:
-            started = self._admit_pass(defer_lattice)
+            started = self._admit_pass()
             self._acct.ph_n += started
             return started
         finally:
             self._acct.phase(prev)
 
-    def _admit_pass(self, defer_lattice: bool) -> int:
+    def _admit_pass(self) -> int:
         """Admit pending requests into free slots; returns the number
-        started. ``defer_lattice``: in-flight admission (see
-        _admit_inflight) must NOT start a chunk-lattice admission — the
+        started. A pass under an un-reaped block (in-flight admission,
+        see _admit_inflight, or a synchronous pass once an earlier
+        admission of its own has queued a block behind its prefill, see
+        _first_token) must NOT start a chunk-lattice admission — the
         lattice interleaves its own decode blocks, which would
         double-decode every active slot from the un-reaped outer
         block's stale _last_tokens — so lattice-path requests stay
-        queued until the outer reap and the next synchronous pass."""
+        queued until the outer reap and the next synchronous pass. Nor
+        may the pass a lattice runs between its own chunks: one chunk
+        stream at a time."""
         started = 0
         for idx, slot in enumerate(self._slots):
             if not slot.free:
@@ -2470,7 +2480,8 @@ class GenerationEngine:
                         allow_throughput=free_now > self._lat_reserve)
                 except queue.Empty:
                     return started
-                if defer_lattice and self._needs_lattice(req):
+                if (self._pipe or self._in_lattice) \
+                        and self._needs_lattice(req):
                     # a lattice admission cannot start under an
                     # un-reaped block (its interleaved decode ticks
                     # would re-decode stale tokens): return the
@@ -2657,16 +2668,52 @@ class GenerationEngine:
 
     def _first_token(self, tok, lp) -> tuple[int, float]:
         """Fetch the token an admission's last program sampled. The
-        copy blocks until every program queued so far is done (the
+        copy blocks until every program queued before it is done (the
         decode block in flight, then the prefill): the thread is
         blocked on the device, which is the loop's ``fetch`` phase, not
-        host work of admission."""
+        host work of admission.
+
+        Before it blocks, the pipe is topped up with ONE decode block
+        for the slots already decoding, queued behind the prefill
+        (_trail): the token comes back when the prefill is done, as
+        before, and the admission's host work after it (prefix store,
+        delivery, _start's bookkeeping, the reap of the older block,
+        the next dispatch pack) runs while that block computes instead
+        of with the stream dry. The admitted slot is not in that block
+        (it joins the next through host_wins), so its second token
+        comes one block later than it would from a dry stream."""
+        self._trail()
         prev = self._acct.phase("fetch")
         try:
             tok, lp = jax.device_get((tok, lp))  # one round trip, not two
             return int(tok), float(lp)
         finally:
             self._acct.phase(prev)
+
+    def _trail(self) -> None:
+        """Queue one decode block behind the admission prefill just
+        dispatched, where the depth policy has room for it: not under
+        the pass a chunk lattice runs between its chunks (it reaps its
+        own block synchronously, and a block queued here would be
+        older and un-reaped), not while another latency-class waiter
+        can be admitted in this pass (its prefill goes first), not past
+        the configured depth. In that block the admitted slot is
+        inactive and its cursor is the one the prefill set (the prompt
+        length), so the step's frozen-cursor scatter lands at the first
+        position after the prompt, which the slot's own first decode
+        step overwrites; on the paged engine the slot's table row is
+        still the trash block's (_paged_admit_prefill installs it after
+        the fetch). Fires the GENERATOR_STEP chaos seam like every
+        other top-up: a failure here is a device loss between a
+        prefill and the block behind it."""
+        pipe = self._pipe
+        if self._in_lattice or len(pipe) >= self._target_depth():
+            return
+        chaos.fire(chaos.GENERATOR_STEP)
+        inflight = self._tick(decode_only=bool(pipe))
+        if inflight is not None:
+            pipe.append(inflight)
+            self._note_depth(len(pipe))
 
     def _lattice_resume_valid(self, L: int, m: int) -> bool:
         """Can the chunk lattice resume at position ``m`` of an L-token
@@ -2767,8 +2814,14 @@ class GenerationEngine:
             #      stay queued — one chunk stream at a time;
             #   2. one decode block for the live batch, reaped
             #      synchronously so its tokens deliver before the
-            #      next chunk occupies the device.
-            self._admit(defer_lattice=True)
+            #      next chunk occupies the device (so an admission of
+            #      step 1 queues no block behind its prefill: that
+            #      block would be reaped after this one).
+            self._in_lattice = True
+            try:
+                self._admit()
+            finally:
+                self._in_lattice = False
             inflight = self._tick(decode_only=True)
             if inflight is not None:
                 self._reap(inflight)
@@ -2884,8 +2937,14 @@ class GenerationEngine:
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
                 self._key, jnp.int32(req.seed), jnp.int32(req.pos_base),
                 self._adapter1(req))
+            # the row goes in AFTER the fetch: the block _first_token
+            # queues behind the prefill holds this slot inactive at
+            # cursor L, and through an installed row its garbage write
+            # would land in the prompt's last block when L fills it
+            # (the clamped row repeats that block)
+            first = self._first_token(tok, lp)
             self._write_table_row(idx)
-            return self._first_token(tok, lp)
+            return first
         if m > 0:
             # restore: shared blocks -> scratch positions [0, m)
             read_blocks = shared + [0] * (self._mb - len(shared))
@@ -4127,10 +4186,16 @@ class GenerationEngine:
         # fused blocks. Depth 1 reproduces the old dispatch->overlap->
         # reap loop exactly; at depth 2 the loop keeps a SECOND block
         # queued on the device stream while reaping the first, so the
-        # host-side reap/delivery/admission work (the ~23% per-block
-        # dispatch gap BENCH_CANDIDATE.json measured) overlaps device
-        # compute instead of idling it.
-        pipe: "deque[_Inflight]" = deque()
+        # host-side reap/delivery/admission work overlaps device compute
+        # instead of idling it (BENCH_CANDIDATE.json, 2026-07-31, put
+        # that gap at ~23% of a block: a claim older than this code).
+        # The invariant: this thread never does host work, and never
+        # blocks on the device, with nothing queued behind the program
+        # it waits for, unless the depth policy says a waiting
+        # request's first token needs it AND that request can be
+        # started (a free slot). A full batch with a standing queue
+        # therefore runs at depth 2.
+        pipe = self._pipe
         while not self._closed:
             try:
                 if pipe or self._active.any() or not self._pending.empty():
@@ -4162,11 +4227,6 @@ class GenerationEngine:
                     self._admit_inflight(pipe[0])
                     with self._device_lock:
                         inflight = pipe.popleft()
-                        self._reaps += 1
-                        if pipe:
-                            # >= 1 block still queued on-device: the
-                            # stream cannot be dry behind this reap
-                            self._overlapped_reaps += 1
                         self._reap(inflight)
                 else:
                     self._acct.phase("park")
@@ -4380,7 +4440,13 @@ class GenerationEngine:
         the submit event and runs admissions NOW: the new request's
         prefill queues on the device stream right behind the in-flight
         block, making its first token cost (remaining block + prefill)
-        — the hardware floor. Readiness is polled via jax.Array
+        — the hardware floor. Behind a full batch at depth 2 that
+        block is the SECOND one queued: the slot a waiter gets was seen
+        free at the reap of the block before it (the depth policy then
+        answers 1 and nothing more is queued), so the refill comes one
+        block later than at depth 1, and with the block _first_token
+        queues behind the prefill the refilled slot decodes from the
+        block after that. Readiness is polled via jax.Array
         .is_ready(); if the probe is unsupported the reap just blocks
         like the old loop. The deadline bounds the poll so a wedged
         device surfaces its error through the blocking reap rather than
@@ -4404,7 +4470,7 @@ class GenerationEngine:
                 started = 0
                 if not self._pending.empty():
                     with self._device_lock:
-                        started = self._admit(defer_lattice=True)
+                        started = self._admit()
                 if started:
                     continue  # more may be queued behind the ones admitted
                 # nothing admitted (queue empty, no free slot, pool
@@ -4420,7 +4486,13 @@ class GenerationEngine:
     def _reap(self, inflight: _Inflight) -> None:
         """Fetch a dispatched tick's results and deliver them: the
         loop's ``fetch`` phase up to the device->host copy's return,
-        ``deliver`` from there (the reap itself moves the phase on)."""
+        ``deliver`` from there (the reap itself moves the phase on).
+        ``inflight`` is off the pipe already: a block still on it is
+        queued behind this one, so the stream cannot run dry behind
+        this reap (the chunk lattice's synchronous reaps have none)."""
+        self._reaps += 1
+        if self._pipe:
+            self._overlapped_reaps += 1
         prev = self._acct.phase("fetch")
         try:
             inflight.reap()
@@ -4446,9 +4518,17 @@ class GenerationEngine:
         stats() so tests and dashboards see the same verdict the loop
         acts on."""
         return self._pipeline.target(
-            latency_waiting=self._pending.qsize_class(SLO_LATENCY) > 0,
+            latency_admittable=self._latency_admittable(),
             lattice_deferred=self._lattice_deferred,
             spec_decode=bool(self._spec_k))
+
+    def _latency_admittable(self) -> bool:
+        """A latency-class request is waiting and a slot is free for it
+        (the two conditions _admit tests before it does anything): its
+        prefill can be dispatched now, so a second queued block would
+        stand in front of its first token."""
+        return (self._pending.qsize_class(SLO_LATENCY) > 0
+                and any(s.free for s in self._slots))
 
     def _note_depth(self, depth: int) -> None:
         if depth == self._depth_now:

@@ -432,6 +432,24 @@ def test_engine_slot_reuse_after_retire(engine, params):
         assert s.tokens() == _greedy(params, p, 6)
 
 
+def test_engine_standing_queue_token_exact_across_depths(params):
+    """12 latency-class requests on 4 slots (short, bucket and chunked
+    prompts, budgets that end inside a block): the same greedy tokens at
+    pipeline depth 1 and 2, with blocks queued behind reaps and behind
+    admission prefills at depth 2. The step's seventh output (the expert
+    counts) rides every reap's one fetch whatever the depth."""
+    from test_tpu_pipeline import _QUEUE_BUDGETS, standing_queue_outputs
+
+    prompts, outs = standing_queue_outputs(
+        lambda depth: GenerationEngine(
+            CFG, params, slots=4, max_seq=64, prompt_buckets=(8, 16),
+            decode_pipeline=depth),
+        256)
+    assert outs[1] == outs[2]
+    for i in (1, 6):
+        assert outs[2][i] == _greedy(params, prompts[i], _QUEUE_BUDGETS[i])
+
+
 def test_engine_counts_the_expert_layers_assignments(params):
     from gofr_tpu.metrics import Manager, register_framework_metrics
     from gofr_tpu.observe import Observe

@@ -104,6 +104,20 @@ def test_native_histogram_concurrent_records():
     assert abs(total - 10_000.0) < 1e-6
 
 
+def test_native_histogram_record_keeps_the_interpreter_lock():
+    """The wait-free record call is bound through PyDLL: a CDLL call
+    would hand the interpreter lock to any thread the recording loop
+    had just woken, once a sample. The calls that can block or copy
+    (snapshot, the queue) still let go of it."""
+    import ctypes
+
+    lib = native.load()
+    holds_lock = ctypes.PyDLL._func_flags_ & ~ctypes.CDLL._func_flags_
+    assert type(lib.hist_record)._flags_ & holds_lock
+    assert not type(lib.hist_snapshot)._flags_ & holds_lock
+    assert not type(lib.gq_pop_batch)._flags_ & holds_lock
+
+
 @pytest.mark.parametrize("use_native", [True, False])
 def test_batcher_backends_equivalent(use_native):
     seen = []

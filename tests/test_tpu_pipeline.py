@@ -15,11 +15,15 @@ tests pin the contracts that make that legal:
   - device failure mid-pipeline unwinds every in-flight dispatch,
     reseeds once, and the next admission is token-exact;
   - the depth policy (resilience.DecodePipelinePolicy) collapses to 1
-    while a latency-class admission waits or spec decode is on, and
+    while a latency-class request waits that a free slot can take, or
+    spec decode is on (a waiter on a full batch leaves it at 2), and
     stats() exposes the same verdict the loop acts on.
 """
 
+import importlib.util
+import os
 import time
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +34,8 @@ from gofr_tpu import chaos
 from gofr_tpu.errors import DeadlineExceeded
 from gofr_tpu.models import llama
 from gofr_tpu.models.common import LLAMA_CONFIGS
+from gofr_tpu.observe import Observe
+from gofr_tpu.observe.timeline import Timeline
 from gofr_tpu.resilience import Deadline, DecodePipelinePolicy
 from gofr_tpu.tpu import GenerationEngine
 from gofr_tpu.tpu.generator import GenerationError
@@ -64,7 +70,7 @@ def _reference_greedy(params, prompt, n):
 def test_pipeline_policy_verdicts():
     p = DecodePipelinePolicy(2)
     assert p.target() == 2
-    assert p.target(latency_waiting=True) == 1
+    assert p.target(latency_admittable=True) == 1
     assert p.target(lattice_deferred=True) == 1
     assert p.target(spec_decode=True) == 1
     assert DecodePipelinePolicy(1).target() == 1
@@ -281,22 +287,56 @@ def test_dispatch_failure_mid_topup_unwinds_pipe(tiny_params):
 
 # -- the depth policy in the live loop ---------------------------------------
 
-def test_depth_drops_while_latency_class_waits(tiny_params):
-    """Deterministic, stats-polled: with every slot busy and a
-    latency-class request queued, the next top-up targets depth 1; once
-    the queue drains it returns to the configured depth."""
+def _pipeline(eng) -> dict:
+    return eng.stats()["scheduler"]["pipeline"]
+
+
+def _poll(cond, timeout: float = 10.0) -> bool:
+    deadline = time.monotonic() + timeout
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.002)
+    return cond()
+
+
+@pytest.mark.parametrize("slot_free", [False, True])
+def test_depth_drops_only_for_an_admittable_waiter(tiny_params, slot_free):
+    """Stats-polled. Every slot busy and a latency-class request
+    queued: nothing can be admitted, so the top-ups keep targeting
+    depth 2 and reaps go on overlapping while it waits. A slot free
+    and a latency-class request queued (the loop held off admission by
+    the device lock): depth 1 until it is admitted, then 2 again."""
     eng = _engine(tiny_params, 2, slots=2)
     try:
-        bg = [eng.generate([2, 3 + i], max_new_tokens=48) for i in range(2)]
-        its = [iter(s) for s in bg]
-        for it in its:
-            next(it)  # both admitted: no free slot remains
-        waiter = eng.generate([9, 9], max_new_tokens=4)  # latency class
-        assert eng.stats()["scheduler"]["pipeline"]["target_depth"] == 1
+        n_bg = 1 if slot_free else 2
+        bg = [eng.generate([2, 3 + i], max_new_tokens=48)
+              for i in range(n_bg)]
+        for it in [iter(s) for s in bg]:
+            next(it)  # admitted and decoding
+        if slot_free:
+            with eng._device_lock:  # the loop cannot admit meanwhile
+                waiter = eng.generate([9, 9], max_new_tokens=4)
+                st = _pipeline(eng)
+                assert st["latency_admittable"]
+                assert st["depth"] == 2 and st["target_depth"] == 1
+            assert waiter.tokens()
+            assert _poll(lambda: _pipeline(eng)["target_depth"] == 2)
+            assert not _pipeline(eng)["latency_admittable"]
+        else:
+            before = _pipeline(eng)["overlapped_reaps"]
+            waiter = eng.generate([9, 9], max_new_tokens=4)  # latency
+            st = _pipeline(eng)
+            assert not st["latency_admittable"]
+            assert st["target_depth"] == 2
+            # the queue stands and the batch is full: block N+1 is
+            # queued while block N is reaped
+            assert _poll(
+                lambda: _pipeline(eng)["overlapped_reaps"] >= before + 3
+                or not eng.stats()["active"])
+            assert _pipeline(eng)["overlapped_reaps"] >= before + 3
+            assert waiter.tokens()  # served once a slot freed
+            assert _pipeline(eng)["target_depth"] == 2
         for s in bg:
-            s.cancel()
-        assert waiter.tokens()  # served once a slot freed
-        assert eng.stats()["scheduler"]["pipeline"]["target_depth"] == 2
+            s.tokens()
     finally:
         eng.close()
 
@@ -311,6 +351,243 @@ def test_spec_decode_pins_depth_one(tiny_params):
         # and the serving path stays exact through the forced depth
         got = eng.generate([5, 17, 42, 7], max_new_tokens=8).tokens()
         assert got == _reference_greedy(tiny_params, [5, 17, 42, 7], 8)
+    finally:
+        eng.close()
+
+
+# -- a standing latency-class queue, and the block behind a prefill ----------
+
+# 12 untagged (latency-class) requests on 4 slots: 8 stand in line from
+# the start. Short, bucket (8, 16) and chunked (> 16) prompts; budgets
+# that end inside a block of 4 as well as on its edge.
+_QUEUE_LENS = (40, 4, 7, 12, 26, 5, 16, 8, 33, 3, 14, 9)
+_QUEUE_BUDGETS = (10, 5, 7, 13, 6, 9, 11, 3, 8, 14, 2, 6)
+
+
+def standing_queue_outputs(make_engine, vocab):
+    """{depth: tokens of every request} for the workload above;
+    ``make_engine(depth)`` builds a 4-slot engine. Shared with the
+    latent family's test."""
+    rng = np.random.default_rng(29)
+    prompts = [rng.integers(1, vocab, n).tolist() for n in _QUEUE_LENS]
+    outs = {}
+    for depth in (1, 2):
+        eng = make_engine(depth)
+        _hold_blocks(eng)  # admissions find blocks in flight, as on a chip
+        try:
+            streams = [eng.generate(p, max_new_tokens=n)
+                       for p, n in zip(prompts, _QUEUE_BUDGETS)]
+            outs[depth] = [s.tokens() for s in streams]
+            st = eng.stats()["scheduler"]["pipeline"]
+            if depth == 2:
+                # the queue did not pin the loop at depth 1
+                assert st["overlapped_reaps"] > 0
+            else:
+                assert st["overlapped_reaps"] == 0
+        finally:
+            eng.close()
+    assert [len(o) for o in outs[1]] == list(_QUEUE_BUDGETS)
+    return prompts, outs
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_standing_latency_queue_token_exact_across_depths(tiny_params, paged):
+    prompts, outs = standing_queue_outputs(
+        lambda depth: _engine(tiny_params, depth, paged=paged),
+        TINY.vocab_size)
+    assert outs[1] == outs[2]
+    for i in (1, 6, 9):  # a short, a bucket-edge and a late-admitted one
+        assert outs[2][i] == _reference_greedy(tiny_params, prompts[i],
+                                               _QUEUE_BUDGETS[i])
+
+
+class _NotYet:
+    """A readiness probe that answers False to its first polls."""
+
+    def __init__(self, polls: int):
+        self.polls = polls
+
+    def is_ready(self) -> bool:
+        self.polls -= 1
+        return self.polls < 0
+
+
+def _hold_blocks(eng, polls: int = 2) -> None:
+    """Every dispatched block reads not-ready to the loop's first
+    polls, as a block does on a chip where it takes tens of
+    milliseconds: the tiny model's blocks are done before the loop
+    looks, and an admission would otherwise always find the pipe
+    reaped (the synchronous pass)."""
+    orig = eng._tick
+
+    def tick(**kw):
+        inflight = orig(**kw)
+        if inflight is not None:
+            inflight.arrays = (*inflight.arrays, _NotYet(polls))
+        return inflight
+
+    eng._tick = tick
+
+
+def _spy_trail(eng) -> list:
+    """Record (blocks on the pipe before, after) of every _trail call;
+    a call made while a latency-class waiter could be admitted (its
+    prefill goes first) must queue nothing."""
+    seen = []
+    orig = eng._trail
+
+    def trail():
+        before, waiter = len(eng._pipe), eng._latency_admittable()
+        orig()
+        assert not (waiter and len(eng._pipe) > max(before, 1))
+        seen.append((before, len(eng._pipe)))
+
+    eng._trail = trail
+    return seen
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_refilled_slot_behind_trailing_block_is_exact(tiny_params, paged):
+    """A slot freed and refilled while the other decodes: the refill's
+    prefill gets a decode block queued behind it before its first token
+    is fetched. In that block the new slot is inactive and its frozen
+    cursor is the prompt length: the garbage write must land outside
+    the prompt just written (paged: prompts of exactly one and two
+    blocks of 8, where a clamped table row would route it INTO the
+    last prompt block). Old and new requests return the reference's
+    greedy tokens."""
+    eng = _engine(tiny_params, 2, paged=paged, slots=2)
+    _hold_blocks(eng)
+    seen = _spy_trail(eng)
+    try:
+        rng = np.random.default_rng(31)
+        work = [(rng.integers(1, TINY.vocab_size, n).tolist(), new)
+                for n, new in ((6, 40), (5, 5), (8, 9), (16, 7), (11, 6))]
+        streams = [eng.generate(p, max_new_tokens=new) for p, new in work]
+        for (p, new), s in zip(work, streams):
+            assert s.tokens() == _reference_greedy(tiny_params, p, new)
+        # it engaged behind an un-reaped block (in-flight admission)
+        assert any(before >= 1 and after == before + 1
+                   for before, after in seen), seen
+        assert max(after for _, after in seen) <= 2  # never past the depth
+    finally:
+        eng.close()
+
+
+def test_trailing_block_waits_for_the_reserved_slots_waiter(tiny_params):
+    """Three slots, one reserved for the latency class. A throughput
+    and a latency request arrive together behind one decoding stream
+    (submitted under the device lock, so one in-flight pass sees
+    both): whichever prefill goes first, no block is queued behind it
+    while the latency request can still be admitted (_spy_trail holds
+    that), and none past the configured depth. Everybody's tokens are
+    the reference's."""
+    from gofr_tpu.resilience import SLO_THROUGHPUT
+
+    eng = _engine(tiny_params, 2, slots=3, slo_latency_slots=1)
+    _hold_blocks(eng, polls=4)
+    seen = _spy_trail(eng)
+    try:
+        rng = np.random.default_rng(41)
+        work = [(rng.integers(1, TINY.vocab_size, n).tolist(), new)
+                for n, new in ((6, 28), (7, 6), (8, 9))]
+        streams = [eng.generate(*work[0][:1], max_new_tokens=work[0][1])]
+        next(iter(streams[0]))  # decoding: blocks are in flight
+        with eng._device_lock:
+            streams.append(eng.generate(work[1][0], max_new_tokens=6,
+                                        slo_class=SLO_THROUGHPUT))
+            streams.append(eng.generate(work[2][0], max_new_tokens=9))
+        got = [streams[0].tokens()] + [s.tokens() for s in streams[1:]]
+        first = _reference_greedy(tiny_params, *work[0])
+        assert got[0] == first[1:]  # its first token went to next()
+        for (p, new), toks in zip(work[1:], got[1:]):
+            assert toks == _reference_greedy(tiny_params, p, new)
+        assert len(seen) == 3 and max(a for _, a in seen) <= 2, seen
+    finally:
+        eng.close()
+
+
+def test_chaos_step_between_prefill_and_trailing_block_recovers(tiny_params):
+    """A seeded GENERATOR_STEP DeviceLost fired by the top-up that
+    queues a block behind an admission's prefill: the admission under
+    way and the stream already decoding fail, the pipe is unwound, the
+    waiter still in line is served token-exact after recovery, and
+    nobody hangs."""
+    eng = _engine(tiny_params, 2, slots=2)
+    sched = chaos.ChaosSchedule(seed=0).on(
+        chaos.GENERATOR_STEP, error=chaos.DeviceLost, every=1, limit=1)
+    _hold_blocks(eng)
+    orig = eng._trail
+
+    def trail():
+        if not eng._pipe or sched.stats()["errors_fired"]:
+            return orig()
+        with chaos.scope(sched):  # only the loop thread fires this seam
+            orig()
+
+    try:
+        rng = np.random.default_rng(37)
+        work = [(rng.integers(1, TINY.vocab_size, n).tolist(), new)
+                for n, new in ((6, 40), (5, 5), (8, 9), (7, 6))]
+        want = _reference_greedy(tiny_params, *work[3])
+        eng._trail = trail
+        streams = [eng.generate(p, max_new_tokens=new) for p, new in work]
+        failed = 0
+        for s in streams[:3]:
+            try:
+                s.tokens()
+            except GenerationError:
+                failed += 1
+        assert sched.stats()["errors_fired"] == {chaos.GENERATOR_STEP: 1}
+        assert failed >= 2  # the long stream and the admission under way
+        assert streams[3].tokens() == want
+        assert eng.down is None and eng._recoveries == 1
+        assert not eng._pipe or eng.stats()["active"] == 0
+    finally:
+        eng.close()
+
+
+def test_overlapped_reap_reader_matches_the_programs_count(tiny_params):
+    """benchmarks/metrics/sched.overlapped_reap_pct.py reads, from the
+    decode events alone, the share the program counts itself
+    (overlapped_reaps / reaps): a block was queued behind a reap
+    exactly when the next decode event starts before this one ends."""
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "metrics", "sched.overlapped_reap_pct.py")
+    spec = importlib.util.spec_from_file_location("overlapped_reap", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+
+    def ev(t0, t1):
+        return (0, t0, t1 - t0, "decode", (0,), 4, 0, 0)
+
+    # dispatch 0..1, 0.5..2 (queued behind the first), 2.1..3 (alone)
+    ctx = SimpleNamespace(trace={"span": (0.0, 10.0)},
+                          timeline=[ev(0.0, 1.0), ev(0.5, 2.0),
+                                    ev(2.1, 3.0)])
+    assert reader.read(ctx) == pytest.approx(100.0 / 3)
+    # only reaps inside the traced span count
+    ctx.trace = {"span": (1.5, 10.0)}
+    assert reader.read(ctx) == pytest.approx(0.0)
+    assert reader.read(SimpleNamespace(trace=None, timeline=[])) is None
+    assert reader.read(SimpleNamespace(trace={"span": (0.0, 1.0)},
+                                       timeline=[])) is None
+
+    obs = Observe(timeline=Timeline(capacity=4096))
+    eng = _engine(tiny_params, 2, observe=obs)
+    try:
+        t0 = time.monotonic()
+        streams = [eng.generate([3, 1, 4, 1 + i], max_new_tokens=20 + 3 * i)
+                   for i in range(7)]
+        for s in streams:
+            s.tokens()
+        assert _poll(lambda: not eng._pipe)
+        st = eng.stats()["scheduler"]["pipeline"]
+        live = SimpleNamespace(trace={"span": (t0, time.monotonic())},
+                               timeline=obs.timeline.events())
+        assert 0 < st["overlapped_reaps"] < st["reaps"]
+        assert reader.read(live) == pytest.approx(
+            100.0 * st["overlapped_reaps"] / st["reaps"])
     finally:
         eng.close()
 

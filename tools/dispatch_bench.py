@@ -26,8 +26,8 @@ keeps >= 1 block queued at a majority of steady-state reaps, and admits
 Phase 2 (mixed load): background throughput-class decodes + latency-
 class TTFT probes on each arm. Gate: the pipelined arm's latency TTFT
 p50 stays within the noise bound of the serial arm's (the depth policy
-drops to 1 while a latency admission waits, so pipelining must not buy
-throughput with TTFT).
+drops to 1 while a latency-class request waits that a free slot can
+take, so pipelining must not buy throughput with TTFT).
 
 Conventions (tools/README.md): the LAST stdout line is the JSON
 artifact; ``--smoke`` is the CI gate (small shapes, same invariants);
