@@ -7,7 +7,7 @@ directory is part of the cache key, so it must never move:
   - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself — nothing
     here touches the directory config.
   - unset: ``<checkout>/.jax_cache`` (git-ignored), the same for the
-    server, ``bench.py``, ``chip_smoke.py`` and the tests.
+    server, ``benchmarks/run.py``, ``chip_smoke.py`` and the tests.
 
 Call before the first compile.
 

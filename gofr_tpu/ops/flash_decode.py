@@ -36,7 +36,7 @@ History, for whoever wants another A/B: v1 looped KV heads over 4-row
 matmuls on sub-tile slices and lost 1.8x to XLA; v2/v3 expanded q
 block-diagonally to one dense [H, KV*hd] matmul a tile on a
 (slot x 128-block) grid with a clamped index map, and lost its one chip
-A/B (2,309 against 2,709 tokens/s, BENCH_CANDIDATE.json, 2026-07-31) for
+A/B to the XLA path (a capture from before PERF_LEDGER.jsonl) for
 three reasons none of which was the tile geometry: it was handed the
 scan's per-layer slice, so the layer copy stayed in front of it; its
 grid was 640 steps a layer, most of them skipped blocks that still paid

@@ -128,7 +128,7 @@ def deadline_scope(deadline: Deadline | None):
 class DecodePipelinePolicy:
     """Depth policy for the generator's decode dispatch pipeline.
 
-    ``depth`` is the configured ceiling (TPU_DECODE_PIPELINE): how many
+    ``depth`` is the ceiling (the engine's ``decode_pipeline``, 2): how many
     fused decode blocks may be in flight on the device stream at once.
     Depth 2 is the steady-state win — the host reaps block N while
     block N+1 computes, so the device never idles between blocks — but

@@ -34,16 +34,6 @@ Config keys (reference config style, pkg/gofr/config/config.go:3):
                       the stream sees K tokens per roundtrip; raise on
                       high-latency links, lower toward 1 for tightest
                       per-token latency)
-  TPU_DECODE_PIPELINE fused decode blocks in flight on the device
-                      stream at once (default 2 — the loop dispatches
-                      block N+1 before reaping block N, overlapping
-                      host reap/delivery/admission with device compute;
-                      on-device stop masks keep finished streams from
-                      burning the extra in-flight block. 1 = the
-                      serial dispatch->reap loop. Depth auto-drops to
-                      1 while a latency-class admission waits, a chunk
-                      lattice is deferred, or spec decode is on —
-                      resilience.DecodePipelinePolicy)
   TPU_ADMIT_WINDOW_MS in-flight admission poll cadence in ms (default
                       2 — decode blocks dispatch async and new requests
                       are admitted while one runs, their prefill
@@ -420,7 +410,6 @@ def new_engine_from_config(cfg, logger=None, metrics=None,
                                   tracer=tracer, logger=logger),
             kv_dtype=kv_dtype,
             decode_block=cfg.get_int("TPU_DECODE_BLOCK", 4),
-            decode_pipeline=cfg.get_int("TPU_DECODE_PIPELINE", 2),
             admit_window_ms=cfg.get_float("TPU_ADMIT_WINDOW_MS", 2.0),
             prefill_chunk=_opt_int(cfg.get("TPU_PREFILL_CHUNK")),
             slo_throughput_share=cfg.get_float("TPU_SLO_THROUGHPUT_SHARE",

@@ -48,7 +48,7 @@ from ..resilience import (SLO_LATENCY, SLO_THROUGHPUT, DecodePipelinePolicy,
 from ..tenancy.fair import WeightedFairLine
 from ..tenancy.registry import current_tenant
 from ..wire import PushStream
-from . import hbm
+from . import hbm, programs
 from .batcher import pad_bucket
 from .kvcache import HostKV, ShardedHostKV, clamp_restore_len, dense_hostkv
 
@@ -145,96 +145,6 @@ class _ClassPending:
 
     def empty(self) -> bool:
         return not (self._lat or self._thr)
-
-
-def _copy_row(dst, src, dst_idx, src_idx):
-    """Copy one batch row of KV (+ scale planes): src[:, src_idx] ->
-    dst[:, dst_idx]. Shared by prefix-pool store (dst=pool) and load
-    (dst=serving cache); lengths are untouched — the slot cursor is set
-    by the chunk dispatches, the pool's lengths live host-side."""
-    import jax.lax as lax
-
-    def cp(d, s):
-        r = lax.dynamic_slice_in_dim(s, src_idx, 1, axis=1)
-        return lax.dynamic_update_slice_in_dim(d, r, dst_idx, axis=1)
-
-    # every array of a cache but ``lengths`` is [L, B, Smax, ...]: K, V
-    # and their scale planes, or a family's latent rows
-    return jax.tree_util.tree_map(
-        cp, dst._replace(lengths=None),
-        src._replace(lengths=None))._replace(lengths=dst.lengths)
-
-
-def _write_row_from_host(pool, k, v, ks, vs, row):
-    """Land a host KV slab in pool row ``row`` — the device half of a
-    T1/T2 restore (kvcache promotion). ``k``/``v`` arrive padded to
-    [L, 1, Smax, KV, hd] (scales [L, 1, Smax, KV]) so the program
-    compiles once; positions past the entry's length are zeros that the
-    resumed prefill overwrites or the cursor masks."""
-    import jax.lax as lax
-
-    def wr(dst, src):
-        return lax.dynamic_update_slice_in_dim(dst, src, row, axis=1)
-
-    quant = pool.k_scale is not None
-    return pool._replace(
-        k=wr(pool.k, k), v=wr(pool.v, v),
-        k_scale=wr(pool.k_scale, ks) if quant else None,
-        v_scale=wr(pool.v_scale, vs) if quant else None)
-
-
-def _write_row_from_host_masked(pool, k, v, ks, vs, row):
-    """GSPMD-friendly _write_row_from_host for SHARDED pools (mesh
-    engines' T1/T2 promotion): the dynamic_update_slice form puts a
-    traced start on the batch axis — the axis the pool shards over the
-    data mesh axes — and GSPMD's only lowering for that replicates the
-    whole pool (the _copy_row hazard). Select the destination row with
-    a one-hot mask and blend instead: ``src`` [L, 1, Smax, ...] arrives
-    replicated and broadcasts over the batch axis, every op partitions
-    cleanly under any batch/tp sharding. Reads the full pool once; that
-    extra HBM stream is the price of mesh support, paid only on a
-    promotion (not per token)."""
-    def wr(dst, src):
-        sel = (jnp.arange(dst.shape[1]) == row)
-        sel = sel.reshape((1, -1) + (1,) * (dst.ndim - 2))
-        return jnp.where(sel, src.astype(dst.dtype), dst)
-
-    quant = pool.k_scale is not None
-    return pool._replace(
-        k=wr(pool.k, k), v=wr(pool.v, v),
-        k_scale=wr(pool.k_scale, ks) if quant else None,
-        v_scale=wr(pool.v_scale, vs) if quant else None)
-
-
-def _copy_row_masked(dst, src, dst_idx, src_idx):
-    """GSPMD-friendly _copy_row for sharded engines. _copy_row's dynamic
-    slice/update puts a TRACED start index on the batch axis — the axis
-    kv_cache_specs shards over the data mesh axes — and GSPMD's only
-    lowering for that is replicating the whole cache (the same
-    involuntary-full-remat class as MULTICHIP_r03's embedding gather).
-    Mask-and-reduce instead: select the source row by one-hot mask and
-    sum over the batch axis (partitioned as local reduce + psum over the
-    data axes), then blend it into the destination row with an
-    elementwise where over a broadcast of the (replicated) row — every
-    op here partitions cleanly under any batch/tp sharding. Reads both
-    caches fully instead of one row each; that extra HBM stream is the
-    price of mesh support and stays well under one decode block."""
-    def cp(d, s):
-        sel_s = (jnp.arange(s.shape[1]) == src_idx)
-        sel_s = sel_s.reshape((1, -1) + (1,) * (s.ndim - 2))
-        # int8 KV sums exactly in int32 (one nonzero term per position)
-        acc = jnp.int32 if jnp.issubdtype(s.dtype, jnp.integer) else s.dtype
-        row = jnp.sum(jnp.where(sel_s, s, 0).astype(acc), axis=1,
-                      keepdims=True)                       # [L, 1, ...]
-        sel_d = (jnp.arange(d.shape[1]) == dst_idx)
-        sel_d = sel_d.reshape((1, -1) + (1,) * (d.ndim - 2))
-        return jnp.where(sel_d, row.astype(d.dtype), d)
-
-    quant = dst.k_scale is not None
-    return dst._replace(
-        k=cp(dst.k, src.k), v=cp(dst.v, src.v),
-        k_scale=cp(dst.k_scale, src.k_scale) if quant else None,
-        v_scale=cp(dst.v_scale, src.v_scale) if quant else None)
 
 
 class GenerationError(RuntimeError):
@@ -598,8 +508,10 @@ class GenerationEngine:
         # dispatch latency K-fold. Cost: a finished stream wastes at
         # most K-1 slot-steps, and admission waits at most one block.
         self.decode_block = max(1, int(decode_block))
-        # Decode dispatch pipeline (TPU_DECODE_PIPELINE): how many fused
-        # blocks may be in flight on the device stream at once. At depth
+        # Decode dispatch pipeline: how many fused blocks may be in
+        # flight on the device stream at once. No setting reaches this
+        # argument: serving runs at 2, and depth 1 stays as the
+        # reference the token-exactness tests compare against. At depth
         # 2 the loop dispatches block N+1 BEFORE reaping block N — all
         # of N+1's inputs (cache, PRNG key, slot-state carry) are device
         # futures chained from N's outputs, so the dispatch queues with
@@ -743,69 +655,11 @@ class GenerationEngine:
         # the loop thread's account of its own time and of the stream
         self._acct = _LoopAccount(self._tl, metrics)
         self.mesh = mesh
-        self.rope_tables = self._fam.get_rope_tables(cfg, self.max_seq)
-
-        # kv_dtype=jnp.int8 halves decode's cache HBM stream (quantize on
-        # write, dequant fused into attention) — the default for serving
-        # big models; None keeps the model dtype (exact numerics).
-        self._kv_dtype = kv_dtype
-        self._cache_sh = None  # set below for mesh engines
         self.down: str | None = None  # set when the device loop is bricked
-        # every persistent device buffer flows through hbm.alloc — the
-        # arbiter leases the bytes against the process budget BEFORE
-        # allocating (reclaiming other subsystems' holdings when it
-        # must), retries once on a real device OOM, and accounts the
-        # result (gofrlint GL202's choke point); keyed to this
-        # instance so close() releases exactly our bytes. The serving
-        # cache is PRI_SERVING: never auto-reclaimed, but the paged
-        # variant attaches the cold-prefix-block release so storms
-        # can still drain logical pool pressure. MESH engines compute
-        # their shardings FIRST (from eval_shape structs) so every
-        # buffer is BORN sharded and leased PER SHARD
-        # (hbm.alloc_sharded): the arbiter settles one lease entry per
-        # device, per-device budgets check each shard, and device-loss
-        # re-placement re-settles the same keys instead of
-        # double-counting.
-        self._rep_sh = None   # mesh: replicated sharding (set below)
-        self._pool_sh = None  # mesh: prefix-pool sharding (set below)
-        self._scratch_sh = None
-        self._dev_labels: tuple = ()
-        self._kv_shards = 1   # tp shards of the KV-head axis
         self._replacements = 0  # warm mesh re-placements survived
-        if self._paged:
-            from ..models.paged_llama import init_paged_cache
-
-            def _init_cache():
-                return self._born_sharded(
-                    lambda: init_paged_cache(cfg, slots, paged_blocks,
-                                             self._block_t, dtype=kv_dtype),
-                    self._cache_sh)
-
-            cache_reclaim = self._hbm_paged_reclaim
-        else:
-            def _init_cache():
-                return self._born_sharded(
-                    lambda: self._fam.init_cache(cfg, slots, self.max_seq,
-                                             dtype=kv_dtype),
-                    self._cache_sh)
-
-            cache_reclaim = None
         self._seed = int(seed)  # recovery reseeds the chained key
         self._recoveries = 0
         if mesh is not None:
-            # ICI-sharded serving (SURVEY §2 last row): KV heads over
-            # tp, slots over the data axes (paged pools: KV heads over
-            # tp only — the block axis stays whole for the global
-            # table). Params carry their own shardings (placed by the
-            # config wiring); out_shardings pin the cache layout so
-            # donation aliases buffers across steps and XLA never
-            # resharding-copies the cache. Collectives are emitted by
-            # XLA from the specs — nothing here names a device.
-            from ..parallel import (kv_cache_specs, kv_head_shards,
-                                    paged_cache_specs, replicated)
-
-            self._dev_labels = tuple(str(d.id) for d in mesh.devices.flat)
-            self._kv_shards = kv_head_shards(mesh, cfg.n_kv_heads)
             tp = mesh.shape.get("tp", 1)
             data = mesh.devices.size // max(tp * mesh.shape.get("sp", 1)
                                             * mesh.shape.get("pp", 1), 1)
@@ -834,28 +688,23 @@ class GenerationEngine:
                     f"n_kv_heads, or drop the data axes (dp/fsdp=1) to "
                     f"serve tp-only on the jnp fallback.",
                     sharding_row=row)
-            self._rep_sh = replicated(mesh)
-            struct = jax.eval_shape(_init_cache)  # _cache_sh still None
-            self._cache_sh = (paged_cache_specs(mesh, struct) if self._paged
-                              else kv_cache_specs(mesh, struct))
-            # commit the seed key to the replicated sharding NOW: the
-            # chained key outputs are rep-committed, and a first
-            # dispatch with an UNCOMMITTED key would occupy a different
-            # jit cache entry than every later one — warming one
-            # signature and serving the other re-lowers the program
-            # mid-serving under the device lock. (GL202 suppressed: a
-            # 16-byte PRNG key sits below accounting granularity — the
-            # arbiter leases buffers, not scalars.)
-            self._key = jax.device_put(jax.random.PRNGKey(seed), self._rep_sh)  # noqa: GL202, E501
-            self.cache = hbm.alloc_sharded(
-                "engine", _init_cache, owner=self, tag="cache",
-                priority=hbm.PRI_SERVING, reclaim=cache_reclaim,
-                devices=self._dev_labels)
-        else:
-            self._key = jax.random.PRNGKey(seed)
-            self.cache = hbm.alloc(
-                "engine", _init_cache, owner=self, tag="cache",
-                priority=hbm.PRI_SERVING, reclaim=cache_reclaim)
+        # The device side (programs.py): what the engine holds in HBM,
+        # how it is sharded and which programs run over it. kv_dtype=
+        # jnp.int8 halves decode's cache HBM stream (quantize on write,
+        # dequant fused into attention) — the default for serving big
+        # models; None keeps the model dtype (exact numerics). Buffers
+        # are allocated in the order cache, pool, scratch: the arbiter's
+        # reclaim order depends on it.
+        self._prog = programs.EnginePrograms(
+            cfg, self._fam, self, max_seq=self.max_seq, kv_dtype=kv_dtype,
+            decode_block=self.decode_block, n_adapters=self._n_adapters,
+            spec_k=max(0, int(spec_decode_k)), mesh=mesh,
+            paged=(paged_blocks, self._block_t) if self._paged else None)
+        self._prog.describe(
+            "cache", slots,
+            self._hbm_paged_reclaim if self._paged else None)
+        self._key = self._prog.key(self._seed)
+        self.cache = self._prog.allocate("cache")
         # what a decode step's attention fetches of a slot at cursor c: c
         # rounded up to this block where the flash-decode kernel takes
         # these shapes, every reserved position (None) on the reference
@@ -907,16 +756,14 @@ class GenerationEngine:
         # with one HBM row copy (T0) or a host->device upload + row
         # copy (T1/T2 promotion); the remainder (always >= 1 token, so
         # the first sample recomputes) prefills from the match point.
-        # On mesh engines the pool shards like the serving cache, the
-        # row copies run mask-and-reduce (_copy_row_masked) instead of
-        # traced-index dynamic slices (which GSPMD could only lower by
-        # replicating the cache), and the OFFLOAD tiers run PER-SHARD:
-        # T1 spills read each tp shard's head range straight off its
-        # own device shard (ShardedHostKV — no cross-device assembly
-        # on the spill path), T2 frames each shard through the
-        # unchanged int8 block codec under a fingerprint carrying the
-        # mesh shape, and promotion lands the assembled dense row via
-        # _write_row_from_host_masked (the same one-hot blend trick).
+        # On mesh engines the pool shards like the serving cache (its
+        # row programs are programs.py's *_masked forms) and the
+        # OFFLOAD tiers run PER-SHARD: T1 spills read each tp shard's
+        # head range straight off its own device shard (ShardedHostKV —
+        # no cross-device assembly on the spill path), T2 frames each
+        # shard through the unchanged int8 block codec under a
+        # fingerprint carrying the mesh shape, and promotion lands the
+        # assembled dense row.
         # (Paged engines built their zero-copy SharedPrefixIndex above
         # instead — no side pool, entries reference pool blocks.)
         self._pool = None
@@ -956,36 +803,12 @@ class GenerationEngine:
                             pass
                     opts = dataclasses.replace(opts, host_mb=0, redis=None)
 
-                def _init_pool():
-                    return self._born_sharded(
-                        lambda: self._fam.init_cache(cfg, prefix_cache_slots,
-                                                 self.max_seq,
-                                                 dtype=kv_dtype),
-                        self._pool_sh)
-
-                # PRI_CACHE with the shrink callback: under budget
-                # pressure from ANY subsystem the arbiter spills this
-                # pool's entries to the host tier and reallocates it
-                # smaller (_hbm_pool_reclaim) — T0 shrinks so e.g. a
-                # paged engine's lease in the same process proceeds.
-                # Mesh pools settle per-shard lease keys; pool shards
-                # like the serving cache (batch rows over the data
-                # axes when they divide, KV heads over tp).
-                if mesh is not None:
-                    from ..parallel import kv_cache_specs
-
-                    self._pool_sh = kv_cache_specs(
-                        mesh, jax.eval_shape(_init_pool))
-                    self._pool = hbm.alloc_sharded(
-                        "kvcache-t0", _init_pool, owner=self, tag="pool",
-                        priority=hbm.PRI_CACHE,
-                        reclaim=self._hbm_pool_reclaim,
-                        devices=self._dev_labels)
-                else:
-                    self._pool = hbm.alloc(
-                        "kvcache-t0", _init_pool,
-                        owner=self, tag="pool", priority=hbm.PRI_CACHE,
-                        reclaim=self._hbm_pool_reclaim)
+                # Mesh pools settle per-shard lease keys; the pool
+                # shards like the serving cache (batch rows over the
+                # data axes when they divide, KV heads over tp).
+                self._prog.describe("pool", prefix_cache_slots,
+                                    self._hbm_pool_reclaim)
+                self._pool = self._prog.allocate("pool")
                 layout = KVLayout(cfg.n_layers, *self._fam.kv_layout(cfg),
                                   self._pool.quantized,
                                   np.dtype(str(self._pool[0].dtype)),
@@ -1062,6 +885,7 @@ class GenerationEngine:
         self._tenant_leased: set[str] = set()   # live tenant:{id} leases
         self._gauge_tenants: set[str] = set()   # tenants ever gauged
 
+        self._build_jits()
         if self._paged and (self.max_seq - 1 > self._chunk
                             or self._prefix_idx is not None):
             # Long-prompt admission AND prefix-hit resume both run the
@@ -1070,22 +894,10 @@ class GenerationEngine:
             # then one dispatch lands the row in the pool
             # (paged_llama.write_row_to_blocks). The scratch costs one
             # slot-row of HBM (~67 MB at 8B/1024).
-            self._alloc_scratch()
-        self._build_jits()
+            self._ensure_scratch()
         self._thread = threading.Thread(target=self._loop, name="gofr-tpu-gen",
                                         daemon=True)
         self._thread.start()
-
-    @staticmethod
-    def _born_sharded(build, shardings):
-        """Run a cache-building thunk so a mesh engine's buffers are
-        created in their shards: built eagerly and then device_put, the
-        whole [L, slots, Smax, KV, hd] cache lands on the first chip
-        before it is split (3.8 GB of extra peak on device 0 at
-        8B/tp=4). ``shardings`` None = single device, build in place."""
-        if shardings is None:
-            return build()
-        return jax.jit(build, out_shardings=shardings)()
 
     def install_tenancy(self, plane) -> None:
         """Attach the multi-tenant serving plane (tenancy.TenantPlane).
@@ -1099,149 +911,17 @@ class GenerationEngine:
                 row_bytes = hbm.tree_nbytes(self._pool) // self._kvc.slots
             self._kvc.set_tenancy(plane.cache_shares, row_bytes=row_bytes)
 
-    def _alloc_scratch(self) -> None:
-        """Allocate the dense single-slot scratch row (paged chunk
-        lattice / prefix restore / PD ingest staging). Mesh engines
-        shard it like a one-row serving cache (KV heads over tp; the
-        batch axis is 1, so data axes fit to nothing) and settle it
-        per shard."""
-        def _init_scratch():
-            return self._born_sharded(
-                lambda: self._fam.init_cache(self.cfg, 1, self.max_seq,
-                                         dtype=self._kv_dtype),
-                self._scratch_sh)
+    def _build_jits(self, needs=None) -> None:
+        """Bind every compiled program the table (programs.TABLE) gives
+        this engine, or only those that need one of ``needs``."""
+        kvc = self._kvc
+        offload = kvc is not None and (kvc.wants_offload or kvc.shares)
+        for attr, prog in self._prog.build(needs, offload=offload).items():
+            setattr(self, attr, prog)
 
-        if self.mesh is not None:
-            from ..parallel import kv_cache_specs
-
-            self._scratch_sh = kv_cache_specs(
-                self.mesh, jax.eval_shape(_init_scratch))
-            self._scratch = hbm.alloc_sharded(
-                "engine", _init_scratch, owner=self, tag="scratch",
-                priority=hbm.PRI_SCRATCH, devices=self._dev_labels)
-        else:
-            self._scratch = hbm.alloc(
-                "engine", _init_scratch, owner=self, tag="scratch",
-                priority=hbm.PRI_SCRATCH)
-
-    def _build_jits(self) -> None:
-        """Build (or REBUILD) every compiled program. Factored out of
-        __init__ because warm device-loss re-placement compiles the
-        whole surface again: out_shardings pin donation aliasing, and
-        a sharding names its mesh, so programs built against a dead
-        mesh can never serve the replacement.
-
-        outputs: (token, logprob, next_key, cache) for prefill/
-        final-chunk, (tokens, logprobs, emitted, slot-state carry,
-        next_key, cache) for the fused step — sampling keys derive
-        in-trace from each request's (seed, absolute position) pair
-        (see _resume_keys; the threaded key is signature ballast), and
-        the carry chains the per-slot decode state — last token,
-        active, budget, position — the pipeline's next dispatch
-        consumes."""
-        mesh = self.mesh
-        if mesh is not None:
-            rep = self._rep_sh
-            cache_sh = self._cache_sh
-            prefill_fn = (self._paged_prefill_fn if self._paged
-                          else self._prefill_fn)
-            step_fn = self._paged_step_fn if self._paged else self._step_fn
-            self._prefill_jit = jax.jit(prefill_fn, donate_argnums=(0,),
-                                        out_shardings=(rep, rep, rep,
-                                                       cache_sh))
-            self._step_jit = jax.jit(step_fn, donate_argnums=(0,),
-                                     out_shardings=(rep, rep, rep,
-                                                    (rep, rep, rep, rep),
-                                                    rep, cache_sh, rep))
-            if self._spec_k:
-                verify_fn = (self._paged_verify_fn if self._paged
-                             else self._verify_fn)
-                self._verify_jit = jax.jit(verify_fn, donate_argnums=(0,),
-                                           out_shardings=(rep, rep, rep,
-                                                          cache_sh))
-            if self._paged:
-                if hasattr(self, "_scratch"):
-                    from ..models.paged_llama import (read_blocks_to_row,
-                                                      write_row_to_blocks)
-
-                    sc = self._scratch_sh
-                    self._chunk_mid_jit = jax.jit(self._chunk_mid,
-                                                  donate_argnums=(0,),
-                                                  out_shardings=sc)
-                    self._chunk_final_jit = jax.jit(self._chunk_final,
-                                                    donate_argnums=(0,),
-                                                    out_shardings=(rep, rep,
-                                                                   rep, sc))
-                    self._row_to_blocks_jit = jax.jit(write_row_to_blocks,
-                                                      donate_argnums=(0,),
-                                                      out_shardings=cache_sh)
-                    self._blocks_to_row_jit = jax.jit(read_blocks_to_row,
-                                                      donate_argnums=(0,),
-                                                      out_shardings=sc)
-            else:
-                self._chunk_mid_jit = jax.jit(self._chunk_mid,
-                                              donate_argnums=(0,),
-                                              out_shardings=cache_sh)
-                self._chunk_final_jit = jax.jit(self._chunk_final,
-                                                donate_argnums=(0,),
-                                                out_shardings=(rep, rep, rep,
-                                                               cache_sh))
-                if self._kvc is not None:
-                    self._build_pool_jits()
-        elif self._paged:
-            self._prefill_jit = jax.jit(self._paged_prefill_fn,
-                                        donate_argnums=(0,))
-            self._step_jit = jax.jit(self._paged_step_fn, donate_argnums=(0,))
-            if self._spec_k:
-                self._verify_jit = jax.jit(self._paged_verify_fn,
-                                           donate_argnums=(0,))
-            if hasattr(self, "_scratch"):
-                from ..models.paged_llama import (read_blocks_to_row,
-                                                  write_row_to_blocks)
-
-                self._chunk_mid_jit = jax.jit(self._chunk_mid,
-                                              donate_argnums=(0,))
-                self._chunk_final_jit = jax.jit(self._chunk_final,
-                                                donate_argnums=(0,))
-                self._row_to_blocks_jit = jax.jit(write_row_to_blocks,
-                                                  donate_argnums=(0,))
-                self._blocks_to_row_jit = jax.jit(read_blocks_to_row,
-                                                  donate_argnums=(0,))
-        else:
-            self._prefill_jit = jax.jit(self._prefill_fn, donate_argnums=(0,))
-            self._step_jit = jax.jit(self._step_fn, donate_argnums=(0,))
-            self._chunk_mid_jit = jax.jit(self._chunk_mid, donate_argnums=(0,))
-            self._chunk_final_jit = jax.jit(self._chunk_final,
-                                            donate_argnums=(0,))
-            if self._kvc is not None:
-                self._pool_load_jit = jax.jit(_copy_row, donate_argnums=(0,))
-                self._pool_store_jit = jax.jit(_copy_row, donate_argnums=(0,))
-                if self._kvc.wants_offload or self._kvc.shares:
-                    self._host_write_jit = jax.jit(_write_row_from_host,
-                                                   donate_argnums=(0,))
-            if self._spec_k:
-                self._verify_jit = jax.jit(self._verify_fn,
-                                           donate_argnums=(0,))
-
-    def _build_pool_jits(self) -> None:
-        """Mesh prefix-pool programs — split out because the arbiter's
-        pool SHRINK reallocates the pool at a new row count, whose
-        fitted sharding can differ (a batch axis the data axes no
-        longer divide replicates), so the shrink path rebuilds these
-        three against the new _pool_sh. The row copies run
-        mask-and-reduce; T1/T2 promotion lands the assembled dense
-        row via the one-hot blend (_write_row_from_host_masked) —
-        both GSPMD-clean under any batch/tp sharding."""
-        self._pool_load_jit = jax.jit(_copy_row_masked,
-                                      donate_argnums=(0,),
-                                      out_shardings=self._cache_sh)
-        self._pool_store_jit = jax.jit(_copy_row_masked,
-                                       donate_argnums=(0,),
-                                       out_shardings=self._pool_sh)
-        if self._kvc.wants_offload or self._kvc.shares:
-            self._host_write_jit = jax.jit(_write_row_from_host_masked,
-                                           donate_argnums=(0,),
-                                           out_shardings=self._pool_sh)
+    @property
+    def _kv_shards(self) -> int:
+        return self._prog.placed.kv_shards  # tp shards of the KV heads
 
     def _mesh_extra(self) -> str:
         """Fingerprint suffix carrying the KV shard layout: the T2
@@ -1268,7 +948,7 @@ class GenerationEngine:
         same shape when all answer — the chaos-simulated case and a
         hot-spare rejoin — or a shrunk plan, dp-first/tp-last, when
         chips are gone), re-place params, recompute every sharding
-        from the surviving buffer SHAPES, and rebuild the compiled
+        from the buffers' descriptions, and rebuild the compiled
         surface. The recovery code that runs next re-settles the same
         hbm lease keys per shard (account's group SET semantics — no
         double count even across a shape change) and rewarms T0 from
@@ -1285,16 +965,16 @@ class GenerationEngine:
         down — restart-and-reload is the path for that case until
         params can re-place from a host/checkpoint copy
         (docs/advanced-guide/multichip-serving.md, known limits)."""
-        from ..parallel import (kv_cache_specs, kv_head_shards,
-                                paged_cache_specs, remesh, replicated,
-                                shardings_for)
+        from ..parallel import remesh, shardings_for
 
         live = [d for d in self.mesh.devices.flat if self._device_alive(d)]
         lost = self.mesh.devices.size - len(live)
         new_mesh = remesh(self.mesh, live)
         self.mesh = new_mesh
-        self._dev_labels = tuple(str(d.id) for d in new_mesh.devices.flat)
-        self._rep_sh = replicated(new_mesh)
+        old_shards = self._kv_shards
+        # shardings recompute from the buffers' descriptions, so the
+        # reallocations that follow land placed on the new mesh
+        self._prog.place(new_mesh)
         # params re-place (a no-op data move when the mesh is
         # unchanged); the LoRA stacks ride along and re-settle their
         # lease via account's SET semantics right below. (GL202
@@ -1308,30 +988,16 @@ class GenerationEngine:
                       if k.startswith("lora_")}
             if stacks:
                 hbm.account("lora", stacks, owner=self)
-        # shardings recompute from the dead buffers' SHAPES (the aval
-        # outlives the donated storage), so the reallocs that follow
-        # land placed on the new mesh
-        self._cache_sh = (paged_cache_specs(new_mesh, self.cache)
-                          if self._paged
-                          else kv_cache_specs(new_mesh, self.cache))
-        if self._pool is not None:
-            self._pool_sh = kv_cache_specs(new_mesh, self._pool)
-        if hasattr(self, "_scratch"):
-            self._scratch_sh = kv_cache_specs(new_mesh, self._scratch)
-        new_shards = kv_head_shards(new_mesh, self.cfg.n_kv_heads)
-        if self._kvc is not None and new_shards != self._kv_shards:
+        if self._kvc is not None and self._kv_shards != old_shards:
             # the shard layout changed (degraded tp): T1 survives
             # (payloads assemble dense at promotion), T2 re-namespaces
             from .kvcache import model_fingerprint
 
-            self._kv_shards = new_shards
             self._kvc.rekey(
                 model_fingerprint(self.cfg, self.params,
                                   extra=str(self._kvc.layout.np_dtype)
                                   + self._mesh_extra()),
-                new_shards)
-        else:
-            self._kv_shards = new_shards
+                self._kv_shards)
         self._build_jits()
         self._replacements += 1
         if self.logger is not None:
@@ -1343,321 +1009,13 @@ class GenerationEngine:
                          zip(new_mesh.axis_names, new_mesh.devices.shape)
                          if v > 1}})
 
-    # top-k truncation width: per-request k is traced (no recompiles);
-    # ranks past k are masked within this fixed top set
-    TOP_K_MAX = 64
-
-    # on-device EOS stop-set width (llama.decode_stop_mask): requests
-    # with more stop ids than this keep host-side retirement as their
-    # only stop — still correct, the slot just burns up to a block of
-    # junk steps before the host notices. Never a compile key per
-    # request (the [B, EOS_MAX] matrix is fixed-shape dispatch data).
-    EOS_MAX = 8
-
-    # dispatch-pack column layout (_dispatch_pack / _fused_decode_scan
-    # must agree): 0 last_token, 1 active, 2 budget, 3 temp (f32 bits),
-    # 4 top_k, 5 adapter, 6 host_wins, 7 seed, 8 pos (absolute
-    # generated-token index of the slot's NEXT sample — the host-side
-    # truth the carry merge reads under host_wins), 9.. EOS set, then
-    # (paged) the block-table row
-    _PACK_EXTRA = 9
-
-    # -- jitted device functions --------------------------------------------
-    @staticmethod
-    def _resume_keys(seeds, pos):
-        """Per-slot sampling keys: fold_in(PRNGKey(seed), position).
-        Re-keying every sample on the request's seed and the ABSOLUTE
-        generated-token position (not the engine's chained key, not a
-        step count) is the durable-streams invariant: a continuation
-        admitted with ``continue_from`` samples token P with exactly
-        the key the original stream would have, on any replica."""
-        return jax.vmap(
-            lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
-        )(seeds, pos)
-
-    @jax.named_scope("sampling")
-    def _sample(self, logits, temps, keys, top_ks):
-        """Greedy where temp==0; categorical(logits/temp) otherwise,
-        truncated to the request's top-k logits when top_k > 0 — all
-        fused per-slot so mixed-sampling batches stay one program.
-        ``keys`` [B, ...]: one PRNG key per slot, derived by the caller
-        from (request seed, absolute position) — see _resume_keys."""
-        V = logits.shape[-1]
-        safe_t = jnp.maximum(temps, 1e-6)[:, None]
-        scaled = logits / safe_t
-        sampled = jax.vmap(jax.random.categorical)(keys, scaled)
-        kmax = min(self.TOP_K_MAX, V)
-        vals, idx = jax.lax.top_k(scaled, kmax)          # [B, kmax]
-        kk = jnp.minimum(jnp.where(top_ks > 0, top_ks, kmax), kmax)
-        vals = jnp.where(jnp.arange(kmax)[None, :] < kk[:, None],
-                         vals, -jnp.inf)
-        in_k = jax.vmap(jax.random.categorical)(keys, vals)
-        topk_tok = jnp.take_along_axis(idx, in_k[:, None], axis=1)[:, 0]
-        sampled = jnp.where(top_ks > 0, topk_tok, sampled)
-        greedy = jnp.argmax(logits, axis=-1)
-        tok = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
-        # logprob of the chosen token under the MODEL's (untempered)
-        # distribution — the number OpenAI-style logprobs report
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        lp = jnp.take_along_axis(logp, tok[:, None], axis=1)[:, 0]
-        return tok, lp
-
-    def _prefill_fn(self, cache, params, tokens, length, slot, temp,
-                    top_k, key, seed, pos, adapter=None):
-        """tokens [1, Sb] (padded), length/slot scalars. Writes the slot's
-        KV, sets its cursor, returns (first_token scalar, cache).
-        ``seed``/``pos``: the request's sampling seed and the absolute
-        position of the token sampled here (pos_base — 0 for a fresh
-        request, the emitted count for a continuation); ``key`` chains
-        through unchanged for signature stability."""
-        # flash prefill everywhere: bare Pallas calls do not partition
-        # under GSPMD, so on mesh engines ops.flash wraps the kernel in
-        # shard_map per head shard (jnp reference when tp would split a
-        # KV head) — the mesh= plumbing picks the form.
-        logits, *kv, _ = self._fam.prefill_kv(
-            params, self.cfg, tokens, jnp.asarray([length]),
-            rope_max=self.max_seq, rope_tables=self.rope_tables,
-            flash=True, mesh=self.mesh, adapter=adapter,
-            logit_pos=jnp.asarray([length - 1]))
-        lengths = cache.lengths.at[slot].set(length)
-        # the slot's row from position 0, every layer: (0, slot, 0, ...)
-        cache = self._fam.write_kv(
-            cache, *kv, (0, slot) + (0,) * (cache[0].ndim - 2), lengths)
-        last = logits[0, 0]  # [V] at the true prompt end (logit_pos)
-        tok, lp = self._sample(last[None, :], temp[None],
-                               self._resume_keys(seed[None], pos[None]),
-                               top_k[None])
-        return tok[0], lp[0], key, cache
-
-    def _chunk_fn(self, cache, params, tokens, start, slot, total_len,
-                  pos_in_chunk, temp, top_k, key, seed, pos, adapter,
-                  sample: bool):
-        """Chunked prefill for prompts longer than the largest bucket:
-        slice the slot's cache view, run one chunk against it, write back.
-        The final chunk (``sample=True``) also sets the slot's cursor to
-        ``total_len`` and samples the first token at ``pos_in_chunk``."""
-        Smax = cache[0].shape[2]
-        # the slot's view of every cache array ([L, 1, Smax, ...]: K, V
-        # and scale planes, or latent rows), and its write-back
-        arrays = cache._replace(lengths=None)
-        small = jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1),
-            arrays)._replace(lengths=jnp.zeros((1,), jnp.int32))
-        logits, small = self._fam.prefill_chunk(
-            params, self.cfg, tokens, small, start,
-            rope_tables=self.rope_tables, compute_logits=sample,
-            adapter=adapter,
-            logit_pos=jnp.asarray(pos_in_chunk)[None] if sample else None)
-        written = jax.tree_util.tree_map(
-            lambda a, s: jax.lax.dynamic_update_slice_in_dim(a, s, slot,
-                                                             axis=1),
-            arrays, small._replace(lengths=None))
-        if not sample:
-            # PARK the slot while its prompt is chunk-written: decode
-            # blocks interleave with mid-chunks, and every decode step
-            # scatter-writes garbage KV at each slot's cursor — a stale
-            # cursor inside [0, prompt_len) would corrupt KV this chunk
-            # just wrote. Cursor = capacity makes those writes land out
-            # of range, where mode="drop" discards them.
-            return written._replace(
-                lengths=cache.lengths.at[slot].set(Smax))
-        lengths = cache.lengths.at[slot].set(total_len)
-        last = logits[0, 0]  # [V] at pos_in_chunk (logit_pos)
-        tok, lp = self._sample(last[None, :], temp[None],
-                               self._resume_keys(seed[None], pos[None]),
-                               top_k[None])
-        return tok[0], lp[0], key, written._replace(lengths=lengths)
-
-    # methods, not functools.partial: jit names a program after its
-    # function, and a partial has no name (jit__unknown in a device trace)
-    def _chunk_mid(self, *args):
-        return self._chunk_fn(*args, sample=False)
-
-    def _chunk_final(self, *args):
-        return self._chunk_fn(*args, sample=True)
-
-    def _fused_decode_scan(self, cache, pack, carry, key, step_model):
-        """K fused decode steps over all slots (K = decode_block); one
-        dispatch returns [K, B] tokens + an emitted mask. Each step
-        feeds its sampled token to the next on device — the host is off
-        the per-token critical path entirely. Inactive cursors stay
-        frozen every step (their garbage KV scatter lands at the frozen
-        position, which admission either overwrites or — for parked
-        slots — drops), and attention is told which slots are active so
-        that it reads nothing of the others. ``step_model(tokens, cache,
-        active) -> (logits, stepped[, counters])`` is the only thing that
-        differs between the contiguous and paged engines and between
-        model families; what a family's step counts beside its logits
-        (the expert layer's assignments) comes back stacked a step as
-        the program's last output, for the reap's one fetch.
-
-        ``pack`` [B, W] int32 is the coalesced host dispatch state (one
-        h2d when dirty — see _dispatch_pack); ``carry`` is the device
-        slot-state chain (last token, active, budget, position)
-        returned by the PREVIOUS block — per slot, ``host_wins`` picks
-        which side is the truth (host after admission/retire/verify,
-        device in steady state). Chaining ACTIVE and BUDGET through the
-        device is what makes depth-2 pipelining exact: block N+1 is
-        dispatched before the host has seen block N's tokens, and a
-        stream that hits EOS/budget/capacity inside N self-deactivates
-        via the in-scan stop mask (llama.decode_stop_mask) so N+1
-        freezes it instead of emitting junk. ``emitted`` [K, B] tells
-        the host exactly which tokens are real — host delivery replays
-        it verbatim, so device stop masks and host retirement stay
-        token-equivalent.
-
-        Sampling keys derive in-trace from the pack's per-request SEED
-        and the carried absolute POSITION (fold_in(PRNGKey(seed), pos))
-        — never from a chained engine key — so a stream interrupted
-        anywhere and resumed via ``generate(continue_from=...)`` samples
-        the identical tokens (the durable-streams contract). Position
-        rides the device carry (not the pack) because under pipelining
-        the host cannot know block N's emitted count when it packs
-        block N+1; it advances only where a token was actually emitted,
-        so delivered token i of a request always consumed position
-        ``pos_base + i``. ``key`` chains through untouched (returned
-        as-is) purely for dispatch-signature stability."""
-        E = self.EOS_MAX
-        host_tokens = pack[:, 0]
-        host_active = pack[:, 1].astype(bool)
-        host_budget = pack[:, 2]
-        temps = jax.lax.bitcast_convert_type(pack[:, 3], jnp.float32)
-        top_ks = pack[:, 4]
-        host_wins = pack[:, 6].astype(bool)
-        seeds = pack[:, 7]
-        host_pos = pack[:, 8]
-        eos_ids = pack[:, self._PACK_EXTRA:self._PACK_EXTRA + E]
-        dev_tokens, dev_active, dev_budget, dev_pos = carry
-        tokens0 = jnp.where(host_wins, host_tokens, dev_tokens)
-        active0 = jnp.where(host_wins, host_active, dev_active)
-        budget0 = jnp.where(host_wins, host_budget, dev_budget)
-        pos0 = jnp.where(host_wins, host_pos, dev_pos)
-        # the host retires one delivered token before the cursor hits
-        # capacity (see _deliver's at_capacity): post-step cursors at
-        # max_seq - 2 mean the NEXT delivery would reach the bound
-        cap = jnp.int32(self.max_seq - 2)
-
-        def body(carry, _):
-            tokens, active, budget, pos, cache = carry
-            logits, stepped, *counters = step_model(tokens, cache, active)
-            lengths = jnp.where(active, stepped.lengths, cache.lengths)
-            stepped = stepped._replace(lengths=lengths)
-            toks, lps = self._sample(logits, temps,
-                                     self._resume_keys(seeds, pos),
-                                     top_ks)
-            toks = jnp.where(active, toks, tokens)
-            emitted = active
-            budget = jnp.where(active, budget - 1, budget)
-            # position advances only where a token was emitted: frozen
-            # slots must not burn positions, or a resume after their
-            # retirement would re-key mid-stream
-            pos = pos + emitted.astype(jnp.int32)
-            stop = active & llama.decode_stop_mask(toks, lengths, budget,
-                                                   eos_ids, cap)
-            return (toks, active & ~stop, budget, pos, stepped), \
-                (toks, lps, emitted, counters)
-
-        (last, active, budget, pos, cache), (toks, lps, emitted, counters) \
-            = jax.lax.scan(body, (tokens0, active0, budget0, pos0, cache),
-                           None, length=self.decode_block)
-        return (toks, lps, emitted, (last, active, budget, pos), key,
-                cache, counters)
-
-    def _verify_epilogue(self, logits, window, active, stepped):
-        """Shared verify-pass tail: greedy tokens + their logprobs, the
-        longest agreeing draft run per slot (accept), emit counts (the
-        +1 is the pass's guaranteed token; inactive slots emit 0), and
-        cursors advanced by exactly what the caller may deliver."""
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, W]
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        lps = jnp.take_along_axis(logp, greedy[..., None], axis=-1)[..., 0]
-        agree = (greedy[:, :-1] == window[:, 1:]).astype(jnp.int32)
-        accept = jnp.sum(jnp.cumprod(agree, axis=1), axis=1)     # [B]
-        emit = jnp.where(active, accept + 1, 0)
-        lengths = stepped.lengths + emit
-        return greedy, lps, emit, stepped._replace(lengths=lengths)
-
-    def _step_fn(self, cache, params, pack, carry, key):
-        adapter = pack[:, 5] if self._n_adapters else None
-
-        def step_model(tokens, cache, active):
-            return self._fam.decode_step(
-                params, self.cfg, tokens, cache,
-                rope_tables=self.rope_tables, adapter=adapter,
-                mesh=self.mesh, active=active)
-
-        return self._fused_decode_scan(cache, pack, carry, key, step_model)
-
-    def _paged_prefill_fn(self, cache, params, tokens, length, blocks,
-                          slot, temp, top_k, key, seed, pos,
-                          adapter=None):
-        """Paged admission: prefill the prompt, write its KV into the
-        slot's allocated ``blocks`` ([ceil(Sb/T)] int32 — entries past
-        the prompt's own blocks point at the trash block so bucket
-        padding lands nowhere), set the cursor, sample the first token
-        (re-keyed on ``seed``/``pos`` — see _resume_keys)."""
-        from ..models import paged_llama
-
-        # flash prefill everywhere — shard_map'd per head shard on mesh,
-        # same contract as the contiguous _prefill_fn
-        logits, k, v, _ = llama.prefill_kv(
-            params, self.cfg, tokens, jnp.asarray([length]),
-            rope_max=self.max_seq, rope_tables=self.rope_tables,
-            flash=True, mesh=self.mesh, adapter=adapter,
-            logit_pos=jnp.asarray([length - 1]))
-        cache = paged_llama.write_prompt_blocks(cache, k, v, blocks, length)
-        cache = cache._replace(lengths=cache.lengths.at[slot].set(length))
-        last = logits[0, 0]  # [V] at the true prompt end (logit_pos)
-        tok, lp = self._sample(last[None, :], temp[None],
-                               self._resume_keys(seed[None], pos[None]),
-                               top_k[None])
-        return tok[0], lp[0], key, cache
-
-    def _paged_verify_fn(self, cache, params, window, active, key, table,
-                         adapter=None):
-        """_verify_fn over the paged pool (models.paged_llama.
-        paged_verify_step): same greedy/accept/emit semantics, window KV
-        routed through the block table."""
-        from ..models import paged_llama
-
-        logits, stepped = paged_llama.paged_verify_step(
-            params, self.cfg, window, cache, table,
-            rope_tables=self.rope_tables, adapter=adapter,
-            flash=True, mesh=self.mesh)
-        return self._verify_epilogue(logits, window, active, stepped)
-
-    def _paged_step_fn(self, cache, params, pack, carry, key):
-        """_step_fn over the block pool. The table rides in the pack's
-        trailing [B, MB] columns — host-owned and constant through the
-        block (the host pre-allocates blocks covering K tokens per
-        slot)."""
-        from ..models import paged_llama
-
-        lo = self._PACK_EXTRA + self.EOS_MAX
-        table = pack[:, lo:lo + self._mb]
-        adapter = pack[:, 5] if self._n_adapters else None
-
-        def step_model(tokens, cache, active):
-            return paged_llama.paged_decode_step(
-                params, self.cfg, tokens, cache, table,
-                rope_tables=self.rope_tables, adapter=adapter,
-                flash=True, mesh=self.mesh)
-
-        return self._fused_decode_scan(cache, pack, carry, key, step_model)
-
-    def _verify_fn(self, cache, params, window, active, key, adapter=None):
-        """One speculative verify pass. ``window`` [B, W]: col 0 = each
-        slot's pending last token, cols 1.. = prompt-lookup drafts.
-        Greedy-only (callers route sampling slots to the decode path).
-        Returns (greedy [B, W], emit [B] — how many of greedy's leading
-        tokens are real, 0 for inactive slots) and the cache with
-        cursors advanced by emit. ``key`` is unused (greedy) but kept so
-        the signature matches _step_fn's calling convention."""
-        logits, stepped = llama.verify_step(params, self.cfg, window,
-                                            cache,
-                                            rope_tables=self.rope_tables,
-                                            adapter=adapter)
-        return self._verify_epilogue(logits, window, active, stepped)
+    # the widths and the dispatch-pack layout the host side shares with
+    # the traced functions (programs.py)
+    TOP_K_MAX = programs.TOP_K_MAX
+    EOS_MAX = programs.EOS_MAX
+    _PACK_EXTRA = programs.PACK_EXTRA
+    # the attribute that holds each described buffer
+    _BUFFER_ATTR = {"cache": "cache", "pool": "_pool", "scratch": "_scratch"}
 
     def _hist_set(self, idx: int, tokens) -> None:
         n = min(len(tokens), self._hist_buf.shape[1])
@@ -2101,82 +1459,63 @@ class GenerationEngine:
                 # (the largest bucket unless TPU_PREFILL_CHUNK bounds
                 # it) — and, with a prefix pool, for ANY hit (prefill
                 # resumes mid-prompt through the chunk lattice), so
-                # they must be warm whenever the pool exists
-                # paged engines chunk into the scratch row; warm those
-                # programs against it below instead of the serving cache
+                # they must be warm whenever the pool exists. They write
+                # the serving cache's free slot, or a paged engine's
+                # scratch row
                 C = self._chunk
-                paged_chunks = self._paged and hasattr(self, "_scratch")
-                chunked_reachable = (not self._paged
-                                     and (self.max_seq - 1 > C
-                                          or self._kvc is not None))
+                i32, zero = jnp.int32, jnp.int32(0)
+                if self._paged:
+                    row, slot = "_scratch", zero
+                    chunked = hasattr(self, "_scratch")
+                else:
+                    row, slot = "cache", i32(free)
+                    chunked = self.max_seq - 1 > C or self._kvc is not None
+
+                def tail():
+                    # (temp, top_k, key, seed, pos, adapter): the key as
+                    # the last call left it, as serving passes it
+                    return (jnp.float32(0.0), zero, self._key, zero, zero,
+                            self._adapter1(None))
+
                 for b in self.prompt_buckets:
                     if b > C:
                         # single-dispatch prefills and final chunks are
                         # both bounded by the chunk budget — wider
                         # buckets never dispatch
                         continue
-                    toks = jnp.zeros((1, b), jnp.int32)
-                    if paged_chunks:
-                        _, _, self._key, self._scratch = \
-                            jax.block_until_ready(self._chunk_final_jit(
-                                self._scratch, self.params, toks,
-                                jnp.int32(0), jnp.int32(0), jnp.int32(1),
-                                jnp.int32(0), jnp.float32(0.0),
-                                jnp.int32(0), self._key, jnp.int32(0),
-                                jnp.int32(0), self._adapter1(None)))
-                    if self._paged:
-                        # dummy KV lands in the trash block (blocks all
-                        # 0); the cursor restore below undoes lengths
-                        zeros = jnp.zeros((-(-b // self._block_t),),
-                                          jnp.int32)
-                        _, _, self._key, self.cache = jax.block_until_ready(
-                            self._prefill_jit(
-                                self.cache, self.params, toks, jnp.int32(1),
-                                zeros, jnp.int32(free), jnp.float32(0.0),
-                                jnp.int32(0), self._key, jnp.int32(0),
-                                jnp.int32(0), self._adapter1(None)))
-                    else:
-                        _, _, self._key, self.cache = jax.block_until_ready(
-                            self._prefill_jit(
-                                self.cache, self.params, toks, jnp.int32(1),
-                                jnp.int32(free), jnp.float32(0.0),
-                                jnp.int32(0), self._key, jnp.int32(0),
-                                jnp.int32(0), self._adapter1(None)))
-                    if chunked_reachable:
+                    toks = jnp.zeros((1, b), i32)
+                    # paged: dummy KV lands in the trash block (blocks
+                    # all 0); the cursor restore below undoes lengths
+                    blocks = (jnp.zeros((-(-b // self._block_t),), i32),) \
+                        if self._paged else ()
+                    _, _, self._key, self.cache = jax.block_until_ready(
+                        self._prefill_jit(
+                            self.cache, self.params, toks, i32(1), *blocks,
+                            i32(free), *tail()))
+                    if chunked:
                         # chunked-admission lattice: the final chunk
                         # compiles per bucket, mid chunks only at C
-                        _, _, self._key, self.cache = jax.block_until_ready(
+                        _, _, self._key, buf = jax.block_until_ready(
                             self._chunk_final_jit(
-                                self.cache, self.params, toks, jnp.int32(0),
-                                jnp.int32(free), jnp.int32(1), jnp.int32(0),
-                                jnp.float32(0.0), jnp.int32(0), self._key,
-                                jnp.int32(0), jnp.int32(0),
-                                self._adapter1(None)))
-                if chunked_reachable:
-                    toks = jnp.zeros((1, C), jnp.int32)
-                    self.cache = jax.block_until_ready(self._chunk_mid_jit(
-                        self.cache, self.params, toks, jnp.int32(0),
-                        jnp.int32(free), jnp.int32(0), jnp.int32(0),
-                        jnp.float32(0.0), jnp.int32(0), self._key,
-                        jnp.int32(0), jnp.int32(0), self._adapter1(None)))
-                if paged_chunks:
-                    toks = jnp.zeros((1, C), jnp.int32)
-                    self._scratch = jax.block_until_ready(
+                                getattr(self, row), self.params, toks, zero,
+                                slot, i32(1), zero, *tail()))
+                        setattr(self, row, buf)
+                if chunked:
+                    setattr(self, row, jax.block_until_ready(
                         self._chunk_mid_jit(
-                            self._scratch, self.params, toks, jnp.int32(0),
-                            jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                            jnp.float32(0.0), jnp.int32(0), self._key,
-                            jnp.int32(0), jnp.int32(0),
-                            self._adapter1(None)))
+                            getattr(self, row), self.params,
+                            jnp.zeros((1, C), i32), zero, slot, zero, zero,
+                            *tail())))
+                if chunked and self._paged:
                     self.cache = jax.block_until_ready(
                         self._row_to_blocks_jit(
                             self.cache, self._scratch,
-                            jnp.zeros((self._mb,), jnp.int32)))
+                            jnp.zeros((self._mb,), i32)))
                     # prefix-hit restore program (trash-block gather)
                     self._scratch = jax.block_until_ready(
                         self._blocks_to_row_jit(
                             self._scratch, self.cache,
-                            jnp.zeros((self._mb,), jnp.int32)))
+                            jnp.zeros((self._mb,), i32)))
             elif self.logger is not None:
                 self.logger.debug({"event": "generator warmup skipped prefill",
                                    "reason": "no free slot"})
@@ -2223,17 +1562,12 @@ class GenerationEngine:
                 # like the step warmup's.
                 window = jnp.zeros((self.n_slots, self._spec_k + 1),
                                    jnp.int32)
-                if self._paged:
-                    _, _, _, cache_w = self._verify_jit(
-                        self.cache, self.params, window,
-                        jnp.zeros((self.n_slots,), bool), self._key,
-                        jnp.zeros_like(jnp.asarray(self._table)),
-                        self._adapters())
-                else:
-                    _, _, _, cache_w = self._verify_jit(
-                        self.cache, self.params, window,
-                        jnp.zeros((self.n_slots,), bool), self._key,
-                        self._adapters())
+                table = (jnp.zeros_like(jnp.asarray(self._table)),) \
+                    if self._paged else ()
+                _, _, _, cache_w = self._verify_jit(
+                    self.cache, self.params, window,
+                    jnp.zeros((self.n_slots,), bool), self._key, *table,
+                    self._adapters())
                 self.cache = jax.block_until_ready(cache_w)
             # restore cursors dirtied by the dummy dispatches
             self.cache = self.cache._replace(lengths=jnp.asarray(cursors))
@@ -3299,8 +2633,8 @@ class GenerationEngine:
                   priority=hbm.PRI_SCRATCH)
         try:
             if self._ingest_write_jit is None:
-                self._ingest_write_jit = jax.jit(_write_row_from_host,
-                                                 donate_argnums=(0,))
+                self._ingest_write_jit = jax.jit(
+                    programs._write_row_from_host, donate_argnums=(0,))
             installed = self._run(
                 self._ingest_write_jit,
                 target, jnp.asarray(k_p), jnp.asarray(v_p),
@@ -3338,14 +2672,9 @@ class GenerationEngine:
         machinery long-prompt admission compiles."""
         if hasattr(self, "_scratch"):
             return
-        from ..models.paged_llama import (read_blocks_to_row,
-                                          write_row_to_blocks)
-
-        self._alloc_scratch()
-        self._row_to_blocks_jit = jax.jit(write_row_to_blocks,
-                                          donate_argnums=(0,))
-        self._blocks_to_row_jit = jax.jit(read_blocks_to_row,
-                                          donate_argnums=(0,))
+        self._prog.describe("scratch", 1)
+        self._scratch = self._prog.allocate("scratch")
+        self._build_jits(("scratch",))
 
     def _prefix_restore(self, idx: int, req: _Request, L: int,
                         C: int) -> int:
@@ -3532,30 +2861,14 @@ class GenerationEngine:
             self._pool = None
             del pool
             try:
-                if self.mesh is not None:
-                    from ..parallel import kv_cache_specs
-
-                    # FITTED fresh: the shrunk row count may stop
-                    # dividing the data axes (replicate instead), and
-                    # the pool programs must rebuild against whatever
-                    # the new placement actually is
-                    self._pool_sh = kv_cache_specs(
-                        self.mesh, jax.eval_shape(
-                            lambda: self._fam.init_cache(
-                                self.cfg, new_slots, self.max_seq,
-                                dtype=self._kv_dtype)))
-
-                def _smaller_pool():
-                    return self._born_sharded(
-                        lambda: self._fam.init_cache(self.cfg, new_slots,
-                                                 self.max_seq,
-                                                 dtype=self._kv_dtype),
-                        self._pool_sh)
-
-                self._pool = hbm.account("kvcache-t0", _smaller_pool(),
-                                         owner=self, tag="pool")
-                if self.mesh is not None:
-                    self._build_pool_jits()
+                # the pool's description at the new row count, FITTED
+                # fresh: the shrunk row count may stop dividing the data
+                # axes (replicate instead), and the pool programs must
+                # rebuild against whatever the new placement actually is
+                self._prog.describe("pool", new_slots,
+                                    self._hbm_pool_reclaim)
+                self._pool = self._prog.allocate("pool", lease=False)
+                self._build_jits(("pool", "offload"))
             except BaseException:
                 # even the SMALLER pool failed to allocate (we are, by
                 # definition, under memory pressure here). A None pool
@@ -3586,6 +2899,7 @@ class GenerationEngine:
         without one — requests keep serving, they just prefill fully."""
         kvc, self._kvc = self._kvc, None
         self._pool = None
+        self._prog.forget("pool")
         self._host_write_jit = None
         if kvc is not None and kvc.redis is not None:
             try:  # the engine owns the T2 client (KVCacheOptions.redis)
@@ -4187,8 +3501,8 @@ class GenerationEngine:
         # reap loop exactly; at depth 2 the loop keeps a SECOND block
         # queued on the device stream while reaping the first, so the
         # host-side reap/delivery/admission work overlaps device compute
-        # instead of idling it (BENCH_CANDIDATE.json, 2026-07-31, put
-        # that gap at ~23% of a block: a claim older than this code).
+        # instead of idling it (PERF_LEDGER.jsonl, PR 29: a full batch
+        # kept at depth 2 gave 7.8 to 23.0% more out_tok_s than at 1).
         # The invariant: this thread never does host work, and never
         # blocks on the device, with nothing queued behind the program
         # it waits for, unless the depth policy says a waiting
@@ -4233,201 +3547,122 @@ class GenerationEngine:
                     self._work.wait(timeout=0.05)
                     self._work.clear()
             except BaseException as e:  # noqa: BLE001 — waiters must not hang
-                # unwind EVERY in-flight dispatch first: their output
-                # futures (and the donated cache chained through them)
-                # died with the failure — reaping one would only
-                # re-raise the same error; recovery below reseeds ONCE
-                # for however many dispatches were in flight
-                pipe.clear()
-                self._acct.phase("other")  # the recovery path
-                if self._closed:
+                if not self._recover(e):
                     return
-                if self.logger is not None:
-                    self.logger.error({"event": "generation loop failed",
-                                       "error": repr(e)})
-                err = GenerationError(f"generation failed: {e!r}")
-                # A failed prefill/step may have consumed the DONATED cache
-                # buffer; continuing would serve every later request an
-                # opaque "donated buffer" error. Recovery runs in three
-                # phases, ordered so consumers neither observe stale
-                # state NOR hang behind device work:
-                #   1. host-side invariants (mirrors, PRNG epoch, prefix
-                #      index) — pure Python, cannot hang;
-                #   2. error delivery — waiters fail fast with every
-                #      host-observable invariant already consistent;
-                #   3. device reallocation — may block indefinitely on a
-                #      WEDGED device, which is exactly why it runs after
-                #      delivery. No admission can race it: only this
-                #      loop thread admits, and it is here.
-                with self._device_lock:
-                    # device-mirror buffers may have died with the
-                    # failed dispatch — rebuild them all on next use
-                    self._mirror.clear()
-                    self._pack = None
-                    self._pack_dirty = True
-                    self._last_dev = None
-                    self._acct.reset()
-                    self._host_wins[:] = True
-                    self._recoveries += 1
-                    if self._prefix_idx is not None:
-                        # paged entries reference blocks of the OLD
-                        # pool and would restore all-zero KV on a hit
-                        self._prefix_idx.clear()
-                    if self._kvc is not None:
-                        # tiered recovery: T0 entries die with the pool
-                        # (they'd match prompts against fresh zeroed
-                        # rows), but T1 host snapshots and T2 shared
-                        # blocks are device-independent and SURVIVE —
-                        # the next admission rewarns the new pool from
-                        # them instead of paying a full prefill
-                        self._kvc.clear_device()
-                # under the device lock: _retire mutates _active/_table/
-                # _cursors, and warmup()/swap_adapter() on OTHER threads
-                # hold the lock while reading slot state — an unlocked
-                # retire here could free a slot mid-warmup-prefill
-                with self._device_lock:
-                    for idx, slot in enumerate(self._slots):
-                        if slot.request is not None:
-                            slot.request.stream.failed = repr(e)
-                            slot.request.stream._q.put(err)
-                            self._retire(idx, slot)
-                try:
-                    with self._device_lock:
-                        if self.mesh is not None:
-                            # warm device-loss re-placement: rebuild
-                            # the mesh over live devices, re-place
-                            # params, recompute shardings, rebuild the
-                            # compiled surface — the reallocs below
-                            # then land placed on the NEW mesh and
-                            # re-settle the same per-shard lease keys
-                            self._replace_mesh()
-                        # the PRNG key chains THROUGH dispatches now: an
-                        # async failure leaves self._key bound to the
-                        # failed computation's error-state output, and
-                        # every later program would consume it and
-                        # re-raise forever — reseed from the host,
-                        # salted so recoveries don't replay the stream
-                        self._key = jax.random.PRNGKey(
-                            self._seed + self._recoveries)
-                        if self._rep_sh is not None:
-                            # (GL202 suppressed: 16-byte key — see
-                            # the mesh-init placement above)
-                            self._key = jax.device_put(self._key, self._rep_sh)  # noqa: GL202, E501
-                        if self._pool is not None:
-                            # _pool_store_jit donates the pool buffer —
-                            # a failed store leaves it consumed/poisoned
-                            def _realloc_pool():
-                                return jax.block_until_ready(
-                                    self._born_sharded(
-                                        lambda: self._fam.init_cache(
-                                            self.cfg, self._kvc.slots,
-                                            self.max_seq,
-                                            dtype=self._kv_dtype),
-                                        self._pool_sh))
 
-                            # re-lease + re-account (set semantics over
-                            # the lease group — mesh pools re-settle
-                            # the same per-shard keys, never double-
-                            # counting): the donated old pool died with
-                            # the failed dispatch, and the arbiter's
-                            # reclaim-then-retry covers a recovery that
-                            # lands while HBM is contended
-                            if self.mesh is not None:
-                                self._pool = hbm.alloc_sharded(
-                                    "kvcache-t0", _realloc_pool,
-                                    owner=self, tag="pool",
-                                    priority=hbm.PRI_CACHE,
-                                    reclaim=self._hbm_pool_reclaim,
-                                    devices=self._dev_labels)
-                            else:
-                                self._pool = hbm.alloc(
-                                    "kvcache-t0", _realloc_pool,
-                                    owner=self, tag="pool",
-                                    priority=hbm.PRI_CACHE,
-                                    reclaim=self._hbm_pool_reclaim)
-                        if self._paged:
-                            from ..models.paged_llama import init_paged_cache
+    def _recover(self, e: BaseException) -> bool:
+        """The loop failed with ``e``: fail the live streams, reallocate
+        what the device held, and say whether the loop may go on (False:
+        the engine is closed, or down).
 
-                            def _realloc_cache():
-                                return init_paged_cache(
-                                    self.cfg, self.n_slots,
-                                    self._alloc.n_blocks, self._block_t,
-                                    dtype=self._kv_dtype)
-
-                            cache_reclaim = self._hbm_paged_reclaim
-                            if hasattr(self, "_scratch"):
-                                # the chunk jits donate the scratch row
-                                # too — a failed chunk dispatch leaves it
-                                # consumed, bricking every later
-                                # long-prompt admission
-
-                                def _realloc_scratch():
-                                    return jax.block_until_ready(
-                                        self._born_sharded(
-                                            lambda: self._fam.init_cache(
-                                                self.cfg, 1, self.max_seq,
-                                                dtype=self._kv_dtype),
-                                            self._scratch_sh))
-
-                                if self.mesh is not None:
-                                    self._scratch = hbm.alloc_sharded(
-                                        "engine", _realloc_scratch,
-                                        owner=self, tag="scratch",
-                                        priority=hbm.PRI_SCRATCH,
-                                        devices=self._dev_labels)
-                                else:
-                                    self._scratch = hbm.alloc(
-                                        "engine", _realloc_scratch,
-                                        owner=self, tag="scratch",
-                                        priority=hbm.PRI_SCRATCH)
-                        else:
-                            def _realloc_cache():
-                                return self._fam.init_cache(self.cfg,
-                                                        self.n_slots,
-                                                        self.max_seq,
-                                                        dtype=self._kv_dtype)
-
-                            cache_reclaim = None
-
-                        def _realloc_placed():
-                            return jax.block_until_ready(
-                                self._born_sharded(_realloc_cache,
-                                                   self._cache_sh))
-
-                        if self.mesh is not None:
-                            self.cache = hbm.alloc_sharded(
-                                "engine", _realloc_placed, owner=self,
-                                tag="cache", priority=hbm.PRI_SERVING,
-                                reclaim=cache_reclaim,
-                                devices=self._dev_labels)
-                        else:
-                            self.cache = hbm.alloc(
-                                "engine", _realloc_placed, owner=self,
-                                tag="cache", priority=hbm.PRI_SERVING,
-                                reclaim=cache_reclaim)
-                    if self.logger is not None:
-                        self.logger.warn({"event": "generation cache "
-                                          "reallocated after device failure"})
-                except BaseException as e2:  # noqa: BLE001
-                    self.down = f"cache reallocation failed: {e2!r} " \
-                                f"(after: {e!r})"
-                    if self.logger is not None:
-                        self.logger.error({"event": "generation engine down",
-                                           "error": self.down})
-                if self.down is not None:
-                    # fail queued requests too — their consumers block on
-                    # the stream and no later iteration will admit them
-                    down_err = GenerationError(
-                        f"generation engine is down: {self.down}")
-                    while True:
-                        try:
-                            req = self._pending.get_nowait()
-                        except queue.Empty:
-                            break
-                        req.stream._q.put(down_err)
-                        req.stream._q.put(None)
-                        self._obs_end(req.stream, "failed", error=self.down)
-                    return
+        A failed prefill/step may have consumed the DONATED cache
+        buffer; continuing would serve every later request an opaque
+        "donated buffer" error. Recovery runs in three phases, ordered
+        so consumers neither observe stale state NOR hang behind device
+        work:
+          1. host-side invariants (mirrors, PRNG epoch, prefix index) —
+             pure Python, cannot hang;
+          2. error delivery — waiters fail fast with every
+             host-observable invariant already consistent;
+          3. device reallocation — may block indefinitely on a WEDGED
+             device, which is exactly why it runs after delivery. No
+             admission can race it: only this loop thread admits, and
+             it is here."""
+        # unwind EVERY in-flight dispatch first: their output futures
+        # (and the donated cache chained through them) died with the
+        # failure — reaping one would only re-raise the same error;
+        # recovery below reseeds ONCE for however many dispatches were
+        # in flight
+        self._pipe.clear()
+        self._acct.phase("other")  # the recovery path
+        if self._closed:
+            return False
+        if self.logger is not None:
+            self.logger.error({"event": "generation loop failed",
+                               "error": repr(e)})
+        err = GenerationError(f"generation failed: {e!r}")
+        with self._device_lock:
+            # device-mirror buffers may have died with the failed
+            # dispatch — rebuild them all on next use
+            self._mirror.clear()
+            self._pack = None
+            self._pack_dirty = True
+            self._last_dev = None
+            self._acct.reset()
+            self._host_wins[:] = True
+            self._recoveries += 1
+            if self._prefix_idx is not None:
+                # paged entries reference blocks of the OLD pool and
+                # would restore all-zero KV on a hit
+                self._prefix_idx.clear()
+            if self._kvc is not None:
+                # tiered recovery: T0 entries die with the pool (they'd
+                # match prompts against fresh zeroed rows), but T1 host
+                # snapshots and T2 shared blocks are device-independent
+                # and SURVIVE — the next admission rewarns the new pool
+                # from them instead of paying a full prefill
+                self._kvc.clear_device()
+        # under the device lock: _retire mutates _active/_table/
+        # _cursors, and warmup()/swap_adapter() on OTHER threads hold
+        # the lock while reading slot state — an unlocked retire here
+        # could free a slot mid-warmup-prefill
+        with self._device_lock:
+            for idx, slot in enumerate(self._slots):
+                if slot.request is not None:
+                    slot.request.stream.failed = repr(e)
+                    slot.request.stream._q.put(err)
+                    self._retire(idx, slot)
+        try:
+            with self._device_lock:
+                if self.mesh is not None:
+                    # warm device-loss re-placement: rebuild the mesh
+                    # over live devices, re-place params, recompute
+                    # shardings, rebuild the compiled surface — the
+                    # reallocations below then land placed on the NEW
+                    # mesh and re-settle the same per-shard lease keys
+                    self._replace_mesh()
+                # the PRNG key chains THROUGH dispatches now: an async
+                # failure leaves self._key bound to the failed
+                # computation's error-state output, and every later
+                # program would consume it and re-raise forever —
+                # reseed from the host, salted so recoveries don't
+                # replay the stream
+                self._key = self._prog.key(self._seed + self._recoveries)
+                # every live buffer again, not the serving cache alone:
+                # the pool's store program donates the pool (a failed
+                # store leaves it consumed/poisoned), and the chunk
+                # programs donate the scratch row (a failed chunk
+                # dispatch would brick every later long-prompt
+                # admission). Each re-leases and re-accounts under its
+                # own key, and the arbiter's reclaim-then-retry covers a
+                # recovery that lands while HBM is contended
+                for tag in self._prog.buffers:
+                    setattr(self, self._BUFFER_ATTR[tag],
+                            self._prog.allocate(tag))
+            if self.logger is not None:
+                self.logger.warn({"event": "generation cache "
+                                  "reallocated after device failure"})
+        except BaseException as e2:  # noqa: BLE001
+            self.down = f"cache reallocation failed: {e2!r} " \
+                        f"(after: {e!r})"
+            if self.logger is not None:
+                self.logger.error({"event": "generation engine down",
+                                   "error": self.down})
+        if self.down is None:
+            return True
+        # fail queued requests too — their consumers block on the
+        # stream and no later iteration will admit them
+        down_err = GenerationError(
+            f"generation engine is down: {self.down}")
+        while True:
+            try:
+                req = self._pending.get_nowait()
+            except queue.Empty:
+                break
+            req.stream._q.put(down_err)
+            req.stream._q.put(None)
+            self._obs_end(req.stream, "failed", error=self.down)
+        return False
 
     def _admit_inflight(self, inflight: _Inflight) -> None:
         """Admit new arrivals while a dispatched tick executes on device.

@@ -1,10 +1,10 @@
 """Device-memory arbiter: one HBM budget every subsystem leases from.
 
-``BENCH_CANDIDATE.json`` showed why this exists: the flat decode path
-is healthy at 2709 tok/s/chip while the prefix-cache, engine and
-speculative arms die with RESOURCE_EXHAUSTED and the paged engine OOMs
-at every batch size — each subsystem allocated HBM assuming it owned
-the whole device, and the first one to be wrong killed the process.
+Why this exists: the flat decode path ran healthy on a chip while the
+prefix-cache, engine and speculative configurations died with
+RESOURCE_EXHAUSTED and the paged engine ran out of memory at every
+batch size — each subsystem allocated HBM assuming it owned the whole
+device, and the first one to be wrong killed the process.
 This module is the arbitration point that makes the subsystems
 coexist: ONE budget, leased out per subsystem, with demand-driven
 reclaim and a shed path so an allocation failure degrades the
@@ -126,8 +126,7 @@ class HBMExhausted(TooManyRequests):
     429 with ``Retry-After`` on HTTP, RESOURCE_EXHAUSTED with the
     retry trailer on gRPC — through the same shed surface queue
     overload uses (resilience.AdmissionGate), instead of killing the
-    process the way an unhandled allocation failure did in
-    BENCH_CANDIDATE.json."""
+    process the way an unhandled allocation failure does."""
 
     def __init__(self, subsystem: str, nbytes: int, *,
                  budget: int | None = None, in_use: int | None = None,

@@ -2,7 +2,7 @@
 invisible — the kernel (interpret mode) matches the dense-gather
 reference, and paged_decode_step streams the exact tokens
 llama.decode_step does from an identically-seeded contiguous cache.
-Hardware existence is proven by bench.py's paged section, never here
+Hardware existence is proven by chip_smoke.py (TPU_PAGED_BLOCKS), never here
 (the r2 flash-kernel lesson)."""
 
 import zlib

@@ -1,13 +1,11 @@
 """Serial-vs-pipelined decode dispatch A/B (CPU; no chip lock).
 
-The 2026-07-31 device capture (BENCH_CANDIDATE.json) put the fused
-decode step at 35.43 ms but the end-to-end dispatched step at 46.15 ms:
-~23% of every decode block was host overhead — reap ``device_get``,
-Python token delivery, re-dispatch with a ~1.9 ms floor — during which
-the device sat idle. The depth-2 dispatch pipeline
-(``TPU_DECODE_PIPELINE``, docs/advanced-guide/serving-scheduler.md)
-closes that gap by keeping a second fused block queued on the device
-stream while the host reaps the first.
+Between two fused decode blocks the serial loop pays host work — reap
+``device_get``, Python token delivery, re-dispatch — during which the
+device sits idle. The depth-2 dispatch pipeline
+(docs/advanced-guide/serving-scheduler.md) closes that gap by keeping a
+second fused block queued on the device stream while the host reaps the
+first (on the chip: PERF_LEDGER.jsonl, PR 29).
 
 This harness proves the mechanism on the CPU backend, where the same
 loop runs with the same instrumentation:

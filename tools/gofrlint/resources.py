@@ -29,8 +29,8 @@ the arbiter's reclaim-then-retry lease form — wrapping the allocation
 or its local). Transient allocations that die with the function are not
 flagged — persistent buffers are exactly the arbiter's future lease
 targets, and an allocation the registry cannot see is capacity the
-arbiter cannot rebalance (the RESOURCE_EXHAUSTED cascade in
-BENCH_CANDIDATE.json). Allocations inside jit-traced functions are
+arbiter cannot rebalance (one subsystem's RESOURCE_EXHAUSTED then
+kills the process). Allocations inside jit-traced functions are
 traced, not eager HBM, and are exempt.
 
 GL203 — scope ``gofr_tpu/tpu/``. Unbounded request-path growth: an
