@@ -161,14 +161,14 @@ def kernel_cases(interpret: bool = False):
 
     def decode(quant):
         def run():
-            # read through a stacked cache [L, B, Smax, KV, D] at its last
+            # read through a stacked cache [L, B, KV, Smax, D] at its last
             # layer, as the serving step does; two dead slots
             lengths = jnp.asarray([0, 1, 127, 128, 129, 500, 0, 1023],
                                   jnp.int32)
             q, kn, vn = (rand(4, (b, 1, H, D)), rand(5, (b, 1, KV, D)),
                          rand(6, (b, 1, KV, D)))
-            kc, vc = (rand(7, (2, b, smax, KV, D)),
-                      rand(8, (2, b, smax, KV, D)))
+            kc, vc = (rand(7, (2, b, KV, smax, D)),
+                      rand(8, (2, b, KV, smax, D)))
             ks = vs = None
             if quant:
                 (kc, ks), (vc, vs) = quantize_kv(kc), quantize_kv(vc)
@@ -179,6 +179,26 @@ def kernel_cases(interpret: bool = False):
                 q, kc[1], vc[1], kn, vn, lengths,
                 None if ks is None else ks[1], None if vs is None else vs[1])
             return _max_err(got, ref)
+        return run
+
+    def append(quant):
+        def run():
+            # the step's write: a row a slot, all layers and KV heads, at
+            # word, tile and cache edges, one slot at capacity (dropped);
+            # every byte of both caches against XLA's scatter
+            pos = jnp.asarray([0, 1, 127, 128, 129, 500, smax - 1, smax],
+                              jnp.int32)
+            kc, vc, kr, vr = (rand(14, (2, b, KV, smax, D)),
+                              rand(15, (2, b, KV, smax, D)),
+                              rand(16, (2, b, KV, D)), rand(17, (2, b, KV, D)))
+            if quant:
+                kc, vc, kr, vr = (quantize_kv(x)[0] for x in (kc, vc, kr, vr))
+            got = flash_decode.append_rows_stacked(kc, vc, kr, vr, pos,
+                                                   interpret=interpret)
+            slots = jnp.arange(b)
+            return max(_max_err(g, c.at[:, slots, :, pos].set(
+                jnp.moveaxis(r, 1, 0), mode="drop"))
+                for g, c, r in zip(got, (kc, vc), (kr, vr)))
         return run
 
     def paged(w):
@@ -209,7 +229,9 @@ def kernel_cases(interpret: bool = False):
             ("paged_decode_attention[int8,T=128]", paged(1)),
             ("paged_window_attention[int8,T=128,W=5]", paged(5)),
             ("flash_decode_stacked[int8]", decode(True)),
-            ("flash_decode_stacked[bf16]", decode(False))]
+            ("flash_decode_stacked[bf16]", decode(False)),
+            ("append_rows_stacked[int8]", append(True)),
+            ("append_rows_stacked[bf16]", append(False))]
 
 
 def phase_kernels(summary: dict, rec: dict) -> None:

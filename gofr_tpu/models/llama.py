@@ -5,9 +5,11 @@ TPU-first design decisions:
     ``lax.scan`` — one compiled layer body regardless of depth (compile time
     flat in n_layers; the scan axis is also the natural pipeline-parallel
     split).
-  - The KV cache is preallocated [L, B, Smax, KV, hd] with a per-slot
-    ``lengths`` cursor, so continuous batching can retire/admit sequences
-    per batch slot without reshaping anything.
+  - The KV cache is preallocated [L, B, KV, Smax, hd], a KV head's
+    positions together (what the decode kernel folds is one head's
+    [block, hd] tile), with a per-slot ``lengths`` cursor, so continuous
+    batching can retire/admit sequences per batch slot without reshaping
+    anything.
   - Weights may be int8 ``QuantizedLinear`` leaves (ops.quant): decode is
     HBM-bound, so int8 halves the weight traffic per step.
   - All matmuls keep [*, dim] x [dim, out] shapes large and MXU-aligned;
@@ -52,16 +54,23 @@ def get_rope_tables(cfg: ModelConfig, max_seq: int):
 
 class KVCache(NamedTuple):
     """Preallocated decode cache. ``k``/``v`` are bf16 — or int8 when the
-    per-vector ``k_scale``/``v_scale`` [L, B, Smax, KV] are present (decode
+    per-vector ``k_scale``/``v_scale`` [L, B, KV, Smax] are present (decode
     is HBM-bound on cache+weight streaming; int8 KV halves the cache half
     of that traffic — see ops.quant.quantize_kv for the fused-dequant
-    scheme)."""
+    scheme). This is the one layout, for every backend and dtype; what
+    leaves the device (tpu.kvcache.HostKV, the Redis and P/D frames) is
+    [L, plen, KV, hd] and is transposed where it crosses."""
 
-    k: jnp.ndarray        # [L, B, Smax, KV, hd]
-    v: jnp.ndarray        # [L, B, Smax, KV, hd]
+    k: jnp.ndarray        # [L, B, KV, Smax, hd]
+    v: jnp.ndarray        # [L, B, KV, Smax, hd]
     lengths: jnp.ndarray  # [B] int32 — valid entries per slot
-    k_scale: jnp.ndarray | None = None  # [L, B, Smax, KV] f32 (int8 caches)
+    k_scale: jnp.ndarray | None = None  # [L, B, KV, Smax] f32 (int8 caches)
     v_scale: jnp.ndarray | None = None
+
+    @property
+    def capacity(self) -> int:
+        """Positions a slot holds (Smax)."""
+        return self.k.shape[3]
 
     @property
     def quantized(self) -> bool:
@@ -74,7 +83,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int | None = None,
     anything else is a plain dense cache in that dtype."""
     max_seq = max_seq or cfg.max_seq
     dtype = dtype or cfg.jdtype
-    shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_seq, cfg.head_dim)
     quant = jnp.dtype(dtype) == jnp.int8
     return KVCache(
         k=jnp.zeros(shape, dtype),
@@ -504,37 +513,37 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     """
     S = tokens.shape[1]
     x, (k_stack, v_stack), lengths, _ = _causal_scan(
-        params, cfg, tokens, lengths, cache.k.shape[2], rope_tables,
+        params, cfg, tokens, lengths, cache.capacity, rope_tables,
         constrain=None, collect_kv=True, flash=flash, adapter=adapter,
         mesh=mesh)
     # k_stack: [L, B, S, KV, hd] -> write into the cache's first S slots
-    if S > cache.k.shape[2]:
-        raise ValueError(f"prompt length {S} exceeds cache capacity {cache.k.shape[2]}")
+    if S > cache.capacity:
+        raise ValueError(f"prompt length {S} exceeds cache capacity {cache.capacity}")
     cache = write_kv(cache, k_stack, v_stack, (0, 0, 0, 0, 0), lengths)
     return _logits(params, cfg, x), cache
 
 
 @jax.named_scope("kv_write")
 def write_kv(cache: KVCache, k_stack, v_stack, index5, lengths) -> KVCache:
-    """Write bf16 KV stacks [L, B', S', KV, hd] into the cache at ``index5``
-    (a 5-tuple of start indices), quantizing on write for int8 caches.
-    Returns the cache with ``lengths`` replaced."""
+    """Write bf16 KV stacks [L, B', S', KV, hd], the order the layers
+    make them in, into the cache at ``index5`` (start indices in the
+    cache's own order, [L, B, KV, Smax, hd]), quantizing on write for int8
+    caches: the stacks are transposed here, once for all layers, in the
+    stored dtype. Returns the cache with ``lengths`` replaced."""
+    def put(dst, src, index):
+        return jax.lax.dynamic_update_slice(
+            dst, jnp.swapaxes(src, 2, 3).astype(dst.dtype), index)
+
     if cache.quantized:
         qk, sk = quantize_kv(k_stack)
         qv, sv = quantize_kv(v_stack)
         return KVCache(
-            k=jax.lax.dynamic_update_slice(cache.k, qk, index5),
-            v=jax.lax.dynamic_update_slice(cache.v, qv, index5),
+            k=put(cache.k, qk, index5), v=put(cache.v, qv, index5),
             lengths=lengths,
-            k_scale=jax.lax.dynamic_update_slice(cache.k_scale, sk, index5[:-1]),
-            v_scale=jax.lax.dynamic_update_slice(cache.v_scale, sv, index5[:-1]),
-        )
-    return KVCache(
-        k=jax.lax.dynamic_update_slice(
-            cache.k, k_stack.astype(cache.k.dtype), index5),
-        v=jax.lax.dynamic_update_slice(
-            cache.v, v_stack.astype(cache.v.dtype), index5),
-        lengths=lengths)
+            k_scale=put(cache.k_scale, sk, index5[:-1]),
+            v_scale=put(cache.v_scale, sv, index5[:-1]))
+    return KVCache(k=put(cache.k, k_stack, index5),
+                   v=put(cache.v, v_stack, index5), lengths=lengths)
 
 
 def prefill_kv(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -544,9 +553,9 @@ def prefill_kv(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     """Causal forward returning the raw KV stacks instead of a filled cache.
 
     The continuous-batching serving engine prefills ONE sequence at a time
-    and writes its KV into a single slot of a shared [L, B, Smax, KV, hd]
+    and writes its KV into a single slot of a shared [L, B, KV, Smax, hd]
     cache; handing back (k_stack, v_stack) [L, B, S, KV, hd] lets it
-    ``dynamic_update_slice`` into that slot without allocating a throwaway
+    write them (``write_kv``) into that slot without allocating a throwaway
     full-capacity cache per admission.
 
     ``logit_pos`` [B]: serving only samples ONE position per prompt —
@@ -590,7 +599,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     matmul — and the cache with KV written).
     """
     B, C = tokens.shape
-    cos, sin = rope_tables or get_rope_tables(cfg, cache.k.shape[2])
+    cos, sin = rope_tables or get_rope_tables(cfg, cache.capacity)
     positions = start + jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32),
                                          (B, C))
 
@@ -613,7 +622,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     x, (k_chunk, v_chunk) = jax.lax.scan(
         body, x, (params["layers"], cache.k, cache.v,
                   cache.k_scale, cache.v_scale))
-    cache = write_kv(cache, k_chunk, v_chunk, (0, 0, start, 0, 0),
+    cache = write_kv(cache, k_chunk, v_chunk, (0, 0, 0, start, 0),
                      cache.lengths)
     if not compute_logits:
         return None, cache
@@ -624,8 +633,8 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
 
 def verify_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
-                cache: KVCache, rope_tables=None,
-                adapter=None) -> tuple[jnp.ndarray, KVCache]:
+                cache: KVCache, rope_tables=None, adapter=None,
+                mesh=None) -> tuple[jnp.ndarray, KVCache]:
     """Multi-token verify pass — speculative decoding's target forward.
 
     ``tokens`` [B, W]: column 0 is each slot's pending last sampled
@@ -646,11 +655,12 @@ def verify_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
     CAPACITY CONTRACT: callers must ensure ``lengths + W <= capacity``
     for slots whose acceptance they will honor — rows past capacity are
-    scatter-dropped and must not be accepted.
+    dropped and must not be accepted. ``mesh``: as decode_step's, for
+    the rows' write.
     """
     cfg = multi_request_serving_config(cfg)
     B, W = tokens.shape
-    cos, sin = rope_tables or get_rope_tables(cfg, cache.k.shape[2])
+    cos, sin = rope_tables or get_rope_tables(cfg, cache.capacity)
     positions = cache.lengths[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
     lengths = cache.lengths
 
@@ -674,26 +684,11 @@ def verify_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     x, (k_w, v_w) = jax.lax.scan(
         body, x, (params["layers"], cache.k, cache.v,
                   cache.k_scale, cache.v_scale))
-    # one scatter for all layers and window rows: [L, B, W, KV, hd] ->
-    # cache[:, b, lengths[b] + j] (adjacent advanced indices broadcast)
+    # all layers and window rows at once: [L, B, W, KV, hd] ->
+    # cache[:, b, :, lengths[b] + j]
     with jax.named_scope("kv_write"):
-        b_idx = jnp.arange(B)[:, None]                       # [B, 1]
-        if cache.quantized:
-            qk, sk = quantize_kv(k_w)
-            qv, sv = quantize_kv(v_w)
-            new = KVCache(
-                k=cache.k.at[:, b_idx, positions].set(qk, mode="drop"),
-                v=cache.v.at[:, b_idx, positions].set(qv, mode="drop"),
-                lengths=lengths,
-                k_scale=cache.k_scale.at[:, b_idx, positions].set(sk, mode="drop"),
-                v_scale=cache.v_scale.at[:, b_idx, positions].set(sv, mode="drop"))
-        else:
-            new = KVCache(
-                k=cache.k.at[:, b_idx, positions].set(
-                    k_w.astype(cache.k.dtype), mode="drop"),
-                v=cache.v.at[:, b_idx, positions].set(
-                    v_w.astype(cache.v.dtype), mode="drop"),
-                lengths=lengths)
+        new = _write_rows(cache, k_w, v_w, positions, lengths, cfg.n_heads,
+                          mesh)
     return _logits(params, cfg, x), new
 
 
@@ -754,8 +749,9 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     (the current token's k/v ride alongside, see
     ``decode_attention_appended``), and the per-layer new-token k/v, the
     only novel data, [L, B, KV, hd], is written by ONE scatter into the
-    donated buffers after the loop. Emitting updated cache slices as scan
-    outputs instead would rewrite the entire cache every token.
+    donated buffers after the loop (``_write_rows``). Emitting updated
+    cache slices as scan outputs instead would rewrite the entire cache
+    every token.
 
     Which attention reads the cache is chosen from what can be observed,
     with no setting (ops.flash_decode.kernel_block): on a TPU, for shapes
@@ -764,7 +760,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     live blocks, in place; pass ``mesh`` on sharded jits, where the
     kernel runs under shard_map per head/batch shard. Otherwise
     (another backend, a head_dim that is not whole lanes, a tp that
-    splits a KV head) the scan slices each layer's [B, Smax, KV, hd]
+    splits a KV head) the scan slices each layer's [B, KV, Smax, hd]
     for ``decode_attention_appended``; on the chip XLA materialises
     that slice as a copy and attention then reads all Smax positions of
     it (PERF.md, Findings PR 25), which is why it is the fallback.
@@ -775,17 +771,16 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     frozen at its old value in the cache. None means all are.
 
     CAPACITY CONTRACT: callers must ensure ``lengths < cache capacity``
-    before stepping: at capacity the scatter index is out of range and
-    the write is dropped (JAX scatter OOB semantics; no data-dependent
-    errors are possible under jit). The serving engine retires slots
-    before they hit capacity.
+    before stepping: at capacity the row's position is out of range and
+    the write is dropped (no data-dependent errors are possible under
+    jit). The serving engine retires slots before they hit capacity.
     """
     from ..ops import flash_decode
 
     # slot isolation: grouped MoE dispatch would couple batch slots
     # (see multi_request_serving_config), so decode is forced dense
     cfg = multi_request_serving_config(cfg)
-    cos, sin = rope_tables or get_rope_tables(cfg, cache.k.shape[2])
+    cos, sin = rope_tables or get_rope_tables(cfg, cache.capacity)
     positions = cache.lengths[:, None]  # [B,1], this token's position
     lengths = cache.lengths
 
@@ -823,36 +818,56 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
               cache.v_scale)
     x, (k_toks, v_toks) = jax.lax.scan(body, x, xs)
     with jax.named_scope("kv_write"):
-        new = _scatter_step_kv(cache, k_toks, v_toks, lengths)
+        new = _write_rows(cache, k_toks, v_toks, positions, lengths + 1,
+                          cfg.n_heads, mesh)
     return _logits(params, cfg, x[:, 0]), new
 
 
-def _scatter_step_kv(cache: KVCache, k_toks, v_toks, lengths) -> KVCache:
-    """One scatter for all layers: the step's [L, B, 1, KV, hd] k/v go
-    to cache[:, b, lengths[b]]. The scales [L, B, KV] go there by a
-    select over the scale arrays, not a scatter: on the chip a scatter
-    wants its window (L, KV) minor, which for [L, B, Smax, KV] float32 is
-    a layout padded sixteenfold, and XLA then converts both arrays
-    between that and the compact one the attention reads, every step
-    (two copies of 0.6 ms each at 32 x 40 x 2,048 x 8, where the select
-    takes 0.5; PERF.md, Findings PR 25). A length at capacity matches no
-    position, so that write is dropped like the scatter's."""
-    slots = jnp.arange(k_toks.shape[1])
-    k_tok, v_tok = k_toks[:, :, 0], v_toks[:, :, 0]  # [L, B, KV, hd]
+def _write_rows(cache: KVCache, k_rows, v_rows, positions, lengths,
+                n_heads: int, mesh=None) -> KVCache:
+    """The rows a step made, [L, B, W, KV, hd] for all layers (W = 1: a
+    decode step; a verify window's W), to cache[:, b, :, positions[b, j]],
+    quantized on the way for int8 caches; the cache with ``lengths``
+    replaced. A position at or past capacity is dropped.
+
+    A position is one row of a KV head's (Smax, hd) tiles, and XLA writes
+    it only through a copy of the whole cache to a layout with that axis
+    major and one back (ops.flash_decode.append_rows_stacked, which is
+    the write wherever ``kernel_block`` answers: in place, the tiles
+    around each cursor read, merged and written). Elsewhere (the CPU, a
+    shape the kernels refuse) it is one scatter. The scales [L, B, W, KV]
+    go by a select over the scale arrays on every path: a scatter's
+    window is not theirs either (two layout copies of 0.6 ms each a
+    step at 32 x 40 x 2,048 x 8, where the select takes 0.5; PERF.md,
+    Findings PR 25)."""
+    from ..ops import flash_decode
+
+    def where_scales(scale, rows):      # [L, B, KV, Smax] <- [L, B, W, KV]
+        at = jnp.arange(scale.shape[3])
+        for j in range(positions.shape[1]):
+            here = (at[None, :] == positions[:, j, None])[None, :, None, :]
+            scale = jnp.where(here, rows[:, :, j, :, None], scale)
+        return scale
+
+    scales = {}
     if cache.quantized:
-        qk, sk = quantize_kv(k_tok)
-        qv, sv = quantize_kv(v_tok)
-        here = (jnp.arange(cache.k.shape[2])[None, :, None]
-                == lengths[:, None, None])[None]          # [1, B, Smax, 1]
-        return KVCache(
-            k=cache.k.at[:, slots, lengths].set(qk, mode="drop"),
-            v=cache.v.at[:, slots, lengths].set(qv, mode="drop"),
-            lengths=lengths + 1,
-            k_scale=jnp.where(here, sk[:, :, None, :], cache.k_scale),
-            v_scale=jnp.where(here, sv[:, :, None, :], cache.v_scale))
-    return KVCache(
-        k=cache.k.at[:, slots, lengths].set(
-            k_tok.astype(cache.k.dtype), mode="drop"),
-        v=cache.v.at[:, slots, lengths].set(
-            v_tok.astype(cache.v.dtype), mode="drop"),
-        lengths=lengths + 1)
+        k_rows, sk = quantize_kv(k_rows)
+        v_rows, sv = quantize_kv(v_rows)
+        scales = dict(k_scale=where_scales(cache.k_scale, sk),
+                      v_scale=where_scales(cache.v_scale, sv))
+    k_rows = k_rows.astype(cache.k.dtype)
+    v_rows = v_rows.astype(cache.v.dtype)
+    if flash_decode.kernel_block(n_heads, cache.k, mesh):
+        k, v = cache.k, cache.v
+        for j in range(positions.shape[1]):
+            k, v = flash_decode.append_rows(
+                k, v, k_rows[:, :, j], v_rows[:, :, j], positions[:, j],
+                n_heads=n_heads, mesh=mesh)
+    else:
+        # advanced indices around a slice: the result leads with [B, W]
+        b_idx = jnp.arange(positions.shape[0])[:, None]
+        k = cache.k.at[:, b_idx, :, positions].set(
+            jnp.transpose(k_rows, (1, 2, 0, 3, 4)), mode="drop")
+        v = cache.v.at[:, b_idx, :, positions].set(
+            jnp.transpose(v_rows, (1, 2, 0, 3, 4)), mode="drop")
+    return KVCache(k=k, v=v, lengths=lengths, **scales)

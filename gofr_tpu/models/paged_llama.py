@@ -272,87 +272,89 @@ def write_prompt_blocks(cache: PagedKVCache, k_stack, v_stack,
     slot's own blocks past its cursor, invisible behind ``lengths`` and
     overwritten as decode advances (the same contract as the contiguous
     cache's write_kv). Quantize-on-write, then one shared block-copy
-    loop (write_row_to_blocks) moves the rows."""
+    loop (_write_stacks_to_blocks) moves the rows."""
     if cache.quantized:
         qk, sk = quantize_kv(k_stack)
         qv, sv = quantize_kv(v_stack)
-        row = llama.KVCache(k=qk, v=qv, lengths=None, k_scale=sk,
-                            v_scale=sv)
-    else:
-        row = llama.KVCache(k=k_stack, v=v_stack, lengths=None)
-    return write_row_to_blocks(cache, row, blocks)
+        return _write_stacks_to_blocks(cache, qk, qv, sk, sv, blocks)
+    return _write_stacks_to_blocks(cache, k_stack, v_stack, None, None,
+                                   blocks)
 
 
 def read_blocks_to_row(row, cache: PagedKVCache,
                        blocks: jnp.ndarray):
     """Inverse of write_row_to_blocks: gather pool blocks into a dense
-    single-slot scratch row [L, 1, Smax, KV, hd] — the restore half of
+    single-slot scratch row (llama.KVCache with B=1,
+    [L, 1, KV, Smax, hd]) — the restore half of
     the paged prefix cache (shared blocks -> scratch, then chunked
     prefill resumes from the match point against the dense row).
     ``blocks`` [MB] int32: entries past the shared prefix may point
     anywhere (typically the trash block); those positions are
-    overwritten by the resumed chunks or ignored past the prompt."""
+    overwritten by the resumed chunks or ignored past the prompt. A
+    block is [T, KV, hd] and the row holds a KV head's positions
+    together: each block is transposed on its way."""
     T = cache.block_size
     mb = blocks.shape[0]
     k, v, ks, vs = row.k, row.v, row.k_scale, row.v_scale
     quant = cache.quantized
+
+    def block(pool, j, span):           # [L, 1, KV, span(, hd)]
+        blk = jax.lax.dynamic_slice(
+            pool, (0, blocks[j]) + (0,) * (pool.ndim - 2),
+            (pool.shape[0], 1, span) + pool.shape[3:])
+        return jnp.swapaxes(blk, 2, 3)
+
     for j in range(mb):
         lo = j * T
-        span = min(T, k.shape[2] - lo)
+        span = min(T, row.capacity - lo)
         if span <= 0:
             break
-        blk_k = jax.lax.dynamic_slice(
-            cache.k, (0, blocks[j], 0, 0, 0),
-            (cache.k.shape[0], 1, span) + cache.k.shape[3:])
-        blk_v = jax.lax.dynamic_slice(
-            cache.v, (0, blocks[j], 0, 0, 0),
-            (cache.v.shape[0], 1, span) + cache.v.shape[3:])
-        k = jax.lax.dynamic_update_slice(k, blk_k.astype(k.dtype),
-                                         (0, 0, lo, 0, 0))
-        v = jax.lax.dynamic_update_slice(v, blk_v.astype(v.dtype),
-                                         (0, 0, lo, 0, 0))
+        k = jax.lax.dynamic_update_slice(
+            k, block(cache.k, j, span).astype(k.dtype), (0, 0, 0, lo, 0))
+        v = jax.lax.dynamic_update_slice(
+            v, block(cache.v, j, span).astype(v.dtype), (0, 0, 0, lo, 0))
         if quant:
-            sk = jax.lax.dynamic_slice(
-                cache.k_scale, (0, blocks[j], 0, 0),
-                (cache.k_scale.shape[0], 1, span, cache.k_scale.shape[3]))
-            sv = jax.lax.dynamic_slice(
-                cache.v_scale, (0, blocks[j], 0, 0),
-                (cache.v_scale.shape[0], 1, span, cache.v_scale.shape[3]))
-            ks = jax.lax.dynamic_update_slice(ks, sk, (0, 0, lo, 0))
-            vs = jax.lax.dynamic_update_slice(vs, sv, (0, 0, lo, 0))
+            ks = jax.lax.dynamic_update_slice(
+                ks, block(cache.k_scale, j, span), (0, 0, 0, lo))
+            vs = jax.lax.dynamic_update_slice(
+                vs, block(cache.v_scale, j, span), (0, 0, 0, lo))
     return row._replace(k=k, v=v, k_scale=ks, v_scale=vs)
 
 
 def write_row_to_blocks(cache: PagedKVCache, row, blocks: jnp.ndarray,
                         ) -> PagedKVCache:
     """Copy a dense single-slot cache row (llama.KVCache with B=1,
-    [L, 1, S, KV, hd]; S may be shorter than MB*T — slices clamp) into
-    pool blocks. The shared block-copy loop under BOTH admission paths:
-    write_prompt_blocks quantizes a prefill's stacks into a row and
-    delegates here; long-prompt admission lands the chunked SCRATCH row
-    directly. ``blocks`` [n] int32: entries past the prompt's own
+    [L, 1, KV, S, hd]; S may be shorter than MB*T — slices clamp) into
+    pool blocks: long-prompt admission lands the chunked SCRATCH row
+    this way. ``blocks`` [n] int32: entries past the prompt's own
     blocks point at the trash block, so positions beyond the prompt
-    land nowhere. Same-dtype copy (int8 + scales move verbatim)."""
+    land nowhere. Same-dtype copy (int8 + scales move verbatim), each
+    block transposed to the pool's [T, KV, hd]."""
+    def stack(a):
+        return None if a is None else jnp.swapaxes(a, 2, 3)
+
+    return _write_stacks_to_blocks(cache, stack(row.k), stack(row.v),
+                                   stack(row.k_scale), stack(row.v_scale),
+                                   blocks)
+
+
+def _write_stacks_to_blocks(cache: PagedKVCache, k, v, ks, vs,
+                            blocks: jnp.ndarray) -> PagedKVCache:
+    """The block-copy loop under both admission paths: ``k``/``v``
+    [L, 1, S, KV, hd] (scales [L, 1, S, KV]) in the cache's dtype, block
+    j of T positions to pool block ``blocks[j]``."""
     T = cache.block_size
-    mb = blocks.shape[0]
-    k, v, ks, vs = cache.k, cache.v, cache.k_scale, cache.v_scale
-    quant = cache.quantized
-    for j in range(mb):
-        lo = j * T
-        k = jax.lax.dynamic_update_slice(
-            k, row.k[:, 0, lo:lo + T][:, None].astype(k.dtype),
-            (0, blocks[j], 0, 0, 0))
-        v = jax.lax.dynamic_update_slice(
-            v, row.v[:, 0, lo:lo + T][:, None].astype(v.dtype),
-            (0, blocks[j], 0, 0, 0))
-        if quant:
-            ks = jax.lax.dynamic_update_slice(
-                ks, row.k_scale[:, 0, lo:lo + T][:, None],
-                (0, blocks[j], 0, 0))
-            vs = jax.lax.dynamic_update_slice(
-                vs, row.v_scale[:, 0, lo:lo + T][:, None],
-                (0, blocks[j], 0, 0))
-    return cache._replace(k=k, v=v, k_scale=ks, v_scale=vs)
+    out = []
+    for pool, src in ((cache.k, k), (cache.v, v), (cache.k_scale, ks),
+                      (cache.v_scale, vs)):
+        if src is not None:
+            for j in range(blocks.shape[0]):
+                pool = jax.lax.dynamic_update_slice(
+                    pool, src[:, :, j * T:(j + 1) * T].astype(pool.dtype),
+                    (0, blocks[j]) + (0,) * (pool.ndim - 2))
+        out.append(pool)
+    return cache._replace(k=out[0], v=out[1], k_scale=out[2],
+                          v_scale=out[3])
 
 
 class BlockAllocator:

@@ -97,14 +97,15 @@ def decode_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
     attends over all ``Smax`` positions whatever ``lengths`` says, and
     handed a per-layer slice of the stacked cache by ``lax.scan`` it is
     preceded, on the chip, by a copy of that slice: XLA materialises each
-    layer's [B, Smax, KV, D] K and V (0.5 s of a 2.95 s trace, PERF.md
-    Findings PR 25), it does not read them in place.
+    layer's K and V (0.5 s of a 2.95 s trace, PERF.md Findings PR 25),
+    it does not read them in place.
 
-    q: [B, 1, H, D]; k_cache/v_cache: [B, Smax, KV, D];
+    q: [B, 1, H, D]; k_cache/v_cache: [B, KV, Smax, D], a layer of the
+    cache in its own order (models.llama.KVCache);
     k_new/v_new: [B, 1, KV, D]; lengths: [B] valid entries (EXCLUDING the
     current token). Returns [B, 1, H, D].
 
-    INT8 cache: when ``k_scale``/``v_scale`` [B, Smax, KV] are given the
+    INT8 cache: when ``k_scale``/``v_scale`` [B, KV, Smax] are given the
     cache tensors are per-vector int8 (ops.quant.quantize_kv). The scale is
     constant over the contracted head_dim, so it is applied to the SCORES
     (k side) and folded into the probabilities (v side) — both tiny
@@ -113,16 +114,15 @@ def decode_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
     dominant HBM stream. k_new/v_new stay bf16 (fresh this step).
     """
     b, _, h, d = q.shape
-    smax = k_cache.shape[1]
-    n_kv = k_cache.shape[2]
+    n_kv, smax = k_cache.shape[1], k_cache.shape[2]
     scale = d ** -0.5
 
     qg = _repeat_kv_shape(q * scale, n_kv)[:, 0]  # [B,KV,G,D]
-    scores_c = jnp.einsum("bkgd,btkd->bkgt", qg, k_cache.astype(qg.dtype),
+    scores_c = jnp.einsum("bkgd,bktd->bkgt", qg, k_cache.astype(qg.dtype),
                           preferred_element_type=jnp.float32)
     if k_scale is not None:
-        # k_scale [B,Smax,KV] -> [B,KV,1,Smax] to match scores [B,KV,G,Smax]
-        scores_c = scores_c * jnp.transpose(k_scale, (0, 2, 1))[:, :, None, :]
+        # k_scale [B,KV,Smax] beside scores [B,KV,G,Smax]
+        scores_c = scores_c * k_scale[:, :, None, :]
     valid = jnp.arange(smax)[None, :] < lengths[:, None]
     scores_c = jnp.where(valid[:, None, None, :], scores_c, NEG_INF)
     scores_s = jnp.einsum("bkgd,btkd->bkgt", qg, k_new,
@@ -131,9 +131,9 @@ def decode_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
                            axis=-1)
     probs_c = probs[..., :smax]
     if v_scale is not None:
-        probs_c = probs_c * jnp.transpose(v_scale, (0, 2, 1))[:, :, None, :]
+        probs_c = probs_c * v_scale[:, :, None, :]
     vdt = q.dtype if v_scale is not None else v_cache.dtype
-    out = (jnp.einsum("bkgt,btkd->bkgd", probs_c.astype(vdt),
+    out = (jnp.einsum("bkgt,bktd->bkgd", probs_c.astype(vdt),
                       v_cache.astype(vdt))
            + jnp.einsum("bkgt,btkd->bkgd", probs[..., smax:].astype(v_new.dtype),
                         v_new))
@@ -153,22 +153,20 @@ def window_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
     the appended decode step; unlike chunk_attention the prefix boundary
     is PER ROW (every slot sits at its own cursor).
 
-    q: [B, W, H, D]; k_cache/v_cache: [B, Smax, KV, D];
+    q: [B, W, H, D]; k_cache/v_cache: [B, KV, Smax, D];
     k_new/v_new: [B, W, KV, D]; lengths: [B] valid cache entries
     (EXCLUDING the window). Returns [B, W, H, D]. Int8 cache scales are
     applied score/prob-side exactly as in decode_attention_appended.
     """
     b, w, h, d = q.shape
-    smax = k_cache.shape[1]
-    n_kv = k_cache.shape[2]
+    n_kv, smax = k_cache.shape[1], k_cache.shape[2]
     scale = d ** -0.5
 
     qg = _repeat_kv_shape(q * scale, n_kv)  # [B,W,KV,G,D]
-    scores_c = jnp.einsum("bwkgd,btkd->bkgwt", qg, k_cache.astype(qg.dtype),
+    scores_c = jnp.einsum("bwkgd,bktd->bkgwt", qg, k_cache.astype(qg.dtype),
                           preferred_element_type=jnp.float32)
     if k_scale is not None:
-        scores_c = scores_c * jnp.transpose(
-            k_scale, (0, 2, 1))[:, :, None, None, :]
+        scores_c = scores_c * k_scale[:, :, None, None, :]
     valid = jnp.arange(smax)[None, :] < lengths[:, None]     # [B, Smax]
     scores_c = jnp.where(valid[:, None, None, None, :], scores_c, NEG_INF)
     scores_s = jnp.einsum("bwkgd,btkd->bkgwt", qg, k_new,
@@ -179,10 +177,9 @@ def window_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
                            axis=-1)
     probs_c = probs[..., :smax]
     if v_scale is not None:
-        probs_c = probs_c * jnp.transpose(
-            v_scale, (0, 2, 1))[:, :, None, None, :]
+        probs_c = probs_c * v_scale[:, :, None, None, :]
     vdt = q.dtype if v_scale is not None else v_cache.dtype
-    out = (jnp.einsum("bkgwt,btkd->bwkgd", probs_c.astype(vdt),
+    out = (jnp.einsum("bkgwt,bktd->bwkgd", probs_c.astype(vdt),
                       v_cache.astype(vdt))
            + jnp.einsum("bkgwt,btkd->bwkgd",
                         probs[..., smax:].astype(v_new.dtype), v_new))
@@ -201,23 +198,22 @@ def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     fixed-size chunks so arbitrary prompt lengths serve from a small
     lattice of compiled shapes.
 
-    q: [B, C, H, D]; k_cache/v_cache: [B, Smax, KV, D];
+    q: [B, C, H, D]; k_cache/v_cache: [B, KV, Smax, D];
     k_new/v_new: [B, C, KV, D]; start: scalar int32.
-    ``k_scale``/``v_scale`` [B, Smax, KV]: per-vector scales for int8
+    ``k_scale``/``v_scale`` [B, KV, Smax]: per-vector scales for int8
     caches (see decode_attention_appended — same fused-dequant scheme).
     Trailing padding inside the chunk is harmless: causality means padded
     positions are never attended BY valid ones. Returns [B, C, H, D].
     """
     b, c, h, d = q.shape
-    smax = k_cache.shape[1]
-    n_kv = k_cache.shape[2]
+    n_kv, smax = k_cache.shape[1], k_cache.shape[2]
     scale = d ** -0.5
 
     qg = _repeat_kv_shape(q * scale, n_kv)  # [B,C,KV,G,D]
-    scores_c = jnp.einsum("bskgd,btkd->bkgst", qg, k_cache.astype(qg.dtype),
+    scores_c = jnp.einsum("bskgd,bktd->bkgst", qg, k_cache.astype(qg.dtype),
                           preferred_element_type=jnp.float32)  # [B,KV,G,C,Smax]
     if k_scale is not None:
-        scores_c = scores_c * jnp.transpose(k_scale, (0, 2, 1))[:, :, None, None, :]
+        scores_c = scores_c * k_scale[:, :, None, None, :]
     in_prefix = jnp.arange(smax)[None, :] < start  # [1,Smax]
     scores_c = jnp.where(in_prefix[None, None, None], scores_c, NEG_INF)
     scores_n = jnp.einsum("bskgd,btkd->bkgst", qg, k_new,
@@ -228,9 +224,9 @@ def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
         jnp.concatenate([scores_c, scores_n], axis=-1), axis=-1)
     probs_c = probs[..., :smax]
     if v_scale is not None:
-        probs_c = probs_c * jnp.transpose(v_scale, (0, 2, 1))[:, :, None, None, :]
+        probs_c = probs_c * v_scale[:, :, None, None, :]
     vdt = q.dtype if v_scale is not None else v_cache.dtype
-    out = (jnp.einsum("bkgst,btkd->bskgd",
+    out = (jnp.einsum("bkgst,bktd->bskgd",
                       probs_c.astype(vdt), v_cache.astype(vdt))
            + jnp.einsum("bkgst,btkd->bskgd",
                         probs[..., smax:].astype(v_new.dtype), v_new))

@@ -1,5 +1,5 @@
 """Pallas TPU flash-decode attention: read the live part of the KV cache,
-once and in place.
+once and in place, and write the step's row where the cache already is.
 
 The decode step is bound by HBM traffic, and what the plain path
 (ops.attention.decode_attention_appended) streams is mostly padding: it
@@ -7,7 +7,7 @@ attends over all ``Smax`` positions of every slot, and inside the layer
 loop XLA first copies each layer's K and V out of the stacked cache
 (PERF.md, Findings PR 25: 13 of a 27 ms step where 23% of the pool was
 live). This kernel is handed the whole stacked cache
-``[L, B, Smax, KV, hd]`` and the layer index, and fetches, per layer and
+``[L, B, KV, Smax, hd]`` and the layer index, and fetches, per layer and
 step, each slot's blocks from position 0 to its length rounded up to
 ``block_s`` — nothing past it, nothing for a slot of length 0, no copy.
 
@@ -18,19 +18,17 @@ w is folded, across slot boundaries too, so a layer pays one DMA latency
 and not one a slot. Same mathematics as the reference: int8 K/V with the
 per-vector scales applied on the score and probability side, float32
 scores and softmax statistics. The current token's k/v, not yet in the
-cache (llama.decode_step defers the write to one post-scan scatter), is
-the recurrence's starting state (m = its score, l = 1, acc = its value),
-which is the exact flash combination; a slot of length 0 therefore
-returns its own value vector.
+cache (llama.decode_step defers the write to one post-loop
+``append_rows``), is the recurrence's starting state (m = its score,
+l = 1, acc = its value), which is the exact flash combination; a slot
+of length 0 therefore returns its own value vector.
 
-GQA: K and V tiles are [block_s, KV, hd] in the cache's own layout.
-Each is viewed as [block_s*KV, hd] rows (free: the (KV, hd) tile is the
-layout's own) and de-interleaved per KV head with a strided read, so
-every KV head does a [G, hd] x [hd, block_s] score matmul and a
-[G, block_s] x [block_s, hd] value matmul with its scale row
-[1, block_s] in the layout the scales have in HBM ([.., KV, Smax]: XLA
-stores ``[L, B, Smax, KV]`` float32 minor-to-major {2,3,1,0}, so the
-transposed view costs nothing).
+GQA: the cache is laid out a KV head at a time, so an item arrives as
+[KV, block_s, hd] and a KV head's [block_s, hd] tile is contiguous, whole
+(32, 128) int8 tiles high whatever the KV count. Each goes int8 ->
+compute dtype in registers and into a [G, hd] x [hd, block_s] score
+matmul and a [G, block_s] x [block_s, hd] value matmul, with its scale
+row [1, block_s] from the scales' own [.., KV, Smax] order.
 
 History, for whoever wants another A/B: v1 looped KV heads over 4-row
 matmuls on sub-tile slices and lost 1.8x to XLA; v2/v3 expanded q
@@ -41,14 +39,19 @@ three reasons none of which was the tile geometry: it was handed the
 scan's per-layer slice, so the layer copy stayed in front of it; its
 grid was 640 steps a layer, most of them skipped blocks that still paid
 the step; and it was given frozen cursors, so it streamed dead slots.
-Within this design, taking an int8 tile apart by byte (a shift pair on
-the tile viewed as 32-bit words, no staging in float32, KV heads left
-interleaved and masked in the scores) compiled and was exact but ran 3x
-slower than the strided read (13.3 against 4.7 ms, PERF.md Findings
-PR 25).
+Until PR 31 the cache was [L, B, Smax, KV, hd], positions and KV heads
+interleaved row by row in a tile: the kernel converted the whole int8
+tile to float32, stored it to a staging buffer and read each KV head
+back with a stride of KV, about 1,000 vector stores and 1,000 strided
+loads an item where the item's DMA needs 0.64 us (3.7 ms a step at
+batch-sat's lengths where the bytes need 2.0; taking the tile apart by
+byte instead, a shift pair on 32-bit words, was exact and 3x slower
+still, 13.3 against 4.7 ms, PERF.md Findings PR 25). The staging buffer,
+the strided read and the refusal of fewer than four int8 KV heads a
+chip all went with the layout (PERF.md, Findings PR 31).
 
 Sharding: a pallas_call is opaque to the GSPMD partitioner, so on a mesh
-the kernel runs under ``shard_map`` over the tp (and data) axes: every
+the kernels run under ``shard_map`` over the tp (and data) axes: every
 device walks its local [KV/tp] head shard of the stacked cache, no
 collective inside attention. The reference stays the path for shapes
 and backends the kernel cannot take (``decode_attention_auto``).
@@ -75,10 +78,15 @@ def block_size(smax: int) -> int:
     matmul, softmax, matmul that nothing overlaps), smaller ones fetch
     less past a slot's length (half a block a live slot on average). On
     the v5e, 32 layers of Mistral-7B's attention at 40 slots x 2,048
-    took 4.4 / 3.7 / 4.7 ms at 128 / 256 / 512 with a quarter of the
-    pool live, 1.7 / 1.5 / 1.8 ms with 14 slots busy, and 15.7 / 11.9 /
-    12.0 ms with all of it live, where the reference and its layer copy
-    take 13.6 (PERF.md, Findings PR 25)."""
+    took 4.0 / 2.7 / 3.1 ms at 128 / 256 / 512 with a quarter of the
+    pool live, 1.1 / 0.9 / 1.0 ms with 14 slots busy, and 12.4 / 7.6 /
+    7.6 ms with all of it live, 5.4 GB that the chip streams in 6.6
+    (PERF.md, Findings PR 31; before the cache was laid out a KV head
+    at a time: 4.2 / 1.2 / 11.8 at 256 on the same lengths). With two
+    items in flight and not three (``_KV_BUF``) the same three read
+    3.4 / 1.0 / 9.8 at 256: an item's DMA takes 0.64 us and its latency
+    is not hidden behind one item's fold; a fourth buffer adds nothing.
+    ``ops.mla`` shares this constant."""
     from .flash import fit_block
 
     return fit_block(smax, 256)
@@ -99,7 +107,8 @@ def _work_list(lengths, smax: int, block_s: int):
     return ends[-1:].astype(jnp.int32), slot, blk.astype(jnp.int32)
 
 
-_N_BUF = 2     # item w+1 in flight while item w is folded
+_N_BUF = 2     # item w+1 in flight while item w is folded (ops.mla)
+_KV_BUF = 3    # items w+1 and w+2 in flight here: see block_size
 
 
 def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool):
@@ -108,43 +117,41 @@ def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool):
     q_ref, kn_ref, vn_ref, k_hbm, v_hbm = refs[5:10]
     if quant:
         ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf = refs[10:17]
-        kf_ref, vf_ref, m_ref, l_ref, acc_ref, sem = refs[17:]
+        m_ref, l_ref, acc_ref, sem = refs[17:]
     else:
         o_ref, kbuf, vbuf = refs[10:13]
-        kf_ref, vf_ref, m_ref, l_ref, acc_ref, sem = refs[13:]
+        m_ref, l_ref, acc_ref, sem = refs[13:]
     layer = layer_ref[0]
     n = n_ref[0]
     cdt = q_ref.dtype
 
     def copies(w, buf):
         slot = slot_ref[w]
-        start = pl.multiple_of(blk_ref[w] * block_s, block_s)
-        cs = [pltpu.make_async_copy(
-                  k_hbm.at[layer, slot, pl.ds(start, block_s)],
-                  kbuf.at[buf], sem.at[0, buf]),
-              pltpu.make_async_copy(
-                  v_hbm.at[layer, slot, pl.ds(start, block_s)],
-                  vbuf.at[buf], sem.at[1, buf])]
+        here = pl.ds(pl.multiple_of(blk_ref[w] * block_s, block_s), block_s)
+        cs = [pltpu.make_async_copy(k_hbm.at[layer, slot, :, here],
+                                    kbuf.at[buf], sem.at[0, buf]),
+              pltpu.make_async_copy(v_hbm.at[layer, slot, :, here],
+                                    vbuf.at[buf], sem.at[1, buf])]
         if quant:
-            cs += [pltpu.make_async_copy(
-                       ks_hbm.at[layer, slot, :, pl.ds(start, block_s)],
-                       ksbuf.at[buf], sem.at[2, buf]),
-                   pltpu.make_async_copy(
-                       vs_hbm.at[layer, slot, :, pl.ds(start, block_s)],
-                       vsbuf.at[buf], sem.at[3, buf])]
+            cs += [pltpu.make_async_copy(ks_hbm.at[layer, slot, :, here],
+                                         ksbuf.at[buf], sem.at[2, buf]),
+                   pltpu.make_async_copy(vs_hbm.at[layer, slot, :, here],
+                                         vsbuf.at[buf], sem.at[3, buf])]
         return cs
 
-    @pl.when(n > 0)
-    def _first():
-        for c in copies(0, 0):
-            c.start()
+    for ahead in range(_KV_BUF - 1):
+        @pl.when(n > ahead)
+        def _first():
+            for c in copies(ahead, ahead):
+                c.start()
 
     def item(w, _):
-        buf = w % _N_BUF
+        buf = w % _KV_BUF
+        ahead = w + _KV_BUF - 1         # into the buffer item w-1 left
 
-        @pl.when(w + 1 < n)
+        @pl.when(ahead < n)
         def _next():
-            for c in copies(w + 1, (w + 1) % _N_BUF):
+            for c in copies(ahead, ahead % _KV_BUF):
                 c.start()
 
         for c in copies(w, buf):
@@ -163,12 +170,6 @@ def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool):
             l_ref[...] = jnp.ones_like(l_ref)
             acc_ref[...] = vn_ref[slot].astype(jnp.float32)
 
-        # [BS, KV, D] -> rows (t, kv): the layout's own order. Upcast
-        # once, then each KV head's rows are a strided read.
-        kf_ref[...] = kbuf[buf].reshape(block_s * n_kv, -1).astype(
-            jnp.float32)
-        vf_ref[...] = vbuf[buf].reshape(block_s * n_kv, -1).astype(
-            jnp.float32)
         pos = blk * block_s + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_s), 1)
         live = pos < length                                  # [1, BS]
@@ -177,9 +178,8 @@ def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool):
         q_all = q_ref[slot]                                  # [KV, Gp, D]
         scores = []
         for kv in range(n_kv):
-            k_kv = kf_ref[pl.ds(kv, block_s, stride=n_kv), :].astype(cdt)
             s = jax.lax.dot_general(
-                q_all[kv], k_kv,
+                q_all[kv], kbuf[buf, kv].astype(cdt),
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)          # [Gp, BS]
             if quant:
@@ -201,9 +201,8 @@ def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool):
                 # where, not a product alone: what lies past the length
                 # in a block is not the slot's to read, whatever it holds
                 p_kv = jnp.where(live, p_kv * vsbuf[buf, kv:kv + 1, :], 0.0)
-            v_kv = vf_ref[pl.ds(kv, block_s, stride=n_kv), :].astype(cdt)
             pv.append(jax.lax.dot_general(
-                p_kv.astype(cdt), v_kv,
+                p_kv.astype(cdt), vbuf[buf, kv].astype(cdt),
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))         # [Gp, D]
         acc_ref[...] = acc_ref[...] * corr + jnp.stack(pv)
@@ -222,13 +221,13 @@ def flash_decode_stacked(q, cache_k, cache_v, k_new, v_new, lengths, layer,
     """decode_attention_appended over layer ``layer`` of the stacked
     cache, reading only what ``lengths`` says is live.
 
-    q: [B, 1, H, D]; cache_k/cache_v: [L, B, Smax, KV, D] (int8 with
-    scales [L, B, Smax, KV], or dense); k_new/v_new: [B, 1, KV, D];
+    q: [B, 1, H, D]; cache_k/cache_v: [L, B, KV, Smax, D] (int8 with
+    scales [L, B, KV, Smax], or dense); k_new/v_new: [B, 1, KV, D];
     lengths [B] EXCLUDING the current token, 0 for a slot whose cache
     must not be read; layer: int32 scalar. Returns [B, 1, H, D] in
     q.dtype."""
     b, _, h, d = q.shape
-    smax, n_kv = cache_k.shape[2], cache_k.shape[3]
+    n_kv, smax = cache_k.shape[2], cache_k.shape[3]
     g = h // n_kv
     g_pad = -(-g // _SUBLANES) * _SUBLANES
     quant = k_scale is not None
@@ -244,22 +243,19 @@ def flash_decode_stacked(q, cache_k, cache_v, k_new, v_new, lengths, layer,
 
     operands = [qg, per_group(k_new), per_group(v_new), cache_k, cache_v]
     in_specs = [vmem, vmem, vmem, hbm, hbm]
-    scratch = [pltpu.VMEM((_N_BUF, block_s, n_kv, d), cache_k.dtype),
-               pltpu.VMEM((_N_BUF, block_s, n_kv, d), cache_v.dtype)]
+    scratch = [pltpu.VMEM((_KV_BUF, n_kv, block_s, d), cache_k.dtype),
+               pltpu.VMEM((_KV_BUF, n_kv, block_s, d), cache_v.dtype)]
     if quant:
-        # [L, B, Smax, KV] -> [L, B, KV, Smax]: the order the scales have
-        # in HBM already, and a [KV, BS] tile has a KV head's scales in
-        # one row, positions along lanes like its scores
-        operands += [jnp.swapaxes(k_scale, 2, 3), jnp.swapaxes(v_scale, 2, 3)]
+        # a [KV, BS] tile has a KV head's scales in one row, positions
+        # along lanes like its scores
+        operands += [k_scale, v_scale]
         in_specs += [hbm, hbm]
-        scratch += [pltpu.VMEM((_N_BUF, n_kv, block_s), jnp.float32),
-                    pltpu.VMEM((_N_BUF, n_kv, block_s), jnp.float32)]
-    scratch += [pltpu.VMEM((block_s * n_kv, d), jnp.float32),
-                pltpu.VMEM((block_s * n_kv, d), jnp.float32),
-                pltpu.VMEM((n_kv, g_pad, _LANES), jnp.float32),
+        scratch += [pltpu.VMEM((_KV_BUF, n_kv, block_s), jnp.float32),
+                    pltpu.VMEM((_KV_BUF, n_kv, block_s), jnp.float32)]
+    scratch += [pltpu.VMEM((n_kv, g_pad, _LANES), jnp.float32),
                 pltpu.VMEM((n_kv, g_pad, _LANES), jnp.float32),
                 pltpu.VMEM((n_kv, g_pad, d), jnp.float32),
-                pltpu.SemaphoreType.DMA((4, _N_BUF))]
+                pltpu.SemaphoreType.DMA((4, _KV_BUF))]
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_s=block_s, n_kv=n_kv,
                           quant=quant),
@@ -291,11 +287,8 @@ def flash_decode_sharded(q, cache_k, cache_v, k_new, v_new, lengths, layer,
     unchanged). check_vma off: pallas_call has no replication rule."""
     from jax.sharding import PartitionSpec as P
 
-    bax = tuple(batch_axes) or None
-    qspec = P(bax, None, head_axis, None)          # q/k_new/v_new [B,1,·,D]
-    cspec = P(None, bax, None, head_axis, None)    # caches [L,B,Smax,KV,D]
-    sspec = P(None, bax, None, head_axis)          # scales [L,B,Smax,KV]
-    specs = (qspec, cspec, cspec, qspec, qspec, P(bax), P())
+    qspec, cspec, sspec, lspec = _shard_specs(batch_axes, head_axis)
+    specs = (qspec, cspec, cspec, qspec, qspec, lspec, P())
     args = (q, cache_k, cache_v, k_new, v_new, lengths, layer)
     if k_scale is not None:
         specs += (sspec, sspec)
@@ -306,31 +299,41 @@ def flash_decode_sharded(q, cache_k, cache_v, k_new, v_new, lengths, layer,
                          check_vma=False)(*args)
 
 
+def _shard_specs(batch_axes, head_axis):
+    """PartitionSpecs of the kernels' shard_map: q/k_new/v_new
+    [B, 1, heads, D]; the caches [L, B, KV, Smax, D]; the scales
+    [L, B, KV, Smax] and a step's rows [L, B, KV, D]; lengths [B]. They
+    mirror parallel.kv_cache_specs, so GSPMD never gathers the cache at
+    the shard_map boundary."""
+    from jax.sharding import PartitionSpec as P
+
+    bax = tuple(batch_axes) or None
+    return (P(bax, None, head_axis, None),
+            P(None, bax, head_axis, None, None),
+            P(None, bax, head_axis, None), P(bax))
+
+
 def kernel_block(n_heads: int, cache_k, mesh=None) -> int | None:
-    """The kernel's block size where backend and shapes allow it, None
-    where decode attention stays on the reference: not a TPU, a head_dim
-    that is not whole lanes, a cache shorter than a block, a tp that
-    would split a KV head, or a local (KV, hd) tile that does not fill
-    whole 32-bit sublanes. The last is int8 with fewer than four local KV
-    heads (tp=4 over 8): XLA pads that tile to (4, 128) in HBM and Mosaic
-    refuses the [block_s, 2, 128] slice of it. ``GOFR_FLASH_INTERPRET=1``
-    runs the kernel interpreted on any backend and shape."""
+    """The kernels' block size where backend and shapes allow them, None
+    where decode attention and the step's write stay on the reference:
+    not a TPU, a head_dim that is not whole lanes, a cache no
+    lane-aligned block divides, or a tp that would split a KV head. The
+    local KV-head count does not matter: a head's (Smax, hd) tiles are
+    whole at any count. ``GOFR_FLASH_INTERPRET=1`` runs the kernels
+    interpreted on any backend and shape."""
     from .flash import interpret_env, tpu_backend_ok
 
-    _, b, smax, n_kv, d = cache_k.shape
+    _, b, n_kv, smax, d = cache_k.shape
     if mesh is not None:
-        from ..parallel.sharding import AXIS_TP, attention_shard_axes
+        from ..parallel.sharding import attention_shard_axes
 
         batch_axes, head_axis = attention_shard_axes(mesh, b, n_heads, n_kv)
         if head_axis is None and not batch_axes:
             return None
-        if head_axis is not None:
-            n_kv //= mesh.shape[AXIS_TP]
     block_s = block_size(smax)
     if interpret_env():
         return block_s
-    if (d % _LANES or smax % _LANES or n_heads % cache_k.shape[3]
-            or (n_kv * cache_k.dtype.itemsize) % 4 or not tpu_backend_ok()):
+    if d % _LANES or smax % _LANES or n_heads % n_kv or not tpu_backend_ok():
         return None
     return block_s
 
@@ -350,7 +353,7 @@ def decode_attention_auto(q, cache_k, cache_v, k_new, v_new, lengths, layer,
         from ..parallel.sharding import attention_shard_axes
 
         batch_axes, head_axis = attention_shard_axes(
-            mesh, q.shape[0], q.shape[2], cache_k.shape[3])
+            mesh, q.shape[0], q.shape[2], cache_k.shape[2])
         return flash_decode_sharded(
             q, cache_k, cache_v, k_new, v_new, lengths, layer, k_scale,
             v_scale, mesh=mesh, batch_axes=batch_axes, head_axis=head_axis,
@@ -358,3 +361,157 @@ def decode_attention_auto(q, cache_k, cache_v, k_new, v_new, lengths, layer,
     return flash_decode_stacked(q, cache_k, cache_v, k_new, v_new, lengths,
                                 layer, k_scale, v_scale, block_s=block_s,
                                 interpret=interpret)
+
+
+# -- the step's write ---------------------------------------------------------
+
+_APPEND_BUF = 3    # slot b+1 read and slot b-1 written while slot b merges
+
+
+def _append_kernel(pos_ref, keep_ref, kn_ref, vn_ref, k_in, v_in, k_hbm,
+                   v_hbm, kbuf, vbuf, sem, *, rows: int, per: int):
+    """Every slot: fetch the ``rows`` positions around its cursor, all
+    layers and KV heads, put the new row's bits into its 32-bit words,
+    write the tiles back."""
+    del k_in, v_in                       # the outputs alias them
+    nb = pos_ref.shape[0]
+    n_kv, d = kbuf.shape[2], kbuf.shape[4]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (1, rows // per, d), 1)
+
+    def dmas(b, read: bool):
+        buf = b % _APPEND_BUF
+        here = pl.ds(pl.multiple_of(pos_ref[b] // rows * rows, rows), rows)
+        out = []
+        for i, (hbm, scratch) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+            pair = (hbm.at[:, b, :, here], scratch.at[buf])
+            out.append(pltpu.make_async_copy(
+                *(pair if read else pair[::-1]), sem.at[int(read), i, buf]))
+        return out
+
+    for c in dmas(0, True):
+        c.start()
+
+    def slot(b, _):
+        @pl.when(b >= 2)
+        def _free():       # slot b-2 wrote from the buffer slot b+1 reads to
+            for c in dmas(b - 2, False):
+                c.wait()
+
+        @pl.when(b + 1 < nb)
+        def _next():
+            for c in dmas(b + 1, True):
+                c.start()
+
+        for c in dmas(b, True):
+            c.wait()
+        buf = b % _APPEND_BUF
+        word = (pos_ref[b] % rows) // per
+        keep = keep_ref[b]
+        for scratch, new_ref in ((kbuf, kn_ref), (vbuf, vn_ref)):
+            for kv in range(n_kv):
+                old = pltpu.bitcast(scratch[buf, :, kv], jnp.int32)
+                new = new_ref[b, :, kv:kv + 1, :]            # [L, 1, D]
+                scratch[buf, :, kv] = pltpu.bitcast(
+                    jnp.where(sub == word, (old & keep) | new, old),
+                    scratch.dtype)
+        for c in dmas(b, False):
+            c.start()
+
+    jax.lax.fori_loop(0, nb, slot, None)
+    for b in range(max(nb - 2, 0), nb):
+        for c in dmas(b, False):
+            c.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def append_rows_stacked(cache_k, cache_v, k_rows, v_rows, positions, *,
+                        interpret: bool = False):
+    """cache[:, b, :, positions[b]] = rows[:, b], every layer and KV
+    head, in place on donated caches; a position at or past ``Smax`` is
+    dropped like a scatter's.
+
+    XLA cannot do this write where the cache is: a position is one row
+    of the (Smax, hd) tiles, a quarter of a 32-bit sublane at int8, and
+    both its scatter and its dynamic_update_slice first copy the whole
+    cache to a layout with the written axis major and then back (compiled
+    for the v5e: two copies of s8[32,40,8,2048,128] a step). Here a slot
+    reads the 8 sublanes of 32-bit words around its cursor ([L, KV, 32,
+    hd] at int8, 1 MB), merges the row's bits into its word by mask, and
+    writes them back; three buffers keep a read, a merge and a write in
+    flight across slots.
+
+    cache_k/cache_v: [L, B, KV, Smax, D]; k_rows/v_rows: [L, B, KV, D] in
+    the caches' dtype; positions: [B] int32. Returns (cache_k, cache_v).
+    """
+    from .flash import fit_block
+
+    n_l, b, n_kv, smax, d = cache_k.shape
+    item = cache_k.dtype.itemsize
+    per, bits = 4 // item, 8 * item
+    rows = fit_block(smax, _SUBLANES * per)
+    if rows % per:
+        raise ValueError(f"append_rows: Smax {smax} is not whole 32-bit "
+                         f"words of {cache_k.dtype} positions")
+    positions = positions.astype(jnp.int32)
+    ok = positions < smax
+    pos = jnp.minimum(positions, smax - 1)
+    shift = ((pos % per) * bits).astype(jnp.uint32)
+    mask = jnp.where(ok, jnp.uint32((1 << bits) - 1) << shift, 0)
+    as_i32 = functools.partial(jax.lax.bitcast_convert_type,
+                               new_dtype=jnp.int32)
+
+    def words(x):    # [L, B, KV, D] -> [B, L, KV, D] int32, bits in place
+        u = jax.lax.bitcast_convert_type(x, jnp.dtype(f"uint{bits}"))
+        u = jnp.moveaxis(u, 1, 0).astype(jnp.uint32)
+        return as_i32((u << shift[:, None, None, None])
+                      & mask[:, None, None, None])
+
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    tile = pltpu.VMEM((_APPEND_BUF, n_l, n_kv, rows, d), cache_k.dtype)
+    return pl.pallas_call(
+        functools.partial(_append_kernel, rows=rows, per=per),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=[vmem, vmem, hbm, hbm], out_specs=[hbm, hbm],
+            scratch_shapes=[tile, tile,
+                            pltpu.SemaphoreType.DMA((2, 2, _APPEND_BUF))]),
+        out_shape=[jax.ShapeDtypeStruct(cache_k.shape, cache_k.dtype),
+                   jax.ShapeDtypeStruct(cache_v.shape, cache_v.dtype)],
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024),
+    )(pos, as_i32(~mask), words(k_rows), words(v_rows), cache_k, cache_v)
+
+
+def append_rows_sharded(cache_k, cache_v, k_rows, v_rows, positions, *,
+                        mesh, n_heads: int, interpret: bool = False):
+    """shard_map'd append_rows_stacked: each device writes its local KV
+    heads' (and slots') rows, the caches staying where
+    parallel.kv_cache_specs placed them."""
+    from ..parallel.sharding import attention_shard_axes
+
+    _, cspec, rspec, lspec = _shard_specs(*attention_shard_axes(
+        mesh, cache_k.shape[1], n_heads, cache_k.shape[2]))
+    run = functools.partial(append_rows_stacked, interpret=interpret)
+    return jax.shard_map(
+        run, mesh=mesh, in_specs=(cspec, cspec, rspec, rspec, lspec),
+        out_specs=(cspec, cspec), check_vma=False)(
+            cache_k, cache_v, k_rows, v_rows, positions)
+
+
+@jax.named_scope("kv_append")
+def append_rows(cache_k, cache_v, k_rows, v_rows, positions, *,
+                n_heads: int, mesh=None):
+    """The step's rows into the stacked cache, in place, under shard_map
+    where ``mesh`` shards heads or batch: the caller has asked
+    ``kernel_block`` for these shapes, and takes XLA's scatter where it
+    is None."""
+    from .flash import interpret_env
+
+    if mesh is not None:
+        return append_rows_sharded(cache_k, cache_v, k_rows, v_rows,
+                                   positions, mesh=mesh, n_heads=n_heads,
+                                   interpret=interpret_env())
+    return append_rows_stacked(cache_k, cache_v, k_rows, v_rows, positions,
+                               interpret=interpret_env())

@@ -305,14 +305,21 @@ def gather_blocks(pool, table):
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
+def gather_heads(pool, table):
+    """gather_blocks in the order the dense references read a cache
+    layer, a KV head's positions together (models.llama.KVCache):
+    [B, KV, MB*T(, hd)]."""
+    return jnp.swapaxes(gather_blocks(pool, table), 1, 2)
+
+
 def paged_attention_reference(q, k_pool, v_pool, k_new, v_new, table,
                               lengths, k_scale=None, v_scale=None):
     """Numerics oracle: gather the table's dense view, run the exact
     reference decode attention."""
-    k_dense = gather_blocks(k_pool, table)
-    v_dense = gather_blocks(v_pool, table)
-    ks = gather_blocks(k_scale, table) if k_scale is not None else None
-    vs = gather_blocks(v_scale, table) if v_scale is not None else None
+    k_dense = gather_heads(k_pool, table)
+    v_dense = gather_heads(v_pool, table)
+    ks = gather_heads(k_scale, table) if k_scale is not None else None
+    vs = gather_heads(v_scale, table) if v_scale is not None else None
     return decode_attention_appended(q, k_dense, v_dense, k_new, v_new,
                                      lengths, ks, vs)
 
@@ -411,10 +418,10 @@ def paged_window_reference(q, k_pool, v_pool, k_new, v_new, table, lengths,
     to the GSPMD partitioner."""
     from .attention import window_attention_appended
 
-    ks = gather_blocks(k_scale, table) if k_scale is not None else None
-    vs = gather_blocks(v_scale, table) if v_scale is not None else None
-    return window_attention_appended(q, gather_blocks(k_pool, table),
-                                     gather_blocks(v_pool, table),
+    ks = gather_heads(k_scale, table) if k_scale is not None else None
+    vs = gather_heads(v_scale, table) if v_scale is not None else None
+    return window_attention_appended(q, gather_heads(k_pool, table),
+                                     gather_heads(v_pool, table),
                                      k_new, v_new, lengths, ks, vs)
 
 

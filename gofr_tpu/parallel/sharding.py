@@ -180,12 +180,12 @@ def activation_constraint(mesh: Mesh) -> Callable:
 
 
 def kv_cache_specs(mesh: Mesh, cache) -> Any:
-    """Shardings for a models.llama.KVCache: [L, B, Smax, KV, hd] — batch
+    """Shardings for a models.llama.KVCache: [L, B, KV, Smax, hd] — batch
     over data axes, kv-heads over tp, everything else local. Int8 caches
-    carry per-vector scale planes [L, B, Smax, KV] that shard identically
+    carry per-vector scale planes [L, B, KV, Smax] that shard identically
     (same axes minus head_dim)."""
-    kv = P(None, DATA_AXES, None, AXIS_TP, None)
-    sc = P(None, DATA_AXES, None, AXIS_TP)
+    kv = P(None, DATA_AXES, AXIS_TP, None, None)
+    sc = P(None, DATA_AXES, AXIS_TP, None)
     ln = P(DATA_AXES)
 
     def fit(spec, leaf):

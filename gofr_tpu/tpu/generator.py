@@ -5,7 +5,7 @@ stateless microservice framework; token streaming is the BASELINE.json
 Llama target). Design:
 
   - A FIXED pool of B batch slots shares one preallocated KV cache
-    [L, B, Smax, KV, hd]. Slots are admitted/retired independently via a
+    [L, B, KV, Smax, hd]. Slots are admitted/retired independently via a
     per-slot ``lengths`` cursor — XLA shapes never change, so the decode
     step compiles exactly once.
   - ADMISSION runs a per-sequence prefill jitted at a small lattice of
@@ -2403,16 +2403,19 @@ class GenerationEngine:
         own device shard (no cross-device gather on the spill path) —
         a ShardedHostKV whose parts the offload tiers store and frame
         verbatim; the restore side assembles the canonical dense row
-        (dense_hostkv) before the placed write."""
+        (dense_hostkv) before the placed write. The device holds a KV
+        head's positions together ([L, B, KV, Smax, hd]); what the host
+        keeps, stores and ships is [L, plen, KV, hd] as it always was:
+        the transposition happens here."""
         quant = store.k_scale is not None
         if self.mesh is None:
-            return HostKV(
-                np.asarray(store.k[:, row, start:plen]),
-                np.asarray(store.v[:, row, start:plen]),
-                np.asarray(store.k_scale[:, row, start:plen])
-                if quant else None,
-                np.asarray(store.v_scale[:, row, start:plen])
-                if quant else None)
+            def get(a):
+                return self._host_order(
+                    np.asarray(a[:, row, :, start:plen]))
+
+            return HostKV(get(store.k), get(store.v),
+                          get(store.k_scale) if quant else None,
+                          get(store.v_scale) if quant else None)
         k_p = self._row_shard_parts(store.k, row, start, plen)
         v_p = self._row_shard_parts(store.v, row, start, plen)
         ks_p = (self._row_shard_parts(store.k_scale, row, start, plen)
@@ -2426,12 +2429,20 @@ class GenerationEngine:
         return parts[0] if len(parts) == 1 else ShardedHostKV(parts)
 
     @staticmethod
-    def _row_shard_parts(arr, row: int, start: int, stop: int) -> list:
+    def _host_order(a: np.ndarray) -> np.ndarray:
+        """A fetched row [L, KV, plen(, hd)] as the host holds it,
+        [L, plen, KV(, hd)], contiguous (HostKV)."""
+        return np.ascontiguousarray(np.swapaxes(a, 1, 2))
+
+    @classmethod
+    def _row_shard_parts(cls, arr, row: int, start: int,
+                         stop: int) -> list:
         """One batch row's positions ``[start, stop)`` read per tp
-        shard of a [L, B, Smax, KV(, hd)] cache leaf: walk the leaf's
-        addressable shards, keep the shard covering ``row`` for each
-        distinct KV-head offset (replicated axes repeat the same
-        heads — first wins), and return the pieces in head order.
+        shard of a [L, B, KV, Smax(, hd)] cache leaf, each in the
+        host's order: walk the leaf's addressable shards, keep the
+        shard covering ``row`` for each distinct KV-head offset
+        (replicated axes repeat the same heads — first wins), and
+        return the pieces in head order.
         Each read is a single-device ``device_get`` of that shard's
         slab — the mesh never assembles the row to spill it."""
         parts: dict[int, np.ndarray] = {}
@@ -2443,10 +2454,11 @@ class GenerationEngine:
             b1 = B if bsl.stop is None else bsl.stop
             if not (b0 <= row < b1):
                 continue
-            h0 = idx[3].start or 0
+            h0 = idx[2].start or 0
             if h0 in parts:
                 continue
-            parts[h0] = np.asarray(sh.data)[:, row - b0, start:stop]
+            parts[h0] = cls._host_order(
+                np.asarray(sh.data)[:, row - b0, :, start:stop])
         return [parts[h] for h in sorted(parts)]
 
     def _offload_victim(self, victim) -> None:
@@ -2473,7 +2485,7 @@ class GenerationEngine:
         if (kv is None or kv.plen > self.max_seq or len(mt.key) < kv.plen
                 or (quant and kv.k_scale is None)
                 or kv.k.shape[0] != self._pool.k.shape[0]
-                or kv.k.shape[2:] != self._pool.k.shape[3:]):
+                or kv.k.shape[2:] != self._fam.kv_layout(self.cfg)):
             return None
         row, victim = self._kvc.store(mt.key[:kv.plen], mt.adapter)
         self._offload_victim(victim)

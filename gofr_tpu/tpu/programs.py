@@ -110,7 +110,7 @@ def shardings(mesh, buffers, n_kv_heads: int) -> Placement:
 def _born_sharded(build, sharding):
     """Run a cache-building thunk so a mesh engine's buffers are
     created in their shards: built eagerly and then device_put, the
-    whole [L, slots, Smax, KV, hd] cache lands on the first chip
+    whole [L, slots, KV, Smax, hd] cache lands on the first chip
     before it is split (3.8 GB of extra peak on device 0 at
     8B/tp=4). ``sharding`` None = single device, build in place."""
     if sharding is None:
@@ -184,8 +184,9 @@ def _copy_row(dst, src, dst_idx, src_idx):
         r = lax.dynamic_slice_in_dim(s, src_idx, 1, axis=1)
         return lax.dynamic_update_slice_in_dim(d, r, dst_idx, axis=1)
 
-    # every array of a cache but ``lengths`` is [L, B, Smax, ...]: K, V
-    # and their scale planes, or a family's latent rows
+    # every array of a cache but ``lengths`` is [L, B, ...]: K and V
+    # [L, B, KV, Smax, hd] and their scale planes, or a family's latent
+    # rows
     return jax.tree_util.tree_map(
         cp, dst._replace(lengths=None),
         src._replace(lengths=None))._replace(lengths=dst.lengths)
@@ -193,14 +194,17 @@ def _copy_row(dst, src, dst_idx, src_idx):
 
 def _write_row_from_host(pool, k, v, ks, vs, row):
     """Land a host KV slab in pool row ``row`` — the device half of a
-    T1/T2 restore (kvcache promotion). ``k``/``v`` arrive padded to
-    [L, 1, Smax, KV, hd] (scales [L, 1, Smax, KV]) so the program
-    compiles once; positions past the entry's length are zeros that the
-    resumed prefill overwrites or the cursor masks."""
+    T1/T2 restore (kvcache promotion). ``k``/``v`` arrive in the host's
+    order (tpu.kvcache.HostKV), padded to [L, 1, Smax, KV, hd] (scales
+    [L, 1, Smax, KV]) so the program compiles once, and are transposed
+    here, on the device, to the cache's [L, 1, KV, Smax(, hd)];
+    positions past the entry's length are zeros that the resumed prefill
+    overwrites or the cursor masks."""
     import jax.lax as lax
 
     def wr(dst, src):
-        return lax.dynamic_update_slice_in_dim(dst, src, row, axis=1)
+        return lax.dynamic_update_slice_in_dim(
+            dst, jnp.swapaxes(src, 2, 3), row, axis=1)
 
     quant = pool.k_scale is not None
     return pool._replace(
@@ -215,15 +219,16 @@ def _write_row_from_host_masked(pool, k, v, ks, vs, row):
     traced start on the batch axis — the axis the pool shards over the
     data mesh axes — and GSPMD's only lowering for that replicates the
     whole pool (the _copy_row hazard). Select the destination row with
-    a one-hot mask and blend instead: ``src`` [L, 1, Smax, ...] arrives
-    replicated and broadcasts over the batch axis, every op partitions
-    cleanly under any batch/tp sharding. Reads the full pool once; that
-    extra HBM stream is the price of mesh support, paid only on a
-    promotion (not per token)."""
+    a one-hot mask and blend instead: ``src`` (the host's order,
+    [L, 1, Smax, KV(, hd)], transposed here like _write_row_from_host's)
+    arrives replicated and broadcasts over the batch axis, every op
+    partitions cleanly under any batch/tp sharding. Reads the full pool
+    once; that extra HBM stream is the price of mesh support, paid only
+    on a promotion (not per token)."""
     def wr(dst, src):
         sel = (jnp.arange(dst.shape[1]) == row)
         sel = sel.reshape((1, -1) + (1,) * (dst.ndim - 2))
-        return jnp.where(sel, src.astype(dst.dtype), dst)
+        return jnp.where(sel, jnp.swapaxes(src, 2, 3).astype(dst.dtype), dst)
 
     quant = pool.k_scale is not None
     return pool._replace(
@@ -485,9 +490,8 @@ class EnginePrograms:
         slice the slot's cache view, run one chunk against it, write back.
         The final chunk (``sample=True``) also sets the slot's cursor to
         ``total_len`` and samples the first token at ``pos_in_chunk``."""
-        Smax = cache[0].shape[2]
-        # the slot's view of every cache array ([L, 1, Smax, ...]: K, V
-        # and scale planes, or latent rows), and its write-back
+        # the slot's view of every cache array ([L, 1, ...]: K, V and
+        # scale planes, or latent rows), and its write-back
         arrays = cache._replace(lengths=None)
         small = jax.tree_util.tree_map(
             lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1),
@@ -509,7 +513,7 @@ class EnginePrograms:
             # just wrote. Cursor = capacity makes those writes land out
             # of range, where mode="drop" discards them.
             return written._replace(
-                lengths=cache.lengths.at[slot].set(Smax))
+                lengths=cache.lengths.at[slot].set(self.max_seq))
         lengths = cache.lengths.at[slot].set(total_len)
         last = logits[0, 0]  # [V] at pos_in_chunk (logit_pos)
         tok, lp = self._sample(last[None, :], temp[None],
@@ -705,5 +709,5 @@ class EnginePrograms:
         logits, stepped = llama.verify_step(params, self.cfg, window,
                                             cache,
                                             rope_tables=self.rope_tables,
-                                            adapter=adapter)
+                                            adapter=adapter, mesh=self.mesh)
         return self._verify_epilogue(logits, window, active, stepped)

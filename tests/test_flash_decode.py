@@ -31,8 +31,8 @@ def _mk(key, cache: str, b: int = B, h: int = H, kv: int = KV):
     ks = jax.random.split(key, 5)
     dt = jnp.bfloat16 if cache == "bfloat16" else jnp.float32
     q = jax.random.normal(ks[0], (b, 1, h, D), dt)
-    k = jax.random.normal(ks[1], (L, b, S, kv, D), jnp.float32)
-    v = jax.random.normal(ks[2], (L, b, S, kv, D), jnp.float32)
+    k = jax.random.normal(ks[1], (L, b, kv, S, D), jnp.float32)
+    v = jax.random.normal(ks[2], (L, b, kv, S, D), jnp.float32)
     k_new = jax.random.normal(ks[3], (b, 1, kv, D), dt)
     v_new = jax.random.normal(ks[4], (b, 1, kv, D), dt)
     if cache != "int8":
@@ -79,11 +79,13 @@ def test_stacked_matches_reference(cache, layer, slot):
 
 
 @pytest.mark.parametrize("cache", CACHES)
-@pytest.mark.parametrize("h,kv", [(16, 4), (32, 8), (8, 8), (32, 16)])
+@pytest.mark.parametrize("h,kv", [(16, 4), (32, 8), (8, 8), (32, 16),
+                                  (4, 1), (8, 2)])
 def test_head_geometries(cache, h, kv):
-    """KV heads are de-interleaved from the [block, KV, hd] tile by a
-    strided read, a group of q heads padded to whole sublanes: groups of
-    four, one and two over four, eight and sixteen KV heads. Poisoned
+    """A KV head's [block, hd] tile is taken whole from the
+    [KV, block, hd] item, a group of q heads padded to whole sublanes:
+    groups of four, one and two over four, eight and sixteen KV heads,
+    and the one and two KV heads a tp shard can be left with. Poisoned
     past the cursors, so a row read for the wrong head or position
     shows."""
     args = _mk(jax.random.PRNGKey(4), cache, h=h, kv=kv)
@@ -119,7 +121,7 @@ def _poison(args, lens):
     as the dtype allows: +-127 (or 1e30) in K and V, 1e30 scales."""
     q, k, v, k_new, v_new, sk, sv = args
     dead = (np.arange(S)[None, :] >= np.asarray(lens)[:, None])  # [B,S]
-    dead5 = jnp.asarray(dead)[None, :, :, None, None]
+    dead5 = jnp.asarray(dead)[None, :, None, :, None]
     sign = jnp.where(jnp.arange(D) % 2 == 0, 1, -1)
     loud = 127 if k.dtype == jnp.int8 else 1e30
     k = jnp.where(dead5, (sign * loud).astype(k.dtype), k)
@@ -158,10 +160,69 @@ def test_work_list(lens, n, slots, blocks):
     assert 0 <= int(slot.min()) and int(slot.max()) < len(lens)
 
 
+# -- the step's write -----------------------------------------------------------
+
+@pytest.mark.parametrize("cache", CACHES)
+@pytest.mark.parametrize("kv", [1, 2, 8])
+def test_append_rows_writes_the_row_and_nothing_else(cache, kv):
+    """Every layer's and KV head's row lands at its slot's position,
+    first and last of a 32-bit word, of a tile and of the cache; a
+    position at capacity is dropped; every other byte of both caches is
+    what it was (the scatter's result, bit for bit)."""
+    dt = {"int8": jnp.int8, "bfloat16": jnp.bfloat16,
+          "float32": jnp.float32}[cache]
+    ks = jax.random.split(jax.random.PRNGKey(kv), 4)
+
+    def rand(key, shape):
+        return (jax.random.normal(key, shape, jnp.float32) * 50).astype(dt)
+
+    k, v = rand(ks[0], (L, B, kv, S, D)), rand(ks[1], (L, B, kv, S, D))
+    k_rows, v_rows = rand(ks[2], (L, B, kv, D)), rand(ks[3], (L, B, kv, D))
+    pos = jnp.asarray([0, 3, 31, 32, S - 1, S], jnp.int32)
+    got = fd.append_rows_stacked(k, v, k_rows, v_rows, pos, interpret=True)
+    slots = jnp.arange(B)
+    for new, old, rows in zip(got, (k, v), (k_rows, v_rows)):
+        want = old.at[:, slots, :, pos].set(jnp.moveaxis(rows, 1, 0),
+                                            mode="drop")
+        np.testing.assert_array_equal(np.asarray(new.astype(jnp.float32)),
+                                      np.asarray(want.astype(jnp.float32)))
+        # the slot at capacity: nothing of it moved
+        np.testing.assert_array_equal(
+            np.asarray(new[:, -1].astype(jnp.float32)),
+            np.asarray(old[:, -1].astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("kv_dtype", [None, jnp.int8])
+@pytest.mark.parametrize("w", [1, 3])
+def test_write_rows_kernel_arm_is_the_scatter(kv_dtype, w, monkeypatch):
+    """llama._write_rows, a decode step's row and a verify window's
+    three: the kernel arm (interpreted) leaves the cache the reference
+    arm's scatter leaves, scales included, a parked slot untouched."""
+    b, smax = 4, 64
+    cache = llama.init_cache(TINY, b, smax, kv_dtype)
+    ks = jax.random.split(jax.random.PRNGKey(w), 2)
+    shape = (TINY.n_layers, b, w, TINY.n_kv_heads, TINY.head_dim)
+    k_rows = jax.random.normal(ks[0], shape, jnp.float32)
+    v_rows = jax.random.normal(ks[1], shape, jnp.float32)
+    lengths = jnp.asarray([0, 17, smax - w, smax], jnp.int32)
+    positions = lengths[:, None] + jnp.arange(w)[None, :]
+    want = llama._write_rows(cache, k_rows, v_rows, positions, lengths + w,
+                             TINY.n_heads)
+    monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
+    got = llama._write_rows(cache, k_rows, v_rows, positions, lengths + w,
+                            TINY.n_heads)
+    for a, e in zip(got, want):
+        assert (a is None) == (e is None)
+        if a is not None:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(e))
+    assert np.asarray(got.k[:, 1, :, 17]).any()
+    assert not np.asarray(got.k[:, 3]).any()
+
+
 # -- selection: what the code can observe, no setting -------------------------
 
 def _cache_shape(kv=8, d=128, smax=2048, dtype=jnp.int8):
-    return jax.ShapeDtypeStruct((2, 4, smax, kv, d), dtype)
+    return jax.ShapeDtypeStruct((2, 4, kv, smax, d), dtype)
 
 
 def test_reference_off_tpu():
@@ -177,7 +238,8 @@ def test_reference_off_tpu():
     (dict(smax=1152), 128),               # the largest block that divides
     (dict(d=64), None),                   # head_dim not whole lanes
     (dict(smax=200), None),               # no lane-aligned block divides
-    (dict(kv=2), None),                   # int8 (2, 128) is half a tile
+    (dict(kv=2), 256),                    # a tp=4 shard of 8 int8 heads
+    (dict(kv=1), 256),
     (dict(kv=2, dtype=jnp.bfloat16), 256),
     (dict(kv=3), None),                   # 32 heads over 3 KV heads
 ])

@@ -1,0 +1,86 @@
+"""The cache's two kernels compiled for a TPU v5e that is described, not
+attached (libtpu's compile-only topology; no chip time, nothing runs):
+what interpret mode cannot see, Mosaic refusing a slice that is not
+whole tiles or a kernel that needs too much VMEM. At Mistral-7B's widths
+with the benchmark's 40 slots x 2,048 positions, with eight int8 KV
+heads (one chip) and with the two a tp=4 shard of Mixtral is left with:
+the shard whose [block, 2, 128] slice of the old [.., Smax, KV, hd]
+cache Mosaic refused.
+
+The topology is described inside a fixture and only this file does so:
+one process at a time may load the TPU's library, and a worker that
+collects this file must not load it while it imports.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from gofr_tpu.ops import flash_decode as fd
+
+L, B, SMAX, D = 32, 40, 2048, 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 (no libtpu, or it is held)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # and cannot be read back without the device: keep it out. And the
+    # chip runs at JAX's default matmul precision, not the float32 that
+    # tests/conftest.py asks of the CPU (Mosaic has no such bfloat16 dot)
+    was = (jax.config.jax_enable_compilation_cache,
+           jax.config.jax_default_matmul_precision)
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was[0])
+    jax.config.update("jax_default_matmul_precision", was[1])
+    compilation_cache.reset_cache()
+
+
+def _shapes(sharding, kv, dtype):
+    def arr(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    cache = arr((L, B, kv, SMAX, D), dtype)
+    scale = arr((L, B, kv, SMAX), jnp.float32)
+    return arr, cache, scale
+
+
+@pytest.mark.parametrize("kv,dtype", [(8, jnp.int8), (2, jnp.int8),
+                                      (1, jnp.int8), (8, jnp.bfloat16)])
+def test_decode_kernel_compiles(one_chip, kv, dtype):
+    arr, cache, scale = _shapes(one_chip, kv, dtype)
+    quant = dtype == jnp.int8
+    q = arr((B, 1, 4 * kv, D), jnp.bfloat16)
+    new = arr((B, 1, kv, D), jnp.bfloat16)
+    compiled = fd.flash_decode_stacked.lower(
+        q, cache, cache, new, new, arr((B,), jnp.int32), arr((), jnp.int32),
+        scale if quant else None, scale if quant else None,
+        block_s=fd.block_size(SMAX)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kv,dtype", [(8, jnp.int8), (2, jnp.int8),
+                                      (1, jnp.int8), (8, jnp.bfloat16)])
+def test_append_kernel_compiles_in_place(one_chip, kv, dtype):
+    """And the caches it returns are the caches it was given: donated,
+    the program holds no second copy of either."""
+    arr, cache, _ = _shapes(one_chip, kv, dtype)
+    rows = arr((L, B, kv, D), dtype)
+    compiled = jax.jit(fd.append_rows_stacked, donate_argnums=(0, 1)).lower(
+        cache, cache, rows, rows, arr((B,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * L * B * kv * SMAX * D * jnp.dtype(dtype).itemsize
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 8

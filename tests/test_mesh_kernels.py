@@ -6,7 +6,8 @@ mode, so the REAL kernel bodies run (as XLA emulation) inside shard_map
 on tp=2 and tp=4 meshes; tokens are asserted EXACT against the
 single-device jnp-reference engine built before the env flag is set.
 tiny's n_kv_heads=2 covers tp=2; tp=4 uses a 4-KV-head variant so both
-factorizations stay in the head-aligned regime. The head-splitting
+factorizations stay in the head-aligned regime, and an 8-KV-head one
+for the two int8 KV heads a shard that Mixtral meets. The head-splitting
 regime is covered the other way round: tp-only meshes fall back to the
 jnp reference (still token-exact), and tp + data axes refuse at
 construction with a typed ShardingConfigError naming the TPU_SHARDING
@@ -31,6 +32,8 @@ from gofr_tpu.tpu import GenerationEngine
 
 TINY = LLAMA_CONFIGS["tiny"]            # n_heads=4, n_kv_heads=2
 TINY4 = TINY.with_(name="tiny4", n_kv_heads=4)  # tp=4 head-aligned
+# tp=4 leaves two KV heads a shard: Mixtral's 8 over four chips
+TINY8 = TINY.with_(name="tiny8", n_heads=8, n_kv_heads=8)
 
 PROMPTS = [[5, 17, 42, 7], [3, 1, 4, 1, 5, 9, 2, 6]]
 REP = [7, 9, 7, 9, 7, 9, 7, 9, 7, 9]   # repetitive: spec windows accept
@@ -45,6 +48,11 @@ def tiny_params():
 @pytest.fixture(scope="module")
 def tiny4_params():
     return llama.init(TINY4, jax.random.PRNGKey(1))
+
+
+@pytest.fixture(scope="module")
+def tiny8_params():
+    return llama.init(TINY8, jax.random.PRNGKey(1))
 
 
 def _cfg_params(tp, tiny_params, tiny4_params):
@@ -88,22 +96,25 @@ def _interpret_on(monkeypatch):
 # -- the decode kernel's sharded arm against the reference ---------------------
 
 @pytest.mark.parametrize("quant", [False, True])
-@pytest.mark.parametrize("tp,dp", [(2, 1), (4, 1), (2, 4)])
-def test_flash_decode_sharded_matches_reference(tp, dp, quant):
+@pytest.mark.parametrize("tp,dp,kv", [(2, 1, 4), (4, 1, 4), (2, 4, 4),
+                                      (4, 1, 8)])
+def test_flash_decode_sharded_matches_reference(tp, dp, kv, quant):
     """Each device walks its own KV-head (and batch) shard of the stacked
     cache, ragged cursors and dead slots included, and the gathered
-    output is the single-device reference's."""
+    output is the single-device reference's: one KV head a shard, two at
+    tp=2, and the two of eight that tp=4 leaves (int8: the shard the
+    old [.., KV, hd] tile kept off the kernel)."""
     import numpy as np
 
     from gofr_tpu.ops.attention import decode_attention_appended
     from gofr_tpu.ops.quant import quantize_kv
     from gofr_tpu.parallel.sharding import attention_shard_axes
 
-    n_l, b, s, h, kv, d = 2, 8, 128, 8, 4, 128
+    n_l, b, s, h, d = 2, 8, 128, 8, 128
     ks = jax.random.split(jax.random.PRNGKey(tp), 5)
     q = jax.random.normal(ks[0], (b, 1, h, d), jnp.float32)
-    k = jax.random.normal(ks[1], (n_l, b, s, kv, d), jnp.float32)
-    v = jax.random.normal(ks[2], (n_l, b, s, kv, d), jnp.float32)
+    k = jax.random.normal(ks[1], (n_l, b, kv, s, d), jnp.float32)
+    v = jax.random.normal(ks[2], (n_l, b, kv, s, d), jnp.float32)
     k_new = jax.random.normal(ks[3], (b, 1, kv, d), jnp.float32)
     v_new = jax.random.normal(ks[4], (b, 1, kv, d), jnp.float32)
     sk = sv = None
@@ -125,27 +136,64 @@ def test_flash_decode_sharded_matches_reference(tp, dp, quant):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("dtype", [jnp.int8, jnp.bfloat16])
+@pytest.mark.parametrize("tp,dp,kv", [(2, 4, 4), (4, 1, 8)])
+def test_append_rows_sharded_matches_scatter(tp, dp, kv, dtype):
+    """The step's write under shard_map: every device merges its own KV
+    heads' (and slots') rows into the tiles around the cursors, a
+    cursor at capacity dropped, and the gathered caches are the
+    scatter's, byte for byte."""
+    import numpy as np
+
+    n_l, b, s, h, d = 2, 8, 64, 8, 128
+    ks = jax.random.split(jax.random.PRNGKey(kv), 4)
+
+    def rand(key, shape):
+        x = jax.random.normal(key, shape, jnp.float32) * 40
+        return x.astype(dtype)
+
+    k, v = rand(ks[0], (n_l, b, kv, s, d)), rand(ks[1], (n_l, b, kv, s, d))
+    k_rows, v_rows = rand(ks[2], (n_l, b, kv, d)), rand(ks[3], (n_l, b, kv, d))
+    pos = jnp.asarray([0, 1, 31, 32, 33, 63, 64, 5], jnp.int32)
+    mesh = make_mesh(tp=tp, dp=dp, devices=jax.devices()[:tp * dp])
+    got_k, got_v = flash_decode.append_rows_sharded(
+        k, v, k_rows, v_rows, pos, mesh=mesh, n_heads=h, interpret=True)
+    slots = jnp.arange(b)
+    for got, cache, rows in ((got_k, k, k_rows), (got_v, v, v_rows)):
+        want = cache.at[:, slots, :, pos].set(jnp.moveaxis(rows, 1, 0),
+                                              mode="drop")
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)),
+            np.asarray(want.astype(jnp.float32)))
+
+
 # -- token exactness: contiguous engine ---------------------------------------
 
 @pytest.mark.parametrize("kv_dtype", [None, jnp.int8])
-@pytest.mark.parametrize("tp", [2, 4])
-def test_mesh_contiguous_token_exact(tp, kv_dtype, tiny_params, tiny4_params,
+@pytest.mark.parametrize("tp,kv", [(2, 2), (4, 4), (4, 8)])
+def test_mesh_contiguous_token_exact(tp, kv, kv_dtype, tiny_params,
+                                     tiny4_params, tiny8_params,
                                      monkeypatch):
     """shard_map'd flash prefill + flash-decode (each device walking its
-    own KV-head and batch shard of the stacked cache) on a dp x tp mesh
-    are token-exact vs the single-device jnp-reference engine, fp and
-    int8 KV, and the sharded kernel forms actually dispatch."""
-    cfg, params = _cfg_params(tp, tiny_params, tiny4_params)
+    own KV-head and batch shard of the stacked cache) and the step's
+    sharded write on a dp x tp mesh are token-exact vs the
+    single-device jnp-reference engine, fp and int8 KV, one KV head a
+    shard and two (tp=4 over eight: the shape Mixtral meets), and the
+    sharded kernel forms actually dispatch."""
+    cfg, params = {2: (TINY, tiny_params), 4: (TINY4, tiny4_params),
+                   8: (TINY8, tiny8_params)}[kv]
     want = _tokens(_engine(cfg, params, kv_dtype=kv_dtype))
 
     _interpret_on(monkeypatch)
     prefills = _counted(monkeypatch, flash, "flash_prefill_sharded")
     decodes = _counted(monkeypatch, flash_decode, "flash_decode_sharded")
+    appends = _counted(monkeypatch, flash_decode, "append_rows_sharded")
     mesh = make_mesh(tp=tp, dp=8 // tp)
     got = _tokens(_engine(cfg, shard_params(params, mesh), mesh=mesh,
                           kv_dtype=kv_dtype))
     assert got == want
-    assert prefills and decodes  # kernel path, not a silent fallback
+    # kernel paths, not a silent fallback
+    assert prefills and decodes and appends
 
 
 @pytest.mark.parametrize("kv_dtype", [None, jnp.int8])

@@ -16,7 +16,7 @@ def test_llama_prefill_shapes_and_cache():
     logits, cache = llama.prefill(params, TINY, tokens, cache)
     assert logits.shape == (2, 4, TINY.vocab_size)
     assert logits.dtype == jnp.float32
-    assert cache.k.shape == (TINY.n_layers, 2, 32, TINY.n_kv_heads, TINY.head_dim)
+    assert cache.k.shape == (TINY.n_layers, 2, TINY.n_kv_heads, 32, TINY.head_dim)
     assert list(cache.lengths) == [4, 4]
 
 

@@ -18,7 +18,7 @@ from gofr_tpu.models.paged_llama import (BlockAllocator,
                                          paged_decode_step,
                                          write_prompt_blocks)
 from gofr_tpu.ops.attention import decode_attention_appended
-from gofr_tpu.ops.paged_attention import (gather_blocks,
+from gofr_tpu.ops.paged_attention import (gather_heads,
                                           paged_attention_reference,
                                           paged_decode_attention)
 from gofr_tpu.ops.quant import quantize_kv
@@ -70,10 +70,10 @@ def test_paged_kernel_matches_dense_reference(quant, lengths):
                                atol=2e-5, rtol=2e-5)
     # and the reference really equals dense attention on the gathered view
     dense = decode_attention_appended(
-        q, gather_blocks(kp, table), gather_blocks(vp, table), k_new,
+        q, gather_heads(kp, table), gather_heads(vp, table), k_new,
         v_new, lens,
-        gather_blocks(sk, table) if quant else None,
-        gather_blocks(sv, table) if quant else None)
+        gather_heads(sk, table) if quant else None,
+        gather_heads(sv, table) if quant else None)
     np.testing.assert_allclose(np.asarray(want), np.asarray(dense),
                                atol=1e-6, rtol=1e-6)
 
@@ -97,10 +97,10 @@ def test_paged_window_kernel_matches_dense_reference(quant, w, lengths):
     got = paged_window_attention(q, kp, vp, k_new, v_new, table, lens,
                                  sk, sv, interpret=True)
     want = window_attention_appended(
-        q, gather_blocks(kp, table), gather_blocks(vp, table), k_new,
+        q, gather_heads(kp, table), gather_heads(vp, table), k_new,
         v_new, lens,
-        gather_blocks(sk, table) if quant else None,
-        gather_blocks(sv, table) if quant else None)
+        gather_heads(sk, table) if quant else None,
+        gather_heads(sv, table) if quant else None)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=3e-5, rtol=3e-5)
 

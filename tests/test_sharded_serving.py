@@ -59,9 +59,9 @@ def test_sharded_cache_layout(tiny_params):
                            max_seq=32, prompt_buckets=(8,), mesh=mesh)
     try:
         spec = eng.cache.k.sharding.spec
-        # [L, B, Smax, KV, hd]: batch over data axes, kv heads over tp
+        # [L, B, KV, Smax, hd]: batch over data axes, kv heads over tp
         assert spec[1] == ("dp", "fsdp", "ep")
-        assert spec[3] == "tp"
+        assert spec[2] == "tp"
         # layout must survive a generation (donation keeps shardings pinned)
         eng.generate([1, 2, 3], max_new_tokens=4).tokens()
         assert eng.cache.k.sharding.spec == spec

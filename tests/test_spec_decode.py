@@ -27,8 +27,8 @@ def test_window_attention_w1_equals_appended_decode():
     rng = jax.random.split(jax.random.PRNGKey(0), 5)
     B, S, H, KV, D = 2, 16, 4, 2, 8
     q = jax.random.normal(rng[0], (B, 1, H, D), jnp.float32)
-    kc = jax.random.normal(rng[1], (B, S, KV, D), jnp.float32)
-    vc = jax.random.normal(rng[2], (B, S, KV, D), jnp.float32)
+    kc = jax.random.normal(rng[1], (B, KV, S, D), jnp.float32)
+    vc = jax.random.normal(rng[2], (B, KV, S, D), jnp.float32)
     kn = jax.random.normal(rng[3], (B, 1, KV, D), jnp.float32)
     vn = jax.random.normal(rng[4], (B, 1, KV, D), jnp.float32)
     lens = jnp.asarray([7, 0], jnp.int32)
