@@ -224,7 +224,56 @@ def kernel_cases(interpret: bool = False):
             return _max_err(got, ref)
         return run
 
-    return [("flash_causal_prefill[S=256]", prefill(256)),
+    def kda_decode():
+        # the delta rule's decode kernel at the published head sizes (64
+        # heads x 128 x 128, 128 slots, 6 linear layers: 3.2 GB of state),
+        # a quarter of the slots idle: the written layer's active states
+        # against the jnp recurrence, every other byte against itself
+        from gofr_tpu.ops import kda
+        lk, slots, heads, d = 6, 128, 64, 128
+        q, k, v, a = (jax.random.normal(jax.random.PRNGKey(20 + i),
+                                        (slots, heads, d), jnp.float32)
+                      for i in range(4))
+        q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+                for x in (q, k))
+        alpha, beta = jax.nn.sigmoid(a), 2 * jax.nn.sigmoid(v[..., 0])
+        state = jax.random.normal(jax.random.PRNGKey(24),
+                                  (lk, slots, heads, d, d), jnp.float32)
+        active = jnp.arange(slots) % 4 != 1
+        before = [jnp.sum(jnp.abs(state[i])) for i in range(lk)]
+        want_o, want_s = kda.recurrent_ref(
+            q[:, None], k[:, None], v[:, None], alpha[:, None],
+            beta[:, None], state[2])
+        want_s = jnp.where(active[:, None, None, None], want_s, state[2])
+        o, state = kda.kda_decode(state, jnp.int32(2), q, k, v, alpha, beta,
+                                  active, interpret=interpret)
+        moved = max(float(jnp.abs(jnp.sum(jnp.abs(state[i])) - before[i]))
+                    for i in range(lk) if i != 2)
+        return max(_max_err(o, want_o[:, 0], active[:, None, None]),
+                   _max_err(state[2], want_s), moved)
+
+    def kda_prefill(t):
+        def run():
+            from gofr_tpu.ops import kda
+            heads, d = 64, 128
+            q, k, v, a = (jax.random.normal(jax.random.PRNGKey(30 + i),
+                                            (1, t, heads, d), jnp.float32)
+                          for i in range(4))
+            q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+                    for x in (q, k))
+            alpha, beta = jax.nn.sigmoid(a + 2), 2 * jax.nn.sigmoid(v[..., 0])
+            s0 = jax.random.normal(jax.random.PRNGKey(34),
+                                   (1, heads, d, d), jnp.float32)
+            o, s1 = kda.kda_prefill(q, k, v, alpha, beta, s0,
+                                    interpret=interpret)
+            want_o, want_s = kda.recurrent_ref(q, k, v, alpha, beta, s0)
+            return max(_max_err(o, want_o), _max_err(s1, want_s))
+        return run
+
+    return [("kda_decode[f32,6x128x64x128x128]", kda_decode),
+            ("kda_prefill[f32,T=32]", kda_prefill(32)),
+            ("kda_prefill[f32,T=512]", kda_prefill(512)),
+            ("flash_causal_prefill[S=256]", prefill(256)),
             ("flash_causal_prefill[S=512]", prefill(512)),
             ("paged_decode_attention[int8,T=128]", paged(1)),
             ("paged_window_attention[int8,T=128,W=5]", paged(5)),

@@ -9,7 +9,7 @@ layer axis. No torch, no module classes — params are data, which is what
 """
 
 from .common import ModelConfig, LLAMA_CONFIGS, BERT_CONFIGS, VIT_CONFIGS
-from . import llama, bert, vit, deepseek_v3
+from . import llama, bert, vit, deepseek_v3, solar_open2
 
 
 def family(cfg: ModelConfig):
@@ -19,9 +19,13 @@ def family(cfg: ModelConfig):
     has never heard of). Every family gives the generator the same entry
     points: ``init``, ``init_cache``, ``get_rope_tables``, ``prefill_kv``,
     ``write_kv``, ``prefill_chunk``, ``decode_step``, ``decode_kv_block``,
-    ``kv_layout``, ``unsupported_options``, ``serving_stats``."""
+    ``kv_layout``, ``unsupported_options``, ``serving_stats``, ``forward``,
+    and ``RECOMPUTABLE``: whether a cached position can be computed
+    again and give the same memory (rows can; a recurrent state cannot)."""
+    if "linear" in cfg.layer_pattern:
+        return solar_open2
     return deepseek_v3 if cfg.kv_lora_rank > 0 else llama
 
 
 __all__ = ["ModelConfig", "LLAMA_CONFIGS", "BERT_CONFIGS", "VIT_CONFIGS",
-           "llama", "bert", "vit", "deepseek_v3", "family"]
+           "llama", "bert", "vit", "deepseek_v3", "solar_open2", "family"]

@@ -59,10 +59,32 @@ class ModelConfig:
     moe_ffn_dim: int = 0
     n_dense_layers: int = 0
     n_experts_held: int = 0
+    # layers of more than one kind (models/solar_open2.py; a "linear"
+    # entry selects that family): one period of the stack, e.g. ("full",
+    # "linear", "linear", "linear"); layer l is of kind
+    # pattern[l % len(pattern)]. () = every layer the family's one kind.
+    # A full layer is softmax attention of n_heads x attn_head_dim
+    # (0 = dim // n_heads), rotated unless use_rope is false, with an
+    # output gate where attn_gate; a linear layer is the gated delta
+    # rule: linear_heads heads of linear_head_dim (key and value), a
+    # causal depthwise convolution over conv_kernel inputs on q, k and
+    # v, and two low-rank projections of rank gate_rank (decay, gate)
+    layer_pattern: tuple = ()
+    use_rope: bool = True
+    attn_gate: bool = False
+    attn_head_dim: int = 0
+    linear_heads: int = 0
+    linear_head_dim: int = 0
+    conv_kernel: int = 0
+    gate_rank: int = 0
+
+    def __post_init__(self):
+        # a configuration file gives the pattern as a list
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.attn_head_dim or self.dim // self.n_heads
 
     @property
     def jdtype(self):
@@ -113,6 +135,19 @@ LLAMA_CONFIGS = {
         experts_per_token=4, n_expert_groups=4, topk_groups=2,
         routed_scaling=2.5, n_shared_experts=1, moe_ffn_dim=40,
         n_dense_layers=1, n_experts_held=4),
+    # the hybrid family at test size: two periods of one full layer to
+    # three linear ones, head sizes that differ from dim // n_heads and
+    # from each other, 4 of 16 experts held
+    "tiny-kda-moe": ModelConfig(
+        name="tiny-kda-moe", vocab_size=256, dim=64, n_layers=8, n_heads=4,
+        n_kv_heads=2, ffn_dim=128, max_seq=128, norm_eps=1e-5,
+        dtype="float32", layer_pattern=("full", "linear", "linear",
+                                        "linear"),
+        use_rope=False, attn_gate=True, attn_head_dim=24, linear_heads=4,
+        linear_head_dim=16, conv_kernel=4, gate_rank=8, n_experts=16,
+        experts_per_token=4, n_expert_groups=1, topk_groups=1,
+        routed_scaling=1.0, n_shared_experts=1, moe_ffn_dim=40,
+        n_experts_held=4),
 }
 
 BERT_CONFIGS = {

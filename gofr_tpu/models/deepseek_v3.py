@@ -65,6 +65,7 @@ from .llama import _logits
 
 _LANES = 128
 _ROPE_CACHE: dict[tuple, tuple] = {}
+RECOMPUTABLE = True  # rows, as llama's: see models.family
 
 
 def get_rope_tables(cfg: ModelConfig, max_seq: int):

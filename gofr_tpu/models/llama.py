@@ -33,6 +33,9 @@ from .common import ModelConfig, dense_init
 
 
 _ROPE_CACHE: dict[tuple, tuple] = {}
+# a cached position computed again gives the same rows: the chunk
+# lattice may overlap its last chunk and a prefix hit may resume anywhere
+RECOMPUTABLE = True
 
 
 def get_rope_tables(cfg: ModelConfig, max_seq: int):

@@ -123,7 +123,8 @@ class Timeline:
                      live: int | None = None,
                      fetched: int | None = None,
                      assigned: int | None = None,
-                     touched: int | None = None) -> None:
+                     touched: int | None = None,
+                     states: int | None = None) -> None:
         """One fused decode dispatch->reap: ``slots`` is the tuple of
         active slot indices as dispatched, ``steps`` the block size,
         ``live`` the KV positions those slots held at dispatch (what
@@ -134,9 +135,15 @@ class Timeline:
         layer that counts them: ``assigned``, the (token, held expert)
         assignments the block's steps made over all routed layers, and
         ``touched``, the (step, layer, expert) cells that got at least
-        one (each is one expert's weights read)."""
+        one (each is one expert's weights read); and after them, where
+        it has recurrent layers, ``states``: the (layer, slot) states the
+        block's steps updated in place (each read and written once). A
+        field keeps its place: states without an expert layer come after
+        two Nones."""
+        counted = () if assigned is None and states is None \
+            else (assigned, touched)
         self.append("decode", t0, t1 - t0, slots, steps, live, fetched,
-                    *(() if assigned is None else (assigned, touched)))
+                    *counted, *(() if states is None else (states,)))
 
     def verify_block(self, t0: float, t1: float, slots, window: int) -> None:
         self.append("verify", t0, t1 - t0, slots, window)
@@ -327,9 +334,10 @@ class Timeline:
                                  "args": {"slots": len(a or ()),
                                           "steps": b, "live_tokens": c,
                                           "kv_fetched": d, "seq": seq,
-                                          **dict(zip(("moe_assigned",
-                                                      "moe_touched"),
-                                                     more))}})
+                                          **{k: v for k, v in zip(
+                                              ("moe_assigned", "moe_touched",
+                                               "states_updated"), more)
+                                             if v is not None}}})
             elif kind == "prefill":
                 body.append({"ph": "X", "pid": 1, "tid": slot_tid(a),
                              "name": f"prefill L={b}", "cat": "prefill",

@@ -15,6 +15,12 @@ Config keys (reference config style, pkg/gofr/config/config.go:3):
                       TPU_SHARDING, TPU_PAGED_BLOCKS, the host and Redis
                       cache tiers, TPU_SPEC_DECODE, TPU_LORA_ADAPTERS,
                       P/D roles and an int8 cache at start-up),
+                      the hybrid family (tiny-kda-moe; any configuration
+                      whose layer_pattern names a "linear" layer: gated
+                      delta-rule layers beside full ones, a recurrent
+                      state beside KV rows; it refuses the same options
+                      but the int8 cache, and TPU_MAX_SEQ must be whole
+                      prefill chunks),
                       bert family (bert/bert-base, bert-tiny), or
                       vit family (vit/vit-l-14, vit-tiny)
   TPU_WEIGHTS         checkpoint path (.npz or orbax dir); absent = random

@@ -30,7 +30,9 @@ from ..ops.quant import QuantizedLinear, quantize_int8
 _QUANT_LEAVES = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
                  # the latent-attention family (models/deepseek_v3.py)
                  "w_qa", "w_qb", "w_kva", "w_kvb",
-                 "ws_gate", "ws_up", "ws_down"}
+                 "ws_gate", "ws_up", "ws_down",
+                 # the hybrid family's full-layer gate (models/solar_open2.py)
+                 "w_attn_gate"}
 
 
 def _flatten(tree: Any, prefix: str = "") -> dict[str, np.ndarray]:

@@ -536,6 +536,10 @@ class GenerationEngine:
         # its assignments): assignments made, (step, layer, expert) cells
         # that got one, cells in all
         self._moe_assigned = self._moe_touched = self._moe_cells = 0
+        # bytes of recurrent state a slot holds whatever its length (0:
+        # the family keeps rows alone), for app_tpu_state_live_bytes
+        self._state_bytes = self._fam.serving_stats(cfg, slots).get(
+            "state_bytes_per_slot", 0)
         # In-flight admission poll cadence (seconds). While a decode
         # block runs on device, the serving loop waits on the submit
         # event in slices of this length and admits new arrivals
@@ -570,6 +574,17 @@ class GenerationEngine:
             self._chunk = pad_bucket(min(int(prefill_chunk), C_max),
                                      self.prompt_buckets)
             self._chunk_interleave = True
+        # can a cached position be computed again? Rows can (the lattice
+        # overlaps its last chunk, a prefix hit resumes anywhere); a
+        # recurrent state cannot: its lattice runs left-aligned chunks,
+        # the last one padded, and a prefix hit resumes only where the
+        # pool holds the state (_chunk_lattice, _resume_at)
+        self._rewind = self._fam.RECOMPUTABLE
+        if not self._rewind and self.max_seq % self._chunk:
+            raise ValueError(
+                f"max_seq {self.max_seq} must be whole prefill chunks of "
+                f"{self._chunk} for the model family of {cfg.name!r}: its "
+                "last chunk is padded, not overlapped")
 
         # Paged (block-pool) KV cache: slots share a pool of fixed
         # T-token blocks via a host-owned block table instead of owning
@@ -1915,12 +1930,7 @@ class GenerationEngine:
             # consistent with the real admission's — a T2 consult does
             # network I/O, and peeking a DIFFERENT answer than the
             # restore would re-open the hazard this guard closes.
-            mt = self._kv_match(req)
-            if mt is None:
-                return False
-            m_eff = clamp_restore_len(mt.matched_len, L)
-            return (m_eff >= self.prompt_buckets[0]
-                    and self._lattice_resume_valid(L, m_eff))
+            return self._resume_at(self._kv_match(req), L) > 0
         if self._paged and self._prefix_idx is not None:
             ver = self._prefix_idx.version
             if req.lattice_peek is not None and req.lattice_peek[0] == ver:
@@ -2062,6 +2072,34 @@ class GenerationEngine:
             rem -= T
         return L - pad_bucket(rem, self.prompt_buckets) >= 0
 
+    def _resume_at(self, mt, L: int) -> int:
+        """The position an L-token prompt's prefill resumes from on
+        match ``mt`` (0: the match is of no use). Rows restore to any
+        matched length on the lattice (at most L - 1: the last position
+        is always prefilled, the pool stores KV, not logits). A family
+        whose memory cannot be rewound stores a row under the tokens
+        before the position its state was taken at (_lattice_snapshot),
+        so its hit is whole or nothing: every token of the entry, which
+        is a chunk boundary; never fewer, never from rows alone. A
+        prompt that ends at that boundary is a miss: the clamp to L - 1
+        would resume one token before the position the state holds."""
+        if mt is None:
+            return 0
+        if not self._rewind:
+            m = mt.matched_len
+            if mt.entry is None or m != len(mt.entry.key) or m >= L \
+                    or m < self.prompt_buckets[0]:
+                return 0
+            return m
+        m = clamp_restore_len(mt.matched_len, L)
+        if m < self.prompt_buckets[0] \
+                or not self._lattice_resume_valid(L, m):
+            # less than the smallest bucket: the copy would not remove a
+            # dispatch's worth of work; and the final chunk needs
+            # [L - Sb, L) to be a valid window
+            return 0
+        return m
+
     def _chunk_lattice(self, attr: str, slot: int, req: _Request,
                        pos: int = 0,
                        track_slot: int | None = None) -> tuple[int, float]:
@@ -2165,12 +2203,23 @@ class GenerationEngine:
             return 0, 0.0
         rem = L - pos
         Sb = pad_bucket(rem, self.prompt_buckets)
-        final = req.prompt[L - Sb:]
+        if self._rewind:
+            # the final chunk ends at the prompt's end and overlaps the
+            # one before: those positions recompute to identical KV
+            begin, final = L - Sb, req.prompt[L - Sb:]
+        else:
+            # a state cannot be rewound: the final chunk starts where
+            # the last one ended and is padded (the family masks what
+            # lies past the sampled position); the memory as it stands
+            # here, at a chunk boundary, is what a prefix hit can use
+            self._lattice_snapshot(slot, req, pos)
+            begin, final = pos, np.zeros((Sb,), np.int32)
+            final[:rem] = req.prompt[pos:]
         tok, lp, self._key, new_cache = self._run(
             self._chunk_final_jit,
             getattr(self, attr), self.params, jnp.asarray(final[None, :]),
-            jnp.int32(L - Sb), jnp.int32(slot), jnp.int32(L),
-            jnp.int32(Sb - 1), jnp.float32(req.temperature),
+            jnp.int32(begin), jnp.int32(slot), jnp.int32(L),
+            jnp.int32(L - begin - 1), jnp.float32(req.temperature),
             jnp.int32(req.top_k), self._key, jnp.int32(req.seed),
             jnp.int32(req.pos_base), self._adapter1(req))
         setattr(self, attr, new_cache)
@@ -2717,13 +2766,9 @@ class GenerationEngine:
         # chunk prefills >= 1 token — the dispatch needs logits at the
         # prompt end to sample the first generated token (the pool
         # stores KV, not logits).
-        m_eff = clamp_restore_len(mt.matched_len, L)
+        m_eff = self._resume_at(mt, L)
         assert m_eff < L, "kvcache restore clamp violated"
-        if (m_eff < self.prompt_buckets[0]
-                # matched less than the smallest bucket: the copy would
-                # not remove a single dispatch's worth of work; and the
-                # final chunk needs [L - Sb, L) to be a valid window
-                or not self._lattice_resume_valid(L, m_eff)):
+        if not m_eff:
             self._kvc.reject(mt)
             return 0
         if mt.tier == "t0":
@@ -2759,8 +2804,8 @@ class GenerationEngine:
         before being overwritten; with the Redis tier on, the fresh
         KV's full blocks write through so sibling replicas skip the
         prefill too."""
-        if req.stream.cancelled.is_set():
-            return
+        if req.stream.cancelled.is_set() or not self._rewind:
+            return  # a state is stored where it was taken: the lattice
         prompt = np.asarray(req.prompt, np.int32)
         t0 = time.monotonic()
         if self._paged:
@@ -2806,6 +2851,32 @@ class GenerationEngine:
                                                         want))
         if self._tl is not None:
             self._tl.store(t0, time.monotonic(), idx, len(prompt), tier)
+
+    def _lattice_snapshot(self, idx: int, req: _Request, pos: int) -> None:
+        """Remember slot ``idx``'s memory as it stands at chunk boundary
+        ``pos`` of its prompt, before the final chunk runs: the rows of
+        the ``pos`` tokens so far and the state taken at exactly that
+        position, one pool row under the key ``prompt[:pos]``. The slot
+        is parked (no decode step writes it) and the copy queues behind
+        the chunks that built it. A prompt of one chunk or less has no
+        boundary and is not stored."""
+        if self._kvc is None or self._paged or pos <= 0 \
+                or req.stream.cancelled.is_set() \
+                or len(req.prompt) < self._store_min:
+            return
+        key = np.asarray(req.prompt[:pos], np.int32)
+        if self._kvc.covered(key, req.adapter):
+            return
+        t0 = time.monotonic()
+        row, _ = self._kvc.store(key, req.adapter,
+                                 tenant=req.tenant
+                                 if self.tenancy is not None else None)
+        self._pool = self._run(self._pool_store_jit, self._pool, self.cache,
+                               jnp.int32(row), jnp.int32(idx))
+        if self.tenancy is not None:
+            self._tenant_cache_sync()
+        if self._tl is not None:
+            self._tl.store(t0, time.monotonic(), idx, pos, "t0")
 
     def _shed_oom(self, req: _Request, e: "hbm.HBMExhausted") -> None:
         """OOM-shed a popped admission: the arbiter could not cover a
@@ -3994,13 +4065,20 @@ class GenerationEngine:
         moe = counters[0] if counters else None
         assigned = None if moe is None else int(moe.sum())
         touched = None if moe is None else int(np.count_nonzero(moe))
+        # a family with recurrent layers: the (layer, slot) states the
+        # block's steps updated in place (each is read and written once)
+        states = int(counters[1].sum()) if len(counters) > 1 else None
         if self._tl is not None:
             # one ring event per fused block, fanned out to per-slot
             # slices only at export time — the hot path pays one append
             self._tl.decode_block(
                 t0, time.monotonic(),
                 tuple(int(i) for i in np.flatnonzero(snap_active)),
-                self.decode_block, live, fetched, assigned, touched)
+                self.decode_block, live, fetched, assigned, touched, states)
+        if states is not None and self.metrics is not None:
+            self.metrics.set_gauge(
+                "app_tpu_state_live_bytes",
+                float(snap_active.sum()) * self._state_bytes)
         if moe is not None:
             self._moe_assigned += assigned
             self._moe_touched += touched

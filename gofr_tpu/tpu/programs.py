@@ -541,8 +541,10 @@ class EnginePrograms:
         active) -> (logits, stepped[, counters])`` is the only thing that
         differs between the contiguous and paged engines and between
         model families; what a family's step counts beside its logits
-        (the expert layer's assignments) comes back stacked a step as
-        the program's last output, for the reap's one fetch.
+        comes back stacked a step as the program's last output, for the
+        reap's one fetch. The counters have fixed places: first the
+        expert layer's assignments (None where the family has no such
+        layer), then the recurrent states updated.
 
         ``pack`` [B, W] int32 is the coalesced host dispatch state (one
         h2d when dirty — see _dispatch_pack); ``carry`` is the device

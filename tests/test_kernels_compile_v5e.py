@@ -1,4 +1,5 @@
-"""The cache's two kernels compiled for a TPU v5e that is described, not
+"""The cache's two kernels, and the delta rule's two (ops/kda.py, at the
+end), compiled for a TPU v5e that is described, not
 attached (libtpu's compile-only topology; no chip time, nothing runs):
 what interpret mode cannot see, Mosaic refusing a slice that is not
 whole tiles or a kernel that needs too much VMEM. At Mistral-7B's widths
@@ -84,3 +85,42 @@ def test_append_kernel_compiles_in_place(one_chip, kv, dtype):
     cache_bytes = 2 * L * B * kv * SMAX * D * jnp.dtype(dtype).itemsize
     assert mem.alias_size_in_bytes >= cache_bytes
     assert mem.temp_size_in_bytes < cache_bytes // 8
+
+
+# -- the delta rule's kernels (ops/kda.py) at Solar-Open2's head sizes ---------
+
+KDA_L, KDA_B, KDA_H = 6, 128, 64
+
+
+def test_kda_decode_compiles_in_place(one_chip):
+    """64 heads x 128 x 128 float32, 128 slots, 6 linear layers: the
+    3.2 GB of states it returns are the ones it was given."""
+    from gofr_tpu.ops import kda
+
+    def arr(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    row = arr((KDA_B, KDA_H, D))
+    compiled = jax.jit(kda.kda_decode, donate_argnums=(0,)).lower(
+        arr((KDA_L, KDA_B, KDA_H, D, D)), arr((), jnp.int32), row, row, row,
+        row, arr((KDA_B, KDA_H)), arr((KDA_B,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    state_bytes = KDA_L * KDA_B * KDA_H * D * D * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 64
+
+
+@pytest.mark.parametrize("tokens", [32, 512])
+def test_kda_prefill_compiles(one_chip, tokens):
+    """The smallest bucket and a whole chunk of one prompt."""
+    from gofr_tpu.ops import kda
+
+    def arr(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    seq = arr((1, tokens, KDA_H, D))
+    compiled = kda.kda_prefill.lower(
+        seq, seq, seq, seq, arr((1, tokens, KDA_H)),
+        arr((1, KDA_H, D, D))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
