@@ -364,14 +364,26 @@ def _layer(x, layer_w, cfg: ModelConfig, cos, sin, positions,
     # operation's op_name, which is what a device trace shows
     with jax.named_scope("attn_qkv"):
         h = rms_norm(x, layer_w["attn_norm"], cfg.norm_eps)
-        q = (qmatmul(h, layer_w["wq"])
-             + _lora(h, layer_w, "wq", adapter)).reshape(B, S, H, hd)
-        k = (qmatmul(h, layer_w["wk"])
-             + _lora(h, layer_w, "wk", adapter)).reshape(B, S, KV, hd)
-        v = (qmatmul(h, layer_w["wv"])
-             + _lora(h, layer_w, "wv", adapter)).reshape(B, S, KV, hd)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+        q = qmatmul(h, layer_w["wq"]) + _lora(h, layer_w, "wq", adapter)
+        k = qmatmul(h, layer_w["wk"]) + _lora(h, layer_w, "wk", adapter)
+        v = qmatmul(h, layer_w["wv"]) + _lora(h, layer_w, "wv", adapter)
+        # The barrier keeps the heads-major layout that the reshape and
+        # the rope want from travelling back into the q and k matmuls.
+        # With it they read wq and wk from the stack as it is stored,
+        # as every other projection does. Without it the dots ask for
+        # the weight as [H, hd, D]: on the chip the decode block then
+        # transposes the whole wq and wk stacks at the top of every
+        # dispatch (0.67 GB of temporaries at Mistral-7B's sizes) and
+        # every program stages a layer's slice of both through VMEM
+        # with the matmul serial behind it (PERF.md, Findings PR 33).
+        # It asks for no other value: the same bits on the CPU
+        # (tests/test_models.py::test_qkv_barrier_changes_no_value); the
+        # compiled program is held by tests/test_kernels_compile_v5e.py
+        # ::test_qk_projections_read_their_weights_in_place.
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
+        q = apply_rope(q.reshape(B, S, H, hd), cos, sin, positions)
+        k = apply_rope(k.reshape(B, S, KV, hd), cos, sin, positions)
+        v = v.reshape(B, S, KV, hd)
 
     with jax.named_scope("kv_write"):
         k_all, v_all = kv_write(k, v)

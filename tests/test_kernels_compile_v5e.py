@@ -1,5 +1,6 @@
-"""The cache's two kernels, and the delta rule's two (ops/kda.py, at the
-end), compiled for a TPU v5e that is described, not
+"""The cache's two kernels, the delta rule's two (ops/kda.py) and, at the
+end, the llama family's decode block and prefill with the weights'
+reads in them, compiled for a TPU v5e that is described, not
 attached (libtpu's compile-only topology; no chip time, nothing runs):
 what interpret mode cannot see, Mosaic refusing a slice that is not
 whole tiles or a kernel that needs too much VMEM. At Mistral-7B's widths
@@ -12,6 +13,8 @@ The topology is described inside a fixture and only this file does so:
 one process at a time may load the TPU's library, and a worker that
 collects this file must not load it while it imports.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,3 +127,73 @@ def test_kda_prefill_compiles(one_chip, tokens):
         seq, seq, seq, seq, arr((1, tokens, KDA_H)),
         arr((1, KDA_H, D, D))).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the llama family's programs: every projection reads its stack in place ----
+
+def _lowered(monkeypatch, sharding, program, layers):
+    """``EnginePrograms``' own decode block or 256-token prefill, lowered
+    from shapes alone (nothing is allocated): Mistral-7B's widths cut to
+    ``layers`` layers, int8 weights and cache, 40 slots x 2,048, both
+    kernels on (a CPU process answers no to ``tpu_backend_ok``)."""
+    from gofr_tpu.models import llama
+    from gofr_tpu.models.common import ModelConfig
+    from gofr_tpu.ops import flash
+    from gofr_tpu.tpu import programs
+    from gofr_tpu.tpu.checkpoint import maybe_quantize
+
+    monkeypatch.setattr(flash, "tpu_backend_ok", lambda: True)
+    cfg = ModelConfig(name="mistral-7b-cut", vocab_size=32768, dim=4096,
+                      n_layers=layers, n_heads=32, n_kv_heads=8,
+                      ffn_dim=14336, max_seq=SMAX, rope_theta=1e6)
+    prog = programs.EnginePrograms(
+        cfg, llama, object(), max_seq=SMAX, kv_dtype=jnp.int8,
+        decode_block=4, n_adapters=0, spec_k=0, paged=None, mesh=None)
+    prog.describe("cache", B)
+    jits = prog.build()
+
+    def arr(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    def described(build):
+        return jax.tree_util.tree_map(lambda s: arr(s.shape, s.dtype),
+                                      jax.eval_shape(build))
+
+    params = described(lambda: maybe_quantize(
+        llama.init(cfg, jax.random.PRNGKey(0)), True))
+    cache = described(lambda: llama.init_cache(cfg, B, SMAX, dtype=jnp.int8))
+    key = arr((2,), jnp.uint32)
+    if program == "decode block":
+        slots = arr((B,))
+        return jits["_step_jit"].lower(
+            cache, params, arr((B, programs.PACK_EXTRA + programs.EOS_MAX)),
+            (slots, arr((B,), jnp.bool_), slots, slots), key)
+    return jits["_prefill_jit"].lower(
+        cache, params, arr((1, 256)), arr(()), arr(()),
+        arr((), jnp.float32), arr(()), key, arr(()), arr(()))
+
+
+@pytest.mark.parametrize("program", ["decode block", "prefill 256"])
+def test_qk_projections_read_their_weights_in_place(one_chip, monkeypatch,
+                                                    program):
+    """``llama._layer``'s barrier, read off the compiled program. Two
+    layers show what 32 do: without it the decode block transposes the
+    whole wq and wk stacks once a dispatch (a ``copy`` of an int8 stack,
+    49 MB of temporaries here, 0.67 GB at 32 layers) and every program
+    stages a layer's slice of both in VMEM (``S(1)``) before the matmul
+    that should have streamed it (PERF.md, Findings PR 33)."""
+    compiled = _lowered(monkeypatch, one_chip, program, layers=2).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    results = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = (s8\[[\d,]+\]\S*) ([\w\-]+)\(", text, re.M)
+    assert results                      # the pattern still reads this HLO
+    # (an asynchronous copy-start of a whole stack into VMEM is these two
+    # layers fitting there, and no change of layout: 32 layers do not)
+    stack_copies = [r for r in results if r[1] in ("copy", "transpose")
+                    and r[0].startswith("s8[2,4096,")]
+    staged = [r for r in results if r[1] == "fusion"
+              and r[0].startswith("s8[1,4096,") and "S(1)" in r[0]]
+    assert not stack_copies
+    assert not staged
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20

@@ -1,6 +1,9 @@
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from gofr_tpu.models import LLAMA_CONFIGS, BERT_CONFIGS, VIT_CONFIGS, bert, llama, vit
 from gofr_tpu.models.common import sample_logits
@@ -149,6 +152,96 @@ def test_int8_kv_cache_chunked_prefill():
     dq_whole = np.asarray(dequantize_kv(whole.k, whole.k_scale,
                                         jnp.float32))[:, :, :8]
     np.testing.assert_allclose(dq_chunk, dq_whole, atol=5e-2)
+
+
+def _parent_qkv(x, layer_w, cfg, cos, sin, positions, adapter):
+    """``_layer``'s q, k and v as they were written before its barrier:
+    each projection reshaped to heads, and roped, in one expression."""
+    from gofr_tpu.ops.norms import rms_norm
+    from gofr_tpu.ops.quant import qmatmul
+    from gofr_tpu.ops.rope import apply_rope
+
+    B, S = x.shape[:2]
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    h = rms_norm(x, layer_w["attn_norm"], cfg.norm_eps)
+    q = (qmatmul(h, layer_w["wq"])
+         + llama._lora(h, layer_w, "wq", adapter)).reshape(B, S, H, hd)
+    k = (qmatmul(h, layer_w["wk"])
+         + llama._lora(h, layer_w, "wk", adapter)).reshape(B, S, KV, hd)
+    v = (qmatmul(h, layer_w["wv"])
+         + llama._lora(h, layer_w, "wv", adapter)).reshape(B, S, KV, hd)
+    return (apply_rope(q, cos, sin, positions),
+            apply_rope(k, cos, sin, positions), v)
+
+
+@pytest.mark.parametrize("lora", [False, True], ids=["base", "lora"])
+@pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_qkv_barrier_changes_no_value(dtype, quant, lora):
+    """The barrier between the three projections and the reshape to
+    heads is about layout alone: q, k and v come out bit for bit as the
+    parent's formulation gives them, with an adapter and without."""
+    cfg = dataclasses.replace(TINY, dtype=dtype)
+    params = llama.init(cfg, jax.random.PRNGKey(0))
+    layers = params["layers"]
+    adapter = None
+    if lora:
+        layers = {**layers,
+                  **llama.init_lora(cfg, 3, 4, jax.random.PRNGKey(2))}
+        for i, name in enumerate(("wq", "wk", "wv")):  # B is made zero
+            b = layers[f"lora_b_{name}"]
+            layers[f"lora_b_{name}"] = (jax.random.normal(
+                jax.random.PRNGKey(10 + i), b.shape) * 0.05).astype(b.dtype)
+        adapter = jnp.asarray([2, 1], jnp.int32)
+    layers = maybe_quantize_tree(layers, quant, min_size=1)
+    layer_w = jax.tree_util.tree_map(lambda a: a[1], layers)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 5, cfg.dim),
+                          cfg.jdtype)
+    cos, sin = llama.get_rope_tables(cfg, 32)
+    positions = jnp.asarray([[3, 4, 5, 6, 7], [0, 1, 2, 3, 4]], jnp.int32)
+
+    def through_layer(x, layer_w):
+        seen = []
+
+        def attend(q, k, v):
+            seen.extend((q, k, v))
+            return q
+
+        llama._layer(x, layer_w, cfg, cos, sin, positions,
+                     kv_write=lambda k, v: (k, v), attend=attend,
+                     adapter=adapter)
+        return tuple(seen)
+
+    got = jax.jit(through_layer)(x, layer_w)
+    want = jax.jit(lambda x, lw: _parent_qkv(x, lw, cfg, cos, sin, positions,
+                                             adapter))(x, layer_w)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+
+
+@pytest.mark.parametrize("kv_dtype", [None, jnp.int8], ids=["float", "int8"])
+def test_decode_step_logits_equal_without_the_barrier(monkeypatch, kv_dtype):
+    """A whole jitted ``decode_step`` on ``tiny``: the logits and the
+    rows it writes are the same bits with the barrier traced as the
+    identity, which is the parent's program."""
+    params = llama.init(TINY, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 6), 0,
+                                TINY.vocab_size)
+    cache = llama.init_cache(TINY, batch=2, max_seq=16, dtype=kv_dtype)
+    _, cache = llama.prefill(params, TINY, tokens, cache)
+    nxt = jnp.asarray([7, 9], jnp.int32)
+
+    def step(tokens, cache):
+        return llama.decode_step(params, TINY, tokens, cache)
+
+    got = jax.jit(step)(nxt, cache)
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    want = jax.jit(lambda t, c: step(t, c))(nxt, cache)  # traced afresh
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_llama_jit_decode_no_retrace():
