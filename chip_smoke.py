@@ -224,6 +224,43 @@ def kernel_cases(interpret: bool = False):
             return _max_err(got, ref)
         return run
 
+    def ring_decode(heads):
+        def run():
+            # the decode kernel over stacked RINGS at the published sizes
+            # (6 window layers x 128 slots x 8 KV heads x 512 rows, 64
+            # query heads; 48 is a full layer's group of 6 on the same
+            # kernel): lengths under, at and over the window, one row
+            # before and after each wrap, dead slots between
+            lw, slots, w = 6, 128, 512
+            some = [0, 1, 255, 256, 511, 512, 513, 767, 768, 1023, 1024,
+                    1025, 1500, 2046, 0, 700]
+            lengths = jnp.asarray(some * (slots // len(some)), jnp.int32)
+            q, kn, vn = (rand(40, (slots, 1, heads, D)),
+                         rand(41, (slots, 1, KV, D)),
+                         rand(42, (slots, 1, KV, D)))
+            kc, vc = (rand(43, (lw, slots, KV, w, D)),
+                      rand(44, (lw, slots, KV, w, D)))
+            got = flash_decode.flash_decode_ring(
+                q, kc, vc, kn, vn, lengths, jnp.int32(4),
+                block_s=flash_decode.block_size(w), interpret=interpret)
+            live, skip = flash_decode.ring_rows(lengths, w)
+            ref = attention.decode_attention_appended(
+                q, kc[4], vc[4], kn, vn, live, exclude=skip)
+            return _max_err(got, ref)
+        return run
+
+    def banded_prefill():
+        # a band narrower than the prompt: k blocks below it are skipped
+        s, w = 1024, 512
+        q, k, v = (rand(45, (1, s, H, D)), rand(46, (1, s, KV, D)),
+                   rand(47, (1, s, KV, D)))
+        lengths = jnp.asarray([s - 100], jnp.int32)
+        mask = jnp.arange(s)[None, :] < lengths[:, None]
+        got = flash.flash_causal_prefill(q, k, v, lengths, window=w,
+                                         interpret=interpret)
+        ref = attention.causal_attention(q, k, v, mask=mask, window=w)
+        return _max_err(got, ref, mask[:, :, None, None])
+
     def kda_decode():
         # the delta rule's decode kernel at the published head sizes (64
         # heads x 128 x 128, 128 slots, 6 linear layers: 3.2 GB of state),
@@ -273,6 +310,9 @@ def kernel_cases(interpret: bool = False):
     return [("kda_decode[f32,6x128x64x128x128]", kda_decode),
             ("kda_prefill[f32,T=32]", kda_prefill(32)),
             ("kda_prefill[f32,T=512]", kda_prefill(512)),
+            ("flash_decode_ring[bf16,6x128x8x512x128,H=64]", ring_decode(64)),
+            ("flash_decode_ring[bf16,6x128x8x512x128,H=48]", ring_decode(48)),
+            ("flash_causal_prefill[S=1024,window=512]", banded_prefill),
             ("flash_causal_prefill[S=256]", prefill(256)),
             ("flash_causal_prefill[S=512]", prefill(512)),
             ("paged_decode_attention[int8,T=128]", paged(1)),

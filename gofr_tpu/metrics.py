@@ -426,6 +426,9 @@ def register_framework_metrics(m: Manager) -> None:
     m.new_gauge("app_tpu_state_live_bytes",
                 "bytes of recurrent state the last decode block's active "
                 "slots hold (a family with linear-attention layers)")
+    m.new_gauge("app_tpu_kv_window_live_bytes",
+                "bytes of K and V the last decode block's active slots hold "
+                "on their rings (a family with sliding-window layers)")
     m.new_counter("app_tpu_requests_total", "total TPU predict requests")
     m.new_counter("app_tpu_tokens_generated_total", "total generated tokens")
     m.new_counter("app_tpu_prefix_cache_hits_total",

@@ -9,7 +9,7 @@ layer axis. No torch, no module classes — params are data, which is what
 """
 
 from .common import ModelConfig, LLAMA_CONFIGS, BERT_CONFIGS, VIT_CONFIGS
-from . import llama, bert, vit, deepseek_v3, solar_open2
+from . import llama, bert, vit, deepseek_v3, solar_open2, laguna
 
 
 def family(cfg: ModelConfig):
@@ -21,11 +21,15 @@ def family(cfg: ModelConfig):
     ``write_kv``, ``prefill_chunk``, ``decode_step``, ``decode_kv_block``,
     ``kv_layout``, ``unsupported_options``, ``serving_stats``, ``forward``,
     and ``RECOMPUTABLE``: whether a cached position can be computed
-    again and give the same memory (rows can; a recurrent state cannot)."""
+    again and give the same memory (rows can; a recurrent state and a
+    ring of rows cannot)."""
     if "linear" in cfg.layer_pattern:
         return solar_open2
+    if "window" in cfg.layer_pattern:
+        return laguna
     return deepseek_v3 if cfg.kv_lora_rank > 0 else llama
 
 
 __all__ = ["ModelConfig", "LLAMA_CONFIGS", "BERT_CONFIGS", "VIT_CONFIGS",
-           "llama", "bert", "vit", "deepseek_v3", "solar_open2", "family"]
+           "llama", "bert", "vit", "deepseek_v3", "solar_open2", "laguna",
+           "family"]

@@ -77,6 +77,20 @@ class ModelConfig:
     linear_head_dim: int = 0
     conv_kernel: int = 0
     gate_rank: int = 0
+    # sliding-window layers beside full ones (models/laguna.py; a
+    # "window" entry in layer_pattern selects that family): a window
+    # layer attends to the last window_size positions, the token's own
+    # among them, and caches them on a ring of window_size rows; it has
+    # window_heads query heads (0 = n_heads) on the same n_kv_heads and
+    # rotates the whole head by plain frequencies of window_rope_theta
+    # (0 = rope_theta). A full layer rotates the first rotary_dim values
+    # of a head (0 = all) by rope_theta and rope_scaling. head_gate: the
+    # output gate is one value a head, sigmoid(W_g x) [heads]
+    window_size: int = 0
+    window_heads: int = 0
+    window_rope_theta: float = 0.0
+    rotary_dim: int = 0
+    head_gate: bool = False
 
     def __post_init__(self):
         # a configuration file gives the pattern as a list
@@ -148,6 +162,24 @@ LLAMA_CONFIGS = {
         experts_per_token=4, n_expert_groups=1, topk_groups=1,
         routed_scaling=1.0, n_shared_experts=1, moe_ffn_dim=40,
         n_experts_held=4),
+    # the window family at test size: two periods of one full layer to
+    # three window layers, a window every test prompt wraps, head counts
+    # that differ by kind (groups of 3 and 4), half the head rotated on
+    # the full layers, one dense layer before the routed ones
+    "tiny-swa-moe": ModelConfig(
+        name="tiny-swa-moe", vocab_size=256, dim=64, n_layers=8, n_heads=6,
+        n_kv_heads=2, ffn_dim=96, max_seq=128, rope_theta=500000.0,
+        norm_eps=1e-6, dtype="float32",
+        rope_scaling={"rope_type": "yarn", "factor": 4.0,
+                      "original_max_position_embeddings": 32,
+                      "beta_fast": 32, "beta_slow": 1,
+                      "attention_factor": 1.1386},
+        layer_pattern=("full", "window", "window", "window"),
+        attn_head_dim=16, window_size=8, window_heads=8,
+        window_rope_theta=10000.0, rotary_dim=8, head_gate=True,
+        n_experts=8, experts_per_token=2, n_expert_groups=1, topk_groups=1,
+        routed_scaling=2.5, n_shared_experts=1, moe_ffn_dim=40,
+        n_dense_layers=1),
 }
 
 BERT_CONFIGS = {

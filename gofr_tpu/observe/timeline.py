@@ -124,7 +124,8 @@ class Timeline:
                      fetched: int | None = None,
                      assigned: int | None = None,
                      touched: int | None = None,
-                     states: int | None = None) -> None:
+                     states: int | None = None,
+                     ring: int | None = None) -> None:
         """One fused decode dispatch->reap: ``slots`` is the tuple of
         active slot indices as dispatched, ``steps`` the block size,
         ``live`` the KV positions those slots held at dispatch (what
@@ -137,13 +138,19 @@ class Timeline:
         ``touched``, the (step, layer, expert) cells that got at least
         one (each is one expert's weights read); and after them, where
         it has recurrent layers, ``states``: the (layer, slot) states the
-        block's steps updated in place (each read and written once). A
+        block's steps updated in place (each read and written once);
+        and where it has window layers, ``ring``: the rows of ONE window
+        layer's rings those slots held at dispatch (each cursor cut to
+        the window, beside ``live``, which the full layers read). A
         field keeps its place: states without an expert layer come after
-        two Nones."""
-        counted = () if assigned is None and states is None \
-            else (assigned, touched)
+        two Nones, ring rows without states after a None."""
+        tail = [assigned, touched, states, ring]
+        while tail and tail[-1] is None:
+            tail.pop()
+        if 0 < len(tail) < 2:
+            tail.append(None)     # the expert layer's two go together
         self.append("decode", t0, t1 - t0, slots, steps, live, fetched,
-                    *counted, *(() if states is None else (states,)))
+                    *tail)
 
     def verify_block(self, t0: float, t1: float, slots, window: int) -> None:
         self.append("verify", t0, t1 - t0, slots, window)
@@ -336,7 +343,8 @@ class Timeline:
                                           "kv_fetched": d, "seq": seq,
                                           **{k: v for k, v in zip(
                                               ("moe_assigned", "moe_touched",
-                                               "states_updated"), more)
+                                               "states_updated",
+                                               "ring_rows"), more)
                                              if v is not None}}})
             elif kind == "prefill":
                 body.append({"ph": "X", "pid": 1, "tid": slot_tid(a),

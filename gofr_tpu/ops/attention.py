@@ -28,12 +28,14 @@ def _repeat_kv_shape(q: jnp.ndarray, n_kv: int) -> jnp.ndarray:
 
 @jax.named_scope("causal_attention")
 def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                     mask: jnp.ndarray | None = None) -> jnp.ndarray:
+                     mask: jnp.ndarray | None = None,
+                     window: int = 0) -> jnp.ndarray:
     """Causal self-attention for prefill.
 
     q: [B, S, H, D]; k, v: [B, S, KV, D] (KV may divide H for GQA).
     mask: optional [B, S] validity mask (1 = real token, 0 = padding).
-    Returns [B, S, H, D].
+    window > 0: a band, position p sees the ``window`` positions
+    (p - window, p]. Returns [B, S, H, D].
     """
     b, s, h, d = q.shape
     n_kv = k.shape[2]
@@ -44,6 +46,8 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, k,
                         preferred_element_type=jnp.float32)
     causal = jnp.tril(jnp.ones((s, s), dtype=bool))
+    if window:
+        causal &= ~jnp.tril(jnp.ones((s, s), dtype=bool), -window)
     scores = jnp.where(causal[None, None, None], scores, NEG_INF)
     if mask is not None:
         scores = jnp.where(mask[:, None, None, None, :], scores, NEG_INF)
@@ -82,7 +86,9 @@ def decode_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
                               v_cache: jnp.ndarray, k_new: jnp.ndarray,
                               v_new: jnp.ndarray, lengths: jnp.ndarray,
                               k_scale: jnp.ndarray | None = None,
-                              v_scale: jnp.ndarray | None = None) -> jnp.ndarray:
+                              v_scale: jnp.ndarray | None = None,
+                              exclude: jnp.ndarray | None = None
+                              ) -> jnp.ndarray:
     """Decode attention over the cache PLUS the current token's k/v, before
     that token has been written back.
 
@@ -103,7 +109,9 @@ def decode_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
     q: [B, 1, H, D]; k_cache/v_cache: [B, KV, Smax, D], a layer of the
     cache in its own order (models.llama.KVCache);
     k_new/v_new: [B, 1, KV, D]; lengths: [B] valid entries (EXCLUDING the
-    current token). Returns [B, 1, H, D].
+    current token). Returns [B, 1, H, D]. ``exclude`` [B]: a cache row
+    not to read whatever ``lengths`` says (a ring's oldest row, which the
+    step is about to overwrite: ops.flash_decode.ring_rows).
 
     INT8 cache: when ``k_scale``/``v_scale`` [B, KV, Smax] are given the
     cache tensors are per-vector int8 (ops.quant.quantize_kv). The scale is
@@ -124,6 +132,8 @@ def decode_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
         # k_scale [B,KV,Smax] beside scores [B,KV,G,Smax]
         scores_c = scores_c * k_scale[:, :, None, :]
     valid = jnp.arange(smax)[None, :] < lengths[:, None]
+    if exclude is not None:
+        valid &= jnp.arange(smax)[None, :] != exclude[:, None]
     scores_c = jnp.where(valid[:, None, None, :], scores_c, NEG_INF)
     scores_s = jnp.einsum("bkgd,btkd->bkgt", qg, k_new,
                           preferred_element_type=jnp.float32)  # [B,KV,G,1]
@@ -230,6 +240,54 @@ def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                       probs_c.astype(vdt), v_cache.astype(vdt))
            + jnp.einsum("bkgst,btkd->bskgd",
                         probs[..., smax:].astype(v_new.dtype), v_new))
+    return out.reshape(b, c, h, d)
+
+
+def ring_held(rows: int, end) -> jnp.ndarray:
+    """The position each of a ring's ``rows`` rows holds once positions
+    [0, end) have been written, position p at row p % rows: the last
+    p < end with p % rows == r, negative where row r holds none yet.
+    ``end``: int32 scalar or [...]; returns [..., rows]."""
+    r = jnp.arange(rows, dtype=jnp.int32)
+    end = jnp.asarray(end, jnp.int32)[..., None]
+    return r + rows * ((end - 1 - r) // rows)
+
+
+@jax.named_scope("ring_chunk_attention")
+def ring_chunk_attention(q: jnp.ndarray, k_ring: jnp.ndarray,
+                         v_ring: jnp.ndarray, k_new: jnp.ndarray,
+                         v_new: jnp.ndarray,
+                         start: jnp.ndarray) -> jnp.ndarray:
+    """``chunk_attention`` for a sliding-window layer whose cache is a
+    ring: C new tokens at positions [start, start + C) attend, each to
+    the ``W`` positions (p - W, p], over the ring as it stands before
+    the chunk (the last W positions below ``start``) and causally within
+    the chunk. The chunk's rows go into the ring afterwards (the caller:
+    a chunk as long as the ring overwrites all of it).
+
+    q: [B, C, H, D]; k_ring/v_ring: [B, KV, W, D], position p at row
+    p % W; k_new/v_new: [B, C, KV, D]; start: scalar int32. Returns
+    [B, C, H, D]."""
+    b, c, h, d = q.shape
+    n_kv, w = k_ring.shape[1], k_ring.shape[2]
+    qg = _repeat_kv_shape(q * d ** -0.5, n_kv)  # [B,C,KV,G,D]
+    q_pos = start + jnp.arange(c, dtype=jnp.int32)
+    held = ring_held(w, start)                                   # [W]
+    seen = (held[None, :] >= 0) & (held[None, :] > q_pos[:, None] - w)
+    scores_c = jnp.einsum("bskgd,bktd->bkgst", qg, k_ring.astype(qg.dtype),
+                          preferred_element_type=jnp.float32)  # [B,KV,G,C,W]
+    scores_c = jnp.where(seen[None, None, None], scores_c, NEG_INF)
+    scores_n = jnp.einsum("bskgd,btkd->bkgst", qg, k_new,
+                          preferred_element_type=jnp.float32)  # [B,KV,G,C,C]
+    band = jnp.tril(jnp.ones((c, c), dtype=bool)) \
+        & ~jnp.tril(jnp.ones((c, c), dtype=bool), -w)
+    scores_n = jnp.where(band[None, None, None], scores_n, NEG_INF)
+    probs = jax.nn.softmax(
+        jnp.concatenate([scores_c, scores_n], axis=-1), axis=-1)
+    out = (jnp.einsum("bkgst,bktd->bskgd",
+                      probs[..., :w].astype(v_ring.dtype), v_ring)
+           + jnp.einsum("bkgst,btkd->bskgd",
+                        probs[..., w:].astype(v_new.dtype), v_new))
     return out.reshape(b, c, h, d)
 
 

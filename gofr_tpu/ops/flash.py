@@ -76,7 +76,8 @@ def fit_block(n: int, block: int) -> int:
 
 def _flash_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *,
-                  block_q: int, block_k: int, scale: float):
+                  block_q: int, block_k: int, scale: float,
+                  window: int = 0):
     """One (batch, head, q-block, k-block) step of the online softmax.
 
     m/l/acc scratch persists across the innermost (k-block) grid dim:
@@ -94,8 +95,13 @@ def _flash_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     # causal skip: this k block participates only if its first row is at
-    # or below the q block's last row
-    @pl.when(ki * block_k < (qi + 1) * block_q)
+    # or below the q block's last row; under a band, only if its last
+    # row is also inside the window of the q block's first row
+    in_reach = ki * block_k < (qi + 1) * block_q
+    if window:
+        in_reach &= (ki + 1) * block_k > qi * block_q - window + 1
+
+    @pl.when(in_reach)
     def _compute():
         q = q_ref[0, 0, :, :] * scale                       # [BQ, D]
         k_blk = k_ref[0, 0, :, :]                           # [BK, D]
@@ -107,7 +113,10 @@ def _flash_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
             jnp.int32, (block_q, block_k), 0)
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        s = jnp.where((k_pos <= q_pos) & (k_pos < length), s, NEG_INF)
+        seen = (k_pos <= q_pos) & (k_pos < length)
+        if window:
+            seen &= k_pos > q_pos - window
+        s = jnp.where(seen, s, NEG_INF)
 
         m_prev = m_ref[:, :1]                               # [BQ, 1]
         l_prev = l_ref[:, :1]
@@ -134,18 +143,19 @@ def _flash_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
-                                             "interpret"))
+                                             "interpret", "window"))
 def flash_causal_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          lengths: jnp.ndarray, *, block_q: int = 128,
-                         block_k: int = 128,
-                         interpret: bool = False) -> jnp.ndarray:
+                         block_k: int = 128, interpret: bool = False,
+                         window: int = 0) -> jnp.ndarray:
     """Causal prefill attention without S² materialization.
 
     q: [B, S, H, D]; k, v: [B, S, KV, D] (KV divides H); lengths: [B]
     int32 true prompt lengths (keys past a row's length are masked;
     query rows past it produce zeros). Requires S divisible by both
     blocks (callers dispatch through causal_attention_auto, which falls
-    back to the jnp reference otherwise).
+    back to the jnp reference otherwise). ``window`` > 0: a band, position
+    p sees (p - window, p]; k blocks wholly below it are skipped.
     Returns [B, S, H, D] in q.dtype.
     """
     b, s, h, d = q.shape
@@ -166,7 +176,8 @@ def flash_causal_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     vt = v.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(_flash_kernel, block_q=block_q,
-                               block_k=block_k, scale=scale)
+                               block_k=block_k, scale=scale,
+                               **({"window": window} if window else {}))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -272,7 +283,7 @@ def causal_attention_auto(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           mask: jnp.ndarray | None = None, *,
                           block_q: int = 128, block_k: int = 128,
                           interpret: bool = False,
-                          mesh=None) -> jnp.ndarray:
+                          mesh=None, window: int = 0) -> jnp.ndarray:
     """Flash kernel when the backend+shapes allow, jnp reference otherwise.
 
     Accepts ``lengths`` [B] or a PREFIX validity ``mask`` [B, S]
@@ -284,7 +295,8 @@ def causal_attention_auto(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     With ``mesh``, the kernel is wrapped in shard_map over the tp/data
     axes (flash_prefill_sharded); the reference — which GSPMD partitions
     fine on its own — remains the fallback when tp would split a KV head
-    or the shapes fail the kernel gate.
+    or the shapes fail the kernel gate. ``window`` > 0 bands the mask
+    (inference only, one device: no mesh form and no gradient).
     """
     interpret = interpret or interpret_env()
     if lengths is None and mask is not None:
@@ -299,6 +311,12 @@ def causal_attention_auto(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if interpret:
         block_q = fit_block(q.shape[1], block_q)
         block_k = fit_block(q.shape[1], block_k)
+    if window:
+        if mesh is None and (interpret or _kernel_ok(q, block_q, block_k)):
+            return flash_causal_prefill(
+                q, k, v, lengths.astype(jnp.int32), block_q=block_q,
+                block_k=block_k, interpret=interpret, window=window)
+        return causal_attention(q, k, v, mask=mask, window=window)
     if mesh is not None:
         from ..parallel.sharding import attention_shard_axes
 

@@ -111,9 +111,12 @@ _N_BUF = 2     # item w+1 in flight while item w is folded (ops.mla)
 _KV_BUF = 3    # items w+1 and w+2 in flight here: see block_size
 
 
-def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool):
+def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool,
+                   ring: bool = False):
     """The whole layer: walk the work list, fold each item."""
     layer_ref, n_ref, slot_ref, blk_ref, len_ref = refs[:5]
+    if ring:      # one more scalar a slot: the row it must not read
+        skip_ref, refs = refs[5], refs[:5] + refs[6:]
     q_ref, kn_ref, vn_ref, k_hbm, v_hbm = refs[5:10]
     if quant:
         ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf = refs[10:17]
@@ -173,6 +176,8 @@ def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool):
         pos = blk * block_s + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_s), 1)
         live = pos < length                                  # [1, BS]
+        if ring:
+            live &= pos != skip_ref[slot]
         # the KV heads' chains are independent: all score matmuls, one
         # softmax update over [KV, Gp, BS], all value matmuls
         q_all = q_ref[slot]                                  # [KV, Gp, D]
@@ -217,21 +222,24 @@ def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool):
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def flash_decode_stacked(q, cache_k, cache_v, k_new, v_new, lengths, layer,
                          k_scale=None, v_scale=None, *, block_s: int,
-                         interpret: bool = False) -> jnp.ndarray:
+                         interpret: bool = False,
+                         exclude=None) -> jnp.ndarray:
     """decode_attention_appended over layer ``layer`` of the stacked
     cache, reading only what ``lengths`` says is live.
 
     q: [B, 1, H, D]; cache_k/cache_v: [L, B, KV, Smax, D] (int8 with
     scales [L, B, KV, Smax], or dense); k_new/v_new: [B, 1, KV, D];
     lengths [B] EXCLUDING the current token, 0 for a slot whose cache
-    must not be read; layer: int32 scalar. Returns [B, 1, H, D] in
-    q.dtype."""
+    must not be read; layer: int32 scalar; exclude: [B] int32 or None,
+    a row of each slot that is not read though it lies below its length
+    (``ring_rows``). Returns [B, 1, H, D] in q.dtype."""
     b, _, h, d = q.shape
     n_kv, smax = cache_k.shape[2], cache_k.shape[3]
     g = h // n_kv
     g_pad = -(-g // _SUBLANES) * _SUBLANES
     quant = k_scale is not None
     lengths = lengths.astype(jnp.int32)
+    skip = () if exclude is None else (exclude.astype(jnp.int32),)
     n, slot, blk = _work_list(lengths, smax, block_s)
     qg = (q[:, 0] * (d ** -0.5)).reshape(b, n_kv, g, d)
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
@@ -258,21 +266,48 @@ def flash_decode_stacked(q, cache_k, cache_v, k_new, v_new, lengths, layer,
                 pltpu.SemaphoreType.DMA((4, _KV_BUF))]
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_s=block_s, n_kv=n_kv,
-                          quant=quant),
+                          quant=quant, **({"ring": True} if skip else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5, grid=(1,), in_specs=in_specs,
+            num_scalar_prefetch=5 + len(skip), grid=(1,), in_specs=in_specs,
             out_specs=vmem, scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g_pad, d), jnp.float32),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024),
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), n, slot, blk, lengths,
-      *operands)
+      *skip, *operands)
     out = out[:, :, :g].reshape(b, h, d)
     # a slot with no item never reached the kernel's write: its answer is
     # the softmax of one element, the appended token's value
     v_rep = jnp.repeat(v_new[:, 0], g, axis=1).astype(jnp.float32)
     out = jnp.where((lengths > 0)[:, None, None], out, v_rep)
     return out.astype(q.dtype).reshape(b, 1, h, d)
+
+
+def ring_rows(lengths, rows: int):
+    """What a decode step reads of a ring of ``rows`` rows, position p at
+    row p % rows, where a slot has ``lengths`` [B] positions cached and
+    attends to the last ``rows`` positions, its new token among them:
+    (rows live [B], the one row among them it must not read [B]). A ring
+    that has wrapped holds the last ``rows`` positions, one more than
+    the window has room for beside the new token: the oldest, at the row
+    the new token's position falls on and this step overwrites."""
+    lengths = lengths.astype(jnp.int32)
+    return jnp.minimum(lengths, rows), lengths % rows
+
+
+@functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
+def flash_decode_ring(q, ring_k, ring_v, k_new, v_new, lengths, layer, *,
+                      block_s: int, interpret: bool = False) -> jnp.ndarray:
+    """``flash_decode_stacked`` over layer ``layer`` of stacked RINGS
+    [L, B, KV, W, D] for slots that have ``lengths`` [B] positions cached
+    (0: a slot that must not be read): each attends to its new token and
+    the W - 1 positions before it (``ring_rows``). A program of its own
+    name, so that a device trace tells a window layer's kernel from a
+    full layer's."""
+    live, skip = ring_rows(lengths, ring_k.shape[3])
+    return flash_decode_stacked.__wrapped__(
+        q, ring_k, ring_v, k_new, v_new, live, layer, block_s=block_s,
+        interpret=interpret, exclude=skip)
 
 
 def flash_decode_sharded(q, cache_k, cache_v, k_new, v_new, lengths, layer,

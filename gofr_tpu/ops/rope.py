@@ -19,13 +19,18 @@ def rope_frequencies(head_dim: int, max_seq: int, theta: float = 500000.0,
 
     ``scaling`` supports the Llama-3 frequency-scaling dict
     {factor, low_freq_factor, high_freq_factor, original_max_position},
-    and with ``rope_type: "yarn"`` the YaRN dict (``yarn_inv_freq``).
+    and with ``rope_type: "yarn"`` the YaRN dict (``yarn_inv_freq``);
+    an ``attention_factor`` there is what the tables are multiplied by,
+    in place of the one reckoned from ``mscale``. ``head_dim`` is the
+    width that is rotated, which ``apply_rope_part`` lets be narrower
+    than the head.
     """
     if is_yarn(scaling):
         inv_freq = yarn_inv_freq(head_dim, theta, scaling)
-        mscale = (yarn_mscale(scaling["factor"], scaling.get("mscale", 1))
-                  / yarn_mscale(scaling["factor"],
-                                scaling.get("mscale_all_dim", 0)))
+        mscale = scaling.get("attention_factor") or (
+            yarn_mscale(scaling["factor"], scaling.get("mscale", 1))
+            / yarn_mscale(scaling["factor"],
+                          scaling.get("mscale_all_dim", 0)))
         freqs = jnp.outer(jnp.arange(max_seq, dtype=jnp.float32), inv_freq)
         return jnp.cos(freqs) * mscale, jnp.sin(freqs) * mscale
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
@@ -115,3 +120,15 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(dtype)
+
+
+def apply_rope_part(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
+                    positions: jnp.ndarray | None) -> jnp.ndarray:
+    """``apply_rope`` over the first ``2 * cos.shape[-1]`` values of each
+    head (paired as halves of that part); the rest passes through. Tables
+    as wide as the head rotate all of it."""
+    part = 2 * cos.shape[-1]
+    if part == x.shape[-1]:
+        return apply_rope(x, cos, sin, positions)
+    return jnp.concatenate(
+        [apply_rope(x[..., :part], cos, sin, positions), x[..., part:]], -1)

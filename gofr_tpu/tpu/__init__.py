@@ -21,6 +21,12 @@ Config keys (reference config style, pkg/gofr/config/config.go:3):
                       state beside KV rows; it refuses the same options
                       but the int8 cache, and TPU_MAX_SEQ must be whole
                       prefill chunks),
+                      the window family (tiny-swa-moe; any configuration
+                      whose layer_pattern names a "window" layer:
+                      sliding-window layers on a ring of rows beside full
+                      layers' rows in one cache; it refuses what the
+                      latent family refuses, and TPU_MAX_SEQ must be
+                      whole prefill chunks),
                       bert family (bert/bert-base, bert-tiny), or
                       vit family (vit/vit-l-14, vit-tiny)
   TPU_WEIGHTS         checkpoint path (.npz or orbax dir); absent = random

@@ -538,8 +538,16 @@ class GenerationEngine:
         self._moe_assigned = self._moe_touched = self._moe_cells = 0
         # bytes of recurrent state a slot holds whatever its length (0:
         # the family keeps rows alone), for app_tpu_state_live_bytes
-        self._state_bytes = self._fam.serving_stats(cfg, slots).get(
-            "state_bytes_per_slot", 0)
+        said = self._fam.serving_stats(cfg, slots)
+        self._state_bytes = said.get("state_bytes_per_slot", 0)
+        # rows of a window layer's ring (0: the family keeps whole rows
+        # alone) and the bytes one ring row takes over all such layers,
+        # for the decode events' ring rows and
+        # app_tpu_kv_window_live_bytes
+        self._ring_rows = said.get("window_rows", 0)
+        self._ring_row_bytes = said.get("window_bytes_per_slot", 0) \
+            // max(self._ring_rows, 1)
+        self._ring_live = 0
         # In-flight admission poll cadence (seconds). While a decode
         # block runs on device, the serving loop waits on the submit
         # event in slices of this length and admits new arrivals
@@ -1373,6 +1381,12 @@ class GenerationEngine:
             },
             **self._fam.serving_stats(self.cfg, self.n_slots),
         }
+        if self._ring_rows:
+            # rows the active slots hold: of the full layers (a layer),
+            # and of a window layer's rings at the last decode block
+            out["kv_live_rows"] = {
+                "full": int(self._cursors[self._active].sum()),
+                "window": self._ring_live}
         if self.tenancy is not None:
             out["scheduler"]["queued_by_tenant"] = \
                 self._pending.qsize_by_tenant()
@@ -4017,6 +4031,9 @@ class GenerationEngine:
         # kernel's block, or all that the slots reserve
         cursors = self._cursors[self._active]
         live = int(cursors.sum())
+        # of a window layer's rings: each cursor cut to the window
+        ring = int(np.minimum(cursors, self._ring_rows).sum()) \
+            if self._ring_rows else None
         bs = self._kv_block
         fetched = (None if self._paged
                    else int((-(-cursors // bs) * bs).sum()) if bs
@@ -4048,13 +4065,14 @@ class GenerationEngine:
         snap_reqs = [s.request for s in self._slots]
         return _Inflight((toks, lps, emitted), functools.partial(
             self._decode_reap, toks, lps, emitted, snap_active, snap_reqs,
-            t_dispatch, live, fetched, counters))
+            t_dispatch, live, fetched, counters, ring))
 
     # invoked through _Inflight.reap, always under the engine's device
     # lock (see _loop)  # gl: holds self._device_lock
     def _decode_reap(self, toks, lps, emitted, snap_active, snap_reqs,
                      t0: float = 0.0, live: int | None = None,
-                     fetched: int | None = None, counters=()) -> None:
+                     fetched: int | None = None, counters=(),
+                     ring: int | None = None) -> None:
         # one fetch: what the family's step counted rides with the tokens
         toks_np, lps_np, emit_np, counters = jax.device_get(
             (toks, lps, emitted, counters))
@@ -4074,7 +4092,13 @@ class GenerationEngine:
             self._tl.decode_block(
                 t0, time.monotonic(),
                 tuple(int(i) for i in np.flatnonzero(snap_active)),
-                self.decode_block, live, fetched, assigned, touched, states)
+                self.decode_block, live, fetched, assigned, touched, states,
+                ring)
+        if ring is not None:
+            self._ring_live = ring
+            if self.metrics is not None:
+                self.metrics.set_gauge("app_tpu_kv_window_live_bytes",
+                                       float(ring * self._ring_row_bytes))
         if states is not None and self.metrics is not None:
             self.metrics.set_gauge(
                 "app_tpu_state_live_bytes",

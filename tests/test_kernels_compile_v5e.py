@@ -1,6 +1,7 @@
 """The cache's two kernels, the delta rule's two (ops/kda.py) and, at the
-end, the llama family's decode block and prefill with the weights'
-reads in them, compiled for a TPU v5e that is described, not
+end, the llama family's decode block and prefill and the window
+family's decode block and chunk program with the weights' reads in them,
+compiled for a TPU v5e that is described, not
 attached (libtpu's compile-only topology; no chip time, nothing runs):
 what interpret mode cannot see, Mosaic refusing a slice that is not
 whole tiles or a kernel that needs too much VMEM. At Mistral-7B's widths
@@ -131,25 +132,22 @@ def test_kda_prefill_compiles(one_chip, tokens):
 
 # -- the llama family's programs: every projection reads its stack in place ----
 
-def _lowered(monkeypatch, sharding, program, layers):
-    """``EnginePrograms``' own decode block or 256-token prefill, lowered
-    from shapes alone (nothing is allocated): Mistral-7B's widths cut to
-    ``layers`` layers, int8 weights and cache, 40 slots x 2,048, both
-    kernels on (a CPU process answers no to ``tpu_backend_ok``)."""
-    from gofr_tpu.models import llama
-    from gofr_tpu.models.common import ModelConfig
+def _engine_lowered(monkeypatch, sharding, cfg, slots, kv_dtype, program):
+    """``EnginePrograms``' own decode block, 256-token prefill or
+    512-token chunk program for ``cfg``, lowered from shapes alone
+    (nothing is allocated): int8 weights, ``slots`` x 2,048, the kernels
+    on (a CPU process answers no to ``tpu_backend_ok``)."""
+    from gofr_tpu.models import family
     from gofr_tpu.ops import flash
     from gofr_tpu.tpu import programs
     from gofr_tpu.tpu.checkpoint import maybe_quantize
 
     monkeypatch.setattr(flash, "tpu_backend_ok", lambda: True)
-    cfg = ModelConfig(name="mistral-7b-cut", vocab_size=32768, dim=4096,
-                      n_layers=layers, n_heads=32, n_kv_heads=8,
-                      ffn_dim=14336, max_seq=SMAX, rope_theta=1e6)
+    fam = family(cfg)
     prog = programs.EnginePrograms(
-        cfg, llama, object(), max_seq=SMAX, kv_dtype=jnp.int8,
+        cfg, fam, object(), max_seq=SMAX, kv_dtype=kv_dtype,
         decode_block=4, n_adapters=0, spec_k=0, paged=None, mesh=None)
-    prog.describe("cache", B)
+    prog.describe("cache", slots)
     jits = prog.build()
 
     def arr(shape, dt=jnp.int32):
@@ -160,17 +158,34 @@ def _lowered(monkeypatch, sharding, program, layers):
                                       jax.eval_shape(build))
 
     params = described(lambda: maybe_quantize(
-        llama.init(cfg, jax.random.PRNGKey(0)), True))
-    cache = described(lambda: llama.init_cache(cfg, B, SMAX, dtype=jnp.int8))
+        fam.init(cfg, jax.random.PRNGKey(0)), True))
+    cache = described(lambda: fam.init_cache(cfg, slots, SMAX,
+                                             dtype=kv_dtype))
     key = arr((2,), jnp.uint32)
     if program == "decode block":
-        slots = arr((B,))
+        b = arr((slots,))
         return jits["_step_jit"].lower(
-            cache, params, arr((B, programs.PACK_EXTRA + programs.EOS_MAX)),
-            (slots, arr((B,), jnp.bool_), slots, slots), key)
+            cache, params,
+            arr((slots, programs.PACK_EXTRA + programs.EOS_MAX)),
+            (b, arr((slots,), jnp.bool_), b, b), key)
+    if program == "chunk 512":
+        return jits["_chunk_mid_jit"].lower(
+            cache, params, arr((1, 512)), arr(()), arr(()), arr(()), arr(()),
+            arr((), jnp.float32), arr(()), key, arr(()), arr(()), None)
     return jits["_prefill_jit"].lower(
         cache, params, arr((1, 256)), arr(()), arr(()),
         arr((), jnp.float32), arr(()), key, arr(()), arr(()))
+
+
+def _lowered(monkeypatch, sharding, program, layers):
+    """Mistral-7B's widths cut to ``layers`` layers, an int8 cache, 40
+    slots."""
+    from gofr_tpu.models.common import ModelConfig
+
+    cfg = ModelConfig(name="mistral-7b-cut", vocab_size=32768, dim=4096,
+                      n_layers=layers, n_heads=32, n_kv_heads=8,
+                      ffn_dim=14336, max_seq=SMAX, rope_theta=1e6)
+    return _engine_lowered(monkeypatch, sharding, cfg, B, jnp.int8, program)
 
 
 @pytest.mark.parametrize("program", ["decode block", "prefill 256"])
@@ -197,3 +212,68 @@ def test_qk_projections_read_their_weights_in_place(one_chip, monkeypatch,
     assert not stack_copies
     assert not staged
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+# -- the window family's programs at the published widths ----------------------
+
+def _lowered_window(monkeypatch, sharding, program):
+    """``benchmarks/configs/laguna-xs.2-int8-pp5.json`` as its cell runs
+    it: eight layers, bfloat16 rows and rings, 128 slots."""
+    import json
+    import os
+
+    from gofr_tpu.models.common import ModelConfig
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "laguna-xs.2-int8-pp5.json")) as f:
+        cfg = ModelConfig(**json.load(f)["model_config"])
+    return _engine_lowered(monkeypatch, sharding, cfg, 128, None, program)
+
+
+@pytest.mark.parametrize("program,kernels", [("decode block", 10),
+                                             ("chunk 512", 0)])
+def test_window_family_reads_weights_and_rings_in_place(one_chip, monkeypatch,
+                                                        program, kernels):
+    """The decode block runs the decode kernel eight times (six rings
+    with a group of 8 query heads a KV head, two full layers with a group
+    of 6) and the append twice (rows, rings); the 512-token chunk program
+    reads a ring before it overwrites all of it. Neither copies an int8
+    weight stack, stages a layer's slice of one in VMEM, or copies a ring
+    or the rows out of place (PERF.md, Findings PR 33 and section 7 item
+    9: the barrier is in ``laguna._attention`` from the start); and the
+    whole engine fits the chip."""
+    compiled = _lowered_window(monkeypatch, one_chip, program).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"^\s*%?[\w.\-]+ = .*custom_call_target="
+                          r"\"tpu_custom_call\"", text, re.M)) == kernels
+    if kernels:
+        assert len(re.findall(r"%flash_decode_ring[\w.]* = ", text)) == 6
+        assert len(re.findall(r"%flash_decode_stacked[\w.]* = ", text)) == 2
+        assert len(re.findall(r"%append_rows_stacked[\w.]* = ", text)) == 2
+    results = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = ((?:s8|bf16)\[[\d,]+\]\S*) ([\w\-]+)\(",
+        text, re.M)
+    assert results                      # the pattern still reads this HLO
+
+    def elements(shape):
+        n = 1
+        for d in shape.split("[")[1].split("]")[0].split(","):
+            n *= int(d)
+        return n
+
+    # an int8 stack of a kind (2 or 6 layers, a dense one, 7 x 256
+    # experts) moved as a whole, or a layer's slice of one staged
+    stack_copies = [r for r in results if r[1] in ("copy", "transpose")
+                    and r[0].startswith("s8[") and elements(r[0]) >= 1 << 22]
+    staged = [r for r in results if r[1] == "fusion"
+              and r[0].startswith("s8[1,2048,") and "S(1)" in r[0]]
+    # rings [6,128,8,512,128] and rows [2,128,8,2048,128], or a slot's
+    moved = [r for r in results if r[1] in ("copy", "transpose")
+             and re.match(r"bf16\[[26],(128|1),8,(512|2048),128\]", r[0])]
+    assert not stack_copies
+    assert not staged
+    assert not moved
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (512 << 20)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
