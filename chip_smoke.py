@@ -307,7 +307,57 @@ def kernel_cases(interpret: bool = False):
             return max(_max_err(o, want_o), _max_err(s1, want_s))
         return run
 
-    return [("kda_decode[f32,6x128x64x128x128]", kda_decode),
+    def experts(layers, held, dim, ffn):
+        def run():
+            # the routed experts' kernel at a cell's decode shapes (128
+            # slots x top-8 in blocks of 16 rows; the stacks whole, int8
+            # with a scale an output channel) against the jnp loop: all
+            # but two experts get a block, the buffer's tail is dead
+            from gofr_tpu.models import deepseek_v3 as ds
+            from gofr_tpu.models.common import ModelConfig
+            from gofr_tpu.ops import moe_experts
+            from gofr_tpu.ops.quant import QuantizedLinear
+
+            bm, rows = ds.expert_dispatch(ModelConfig(
+                dim=dim, moe_ffn_dim=ffn, n_experts=held,
+                experts_per_token=8), 128)
+
+            def stack(i, n_in, n_out):
+                # a layer at a time: the generator counts in 32 bits
+                w = jnp.stack([jax.lax.bitcast_convert_type(jax.random.bits(
+                    jax.random.fold_in(jax.random.PRNGKey(i), l),
+                    (held, n_in, n_out), jnp.uint8), jnp.int8)
+                    for l in range(layers)])
+                scale = jax.random.uniform(
+                    jax.random.PRNGKey(i + 1), (layers, held, n_out),
+                    jnp.float32, 0.5, 1.5) / (74.0 * n_in ** 0.5)
+                return QuantizedLinear(w, scale)
+
+            stacks = {"w_gate": stack(50, dim, ffn),
+                      "w_up": stack(52, dim, ffn),
+                      "w_down": stack(54, ffn, dim)}
+            live = held - 2
+            blk = jnp.minimum(jnp.arange(rows // bm, dtype=jnp.int32) + 1,
+                              held - 1)
+            xs = rand(56, (rows, dim))
+            li, n = jnp.int32(layers - 2), jnp.int32(live)
+            got = moe_experts.expert_blocks_stacked(
+                xs, blk, n, li, *(stacks[k].w for k in ds.EXPERT_STACKS),
+                *(stacks[k].scale for k in ds.EXPERT_STACKS),
+                block_rows=bm, interpret=interpret)
+            ref = jax.jit(ds._blocks_loop, static_argnums=5)(
+                xs, blk, n, stacks, li, bm)
+            dead = float(jnp.abs(got[live * bm:]).max())
+            return max(_max_err(got, ref), dead)
+        return run
+
+    return [("expert_blocks_stacked[int8,7x256x2048x512]",
+             experts(7, 256, 2048, 512)),
+            ("expert_blocks_stacked[int8,8x40x4096x1280]",
+             experts(8, 40, 4096, 1280)),
+            ("expert_blocks_stacked[int8,8x16x7168x2048]",
+             experts(8, 16, 7168, 2048)),
+            ("kda_decode[f32,6x128x64x128x128]", kda_decode),
             ("kda_prefill[f32,T=32]", kda_prefill(32)),
             ("kda_prefill[f32,T=512]", kda_prefill(512)),
             ("flash_decode_ring[bf16,6x128x8x512x128,H=64]", ring_decode(64)),

@@ -39,8 +39,9 @@ stream, RMSNorm before attention and before the feed-forward):
 
 Expert dispatch (``_experts``): the (token, held expert) assignments are
 sorted by expert into row blocks of ``block`` rows, each block one
-expert's, and a loop over the blocks THAT EXIST runs one SwiGLU a
-block, so FLOPs follow the assignments. No capacity, no token dropped,
+expert's, and the blocks THAT EXIST run one SwiGLU each (one kernel
+over them, ``ops/moe_experts.py``, or a loop where that cannot run), so
+FLOPs follow the assignments. No capacity, no token dropped,
 and a token's result does not depend on what else is in the batch: a
 block's rows are independent rows of one matmul.
 
@@ -56,7 +57,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import mla
+from ..ops import mla, moe_experts
+from ..ops.flash import interpret_env
 from ..ops.norms import rms_norm
 from ..ops.quant import QuantizedLinear, qmatmul
 from ..ops.rope import apply_rope, rope_frequencies, yarn_softmax_scale
@@ -258,15 +260,64 @@ def expert_dispatch(cfg: ModelConfig, tokens: int) -> tuple[int, int]:
     return bm, nb_max * bm
 
 
+def experts_on_kernel(cfg: ModelConfig, dtype=None) -> bool:
+    """Whether ``_experts`` runs its blocks through the kernel of
+    ``ops/moe_experts.py`` (chosen from backend, widths and the
+    activations' type) or through the jnp loop it is tested against."""
+    return moe_experts.kernel_ok(cfg.dim, cfg.moe_ffn_dim,
+                                 dtype or cfg.jdtype)
+
+
 def serving_stats(cfg: ModelConfig, slots: int) -> dict:
-    """What ``GenerationEngine.stats()`` says of this family's programs:
-    the decode step's expert dispatch shapes (the device operations that
-    tall are the routed experts': benchmarks/metrics reads them here)."""
+    """What ``GenerationEngine.stats()`` says of the programs of a family
+    that routes through ``moe_ffn``: the decode step's expert dispatch
+    shapes (the device operations that tall are the routed experts':
+    benchmarks/metrics reads them here) and the path its blocks take."""
     bm, rows = expert_dispatch(cfg, slots)
-    return {"moe_decode_dispatch": {"block_rows": bm, "buffer_rows": rows}}
+    return {"moe_decode_dispatch": {
+        "block_rows": bm, "buffer_rows": rows,
+        "path": "kernel" if experts_on_kernel(cfg) else "loop"}}
 
 
 EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def _blocks_loop(xs, blk_expert, n_blocks, stacks, li, bm: int):
+    """The dispatch buffer's live blocks through their experts, one loop
+    turn a block: xs [rows, D] -> [rows, D], zero past ``n_blocks``."""
+    def one(a, e):  # a [Ls, Eh, ...] -> a[li, e]
+        return jax.lax.dynamic_index_in_dim(
+            a.reshape((-1,) + a.shape[2:]), li * a.shape[1] + e, 0,
+            keepdims=False)
+
+    def at(leaf, e):
+        if isinstance(leaf, QuantizedLinear):
+            return QuantizedLinear(one(leaf.w, e), one(leaf.scale, e))
+        return one(leaf, e)
+
+    def body(j, out):
+        e = blk_expert[j]
+        x = jax.lax.dynamic_slice_in_dim(xs, j * bm, bm, axis=0)
+        y = _swiglu(x, at(stacks["w_gate"], e), at(stacks["w_up"], e),
+                    at(stacks["w_down"], e))
+        return jax.lax.dynamic_update_slice_in_dim(out, y, j * bm, axis=0)
+
+    return jax.lax.fori_loop(0, n_blocks, body, jnp.zeros_like(xs))
+
+
+def _blocks_kernel(xs, blk_expert, n_blocks, stacks, li, bm: int,
+                   tile: int | None = None):
+    """``_blocks_loop`` as one kernel over the blocks that exist (``tile``:
+    columns of the expert width a grid step takes, from the shapes
+    unless a test says)."""
+    leaves = [stacks[name] for name in EXPERT_STACKS]
+    scales = ()
+    if isinstance(leaves[0], QuantizedLinear):
+        scales = tuple(leaf.scale for leaf in leaves)
+        leaves = [leaf.w for leaf in leaves]
+    return moe_experts.expert_blocks_stacked(
+        xs, blk_expert, n_blocks, li, *leaves, *scales, block_rows=bm,
+        tile=tile, interpret=interpret_env())
 
 
 @jax.named_scope("moe/experts")
@@ -284,8 +335,10 @@ def _experts(hf, topi, w, stacks, li, cfg: ModelConfig, valid=None):
 
     The assignments are sorted by expert (absent experts and invalid
     rows last); expert e's rows start at a multiple of ``block`` in a
-    padded buffer, so every block of it is one expert's; a while loop
-    over the blocks that hold rows runs one SwiGLU each."""
+    padded buffer, so every block of it is one expert's; the blocks that
+    hold rows run one SwiGLU each, in one kernel
+    (``ops.moe_experts.expert_blocks_stacked``) or, where that cannot
+    run (``experts_on_kernel``), a while loop of the same arithmetic."""
     T, D = hf.shape
     K, Eh = topi.shape[1], n_held(cfg)
     bm, buf_rows = expert_dispatch(cfg, T)
@@ -316,25 +369,10 @@ def _experts(hf, topi, w, stacks, li, cfg: ModelConfig, valid=None):
     src = order[jnp.clip(start[e_p] + r_p, 0, N - 1)]      # flat index
     xs = jnp.where(live_p[:, None], hf[tok[src]], 0).astype(hf.dtype)
 
-    def one(a, e):  # a [Ls, Eh, ...] -> a[li, e]
-        return jax.lax.dynamic_index_in_dim(
-            a.reshape((-1,) + a.shape[2:]), li * a.shape[1] + e, 0,
-            keepdims=False)
-
-    def at(leaf, e):
-        if isinstance(leaf, QuantizedLinear):
-            return QuantizedLinear(one(leaf.w, e), one(leaf.scale, e))
-        return one(leaf, e)
-
-    def body(j, out):
-        e = blk_expert[j]
-        x = jax.lax.dynamic_slice_in_dim(xs, j * bm, bm, axis=0)
-        y = _swiglu(x, at(stacks["w_gate"], e), at(stacks["w_up"], e),
-                    at(stacks["w_down"], e))
-        return jax.lax.dynamic_update_slice_in_dim(out, y, j * bm, axis=0)
-
-    out = jax.lax.fori_loop(0, n_blocks, body,
-                            jnp.zeros((nb_max * bm, D), hf.dtype))
+    if experts_on_kernel(cfg, hf.dtype):
+        out = _blocks_kernel(xs, blk_expert, n_blocks, stacks, li, bm)
+    else:
+        out = _blocks_loop(xs, blk_expert, n_blocks, stacks, li, bm)
     # assignment (t, k) sits at its expert's buffer offset plus its rank
     # among that expert's sorted assignments
     e_flat = jnp.minimum(key, Eh - 1)

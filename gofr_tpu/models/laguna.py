@@ -54,10 +54,9 @@ from ..ops.flash import interpret_env
 from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
 from ..ops.rope import apply_rope_part
-from . import llama
+from . import deepseek_v3, llama
 from .common import ModelConfig, dense_init
-from .deepseek_v3 import (EXPERT_STACKS, dense_ffn, expert_dispatch, moe_ffn,
-                          n_held)
+from .deepseek_v3 import EXPERT_STACKS, dense_ffn, moe_ffn, n_held
 from .llama import _logits
 
 # a ring row that has been overwritten is gone: the chunk lattice runs
@@ -145,13 +144,12 @@ def _row_bytes(cfg: ModelConfig) -> int:
 
 def serving_stats(cfg: ModelConfig, slots: int) -> dict:
     """What ``GenerationEngine.stats()`` says of this family: the decode
-    step's expert dispatch shapes (as the latent family), the rows of a
-    ring and the bytes a slot's rings take whatever its length, and the
-    bytes a cached token takes in the full layers, in the model's type
-    (benchmarks/metrics reads them here)."""
+    step's expert dispatch shapes and path (the latent family's word),
+    the rows of a ring and the bytes a slot's rings take whatever its
+    length, and the bytes a cached token takes in the full layers, in
+    the model's type (benchmarks/metrics reads them here)."""
     n = counts(cfg)
-    bm, rows = expert_dispatch(cfg, slots)
-    return {"moe_decode_dispatch": {"block_rows": bm, "buffer_rows": rows},
+    return {**deepseek_v3.serving_stats(cfg, slots),
             "window_rows": cfg.window_size,
             "window_bytes_per_slot": n["window"] * cfg.window_size
             * _row_bytes(cfg),

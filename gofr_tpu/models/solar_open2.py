@@ -53,9 +53,9 @@ from ..ops.attention import (causal_attention, chunk_attention,
                              decode_attention_appended)
 from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
-from . import llama
+from . import deepseek_v3, llama
 from .common import ModelConfig, dense_init
-from .deepseek_v3 import (EXPERT_STACKS, expert_dispatch, moe_ffn, n_held)
+from .deepseek_v3 import EXPERT_STACKS, moe_ffn, n_held
 from .llama import _logits
 
 # a cached position of a linear layer cannot be computed again on top of
@@ -152,12 +152,11 @@ def state_bytes_per_slot(cfg: ModelConfig) -> int:
 
 def serving_stats(cfg: ModelConfig, slots: int) -> dict:
     """What ``GenerationEngine.stats()`` says of this family: the decode
-    step's expert dispatch shapes (as the latent family), the bytes a
-    slot's state takes and those a cached token takes in the model's
-    type (benchmarks/metrics reads them here)."""
+    step's expert dispatch shapes and path (the latent family's word),
+    the bytes a slot's state takes and those a cached token takes in
+    the model's type (benchmarks/metrics reads them here)."""
     P, nf, _ = counts(cfg)
-    bm, rows = expert_dispatch(cfg, slots)
-    return {"moe_decode_dispatch": {"block_rows": bm, "buffer_rows": rows},
+    return {**deepseek_v3.serving_stats(cfg, slots),
             "state_bytes_per_slot": state_bytes_per_slot(cfg),
             "kv_bytes_per_token": P * nf * 2 * cfg.n_kv_heads
             * cfg.head_dim * cfg.jdtype.itemsize}

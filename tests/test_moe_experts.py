@@ -1,0 +1,194 @@
+"""The routed experts' kernel (ops/moe_experts.py), interpreted on the
+CPU, against the jnp loop it replaces (deepseek_v3._blocks_loop) on the
+same inputs: the dispatch buffer's blocks directly, then the whole
+``_experts`` with the kernel switched on by GOFR_FLASH_INTERPRET."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from gofr_tpu.models import deepseek_v3 as ds
+from gofr_tpu.models.common import ModelConfig
+from gofr_tpu.ops import moe_experts
+from gofr_tpu.ops.quant import quantize_int8
+
+D, F, LS = 128, 256, 2
+
+
+def _stacks(n_held: int, quant: bool, dtype=jnp.float32, seed: int = 0):
+    """Expert stacks [LS, n_held, ...] as ``init`` lays them out, int8
+    with a scale an output channel or plain."""
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
+    shapes = {"w_gate": (LS, n_held, D, F), "w_up": (LS, n_held, D, F),
+              "w_down": (LS, n_held, F, D)}
+    out = {}
+    for key, (name, shape) in zip(k, shapes.items()):
+        w = jax.random.normal(key, shape, jnp.float32) * shape[2] ** -0.5
+        if quant:
+            out[name] = quantize_int8(w, axis=2)
+        else:
+            out[name] = w.astype(dtype)
+    return out
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """``deepseek_v3._blocks_kernel`` runs the kernel interpreted."""
+    monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
+
+
+# (blocks of the buffer, live blocks, each block's expert): an expert
+# with no block, one expert over several blocks (one fetch), nothing
+# live, every block live
+BUFFERS = {
+    "an_expert_without_a_block": (6, 4, [0, 0, 2, 3, 3, 3]),
+    "one_expert_over_every_block": (5, 5, [1, 1, 1, 1, 1]),
+    "no_block_live": (4, 0, [3, 3, 3, 3]),
+    "every_block_live": (4, 4, [0, 1, 2, 3]),
+}
+
+
+@pytest.mark.parametrize("tile", [None, 128], ids=["whole_F", "F_in_tiles"])
+@pytest.mark.parametrize("bm", [16, 64])
+@pytest.mark.parametrize("quant", [True, False], ids=["int8", "plain"])
+@pytest.mark.parametrize("buffer", list(BUFFERS))
+def test_blocks_through_the_kernel_equal_the_loop(buffer, quant, bm, tile,
+                                                  interpreted):
+    nb, live, experts = BUFFERS[buffer]
+    stacks = _stacks(4, quant)
+    xs = jax.random.normal(jax.random.PRNGKey(nb), (nb * bm, D))
+    blk = jnp.array(experts, jnp.int32)
+    n, li = jnp.int32(live), jnp.int32(1)
+    want = ds._blocks_loop(xs, blk, n, stacks, li, bm)
+    got = ds._blocks_kernel(xs, blk, n, stacks, li, bm, tile)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    assert not np.asarray(got[live * bm:]).any()      # dead blocks read 0
+    if live:
+        assert np.asarray(got[:live * bm]).any()
+
+
+def test_scales_come_in_groups_of_eight_experts(interpreted):
+    """16 held experts: a block's scale row is one of a fetched group."""
+    stacks = _stacks(16, True, seed=3)
+    bm, experts = 16, [0, 7, 8, 9, 15]
+    xs = jax.random.normal(jax.random.PRNGKey(1), (len(experts) * bm, D))
+    blk, n, li = jnp.array(experts, jnp.int32), jnp.int32(5), jnp.int32(0)
+    want = ds._blocks_loop(xs, blk, n, stacks, li, bm)
+    got = ds._blocks_kernel(xs, blk, n, stacks, li, bm, 128)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def test_bfloat16_rounds_no_coarser_than_the_loop(interpreted):
+    """bfloat16 activations, int8 weights: against the same arithmetic in
+    float32 the kernel is no further off than the loop (it rounds
+    silu(g) * u once where the loop rounds g, u and their product)."""
+    stacks = _stacks(4, True, seed=5)
+    bm, blk = 16, jnp.array([0, 1, 3], jnp.int32)
+    x32 = jax.random.normal(jax.random.PRNGKey(2), (3 * bm, D))
+    n, li = jnp.int32(3), jnp.int32(1)
+    exact = np.asarray(ds._blocks_loop(x32.astype(jnp.bfloat16).astype(
+        jnp.float32), blk, n, stacks, li, bm))
+    xs = x32.astype(jnp.bfloat16)
+    loop = np.asarray(ds._blocks_loop(xs, blk, n, stacks, li, bm),
+                      np.float32)
+    for tile in (None, 128):
+        got = ds._blocks_kernel(xs, blk, n, stacks, li, bm, tile)
+        assert got.dtype == jnp.bfloat16
+        err = np.abs(np.asarray(got, np.float32) - exact).max()
+        assert err <= np.abs(loop - exact).max() + 1e-3
+        assert err < 2 ** -7 * np.abs(exact).max()    # one bfloat16 step
+
+
+def test_tile_columns_fit_the_budget():
+    """The F tile from the shapes: a whole expert where two of it fit,
+    else the largest divisor of F in whole lanes that does."""
+    assert moe_experts.tile_columns(2048, 512, 1) == 512      # laguna
+    assert moe_experts.tile_columns(4096, 1280, 1) == 640     # solar
+    assert moe_experts.tile_columns(7168, 2048, 1) == 256     # gigachat
+    assert moe_experts.tile_columns(7168, 2048, 2) == 128     # bfloat16
+    for dim, ffn, size in ((2048, 512, 1), (4096, 1280, 1),
+                           (7168, 2048, 1)):
+        t = moe_experts.tile_columns(dim, ffn, size)
+        assert ffn % t == 0 and t % 128 == 0
+        assert 6 * dim * t * size <= moe_experts._TILE_BUDGET
+
+
+@pytest.mark.parametrize("dim,ffn,dtype,want", [
+    (2048, 512, jnp.bfloat16, True),         # laguna: 3.1M weights
+    (4096, 1280, jnp.bfloat16, True),        # solar: 15.7M
+    (7168, 2048, jnp.bfloat16, False),       # gigachat: 44M, the loop's
+    (2048, 512, jnp.float32, False),         # a float32 model
+    (2048, 520, jnp.bfloat16, False),        # not whole lanes
+])
+def test_path_is_chosen_from_backend_and_shapes(dim, ffn, dtype, want,
+                                                monkeypatch):
+    from gofr_tpu.ops import flash
+
+    monkeypatch.delenv("GOFR_FLASH_INTERPRET", raising=False)
+    assert not moe_experts.kernel_ok(dim, ffn, dtype)     # a CPU process
+    monkeypatch.setattr(flash, "tpu_backend_ok", lambda: True)
+    assert moe_experts.kernel_ok(dim, ffn, dtype) is want
+
+
+# -- the whole expert layer, kernel against loop -----------------------------
+
+CFG = ModelConfig(
+    vocab_size=64, dim=D, n_layers=3, n_heads=2, n_kv_heads=2, ffn_dim=64,
+    max_seq=64, dtype="float32", n_experts=16, experts_per_token=4,
+    n_expert_groups=4, topk_groups=2, moe_ffn_dim=F, n_experts_held=4,
+    n_dense_layers=1, kv_lora_rank=16, q_lora_rank=16, qk_nope_head_dim=8,
+    qk_rope_head_dim=8, v_head_dim=8, n_shared_experts=1,
+    routed_scaling=2.5)
+
+# (tokens, the k experts every token chooses or None for the router's
+# choice, tokens that are valid or None for all)
+LAYERS = {
+    "an_expert_with_no_token": (40, [0, 1, 3, 8], None),
+    "every_token_on_one_expert": (40, [2, 8, 9, 12], None),
+    "every_choice_unheld": (40, [8, 9, 12, 13], None),
+    "invalid_rows": (40, None, 10),
+    "no_valid_row": (24, None, 0),
+    "routed_with_unheld_experts": (40, None, None),
+    "a_prompt_in_blocks_of_64": (160, None, 150),
+}
+
+
+@pytest.mark.parametrize("quant", [True, False], ids=["int8", "plain"])
+@pytest.mark.parametrize("layer", list(LAYERS))
+def test_expert_layer_on_the_kernel_equals_the_loop(layer, quant,
+                                                    monkeypatch):
+    T, chosen, n_valid = LAYERS[layer]
+    stacks = _stacks(4, quant, seed=7)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(T))
+    h = jax.random.normal(k1, (T, D))
+    if chosen is None:
+        router = jax.random.normal(k2, (D, CFG.n_experts)) * 0.3
+        topi, w = ds.route(h, router, jnp.zeros((CFG.n_experts,)), CFG)
+    else:
+        topi = jnp.tile(jnp.array([chosen], jnp.int32), (T, 1))
+        w = jnp.full((T, 4), 0.625)
+    valid = None if n_valid is None else jnp.arange(T) < n_valid
+    li = jnp.int32(1)
+    assert ds.expert_dispatch(CFG, T)[0] == (16 if T <= 128 else 64)
+
+    monkeypatch.delenv("GOFR_FLASH_INTERPRET", raising=False)
+    assert not ds.experts_on_kernel(CFG)
+    assert ds.serving_stats(CFG, 8)["moe_decode_dispatch"]["path"] == "loop"
+    want, counts, blocks = ds._experts(h, topi, w, stacks, li, CFG, valid)
+    monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
+    assert ds.experts_on_kernel(CFG)
+    assert ds.serving_stats(CFG, 8)["moe_decode_dispatch"]["path"] \
+        == "kernel"
+    got, counts_k, blocks_k = ds._experts(h, topi, w, stacks, li, CFG, valid)
+
+    assert counts_k.tolist() == counts.tolist()
+    assert int(blocks_k) == int(blocks)
+    np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5)
+    if layer in ("every_choice_unheld", "no_valid_row"):
+        assert int(blocks) == 0 and not np.asarray(got).any()
+    else:
+        assert np.asarray(got).any()
+    if valid is not None:
+        assert not np.asarray(got[n_valid:]).any()
