@@ -15,7 +15,10 @@ The same module owns the one compile listener: ``clock()`` counts the
 seconds JAX spends in backend compiles (persistent-cache loads
 included), the programs, and the cache's hits and misses, and marks
 each compile on the serving timeline so "which step recompiled" is a
-mark on the host loop's track.
+mark on the host loop's track. The mark, and each miss, carry what was
+being built: ``label``, which the start-up account (observe/startup.py)
+sets to its phase or to the program and shape a warm-up is calling, and
+the name JAX gives the jitted function.
 """
 
 from __future__ import annotations
@@ -46,10 +49,17 @@ def configure() -> str:
     return _CHECKOUT_DIR
 
 
+SERVING = "serving"  # the label outside start-up and warm-up
+
+
 class CompileClock:
     """Totals since the process's first ``clock()`` call. ``log`` keeps
     (monotonic time, seconds) of every compile; ``timeline`` is the
-    serving timeline the marks go to (the engine attaches its own)."""
+    serving timeline the marks go to (the engine attaches its own).
+    ``missed`` names every compile that missed the persistent cache, as
+    "<label> <jitted function>"; it stops growing at ``MISSED_MAX``."""
+
+    MISSED_MAX = 4096
 
     def __init__(self):
         self.seconds = 0.0
@@ -58,24 +68,36 @@ class CompileClock:
         self.misses = 0
         self.log: list[tuple[float, float]] = []
         self.timeline = None
+        self.label = SERVING
+        self.missed: list[str] = []
+        # JAX reports a miss inside the compile it belongs to, on the
+        # compiling thread, before that compile's duration
+        self._miss = threading.local()
         jax.monitoring.register_event_duration_secs_listener(self._duration)
         jax.monitoring.register_event_listener(self._event)
 
-    def _duration(self, event: str, seconds: float, **_) -> None:
+    def _duration(self, event: str, seconds: float, fun_name: str = "",
+                  **_) -> None:
         if event != "/jax/core/compile/backend_compile_duration":
             return
         self.seconds += seconds
         self.programs += 1
         self.log.append((time.monotonic(), seconds))
+        what = f"{self.label} {fun_name}".rstrip()
+        if getattr(self._miss, "pending", False):
+            self._miss.pending = False
+            if len(self.missed) < self.MISSED_MAX:
+                self.missed.append(what)
         tl = self.timeline
         if tl is not None:
-            tl.compile(seconds)
+            tl.compile(seconds, what)
 
     def _event(self, event: str, **_) -> None:
         if event == "/jax/compilation_cache/cache_hits":
             self.hits += 1
         elif event == "/jax/compilation_cache/cache_misses":
             self.misses += 1
+            self._miss.pending = True
 
     def snapshot(self) -> dict:
         return {"seconds": self.seconds, "programs": self.programs,

@@ -628,6 +628,16 @@ def register_framework_metrics(m: Manager) -> None:
     m.new_gauge("app_tpu_pipeline_depth",
                 "fused decode blocks in flight on the device stream "
                 "after the last pipeline top-up")
+    m.new_gauge("app_tpu_startup_seconds",
+                "seconds the engine's start-up spent in each phase "
+                "(configure / weights / allocate / programs / warmup), "
+                "set when the engine is ready and again when its first "
+                "warm-up ends (observe/startup.py)")
+    m.new_gauge("app_tpu_startup_cache_misses",
+                "programs that missed the persistent compile cache "
+                "from the start of the engine's set-up to the end of "
+                "its first warm-up; stats()['startup']['missed'] names "
+                "them")
     m.new_histogram("app_tpu_request_segment_duration",
                     "per-request critical-path segment time in seconds, "
                     "by segment (queue_wait / prefill / handoff / "

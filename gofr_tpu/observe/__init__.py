@@ -14,6 +14,7 @@ from .clock import ClockRegistry, PeerClock
 from .profiler import collect_profile, render_collapsed, sample_once
 from .recorder import FlightRecorder
 from .registry import InflightRequest, RequestRegistry
+from .startup import StartupAccount
 from .timeline import Timeline, _enabled_from_env, timeline_from_config
 
 __all__ = [
@@ -23,6 +24,7 @@ __all__ = [
     "InflightRequest",
     "PeerClock",
     "RequestRegistry",
+    "StartupAccount",
     "Timeline",
     "collect_profile",
     "render_collapsed",
@@ -33,8 +35,8 @@ __all__ = [
 
 class Observe:
     """The container's observability bundle: request registry + flight
-    recorder + serving timeline + the tracer the serving stack emits
-    stage spans through. Always constructed (the recorder and timeline
+    recorder + serving timeline + start-up account + the tracer the
+    serving stack emits stage spans through. Always constructed (the recorder and timeline
     are bounded rings and the registry is O(active requests)) —
     observability is not opt-in."""
 
@@ -55,3 +57,7 @@ class Observe:
         # container wiring, which passes timeline_from_config(config)
         self.timeline = timeline if timeline is not None else Timeline(
             enabled=_enabled_from_env())
+        # the engine's account of its start-up (startup.py): one a
+        # process, written by the config wiring, the engines' buffers and
+        # programs and their warm-ups
+        self.startup = StartupAccount(self.timeline, metrics, tracer)

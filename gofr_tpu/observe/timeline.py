@@ -27,15 +27,17 @@ vLLM/SGLang-class schedulers became debuggable with:
     / deliver / park / other), ``gap`` intervals in which it knew the
     device dry, one ``store`` per prefix-cache store, one ``first`` per
     stream when its first message reaches the socket, and a ``compile``
-    mark per backend compile (``compile_cache.py``).
+    mark per backend compile (``compile_cache.py``). Before all of that
+    the engine accounts for its start-up (``startup.py``): one
+    ``startup`` interval a phase and a warm-up call.
   - ``chrome_trace()`` renders the ring as Chrome-trace JSON ("JSON
     Array Format" with ``traceEvents``) that Perfetto / chrome://tracing
     load directly: one track per decode slot, a scheduler track for
     instant decisions, a device-stream track of dry intervals, a host
-    loop track of the generation thread's phases, a predict track per
-    program, and one counter track per HBM subsystem. ``/debug/timeline?last_ms=N`` serves it
-    from the metrics port; ``tools/timeline_dump.py`` fetches or
-    self-hosts it.
+    loop track of the generation thread's phases, a start-up track, a
+    predict track per program, and one counter track per HBM
+    subsystem. ``/debug/timeline?last_ms=N`` serves it from the metrics
+    port; ``tools/timeline_dump.py`` fetches or self-hosts it.
 
 Event tuple layout (fixed 8-slot, index-stable for the exporter):
 
@@ -60,6 +62,7 @@ _TID_SCHED = 1          # admission / shed / expiry decisions
 _TID_DEVICE = 2         # device-stream dispatch gaps (idle windows)
 _TID_LOOP = 3           # the generation thread's phases (+ compile marks)
 _TID_TRANSPORT = 4      # first message of each stream reaching the socket
+_TID_STARTUP = 5        # the start-up account's phases and warm-up calls
 _TID_SLOT0 = 10         # decode slot i -> tid 10 + i
 _TID_PREDICT0 = 1000    # predict program tracks, assigned in export order
 
@@ -204,14 +207,18 @@ class Timeline:
         ``write1``."""
         self.append("first", send[3], None, request_id, trace_id, path, send)
 
-    def compile(self, seconds: float) -> None:
-        """One backend compile (or persistent-cache load) just ended."""
-        self.append("compile", time.monotonic(), None, round(seconds, 6))
+    def compile(self, seconds: float, what: str = "") -> None:
+        """One backend compile (or persistent-cache load) just ended;
+        ``what``: the clock's label and the jitted function's name."""
+        self.append("compile", time.monotonic(), None, round(seconds, 6),
+                    what)
 
-    def pipeline_depth(self, depth: int) -> None:
-        """Counter sample: fused decode blocks in flight after a
-        pipeline top-up (the Perfetto twin of app_tpu_pipeline_depth)."""
-        self.append("depth", time.monotonic(), None, depth)
+    def startup(self, t0: float, t1: float, phase: str,
+                detail: str = "") -> None:
+        """One interval of the start-up account (observe/startup.py): a
+        phase, or inside ``warmup`` one program call (``detail`` its
+        program and shape; for an ``allocate`` the buffer's tag)."""
+        self.append("startup", t0, t1 - t0, phase, detail)
 
     def admit(self, slot: int, slo_class: str, wait_s: float,
               request_id, trace_id: str = "") -> None:
@@ -300,6 +307,10 @@ class Timeline:
              "name": "thread_name", "args": {"name": "transport"}},
             {"ph": "M", "pid": 1, "tid": _TID_TRANSPORT,
              "name": "thread_sort_index", "args": {"sort_index": 3}},
+            {"ph": "M", "pid": 1, "tid": _TID_STARTUP, "name": "thread_name",
+             "args": {"name": "start-up"}},
+            {"ph": "M", "pid": 1, "tid": _TID_STARTUP,
+             "name": "thread_sort_index", "args": {"sort_index": 4}},
         ]
         named_slots: set[int] = set()
         predict_tids: dict[str, int] = {}
@@ -416,10 +427,12 @@ class Timeline:
                 body.append({"ph": "i", "s": "t", "pid": 1,
                              "tid": _TID_LOOP, "name": f"compile {a:.3f}s",
                              "cat": "compile", "ts": us,
-                             "args": {"seconds": a, "seq": seq}})
-            elif kind == "depth":
-                body.append({"ph": "C", "pid": 1, "name": "pipeline_depth",
-                             "ts": us, "args": {"depth": a}})
+                             "args": {"seconds": a, "what": b, "seq": seq}})
+            elif kind == "startup":
+                body.append({"ph": "X", "pid": 1, "tid": _TID_STARTUP,
+                             "name": f"{a} {b}".rstrip(), "cat": "startup",
+                             "ts": us, "dur": max(dur, 0.0) * 1e6,
+                             "args": {"phase": a, "detail": b, "seq": seq}})
             elif kind == "hbm":
                 body.append({"ph": "C", "pid": 1, "name": f"hbm:{a}",
                              "ts": us, "args": {"bytes": b}})

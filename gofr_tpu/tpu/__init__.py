@@ -300,8 +300,14 @@ def new_engine_from_config(cfg, logger=None, metrics=None,
                            observe=None) -> TPUEngine:
     from .. import compile_cache
     from ..models import BERT_CONFIGS, LLAMA_CONFIGS, VIT_CONFIGS
+    from ..observe.startup import StartupAccount
 
     compile_cache.configure()
+    # the account of this start-up (observe/startup.py): from here to the
+    # ready line every second belongs to a phase. It is the container's
+    # (``observe.startup``), which the engines take the same way
+    startup = observe.startup if observe is not None else StartupAccount()
+    startup.phase("configure")
 
     if (cfg.get("TPU_SERVING_ROLE") or "").strip().lower() == "gateway":
         # the gateway role (gofr_tpu/gateway) is an APP mode, not an
@@ -352,9 +358,18 @@ def new_engine_from_config(cfg, logger=None, metrics=None,
     quant = (cfg.get("TPU_QUANT") or "").lower() == "int8"
 
     def params_for(model_cfg, init_fn):
-        if weights:
-            return placed(maybe_quantize(load_params(weights), quant), mesh)
-        return random_params(init_fn, model_cfg, quant=quant, mesh=mesh)
+        with startup.within("weights") as acct:
+            if weights:
+                params = placed(maybe_quantize(load_params(weights), quant),
+                                mesh)
+            else:
+                params = random_params(init_fn, model_cfg, quant=quant,
+                                       mesh=mesh)
+            # dispatched, not waited for: the host's next second overlaps
+            # the device's last leaf, as before the account
+            leaves = jax.tree_util.tree_leaves(params)
+            acct.note(leaves=len(leaves), bytes=hbm.tree_nbytes(leaves))
+        return params
 
     if name.startswith("bert"):
         from ..models import bert
@@ -481,9 +496,13 @@ def new_engine_from_config(cfg, logger=None, metrics=None,
 
     if cfg.get_bool("TPU_WARMUP"):
         engine.warmup()
+    took = startup.finish()
     if logger is not None:
+        # seconds from the entry of this function, the three longest
+        # phases, and the programs that missed the persistent cache
         logger.info({"event": "tpu engine ready", "model": name,
                      "platform": engine.platform, "devices": len(engine.devices),
                      "quant": "int8" if quant else "none",
-                     "sharding": cfg.get("TPU_SHARDING") or "single"})
+                     "sharding": cfg.get("TPU_SHARDING") or "single",
+                     **took})
     return engine

@@ -34,6 +34,7 @@ import numpy as np
 
 from ..datasource import Health, STATUS_DEGRADED, STATUS_DOWN, STATUS_UP
 from ..errors import DeadlineExceeded, ProgramNotFound, ServiceUnavailable
+from ..observe.startup import StartupAccount
 from ..resilience import current_deadline, current_slo_class
 from . import hbm
 from .batcher import ClassPolicy, CoalescingBatcher, pad_bucket
@@ -93,6 +94,10 @@ class TPUEngine:
         tl = getattr(observe, "timeline", None) if observe is not None \
             else None
         self._tl = tl if (tl is not None and tl.enabled) else None
+        # the account of this engine's start-up (observe/startup.py):
+        # the container's, which the generator is handed the same way
+        self.startup = observe.startup if observe is not None \
+            else StartupAccount()
         # resilience.AdmissionGate TEMPLATE (None = admit everything):
         # each program gets its own clone (one gate per queue — a shared
         # wait EWMA would let a backlogged program shed a healthy one's
@@ -148,7 +153,7 @@ class TPUEngine:
                        batch_buckets=tuple(batch_buckets),
                        seq_buckets=tuple(seq_buckets),
                        example_item=example_item)
-        with self._lock:
+        with self.startup.within("programs", program=name), self._lock:
             self._programs[name] = prog
             if self.gate is not None:
                 self._gates[name] = self.gate.clone(name)
@@ -409,31 +414,45 @@ class TPUEngine:
         return self.generator.generate(*args, **kw)
 
     # -- warmup (compile-cache priming; BASELINE TTFT target needs this) -----
+    def _warm_shapes(self, prog: Program) -> list[tuple]:
+        if prog.kind == "tokens":
+            return [(Bb, Sb) for Bb in prog.batch_buckets
+                    for Sb in prog.seq_buckets]
+        if prog.example_item is not None:
+            return [(Bb,) for Bb in prog.batch_buckets]
+        if self.logger is not None:
+            self.logger.warn({"event": "tpu warmup skipped",
+                              "program": prog.name,
+                              "reason": "fixed-kind program registered "
+                                        "without example_item"})
+        return []
+
     def warmup(self, program: str | None = None) -> None:
+        """Compile every bucket of the batcher programs, then the
+        generator's: one record a call in the start-up account."""
         names = [program] if program else list(self._programs)
-        for name in names:
-            prog = self._programs[name]
-            if prog.kind == "tokens":
-                for Bb in prog.batch_buckets:
-                    for Sb in prog.seq_buckets:
-                        toks = jnp.zeros((Bb, Sb), jnp.int32)
-                        lens = jnp.full((Bb,), Sb, jnp.int32)
-                        jax.block_until_ready(prog._jitted(prog.params, toks, lens))
-                        self._note_shape(prog, (Bb, Sb))
-            elif prog.example_item is not None:
-                for Bb in prog.batch_buckets:
-                    batch = jax.tree.map(
-                        lambda a: jnp.broadcast_to(jnp.asarray(a)[None], (Bb,) + np.shape(a)),
-                        prog.example_item)
-                    jax.block_until_ready(prog._jitted(prog.params, batch))
-                    self._note_shape(prog, (Bb,))
-            elif self.logger is not None:
-                self.logger.warn({"event": "tpu warmup skipped",
-                                  "program": name,
-                                  "reason": "fixed-kind program registered "
-                                            "without example_item"})
-        if self.generator is not None:
-            self.generator.warmup()
+        plan = [(self._programs[n], shape) for n in names
+                for shape in self._warm_shapes(self._programs[n])]
+        with self.startup.warming() as acct:
+            acct.expect(len(plan))
+            for prog, shape in plan:
+                with acct.call(prog.name, shape):
+                    if prog.kind == "tokens":
+                        Bb, Sb = shape
+                        args = (jnp.zeros((Bb, Sb), jnp.int32),
+                                jnp.full((Bb,), Sb, jnp.int32))
+                    else:
+                        args = (jax.tree.map(
+                            lambda a: jnp.broadcast_to(
+                                jnp.asarray(a)[None], shape + np.shape(a)),
+                            prog.example_item),)
+                    jax.block_until_ready(prog._jitted(prog.params, *args))
+                self._note_shape(prog, shape)
+            if self.generator is not None:
+                self.generator.warmup()
+
+    def stats(self) -> dict:
+        return {"startup": self.startup.stats()}
 
     # -- health (reference container/health.go:5-25 shape) -------------------
     def health_check(self) -> Health:
@@ -482,6 +501,12 @@ class TPUEngine:
                     details["hbm_arbiter"][k] = arb[k]
         if self.generator is not None:
             details["generator"] = self.generator.stats()
+            # the whole account is /debug/vars'; health says how far
+            details["generator"].pop("startup", None)
+        starting = self.startup.progress()
+        if starting is not None:
+            # until ready: which phase, and how far the warm-up is
+            details["startup"] = starting
         if self.tenancy is not None:
             details["tenancy"] = self.tenancy.stats()
         if self.serving_role != "fused":

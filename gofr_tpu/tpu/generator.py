@@ -43,6 +43,7 @@ from .. import chaos, compile_cache
 from ..errors import DeadlineExceeded, UnsupportedOptions
 from ..models import family, llama
 from ..models.common import ModelConfig
+from ..observe.startup import StartupAccount
 from ..resilience import (SLO_LATENCY, SLO_THROUGHPUT, DecodePipelinePolicy,
                           current_deadline, current_slo_class)
 from ..tenancy.fair import WeightedFairLine
@@ -677,6 +678,10 @@ class GenerationEngine:
             compile_cache.clock().timeline = self._tl
         # the loop thread's account of its own time and of the stream
         self._acct = _LoopAccount(self._tl, metrics)
+        # the account of start-up (observe/startup.py): the container's,
+        # as the timeline is; buffers, programs and warm-ups write to it
+        self._startup = observe.startup if observe is not None \
+            else StartupAccount()
         self.mesh = mesh
         self.down: str | None = None  # set when the device loop is bricked
         self._replacements = 0  # warm mesh re-placements survived
@@ -722,7 +727,8 @@ class GenerationEngine:
             cfg, self._fam, self, max_seq=self.max_seq, kv_dtype=kv_dtype,
             decode_block=self.decode_block, n_adapters=self._n_adapters,
             spec_k=max(0, int(spec_decode_k)), mesh=mesh,
-            paged=(paged_blocks, self._block_t) if self._paged else None)
+            paged=(paged_blocks, self._block_t) if self._paged else None,
+            startup=self._startup)
         self._prog.describe(
             "cache", slots,
             self._hbm_paged_reclaim if self._paged else None)
@@ -836,16 +842,19 @@ class GenerationEngine:
                                   self._pool.quantized,
                                   np.dtype(str(self._pool[0].dtype)),
                                   self.max_seq)
-                self._kvc = CacheManager(
-                    prefix_cache_slots, layout, block=opts.block,
-                    host_bytes=opts.host_mb << 20, redis=opts.redis,
-                    redis_ttl_s=opts.redis_ttl_s,
-                    epoch_refresh_s=opts.epoch_refresh_s,
-                    fingerprint=model_fingerprint(
-                        cfg, params,
-                        extra=str(layout.np_dtype) + self._mesh_extra()),
-                    metrics=metrics, logger=logger,
-                    shards=self._kv_shards)
+                # (a part of start-up with a name of its own: the
+                # fingerprint reads samples of the weights on the device)
+                with self._startup.within("configure", tag="kvcache"):
+                    self._kvc = CacheManager(
+                        prefix_cache_slots, layout, block=opts.block,
+                        host_bytes=opts.host_mb << 20, redis=opts.redis,
+                        redis_ttl_s=opts.redis_ttl_s,
+                        epoch_refresh_s=opts.epoch_refresh_s,
+                        fingerprint=model_fingerprint(
+                            cfg, params,
+                            extra=str(layout.np_dtype) + self._mesh_extra()),
+                        metrics=metrics, logger=logger,
+                        shards=self._kv_shards)
                 self._store_min = int(prefix_store_min
                                       or self.prompt_buckets[-1])
         if (self._kvc is None and kvcache is not None
@@ -1380,6 +1389,8 @@ class GenerationEngine:
                 "pipeline": self._pipeline_stats(),
             },
             **self._fam.serving_stats(self.cfg, self.n_slots),
+            # phases, warm-up records, cache misses (observe/startup.py)
+            "startup": self._startup.stats(),
         }
         if self._ring_rows:
             # rows the active slots hold: of the full layers (a layer),
@@ -1479,127 +1490,174 @@ class GenerationEngine:
         go into a FREE slot only (they overwrite that slot's KV), and the
         cursor snapshot restores the lengths afterwards. With every slot
         busy the prefill warmup is skipped — an all-busy engine has those
-        shapes compiled already or will compile them on admission."""
+        shapes compiled already or will compile them on admission.
+
+        Each call is a record of the start-up account (its seconds, its
+        compiles, the cache's hits and misses, the memory peak after it),
+        under a ``warmup`` phase of its own when called again later."""
         with self._device_lock:
-            cursors = np.asarray(jax.device_get(self.cache.lengths))
             free = next((i for i, s in enumerate(self._slots) if s.free), None)
-            if free is not None:
-                # chunk programs run for prompts past the chunk budget
-                # (the largest bucket unless TPU_PREFILL_CHUNK bounds
-                # it) — and, with a prefix pool, for ANY hit (prefill
-                # resumes mid-prompt through the chunk lattice), so
-                # they must be warm whenever the pool exists. They write
-                # the serving cache's free slot, or a paged engine's
-                # scratch row
-                C = self._chunk
-                i32, zero = jnp.int32, jnp.int32(0)
-                if self._paged:
-                    row, slot = "_scratch", zero
-                    chunked = hasattr(self, "_scratch")
-                else:
-                    row, slot = "cache", i32(free)
-                    chunked = self.max_seq - 1 > C or self._kvc is not None
+            with self._startup.warming() as acct:
+                plan = self._warm_plan(free)
+                acct.expect(len(plan))
+                cursors = np.asarray(jax.device_get(self.cache.lengths))
+                for attr, shape, run in plan:
+                    with acct.call(attr, shape):
+                        run()
+                # restore cursors dirtied by the dummy dispatches
+                self.cache = self.cache._replace(
+                    lengths=jnp.asarray(cursors))
 
-                def tail():
-                    # (temp, top_k, key, seed, pos, adapter): the key as
-                    # the last call left it, as serving passes it
-                    return (jnp.float32(0.0), zero, self._key, zero, zero,
-                            self._adapter1(None))
+    def _warm_plan(self, free: int | None) -> list[tuple]:
+        """What a warm-up calls, in order: (the engine attribute of the
+        ``programs.TABLE`` row, the shape that keys the compile, the
+        call, which blocks until its result is ready). Each call rebinds
+        the buffer its program donates."""
+        plan: list[tuple] = []
+        i32, zero = jnp.int32, jnp.int32(0)
 
-                for b in self.prompt_buckets:
-                    if b > C:
-                        # single-dispatch prefills and final chunks are
-                        # both bounded by the chunk budget — wider
-                        # buckets never dispatch
-                        continue
-                    toks = jnp.zeros((1, b), i32)
-                    # paged: dummy KV lands in the trash block (blocks
-                    # all 0); the cursor restore below undoes lengths
-                    blocks = (jnp.zeros((-(-b // self._block_t),), i32),) \
-                        if self._paged else ()
-                    _, _, self._key, self.cache = jax.block_until_ready(
-                        self._prefill_jit(
-                            self.cache, self.params, toks, i32(1), *blocks,
-                            i32(free), *tail()))
-                    if chunked:
-                        # chunked-admission lattice: the final chunk
-                        # compiles per bucket, mid chunks only at C
-                        _, _, self._key, buf = jax.block_until_ready(
-                            self._chunk_final_jit(
-                                getattr(self, row), self.params, toks, zero,
-                                slot, i32(1), zero, *tail()))
-                        setattr(self, row, buf)
+        def tail():
+            # (temp, top_k, key, seed, pos, adapter): the key as the last
+            # call left it, as serving passes it
+            return (jnp.float32(0.0), zero, self._key, zero, zero,
+                    self._adapter1(None))
+
+        if free is not None:
+            # chunk programs run for prompts past the chunk budget (the
+            # largest bucket unless TPU_PREFILL_CHUNK bounds it) — and,
+            # with a prefix pool, for ANY hit (prefill resumes mid-prompt
+            # through the chunk lattice), so they must be warm whenever
+            # the pool exists. They write the serving cache's free slot,
+            # or a paged engine's scratch row
+            C = self._chunk
+            if self._paged:
+                row, slot = "_scratch", zero
+                chunked = hasattr(self, "_scratch")
+            else:
+                row, slot = "cache", i32(free)
+                chunked = self.max_seq - 1 > C or self._kvc is not None
+
+            def prefill(b):
+                # paged: dummy KV lands in the trash block (blocks all
+                # 0); the cursor restore undoes lengths
+                blocks = (jnp.zeros((-(-b // self._block_t),), i32),) \
+                    if self._paged else ()
+                _, _, self._key, self.cache = jax.block_until_ready(
+                    self._prefill_jit(
+                        self.cache, self.params, jnp.zeros((1, b), i32),
+                        i32(1), *blocks, i32(free), *tail()))
+
+            def chunk_final(b):
+                _, _, self._key, buf = jax.block_until_ready(
+                    self._chunk_final_jit(
+                        getattr(self, row), self.params,
+                        jnp.zeros((1, b), i32), zero, slot, i32(1), zero,
+                        *tail()))
+                setattr(self, row, buf)
+
+            def chunk_mid():
+                setattr(self, row, jax.block_until_ready(
+                    self._chunk_mid_jit(
+                        getattr(self, row), self.params,
+                        jnp.zeros((1, C), i32), zero, slot, zero, zero,
+                        *tail())))
+
+            def row_to_blocks():
+                self.cache = jax.block_until_ready(self._row_to_blocks_jit(
+                    self.cache, self._scratch, jnp.zeros((self._mb,), i32)))
+
+            def blocks_to_row():
+                # prefix-hit restore program (trash-block gather)
+                self._scratch = jax.block_until_ready(
+                    self._blocks_to_row_jit(
+                        self._scratch, self.cache,
+                        jnp.zeros((self._mb,), i32)))
+
+            for b in self.prompt_buckets:
+                if b > C:
+                    # single-dispatch prefills and final chunks are both
+                    # bounded by the chunk budget — wider buckets never
+                    # dispatch
+                    continue
+                plan.append(("_prefill_jit", (1, b), functools.partial(prefill, b)))
                 if chunked:
-                    setattr(self, row, jax.block_until_ready(
-                        self._chunk_mid_jit(
-                            getattr(self, row), self.params,
-                            jnp.zeros((1, C), i32), zero, slot, zero, zero,
-                            *tail())))
-                if chunked and self._paged:
-                    self.cache = jax.block_until_ready(
-                        self._row_to_blocks_jit(
-                            self.cache, self._scratch,
-                            jnp.zeros((self._mb,), i32)))
-                    # prefix-hit restore program (trash-block gather)
-                    self._scratch = jax.block_until_ready(
-                        self._blocks_to_row_jit(
-                            self._scratch, self.cache,
-                            jnp.zeros((self._mb,), i32)))
-            elif self.logger is not None:
-                self.logger.debug({"event": "generator warmup skipped prefill",
-                                   "reason": "no free slot"})
-            if self._host_write_jit is not None:
-                # warm the T1/T2 promote program with an IDENTITY
-                # rewrite of pool row 0 (a zero-filled dummy would
-                # corrupt a live entry's stored KV); mesh snapshots
-                # assemble dense first, like the promote path
-                kv = dense_hostkv(self._kv_row_get(self._pool, 0,
-                                                   self.max_seq))
-                quant = self._pool.quantized
-                self._pool = jax.block_until_ready(self._host_write_jit(
-                    self._pool, jnp.asarray(kv.k[:, None]),
-                    jnp.asarray(kv.v[:, None]),
-                    jnp.asarray(kv.k_scale[:, None]) if quant else None,
-                    jnp.asarray(kv.v_scale[:, None]) if quant else None,
-                    jnp.int32(0)))
-            # All-inactive warm pack (host_wins set, active clear, EOS
-            # padded, paged table ZEROED — not the live one: an active
-            # slot whose cursor sits at an unallocated block boundary
-            # would have its clamped row redirect the dummy write INTO
-            # its last live block; with zeros every garbage write lands
-            # in the trash block). Two calls: the first covers the
-            # host-built carry signature (first live block,
-            # _last_dev=None); the second feeds the returned carry +
-            # chained key back — the STEADY-STATE signature, whose
-            # inputs are jit-output-committed (mesh: rep-sharded).
-            # Warming only one would re-lower the big fused scan
-            # mid-serving.
-            warm_pack = self._warm_pack()
-            _, _, _, carry_w, self._key, self.cache, _ = \
+                    # chunked-admission lattice: the final chunk compiles
+                    # per bucket, mid chunks only at C
+                    plan.append(("_chunk_final_jit", (1, b),
+                                 functools.partial(chunk_final, b)))
+            if chunked:
+                plan.append(("_chunk_mid_jit", (1, C), chunk_mid))
+            if chunked and self._paged:
+                plan.append(("_row_to_blocks_jit", (self._mb,),
+                             row_to_blocks))
+                plan.append(("_blocks_to_row_jit", (self._mb,),
+                             blocks_to_row))
+        elif self.logger is not None:
+            self.logger.debug({"event": "generator warmup skipped prefill",
+                               "reason": "no free slot"})
+
+        def host_write():
+            # an IDENTITY rewrite of pool row 0 (a zero-filled dummy
+            # would corrupt a live entry's stored KV); mesh snapshots
+            # assemble dense first, like the promote path
+            kv = dense_hostkv(self._kv_row_get(self._pool, 0, self.max_seq))
+            quant = self._pool.quantized
+            self._pool = jax.block_until_ready(self._host_write_jit(
+                self._pool, jnp.asarray(kv.k[:, None]),
+                jnp.asarray(kv.v[:, None]),
+                jnp.asarray(kv.k_scale[:, None]) if quant else None,
+                jnp.asarray(kv.v_scale[:, None]) if quant else None,
+                jnp.int32(0)))
+
+        if self._host_write_jit is not None:
+            # the T1/T2 promote program
+            plan.append(("_host_write_jit", (self.max_seq,), host_write))
+
+        # All-inactive warm pack (host_wins set, active clear, EOS padded,
+        # paged table ZEROED — not the live one: an active slot whose
+        # cursor sits at an unallocated block boundary would have its
+        # clamped row redirect the dummy write INTO its last live block;
+        # with zeros every garbage write lands in the trash block). Two
+        # calls: the first covers the host-built carry signature (first
+        # live block, _last_dev=None); the second feeds the returned
+        # carry + chained key back — the STEADY-STATE signature, whose
+        # inputs are jit-output-committed (mesh: rep-sharded). Warming
+        # only one would re-lower the big fused scan mid-serving.
+        warm_pack, carry = self._warm_pack(), {}
+
+        def step(first: bool):
+            _, _, _, carry["dev"], self._key, self.cache, _ = \
                 jax.block_until_ready(self._step_jit(
                     self.cache, self.params, warm_pack,
-                    self._host_carry(), self._key))
-            _, _, _, _, self._key, self.cache, _ = jax.block_until_ready(
-                self._step_jit(self.cache, self.params, warm_pack,
-                               carry_w, self._key))
-            if self._spec_k:
-                # the verify program too — its first real tick would
-                # otherwise compile mid-serving under the device lock,
-                # freezing every live stream. All-inactive dispatch:
-                # emit 0, cursors frozen, garbage KV lands beyond
-                # cursors (paged: in the trash block via a zeroed table)
-                # like the step warmup's.
-                window = jnp.zeros((self.n_slots, self._spec_k + 1),
-                                   jnp.int32)
-                table = (jnp.zeros_like(jnp.asarray(self._table)),) \
-                    if self._paged else ()
-                _, _, _, cache_w = self._verify_jit(
-                    self.cache, self.params, window,
-                    jnp.zeros((self.n_slots,), bool), self._key, *table,
-                    self._adapters())
-                self.cache = jax.block_until_ready(cache_w)
-            # restore cursors dirtied by the dummy dispatches
-            self.cache = self.cache._replace(lengths=jnp.asarray(cursors))
+                    self._host_carry() if first else carry["dev"],
+                    self._key))
+
+        shape = (self.n_slots, self.decode_block)
+        plan.append(("_step_jit", (*shape, "host carry"),
+                     functools.partial(step, True)))
+        plan.append(("_step_jit", (*shape, "device carry"),
+                     functools.partial(step, False)))
+
+        def verify():
+            # All-inactive dispatch: emit 0, cursors frozen, garbage KV
+            # lands beyond cursors (paged: in the trash block via a
+            # zeroed table) like the step warmup's
+            window = jnp.zeros((self.n_slots, self._spec_k + 1), jnp.int32)
+            table = (jnp.zeros_like(jnp.asarray(self._table)),) \
+                if self._paged else ()
+            _, _, _, cache_w = self._verify_jit(
+                self.cache, self.params, window,
+                jnp.zeros((self.n_slots,), bool), self._key, *table,
+                self._adapters())
+            self.cache = jax.block_until_ready(cache_w)
+
+        if self._spec_k:
+            # the verify program too — its first real tick would
+            # otherwise compile mid-serving under the device lock,
+            # freezing every live stream
+            plan.append(("_verify_jit", (self.n_slots, self._spec_k + 1),
+                         verify))
+        return plan
 
     def kvcache_stats(self) -> dict | None:
         """Tiered prefix-cache stats for /debug/cache; None when no
@@ -3869,8 +3927,6 @@ class GenerationEngine:
         if self.metrics is not None:
             self.metrics.set_gauge("app_tpu_pipeline_depth", float(depth),
                                    program="generate")
-        if self._tl is not None:
-            self._tl.pipeline_depth(depth)
 
     def _tick(self, decode_only: bool = False) -> "_Inflight | None":
         """Dispatch one serving tick: a speculative verify pass when the

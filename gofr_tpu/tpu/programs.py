@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import llama
+from ..observe.startup import StartupAccount
 from . import hbm
 
 # top-k truncation width: per-request k is traced (no recompiles);
@@ -279,10 +280,13 @@ class EnginePrograms:
 
     def __init__(self, cfg, fam, owner, *, max_seq: int, kv_dtype,
                  decode_block: int, n_adapters: int, spec_k: int,
-                 paged: "tuple[int, int] | None", mesh):
+                 paged: "tuple[int, int] | None", mesh, startup=None):
         self.cfg = cfg
         self._fam = fam
         self._owner = owner  # of the leases: close() releases by it
+        # the start-up account (observe/startup.py): every allocation
+        # and every build is a phase of it
+        self._startup = startup if startup is not None else StartupAccount()
         self.max_seq = max_seq
         self._kv_dtype = kv_dtype
         self.decode_block = decode_block
@@ -348,13 +352,17 @@ class EnginePrograms:
             return jax.block_until_ready(_born_sharded(
                 lambda: buf.build(buf.rows), sharding))
 
-        if not lease:
-            return hbm.account(buf.group, build(), owner=self._owner,
-                               tag=tag)
-        return hbm.alloc_sharded(
-            buf.group, build, owner=self._owner, tag=tag,
-            priority=buf.priority, reclaim=buf.reclaim,
-            devices=self.placed.labels)
+        with self._startup.within("allocate", tag=tag, rows=buf.rows) as acct:
+            if not lease:
+                made = hbm.account(buf.group, build(), owner=self._owner,
+                                   tag=tag)
+            else:
+                made = hbm.alloc_sharded(
+                    buf.group, build, owner=self._owner, tag=tag,
+                    priority=buf.priority, reclaim=buf.reclaim,
+                    devices=self.placed.labels)
+            acct.note(bytes=hbm.tree_nbytes(made))
+            return made
 
     def key(self, seed: int):
         """The chained PRNG key. A mesh engine commits it to the
@@ -393,14 +401,17 @@ class EnginePrograms:
         layout = _P if self.paged else _C
         sharded = self.mesh is not None
         out = {}
-        for p in TABLE:
-            if p.layout not in (None, layout) or p.needs not in have \
-                    or (needs is not None and p.needs not in needs):
-                continue
-            fn = self._traced(p.mesh_fn if sharded and p.mesh_fn else p.fn)
-            out[p.attr] = jax.jit(
-                fn, donate_argnums=p.donate,
-                out_shardings=self._out(p.out) if sharded else None)
+        with self._startup.within("programs") as acct:
+            for p in TABLE:
+                if p.layout not in (None, layout) or p.needs not in have \
+                        or (needs is not None and p.needs not in needs):
+                    continue
+                fn = self._traced(
+                    p.mesh_fn if sharded and p.mesh_fn else p.fn)
+                out[p.attr] = jax.jit(
+                    fn, donate_argnums=p.donate,
+                    out_shardings=self._out(p.out) if sharded else None)
+            acct.note(programs=len(out))
         return out
 
     def _traced(self, name: str):

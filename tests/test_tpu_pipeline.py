@@ -594,19 +594,16 @@ def test_overlapped_reap_reader_matches_the_programs_count(tiny_params):
 
 # -- observability ------------------------------------------------------------
 
-def test_timeline_gap_and_depth_tracks_export():
+def test_timeline_gap_track_exports():
     from gofr_tpu.observe.timeline import Timeline
 
     tl = Timeline(capacity=64)
     t = time.monotonic()
     tl.dispatch_gap(t, t + 0.004)
-    tl.pipeline_depth(2)
     events = tl.chrome_trace()["traceEvents"]
     gap = next(e for e in events if e.get("name") == "dispatch gap")
     assert gap["ph"] == "X" and gap["tid"] == 2
     assert abs(gap["dur"] - 4000.0) < 100.0
-    depth = next(e for e in events if e.get("name") == "pipeline_depth")
-    assert depth["ph"] == "C" and depth["args"]["depth"] == 2
     # the device-stream track is named in the metadata header
     assert any(e.get("name") == "thread_name" and e.get("tid") == 2
                and e["args"]["name"] == "device stream" for e in events)
