@@ -417,6 +417,17 @@ def _logits(params, cfg: ModelConfig, x):
     return qmatmul(x, params["lm_head"]).astype(jnp.float32)
 
 
+def logits_dtype(cfg: ModelConfig):
+    """The type ``_logits``' float32 values are exact in, for every
+    family (all project through ``_logits``): the activations' own,
+    which ``qmatmul`` returns and the cast only widens; float32 where
+    tied embeddings give a float32 dot. A consumer that must hand the
+    logits across a program boundary (a ``cond``'s branch) narrows them
+    to it, and XLA drops the pair of casts instead of writing a float32
+    copy of the array."""
+    return jnp.dtype(jnp.float32) if cfg.tie_embeddings else cfg.jdtype
+
+
 def _causal_scan(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                  lengths: jnp.ndarray | None, rope_max: int, rope_tables,
                  constrain, collect_kv: bool, flash: bool = False,

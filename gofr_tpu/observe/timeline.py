@@ -128,7 +128,8 @@ class Timeline:
                      assigned: int | None = None,
                      touched: int | None = None,
                      states: int | None = None,
-                     ring: int | None = None) -> None:
+                     ring: int | None = None,
+                     sampled: int | None = None) -> None:
         """One fused decode dispatch->reap: ``slots`` is the tuple of
         active slot indices as dispatched, ``steps`` the block size,
         ``live`` the KV positions those slots held at dispatch (what
@@ -144,10 +145,14 @@ class Timeline:
         block's steps updated in place (each read and written once);
         and where it has window layers, ``ring``: the rows of ONE window
         layer's rings those slots held at dispatch (each cursor cut to
-        the window, beside ``live``, which the full layers read). A
-        field keeps its place: states without an expert layer come after
-        two Nones, ring rows without states after a None."""
-        tail = [assigned, touched, states, ring]
+        the window, beside ``live``, which the full layers read); and
+        last, on a block with a slot that draws, ``sampled``: what it
+        asked of the sampler (bit 0 a slot draws, bit 1 one draws from
+        its top-k: generator._sampling_flag; an all-greedy block says
+        nothing). A field keeps its place: states without an expert
+        layer come after two Nones, ring rows without states after a
+        None."""
+        tail = [assigned, touched, states, ring, sampled]
         while tail and tail[-1] is None:
             tail.pop()
         if 0 < len(tail) < 2:
@@ -355,7 +360,8 @@ class Timeline:
                                           **{k: v for k, v in zip(
                                               ("moe_assigned", "moe_touched",
                                                "states_updated",
-                                               "ring_rows"), more)
+                                               "ring_rows", "sampled"),
+                                              more)
                                              if v is not None}}})
             elif kind == "prefill":
                 body.append({"ph": "X", "pid": 1, "tid": slot_tid(a),

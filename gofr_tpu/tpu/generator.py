@@ -537,6 +537,10 @@ class GenerationEngine:
         # its assignments): assignments made, (step, layer, expert) cells
         # that got one, cells in all
         self._moe_assigned = self._moe_touched = self._moe_cells = 0
+        # the sampler's account (_sampling_flag): decode blocks
+        # dispatched, those with an active slot that draws, those with
+        # one that draws from its top-k
+        self._sample_blocks = self._sample_drawn = self._sample_topk = 0
         # bytes of recurrent state a slot holds whatever its length (0:
         # the family keeps rows alone), for app_tpu_state_live_bytes
         said = self._fam.serving_stats(cfg, slots)
@@ -1389,6 +1393,11 @@ class GenerationEngine:
                 "pipeline": self._pipeline_stats(),
             },
             **self._fam.serving_stats(self.cfg, self.n_slots),
+            # which of the sampler's branches the decode blocks asked
+            # for (_sampling_flag)
+            "sampling": {"blocks": self._sample_blocks,
+                         "drawn_blocks": self._sample_drawn,
+                         "topk_blocks": self._sample_topk},
             # phases, warm-up records, cache misses (observe/startup.py)
             "startup": self._startup.stats(),
         }
@@ -1783,6 +1792,24 @@ class GenerationEngine:
                 jnp.asarray(np.array(self._active)),
                 jnp.asarray(np.array(self._budgets)),
                 jnp.asarray(np.array(self._pos_abs)))
+
+    def _sampling_flag(self) -> int:
+        """What the block about to be dispatched asks of the sampler
+        (programs._sample), counted into ``stats()["sampling"]``: bit 0
+        an active slot has ``temperature > 0`` (the step takes the drawn
+        branch), bit 1 such a slot also has ``top_k > 0`` (the top-64
+        runs). The host's own arrays, no device read. The device's
+        predicate follows the carried ``active`` mask: a slot that stops
+        inside a block already dispatched still counts here for the
+        block behind it, where the device skips it."""
+        drawing = self._active & (self._temps > 0)
+        flag = 0
+        if drawing.any():
+            flag = 3 if (self._top_ks[drawing] > 0).any() else 1
+        self._sample_blocks += 1
+        self._sample_drawn += flag & 1
+        self._sample_topk += flag >> 1
+        return flag
 
     def _dispatch_pack(self):
         """The decode dispatch's ONE host input: every host-owned
@@ -4095,6 +4122,7 @@ class GenerationEngine:
                    else int((-(-cursors // bs) * bs).sum()) if bs
                    else self.n_slots * self.max_seq)
         t_dispatch = time.monotonic()
+        sampled = self._sampling_flag()
         pack = self._dispatch_pack()
         toks, lps, emitted, self._last_dev, self._key, self.cache, \
             counters = self._run(self._step_jit, self.cache, self.params,
@@ -4121,14 +4149,15 @@ class GenerationEngine:
         snap_reqs = [s.request for s in self._slots]
         return _Inflight((toks, lps, emitted), functools.partial(
             self._decode_reap, toks, lps, emitted, snap_active, snap_reqs,
-            t_dispatch, live, fetched, counters, ring))
+            t_dispatch, live, fetched, counters, ring, sampled))
 
     # invoked through _Inflight.reap, always under the engine's device
     # lock (see _loop)  # gl: holds self._device_lock
     def _decode_reap(self, toks, lps, emitted, snap_active, snap_reqs,
                      t0: float = 0.0, live: int | None = None,
                      fetched: int | None = None, counters=(),
-                     ring: int | None = None) -> None:
+                     ring: int | None = None,
+                     sampled: int | None = None) -> None:
         # one fetch: what the family's step counted rides with the tokens
         toks_np, lps_np, emit_np, counters = jax.device_get(
             (toks, lps, emitted, counters))
@@ -4149,7 +4178,7 @@ class GenerationEngine:
                 t0, time.monotonic(),
                 tuple(int(i) for i in np.flatnonzero(snap_active)),
                 self.decode_block, live, fetched, assigned, touched, states,
-                ring)
+                ring, sampled or None)
         if ring is not None:
             self._ring_live = ring
             if self.metrics is not None:

@@ -441,26 +441,58 @@ class EnginePrograms:
         )(seeds, pos)
 
     @jax.named_scope("sampling")
-    def _sample(self, logits, temps, keys, top_ks):
-        """Greedy where temp==0; categorical(logits/temp) otherwise,
-        truncated to the request's top-k logits when top_k > 0 — all
-        fused per-slot so mixed-sampling batches stay one program.
-        ``keys`` [B, ...]: one PRNG key per slot, derived by the caller
-        from (request seed, absolute position) — see _resume_keys."""
-        V = logits.shape[-1]
-        safe_t = jnp.maximum(temps, 1e-6)[:, None]
-        scaled = logits / safe_t
-        sampled = jax.vmap(jax.random.categorical)(keys, scaled)
-        kmax = min(self.TOP_K_MAX, V)
-        vals, idx = jax.lax.top_k(scaled, kmax)          # [B, kmax]
-        kk = jnp.minimum(jnp.where(top_ks > 0, top_ks, kmax), kmax)
-        vals = jnp.where(jnp.arange(kmax)[None, :] < kk[:, None],
-                         vals, -jnp.inf)
-        in_k = jax.vmap(jax.random.categorical)(keys, vals)
-        topk_tok = jnp.take_along_axis(idx, in_k[:, None], axis=1)[:, 0]
-        sampled = jnp.where(top_ks > 0, topk_tok, sampled)
-        greedy = jnp.argmax(logits, axis=-1)
-        tok = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+    def _sample(self, logits, temps, seeds, pos, top_ks, active=None):
+        """What the batch's settings ask for, decided on the device.
+
+        Every slot gets ``argmax(logits)`` and the chosen token's
+        logprob; that is all an all-greedy batch computes. The draws sit
+        under ``lax.cond``: with a slot that is ``active`` (None: all)
+        and has ``temperature > 0``, the keys (``_resume_keys`` of
+        ``seeds`` and ``pos``) and then, each only where such a slot asks
+        for it, categorical(logits/temp) over the whole vocabulary
+        (``top_k == 0``) and over the request's top-k of ``TOP_K_MAX``
+        logits (``top_k > 0``). A mixed batch stays one program, and a
+        slot's token is the same whichever slots sit beside it. A slot
+        that retired with its temperature still in the pack does not
+        keep a branch alive (its token is never emitted). The host's
+        count of the blocks that drew is ``stats()["sampling"]``."""
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        drawing = temps > 0 if active is None else (temps > 0) & active
+        by_k = top_ks > 0
+        # the branches take the logits as the head computed them
+        # (llama.logits_dtype): a float32 operand of a conditional is
+        # an array of its own, [B, V] written every step for nobody
+        narrow = logits.astype(llama.logits_dtype(self.cfg))
+
+        def draw():
+            keys = self._resume_keys(seeds, pos)
+            safe_t = jnp.maximum(temps, 1e-6)[:, None]
+
+            # narrow / safe_t (float32) inside each branch: fused into
+            # the draw that reads it, no array of its own
+            def whole():
+                return jax.vmap(jax.random.categorical)(
+                    keys, narrow / safe_t).astype(jnp.int32)
+
+            def truncated():
+                kmax = min(self.TOP_K_MAX, logits.shape[-1])
+                vals, idx = jax.lax.top_k(narrow / safe_t, kmax)  # [B, kmax]
+                kk = jnp.minimum(jnp.where(by_k, top_ks, kmax), kmax)
+                vals = jnp.where(jnp.arange(kmax)[None, :] < kk[:, None],
+                                 vals, -jnp.inf)
+                in_k = jax.vmap(jax.random.categorical)(keys, vals)
+                return jnp.take_along_axis(
+                    idx, in_k[:, None], axis=1)[:, 0].astype(jnp.int32)
+
+            sampled = jnp.where(
+                by_k,
+                jax.lax.cond(jnp.any(drawing & by_k), truncated,
+                             lambda: greedy),
+                jax.lax.cond(jnp.any(drawing & ~by_k), whole,
+                             lambda: greedy))
+            return jnp.where(temps > 0, sampled, greedy)
+
+        tok = jax.lax.cond(jnp.any(drawing), draw, lambda: greedy)
         # logprob of the chosen token under the MODEL's (untempered)
         # distribution — the number OpenAI-style logprobs report
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
@@ -489,9 +521,8 @@ class EnginePrograms:
         cache = self._fam.write_kv(
             cache, *kv, (0, slot) + (0,) * (cache[0].ndim - 2), lengths)
         last = logits[0, 0]  # [V] at the true prompt end (logit_pos)
-        tok, lp = self._sample(last[None, :], temp[None],
-                               self._resume_keys(seed[None], pos[None]),
-                               top_k[None])
+        tok, lp = self._sample(last[None, :], temp[None], seed[None],
+                               pos[None], top_k[None])
         return tok[0], lp[0], key, cache
 
     def _chunk_fn(self, cache, params, tokens, start, slot, total_len,
@@ -527,9 +558,8 @@ class EnginePrograms:
                 lengths=cache.lengths.at[slot].set(self.max_seq))
         lengths = cache.lengths.at[slot].set(total_len)
         last = logits[0, 0]  # [V] at pos_in_chunk (logit_pos)
-        tok, lp = self._sample(last[None, :], temp[None],
-                               self._resume_keys(seed[None], pos[None]),
-                               top_k[None])
+        tok, lp = self._sample(last[None, :], temp[None], seed[None],
+                               pos[None], top_k[None])
         return tok[0], lp[0], key, written._replace(lengths=lengths)
 
     # methods, not functools.partial: jit names a program after its
@@ -608,9 +638,8 @@ class EnginePrograms:
             logits, stepped, *counters = step_model(tokens, cache, active)
             lengths = jnp.where(active, stepped.lengths, cache.lengths)
             stepped = stepped._replace(lengths=lengths)
-            toks, lps = self._sample(logits, temps,
-                                     self._resume_keys(seeds, pos),
-                                     top_ks)
+            toks, lps = self._sample(logits, temps, seeds, pos, top_ks,
+                                     active)
             toks = jnp.where(active, toks, tokens)
             emitted = active
             budget = jnp.where(active, budget - 1, budget)
@@ -674,9 +703,8 @@ class EnginePrograms:
         cache = paged_llama.write_prompt_blocks(cache, k, v, blocks, length)
         cache = cache._replace(lengths=cache.lengths.at[slot].set(length))
         last = logits[0, 0]  # [V] at the true prompt end (logit_pos)
-        tok, lp = self._sample(last[None, :], temp[None],
-                               self._resume_keys(seed[None], pos[None]),
-                               top_k[None])
+        tok, lp = self._sample(last[None, :], temp[None], seed[None],
+                               pos[None], top_k[None])
         return tok[0], lp[0], key, cache
 
     def _paged_verify_fn(self, cache, params, window, active, key, table,

@@ -288,3 +288,73 @@ def test_window_family_reads_weights_and_rings_in_place(one_chip, monkeypatch,
     if program == "decode block":       # the loop's 156.6 MB, to 3 digits
         assert mem.temp_size_in_bytes < 0.1575e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+
+
+# -- the sampler's branches in the compiled decode block -----------------------
+
+def _outside_conditionals(text):
+    """The lines of a compiled module's computations that run whatever a
+    ``conditional`` decides (the entry and what it reaches by a loop's
+    body, a fusion's or a call's computation, but not through a
+    conditional's ``branch_computations``), those among them that are
+    instructions of their own (the entry's and the loops' bodies', not
+    what is fused into one), and the lines of every other computation."""
+    comps, cur, entry = {}, None, None
+    for line in text.splitlines():
+        if line[:1].strip() and line.rstrip().endswith("{") and "(" in line:
+            cur = line.split()[1 if line.startswith("ENTRY") else 0]
+            cur = cur.lstrip("%")
+            comps[cur] = []
+            entry = cur if line.startswith("ENTRY") else entry
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+    loops = re.compile(r"(?:body|condition)=%?([\w.\-]+)")
+    fused = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
+
+    def reach(edges):
+        seen, stack = set(), [entry]
+        while stack:
+            c = stack.pop()
+            if c not in seen:
+                seen.add(c)
+                stack += [n for ln in comps[c] for e in edges
+                          for n in e.findall(ln)]
+        return seen
+
+    free, own = reach((loops, fused)), reach((loops,))
+    return ([ln for c in free for ln in comps[c]],
+            [ln for c in own for ln in comps[c]],
+            [ln for c in comps if c not in free for ln in comps[c]])
+
+
+def test_window_family_draws_only_inside_a_conditional(one_chip, monkeypatch):
+    """``programs._sample``'s branches, read off Laguna's compiled decode
+    block: XLA kept the ``conditional``s (no ``select`` over both sides),
+    the vocabulary-wide top-k and every threefry round of the draws and
+    of their keys are in branch computations, the entry and the scan's
+    body outside them hold none of either, and the branches get the
+    head's bfloat16 logits, not a float32 copy written every step. The
+    program's temporaries are no larger than they were with the draws
+    unconditional (157,064,192 B: PERF.md, Findings PR 39)."""
+    compiled = _lowered_window(monkeypatch, one_chip, "decode block").compile()
+    text = compiled.as_text()
+    outside, own, inside = _outside_conditionals(text)
+    assert len(re.findall(r" conditional\(", text)) == 3
+    draws = re.compile(
+        r'op_name="[^"]*/sampling/[^"]*(top_k|threefry|_gumbel|_uniform|'
+        r'random_bits|fold_in)')
+    assert sum(bool(draws.search(ln)) for ln in inside) > 100
+    assert not [ln[:160] for ln in outside if draws.search(ln)]
+    # the router sorts 256 scores a token outside; nothing vocabulary-wide
+    # is sorted or drawn there, and the one float32 [slots, vocabulary]
+    # array is log_softmax's
+    wide = [ln for ln in own if re.match(
+        r"\s*(ROOT )?%?[\w.\-]+ = \(?[a-z0-9]+\[128,100352\]", ln)]
+    assert any("lm_head" in ln for ln in wide)
+    assert not [ln[:160] for ln in wide
+                if re.search(r" (sort|custom-call|rng[\w\-]*)\(", ln)]
+    f32 = [ln for ln in wide if " = f32[" in ln or " = (f32[" in ln]
+    assert all("log_softmax" in ln for ln in f32), [ln[:200] for ln in f32]
+    assert compiled.memory_analysis().temp_size_in_bytes <= 157_064_192
