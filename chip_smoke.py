@@ -224,6 +224,55 @@ def kernel_cases(interpret: bool = False):
             return _max_err(got, ref)
         return run
 
+    def decode_pairs():
+        def run():
+            # heads of 64 values, two KV heads a cache row (five full
+            # layers x 96 slots x 4 pairs x 2,048 x 128, 32 query heads
+            # each with zeros in the other head's half) against the jnp
+            # form on the heads as they are, 64 wide: lengths at block
+            # and tile edges, dead slots between
+            lf, slots, kv, d, cap = 5, 96, 8, 64, 2048
+            some = [0, 1, 255, 256, 257, 511, 512, 1000, 1500, 2046, 0, 700]
+            lengths = jnp.asarray(some * (slots // len(some)), jnp.int32)
+            q, kn, vn = (rand(60, (slots, 1, 32, d)),
+                         rand(61, (slots, 1, kv, d)),
+                         rand(62, (slots, 1, kv, d)))
+            kc, vc = (rand(63, (lf, slots, kv // 2, cap, 2 * d)),
+                      rand(64, (lf, slots, kv // 2, cap, 2 * d)))
+            got = attention.unpair_heads(flash_decode.flash_decode_stacked(
+                attention.pair_queries(q, kv), kc, vc,
+                attention.pair_rows(kn), attention.pair_rows(vn), lengths,
+                jnp.int32(3), block_s=flash_decode.block_size(cap),
+                interpret=interpret, scale=d ** -0.5), kv)
+
+            def heads(rows):   # [B, KV/2, S, 2 d] -> [B, KV, S, d]
+                r = rows.reshape(slots, kv // 2, cap, 2, d)
+                return jnp.moveaxis(r, 3, 2).reshape(slots, kv, cap, d)
+
+            ref = attention.decode_attention_appended(
+                q, heads(kc[3]), heads(vc[3]), kn, vn, lengths)
+            return _max_err(got, ref)
+        return run
+
+    def append_pairs():
+        def run():
+            # the step's write on paired rows: each 64-wide head's row in
+            # its half, every byte of both caches against XLA's scatter
+            lf, slots, kv, d, cap = 5, 8, 8, 64, 2048
+            pos = jnp.asarray([0, 1, 127, 128, 129, 500, cap - 1, cap],
+                              jnp.int32)
+            kc, vc = (rand(65, (lf, slots, kv // 2, cap, 2 * d)),
+                      rand(66, (lf, slots, kv // 2, cap, 2 * d)))
+            kr, vr = (attention.pair_rows(rand(i, (lf, slots, kv, d)))
+                      for i in (67, 68))
+            got = flash_decode.append_rows_stacked(kc, vc, kr, vr, pos,
+                                                   interpret=interpret)
+            at = jnp.arange(slots)
+            return max(_max_err(g, c.at[:, at, :, pos].set(
+                jnp.moveaxis(r, 1, 0), mode="drop"))
+                for g, c, r in zip(got, (kc, vc), (kr, vr)))
+        return run
+
     def ring_decode(heads):
         def run():
             # the decode kernel over stacked RINGS at the published sizes
@@ -307,12 +356,13 @@ def kernel_cases(interpret: bool = False):
             return max(_max_err(o, want_o), _max_err(s1, want_s))
         return run
 
-    def experts(layers, held, dim, ffn):
+    def experts(layers, held, dim, ffn, top_k=8, slots=128):
         def run():
             # the routed experts' kernel at a cell's decode shapes (128
-            # slots x top-8 in blocks of 16 rows; the stacks whole, int8
-            # with a scale an output channel) against the jnp loop: all
-            # but two experts get a block, the buffer's tail is dead
+            # slots x top-8 in blocks of 16 rows, or 96 x top-4 where an
+            # expert is two tiles of the kernel wide; the stacks whole,
+            # int8 with a scale an output channel) against the jnp loop:
+            # all but two experts get a block, the buffer's tail is dead
             from gofr_tpu.models import deepseek_v3 as ds
             from gofr_tpu.models.common import ModelConfig
             from gofr_tpu.ops import moe_experts
@@ -320,7 +370,7 @@ def kernel_cases(interpret: bool = False):
 
             bm, rows = ds.expert_dispatch(ModelConfig(
                 dim=dim, moe_ffn_dim=ffn, n_experts=held,
-                experts_per_token=8), 128)
+                experts_per_token=top_k), slots)
 
             def stack(i, n_in, n_out):
                 # a layer at a time: the generator counts in 32 bits
@@ -357,6 +407,11 @@ def kernel_cases(interpret: bool = False):
              experts(8, 40, 4096, 1280)),
             ("expert_blocks_stacked[int8,8x16x7168x2048]",
              experts(8, 16, 7168, 2048)),
+            ("expert_blocks_stacked[int8,4x64x2048x1536,tile=768]",
+             experts(4, 64, 2048, 1536, top_k=4, slots=96)),
+            ("flash_decode_stacked[bf16,5x96x4x2048x128,hd=64 paired]",
+             decode_pairs()),
+            ("append_rows_stacked[bf16,hd=64 paired]", append_pairs()),
             ("kda_decode[f32,6x128x64x128x128]", kda_decode),
             ("kda_prefill[f32,T=32]", kda_prefill(32)),
             ("kda_prefill[f32,T=512]", kda_prefill(512)),

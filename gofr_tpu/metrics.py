@@ -424,8 +424,9 @@ def register_framework_metrics(m: Manager) -> None:
                 "share of (step, routed layer, held expert) cells of the "
                 "last decode block that got no token")
     m.new_gauge("app_tpu_state_live_bytes",
-                "bytes of recurrent state the last decode block's active "
-                "slots hold (a family with linear-attention layers)")
+                "bytes of recurrent state or convolution tails the last "
+                "decode block's active slots hold (a family with "
+                "linear-attention or short-convolution layers)")
     m.new_gauge("app_tpu_kv_window_live_bytes",
                 "bytes of K and V the last decode block's active slots hold "
                 "on their rings (a family with sliding-window layers)")

@@ -9,7 +9,7 @@ layer axis. No torch, no module classes — params are data, which is what
 """
 
 from .common import ModelConfig, LLAMA_CONFIGS, BERT_CONFIGS, VIT_CONFIGS
-from . import llama, bert, vit, deepseek_v3, solar_open2, laguna
+from . import llama, bert, vit, deepseek_v3, solar_open2, laguna, lfm2
 
 
 def family(cfg: ModelConfig):
@@ -21,15 +21,17 @@ def family(cfg: ModelConfig):
     ``write_kv``, ``prefill_chunk``, ``decode_step``, ``decode_kv_block``,
     ``kv_layout``, ``unsupported_options``, ``serving_stats``, ``forward``,
     and ``RECOMPUTABLE``: whether a cached position can be computed
-    again and give the same memory (rows can; a recurrent state and a
-    ring of rows cannot)."""
+    again and give the same memory (rows can; a recurrent state, a
+    ring of rows and a convolution's tail cannot)."""
     if "linear" in cfg.layer_pattern:
         return solar_open2
     if "window" in cfg.layer_pattern:
         return laguna
+    if "conv" in cfg.layer_pattern:
+        return lfm2
     return deepseek_v3 if cfg.kv_lora_rank > 0 else llama
 
 
 __all__ = ["ModelConfig", "LLAMA_CONFIGS", "BERT_CONFIGS", "VIT_CONFIGS",
            "llama", "bert", "vit", "deepseek_v3", "solar_open2", "laguna",
-           "family"]
+           "lfm2", "family"]

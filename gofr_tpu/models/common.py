@@ -91,6 +91,15 @@ class ModelConfig:
     window_rope_theta: float = 0.0
     rotary_dim: int = 0
     head_gate: bool = False
+    # gated short-convolution layers beside full ones (models/lfm2.py; a
+    # "conv" entry in layer_pattern selects that family): a conv layer
+    # is y = W_out (C * conv(B * X)) with [B | C | X] = W_in h and a
+    # causal depthwise convolution over conv_kernel inputs, no bias and
+    # no activation; its whole cache is the last conv_kernel - 1 inputs
+    # of the convolution, whatever the length. qk_norm: a full layer's q
+    # and k are RMS-normed a head (weights of head_dim) before the
+    # rotation
+    qk_norm: bool = False
 
     def __post_init__(self):
         # a configuration file gives the pattern as a list
@@ -180,6 +189,18 @@ LLAMA_CONFIGS = {
         n_experts=8, experts_per_token=2, n_expert_groups=1, topk_groups=1,
         routed_scaling=2.5, n_shared_experts=1, moe_ffn_dim=40,
         n_dense_layers=1),
+    # the conv family at test size: two periods of three gated
+    # short-convolution layers to one full layer, heads of 16 in groups
+    # of three (a pair of KV heads a cache row), a q/k norm a head, one
+    # dense layer before the routed ones, no shared expert, tied head
+    "tiny-conv-moe": ModelConfig(
+        name="tiny-conv-moe", vocab_size=256, dim=64, n_layers=8, n_heads=6,
+        n_kv_heads=2, ffn_dim=96, max_seq=128, rope_theta=1e6,
+        norm_eps=1e-5, tie_embeddings=True, dtype="float32",
+        layer_pattern=("conv", "conv", "full", "conv"), attn_head_dim=16,
+        conv_kernel=3, qk_norm=True, n_experts=8, experts_per_token=2,
+        n_expert_groups=1, topk_groups=1, routed_scaling=1.0,
+        n_shared_experts=0, moe_ffn_dim=40, n_dense_layers=1),
 }
 
 BERT_CONFIGS = {

@@ -164,6 +164,27 @@ def unsupported_options(*, mesh=None, paged_blocks: int = 0, kvcache=None,
     return refused
 
 
+def init_routed(keys, cfg: ModelConfig, L: int) -> dict:
+    """Random-init leaves of ``L`` routed feed-forwards (what ``moe_ffn``
+    reads), one key of ``keys`` a leaf in this order: the router
+    ``n_experts`` wide and its bias whole, ``n_held`` experts, and the
+    shared experts' leaves where the configuration has any."""
+    dt, D = cfg.jdtype, cfg.dim
+    E, Eh, Fm = cfg.n_experts, n_held(cfg), cfg.moe_ffn_dim
+    Fs = Fm * cfg.n_shared_experts
+    w = {"router": dense_init(next(keys), (L, D, E), dt),
+         "router_bias": 0.01 * jax.random.normal(next(keys), (L, E),
+                                                 jnp.float32),
+         "w_gate": dense_init(next(keys), (L, Eh, D, Fm), dt),
+         "w_up": dense_init(next(keys), (L, Eh, D, Fm), dt),
+         "w_down": dense_init(next(keys), (L, Eh, Fm, D), dt)}
+    if Fs:
+        w.update(ws_gate=dense_init(next(keys), (L, D, Fs), dt),
+                 ws_up=dense_init(next(keys), (L, D, Fs), dt),
+                 ws_down=dense_init(next(keys), (L, Fs, D), dt))
+    return w
+
+
 def init(cfg: ModelConfig, key) -> dict:
     """Random-init params of the share this chip holds: every norm, the
     router (``n_experts`` wide) and its bias whole, ``n_experts_held``
@@ -175,8 +196,6 @@ def init(cfg: ModelConfig, key) -> dict:
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     nd = cfg.n_dense_layers
     ns = cfg.n_layers - nd
-    E, Eh, Fm = cfg.n_experts, n_held(cfg), cfg.moe_ffn_dim
-    Fs = Fm * cfg.n_shared_experts
 
     def attn(L):
         return {
@@ -201,15 +220,7 @@ def init(cfg: ModelConfig, key) -> dict:
         },
         "layers": {
             **attn(ns),
-            "router": dense_init(next(k), (ns, D, E), dt),
-            "router_bias": 0.01 * jax.random.normal(next(k), (ns, E),
-                                                    jnp.float32),
-            "w_gate": dense_init(next(k), (ns, Eh, D, Fm), dt),
-            "w_up": dense_init(next(k), (ns, Eh, D, Fm), dt),
-            "w_down": dense_init(next(k), (ns, Eh, Fm, D), dt),
-            "ws_gate": dense_init(next(k), (ns, D, Fs), dt),
-            "ws_up": dense_init(next(k), (ns, D, Fs), dt),
-            "ws_down": dense_init(next(k), (ns, Fs, D), dt),
+            **init_routed(k, cfg, ns),
         },
         "final_norm": jnp.ones((D,), dt),
     }
@@ -274,9 +285,16 @@ def serving_stats(cfg: ModelConfig, slots: int) -> dict:
     shapes (the device operations that tall are the routed experts':
     benchmarks/metrics reads them here) and the path its blocks take."""
     bm, rows = expert_dispatch(cfg, slots)
-    return {"moe_decode_dispatch": {
-        "block_rows": bm, "buffer_rows": rows,
-        "path": "kernel" if experts_on_kernel(cfg) else "loop"}}
+    said = {"block_rows": bm, "buffer_rows": rows, "path": "loop"}
+    if experts_on_kernel(cfg):
+        # columns of the expert width a grid step takes, by the weights'
+        # type: fewer than the width where an expert's tiles are over
+        # the kernel's budget
+        said.update(path="kernel", tile_columns={
+            name: moe_experts.tile_columns(cfg.dim, cfg.moe_ffn_dim, size)
+            for name, size in (("int8", 1),
+                               (cfg.dtype, cfg.jdtype.itemsize))})
+    return {"moe_decode_dispatch": said}
 
 
 EXPERT_STACKS = ("w_gate", "w_up", "w_down")
@@ -392,8 +410,9 @@ def moe_ffn(h, lw, cfg: ModelConfig, valid=None):
     topi, w = route(hf, lw["router"], lw["router_bias"], cfg)
     y, counts, _ = _experts(hf, topi, w, *lw["experts"], cfg,
                             None if valid is None else valid.reshape(B * S))
-    with jax.named_scope("moe/shared"):
-        y = y + _swiglu(hf, lw["ws_gate"], lw["ws_up"], lw["ws_down"])
+    if "ws_gate" in lw:     # n_shared_experts 0: no leaves, nothing added
+        with jax.named_scope("moe/shared"):
+            y = y + _swiglu(hf, lw["ws_gate"], lw["ws_up"], lw["ws_down"])
     return y.reshape(B, S, D), counts
 
 

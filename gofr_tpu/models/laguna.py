@@ -56,7 +56,7 @@ from ..ops.quant import qmatmul
 from ..ops.rope import apply_rope_part
 from . import deepseek_v3, llama
 from .common import ModelConfig, dense_init
-from .deepseek_v3 import EXPERT_STACKS, dense_ffn, moe_ffn, n_held
+from .deepseek_v3 import EXPERT_STACKS, dense_ffn, moe_ffn
 from .llama import _logits
 
 # a ring row that has been overwritten is gone: the chunk lattice runs
@@ -198,8 +198,6 @@ def init(cfg: ModelConfig, key) -> dict:
     D, V, KV, hd = cfg.dim, cfg.vocab_size, cfg.n_kv_heads, cfg.head_dim
     nd = cfg.n_dense_layers
     ns = cfg.n_layers - nd
-    E, Eh, Fm = cfg.n_experts, n_held(cfg), cfg.moe_ffn_dim
-    Fs = Fm * cfg.n_shared_experts
 
     def attn(kind):
         L, H = n[kind], heads(cfg, kind)
@@ -221,16 +219,8 @@ def init(cfg: ModelConfig, key) -> dict:
             "w_gate": dense_init(next(ks), (nd, D, cfg.ffn_dim), dt),
             "w_up": dense_init(next(ks), (nd, D, cfg.ffn_dim), dt),
             "w_down": dense_init(next(ks), (nd, cfg.ffn_dim, D), dt)},
-        "moe": {
-            "ffn_norm": jnp.ones((ns, D), dt),
-            "router": dense_init(next(ks), (ns, D, E), dt),
-            "router_bias": 0.01 * jax.random.normal(next(ks), (ns, E), F32),
-            "w_gate": dense_init(next(ks), (ns, Eh, D, Fm), dt),
-            "w_up": dense_init(next(ks), (ns, Eh, D, Fm), dt),
-            "w_down": dense_init(next(ks), (ns, Eh, Fm, D), dt),
-            "ws_gate": dense_init(next(ks), (ns, D, Fs), dt),
-            "ws_up": dense_init(next(ks), (ns, D, Fs), dt),
-            "ws_down": dense_init(next(ks), (ns, Fs, D), dt)},
+        "moe": {"ffn_norm": jnp.ones((ns, D), dt),
+                **deepseek_v3.init_routed(ks, cfg, ns)},
         "final_norm": jnp.ones((D,), dt),
     }
     if not cfg.tie_embeddings:
@@ -252,8 +242,13 @@ def _attention(x, lw, cfg: ModelConfig, kind: str, rope, positions, attend):
         # the heads-major layout the reshape and the rope want stays on
         # this side (llama._layer says what it costs without)
         q, k, v = jax.lax.optimization_barrier((q, k, v))
-        q = apply_rope_part(q.reshape(B, S, H, hd), *rope[kind], positions)
-        k = apply_rope_part(k.reshape(B, S, KV, hd), *rope[kind], positions)
+        q, k = q.reshape(B, S, H, hd), k.reshape(B, S, KV, hd)
+        if cfg.qk_norm:     # a head at a time, before the rotation
+            with jax.named_scope("attn/qk_norm"):
+                q = rms_norm(q, lw["q_head_norm"], cfg.norm_eps)
+                k = rms_norm(k, lw["k_head_norm"], cfg.norm_eps)
+        q = apply_rope_part(q, *rope[kind], positions)
+        k = apply_rope_part(k, *rope[kind], positions)
         v = v.reshape(B, S, KV, hd)
     a = attend(q, k, v)
     with jax.named_scope("attn_out"):
@@ -283,7 +278,9 @@ def _stack(params, cfg: ModelConfig, x, layer):
     routed layers' n stacked [Ls, ...])."""
     pat, nd = cfg.layer_pattern, cfg.n_dense_layers
     period, P = len(pat), cfg.n_layers // len(pat)
-    per = {k: pat.count(k) for k in KINDS}
+    # the kinds are the pattern's own (models/lfm2.py runs its stack
+    # here too, ``params[kind]`` a stack a kind)
+    per = {k: pat.count(k) for k in dict.fromkeys(pat)}
     unrolled = min(-(-nd // period), P)
     # the expert stacks go on whole to deepseek_v3._experts, which reads
     # expert (layer, e) in place
@@ -301,7 +298,7 @@ def _stack(params, cfg: ModelConfig, x, layer):
     def run(x, p):
         """Period ``p``: a python int (a dense layer's feed-forward is
         chosen here) or the scan's index."""
-        rows = {k: [] for k in KINDS}
+        rows = {k: [] for k in per}
         ns = []
         for j, kind in enumerate(pat):
             l = p * period + j

@@ -55,7 +55,7 @@ from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
 from . import deepseek_v3, llama
 from .common import ModelConfig, dense_init
-from .deepseek_v3 import EXPERT_STACKS, moe_ffn, n_held
+from .deepseek_v3 import EXPERT_STACKS, moe_ffn
 from .llama import _logits
 
 # a cached position of a linear layer cannot be computed again on top of
@@ -202,21 +202,10 @@ def init(cfg: ModelConfig, key) -> dict:
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     Hl, d, r, W = (cfg.linear_heads, cfg.linear_head_dim, cfg.gate_rank,
                    cfg.conv_kernel)
-    E, Eh, Fm = cfg.n_experts, n_held(cfg), cfg.moe_ffn_dim
-    Fs = Fm * cfg.n_shared_experts
 
     def ffn(L):
-        return {
-            "ffn_norm": jnp.ones((L, D), dt),
-            "router": dense_init(next(ks), (L, D, E), dt),
-            "router_bias": 0.01 * jax.random.normal(next(ks), (L, E), F32),
-            "w_gate": dense_init(next(ks), (L, Eh, D, Fm), dt),
-            "w_up": dense_init(next(ks), (L, Eh, D, Fm), dt),
-            "w_down": dense_init(next(ks), (L, Eh, Fm, D), dt),
-            "ws_gate": dense_init(next(ks), (L, D, Fs), dt),
-            "ws_up": dense_init(next(ks), (L, D, Fs), dt),
-            "ws_down": dense_init(next(ks), (L, Fs, D), dt),
-        }
+        return {"ffn_norm": jnp.ones((L, D), dt),
+                **deepseek_v3.init_routed(ks, cfg, L)}
 
     Lf, Lk = P * nf, P * nl
     full = {

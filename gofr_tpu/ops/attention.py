@@ -26,6 +26,43 @@ def _repeat_kv_shape(q: jnp.ndarray, n_kv: int) -> jnp.ndarray:
     return q.reshape(b, s, n_kv, h // n_kv, d)
 
 
+# -- heads of half a lane row, two KV heads a row ---------------------------------
+#
+# A cache row of a head narrower than the 128 lanes is padded to them in
+# HBM, and a kernel's tile of it is half empty. Where a head is 64 values
+# the cache holds KV heads 2p and 2p + 1 side by side in one row
+# [.., KV/2, Smax, 128] (the K or V of a token a KV head apart is one
+# reshape), and a query head takes its own half by carrying zeros in the
+# other: q' . row = q . k of its KV head, exactly. The value product comes
+# back a row wide, each query head's answer in its half. The kernels and
+# the jnp forms then run as at 128 with half the KV heads and twice the
+# group, handed the softmax scale of the true width.
+
+def pair_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """[..., KV, d] -> [..., KV/2, 2d]: KV heads 2p | 2p + 1 in one row."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] // 2, 2 * x.shape[-1]))
+
+
+def pair_queries(q: jnp.ndarray, n_kv: int) -> jnp.ndarray:
+    """[B, S, H, d] -> [B, S, H, 2d]: each head's values in its KV head's
+    half of the pair's row, zeros in the other."""
+    b, s, h, d = q.shape
+    qp = q.reshape(b, s, n_kv // 2, 2, h // n_kv, d)
+    zero = jnp.zeros_like(qp[:, :, :, 0])
+    return jnp.stack([jnp.concatenate([qp[:, :, :, 0], zero], -1),
+                      jnp.concatenate([zero, qp[:, :, :, 1]], -1)],
+                     axis=3).reshape(b, s, h, 2 * d)
+
+
+def unpair_heads(o: jnp.ndarray, n_kv: int) -> jnp.ndarray:
+    """[B, S, H, 2d] -> [B, S, H, d]: each head's own half of what a
+    paired attention returned."""
+    b, s, h, d2 = o.shape
+    op = o.reshape(b, s, n_kv // 2, 2, h // n_kv, 2, d2 // 2)
+    return jnp.stack([op[:, :, :, 0, :, 0], op[:, :, :, 1, :, 1]],
+                     axis=3).reshape(b, s, h, d2 // 2)
+
+
 @jax.named_scope("causal_attention")
 def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      mask: jnp.ndarray | None = None,
@@ -87,8 +124,8 @@ def decode_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
                               v_new: jnp.ndarray, lengths: jnp.ndarray,
                               k_scale: jnp.ndarray | None = None,
                               v_scale: jnp.ndarray | None = None,
-                              exclude: jnp.ndarray | None = None
-                              ) -> jnp.ndarray:
+                              exclude: jnp.ndarray | None = None,
+                              scale: float | None = None) -> jnp.ndarray:
     """Decode attention over the cache PLUS the current token's k/v, before
     that token has been written back.
 
@@ -111,7 +148,8 @@ def decode_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
     k_new/v_new: [B, 1, KV, D]; lengths: [B] valid entries (EXCLUDING the
     current token). Returns [B, 1, H, D]. ``exclude`` [B]: a cache row
     not to read whatever ``lengths`` says (a ring's oldest row, which the
-    step is about to overwrite: ops.flash_decode.ring_rows).
+    step is about to overwrite: ops.flash_decode.ring_rows). ``scale``:
+    the softmax scale where it is not D^-1/2 (paired heads, above).
 
     INT8 cache: when ``k_scale``/``v_scale`` [B, KV, Smax] are given the
     cache tensors are per-vector int8 (ops.quant.quantize_kv). The scale is
@@ -123,7 +161,7 @@ def decode_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
     """
     b, _, h, d = q.shape
     n_kv, smax = k_cache.shape[1], k_cache.shape[2]
-    scale = d ** -0.5
+    scale = scale or d ** -0.5
 
     qg = _repeat_kv_shape(q * scale, n_kv)[:, 0]  # [B,KV,G,D]
     scores_c = jnp.einsum("bkgd,bktd->bkgt", qg, k_cache.astype(qg.dtype),
@@ -201,7 +239,8 @@ def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                     k_new: jnp.ndarray, v_new: jnp.ndarray,
                     start: jnp.ndarray,
                     k_scale: jnp.ndarray | None = None,
-                    v_scale: jnp.ndarray | None = None) -> jnp.ndarray:
+                    v_scale: jnp.ndarray | None = None,
+                    scale: float | None = None) -> jnp.ndarray:
     """Chunked-prefill attention: a block of C new tokens at positions
     [start, start+C) attends to the cache prefix (positions < start) plus
     causally within the chunk — the long-prompt path, processing prompts in
@@ -213,11 +252,12 @@ def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     ``k_scale``/``v_scale`` [B, KV, Smax]: per-vector scales for int8
     caches (see decode_attention_appended — same fused-dequant scheme).
     Trailing padding inside the chunk is harmless: causality means padded
-    positions are never attended BY valid ones. Returns [B, C, H, D].
+    positions are never attended BY valid ones. ``scale``: the softmax
+    scale where it is not D^-1/2 (paired heads). Returns [B, C, H, D].
     """
     b, c, h, d = q.shape
     n_kv, smax = k_cache.shape[1], k_cache.shape[2]
-    scale = d ** -0.5
+    scale = scale or d ** -0.5
 
     qg = _repeat_kv_shape(q * scale, n_kv)  # [B,C,KV,G,D]
     scores_c = jnp.einsum("bskgd,bktd->bkgst", qg, k_cache.astype(qg.dtype),

@@ -48,7 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .attention import causal_attention
+from .attention import (causal_attention, pair_queries, pair_rows,
+                        unpair_heads)
 
 NEG_INF = -1e30
 _LANES = 128  # VMEM scratch minor dim (min f32 tile is 8 x 128)
@@ -143,11 +144,12 @@ def _flash_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
-                                             "interpret", "window"))
+                                             "interpret", "window", "scale"))
 def flash_causal_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          lengths: jnp.ndarray, *, block_q: int = 128,
                          block_k: int = 128, interpret: bool = False,
-                         window: int = 0) -> jnp.ndarray:
+                         window: int = 0,
+                         scale: float | None = None) -> jnp.ndarray:
     """Causal prefill attention without S² materialization.
 
     q: [B, S, H, D]; k, v: [B, S, KV, D] (KV divides H); lengths: [B]
@@ -156,6 +158,7 @@ def flash_causal_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     blocks (callers dispatch through causal_attention_auto, which falls
     back to the jnp reference otherwise). ``window`` > 0: a band, position
     p sees (p - window, p]; k blocks wholly below it are skipped.
+    ``scale``: the softmax scale where it is not D^-1/2 (paired heads).
     Returns [B, S, H, D] in q.dtype.
     """
     b, s, h, d = q.shape
@@ -163,7 +166,7 @@ def flash_causal_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if s % block_q or s % block_k:
         raise ValueError(f"S={s} not divisible by blocks "
                          f"({block_q}, {block_k})")
-    scale = d ** -0.5
+    scale = scale or d ** -0.5
     grid = (b, h, s // block_q, s // block_k)
 
     # Mosaic requires the last two BLOCK dims divisible by (8, 128) or
@@ -225,6 +228,18 @@ def _kernel_ok(q: jnp.ndarray, block_q: int, block_k: int) -> bool:
     if d % 128 or s < 2 * block_q or s % block_q or s % block_k:
         return False
     return tpu_backend_ok()
+
+
+def _pairs_ok(q, k, block_q: int, block_k: int, interpret: bool, mesh,
+              window: int) -> bool:
+    """Whether a prefill of 64-wide heads runs the kernel on paired heads
+    (inference only, one device, no band): where the kernel would run at
+    twice the width, or interpreted."""
+    b, s, h, d = q.shape
+    if 2 * d != _LANES or k.shape[2] % 2 or mesh is not None or window:
+        return False
+    return interpret or _kernel_ok(
+        jax.ShapeDtypeStruct((b, s, h, 2 * d), q.dtype), block_q, block_k)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -311,6 +326,14 @@ def causal_attention_auto(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if interpret:
         block_q = fit_block(q.shape[1], block_q)
         block_k = fit_block(q.shape[1], block_k)
+    if _pairs_ok(q, k, block_q, block_k, interpret, mesh, window):
+        # heads of half a lane row: two KV heads a row, as the cache
+        # holds them (ops.attention.pair_rows), and the kernel as at 128
+        n_kv = k.shape[2]
+        return unpair_heads(flash_causal_prefill(
+            pair_queries(q, n_kv), pair_rows(k), pair_rows(v),
+            lengths.astype(jnp.int32), block_q=block_q, block_k=block_k,
+            interpret=interpret, scale=q.shape[3] ** -0.5), n_kv)
     if window:
         if mesh is None and (interpret or _kernel_ok(q, block_q, block_k)):
             return flash_causal_prefill(

@@ -219,11 +219,12 @@ def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool,
     jax.lax.fori_loop(0, n, item, None)
 
 
-@functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("block_s", "interpret", "scale"))
 def flash_decode_stacked(q, cache_k, cache_v, k_new, v_new, lengths, layer,
                          k_scale=None, v_scale=None, *, block_s: int,
-                         interpret: bool = False,
-                         exclude=None) -> jnp.ndarray:
+                         interpret: bool = False, exclude=None,
+                         scale: float | None = None) -> jnp.ndarray:
     """decode_attention_appended over layer ``layer`` of the stacked
     cache, reading only what ``lengths`` says is live.
 
@@ -232,7 +233,14 @@ def flash_decode_stacked(q, cache_k, cache_v, k_new, v_new, lengths, layer,
     lengths [B] EXCLUDING the current token, 0 for a slot whose cache
     must not be read; layer: int32 scalar; exclude: [B] int32 or None,
     a row of each slot that is not read though it lies below its length
-    (``ring_rows``). Returns [B, 1, H, D] in q.dtype."""
+    (``ring_rows``); scale: the softmax scale where it is not D^-1/2.
+    Returns [B, 1, H, D] in q.dtype.
+
+    A head of 64 values comes here PAIRED (ops.attention.pair_rows): the
+    cache [L, B, KV/2, Smax, 128] two KV heads a row, q with zeros in the
+    half that is not its KV head's, ``scale`` 64^-1/2; a KV head's group
+    of four is then a pair's group of eight, one float32 sublane tile,
+    and the kernel below is the one that 128-wide heads run."""
     b, _, h, d = q.shape
     n_kv, smax = cache_k.shape[2], cache_k.shape[3]
     g = h // n_kv
@@ -241,7 +249,7 @@ def flash_decode_stacked(q, cache_k, cache_v, k_new, v_new, lengths, layer,
     lengths = lengths.astype(jnp.int32)
     skip = () if exclude is None else (exclude.astype(jnp.int32),)
     n, slot, blk = _work_list(lengths, smax, block_s)
-    qg = (q[:, 0] * (d ** -0.5)).reshape(b, n_kv, g, d)
+    qg = (q[:, 0] * (scale or d ** -0.5)).reshape(b, n_kv, g, d)
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -351,7 +359,8 @@ def _shard_specs(batch_axes, head_axis):
 def kernel_block(n_heads: int, cache_k, mesh=None) -> int | None:
     """The kernels' block size where backend and shapes allow them, None
     where decode attention and the step's write stay on the reference:
-    not a TPU, a head_dim that is not whole lanes, a cache no
+    not a TPU, a cache row that is not whole lanes (a head of 64 is
+    cached two KV heads a row and is: ops.attention.pair_rows), a cache no
     lane-aligned block divides, or a tp that would split a KV head. The
     local KV-head count does not matter: a head's (Smax, hd) tiles are
     whole at any count. ``GOFR_FLASH_INTERPRET=1`` runs the kernels

@@ -271,15 +271,17 @@ def prefill_auto(q, k, v, alpha, beta, state):
 
 # -- the short convolution ----------------------------------------------------
 
-@jax.named_scope("kda/conv")
-def short_conv(x, tail, weight, lengths=None):
+def conv_taps(x, tail, weight, lengths=None):
     """Causal depthwise convolution a channel over the last W inputs,
-    then SiLU, with the W - 1 inputs before the block in ``tail``.
+    with the W - 1 inputs before the block in ``tail``: taps and tail,
+    no activation (the hybrid family's ``short_conv`` puts a SiLU on it;
+    the conv family's gated operator, models/lfm2.py, none).
 
     x [B, T, C]; tail [B, W - 1, C]; weight [W, C] (weight[W - 1] meets
     the current input); lengths [B]: valid inputs of x (None: all).
     Returns (y [B, T, C] float32, the last W - 1 valid inputs: the tail
-    as it stands after input ``lengths - 1``)."""
+    as it stands after input ``lengths - 1``; zeros stay where fewer
+    than W - 1 inputs have been seen)."""
     B, T, _ = x.shape
     W = weight.shape[0]
     xs = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
@@ -290,4 +292,12 @@ def short_conv(x, tail, weight, lengths=None):
     else:
         new_tail = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(
             row, n, W - 1, axis=0))(xs, lengths.astype(jnp.int32))
-    return jax.nn.silu(y), new_tail.astype(tail.dtype)
+    return y, new_tail.astype(tail.dtype)
+
+
+@jax.named_scope("kda/conv")
+def short_conv(x, tail, weight, lengths=None):
+    """``conv_taps`` then SiLU: the delta-rule layer's convolution on q,
+    k and v."""
+    y, tail = conv_taps(x, tail, weight, lengths)
+    return jax.nn.silu(y), tail

@@ -27,6 +27,14 @@ Config keys (reference config style, pkg/gofr/config/config.go:3):
                       layers' rows in one cache; it refuses what the
                       latent family refuses, and TPU_MAX_SEQ must be
                       whole prefill chunks),
+                      the conv family (tiny-conv-moe; any configuration
+                      whose layer_pattern names a "conv" layer: gated
+                      short-convolution layers that keep the last
+                      conv_kernel - 1 inputs a slot and no rows, beside
+                      full layers whose 64-wide KV heads share a cache
+                      row two by two; it refuses what the latent family
+                      refuses, and TPU_MAX_SEQ must be whole prefill
+                      chunks),
                       bert family (bert/bert-base, bert-tiny), or
                       vit family (vit/vit-l-14, vit-tiny)
   TPU_WEIGHTS         checkpoint path (.npz or orbax dir); absent = random

@@ -1,6 +1,7 @@
 """The cache's two kernels, the delta rule's two (ops/kda.py) and, at the
-end, the llama family's decode block and prefill and the window
-family's decode block and chunk program with the weights' reads in them,
+end, the llama family's decode block and prefill, the window family's
+decode block and chunk program and the conv family's decode block and
+prefill with the weights' reads in them,
 compiled for a TPU v5e that is described, not
 attached (libtpu's compile-only topology; no chip time, nothing runs):
 what interpret mode cannot see, Mosaic refusing a slice that is not
@@ -288,6 +289,78 @@ def test_window_family_reads_weights_and_rings_in_place(one_chip, monkeypatch,
     if program == "decode block":       # the loop's 156.6 MB, to 3 digits
         assert mem.temp_size_in_bytes < 0.1575e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+
+
+# -- the conv family's programs at the published widths ------------------------
+
+def _lowered_conv(monkeypatch, sharding, program):
+    """``benchmarks/configs/lfm2-24b-a2b-int8-pp2.json`` as its cell runs
+    it: twenty layers, bfloat16 rows two KV heads a row, 96 slots."""
+    import json
+    import os
+
+    from gofr_tpu.models.common import ModelConfig
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "lfm2-24b-a2b-int8-pp2.json")) as f:
+        cfg = ModelConfig(**json.load(f)["model_config"])
+    return _engine_lowered(monkeypatch, sharding, cfg, 96, None, program)
+
+
+@pytest.mark.parametrize("program,kernels", [("decode block", 9),
+                                             ("prefill 256", 8)])
+def test_conv_family_reads_weights_rows_and_tails_in_place(one_chip,
+                                                           monkeypatch,
+                                                           program, kernels):
+    """The decode block holds the decode kernel over the paired 64-wide
+    rows, the routed experts' kernel (an expert two tiles wide) and one
+    row append; the first period, which holds the dense layers, stands
+    beside the scan's body, so the text names the decode kernel twice and
+    the experts' six times (two sparse layers of the first period, four of
+    the body). The 256-token prefill runs the flash kernel on paired
+    heads. Neither copies an int8 weight stack or an expert stack, stages
+    a layer's slice of one in VMEM, or moves the K and V rows; the decode
+    block's temporaries are 40 MB and the whole engine fits the chip."""
+    compiled = _lowered_conv(monkeypatch, one_chip, program).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"^\s*%?[\w.\-]+ = .*custom_call_target="
+                          r"\"tpu_custom_call\"", text, re.M)) == kernels
+    assert len(re.findall(r"%expert_blocks_stacked[\w.]* = ", text)) == 6
+    assert len(re.findall(r" while\(", text)) <= 2
+    if program == "decode block":
+        assert len(re.findall(r"%flash_decode_stacked[\w.]* = ", text)) == 2
+        assert len(re.findall(r"%append_rows_stacked[\w.]* = ", text)) == 1
+    else:
+        assert len(re.findall(r"%flash_causal_prefill[\w.]* = ", text)) == 2
+    results = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = ((?:s8|bf16)\[[\d,]+\]\S*) ([\w\-]+)\(",
+        text, re.M)
+    assert results                      # the pattern still reads this HLO
+
+    def elements(shape):
+        n = 1
+        for d in shape.split("[")[1].split("]")[0].split(","):
+            n *= int(d)
+        return n
+
+    # an int8 stack (15 conv operators, 5 attention, 2 dense, 18 x 64
+    # experts) moved as a whole, or a layer's slice of one staged
+    stack_copies = [r for r in results if r[1] in ("copy", "transpose")
+                    and r[0].startswith("s8[") and elements(r[0]) >= 1 << 22]
+    staged = [r for r in results if r[1] == "fusion"
+              and r[0].startswith("s8[1,2048,") and "S(1)" in r[0]]
+    # the rows [5, 96, 4, 2048, 128], or a slot's
+    moved = [r for r in results if r[1] in ("copy", "transpose")
+             and re.match(r"bf16\[5,(96|1),4,2048,128\]", r[0])]
+    assert not stack_copies
+    assert not staged
+    assert not moved
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (64 << 20)
+    # 11.6 GB of weights, 2.03 GB of cache, and what a step needs
+    assert 13.5e9 < mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 14.2e9
 
 
 # -- the sampler's branches in the compiled decode block -----------------------
