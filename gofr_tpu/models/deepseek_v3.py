@@ -585,7 +585,7 @@ def write_kv(cache: LatentCache, rows, index, lengths) -> LatentCache:
 def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                   cache: LatentCache, start, rope_tables=None,
                   compute_logits: bool = True, adapter=None,
-                  logit_pos: jnp.ndarray | None = None):
+                  logit_pos: jnp.ndarray | None = None, mesh=None):
     """A chunk of C prompt tokens at [start, start + C) against the
     growing cache: absorbed over the rows before it, expanded within
     itself. ``cache.lengths`` is not advanced (llama.prefill_chunk's

@@ -425,7 +425,7 @@ def forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                   cache: WindowCache, start, rope_tables=None,
                   compute_logits: bool = True, adapter=None,
-                  logit_pos: jnp.ndarray | None = None):
+                  logit_pos: jnp.ndarray | None = None, mesh=None):
     """A chunk of C prompt tokens at [start, start + C) against the
     cache: the full layers attend to the rows before it, the window
     layers to their rings as they stand, both causally within the chunk;

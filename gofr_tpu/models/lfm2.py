@@ -373,7 +373,7 @@ def write_kv(cache: ConvCache, k_stack, v_stack, tails, index, lengths
 def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                   cache: ConvCache, start, rope_tables=None,
                   compute_logits: bool = True, adapter=None,
-                  logit_pos: jnp.ndarray | None = None):
+                  logit_pos: jnp.ndarray | None = None, mesh=None):
     """A chunk of C prompt tokens at [start, start + C) against the
     cache: the full layers attend to the rows before it and within
     itself, the conv layers go on from the cache's tails (from zeros at

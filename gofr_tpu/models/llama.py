@@ -27,7 +27,7 @@ from ..ops.attention import (causal_attention, chunk_attention,
                              decode_attention_appended,
                              window_attention_appended)
 from ..ops.norms import rms_norm
-from ..ops.quant import qmatmul, quantize_kv
+from ..ops.quant import QuantizedLinear, qmatmul, quantize_kv
 from ..ops.rope import apply_rope, rope_frequencies
 from .common import ModelConfig, dense_init
 
@@ -215,8 +215,6 @@ def _expert_mm(h, w, pattern: str, scale_expand=(None, None)):
     broadcasts against the output — (None, None) prepends two (the
     [B,S,E,out] dense-dispatch layout); for [E,C,out] grouped buffers
     pass (slice(None), None)."""
-    from ..ops.quant import QuantizedLinear
-
     if isinstance(w, QuantizedLinear):
         y = jnp.einsum(pattern, h, w.w.astype(h.dtype),
                        preferred_element_type=jnp.float32)
@@ -295,7 +293,60 @@ def _moe_ffn_grouped(h, layer_w, cfg: ModelConfig, valid=None):
     return out.reshape(B, S, D), probs.reshape(B, S, E)
 
 
-def _moe_ffn(h, layer_w, cfg: ModelConfig, valid=None):
+def _experts_down(gated, w_down, combine):
+    """The tail of the dense dispatch, ``gated`` [B,S,E,F] through
+    ``w_down`` [E,F,D] and the combine weights [B,S,E] to [B,S,D], all in
+    float32: the contraction over ``f``, the int8 scale [E,D] where the
+    stack is quantized, the combine weights, the sum over ``e``. Scale
+    and combine weights are factors that do not depend on ``f``, so on
+    an ``F/tp`` slice of ``gated`` and ``w_down`` this is the slice's
+    share of the result and the shares add up (_combine_experts)."""
+    quant = isinstance(w_down, QuantizedLinear)
+    w = w_down.w.astype(gated.dtype) if quant else w_down
+    y = jnp.einsum("bsef,efd->bsed", gated, w,
+                   preferred_element_type=jnp.float32)
+    if quant:
+        y = y * w_down.scale
+    return jnp.einsum("bsed,bse->bsd", y, combine.astype(jnp.float32))
+
+
+def _combine_experts(gated, w_down, combine, mesh):
+    """``_experts_down`` rounded once, to ``gated``'s type. Where
+    ``mesh`` has a ``tp`` axis that splits ``F`` (parallel.sharding puts
+    ``w_down``'s F there), each chip's slice goes through the tail in a
+    region manual over ``tp`` and the [B,S,D] shares are summed outside
+    it, a sum over a sharded axis that GSPMD turns into one all-reduce.
+    Left to GSPMD the whole way, the reduction sits straight after the
+    contraction and carries [B,S,E,D], E times the bytes for the same
+    sum (PERF.md, Findings PR 41). The axes that split something else
+    stay GSPMD's inside the region too."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import AXIS_TP
+
+    tp = mesh.shape.get(AXIS_TP, 1) if mesh is not None else 1
+    if tp == 1 or gated.shape[-1] % tp:
+        return _experts_down(gated, w_down, combine).astype(gated.dtype)
+
+    # An axis of one device is manual too: it splits nothing, and in a
+    # region that leaves any axis to GSPMD every operation is lowered
+    # with a sharding annotation behind it. The one behind the int8
+    # convert keeps the chip's compiler from folding the convert into the
+    # matmul, and the prefill programs then transpose a layer's w_down
+    # before they read it (tests/test_kernels_compile_v5e.py
+    # ::test_experts_are_combined_before_they_cross_the_chips).
+    manual = {a for a, n in mesh.shape.items() if a == AXIS_TP or n == 1}
+    w_spec = P(None, AXIS_TP)                 # [E,F,D] on F
+    if isinstance(w_down, QuantizedLinear):   # its scale [E,D] whole
+        w_spec = QuantizedLinear(w_spec, P())
+    shares = jax.shard_map(
+        lambda *a: _experts_down(*a)[None], mesh=mesh, axis_names=manual,
+        in_specs=(P(None, None, None, AXIS_TP), w_spec, P()),
+        out_specs=P(AXIS_TP))(gated, w_down, combine)         # [tp,B,S,D]
+    return jnp.sum(shares, axis=0).astype(gated.dtype)
+
+
+def _moe_ffn(h, layer_w, cfg: ModelConfig, valid=None, mesh=None):
     """Mixture-of-experts SwiGLU FFN: softmax router, top-k expert
     selection with renormalized weights, dense-dispatch combine.
 
@@ -311,6 +362,8 @@ def _moe_ffn(h, layer_w, cfg: ModelConfig, valid=None):
     Weights: router [D,E]; w_gate/w_up [E,D,F]; w_down [E,F,D] — dense
     or int8 QuantizedLinear stacks (TPU_QUANT=int8 quantizes experts
     per-output-channel like every other projection).
+    ``mesh``: the jit's mesh, for the one collective of the dense
+    dispatch (_combine_experts).
     Returns (ffn_out [B,S,D], router_probs [B,S,E] f32 — the aux
     load-balancing loss input, collected by the training path).
     """
@@ -331,9 +384,8 @@ def _moe_ffn(h, layer_w, cfg: ModelConfig, valid=None):
         gated = jax.nn.silu(
             _expert_mm(h, layer_w["w_gate"], "bsd,edf->bsef")) \
             * _expert_mm(h, layer_w["w_up"], "bsd,edf->bsef")
-        out = _expert_mm(gated, layer_w["w_down"], "bsef,efd->bsed")
-        return (jnp.einsum("bsed,bse->bsd", out,
-                           combine.astype(out.dtype)), probs)
+        return _combine_experts(gated, layer_w["w_down"], combine,
+                                mesh), probs
 
 
 def _lora(h, layer_w, name: str, adapter):
@@ -351,11 +403,12 @@ def _lora(h, layer_w, name: str, adapter):
 
 
 def _layer(x, layer_w, cfg: ModelConfig, cos, sin, positions,
-           kv_write, attend, valid=None, adapter=None):
+           kv_write, attend, valid=None, adapter=None, mesh=None):
     """One transformer block. ``kv_write(k_new, v_new) -> (k_all, v_all)``
     handles cache interaction; ``attend(q, k, v)`` runs attention.
     ``adapter`` [B] int32 selects each row's LoRA adapter when the
-    params carry adapter stacks (multi-LoRA serving).
+    params carry adapter stacks (multi-LoRA serving). ``mesh``: the
+    jit's mesh, for the expert layer's collective (_combine_experts).
     Returns (x_out, (k_stored, v_stored))."""
     B, S = x.shape[0], x.shape[1]
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -397,7 +450,7 @@ def _layer(x, layer_w, cfg: ModelConfig, cos, sin, positions,
     if cfg.n_experts > 0:
         with jax.named_scope("moe"):
             h = rms_norm(x, layer_w["ffn_norm"], cfg.norm_eps)
-            ffn, router_probs = _moe_ffn(h, layer_w, cfg, valid)
+            ffn, router_probs = _moe_ffn(h, layer_w, cfg, valid, mesh)
             x = x + ffn
     else:
         with jax.named_scope("mlp"):
@@ -485,7 +538,7 @@ def _causal_scan(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     def body(x, layer_w):
         x, kv, probs = _layer(x, layer_w, cfg, cos_g, sin_g, None,
                               kv_write=lambda k, v: (k, v), attend=attend,
-                              valid=valid, adapter=adapter)
+                              valid=valid, adapter=adapter, mesh=mesh)
         # Training drops the per-layer k/v so the scan never materializes
         # the [L,B,S,KV,hd] stacks it would otherwise carry.
         return constrain(x), (kv if collect_kv else None,
@@ -499,7 +552,7 @@ def forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             lengths: jnp.ndarray | None = None, rope_tables=None,
             constrain=None, attend_override=None,
             return_router_probs: bool = False, adapter=None,
-            logit_pos: jnp.ndarray | None = None):
+            logit_pos: jnp.ndarray | None = None, mesh=None):
     """Cache-free causal forward over [B, S] tokens -> [B, S, V] f32 logits.
     The training/scoring path: no KV-cache allocation or writes.
     ``attend_override``: see _causal_scan (ring attention hook).
@@ -507,13 +560,14 @@ def forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     probabilities [L, B, S, E] (the load-balancing aux-loss input);
     returns (logits, probs) — probs is None for dense models.
     ``logit_pos`` [B]: project ONE position per row -> [B, 1, V] (the
-    gather precedes lm_head — see prefill_kv)."""
+    gather precedes lm_head — see prefill_kv). ``mesh``: the jit's mesh,
+    for the expert layer's collective (_combine_experts)."""
     x, _, _, probs = _causal_scan(params, cfg, tokens, lengths,
                                   tokens.shape[1], rope_tables, constrain,
                                   collect_kv=False,
                                   attend_override=attend_override,
                                   collect_router=return_router_probs,
-                                  adapter=adapter)
+                                  adapter=adapter, mesh=mesh)
     if logit_pos is not None:
         x = jnp.take_along_axis(x, logit_pos[:, None, None]
                                 .astype(jnp.int32), axis=1)  # [B, 1, D]
@@ -607,7 +661,7 @@ def prefill_kv(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                   cache: KVCache, start, rope_tables=None,
                   compute_logits: bool = True, adapter=None,
-                  logit_pos: jnp.ndarray | None = None):
+                  logit_pos: jnp.ndarray | None = None, mesh=None):
     """Process a chunk of C prompt tokens at positions [start, start+C)
     against the growing cache — the long-prompt path (chunked prefill):
     prompts of any length up to cache capacity run as a sequence of
@@ -622,7 +676,8 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     the true end caller-known only) — callers set lengths once after the
     last chunk. Returns (logits [B, C, V] f32 — or None when
     ``compute_logits`` is False, sparing mid-prompt chunks the lm_head
-    matmul — and the cache with KV written).
+    matmul — and the cache with KV written). ``mesh``: the jit's mesh,
+    for the expert layer's collective (_combine_experts).
     """
     B, C = tokens.shape
     cos, sin = rope_tables or get_rope_tables(cfg, cache.capacity)
@@ -642,7 +697,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
         x, kv, _ = _layer(x, layer_w, cfg, cos, sin, positions,
                           kv_write=lambda k, v: (k, v), attend=attend,
-                          adapter=adapter)
+                          adapter=adapter, mesh=mesh)
         return x, kv
 
     x, (k_chunk, v_chunk) = jax.lax.scan(
@@ -704,7 +759,7 @@ def verify_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
         x, kv, _ = _layer(x, layer_w, cfg, cos, sin, positions,
                           kv_write=lambda k, v: (k, v), attend=attend,
-                          adapter=adapter)
+                          adapter=adapter, mesh=mesh)
         return x, kv
 
     x, (k_w, v_w) = jax.lax.scan(
@@ -816,7 +871,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     def layer(x, layer_w, attend):
         x, kv_tok, _ = _layer(x, layer_w, cfg, cos, sin, positions,
                               kv_write=lambda k, v: (k, v), attend=attend,
-                              adapter=adapter)
+                              adapter=adapter, mesh=mesh)
         return x, kv_tok
 
     block_s = flash_decode.kernel_block(cfg.n_heads, cache.k, mesh)

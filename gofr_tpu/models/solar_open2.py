@@ -482,7 +482,7 @@ def write_kv(cache: HybridCache, k_stack, v_stack, state, conv, index,
 def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                   cache: HybridCache, start, rope_tables=None,
                   compute_logits: bool = True, adapter=None,
-                  logit_pos: jnp.ndarray | None = None):
+                  logit_pos: jnp.ndarray | None = None, mesh=None):
     """A chunk of C prompt tokens at [start, start + C) against the
     cache: the full layers attend to the rows before it and within
     itself, the linear layers go on from the cache's state (from an

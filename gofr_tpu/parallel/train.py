@@ -17,6 +17,7 @@ it serves. Design:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -219,8 +220,10 @@ def make_train_step(cfg: ModelConfig, optimizer: optax.GradientTransformation,
             attend_override = make_ring_attention(
                 mesh, axis_name=AXIS_SP, batch_axes=DATA_AXES)
 
-        fwd = (jax.checkpoint(llama.forward, static_argnums=(1, 5, 6, 7))
-               if remat else llama.forward)
+        # mesh: the expert layer's one collective (llama._combine_experts)
+        fwd = functools.partial(llama.forward, mesh=mesh)
+        if remat:
+            fwd = jax.checkpoint(fwd, static_argnums=(1, 5, 6, 7))
 
         def loss_fn(params, tokens, lengths):
             if moe:

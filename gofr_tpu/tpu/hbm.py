@@ -19,7 +19,8 @@ Three layers, lowest first:
   enforces the discipline statically and ``pytest --hbmwatch``
   reconciles declared bytes against ``jax.live_arrays()`` ground
   truth. ``release(owner=self)`` in ``close()``; a weakref finalizer
-  backstops owners that die without it.
+  backstops owners that die without it (it only notes the owner: the
+  next call drops the entries, ``_Registry._reap``).
 
   **Leases** (the arbiter): :func:`lease` reserves bytes against the
   budget BEFORE an allocation, tagged with a priority class —
@@ -87,6 +88,7 @@ vocabulary: ``engine`` (serving KV cache + chunk scratch),
 
 from __future__ import annotations
 
+import collections
 import threading
 import weakref
 from typing import Any, Callable
@@ -285,6 +287,8 @@ class _Registry:
         # every accounting change lands a counter sample so the
         # exported Perfetto trace carries an HBM track per subsystem
         self._timelines: "weakref.WeakSet[Any]" = weakref.WeakSet()
+        # ids of owners the collector has finalized since the last _reap
+        self._dead_owners: "collections.deque[int]" = collections.deque()
 
     # -- accounting (PR-6 contract; sharded trees split per device) ----------
     def account(self, subsystem: str, tree: Any, *, owner: Any = None,
@@ -292,6 +296,7 @@ class _Registry:
         base = (subsystem, id(owner) if owner is not None else 0, tag)
         n = tree_nbytes(tree)
         dev = shard_breakdown(tree)
+        self._reap()
         with self._mu:
             # SET semantics over the whole lease GROUP: drop every
             # device's entry for (subsystem, owner, tag) before writing
@@ -337,15 +342,32 @@ class _Registry:
         return tree
 
     def _release_owner_id(self, oid: int) -> None:
+        """An owner's finalizer. The collector runs it on whichever
+        thread crossed its threshold, between two bytecodes of whatever
+        that thread was doing: inside a ``with self._mu`` of this class
+        or a metrics sink's ``with m.lock``, neither re-entrant, and
+        waiting for one of those here is waiting for oneself (a tier-1
+        worker stood in ``arbiter_stats`` so until the run's limit cut
+        it, PR 41). So it takes no lock: it notes the id, and the next
+        call from outside drops the entries (_reap)."""
+        self._dead_owners.append(oid)  # noqa: GL001 — a deque's append
+
+    def _reap(self) -> None:
+        """Drop what collected owners left, leases and reclaim callbacks
+        with the entries, and push their subsystems' gauges. First thing
+        in every call that reads or writes the table: a reader sees no
+        dead owner's bytes, and a new owner whose id() is a dead one's
+        (queued before its memory could be reused) starts clean."""
+        if not self._dead_owners:
+            return
         touched: set[str] = set()
         with self._mu:
-            for key in list(self._entries):
-                if key[1] == oid:
+            while self._dead_owners:
+                oid = self._dead_owners.popleft()
+                for key in [k for k in self._entries if k[1] == oid]:
                     self._entries.pop(key)
-                    self._meta.pop(key, None)
                     touched.add(key[0])
-            for key in list(self._meta):
-                if key[1] == oid:
+                for key in [k for k in self._meta if k[1] == oid]:
                     self._meta.pop(key)
         for sub in touched:
             self._push(sub)
@@ -360,6 +382,7 @@ class _Registry:
         oid = None if owner is None else id(owner)
         dropped = 0
         touched: set[str] = set()
+        self._reap()
         with self._mu:
             for key in list(self._entries):
                 sub, key_oid, key_tag, _ = key
@@ -381,6 +404,7 @@ class _Registry:
         subsystems with live keys included — a released-to-zero
         subsystem disappears)."""
         out: dict[str, int] = {}
+        self._reap()
         with self._mu:
             for (sub, _, _, _), n in self._entries.items():
                 out[sub] = out.get(sub, 0) + n
@@ -389,6 +413,7 @@ class _Registry:
     def device_bytes(self) -> dict[str, int]:
         """Accounted bytes aggregated by device id ("" = device-less
         entries: single-device processes and unsharded leaves)."""
+        self._reap()
         with self._mu:
             out = self._device_bytes_locked()
         return dict(sorted(out.items()))
@@ -398,6 +423,7 @@ class _Registry:
                    if d == dev)
 
     def snapshot(self) -> dict[tuple[str, int, str, str], int]:
+        self._reap()
         with self._mu:
             return dict(self._entries)
 
@@ -531,6 +557,7 @@ class _Registry:
         device's leases. Returns ``nbytes``."""
         if _seam:  # _alloc_impl fires once for its whole share split
             self._fire_seam(subsystem, int(nbytes))
+        self._reap()
         need = int(nbytes)
         dev = str(device or "")
         key = (subsystem, id(owner) if owner is not None else 0, tag, dev)
@@ -660,6 +687,7 @@ class _Registry:
                     reclaim: Callable[[int], int] | None,
                     devices: "list[str] | None") -> Any:
         base = (subsystem, id(owner) if owner is not None else 0, tag)
+        self._reap()
         with self._mu:
             prior = {k: self._entries[k] for k in self._entries
                      if k[:3] == base}
@@ -738,6 +766,7 @@ class _Registry:
         its OWN reclaim pass (one hot shard spills without flushing
         the mesh) and a surviving per-device overshoot sheds too."""
         self._fire_seam(subsystem, 0)
+        self._reap()
         b = self._budget
         if b:
             with self._mu:
@@ -804,6 +833,7 @@ class _Registry:
         retry uses, where the transient deficit is unknowable and the
         alternative is shedding the whole batch. Returns bytes freed;
         lease/alloc/check run sized passes implicitly."""
+        self._reap()
         if nbytes is None:
             with self._mu:
                 need = sum(self._entries.get(k, 0)
@@ -919,6 +949,7 @@ class _Registry:
         (subsystem/tag/bytes/priority/reclaimable), and the reclaim/
         shed/retry counters — what /debug/vars, health_check and
         tools/hbm_report.py render."""
+        self._reap()
         with self._mu:
             entries = dict(self._entries)
             meta = dict(self._meta)
@@ -986,6 +1017,7 @@ class _Registry:
         counters (and zero pushed gauges)."""
         with self._mu:
             subs = {sub for (sub, _, _, _) in self._entries}
+            self._dead_owners.clear()
             self._entries.clear()
             self._meta.clear()
             self._reclaims.clear()
@@ -1038,12 +1070,15 @@ class _Registry:
         # per-device values AFTER another thread's explicit zeros —
         # re-creating exactly the phantom-in-use the zeros prevent
         with self._push_mu:
-            value = float(self.live_bytes().get(subsystem, 0))
             # devices whose entries vanished (engine closed, mesh
             # shrank) must push an explicit 0 — a gauge series that
             # just stops updating reads as phantom in-use forever (the
             # subsystem gauge's zero-on-release contract, per device)
             with self._mu:
+                # not live_bytes(): that reaps, and a reap pushes
+                value = float(sum(n for (sub, _, _, _), n
+                                  in self._entries.items()
+                                  if sub == subsystem))
                 per_dev = {d: n for d, n in
                            self._device_bytes_locked().items() if d}
                 gone = self._pushed_devs - set(per_dev)

@@ -541,7 +541,7 @@ class EnginePrograms:
         logits, small = self._fam.prefill_chunk(
             params, self.cfg, tokens, small, start,
             rope_tables=self.rope_tables, compute_logits=sample,
-            adapter=adapter,
+            adapter=adapter, mesh=self.mesh,
             logit_pos=jnp.asarray(pos_in_chunk)[None] if sample else None)
         written = jax.tree_util.tree_map(
             lambda a, s: jax.lax.dynamic_update_slice_in_dim(a, s, slot,

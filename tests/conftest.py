@@ -85,6 +85,36 @@ def pytest_unconfigure(config):
         watch.uninstall()
 
 
+# the longest tier-1 test takes 75 s of a loaded run (PERF.md, Findings PR 41)
+TEST_TIME_LIMIT_S = 300
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_protocol(item, nextitem):
+    """Every test's time limit, set-up and teardown included: past it
+    the interpreter writes every thread's stack to the run's stderr and
+    ends the process (``faulthandler``'s own thread, which needs no
+    GIL). Under xdist that is a crashed worker: the test is reported
+    failed by name, a new worker takes the rest of its batch and the
+    run ends. Without it a test that waits for ever (a consumer on a
+    queue whose producer is stuck in a device dispatch) holds its
+    worker's whole batch until the run's own limit cuts it, and the run
+    then names nothing."""
+    import faulthandler
+
+    from _pytest.faulthandler import fault_handler_stderr_fd_key
+
+    # pytest's copy of the real stderr: fd 2 is the capture's file here
+    fd = item.config.stash.get(fault_handler_stderr_fd_key, None)
+    faulthandler.dump_traceback_later(
+        TEST_TIME_LIMIT_S, exit=True,
+        file=fd if fd is not None else sys.__stderr__)
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _release_compiled_executables_between_modules():
     """Cap the process's memory-map count. Every compiled XLA executable

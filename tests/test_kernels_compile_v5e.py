@@ -16,7 +16,11 @@ one process at a time may load the TPU's library, and a worker that
 collects this file must not load it while it imports.
 """
 
+import json
+import math
+import os
 import re
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -28,10 +32,9 @@ L, B, SMAX, D = 32, 40, 2048, 128
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -47,10 +50,25 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", False)
     jax.config.update("jax_default_matmul_precision", None)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was[0])
     jax.config.update("jax_default_matmul_precision", was[1])
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tp4(v5e_2x2):
+    """The four chips as the mesh ``TPU_SHARDING=tp=4`` makes of them."""
+    from gofr_tpu import parallel
+
+    return parallel.make_mesh(tp=4, devices=v5e_2x2.devices)
 
 
 def _shapes(sharding, kv, dtype):
@@ -133,11 +151,26 @@ def test_kda_prefill_compiles(one_chip, tokens):
 
 # -- the llama family's programs: every projection reads its stack in place ----
 
-def _engine_lowered(monkeypatch, sharding, cfg, slots, kv_dtype, program):
+def _cell_config(name, **cut):
+    """``benchmarks/configs/<name>.json``'s ``model_config``, as the cell
+    runs it but for ``cut``."""
+    from gofr_tpu.models.common import ModelConfig
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           name + ".json")) as f:
+        return ModelConfig(**{**json.load(f)["model_config"], **cut})
+
+
+def _engine_lowered(monkeypatch, sharding, cfg, slots, kv_dtype, program,
+                    mesh=None):
     """``EnginePrograms``' own decode block, 256-token prefill or
     512-token chunk program for ``cfg``, lowered from shapes alone
     (nothing is allocated): int8 weights, ``slots`` x 2,048, the kernels
-    on (a CPU process answers no to ``tpu_backend_ok``)."""
+    on (a CPU process answers no to ``tpu_backend_ok``). On ``sharding``,
+    or on ``mesh`` with weights and cache sharded as an engine places
+    them and the rest replicated."""
+    from gofr_tpu import parallel
     from gofr_tpu.models import family
     from gofr_tpu.ops import flash
     from gofr_tpu.tpu import programs
@@ -147,21 +180,29 @@ def _engine_lowered(monkeypatch, sharding, cfg, slots, kv_dtype, program):
     fam = family(cfg)
     prog = programs.EnginePrograms(
         cfg, fam, object(), max_seq=SMAX, kv_dtype=kv_dtype,
-        decode_block=4, n_adapters=0, spec_k=0, paged=None, mesh=None)
+        decode_block=4, n_adapters=0, spec_k=0, paged=None, mesh=mesh)
     prog.describe("cache", slots)
     jits = prog.build()
+    if mesh is not None:
+        sharding = prog.placed.rep
 
-    def arr(shape, dt=jnp.int32):
+    def arr(shape, dt=jnp.int32, sharding=sharding):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
 
-    def described(build):
-        return jax.tree_util.tree_map(lambda s: arr(s.shape, s.dtype),
-                                      jax.eval_shape(build))
+    def described(build, shardings=None):
+        shapes = jax.eval_shape(build)
+        if shardings is None:
+            shardings = (parallel.shardings_for(shapes, mesh) if mesh
+                         else jax.tree_util.tree_map(lambda _: sharding,
+                                                     shapes))
+        return jax.tree_util.tree_map(
+            lambda s, sh: arr(s.shape, s.dtype, sh), shapes, shardings)
 
     params = described(lambda: maybe_quantize(
         fam.init(cfg, jax.random.PRNGKey(0)), True))
     cache = described(lambda: fam.init_cache(cfg, slots, SMAX,
-                                             dtype=kv_dtype))
+                                             dtype=kv_dtype),
+                      prog.placed.cache)
     key = arr((2,), jnp.uint32)
     if program == "decode block":
         b = arr((slots,))
@@ -174,7 +215,7 @@ def _engine_lowered(monkeypatch, sharding, cfg, slots, kv_dtype, program):
             cache, params, arr((1, 512)), arr(()), arr(()), arr(()), arr(()),
             arr((), jnp.float32), arr(()), key, arr(()), arr(()), None)
     return jits["_prefill_jit"].lower(
-        cache, params, arr((1, 256)), arr(()), arr(()),
+        cache, params, arr((1, int(program.split()[1]))), arr(()), arr(()),
         arr((), jnp.float32), arr(()), key, arr(()), arr(()))
 
 
@@ -215,20 +256,91 @@ def test_qk_projections_read_their_weights_in_place(one_chip, monkeypatch,
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
+# -- Mixtral's shard on the four chips: the experts' one reduction --------------
+
+class _Inst(NamedTuple):
+    """An instruction of a compiled module's text: its first output's
+    type and dimensions, its opcode, its first operand, its line."""
+    dtype: str
+    dims: list
+    opcode: str
+    operand: str
+    line: str
+
+
+def _lowered_tp4(monkeypatch, mesh, program):
+    """``benchmarks/configs/mixtral-8x7b-int8-tp4.json`` as its cell runs
+    it, cut to two layers: tp=4, an int8 cache, 40 slots."""
+    cfg = _cell_config("mixtral-8x7b-int8-tp4", n_layers=2)
+    return _engine_lowered(monkeypatch, None, cfg, B, jnp.int8, program,
+                           mesh=mesh)
+
+
+@pytest.mark.parametrize("program,tokens", [("decode block", 40),
+                                            ("prefill 512", 512),
+                                            ("chunk 512", 512)])
+def test_experts_are_combined_before_they_cross_the_chips(tp4, monkeypatch,
+                                                          program, tokens):
+    """``llama._combine_experts``, read off the compiled programs. (a) No
+    all-reduce carries the expert axis (the parent's was
+    ``f32[40,1,4096,8]``, 5.24 MB a layer where the sum is 0.66), and the
+    expert layer's one all-reduce adds float32 shares of [tokens, 4096]
+    in float32 (the compiler folds the cast to bfloat16 into its
+    output). (b) ``w_down`` is read where it lies: no copy or transpose
+    of an int8 expert stack or of a layer's slice of one (a region that
+    left the one-device axes to GSPMD made the prefill programs
+    transpose 117 MB a layer), no bfloat16 copy of the experts, and the
+    temporaries are megabytes (the parent's prefill held 70). (c) The
+    fusion that streams ``w_down`` takes the int8 stack itself, slice
+    and convert inside it (PERF.md, Findings PR 41)."""
+    compiled = _lowered_tp4(monkeypatch, tp4, program).compile()
+    text = compiled.as_text()
+    _, own, _ = _outside_conditionals(text)
+    insts = {}
+    for ln in own:
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \(?(\w+)\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(%?([\w.\-]*)", ln)
+        if m:
+            name, dtype, dims, opcode, operand = m.groups()
+            insts[name] = _Inst(dtype, [int(d) for d in dims.split(",") if d],
+                                opcode, operand, ln)
+    expert_slice = 8 * 3584 * 4096      # a layer's w_down a chip
+
+    # (a)
+    reduces = [i for i in insts.values() if i.opcode.startswith("all-reduce")]
+    assert len(reduces) >= 3            # embedding, wo, experts
+    assert not [i[:3] for i in reduces if 8 in i.dims]
+    experts = [i for i in reduces if "/moe_experts/" in i.line]
+    assert len(experts) == 1
+    shares = insts[experts[0].operand]
+    assert math.prod(shares.dims) == tokens * 4096
+    assert shares.dtype == "f32" and shares.dims == experts[0].dims
+    region = re.search(r"to_apply=%?([\w.\-]+)", experts[0].line).group(1)
+    assert re.search(rf"^%?{re.escape(region)} \([\w.]+: f32\[\], "
+                     rf"[\w.]+: f32\[\]\) -> f32\[\]", text, re.M)
+    # (b)
+    moved = [i[:3] for i in insts.values() if i.dtype in ("s8", "bf16")
+             and math.prod(i.dims) >= expert_slice
+             and i.opcode in ("copy", "transpose", "fusion", "convert")]
+    assert not moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    # (c)
+    down = [i for i in insts.values() if i.opcode == "fusion"
+            and "/moe_experts/" in i.line and "efd->bsed" in i.line]
+    assert len(down) == 1
+    operands = re.search(r" fusion\(([^)]*)\)", down[0].line).group(1)
+    stacks = [insts[o] for o in re.findall(r"%([\w.\-]+)", operands)]
+    assert [i for i in stacks
+            if (i.dtype, i.dims) == ("s8", [2, 8, 3584, 4096])]
+    assert not [i[:3] for i in stacks if i.dtype == "bf16"
+                and math.prod(i.dims) >= expert_slice]
+
 # -- the window family's programs at the published widths ----------------------
 
 def _lowered_window(monkeypatch, sharding, program):
     """``benchmarks/configs/laguna-xs.2-int8-pp5.json`` as its cell runs
     it: eight layers, bfloat16 rows and rings, 128 slots."""
-    import json
-    import os
-
-    from gofr_tpu.models.common import ModelConfig
-
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(here, "benchmarks", "configs",
-                           "laguna-xs.2-int8-pp5.json")) as f:
-        cfg = ModelConfig(**json.load(f)["model_config"])
+    cfg = _cell_config("laguna-xs.2-int8-pp5")
     return _engine_lowered(monkeypatch, sharding, cfg, 128, None, program)
 
 
@@ -296,15 +408,7 @@ def test_window_family_reads_weights_and_rings_in_place(one_chip, monkeypatch,
 def _lowered_conv(monkeypatch, sharding, program):
     """``benchmarks/configs/lfm2-24b-a2b-int8-pp2.json`` as its cell runs
     it: twenty layers, bfloat16 rows two KV heads a row, 96 slots."""
-    import json
-    import os
-
-    from gofr_tpu.models.common import ModelConfig
-
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(here, "benchmarks", "configs",
-                           "lfm2-24b-a2b-int8-pp2.json")) as f:
-        cfg = ModelConfig(**json.load(f)["model_config"])
+    cfg = _cell_config("lfm2-24b-a2b-int8-pp2")
     return _engine_lowered(monkeypatch, sharding, cfg, 96, None, program)
 
 

@@ -100,6 +100,51 @@ def test_dead_owner_without_close_releases_on_gc():
     assert hbm.live_bytes() == {}
 
 
+@pytest.mark.parametrize("held", ["table", "push", "sink"])
+def test_owner_collected_under_a_lock_of_its_own_thread_does_not_wait(held):
+    # the collector runs an owner's finalizer on whichever thread it
+    # interrupts: one inside the registry's critical sections, or a
+    # sink's. None of those locks is re-entrant, so a finalizer that
+    # takes one waits for its own thread: a tier-1 worker stood so in
+    # arbiter_stats (health_check) until the run's limit cut it (PR 41)
+    import threading
+
+    hbm.reset()
+    m = Manager()
+    register_framework_metrics(m)
+    hbm.set_metrics(m)
+
+    class Owner:
+        pass
+
+    alive = [Owner()]
+    hbm.lease("engine", 32, owner=alive[0], reclaim=lambda need: 0)
+    hbm.account("engine", np.zeros((8,), np.float32), owner=alive[0])
+    lock = {"table": hbm._registry._mu, "push": hbm._registry._push_mu,
+            "sink": m._get(hbm.GAUGE, "gauge").lock}[held]
+    out = threading.Event()
+
+    def inside():
+        with lock:
+            alive.clear()       # the last reference: finalized here
+        out.set()
+
+    t = threading.Thread(target=inside, daemon=True)
+    t.start()
+    t.join(10.0)
+    try:
+        assert out.is_set(), f"the finalizer waited for the {held} lock"
+        # the next call from outside drops the entries, the lease with
+        # them, and pushes the subsystem's gauge
+        assert hbm.live_bytes() == {}
+        assert hbm.arbiter_stats()["leases"] == []
+        assert 'app_tpu_device_bytes{subsystem="engine"} 0' \
+            in m.render_prometheus()
+    finally:
+        hbm.set_metrics(None)
+        hbm.reset()
+
+
 def test_two_metrics_sinks_both_receive_pushes():
     # two engines with two Managers (A/B serving, tests): registering
     # B must not stop A's exporter from seeing later changes

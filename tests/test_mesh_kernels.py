@@ -169,11 +169,30 @@ def test_append_rows_sharded_matches_scatter(tp, dp, kv, dtype):
 
 # -- token exactness: contiguous engine ---------------------------------------
 
+@pytest.fixture
+def compiled_not_loaded():
+    """The persistent compile cache off for one test. XLA's CPU backend
+    compiles the dp=4 x tp=2 engine's decode block right and loads it
+    wrong: read back from ``.jax_cache``, the eight devices' threads
+    take the step's all-reduces over ``tp`` and over ``dp`` in different
+    orders, wait for each other in the rendezvous, and XLA aborts the
+    process after 60 s (ROADMAP.md, D7). The chip's backend is not
+    known to."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("kv_dtype", [None, jnp.int8])
 @pytest.mark.parametrize("tp,kv", [(2, 2), (4, 4), (4, 8)])
 def test_mesh_contiguous_token_exact(tp, kv, kv_dtype, tiny_params,
                                      tiny4_params, tiny8_params,
-                                     monkeypatch):
+                                     monkeypatch, compiled_not_loaded):
     """shard_map'd flash prefill + flash-decode (each device walking its
     own KV-head and batch shard of the stacked cache) and the step's
     sharded write on a dp x tp mesh are token-exact vs the

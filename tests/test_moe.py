@@ -339,3 +339,129 @@ def test_grouped_moe_decode_preserves_slot_isolation(moe_params):
         else:
             np.testing.assert_allclose(np.asarray(logits[1]), base,
                                        rtol=1e-6, atol=1e-6)
+
+
+# -- the dense dispatch's one collective on a tp mesh (llama._combine_experts) --
+
+# eight experts beside a batch of 5 x 3 and widths of 64 and 128 / 4: an
+# 8 in a compiled collective's shape is the expert axis and nothing else
+TP = MOE.with_(name="tiny-moe-tp", n_experts=8)
+TP_B, TP_S = 5, 3
+COLLECTIVE = (r"= \(?(\w+\[[\d,]*\])\S* (all-reduce|all-gather|"
+              r"reduce-scatter|all-to-all|collective-permute)(?:-start)?\(")
+
+
+def _tp_layers(stacks: str, dtype: str):
+    """``TP``'s layer stacks with the experts in bfloat16 or int8, the
+    activations in ``dtype``, and an input [5, 3, 64] of that type."""
+    from gofr_tpu.tpu.checkpoint import maybe_quantize
+
+    cfg = TP.with_(dtype=dtype)
+    layers = llama.init(TP.with_(dtype="bfloat16"),
+                        jax.random.PRNGKey(11))["layers"]
+    experts = {k: layers[k] for k in ("w_gate", "w_up", "w_down")}
+    layers = {**layers, **maybe_quantize(experts, stacks == "int8")}
+    h = jax.random.normal(jax.random.PRNGKey(12), (TP_B, TP_S, TP.dim),
+                          jnp.float32).astype(cfg.jdtype)
+    return cfg, layers, h
+
+
+def _moe_layer0(cfg, mesh=None):
+    """jit of ``_moe_ffn`` on layer 0 of the stacks, which arrive
+    sharded as ``parallel.shard_params`` places them."""
+    def run(layers, h):
+        lw = jax.tree_util.tree_map(lambda a: a[0], layers)
+        return llama._moe_ffn(h, lw, cfg, None, mesh)[0]
+
+    return jax.jit(run)
+
+
+def _tp4():
+    from gofr_tpu import parallel
+
+    return parallel.make_mesh(tp=4, devices=jax.devices()[:4])
+
+
+@pytest.mark.parametrize("stacks", ["int8", "bfloat16"])
+def test_tp_experts_cross_the_chips_combined(stacks):
+    """Compiled for tp=4, the expert layer holds one collective: the
+    all-reduce of [B, S, D] float32 shares. Left to GSPMD it was
+    ``f32[5,3,8,64]``, straight after ``w_down``."""
+    import re
+
+    from gofr_tpu import parallel
+
+    cfg, layers, h = _tp_layers(stacks, "bfloat16")
+    mesh = _tp4()
+    text = _moe_layer0(cfg, mesh).lower(
+        parallel.shard_params(layers, mesh), h).compile().as_text()
+    found = re.findall(COLLECTIVE, text)
+    assert found == [(f"f32[{TP_B},{TP_S},{TP.dim}]", "all-reduce")]
+
+
+@pytest.mark.parametrize("stacks", ["int8", "bfloat16"])
+def test_tp_experts_equal_the_unsharded_layer(stacks):
+    """Float32 activations, so that the one rounding hides nothing: the
+    four chips' shares add up to the unsharded sum over ``f`` but for
+    the order of a float32 addition."""
+    from gofr_tpu import parallel
+
+    cfg, layers, h = _tp_layers(stacks, "float32")
+    mesh = _tp4()
+    want = _moe_layer0(cfg)(layers, h)
+    got = _moe_layer0(cfg, mesh)(parallel.shard_params(layers, mesh), h)
+    assert got.dtype == want.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
+def _plain_mixtral_ffn(h, lw, k: int):
+    """Float32 ``jax.numpy`` Mixtral block: softmax over the router's
+    logits, top-k, renormalise, SwiGLU an expert, the weighted sum."""
+    from gofr_tpu.ops.quant import QuantizedLinear
+
+    def f32(w):
+        if isinstance(w, QuantizedLinear):
+            return w.w.astype(jnp.float32) * w.scale[..., None, :]
+        return w.astype(jnp.float32)
+
+    h = h.astype(jnp.float32)
+    probs = jax.nn.softmax(h @ f32(lw["router"]), axis=-1)
+    topv, topi = jax.lax.top_k(probs, k)
+    topv = topv / topv.sum(-1, keepdims=True)
+    gate, up, down = (f32(lw[n]) for n in ("w_gate", "w_up", "w_down"))
+    out = jnp.zeros_like(h)
+    for e in range(gate.shape[0]):
+        y = (jax.nn.silu(h @ gate[e]) * (h @ up[e])) @ down[e]
+        out += jnp.where(topi == e, topv, 0.0).sum(-1)[..., None] * y
+    return out
+
+
+@pytest.mark.parametrize("stacks", ["int8", "bfloat16"])
+def test_tp_experts_no_further_from_plain_float32(stacks, monkeypatch):
+    """Against the plain layer the new tail, on the mesh, is where the
+    parent's was (each expert's output cast to the activations' type,
+    then the weighted sum). In float32, as XLA's CPU backend has no
+    batched bfloat16 x bfloat16 = float32 dot to run either with: the
+    one rounding to bfloat16 where the parent made nine is the chip's."""
+    from gofr_tpu import parallel
+
+    def cast_an_expert(gated, w_down, combine, mesh):
+        out = llama._expert_mm(gated, w_down, "bsef,efd->bsed")
+        return jnp.einsum("bsed,bse->bsd", out, combine.astype(out.dtype))
+
+    cfg, layers, h = _tp_layers(stacks, "float32")
+    mesh = _tp4()
+    want = _plain_mixtral_ffn(
+        h, jax.tree_util.tree_map(lambda a: a[0], layers),
+        cfg.experts_per_token)
+    got = _moe_layer0(cfg, mesh)(parallel.shard_params(layers, mesh), h)
+    monkeypatch.setattr(llama, "_combine_experts", cast_an_expert)
+    was = _moe_layer0(cfg)(layers, h)
+
+    def off(x):
+        return float(jnp.abs(x - want).max() / jnp.abs(want).max())
+
+    print(f"max |layer - plain| / max |plain|: {off(got):.3g} on the "
+          f"mesh, {off(was):.3g} the parent's tail")
+    assert 0 < off(got) <= max(off(was), 1e-6) < 1e-5
