@@ -356,7 +356,56 @@ def kernel_cases(interpret: bool = False):
             return max(_max_err(o, want_o), _max_err(s1, want_s))
         return run
 
-    def experts(layers, held, dim, ffn, top_k=8, slots=128):
+    def ssd_inputs(b, t, seed):
+        # Mamba-2's published sizes: 128 heads of 64 in 8 groups, state 128
+        from gofr_tpu.ops import ssd
+        heads, groups, n, r = 128, 8, 128, 1024
+        ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+        delta = jax.nn.softplus(jax.random.normal(ks[0], (b, t, heads)) - 3)
+        la = -jnp.exp(jax.random.uniform(ks[1], (heads,), jnp.float32, 0.0,
+                                         2.8)) * delta
+        dx = jax.random.normal(ks[2], (b, t, groups, r)) \
+            * ssd._rows(delta, groups, r)
+        bm, cm = (jax.random.normal(k, (b, t, groups, n)) for k in ks[3:])
+        return dx, la, bm, cm
+
+    def ssd_decode():
+        # the state-space decode kernel at the cell's sizes (96 slots, 10
+        # mamba layers: 4 GB of state), a quarter of the slots idle: the
+        # written layer's active states against the jnp recurrence, every
+        # other byte against itself
+        from gofr_tpu.ops import ssd
+        lm, slots = 10, 96
+        dx, la, bm, cm = (a[:, 0] for a in ssd_inputs(slots, 1, 60))
+        state = jax.random.normal(jax.random.PRNGKey(61),
+                                  (lm, slots, 8, 128, 1024), jnp.float32)
+        active = jnp.arange(slots) % 4 != 1
+        before = [jnp.sum(jnp.abs(state[i])) for i in range(lm)]
+        want_y, want_s = ssd.recurrent_ref(dx[:, None], la[:, None],
+                                           bm[:, None], cm[:, None], state[3])
+        want_s = jnp.where(active[:, None, None, None], want_s, state[3])
+        y, state = ssd.ssd_decode(state, jnp.int32(3), dx, la, bm, cm, active,
+                                  interpret=interpret)
+        moved = max(float(jnp.abs(jnp.sum(jnp.abs(state[i])) - before[i]))
+                    for i in range(lm) if i != 3)
+        return max(_max_err(y, want_y[:, 0], active[:, None, None]),
+                   _max_err(state[3], want_s), moved)
+
+    def ssd_prefill(t):
+        def run():
+            # the chunk kernel (chunks of 128, the published chunk_size)
+            # against the token-by-token recurrence, from a state
+            from gofr_tpu.ops import ssd
+            dx, la, bm, cm = ssd_inputs(1, t, 62)
+            s0 = jax.random.normal(jax.random.PRNGKey(63), (1, 8, 128, 1024),
+                                   jnp.float32)
+            y, s1 = ssd.ssd_prefill(dx, la, bm, cm, s0, chunk=128,
+                                    interpret=interpret)
+            want_y, want_s = ssd.recurrent_ref(dx, la, bm, cm, s0)
+            return max(_max_err(y, want_y), _max_err(s1, want_s))
+        return run
+
+    def experts(layers, held, dim, ffn, top_k=8, slots=128, gated=True):
         def run():
             # the routed experts' kernel at a cell's decode shapes (128
             # slots x top-8 in blocks of 16 rows, or 96 x top-4 where an
@@ -383,17 +432,22 @@ def kernel_cases(interpret: bool = False):
                     jnp.float32, 0.5, 1.5) / (74.0 * n_in ** 0.5)
                 return QuantizedLinear(w, scale)
 
-            stacks = {"w_gate": stack(50, dim, ffn),
-                      "w_up": stack(52, dim, ffn),
+            # ``dim`` is the width the experts read: the model's, or a
+            # latent's; without a gate an expert is w_down relu(w_up x)^2
+            stacks = {"w_up": stack(52, dim, ffn),
                       "w_down": stack(54, ffn, dim)}
+            if gated:
+                stacks["w_gate"] = stack(50, dim, ffn)
             live = held - 2
             blk = jnp.minimum(jnp.arange(rows // bm, dtype=jnp.int32) + 1,
                               held - 1)
             xs = rand(56, (rows, dim))
             li, n = jnp.int32(layers - 2), jnp.int32(live)
+            leaves = [stacks.get(k) for k in ds.EXPERT_STACKS]
             got = moe_experts.expert_blocks_stacked(
-                xs, blk, n, li, *(stacks[k].w for k in ds.EXPERT_STACKS),
-                *(stacks[k].scale for k in ds.EXPERT_STACKS),
+                xs, blk, n, li,
+                *(None if a is None else a.w for a in leaves),
+                *(None if a is None else a.scale for a in leaves),
                 block_rows=bm, interpret=interpret)
             ref = jax.jit(ds._blocks_loop, static_argnums=5)(
                 xs, blk, n, stacks, li, bm)
@@ -409,6 +463,11 @@ def kernel_cases(interpret: bool = False):
              experts(8, 16, 7168, 2048)),
             ("expert_blocks_stacked[int8,4x64x2048x1536,tile=768]",
              experts(4, 64, 2048, 1536, top_k=4, slots=96)),
+            ("expert_blocks_stacked[int8,10x128x1024x2688,relu2]",
+             experts(10, 128, 1024, 2688, top_k=22, slots=96, gated=False)),
+            ("ssd_decode[f32,10x96x8x128x1024]", ssd_decode),
+            ("ssd_prefill[f32,T=128]", ssd_prefill(128)),
+            ("ssd_prefill[f32,T=512]", ssd_prefill(512)),
             ("flash_decode_stacked[bf16,5x96x4x2048x128,hd=64 paired]",
              decode_pairs()),
             ("append_rows_stacked[bf16,hd=64 paired]", append_pairs()),

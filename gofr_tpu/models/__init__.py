@@ -9,7 +9,8 @@ layer axis. No torch, no module classes — params are data, which is what
 """
 
 from .common import ModelConfig, LLAMA_CONFIGS, BERT_CONFIGS, VIT_CONFIGS
-from . import llama, bert, vit, deepseek_v3, solar_open2, laguna, lfm2
+from . import (llama, bert, vit, deepseek_v3, solar_open2, laguna, lfm2,
+               nemotron_h)
 
 
 def family(cfg: ModelConfig):
@@ -21,8 +22,10 @@ def family(cfg: ModelConfig):
     ``write_kv``, ``prefill_chunk``, ``decode_step``, ``decode_kv_block``,
     ``kv_layout``, ``unsupported_options``, ``serving_stats``, ``forward``,
     and ``RECOMPUTABLE``: whether a cached position can be computed
-    again and give the same memory (rows can; a recurrent state, a
-    ring of rows and a convolution's tail cannot)."""
+    again and give the same memory (rows can; a recurrent or
+    state-space state, a ring of rows and a convolution's tail cannot)."""
+    if "mamba" in cfg.layer_pattern:
+        return nemotron_h
     if "linear" in cfg.layer_pattern:
         return solar_open2
     if "window" in cfg.layer_pattern:
@@ -34,4 +37,4 @@ def family(cfg: ModelConfig):
 
 __all__ = ["ModelConfig", "LLAMA_CONFIGS", "BERT_CONFIGS", "VIT_CONFIGS",
            "llama", "bert", "vit", "deepseek_v3", "solar_open2", "laguna",
-           "lfm2", "family"]
+           "lfm2", "nemotron_h", "family"]

@@ -100,6 +100,29 @@ class ModelConfig:
     # and k are RMS-normed a head (weights of head_dim) before the
     # rotation
     qk_norm: bool = False
+    # state-space layers beside attention and expert layers, ONE block a
+    # layer (models/nemotron_h.py; a "mamba" entry in layer_pattern
+    # selects that family, and there the pattern names every layer, so a
+    # stack that does not tile is one period of n_layers entries: "mamba",
+    # "moe" or "attn", each x += Block(RMSNorm(x))). A mamba layer is
+    # Mamba-2: ssm_heads heads of ssm_head_dim, B and C shared by the
+    # heads of one of ssm_groups groups, a state of ssm_state values a
+    # channel, a causal depthwise convolution over conv_kernel inputs
+    # with a bias, prompts run in chunks of ssm_chunk. A moe layer routes
+    # as the latent family does, but where moe_latent_dim > 0 the routed
+    # experts read and write a latent of that width (one projection down
+    # before the dispatch, one up after the weighted sum); expert_act is
+    # the experts' form, "swiglu" (three matrices) or "relu2" (two:
+    # W2 relu(W1 x)^2, no gate), the shared expert's too, whose width is
+    # shared_ffn_dim (0 = moe_ffn_dim x n_shared_experts)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_chunk: int = 128
+    moe_latent_dim: int = 0
+    expert_act: str = "swiglu"
+    shared_ffn_dim: int = 0
 
     def __post_init__(self):
         # a configuration file gives the pattern as a list
@@ -201,6 +224,23 @@ LLAMA_CONFIGS = {
         conv_kernel=3, qk_norm=True, n_experts=8, experts_per_token=2,
         n_expert_groups=1, topk_groups=1, routed_scaling=1.0,
         n_shared_experts=0, moe_ffn_dim=40, n_dense_layers=1),
+    # the state-space family at test size: eleven layers of three kinds
+    # in an order that does not tile (as the published string does not),
+    # 8 heads of 8 in 2 groups (G < H), a state of 16, chunks of 8, a
+    # latent of 24 behind a 16-way router with 4 experts held, two-matrix
+    # relu2 experts of width 40 and a shared one of 56
+    "tiny-ssm-moe": ModelConfig(
+        name="tiny-ssm-moe", vocab_size=256, dim=64, n_layers=11, n_heads=4,
+        n_kv_heads=2, ffn_dim=128, max_seq=128, norm_eps=1e-5,
+        dtype="float32",
+        layer_pattern=("mamba", "moe", "mamba", "moe", "mamba", "attn",
+                       "moe", "mamba", "moe", "attn", "mamba"),
+        use_rope=False, attn_head_dim=24, conv_kernel=4, ssm_heads=8,
+        ssm_head_dim=8, ssm_groups=2, ssm_state=16, ssm_chunk=8,
+        n_experts=16, experts_per_token=4, n_expert_groups=1, topk_groups=1,
+        routed_scaling=2.5, n_shared_experts=1, moe_ffn_dim=40,
+        n_experts_held=4, moe_latent_dim=24, expert_act="relu2",
+        shared_ffn_dim=56),
 }
 
 BERT_CONFIGS = {

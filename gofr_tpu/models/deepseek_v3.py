@@ -65,6 +65,9 @@ from ..ops.rope import apply_rope, rope_frequencies, yarn_softmax_scale
 from .common import ModelConfig, dense_init
 from .llama import _logits
 
+# the leaves of a routed expert [Ls, Eh, ...]: all three a SwiGLU, the
+# last two a two-matrix relu^2 expert (``expert_stacks``)
+EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 _LANES = 128
 _ROPE_CACHE: dict[tuple, tuple] = {}
 RECOMPUTABLE = True  # rows, as llama's: see models.family
@@ -167,21 +170,26 @@ def unsupported_options(*, mesh=None, paged_blocks: int = 0, kvcache=None,
 def init_routed(keys, cfg: ModelConfig, L: int) -> dict:
     """Random-init leaves of ``L`` routed feed-forwards (what ``moe_ffn``
     reads), one key of ``keys`` a leaf in this order: the router
-    ``n_experts`` wide and its bias whole, ``n_held`` experts, and the
-    shared experts' leaves where the configuration has any."""
-    dt, D = cfg.jdtype, cfg.dim
+    ``n_experts`` wide and its bias whole, ``n_held`` experts in their
+    form (``expert_stacks``) and width (``expert_width``), the shared
+    experts' leaves where the configuration has any, and the latent's
+    two projections where it has one."""
+    dt, D, Dx = cfg.jdtype, cfg.dim, expert_width(cfg)
     E, Eh, Fm = cfg.n_experts, n_held(cfg), cfg.moe_ffn_dim
-    Fs = Fm * cfg.n_shared_experts
+    Fs = cfg.shared_ffn_dim or Fm * cfg.n_shared_experts
     w = {"router": dense_init(next(keys), (L, D, E), dt),
          "router_bias": 0.01 * jax.random.normal(next(keys), (L, E),
-                                                 jnp.float32),
-         "w_gate": dense_init(next(keys), (L, Eh, D, Fm), dt),
-         "w_up": dense_init(next(keys), (L, Eh, D, Fm), dt),
-         "w_down": dense_init(next(keys), (L, Eh, Fm, D), dt)}
+                                                 jnp.float32)}
+    for name in expert_stacks(cfg):
+        shape = (L, Eh, Fm, Dx) if name == "w_down" else (L, Eh, Dx, Fm)
+        w[name] = dense_init(next(keys), shape, dt)
     if Fs:
-        w.update(ws_gate=dense_init(next(keys), (L, D, Fs), dt),
-                 ws_up=dense_init(next(keys), (L, D, Fs), dt),
-                 ws_down=dense_init(next(keys), (L, Fs, D), dt))
+        for name in expert_stacks(cfg):
+            shape = (L, Fs, D) if name == "w_down" else (L, D, Fs)
+            w["ws" + name[1:]] = dense_init(next(keys), shape, dt)
+    if cfg.moe_latent_dim:
+        w.update(w_latent_down=dense_init(next(keys), (L, D, Dx), dt),
+                 w_latent_up=dense_init(next(keys), (L, Dx, D), dt))
     return w
 
 
@@ -258,6 +266,23 @@ def _swiglu(x, gate, up, down):
     return qmatmul(jax.nn.silu(qmatmul(x, gate)) * qmatmul(x, up), down)
 
 
+def _relu2(x, up, down):
+    """The two-matrix expert: ``W2 relu(W1 x)^2``, no gate."""
+    return qmatmul(jnp.square(jax.nn.relu(qmatmul(x, up))), down)
+
+
+def expert_width(cfg: ModelConfig) -> int:
+    """The width the routed experts read and write, and so the width of
+    the dispatch: a latent's where the configuration has one, else the
+    model's."""
+    return cfg.moe_latent_dim or cfg.dim
+
+
+def expert_stacks(cfg: ModelConfig) -> tuple[str, ...]:
+    """The leaves of one routed expert, by its form."""
+    return EXPERT_STACKS if cfg.expert_act == "swiglu" else EXPERT_STACKS[1:]
+
+
 def expert_dispatch(cfg: ModelConfig, tokens: int) -> tuple[int, int]:
     """(rows of a dispatch block, rows of the padded dispatch buffer) for
     ``tokens`` tokens. A block is one bfloat16 sublane tile for a decode
@@ -275,8 +300,9 @@ def experts_on_kernel(cfg: ModelConfig, dtype=None) -> bool:
     """Whether ``_experts`` runs its blocks through the kernel of
     ``ops/moe_experts.py`` (chosen from backend, widths and the
     activations' type) or through the jnp loop it is tested against."""
-    return moe_experts.kernel_ok(cfg.dim, cfg.moe_ffn_dim,
-                                 dtype or cfg.jdtype)
+    return moe_experts.kernel_ok(expert_width(cfg), cfg.moe_ffn_dim,
+                                 dtype or cfg.jdtype,
+                                 len(expert_stacks(cfg)))
 
 
 def serving_stats(cfg: ModelConfig, slots: int) -> dict:
@@ -285,19 +311,19 @@ def serving_stats(cfg: ModelConfig, slots: int) -> dict:
     shapes (the device operations that tall are the routed experts':
     benchmarks/metrics reads them here) and the path its blocks take."""
     bm, rows = expert_dispatch(cfg, slots)
-    said = {"block_rows": bm, "buffer_rows": rows, "path": "loop"}
+    said = {"block_rows": bm, "buffer_rows": rows,
+            "width": expert_width(cfg), "path": "loop"}
     if experts_on_kernel(cfg):
         # columns of the expert width a grid step takes, by the weights'
         # type: fewer than the width where an expert's tiles are over
         # the kernel's budget
         said.update(path="kernel", tile_columns={
-            name: moe_experts.tile_columns(cfg.dim, cfg.moe_ffn_dim, size)
+            name: moe_experts.tile_columns(expert_width(cfg),
+                                           cfg.moe_ffn_dim, size,
+                                           len(expert_stacks(cfg)))
             for name, size in (("int8", 1),
                                (cfg.dtype, cfg.jdtype.itemsize))})
     return {"moe_decode_dispatch": said}
-
-
-EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
 def _blocks_loop(xs, blk_expert, n_blocks, stacks, li, bm: int):
@@ -316,8 +342,9 @@ def _blocks_loop(xs, blk_expert, n_blocks, stacks, li, bm: int):
     def body(j, out):
         e = blk_expert[j]
         x = jax.lax.dynamic_slice_in_dim(xs, j * bm, bm, axis=0)
-        y = _swiglu(x, at(stacks["w_gate"], e), at(stacks["w_up"], e),
-                    at(stacks["w_down"], e))
+        up, down = at(stacks["w_up"], e), at(stacks["w_down"], e)
+        y = _swiglu(x, at(stacks["w_gate"], e), up, down) \
+            if "w_gate" in stacks else _relu2(x, up, down)
         return jax.lax.dynamic_update_slice_in_dim(out, y, j * bm, axis=0)
 
     return jax.lax.fori_loop(0, n_blocks, body, jnp.zeros_like(xs))
@@ -328,11 +355,13 @@ def _blocks_kernel(xs, blk_expert, n_blocks, stacks, li, bm: int,
     """``_blocks_loop`` as one kernel over the blocks that exist (``tile``:
     columns of the expert width a grid step takes, from the shapes
     unless a test says)."""
-    leaves = [stacks[name] for name in EXPERT_STACKS]
+    # a stack without a gate hands the kernel None in its place
+    leaves = [stacks.get(name) for name in EXPERT_STACKS]
     scales = ()
-    if isinstance(leaves[0], QuantizedLinear):
-        scales = tuple(leaf.scale for leaf in leaves)
-        leaves = [leaf.w for leaf in leaves]
+    if isinstance(leaves[-1], QuantizedLinear):
+        scales = tuple(None if leaf is None else leaf.scale
+                       for leaf in leaves)
+        leaves = [None if leaf is None else leaf.w for leaf in leaves]
     return moe_experts.expert_blocks_stacked(
         xs, blk_expert, n_blocks, li, *leaves, *scales, block_rows=bm,
         tile=tile, interpret=interpret_env())
@@ -342,8 +371,11 @@ def _blocks_kernel(xs, blk_expert, n_blocks, stacks, li, bm: int,
 def _experts(hf, topi, w, stacks, li, cfg: ModelConfig, valid=None):
     """Sum over the HELD experts each token chose, weighted.
 
-    hf [T, D]; topi/w [T, k] from ``route``; stacks: the routed stack's
-    expert weights WHOLE, [Ls, Eh, ...], and ``li`` the layer's index in
+    hf [T, D]: what the experts read, D the dispatch's width (the
+    model's, or a latent's: ``expert_width``); topi/w [T, k] from
+    ``route``; stacks: the routed stack's expert weights WHOLE,
+    [Ls, Eh, ...] (with ``w_gate``: SwiGLU; without: relu^2), and ``li``
+    the layer's index in
     them (a block's matmul reads expert (li, e) in place; handed the
     layer's slice, the layer loop copies all Eh experts out of the stack
     every layer, every step: 18.7 of a 36.5 ms step, PERF.md Findings
@@ -354,7 +386,7 @@ def _experts(hf, topi, w, stacks, li, cfg: ModelConfig, valid=None):
     The assignments are sorted by expert (absent experts and invalid
     rows last); expert e's rows start at a multiple of ``block`` in a
     padded buffer, so every block of it is one expert's; the blocks that
-    hold rows run one SwiGLU each, in one kernel
+    hold rows run one expert each, in one kernel
     (``ops.moe_experts.expert_blocks_stacked``) or, where that cannot
     run (``experts_on_kernel``), a while loop of the same arithmetic."""
     T, D = hf.shape
@@ -404,15 +436,28 @@ def _experts(hf, topi, w, stacks, li, cfg: ModelConfig, valid=None):
 def moe_ffn(h, lw, cfg: ModelConfig, valid=None):
     """The routed feed-forward of one layer: h [B, S, D] ->
     (y [B, S, D], assignments a held expert [Eh]). ``lw["experts"]`` is
-    (the expert stacks whole, this layer's index in them)."""
+    (the expert stacks whole, this layer's index in them). The router
+    reads the full width; where the layer has a latent
+    (``w_latent_down``/``w_latent_up``) the experts read it, projected
+    down once a token before the dispatch, and their weighted sum is
+    projected up once a token after it."""
     B, S, D = h.shape
     hf = h.reshape(B * S, D)
     topi, w = route(hf, lw["router"], lw["router_bias"], cfg)
-    y, counts, _ = _experts(hf, topi, w, *lw["experts"], cfg,
+    xe = hf
+    if "w_latent_down" in lw:
+        with jax.named_scope("moe/latent_down"):
+            xe = qmatmul(hf, lw["w_latent_down"])
+    y, counts, _ = _experts(xe, topi, w, *lw["experts"], cfg,
                             None if valid is None else valid.reshape(B * S))
-    if "ws_gate" in lw:     # n_shared_experts 0: no leaves, nothing added
+    if "w_latent_up" in lw:
+        with jax.named_scope("moe/latent_up"):
+            y = qmatmul(y, lw["w_latent_up"])
+    if "ws_up" in lw:     # n_shared_experts 0: no leaves, nothing added
         with jax.named_scope("moe/shared"):
-            y = y + _swiglu(hf, lw["ws_gate"], lw["ws_up"], lw["ws_down"])
+            y = y + (_swiglu(hf, lw["ws_gate"], lw["ws_up"], lw["ws_down"])
+                     if "ws_gate" in lw
+                     else _relu2(hf, lw["ws_up"], lw["ws_down"]))
     return y.reshape(B, S, D), counts
 
 

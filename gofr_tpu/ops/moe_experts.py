@@ -1,6 +1,7 @@
-"""Pallas TPU kernel for the routed experts: one SwiGLU a dispatch block,
-over the blocks that exist, the next block's weights in flight while
-this one multiplies.
+"""Pallas TPU kernel for the routed experts: one expert a dispatch block
+(SwiGLU of three matrices, or the two-matrix ``W2 relu(W1 x)^2`` where
+the stacks hold no gate), over the blocks that exist, the next block's
+weights in flight while this one multiplies.
 
 ``models.deepseek_v3._experts`` sorts a step's (token, held expert)
 assignments into a padded buffer ``xs [rows, D]`` of ``block_rows``-row
@@ -59,21 +60,23 @@ _TILE_BUDGET = 16 << 20
 _MAX_EXPERT_WEIGHTS = 32 << 20
 
 
-def tile_columns(dim: int, ffn: int, itemsize: int) -> int:
+def tile_columns(dim: int, ffn: int, itemsize: int, stacks: int = 3) -> int:
     """Columns of the expert width F a grid step takes: the largest
-    divisor of ``ffn`` in whole lanes whose three double-buffered tiles
-    fit ``_TILE_BUDGET`` (at least one lane group)."""
+    divisor of ``ffn`` in whole lanes whose ``stacks`` (three, or two
+    without a gate) double-buffered tiles fit ``_TILE_BUDGET`` (at least
+    one lane group)."""
     for n in range(1, ffn // _LANES + 1):
         if ffn % n or (ffn // n) % _LANES:
             continue
-        if 2 * 3 * dim * (ffn // n) * itemsize <= _TILE_BUDGET:
+        if 2 * stacks * dim * (ffn // n) * itemsize <= _TILE_BUDGET:
             return ffn // n
     return _LANES
 
 
-def kernel_ok(dim: int, ffn: int, dtype) -> bool:
-    """Whether the expert blocks of a model ``dim`` wide with experts
-    ``ffn`` wide and activations of ``dtype`` run the kernel: on a TPU,
+def kernel_ok(dim: int, ffn: int, dtype, stacks: int = 3) -> bool:
+    """Whether the expert blocks dispatched ``dim`` wide (the model's
+    width, or a latent's) with experts of ``stacks`` matrices ``ffn``
+    wide and activations of ``dtype`` run the kernel: on a TPU,
     where both widths are whole lanes, the activations bfloat16 (a
     16-row block is one bfloat16 sublane tile; a float32 test model
     stays on the loop) and an expert no larger than the loop turn's
@@ -85,7 +88,7 @@ def kernel_ok(dim: int, ffn: int, dtype) -> bool:
     if interpret_env():
         return True
     return (dim % _LANES == 0 and ffn % _LANES == 0
-            and 3 * dim * ffn <= _MAX_EXPERT_WEIGHTS
+            and stacks * dim * ffn <= _MAX_EXPERT_WEIGHTS
             and jnp.dtype(dtype) == jnp.bfloat16 and tpu_backend_ok())
 
 
@@ -103,28 +106,33 @@ def _dot(x, w_ref):
                    preferred_element_type=jnp.float32)
 
 
-def _experts_kernel(layer_ref, n_ref, expert_ref, x_ref, wg_ref, wu_ref,
-                    wd_ref, *rest, quant: bool, group: int, n_tiles: int):
-    """One (block, F tile) step: this tile's share of the block's SwiGLU."""
+def _experts_kernel(layer_ref, n_ref, expert_ref, x_ref, *rest, gated: bool,
+                    quant: bool, group: int, n_tiles: int):
+    """One (block, F tile) step: this tile's share of the block's expert
+    (``gated``: SwiGLU; else relu(W1 x)^2 into W2)."""
     del layer_ref                        # the index maps read it
+    n_w = 3 if gated else 2
+    *w_refs, wd_ref = rest[:n_w]
     if quant:
-        sg_ref, su_ref, sd_ref, o_ref, acc_ref = rest
-    else:
-        o_ref, acc_ref = rest
+        *s_refs, sd_ref = rest[n_w:2 * n_w]
+    o_ref, acc_ref = rest[-2:]
     j, f = pl.program_id(0), pl.program_id(1)
     live = j < n_ref[0]
 
     @pl.when(live)
     def _run():
         x = x_ref[...]
-        g = _dot(x, wg_ref)
-        u = _dot(x, wu_ref)
+        ins = [_dot(x, w_ref) for w_ref in w_refs]
         if quant:
             row = expert_ref[j] % group     # in the fetched group of scales
-            g = g * _scale_row(sg_ref, row)
-            u = u * _scale_row(su_ref, row)
-        h = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
-        d = _dot(h, wd_ref)
+            ins = [a * _scale_row(s_ref, row)
+                   for a, s_ref in zip(ins, s_refs)]
+        if gated:
+            g, u = ins
+            h = g * jax.nn.sigmoid(g) * u
+        else:
+            h = jnp.square(jnp.maximum(ins[0], 0.0))
+        d = _dot(h.astype(x.dtype), wd_ref)
 
         def finish(total):
             if quant:
@@ -157,8 +165,9 @@ def expert_blocks_stacked(xs, blk_expert, n_blocks, layer, w_gate, w_up,
                           w_down, s_gate=None, s_up=None, s_down=None, *,
                           block_rows: int, tile: int | None = None,
                           interpret: bool = False):
-    """SwiGLU of every live block of the dispatch buffer through its
-    expert of layer ``layer`` of the stacked weights.
+    """Every live block of the dispatch buffer through its expert of
+    layer ``layer`` of the stacked weights: SwiGLU, or with ``w_gate``
+    (and ``s_gate``) None the two-matrix ``w_down relu(w_up x)^2``.
 
     xs: [rows, D], ``rows`` whole blocks of ``block_rows``; blk_expert:
     [rows / block_rows] int32, the expert of each block (any held id for
@@ -169,10 +178,13 @@ def expert_blocks_stacked(xs, blk_expert, n_blocks, layer, w_gate, w_up,
     Returns [rows, D] in xs' dtype, zero in every block that is not
     live."""
     rows, dim = xs.shape
-    n_held, _, ffn = w_gate.shape[1:]
-    quant = s_gate is not None
+    n_held, _, ffn = w_up.shape[1:]
+    gated, quant = w_gate is not None, s_up is not None
+    w_in = [w_gate, w_up] if gated else [w_up]
+    s_in = [s_gate, s_up] if gated else [s_up]
     tile = tile or (ffn if interpret else
-                    tile_columns(dim, ffn, w_gate.dtype.itemsize))
+                    tile_columns(dim, ffn, w_up.dtype.itemsize,
+                                 len(w_in) + 1))
     n_tiles = ffn // tile
     nb = rows // block_rows
     group = _SUBLANES if n_held % _SUBLANES == 0 else n_held
@@ -198,19 +210,17 @@ def expert_blocks_stacked(xs, blk_expert, n_blocks, layer, w_gate, w_up,
     def at_s_down(j, f, li, n, e):
         return li[0], e[block_of(j, n)] // group, 0
 
-    in_specs = [pl.BlockSpec((block_rows, dim), at_x),
-                pl.BlockSpec((None, None, dim, tile), at_in),
-                pl.BlockSpec((None, None, dim, tile), at_in),
-                pl.BlockSpec((None, None, tile, dim), at_down)]
-    operands = [xs, w_gate, w_up, w_down]
+    in_specs = [pl.BlockSpec((block_rows, dim), at_x)] \
+        + [pl.BlockSpec((None, None, dim, tile), at_in)] * len(w_in) \
+        + [pl.BlockSpec((None, None, tile, dim), at_down)]
+    operands = [xs, *w_in, w_down]
     if quant:
-        in_specs += [pl.BlockSpec((None, group, tile), at_s_in),
-                     pl.BlockSpec((None, group, tile), at_s_in),
-                     pl.BlockSpec((None, group, dim), at_s_down)]
-        operands += [s_gate, s_up, s_down]
+        in_specs += [pl.BlockSpec((None, group, tile), at_s_in)] * len(s_in) \
+            + [pl.BlockSpec((None, group, dim), at_s_down)]
+        operands += [*s_in, s_down]
     return pl.pallas_call(
-        functools.partial(_experts_kernel, quant=quant, group=group,
-                          n_tiles=n_tiles),
+        functools.partial(_experts_kernel, gated=gated, quant=quant,
+                          group=group, n_tiles=n_tiles),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(nb, n_tiles), in_specs=in_specs,
             out_specs=pl.BlockSpec((block_rows, dim),

@@ -35,6 +35,14 @@ Config keys (reference config style, pkg/gofr/config/config.go:3):
                       row two by two; it refuses what the latent family
                       refuses, and TPU_MAX_SEQ must be whole prefill
                       chunks),
+                      the state-space family (tiny-ssm-moe; any
+                      configuration whose layer_pattern names a "mamba"
+                      layer, and then names every layer: Mamba-2 layers
+                      that keep a float32 state and a convolution tail a
+                      slot, attention layers' rows beside them, expert
+                      layers of two-matrix relu2 experts in a latent; it
+                      refuses what the hybrid family refuses, and
+                      TPU_MAX_SEQ must be whole prefill chunks),
                       bert family (bert/bert-base, bert-tiny), or
                       vit family (vit/vit-l-14, vit-tiny)
   TPU_WEIGHTS         checkpoint path (.npz or orbax dir); absent = random

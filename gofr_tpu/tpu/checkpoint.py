@@ -32,7 +32,10 @@ _QUANT_LEAVES = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
                  "w_qa", "w_qb", "w_kva", "w_kvb",
                  "ws_gate", "ws_up", "ws_down",
                  # the hybrid family's full-layer gate (models/solar_open2.py)
-                 "w_attn_gate"}
+                 "w_attn_gate",
+                 # the state-space family (models/nemotron_h.py): the
+                 # mamba layer's two projections, the latent's pair
+                 "w_ssm_in", "w_ssm_out", "w_latent_down", "w_latent_up"}
 
 
 def _flatten(tree: Any, prefix: str = "") -> dict[str, np.ndarray]:

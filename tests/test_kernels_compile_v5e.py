@@ -467,6 +467,94 @@ def test_conv_family_reads_weights_rows_and_tails_in_place(one_chip,
         < 14.2e9
 
 
+# -- the state-space family: its kernels and programs at the published widths --
+
+SSD_L, SSD_B, SSD_G, SSD_N, SSD_R, SSD_H = 10, 96, 8, 128, 1024, 128
+
+
+def test_ssd_decode_compiles_in_place(one_chip):
+    """128 heads x 64 x 128 float32 in 8 groups, 96 slots, 10 mamba
+    layers: the 4 GB of states it returns are the ones it was given."""
+    from gofr_tpu.ops import ssd
+
+    def arr(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(ssd.ssd_decode, donate_argnums=(0,)).lower(
+        arr((SSD_L, SSD_B, SSD_G, SSD_N, SSD_R)), arr((), jnp.int32),
+        arr((SSD_B, SSD_G, SSD_R)), arr((SSD_B, SSD_H)),
+        arr((SSD_B, SSD_G, SSD_N)), arr((SSD_B, SSD_G, SSD_N)),
+        arr((SSD_B,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    state_bytes = SSD_L * SSD_B * SSD_G * SSD_N * SSD_R * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 64
+
+
+@pytest.mark.parametrize("tokens", [32, 512])
+def test_ssd_prefill_compiles(one_chip, tokens):
+    """The smallest bucket (padded to one chunk of 128) and a whole chunk
+    of a prompt (four of them)."""
+    from gofr_tpu.ops import ssd
+
+    def arr(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = ssd.ssd_prefill.lower(
+        arr((1, tokens, SSD_G, SSD_R)), arr((1, tokens, SSD_H)),
+        arr((1, tokens, SSD_G, SSD_N)), arr((1, tokens, SSD_G, SSD_N)),
+        arr((1, SSD_G, SSD_N, SSD_R)), chunk=128).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode block", "chunk 512"])
+def test_state_space_family_leaves_states_and_stacks_where_they_lie(
+        one_chip, monkeypatch, program):
+    """``benchmarks/configs/nemotron-3-super-120b-int8-ep4.json`` as its
+    cell runs it: 22 layers of three kinds in ONE scan whose body switches
+    on the kind (so the text holds each kind's kernels once whatever the
+    depth), 96 slots. A branch that is no mamba layer hands the 4 GB of
+    states and the tails back through ``ssd_untouched``, not as a copy;
+    no int8 stack or expert stack is copied; the engine fits the chip."""
+    cfg = _cell_config("nemotron-3-super-120b-int8-ep4")
+    compiled = _engine_lowered(monkeypatch, one_chip, cfg, 96, None,
+                               program).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%expert_blocks_stacked[\w.]* = ", text)) == 1
+    assert len(re.findall(r" conditional\(", text)) >= 1
+    if program == "decode block":
+        assert len(re.findall(r"%ssd_decode[\w.]* = ", text)) == 1
+        assert len(re.findall(r"%flash_decode_stacked[\w.]* = ", text)) == 1
+        assert len(re.findall(r"%append_rows_stacked[\w.]* = ", text)) == 1
+        # states and tails: one in each of the two other branches
+        assert len(re.findall(r"%ssd_untouched[\w.]* = ", text)) == 4
+    else:
+        assert len(re.findall(r"%ssd_prefill[\w.]* = ", text)) == 1
+    results = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = ((?:s8|bf16|f32)\[[\d,]+\]\S*) "
+        r"([\w\-]+)\(", text, re.M)
+    assert results                      # the pattern still reads this HLO
+
+    def elements(shape):
+        n = 1
+        for d in shape.split("[")[1].split("]")[0].split(","):
+            n *= int(d)
+        return n
+
+    moved = [r for r in results if r[1] in ("copy", "transpose")
+             and (r[0].startswith("s8[") and elements(r[0]) >= 1 << 22
+                  or re.match(r"f32\[10,96,8,128,1024\]", r[0])
+                  or re.match(r"bf16\[10,96,30720\]", r[0])
+                  or re.match(r"bf16\[2,96,2,2048,128\]", r[0]))]
+    assert not moved
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (256 << 20)
+    # 9.2 GB of weights, 4.5 GB of cache, and what a step needs
+    assert 13.5e9 < mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 14.3e9
+
+
 # -- the sampler's branches in the compiled decode block -----------------------
 
 def _outside_conditionals(text):

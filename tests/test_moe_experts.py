@@ -16,12 +16,20 @@ from gofr_tpu.ops.quant import quantize_int8
 D, F, LS = 128, 256, 2
 
 
-def _stacks(n_held: int, quant: bool, dtype=jnp.float32, seed: int = 0):
+# the experts' forms: SwiGLU as wide as the model, and the two-matrix
+# relu^2 expert in a latent narrower than the model (no gate stack)
+FORMS = {"swiglu": (("w_gate", "w_up", "w_down"), D),
+         "relu2_in_a_latent": (("w_up", "w_down"), 64)}
+
+
+def _stacks(n_held: int, quant: bool, dtype=jnp.float32, seed: int = 0,
+            form: str = "swiglu"):
     """Expert stacks [LS, n_held, ...] as ``init`` lays them out, int8
-    with a scale an output channel or plain."""
+    with a scale an output channel or plain, in one of ``FORMS``."""
     k = jax.random.split(jax.random.PRNGKey(seed), 3)
-    shapes = {"w_gate": (LS, n_held, D, F), "w_up": (LS, n_held, D, F),
-              "w_down": (LS, n_held, F, D)}
+    names, width = FORMS[form]
+    shapes = {name: (LS, n_held, F, width) if name == "w_down"
+              else (LS, n_held, width, F) for name in names}
     out = {}
     for key, (name, shape) in zip(k, shapes.items()):
         w = jax.random.normal(key, shape, jnp.float32) * shape[2] ** -0.5
@@ -49,15 +57,16 @@ BUFFERS = {
 }
 
 
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("tile", [None, 128], ids=["whole_F", "F_in_tiles"])
 @pytest.mark.parametrize("bm", [16, 64])
 @pytest.mark.parametrize("quant", [True, False], ids=["int8", "plain"])
 @pytest.mark.parametrize("buffer", list(BUFFERS))
 def test_blocks_through_the_kernel_equal_the_loop(buffer, quant, bm, tile,
-                                                  interpreted):
+                                                  form, interpreted):
     nb, live, experts = BUFFERS[buffer]
-    stacks = _stacks(4, quant)
-    xs = jax.random.normal(jax.random.PRNGKey(nb), (nb * bm, D))
+    stacks = _stacks(4, quant, form=form)
+    xs = jax.random.normal(jax.random.PRNGKey(nb), (nb * bm, FORMS[form][1]))
     blk = jnp.array(experts, jnp.int32)
     n, li = jnp.int32(live), jnp.int32(1)
     want = ds._blocks_loop(xs, blk, n, stacks, li, bm)
@@ -113,23 +122,29 @@ def test_tile_columns_fit_the_budget():
         t = moe_experts.tile_columns(dim, ffn, size)
         assert ffn % t == 0 and t % 128 == 0
         assert 6 * dim * t * size <= moe_experts._TILE_BUDGET
+    # two stacks of a latent's width: a whole expert (2 x 2.75 MB, twice)
+    assert moe_experts.tile_columns(1024, 2688, 1, 2) == 2688
+    assert moe_experts.tile_columns(1024, 2688, 2, 2) == 896       # of 21 x 128
 
 
-@pytest.mark.parametrize("dim,ffn,dtype,want", [
-    (2048, 512, jnp.bfloat16, True),         # laguna: 3.1M weights
-    (4096, 1280, jnp.bfloat16, True),        # solar: 15.7M
-    (7168, 2048, jnp.bfloat16, False),       # gigachat: 44M, the loop's
-    (2048, 512, jnp.float32, False),         # a float32 model
-    (2048, 520, jnp.bfloat16, False),        # not whole lanes
+@pytest.mark.parametrize("dim,ffn,dtype,stacks,want", [
+    (2048, 512, jnp.bfloat16, 3, True),      # laguna: 3.1M weights
+    (4096, 1280, jnp.bfloat16, 3, True),     # solar: 15.7M
+    (7168, 2048, jnp.bfloat16, 3, False),    # gigachat: 44M, the loop's
+    (2048, 512, jnp.float32, 3, False),      # a float32 model
+    (2048, 520, jnp.bfloat16, 3, False),     # not whole lanes
+    (1024, 2688, jnp.bfloat16, 2, True),     # two stacks in a latent: 5.5M
+    (4096, 2816, jnp.bfloat16, 2, True),     # 23.1M in two stacks,
+    (4096, 2816, jnp.bfloat16, 3, False),    # 34.6M in three
 ])
-def test_path_is_chosen_from_backend_and_shapes(dim, ffn, dtype, want,
-                                                monkeypatch):
+def test_path_is_chosen_from_backend_and_shapes(dim, ffn, dtype, stacks,
+                                                want, monkeypatch):
     from gofr_tpu.ops import flash
 
     monkeypatch.delenv("GOFR_FLASH_INTERPRET", raising=False)
-    assert not moe_experts.kernel_ok(dim, ffn, dtype)     # a CPU process
+    assert not moe_experts.kernel_ok(dim, ffn, dtype, stacks)  # a CPU process
     monkeypatch.setattr(flash, "tpu_backend_ok", lambda: True)
-    assert moe_experts.kernel_ok(dim, ffn, dtype) is want
+    assert moe_experts.kernel_ok(dim, ffn, dtype, stacks) is want
 
 
 # -- the whole expert layer, kernel against loop -----------------------------
@@ -155,17 +170,30 @@ LAYERS = {
 }
 
 
+# the layer's configuration a form: the dispatch as wide as the model,
+# and a dispatch width unlike the model's
+CFGS = {"swiglu": CFG,
+        "relu2_in_a_latent": CFG.with_(moe_latent_dim=64,
+                                       expert_act="relu2")}
+
+
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("quant", [True, False], ids=["int8", "plain"])
 @pytest.mark.parametrize("layer", list(LAYERS))
-def test_expert_layer_on_the_kernel_equals_the_loop(layer, quant,
+def test_expert_layer_on_the_kernel_equals_the_loop(layer, quant, form,
                                                     monkeypatch):
     T, chosen, n_valid = LAYERS[layer]
-    stacks = _stacks(4, quant, seed=7)
-    k1, k2 = jax.random.split(jax.random.PRNGKey(T))
-    h = jax.random.normal(k1, (T, D))
+    stacks = _stacks(4, quant, seed=7, form=form)
+    CFG, width = CFGS[form], FORMS[form][1]
+    assert ds.expert_width(CFG) == width
+    assert ds.expert_stacks(CFG) == FORMS[form][0]
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(T), 3)
+    h = jax.random.normal(k1, (T, width))      # what the experts read
     if chosen is None:
+        # the router reads the model's width whatever the experts'
         router = jax.random.normal(k2, (D, CFG.n_experts)) * 0.3
-        topi, w = ds.route(h, router, jnp.zeros((CFG.n_experts,)), CFG)
+        topi, w = ds.route(jax.random.normal(k3, (T, D)), router,
+                           jnp.zeros((CFG.n_experts,)), CFG)
     else:
         topi = jnp.tile(jnp.array([chosen], jnp.int32), (T, 1))
         w = jnp.full((T, 4), 0.625)
@@ -175,7 +203,8 @@ def test_expert_layer_on_the_kernel_equals_the_loop(layer, quant,
 
     monkeypatch.delenv("GOFR_FLASH_INTERPRET", raising=False)
     assert not ds.experts_on_kernel(CFG)
-    assert ds.serving_stats(CFG, 8)["moe_decode_dispatch"]["path"] == "loop"
+    said = ds.serving_stats(CFG, 8)["moe_decode_dispatch"]
+    assert (said["path"], said["width"]) == ("loop", width)
     want, counts, blocks = ds._experts(h, topi, w, stacks, li, CFG, valid)
     monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
     assert ds.experts_on_kernel(CFG)
@@ -183,6 +212,7 @@ def test_expert_layer_on_the_kernel_equals_the_loop(layer, quant,
         == "kernel"
     got, counts_k, blocks_k = ds._experts(h, topi, w, stacks, li, CFG, valid)
 
+    assert got.shape == (T, width)
     assert counts_k.tolist() == counts.tolist()
     assert int(blocks_k) == int(blocks)
     np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5)
