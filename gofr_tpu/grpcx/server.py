@@ -369,7 +369,7 @@ class _PushSender:
     """
 
     __slots__ = ("server", "conn", "st", "codec", "map_fn", "source",
-                 "deadline", "outbox", "downgraded", "_spans_done")
+                 "deadline", "outbox", "downgraded", "closed", "_spans_done")
 
     def __init__(self, server: "GRPCServer", conn: _Connection, st: _Stream,
                  codec, map_fn, source, deadline: float | None):
@@ -382,6 +382,7 @@ class _PushSender:
         self.deadline = deadline
         self.outbox = Outbox(self._drain)
         self.downgraded = False
+        self.closed = False
         self._spans_done = False
 
     # -- producing thread ----------------------------------------------------
@@ -400,19 +401,25 @@ class _PushSender:
             self.downgraded = True  # multi-frame message: worker path
             return False
         self.outbox.append(payload)
+        # in a producer's burst (a decode block's K tokens a stream) the
+        # pump waits for its end: one drain and one write, not K
+        if not wire.defer(self, self._pump):
+            self._pump()
+        return True
+
+    def _pump(self) -> None:
         try:
             self.outbox.pump(block=False)
         except Exception:
             self.downgraded = True
             self._wake_worker()  # committed bytes need a flusher
-            return True
+            return
         if self.outbox.stalled:
             self.downgraded = True
             # the stalled item has NO other waker: the worker is parked
             # in q.get and the next token may be a decode block away —
             # without this the first byte waits for the second token
             self._wake_worker()
-        return True
 
     def _wake_worker(self) -> None:
         w = getattr(self.source, "wake", None)
@@ -430,9 +437,20 @@ class _PushSender:
         # WRITER's backlog (one layer below the outbox) — drain that too
         self.conn.io.flush()
 
+    def close(self) -> None:
+        """The RPC is over, trailers follow: nothing of this stream may
+        reach the wire after this returns. A pump put off to the end of
+        a burst may still come, and one may be mid-drain on the
+        producing thread: the blocking pump waits that one out and
+        discards what an aborted RPC left (``_drain`` once closed)."""
+        self.closed = True
+        self.outbox.pump(block=True)
+
     # -- outbox drain (single flusher at a time; see wire.Outbox) ------------
     def _drain(self, batch, block: bool) -> int:
         conn, st = self.conn, self.st
+        if self.closed:
+            return len(batch)
         if block:
             for payload in batch:
                 got = time.monotonic()
@@ -483,8 +501,9 @@ class _PushSender:
                 stages["write0"], stages["write1"] = t0, time.monotonic()
                 self._spans(got, stages)
             if not on_wire:
-                # bytes parked in the writer backlog (socket full /
-                # write lock contended): same no-waker hazard as an
+                # bytes parked in the writer backlog (socket full; a
+                # contended write leaves with the writer that holds the
+                # socket and reads True here): same no-waker hazard as an
                 # outbox stall one layer up — the backlog would sit
                 # until the NEXT write on the connection. Downgrade and
                 # wake the worker, whose finish() flushes the writer.
@@ -847,6 +866,7 @@ class GRPCServer:
             clear = getattr(src, "clear_sink", None)
             if clear is not None:
                 clear()
+            sender.close()
 
     def _first_send_spans(self, st: _Stream, source, got: float,
                           stages: dict) -> None:

@@ -37,9 +37,11 @@ stream, RMSNorm before attention and before the feed-forward):
     would add is left out, and that partial sum goes on. Nothing stands
     in for the other chips.
 
-Expert dispatch (``_experts``): the (token, held expert) assignments are
-sorted by expert into row blocks of ``block`` rows, each block one
-expert's, and the blocks THAT EXIST run one SwiGLU each (one kernel
+Expert dispatch (``_experts``): the (token, held expert) assignments
+stand expert by expert in row blocks of ``block`` rows, each block one
+expert's (their rows counted, not sorted: ``_tables``; the buffer filled
+by a 0/1 matmul: ``_fill``), and the blocks THAT EXIST run one SwiGLU
+each (one kernel
 over them, ``ops/moe_experts.py``, or a loop where that cannot run), so
 FLOPs follow the assignments. No capacity, no token dropped,
 and a token's result does not depend on what else is in the batch: a
@@ -309,10 +311,12 @@ def serving_stats(cfg: ModelConfig, slots: int) -> dict:
     """What ``GenerationEngine.stats()`` says of the programs of a family
     that routes through ``moe_ffn``: the decode step's expert dispatch
     shapes (the device operations that tall are the routed experts':
-    benchmarks/metrics reads them here) and the path its blocks take."""
+    benchmarks/metrics reads them here), the path its blocks take and
+    how the dispatch tables are built (``_tables``)."""
     bm, rows = expert_dispatch(cfg, slots)
     said = {"block_rows": bm, "buffer_rows": rows,
-            "width": expert_width(cfg), "path": "loop"}
+            "width": expert_width(cfg), "path": "loop",
+            "tables": "counted"}
     if experts_on_kernel(cfg):
         # columns of the expert width a grid step takes, by the weights'
         # type: fewer than the width where an expert's tiles are over
@@ -367,6 +371,91 @@ def _blocks_kernel(xs, blk_expert, n_blocks, stacks, li, bm: int,
         tile=tile, interpret=interpret_env())
 
 
+# tokens one triangular matmul counts: a decode batch or a 512-token chunk
+# is one pass, and no [T, T] matrix is ever larger than half a megabyte
+_COUNT_ROWS = 512
+
+
+def _counted(chose):
+    """chose [T, E1] 0/1, a row a token and a column a key -> seen
+    [T, E1] int32: the tokens 0..t that chose the column's key, token t
+    counted. Chunks of ``_COUNT_ROWS`` tokens against a lower-triangular
+    0/1 matrix on the matrix unit (0/1 in bfloat16, float32 sums of at
+    most a chunk's rows: exact), and the chunks before a chunk added in
+    int32: no scan down the rows, and one chunk is the whole of it."""
+    T, E1 = chose.shape
+    c = min(T, _COUNT_ROWS)
+    C = -(-T // c)
+    # rows past T chose nothing: they count for nobody
+    chunks = jnp.pad(chose, ((0, C * c - T), (0, 0))).reshape(C, c, E1)
+    i, j = jnp.arange(c), jnp.arange(C)
+    inside = jnp.einsum(
+        "ij,cje->cie", (i[None, :] <= i[:, None]).astype(jnp.bfloat16),
+        chunks.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32).astype(jnp.int32)
+    before = jnp.sum(jnp.where((j[None, :] < j[:, None])[..., None],
+                               inside[None, :, -1], 0), axis=1)   # [C, E1]
+    return (inside + before[:, None]).reshape(C * c, E1)[:T]
+
+
+@jax.named_scope("tables")
+def _tables(topi, valid, Eh: int, bm: int, nb_max: int):
+    """Where each assignment goes in the padded dispatch buffer, by
+    counting: topi [T, K], a token's K experts all different as a top-k
+    gives them (one at or past ``Eh`` is not held), valid [T] bool or
+    None -> (assignments a held expert [Eh], blocks that hold rows, each
+    block's expert [nb_max], dest [T, K]: the assignment's row of the
+    buffer, at or past ``nb_max * bm`` where it is not dispatched).
+
+    An assignment's key is its held expert (``Eh``: none), and its row is
+    its expert's offset, a multiple of ``bm``, plus the earlier tokens
+    that chose the same: what a stable sort by key gives, with no sort
+    and no gather from a table. ``offset[key]`` and ``seen[t, key]`` are
+    picked by the key's one-hot row inside one reduction."""
+    held = topi < Eh
+    if valid is not None:
+        held = held & valid[:, None]
+    key = jnp.where(held, topi, Eh).astype(jnp.int32)      # [T, K]
+    onehot = key[..., None] == jnp.arange(Eh + 1, dtype=jnp.int32)
+    seen = _counted(jnp.any(onehot, axis=1))               # [T, Eh + 1]
+    counts = seen[-1, :Eh]
+    nblk = jax.lax.div(counts + (bm - 1), bm)              # none negative
+    e = jnp.arange(Eh)
+    # a running sum as a masked [Eh, Eh] reduction: it fuses with what
+    # reads it, where a cumsum is a reduce-window and a copy of their own
+    blk_end = jnp.sum(jnp.where(e[None, :] <= e[:, None], nblk[None, :], 0),
+                      axis=1)
+    pad_start = (blk_end - nblk) * bm                      # buffer offset
+    n_blocks = jnp.sum(nblk)
+    # a block's expert: the experts that end at or before it, and a block
+    # past the last expert's end is the last expert's
+    blk_expert = jnp.sum(
+        blk_end[None, :-1] <= jnp.arange(nb_max)[:, None],
+        axis=1).astype(jnp.int32)                          # [nb_max]
+    # the key that is no expert starts past the buffer's end
+    offset = jnp.concatenate(
+        [pad_start, jnp.full((1,), nb_max * bm, jnp.int32)])
+    dest = jnp.sum(jnp.where(onehot, (seen - 1 + offset)[:, None], 0),
+                   axis=2)
+    return counts, n_blocks, blk_expert, dest
+
+
+@jax.named_scope("fill")
+def _fill(hf, dest, valid, rows: int):
+    """The dispatch buffer [rows, D]: row ``dest[t, k]`` is token t's
+    ``hf[t]``, every other row zeros. A 0/1 matrix [rows, T] times ``hf``
+    on the matrix unit: one 1 a row at most and float32 accumulation, so
+    a row arrives bit for bit (a row that is no token is zeroed first:
+    whatever an idle slot holds meets only zeros)."""
+    r = jnp.arange(rows, dtype=jnp.int32)
+    put = jnp.any(dest[None] == r[:, None, None], axis=2)  # [rows, T]
+    if valid is not None:
+        hf = jnp.where(valid[:, None], hf, 0)
+    return jnp.dot(put.astype(hf.dtype), hf,
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32).astype(hf.dtype)
+
+
 @jax.named_scope("moe/experts")
 def _experts(hf, topi, w, stacks, li, cfg: ModelConfig, valid=None):
     """Sum over the HELD experts each token chose, weighted.
@@ -383,54 +472,25 @@ def _experts(hf, topi, w, stacks, li, cfg: ModelConfig, valid=None):
     are not dispatched). Returns (y [T, D], assignments a held expert
     [Eh] int32, blocks run: int32 scalar).
 
-    The assignments are sorted by expert (absent experts and invalid
-    rows last); expert e's rows start at a multiple of ``block`` in a
-    padded buffer, so every block of it is one expert's; the blocks that
-    hold rows run one expert each, in one kernel
+    The assignments stand in a padded buffer by expert, in the order
+    they come within one (``_tables``: counted, not sorted; absent
+    experts and invalid rows nowhere); expert e's rows start at a
+    multiple of ``block``, so every block of it is one expert's; the
+    blocks that hold rows run one expert each, in one kernel
     (``ops.moe_experts.expert_blocks_stacked``) or, where that cannot
     run (``experts_on_kernel``), a while loop of the same arithmetic."""
-    T, D = hf.shape
-    K, Eh = topi.shape[1], n_held(cfg)
-    bm, buf_rows = expert_dispatch(cfg, T)
-    nb_max = buf_rows // bm
-    N = T * K
-    flat_e = topi.reshape(N)
-    tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)
-    held = flat_e < Eh
-    if valid is not None:
-        held = held & valid[tok]
-    key = jnp.where(held, flat_e, Eh).astype(jnp.int32)
-    order = jnp.argsort(key, stable=True)                  # sorted -> flat
-    rank_of = jnp.argsort(order, stable=True)              # flat -> sorted
-    counts = jnp.sum(jax.nn.one_hot(key, Eh + 1, dtype=jnp.int32),
-                     axis=0)[:Eh]                          # [Eh]
-    start = jnp.cumsum(counts) - counts                    # sorted offset
-    nblk = (counts + bm - 1) // bm
-    blk_end = jnp.cumsum(nblk)
-    pad_start = (blk_end - nblk) * bm                      # buffer offset
-    n_blocks = blk_end[-1]
-    blk_expert = jnp.minimum(
-        jnp.sum(blk_end[None, :] <= jnp.arange(nb_max)[:, None], axis=1),
-        Eh - 1).astype(jnp.int32)                          # [nb_max]
-    p = jnp.arange(nb_max * bm, dtype=jnp.int32)
-    e_p = blk_expert[p // bm]
-    r_p = p - pad_start[e_p]                               # rank in group
-    live_p = (r_p < counts[e_p]) & (p < n_blocks * bm)
-    src = order[jnp.clip(start[e_p] + r_p, 0, N - 1)]      # flat index
-    xs = jnp.where(live_p[:, None], hf[tok[src]], 0).astype(hf.dtype)
-
+    bm, rows = expert_dispatch(cfg, hf.shape[0])
+    counts, n_blocks, blk_expert, dest = _tables(topi, valid, n_held(cfg),
+                                                 bm, rows // bm)
+    xs = _fill(hf, dest, valid, rows)
     if experts_on_kernel(cfg, hf.dtype):
         out = _blocks_kernel(xs, blk_expert, n_blocks, stacks, li, bm)
     else:
         out = _blocks_loop(xs, blk_expert, n_blocks, stacks, li, bm)
-    # assignment (t, k) sits at its expert's buffer offset plus its rank
-    # among that expert's sorted assignments
-    e_flat = jnp.minimum(key, Eh - 1)
-    dest = pad_start[e_flat] + rank_of - start[e_flat]
-    y = out[jnp.clip(dest, 0, nb_max * bm - 1)].astype(jnp.float32) \
-        * jnp.where(held, w.reshape(N), 0.0)[:, None]
-    return (jnp.sum(y.reshape(T, K, D), axis=1).astype(hf.dtype), counts,
-            n_blocks)
+    # an assignment that was not dispatched reads the last row, times 0
+    y = out[jnp.minimum(dest, rows - 1)].astype(jnp.float32) \
+        * jnp.where(dest < rows, w, 0.0)[..., None]
+    return jnp.sum(y, axis=1).astype(hf.dtype), counts, n_blocks
 
 
 def moe_ffn(h, lw, cfg: ModelConfig, valid=None):

@@ -3,8 +3,10 @@
 the stacks hold no gate), over the blocks that exist, the next block's
 weights in flight while this one multiplies.
 
-``models.deepseek_v3._experts`` sorts a step's (token, held expert)
-assignments into a padded buffer ``xs [rows, D]`` of ``block_rows``-row
+``models.deepseek_v3._experts`` puts a step's (token, held expert)
+assignments, expert by expert and in the order they come within one
+(their places counted, not sorted: ``deepseek_v3._tables``), into a
+padded buffer ``xs [rows, D]`` of ``block_rows``-row
 blocks, each one expert's (``blk_expert [rows / block_rows]``), of which
 the first ``n_blocks`` hold rows. Its jnp form runs a ``fori_loop`` of
 one turn a block: a slice of ``xs``, three slices of the weight stacks

@@ -48,7 +48,7 @@ from ..resilience import (SLO_LATENCY, SLO_THROUGHPUT, DecodePipelinePolicy,
                           current_deadline, current_slo_class)
 from ..tenancy.fair import WeightedFairLine
 from ..tenancy.registry import current_tenant
-from ..wire import PushStream
+from ..wire import PushStream, burst
 from . import hbm, programs
 from .batcher import pad_bucket
 from .kvcache import HostKV, ShardedHostKV, clamp_restore_len, dense_hostkv
@@ -4059,19 +4059,21 @@ class GenerationEngine:
         for idx in range(self.n_slots):
             self._cursors[idx] += emit_l[idx]
         toks_l, lps_l = toks_np.tolist(), lps_np.tolist()
-        for idx, slot in enumerate(self._slots):
-            if not snap_active[idx] or slot.request is not snap_reqs[idx]:
-                continue
-            if self._expire_decoding(idx, slot):
-                continue
-            self._record_itl(slot, emit_l[idx])
-            for k in range(emit_l[idx]):
-                if not self._active[idx]:
-                    break  # retired mid-window (EOS/budget/cancel)
-                t = toks_l[idx][k]
-                self._last_tokens[idx] = t
-                self._hist_append(idx, t)
-                self._deliver(idx, slot, t, lps_l[idx][k])
+        with burst():  # a stream's accepted tokens leave in one write
+            for idx, slot in enumerate(self._slots):
+                if not snap_active[idx] \
+                        or slot.request is not snap_reqs[idx]:
+                    continue
+                if self._expire_decoding(idx, slot):
+                    continue
+                self._record_itl(slot, emit_l[idx])
+                for k in range(emit_l[idx]):
+                    if not self._active[idx]:
+                        break  # retired mid-window (EOS/budget/cancel)
+                    t = toks_l[idx][k]
+                    self._last_tokens[idx] = t
+                    self._hist_append(idx, t)
+                    self._deliver(idx, slot, t, lps_l[idx][k])
         # a verify pass advanced host state outside the decode carry
         # chain: host wins the next decode dispatch's merge, so sync
         # the budget mirror to what the deliveries left behind
@@ -4217,18 +4219,21 @@ class GenerationEngine:
                     continue
                 if counts[idx]:
                     self._record_itl(slot, int(counts[idx]))
-        for k in range(len(toks_l)):
-            trow, lrow, erow = toks_l[k], lps_l[k], emit_l[k]
-            for idx, slot in enumerate(self._slots):
-                if not snap_active[idx] or not self._active[idx] \
-                        or slot.request is not snap_reqs[idx] \
-                        or not erow[idx]:
-                    # the emitted mask replays the device stop masks:
-                    # tokens a self-deactivated slot carried (frozen
-                    # repeats) are never delivered, keeping the stream
-                    # identical to host-side retirement
-                    continue
-                self._last_tokens[idx] = trow[idx]
-                if self._spec_k:
-                    self._hist_append(idx, trow[idx])
-                self._deliver(idx, slot, trow[idx], lrow[idx])
+        # one burst a block: a stream's K tokens leave in one write at
+        # its end, not in K (wire.burst)
+        with burst():
+            for k in range(len(toks_l)):
+                trow, lrow, erow = toks_l[k], lps_l[k], emit_l[k]
+                for idx, slot in enumerate(self._slots):
+                    if not snap_active[idx] or not self._active[idx] \
+                            or slot.request is not snap_reqs[idx] \
+                            or not erow[idx]:
+                        # the emitted mask replays the device stop masks:
+                        # tokens a self-deactivated slot carried (frozen
+                        # repeats) are never delivered, keeping the stream
+                        # identical to host-side retirement
+                        continue
+                    self._last_tokens[idx] = trow[idx]
+                    if self._spec_k:
+                        self._hist_append(idx, trow[idx])
+                    self._deliver(idx, slot, trow[idx], lrow[idx])

@@ -7,14 +7,21 @@ hardware capture):
   SocketWriter — vectored (``sendmsg``) frame writes with an ordered
       backlog, so a producer thread can hand bytes to the wire WITHOUT
       ever blocking on the socket or on another writer. One syscall
-      carries many frames; partial/contended writes park in the backlog
-      and ride out with the next write.
+      carries many frames; a contended write parks in the backlog and
+      leaves with the writer that holds the socket, a partial one rides
+      out with the next write.
 
   Outbox — an ordered multi-producer send queue drained by whichever
       thread is available (thread-combining), never by a dedicated
       flusher thread. This is the write scheduler: bursts (a fused
       decode block delivering K tokens back-to-back) coalesce into one
       vectored write instead of K wakeups and K syscalls.
+
+  burst / defer — a producer that pushes a run of items to many streams
+      on one thread (a fused decode block: K tokens to every slot) says
+      so with ``burst()``; a sink called inside may ``defer`` its send to
+      the end of the run, so that a stream's K items leave in one write
+      and not in K.
 
   PushStream / MappedStream — a queue-backed item stream with an
       optional zero-handoff *sink*: when a consumer registers one, the
@@ -31,6 +38,7 @@ HTTP chunked encoding both sit on top.
 from __future__ import annotations
 
 import collections
+import contextlib
 import queue
 import socket
 import threading
@@ -55,7 +63,16 @@ class SocketWriter:
         the backlog;
       - every blocking write drains the backlog ahead of its own bytes,
         so any stream that *ends* with a blocking write (gRPC trailers,
-        the terminal HTTP chunk) leaves the wire fully flushed.
+        the terminal HTTP chunk) leaves the wire fully flushed;
+      - bytes parked behind a writer that holds the socket leave with
+        that writer: it sweeps the backlog when it lets the socket go
+        (``_sweep``). Only a FULL socket leaves bytes parked with no one
+        to send them, and only then does a nonblocking write say False.
+        (Until PR 43 a contended write said False too, and the gRPC
+        sender that heard it sent the rest of its stream through its
+        worker thread, whose blocking writes the engine's next writes
+        then met: at 6,000 tokens a second five streams in six ended up
+        there.)
     """
 
     def __init__(self, sock: socket.socket):
@@ -64,6 +81,7 @@ class SocketWriter:
         self._blk = threading.Lock()    # guards _backlog and _closed
         self._backlog = bytearray()
         self._closed = False
+        self._flusher = False  # a _flush_parked thread is alive
         self.syscalls = 0     # sendmsg calls issued (incl. EAGAIN probes)
         self.bytes_sent = 0
         self.deferred = 0     # nonblocking writes parked without a syscall
@@ -116,48 +134,116 @@ class SocketWriter:
     def write(self, bufs, block: bool = True) -> bool:
         """Write ``bufs`` (an iterable of bytes-likes, or one bytes-like)
         in order. ``block=False`` returns immediately: contended or
-        would-block bytes park in the backlog and are flushed by the
-        next write on this connection.
+        would-block bytes park in the backlog. Contended ones leave
+        with the writer that holds the socket; would-block ones are
+        flushed by the next write on this connection.
 
         Returns True when everything (backlog included) reached the
-        socket, False when bytes were parked — a nonblocking caller
+        socket or a writer that holds it will send it, False when the
+        socket would block with bytes parked — a nonblocking caller
         that gets False must arrange for SOME later write/flush on the
         connection, or the parked bytes sit until the next traffic."""
         if isinstance(bufs, (bytes, bytearray, memoryview)):
             bufs = [bufs]
         if block:
             with self._lock:
-                views = self._take(bufs)
-                self._drain(views, 0)
+                self._send_taken(bufs, 0)
+            return self._sweep(0)
+        if defer(self, self._burst_end):
+            # a producer's burst on this thread (a decode block's tokens
+            # to every stream): the bytes wait in the backlog for its
+            # end and leave in one syscall a connection, not one a stream
+            self._park(bufs)
             return True
         if not self._lock.acquire(blocking=False):
             # a writer holds the socket: it already swapped the backlog
             # out, so parking here lands AFTER its bytes — commit order
-            # is preserved. The next write on the connection flushes.
-            with self._blk:
-                if self._closed:
-                    raise ConnectionLost("connection closed")
-                for b in bufs:
-                    self._backlog += b
-                self.deferred += 1
-            return False
+            # is preserved. The holder sweeps the backlog when it lets
+            # the socket go; it may have let go since the try above, so
+            # this thread sweeps too.
+            self._park(bufs)
+            return self._sweep(socket.MSG_DONTWAIT)
         try:
-            views = self._take(bufs)
-            total = sum(len(v) for v in views)
-            sent = self._drain(views, socket.MSG_DONTWAIT)
-            if sent < total:
-                # _drain advanced ``views`` in place: what remains is
-                # exactly the unsent tail
-                rest = b"".join(views)
-                with self._blk:
-                    # unsent tail goes back to the FRONT: bytes parked by
-                    # other threads during this send came later
-                    self._backlog[:0] = rest
-                    self.deferred += 1
+            if not self._send_taken(bufs, socket.MSG_DONTWAIT):
                 return False
-            return True
         finally:
             self._lock.release()
+        return self._sweep(socket.MSG_DONTWAIT)
+
+    def _park(self, bufs) -> None:
+        with self._blk:
+            if self._closed:
+                raise ConnectionLost("connection closed")
+            for b in bufs:
+                self._backlog += b
+            self.deferred += 1
+
+    def _burst_end(self) -> None:
+        """What a burst parked here, in one send. Its writers heard True,
+        so where the socket is full a thread of this writer's own waits
+        for room (a client that reads slower than the engine writes)."""
+        try:
+            if self.write((), block=False):
+                return
+        except (OSError, ConnectionLost):
+            return  # a dead connection: its streams' workers hear of it
+        with self._blk:
+            if self._flusher or self._closed:
+                return
+            self._flusher = True
+        threading.Thread(target=self._flush_parked, name="gofr-wire-flush",
+                         daemon=True).start()
+
+    def _flush_parked(self) -> None:
+        try:
+            while True:
+                self.flush()
+                with self._blk:
+                    if not self._backlog:
+                        self._flusher = False  # with the look, not after
+                        return
+        except (OSError, ConnectionLost):
+            return  # a dead connection: its streams' workers hear of it
+        finally:
+            with self._blk:
+                self._flusher = False
+
+    def _send_taken(self, bufs, flags: int) -> bool:
+        """Holding ``_lock``: the backlog and ``bufs`` to the socket. False
+        if the socket took only part (nonblocking): the rest is parked."""
+        views = self._take(bufs)
+        total = sum(len(v) for v in views)
+        sent = self._drain(views, flags)
+        if sent < total:
+            # _drain advanced ``views`` in place: what remains is
+            # exactly the unsent tail
+            rest = b"".join(views)
+            with self._blk:
+                # unsent tail goes back to the FRONT: bytes parked by
+                # other threads during this send came later
+                self._backlog[:0] = rest
+                self.deferred += 1
+            return False
+        return True
+
+    def _sweep(self, flags: int) -> bool:
+        """After a write let the socket go: send what other threads
+        parked while it held it, so that a contended nonblocking write
+        needs no later flush of its caller's. Whoever parks tries the
+        lock AFTER parking and whoever holds it looks at the backlog
+        AFTER releasing, so one of the two sees the bytes. False only
+        if the socket would block with bytes still parked."""
+        while True:
+            with self._blk:
+                if not self._backlog:
+                    return True
+            if not self._lock.acquire(blocking=False):
+                return True  # that writer sweeps when it lets go
+            try:
+                if not self._send_taken((), flags):
+                    return False
+            finally:
+                self._lock.release()
 
     def flush(self) -> None:
         """Blocking drain of any backlog left by nonblocking writes."""
@@ -267,6 +353,55 @@ class Outbox:
                     self._flushing = False
             # items appended between the final emptiness check and the
             # flag clear are picked up by looping (no lost wakeup)
+
+
+class _Burst(threading.local):
+    pending: "dict | None" = None  # inside a burst: {key: flush}, in order
+
+
+_burst = _Burst()
+
+
+@contextlib.contextmanager
+def burst():
+    """A run of pushes on this thread, to many streams: sinks called inside
+    may put their sends off to its end (``defer``). The engine's reap
+    wraps a decode block's deliveries in one: K tokens a stream then cost
+    one pump at the end of the block and a connection one syscall, where a
+    pump and a syscall a token made delivery the longest phase of the loop
+    once every token went through the sink (PERF.md, PR 43). Nested bursts
+    flush with the outermost."""
+    if _burst.pending is not None:
+        yield
+        return
+    _burst.pending = pending = {}
+    try:
+        yield
+    finally:
+        # the sinks' flushes first, still inside the burst: what they
+        # write parks with its SocketWriter, whose one send a connection
+        # runs last, outside it
+        for last in (False, True):
+            flushes = list(pending.values())
+            pending.clear()
+            if last:
+                _burst.pending = None
+            for flush in flushes:
+                try:
+                    flush()
+                except Exception:
+                    pass  # a flush answers for its own failures, as a sink
+
+
+def defer(key, flush) -> bool:
+    """Inside a burst of this thread: ``flush()`` runs once at its end
+    (one call a ``key``, in the order of first deferral); says True.
+    Outside a burst: does nothing and says False."""
+    pending = _burst.pending
+    if pending is None:
+        return False
+    pending.setdefault(key, flush)
+    return True
 
 
 # sentinel a producer-side sink can enqueue (PushStream.wake) to rouse

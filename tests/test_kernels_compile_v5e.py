@@ -555,15 +555,9 @@ def test_state_space_family_leaves_states_and_stacks_where_they_lie(
         < 14.3e9
 
 
-# -- the sampler's branches in the compiled decode block -----------------------
-
-def _outside_conditionals(text):
-    """The lines of a compiled module's computations that run whatever a
-    ``conditional`` decides (the entry and what it reaches by a loop's
-    body, a fusion's or a call's computation, but not through a
-    conditional's ``branch_computations``), those among them that are
-    instructions of their own (the entry's and the loops' bodies', not
-    what is fused into one), and the lines of every other computation."""
+def _computations(text):
+    """A compiled module's text as ({computation: its instruction
+    lines}, the entry computation's name)."""
     comps, cur, entry = {}, None, None
     for line in text.splitlines():
         if line[:1].strip() and line.rstrip().endswith("{") and "(" in line:
@@ -575,6 +569,64 @@ def _outside_conditionals(text):
             cur = None
         elif cur is not None:
             comps[cur].append(line)
+    return comps, entry
+
+
+# -- the routed experts' dispatch tables in the compiled decode blocks ----------
+
+def _own_instructions(text):
+    """The lines of a compiled module that are instructions of their own:
+    those of every computation no ``fusion`` calls and no reduction
+    applies (the entry, loop bodies, a conditional's branches), less what
+    takes no device time."""
+    comps, _ = _computations(text)
+    inner = set(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", text))
+    free = re.compile(r" (get-tuple-element|bitcast|constant|parameter)\(")
+    return [ln for c in comps if c not in inner for ln in comps[c]
+            if not free.search(ln)]
+
+
+@pytest.mark.parametrize("cell,slots,kernels", [
+    ("lfm2-24b-a2b-int8-pp2", 96, 6),
+    ("nemotron-3-super-120b-int8-ep4", 96, 1)])
+def test_dispatch_tables_are_counted_not_sorted(one_chip, monkeypatch, cell,
+                                                slots, kernels):
+    """``deepseek_v3._tables``, read off LFM2's and nemotron's compiled
+    decode blocks: nothing under ``moe/experts`` is a ``sort`` (the
+    router's top-k, under ``moe/route``, is the only one a layer), and
+    the tables are ten instructions a routed layer (the key, the tokens'
+    one-hot, the triangular matmul, four over the held experts, the
+    blocks' experts, the offsets beside the counts, the rows) where the
+    sorted form was over five hundred: the builder found 10, the bound is
+    12 (PERF.md, Findings PR 43). Laguna's temporaries are held by
+    ``test_window_family_draws_only_inside_a_conditional``."""
+    compiled = _engine_lowered(monkeypatch, one_chip, _cell_config(cell),
+                               slots, None, "decode block").compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%expert_blocks_stacked[\w.]* = ", text)) \
+        == kernels
+    scoped = [ln for ln in text.splitlines() if re.search(
+        r'op_name="[^"]*/moe/experts/', ln)]
+    assert len(scoped) > 20 * kernels        # the scope still reads this HLO
+    assert not [ln[:160] for ln in scoped if re.search(r" sort\(", ln)]
+    tables = [ln for ln in _own_instructions(text) if re.search(
+        r'op_name="[^"]*/moe/experts/tables/', ln)]
+    assert 4 * kernels <= len(tables) <= 12 * kernels, len(tables)
+    fills = [ln for ln in _own_instructions(text) if re.search(
+        r'op_name="[^"]*/moe/experts/fill/', ln)]
+    assert kernels <= len(fills) <= 3 * kernels, len(fills)
+
+
+# -- the sampler's branches in the compiled decode block -----------------------
+
+def _outside_conditionals(text):
+    """The lines of a compiled module's computations that run whatever a
+    ``conditional`` decides (the entry and what it reaches by a loop's
+    body, a fusion's or a call's computation, but not through a
+    conditional's ``branch_computations``), those among them that are
+    instructions of their own (the entry's and the loops' bodies', not
+    what is fused into one), and the lines of every other computation."""
+    comps, entry = _computations(text)
     loops = re.compile(r"(?:body|condition)=%?([\w.\-]+)")
     fused = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
 

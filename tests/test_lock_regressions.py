@@ -104,7 +104,10 @@ def test_socketwriter_contended_park_counts_deferred_under_blk():
             t.start()
             t.join(5)
             assert not t.is_alive()
-            assert got == [False]
+            # the writer that holds the socket sweeps the backlog when
+            # it lets go (tests/test_transport_fast.py holds that), so
+            # the parked write asks nothing of its caller
+            assert got == [True]
             assert w.deferred == 1
             assert bytes(w._backlog) == b"parked"
         finally:

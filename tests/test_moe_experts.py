@@ -222,3 +222,96 @@ def test_expert_layer_on_the_kernel_equals_the_loop(layer, quant, form,
         assert np.asarray(got).any()
     if valid is not None:
         assert not np.asarray(got[n_valid:]).any()
+
+
+# -- the dispatch tables alone, against the sort they replace -----------------
+
+def _sorted_tables(hf, topi, valid, Eh, bm, nb_max):
+    """What ``_tables`` and ``_fill`` must give, by the stable sort they
+    replace (``_experts`` up to PR 42: assignments sorted by held expert,
+    an expert's rows from a multiple of ``bm``), in numpy."""
+    T, K = topi.shape
+    key = np.where((topi < Eh) & valid[:, None], topi, Eh).reshape(T * K)
+    order = np.argsort(key, kind="stable")                 # sorted -> flat
+    counts = np.bincount(key, minlength=Eh + 1)[:Eh]
+    start = np.cumsum(counts) - counts
+    nblk = -(-counts // bm)
+    blk_end = np.cumsum(nblk)
+    pad_start = (blk_end - nblk) * bm
+    xs = np.zeros((nb_max * bm, hf.shape[1]), hf.dtype)
+    dest = np.full(T * K, -1)
+    for place, flat in enumerate(order):
+        e = key[flat]
+        if e < Eh:
+            dest[flat] = pad_start[e] + place - start[e]
+            xs[dest[flat]] = hf[flat // K]
+    blk_expert = np.minimum(
+        np.searchsorted(blk_end, np.arange(nb_max), side="right"), Eh - 1)
+    return xs, blk_expert, blk_end[-1], counts, dest.reshape(T, K)
+
+
+# (tokens, k, experts held, held share of the router's experts, valid
+# share of the tokens): the five cells' decode shapes cut small (LFM2 all
+# 64 held, nemotron 22 of 512 with a quarter held, Laguna 256 held for
+# 128 slots, solar an eighth, gigachat a sixteenth), a 512-token chunk in
+# blocks of 64, more tokens than one count's chunk, and the edges
+TABLES = {
+    "lfm2_decode": (24, 4, 16, 1.0, 0.9),
+    "nemotron_decode": (24, 6, 8, 0.25, 0.9),
+    "laguna_decode": (32, 4, 64, 1.0, 0.9),
+    "solar_decode": (32, 4, 5, 0.125, 0.9),
+    "gigachat_decode": (32, 4, 2, 0.0625, 0.9),
+    "a_512_token_chunk": (512, 6, 8, 0.25, 0.95),
+    "more_tokens_than_one_count": (640, 2, 4, 0.5, 1.0),
+    "every_token_on_one_expert": (40, 4, 8, "one", 1.0),
+    "no_token_on_a_held_expert": (40, 4, 8, "none", 1.0),
+    "no_valid_token": (24, 4, 8, 1.0, 0.0),
+    "one_expert_held": (24, 2, 1, 0.25, 0.9),
+}
+
+
+@pytest.mark.parametrize("valid_given", [True, False],
+                         ids=["valid", "valid_is_None"])
+@pytest.mark.parametrize("case", list(TABLES))
+def test_counted_tables_equal_the_stable_sort(case, valid_given):
+    """``xs``, ``blk_expert``, ``n_blocks``, ``counts`` and every
+    dispatched assignment's ``dest`` are value for value what the sort
+    gave, so the blocks' kernel gets the operands it always got; an
+    assignment that is not dispatched points past the buffer."""
+    T, K, Eh, share, valid_share = TABLES[case]
+    rng = np.random.default_rng(T * K + Eh)
+    E = Eh if isinstance(share, str) else round(Eh / share)
+    if share == "one":        # the held expert 3, and K - 1 that are not
+        topi = np.tile(np.array([[3] + list(range(Eh, Eh + K - 1))]), (T, 1))
+    elif share == "none":
+        topi = np.tile(np.arange(Eh, Eh + K)[None], (T, 1))
+    else:                     # k different experts a token, as top-k gives
+        topi = np.argsort(rng.random((T, E)), axis=1)[:, :K]
+    valid = rng.random(T) < valid_share if valid_given else np.ones(T, bool)
+    hf = rng.standard_normal((T, 32)).astype(np.float32)
+    cfg = CFG.with_(n_experts=max(E, Eh + K), n_experts_held=Eh,
+                    experts_per_token=K)
+    bm, rows = ds.expert_dispatch(cfg, T)
+    assert bm == (16 if T <= 128 else 64) and ds.n_held(cfg) == Eh
+    want = _sorted_tables(hf, topi, valid, Eh, bm, rows // bm)
+
+    v = jnp.asarray(valid) if valid_given else None
+    counts, n_blocks, blk_expert, dest = jax.jit(
+        lambda t, v: ds._tables(t, v, Eh, bm, rows // bm))(
+            jnp.asarray(topi, jnp.int32), v)
+    xs = jax.jit(lambda h, d, v: ds._fill(h, d, v, rows))(
+        jnp.asarray(hf), dest, v)
+    dest, sent = np.asarray(dest), want[4] >= 0
+    np.testing.assert_array_equal(np.asarray(xs), want[0])
+    np.testing.assert_array_equal(np.asarray(blk_expert), want[1])
+    assert int(n_blocks) == want[2]
+    np.testing.assert_array_equal(np.asarray(counts), want[3])
+    np.testing.assert_array_equal(dest[sent], want[4][sent])
+    assert (dest[~sent] >= rows).all()
+    if case in ("no_token_on_a_held_expert", "no_valid_token") \
+            and (valid_given or case != "no_valid_token"):
+        assert want[2] == 0 and not sent.any()
+    bf16 = jnp.asarray(hf).astype(jnp.bfloat16)            # bit for bit
+    np.testing.assert_array_equal(
+        np.asarray(ds._fill(bf16, jnp.asarray(dest), v, rows), np.float32),
+        np.asarray(jnp.asarray(want[0]).astype(jnp.bfloat16), np.float32))

@@ -72,3 +72,45 @@ def test_moe_blocks_are_scoped():
     for scope in ("moe", "moe_route", "moe_experts"):
         assert f'"{scope}"' in text or f"{scope}/" in text, scope
     assert '"mlp"' not in text
+
+
+# -- the routed experts of the families that dispatch through moe_ffn ---------
+
+@pytest.fixture(scope="module")
+def routed_engine():
+    from gofr_tpu.models import deepseek_v3
+
+    cfg = LLAMA_CONFIGS["tiny-mla-moe"]
+    eng = GenerationEngine(cfg, deepseek_v3.init(cfg, jax.random.PRNGKey(0)),
+                           slots=2, max_seq=64, prompt_buckets=(8, 16))
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+@pytest.mark.parametrize("scope", ["moe/route", "moe/experts/tables",
+                                   "moe/experts/fill", "moe/shared"])
+def test_expert_layer_scopes_tell_tables_from_blocks(routed_engine, which,
+                                                     scope):
+    """Inside ``moe/experts`` the dispatch tables (counted: index
+    arithmetic that streams nothing) and the buffer's fill have scopes of
+    their own, so a trace tells them from the blocks' kernel or loop."""
+    text = _lowered(routed_engine, which)
+    names = [line for line in text.splitlines() if "loc(" in line]
+    assert any(f'{scope}"' in line or f"{scope}/" in line
+               for line in names), scope
+    assert "sort" not in "".join(
+        line for line in names if "moe/experts" in line)
+
+
+def test_stats_say_how_the_dispatch_tables_are_built(routed_engine):
+    """``stats()["moe_decode_dispatch"]``: what the benchmark's readers
+    key on (the block's and the buffer's rows, the width, the path) and
+    how the tables are built."""
+    from gofr_tpu.models import deepseek_v3
+
+    said = routed_engine.stats()["moe_decode_dispatch"]
+    bm, rows = deepseek_v3.expert_dispatch(routed_engine.cfg, 2)
+    assert said == {"block_rows": bm, "buffer_rows": rows,
+                    "width": routed_engine.cfg.dim, "path": "loop",
+                    "tables": "counted"}

@@ -23,6 +23,7 @@ from gofr_tpu.grpcx import (GRPCServer, GRPCService, ServerStream,
                             TransportOptions, dial)
 from gofr_tpu.grpcx import http2 as h2
 from gofr_tpu.grpcx.hpack import Decoder, Encoder, encode_stateless
+from gofr_tpu import wire
 from gofr_tpu.wire import Outbox, PushStream, SocketWriter
 
 NAME_CHARS = string.ascii_lowercase + string.digits + "-"
@@ -179,6 +180,66 @@ def test_socket_writer_vectored_single_syscall():
         b.close()
 
 
+@pytest.mark.parametrize("holder_blocks", [True, False],
+                         ids=["blocking-holder", "nonblocking-holder"])
+def test_socket_writer_holder_sweeps_what_was_parked_behind_it(
+        holder_blocks):
+    """A nonblocking write that meets a writer on the socket parks, says
+    True and asks nothing more of its caller: the writer that holds the
+    socket sends the parked bytes when it lets go, after its own and with
+    no further call on the connection. (The engine's loop writes tokens
+    this way while a stream's worker writes trailers; when such a write
+    said False, its stream went to its worker thread for good.)"""
+    a, b = socket.socketpair()
+    wr = SocketWriter(a)
+    inside, parked = threading.Event(), threading.Event()
+    real = wr._drain
+
+    def held(views, flags):
+        # the holder is in its send, the socket's lock taken, until the
+        # other thread has parked
+        inside.set()
+        assert parked.wait(5)
+        return real(views, flags)
+
+    wr._drain = held
+    try:
+        t = threading.Thread(
+            target=lambda: wr.write([b"held|"], block=holder_blocks))
+        t.start()
+        assert inside.wait(5)
+        wr._drain = real
+        assert wr.write([b"parked"], block=False) is True
+        assert wr.deferred == 1 and wr.backlog_bytes == len(b"parked")
+        parked.set()
+        t.join(5)
+        assert not t.is_alive()
+        assert wr.backlog_bytes == 0
+        b.settimeout(5)
+        got = b""
+        while len(got) < len(b"held|parked"):
+            got += b.recv(64)
+        assert got == b"held|parked"
+    finally:
+        a.close()
+        b.close()
+
+
+def test_socket_writer_full_socket_still_says_false():
+    """What is left of False: the socket would block and nobody holds it,
+    so the parked bytes have no one to send them but a later write."""
+    wr, a, b = _writer_pair()
+    try:
+        assert wr.write([b"x" * 1_000_000], block=False) is False
+        assert wr.backlog_bytes > 0
+        # and a contended write behind such bytes: the holder's sweep
+        # meets the full socket too, and its caller hears False
+        assert wr.write([b"y"], block=False) is False
+    finally:
+        a.close()
+        b.close()
+
+
 # -- Outbox -------------------------------------------------------------------
 
 def test_outbox_drains_in_order_across_threads():
@@ -233,6 +294,77 @@ def test_outbox_stall_then_blocking_pump_completes():
 
 
 # -- PushStream ---------------------------------------------------------------
+
+# -- burst / defer ------------------------------------------------------------
+
+def test_burst_runs_each_deferred_flush_once_at_its_end_in_order():
+    ran = []
+    assert wire.defer("a", lambda: ran.append("outside")) is False
+    with wire.burst():
+        for key in ("a", "b", "a", "c", "b"):
+            assert wire.defer(key, lambda key=key: ran.append(key)) is True
+        with wire.burst():  # nested: the outermost flushes
+            assert wire.defer("d", lambda: ran.append("d")) is True
+        assert ran == []
+    assert ran == ["a", "b", "c", "d"]
+    assert wire.defer("a", lambda: ran.append("outside")) is False
+
+
+def test_burst_is_this_threads_alone():
+    seen = []
+    with wire.burst():
+        t = threading.Thread(
+            target=lambda: seen.append(wire.defer("k", lambda: None)))
+        t.start()
+        t.join(5)
+    assert seen == [False]
+
+
+def test_socket_writer_in_a_burst_sends_once_a_connection():
+    """Inside a producer's burst nonblocking writes wait in the backlog
+    and leave in one syscall at its end, in order."""
+    a, b = socket.socketpair()
+    wr = SocketWriter(a)
+    try:
+        with wire.burst():
+            for i in range(10):
+                assert wr.write([b"%d|" % i], block=False) is True
+            assert wr.syscalls == 0 and wr.backlog_bytes == 20
+        assert wr.syscalls == 1 and wr.backlog_bytes == 0
+        assert b.recv(64) == b"0|1|2|3|4|5|6|7|8|9|"
+        # a blocking write inside a burst does not wait for its end
+        with wire.burst():
+            wr.write([b"parked|"], block=False)
+            wr.write([b"now"], block=True)
+            assert b.recv(64) == b"parked|now"
+    finally:
+        a.close()
+        b.close()
+
+
+def test_socket_writer_burst_on_a_full_socket_flushes_by_itself():
+    """The burst's writers heard True, so where the socket is full at its
+    end nobody is left to flush: a thread of the writer's own waits for
+    room, and ends when the backlog is out."""
+    wr, a, b = _writer_pair()
+    payload = b"".join(bytes([i % 251]) * 1000 for i in range(400))
+    try:
+        with wire.burst():
+            assert wr.write([payload], block=False) is True
+        assert wr._flusher  # the socket took a part: nobody reads yet
+        got = bytearray()
+        b.settimeout(10)
+        while len(got) < len(payload):
+            got.extend(b.recv(65536))
+        assert bytes(got) == payload
+        deadline = time.monotonic() + 5
+        while wr._flusher and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert not wr._flusher and wr.backlog_bytes == 0
+    finally:
+        a.close()
+        b.close()
+
 
 def test_push_stream_sink_registration_drains_in_order():
     src = PushStream()
@@ -396,6 +528,68 @@ def test_zero_handoff_cancel_mid_stream_releases_cleanly():
         it.close()  # RST_STREAM
         assert not ch._calls
         assert ch.unary("/t.Stream/Echo", {"after": 1}) == {"after": 1}
+    finally:
+        ch.close()
+        srv.stop()
+
+
+def _burst_server(bursts, k, cancel_after=None):
+    """Streams ``bursts`` x ``k`` tokens, each run of ``k`` pushed inside
+    one ``wire.burst()``, as the engine's reap delivers a decode block."""
+    svc = GRPCService("t.Burst")
+    state = {}
+
+    @svc.server_stream("Tokens")
+    def tokens(ctx, req):
+        src = PushStream()
+
+        def produce():
+            i = 0
+            for _ in range(bursts):
+                with wire.burst():
+                    for _ in range(k):
+                        src._push({"t": i})
+                        i += 1
+                time.sleep(0.002)
+            src._push(None)
+
+        state["thread"] = t = threading.Thread(target=produce, daemon=True)
+        t.start()
+        return ServerStream(src)
+
+    srv = GRPCServer([svc], port=0)
+    srv.start()
+    return srv, state
+
+
+def test_burst_tokens_arrive_complete_ordered_and_coalesced():
+    srv, _ = _burst_server(bursts=25, k=4)
+    ch = dial(f"127.0.0.1:{srv.port}")
+    try:
+        got = [m["t"] for m in ch.server_stream("/t.Burst/Tokens", {})]
+        assert got == list(range(100))
+        conn = next(iter(srv._conns))
+        # a run of four left in one write (and HEADERS, trailers and the
+        # settings exchange in a few more): far fewer than a token each
+        assert conn.io.writer.syscalls < 60
+    finally:
+        ch.close()
+        srv.stop()
+
+
+def test_burst_that_ends_after_a_cancel_writes_nothing_more():
+    """A pump put off to a burst's end may come after the RPC is over:
+    it finds the sender closed and the connection stays usable."""
+    srv, state = _burst_server(bursts=2000, k=4)
+    ch = dial(f"127.0.0.1:{srv.port}")
+    try:
+        it = ch.server_stream("/t.Burst/Tokens", {})
+        assert [next(it)["t"] for _ in range(3)] == [0, 1, 2]
+        it.close()  # RST_STREAM mid-stream, the producer still bursting
+        for _ in range(20):
+            assert ch.unary("/grpc.health.v1.Health/Check", {}) == {
+                "status": "SERVING"}
+            time.sleep(0.005)
     finally:
         ch.close()
         srv.stop()
