@@ -86,7 +86,12 @@ def block_size(smax: int) -> int:
     items in flight and not three (``_KV_BUF``) the same three read
     3.4 / 1.0 / 9.8 at 256: an item's DMA takes 0.64 us and its latency
     is not hidden behind one item's fold; a fourth buffer adds nothing.
-    ``ops.mla`` shares this constant."""
+    ``ops.mla``'s kernel fetches by this block too (its item is one
+    [256, 640] bfloat16 tile, 0.40 us of DMA) but keeps buffers and a
+    loop of its own: four items a trip and eight more in flight, 3.31
+    -> 1.91 ms for nine layers at 128 slots x 2,048 with 36% of the
+    pool live, where three buffers alone read 2.88 (ops/mla.py; PERF.md,
+    Findings PR 44)."""
     from .flash import fit_block
 
     return fit_block(smax, 256)
@@ -107,8 +112,8 @@ def _work_list(lengths, smax: int, block_s: int):
     return ends[-1:].astype(jnp.int32), slot, blk.astype(jnp.int32)
 
 
-_N_BUF = 2     # item w+1 in flight while item w is folded (ops.mla)
-_KV_BUF = 3    # items w+1 and w+2 in flight here: see block_size
+_KV_BUF = 3    # items w+1 and w+2 in flight while item w is folded: see
+#                block_size (ops.mla has a buffer count of its own)
 
 
 def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool,

@@ -21,8 +21,28 @@ Three shapes of it, all float32 scores and softmax:
     stacked cache ``[L, B, Smax, width]`` and the layer index and
     fetches each slot's rows from 0 to its cursor rounded up to the
     block, once and in place: nothing past it, nothing for a slot of
-    length 0, no layer copy (ops/flash_decode.py's work list and
-    double-buffered DMA; one row serves scores and values).
+    length 0, no layer copy (ops/flash_decode.py's work list and block;
+    one row tile serves scores and values).
+
+The kernel's schedule (PERF.md, Findings PR 44; TPU v5e, nine layers a
+call at 128 slots x 2,048 rows x 640 lanes with 36% of the pool live and
+42% fetched, ms a call of which 0.21 are the work lists and selects
+around the kernels): an item is a [256, 640] bfloat16 tile, 0.40 us of
+DMA at the chip's bandwidth, and two matmuls that 64 query rows run
+through a 128-wide matrix unit in about as long. One item a trip of the
+loop with one more in flight, as the kernel was written, paid the two
+one after the other: 3.31. Two in flight: 2.88; three: 2.87. Two items
+a trip, as two chains one after the other in one straight line of code
+so that the second's score matmul is issued while the first's softmax
+runs: 2.40 with two more in flight, 2.12 with four, 2.10 with six.
+Three a trip and six in flight 1.96; FOUR A TRIP AND EIGHT IN FLIGHT
+1.91 (``_CHAINS``, ``_N_BUF``); twelve in flight, six or eight a trip
+the same. With every slot full the same call reads 7.32 -> 4.20, 92% of
+the bandwidth. Read no better and left out: a pair of one slot as ONE
+softmax over 512 positions (2.17 against 2.12; folded alone where the
+pair straddles two slots 2.60), the last division as a reciprocal and a
+product (2.16 against 2.12), the running max and sum kept one lane wide
+(2.11 against 2.12).
 
 Query scale: the caller multiplies the queries by the softmax scale,
 ``(nope + rope)^-1/2`` times YaRN's factor; nothing here knows it.
@@ -46,8 +66,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import NEG_INF
-from .flash_decode import (_LANES, _N_BUF, _SUBLANES, _work_list,
-                           block_size)
+from .flash_decode import _LANES, _SUBLANES, _work_list, block_size
 
 
 @jax.named_scope("mla/prefill_attn")
@@ -120,71 +139,111 @@ def decode_attention_reference(q_cat, rows, row_new, lengths, rank: int):
     return o.astype(q_cat.dtype)
 
 
+_CHAINS = 4             # items a trip of the kernel's loop folds, a chain each
+_N_BUF = 3 * _CHAINS    # tiles in VMEM: a trip's, two more trips' in flight
+
+
 def _decode_kernel(layer_ref, n_ref, slot_ref, blk_ref, len_ref, q_ref,
-                   new_ref, rows_hbm, o_ref, buf, m_ref, l_ref, acc_ref, sem,
-                   *, block_s: int, rank: int):
-    """One layer: walk the (slot, block) work list, fold each item. The
-    row tile serves the score matmul whole and the value matmul by its
-    first ``rank`` lanes."""
+                   new_ref, rows_hbm, o_ref, buf, m_ref, l_ref, acc_ref,
+                   first_ref, sem, *, block_s: int, rank: int):
+    """One layer: walk the (slot, block) work list ``_CHAINS`` items a
+    trip, the next two trips' tiles in flight. A row tile serves the
+    score matmul whole and the value matmul by its first ``rank`` lanes.
+
+    A trip's items are one straight line of code whatever their slots:
+    every score matmul first, then each item's softmax update starting
+    from the item before it, or afresh (a select) where it opens a
+    slot, so that one item's matmuls are issued while another's softmax
+    runs. What needs a branch stands before the line (a slot's first
+    score) or after it (a slot's answer)."""
     layer = layer_ref[0]
     n = n_ref[0]
 
-    def copy(w, b):
+    def copy(w):
         start = pl.multiple_of(blk_ref[w] * block_s, block_s)
         return pltpu.make_async_copy(
             rows_hbm.at[layer, slot_ref[w], pl.ds(start, block_s)],
-            buf.at[b], sem.at[b])
+            buf.at[w % _N_BUF], sem.at[w % _N_BUF])
 
-    @pl.when(n > 0)
-    def _first():
-        copy(0, 0).start()
+    def first_score(slot):
+        # the appended row is the recurrence's first element
+        new = new_ref[slot].astype(jnp.float32)              # [1, width]
+        return jnp.sum(q_ref[slot].astype(jnp.float32) * new, axis=-1,
+                       keepdims=True)                        # [H, 1]
 
-    def item(w, _):
-        b = w % _N_BUF
+    def first_acc(slot):
+        return jnp.broadcast_to(
+            new_ref[slot].astype(jnp.float32)[:, :rank], acc_ref.shape)
 
-        @pl.when(w + 1 < n)
-        def _next():
-            copy(w + 1, (w + 1) % _N_BUF).start()
+    def fold(a, count: int):
+        """Items a .. a + count - 1 (their tiles have landed)."""
+        slots = [slot_ref[a + j] for j in range(count)]
+        blks = [blk_ref[a + j] for j in range(count)]
+        lens = [len_ref[slot] for slot in slots]
 
-        copy(w, b).wait()
-        slot = slot_ref[w]
-        blk = blk_ref[w]
-        length = len_ref[slot]
-        q = q_ref[slot]                                      # [H, width]
+        for j in range(count):
+            @pl.when(blks[j] == 0)
+            def _first():
+                first_ref[j] = jnp.broadcast_to(first_score(slots[j]),
+                                                first_ref.shape[1:])
 
-        @pl.when(blk == 0)
-        def _init():
-            # the appended row is the recurrence's first element
-            new = new_ref[slot].astype(jnp.float32)          # [1, width]
-            s_new = jnp.sum(q.astype(jnp.float32) * new, axis=-1,
-                            keepdims=True)                   # [H, 1]
-            m_ref[...] = jnp.broadcast_to(s_new, m_ref.shape)
-            l_ref[...] = jnp.ones_like(l_ref)
-            acc_ref[...] = jnp.broadcast_to(new[:, :rank], acc_ref.shape)
+        scored = []
+        for j in range(count):
+            tile = buf[(a + j) % _N_BUF]                     # [BS, width]
+            s = jax.lax.dot_general(
+                q_ref[slots[j]], tile, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            pos = blks[j] * block_s + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_s), 1)
+            scored.append((tile, jnp.where(pos < lens[j], s, NEG_INF)))
+        m, l, acc = m_ref[:, :1], l_ref[:, :1], acc_ref[...]
+        answers = []
+        for j, (tile, s) in enumerate(scored):               # s [H, BS]
+            fresh = blks[j] == 0
+            m = jnp.where(fresh, first_ref[j][:, :1], m)
+            l = jnp.where(fresh, 1.0, l)
+            acc = jnp.where(fresh, first_acc(slots[j]), acc)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * corr + jax.lax.dot_general(
+                p.astype(tile.dtype), tile[:, :rank],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m = m_new
+            answers.append((l, acc))
+        m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l, l_ref.shape)
+        acc_ref[...] = acc
+        for j, (l, acc) in enumerate(answers):
+            @pl.when((blks[j] + 1) * block_s >= lens[j])
+            def _done():
+                o_ref[slots[j]] = (acc / l).astype(o_ref.dtype)
 
-        tile = buf[b]                                        # [BS, width]
-        s = jax.lax.dot_general(q, tile, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        pos = blk * block_s + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_s), 1)
-        s = jnp.where(pos < length, s, NEG_INF)              # [H, BS]
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = jnp.broadcast_to(
-            l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
-            l_ref.shape)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(tile.dtype), tile[:, :rank], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    ahead = _N_BUF - _CHAINS
+    for w in range(ahead):
+        @pl.when(w < n)
+        def _start():
+            copy(w).start()
 
-        @pl.when((blk + 1) * block_s >= length)
-        def _done():
-            o_ref[slot] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+    def trip(t, _):
+        a = t * _CHAINS
+        for j in range(_CHAINS):       # into the buffers the last trip left
+            @pl.when(a + ahead + j < n)
+            def _next():
+                copy(a + ahead + j).start()
 
-    jax.lax.fori_loop(0, n, item, None)
+        for j in range(_CHAINS):
+            copy(a + j).wait()
+        fold(a, _CHAINS)
+
+    def alone(w, _):
+        copy(w).wait()
+        fold(w, 1)
+
+    jax.lax.fori_loop(0, n // _CHAINS, trip, None)
+    jax.lax.fori_loop(n - n % _CHAINS, n, alone, None)
 
 
 @functools.partial(jax.jit, static_argnames=("rank", "block_s", "interpret"))
@@ -212,6 +271,7 @@ def decode_attention_stacked(q_cat, rows, row_new, lengths, layer, *,
                 pltpu.VMEM((h_pad, _LANES), jnp.float32),
                 pltpu.VMEM((h_pad, _LANES), jnp.float32),
                 pltpu.VMEM((h_pad, rank), jnp.float32),
+                pltpu.VMEM((_CHAINS, h_pad, _LANES), jnp.float32),
                 pltpu.SemaphoreType.DMA((_N_BUF,))]),
         out_shape=jax.ShapeDtypeStruct((b, h_pad, rank), q_cat.dtype),
         interpret=interpret,

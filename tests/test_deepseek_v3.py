@@ -130,11 +130,38 @@ def test_expanded_and_absorbed_attention_are_the_same_numbers():
     assert np.abs(np.asarray(absorbed - expanded)).max() < 1e-5
 
 
-@pytest.mark.parametrize("lengths,active", [
+_KERNEL_CASES = [
+    # a cache of two 128-row blocks a slot
     ([0, 100, 255], None), ([128, 256, 1], None),
-    ([40, 200, 130], [True, False, True])])
+    ([40, 200, 130], [True, False, True]),
+    # no item at all: nothing cached, and every slot inactive
+    ([0, 0, 0], None), ([100, 200, 50], [False, False, False]),
+    # one, two and three items in all; a slot of length 0 between the two
+    # live ones whose blocks are folded together
+    ([0, 100, 0], None), ([128, 0, 1], None), ([129, 0, 5], None),
+    # the last slot the only live one
+    ([0, 0, 77], None), ([0, 0, 0, 0, 1000], None),
+    # a cache of eight blocks a slot. One slot of exactly one block, of
+    # one block and a row, of an odd and an even number of blocks: a trip
+    # of the loop takes four items, what is left over is folded alone
+    ([0, 0, 128, 0, 0], None), ([0, 129, 0, 0, 0], None),
+    ([384, 0, 0, 0, 0], None), ([0, 0, 0, 512, 0], None),
+    ([0, 0, 640, 0, 0], None), ([0, 897, 0, 0, 0], None),
+    ([0, 0, 0, 0, 1023], None),
+    # a trip whose four items open four slots; trips that straddle slots
+    # at every offset, with dead and inactive slots between live ones
+    ([5, 128, 100, 1, 0], None), ([300, 0, 520, 129, 1000], None),
+    ([130, 257, 385, 513, 641], None), ([1024, 1, 1024, 1, 1024], None),
+    ([640, 333, 1024, 77, 129], [True, False, True, True, False]),
+    ([512, 512, 0, 512, 512], [True, True, True, False, True]),
+]
+
+
+@pytest.mark.parametrize("lengths,active", _KERNEL_CASES)
 def test_the_decode_kernel_interpreted_against_the_reference(lengths, active):
-    L, B, S, W, R, H = 2, 3, 256, 128, 32, 4
+    # the stack's first layer and a middle one: the two stacks' call sites
+    L, B, W, R, H = 3, len(lengths), 128, 32, 4
+    S = 256 if B == 3 else 1024
     k = iter(jax.random.split(jax.random.PRNGKey(4), 4))
     rows = jax.random.normal(next(k), (L, B, S, W))
     q = jax.random.normal(next(k), (B, H, W)) * 0.3
@@ -142,7 +169,7 @@ def test_the_decode_kernel_interpreted_against_the_reference(lengths, active):
     lengths = jnp.asarray(lengths, jnp.int32)
     live = lengths if active is None else jnp.where(jnp.asarray(active),
                                                     lengths, 0)
-    for layer in range(L):
+    for layer in (0, 1):
         want = mla.decode_attention_reference(q, rows[layer], new, live, R)
         got = mla.decode_attention_stacked(q, rows, new, live,
                                            jnp.int32(layer), rank=R,
@@ -479,6 +506,43 @@ def test_engine_counts_the_expert_layers_assignments(params):
     args = [e["args"] for e in obs.timeline.chrome_trace()["traceEvents"]
             if e.get("cat") == "decode"]
     assert args and "moe_assigned" in args[0]
+
+
+def test_the_generator_counts_the_positions_the_kernel_fetches(params,
+                                                               monkeypatch):
+    """``attn.kv_read_pct`` is the generator's count: each decode event
+    carries the positions its block's attention fetched, reckoned from
+    ``decode_kv_block``. The kernel fetches by its work list. One
+    request alone, whose cursor crosses a block's edge: every event's
+    count is the work list's items for that cursor x the kernel's block,
+    so a block changed in one place fails here."""
+    from gofr_tpu.observe import Observe
+    from gofr_tpu.observe.timeline import Timeline
+    from gofr_tpu.ops.flash_decode import _work_list
+
+    monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
+    tl = Timeline(capacity=1024)
+    cfg = CFG.with_(max_seq=512)
+    eng = GenerationEngine(cfg, params, slots=2, max_seq=512,
+                           prompt_buckets=(16, 32),
+                           observe=Observe(timeline=tl))
+    try:
+        smax = eng.cache.rows.shape[2]
+        block = eng.stats()["decode_kv_block"]
+        assert block == ds.decode_kv_block(cfg, eng.cache) \
+            == mla.decode_block(eng.cache.rows, cfg.kv_lora_rank)
+        assert block and 2 * block <= smax
+        prompt = np.random.default_rng(7).integers(1, 256,
+                                                   block - 6).tolist()
+        assert len(eng.generate(prompt, max_new_tokens=12).tokens()) == 12
+    finally:
+        eng.close()
+    events = [e for e in tl.events() if e[3] == "decode"]
+    assert events
+    for e in events:
+        n, _, _ = _work_list(jnp.asarray([e[6], 0], jnp.int32), smax, block)
+        assert e[7] == int(n[0]) * block
+    assert {e[7] for e in events} == {block, 2 * block}
 
 
 class _Tiers:

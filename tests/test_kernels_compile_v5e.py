@@ -110,6 +110,27 @@ def test_append_kernel_compiles_in_place(one_chip, kv, dtype):
     assert mem.temp_size_in_bytes < cache_bytes // 8
 
 
+def test_latent_decode_kernel_compiles(one_chip):
+    """ops/mla.py's kernel at gigachat's cell: nine layers of 128 slots x
+    2,048 rows stored 640 lanes wide, 64 heads over a latent of 512. Its
+    twelve row tiles, four chains a trip and the statistics' scratch
+    fit the VMEM it asks for, and the stacked cache is read in place."""
+    from gofr_tpu.ops import mla
+
+    def arr(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    slots, width, rank = 128, 640, 512
+    rows = arr((9, slots, SMAX, width))
+    compiled = mla.decode_attention_stacked.lower(
+        arr((slots, 64, width)), rows, arr((slots, width)),
+        arr((slots,), jnp.int32), arr((), jnp.int32), rank=rank,
+        block_s=fd.block_size(SMAX)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 9 * slots * SMAX * width * 2 // 64
+
+
 # -- the delta rule's kernels (ops/kda.py) at Solar-Open2's head sizes ---------
 
 KDA_L, KDA_B, KDA_H = 6, 128, 64
