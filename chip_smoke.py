@@ -412,12 +412,12 @@ def kernel_cases(interpret: bool = False):
             # expert is two tiles of the kernel wide; the stacks whole,
             # int8 with a scale an output channel) against the jnp loop:
             # all but two experts get a block, the buffer's tail is dead
-            from gofr_tpu.models import deepseek_v3 as ds
+            from gofr_tpu.models import moe
             from gofr_tpu.models.common import ModelConfig
             from gofr_tpu.ops import moe_experts
             from gofr_tpu.ops.quant import QuantizedLinear
 
-            bm, rows = ds.expert_dispatch(ModelConfig(
+            bm, rows = moe.expert_dispatch(ModelConfig(
                 dim=dim, moe_ffn_dim=ffn, n_experts=held,
                 experts_per_token=top_k), slots)
 
@@ -443,13 +443,13 @@ def kernel_cases(interpret: bool = False):
                               held - 1)
             xs = rand(56, (rows, dim))
             li, n = jnp.int32(layers - 2), jnp.int32(live)
-            leaves = [stacks.get(k) for k in ds.EXPERT_STACKS]
+            leaves = [stacks.get(k) for k in moe.EXPERT_STACKS]
             got = moe_experts.expert_blocks_stacked(
                 xs, blk, n, li,
                 *(None if a is None else a.w for a in leaves),
                 *(None if a is None else a.scale for a in leaves),
                 block_rows=bm, interpret=interpret)
-            ref = jax.jit(ds._blocks_loop, static_argnums=5)(
+            ref = jax.jit(moe.blocks_loop, static_argnums=5)(
                 xs, blk, n, stacks, li, bm)
             dead = float(jnp.abs(got[live * bm:]).max())
             return max(_max_err(got, ref), dead)

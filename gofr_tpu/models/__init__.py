@@ -1,4 +1,20 @@
-"""Model zoo: Llama (flagship decode path), BERT (embeddings), ViT (vision).
+"""Model zoo: six decoder families behind one seam (``family``), BERT
+(embeddings), ViT (vision).
+
+The decoders: ``llama`` (dense GQA and Mixtral's eight experts; the row
+cache and the dense block every family builds on), ``deepseek_v3``
+(latent attention), ``solar_open2`` (gated delta-rule layers beside full
+ones), ``laguna`` (sliding-window layers on a ring), ``lfm2`` (gated
+short convolutions), ``nemotron_h`` (Mamba-2 state-space layers). What
+they share has an owner that is no family: ``moe`` (the routed
+feed-forward of the five sparse ones), ``blocks`` (embedding, the gather
+before the logits, a layer out of a stack, the window and conv families'
+attention block and stack), ``hybrid_cache`` (a state beside rows) and
+``common`` (the configuration and the one list of serving options a
+family may refuse). A family imports those, ``llama`` and ``ops/``, never
+a sibling family nor another module's private name
+(tests/test_models_layering.py; docs/tpu/serving-engine.md says what a
+new family touches).
 
 All models are pure-functional JAX: ``init(cfg, key) -> params`` pytrees of
 plain arrays (or QuantizedLinear leaves), ``apply``-style forwards, static
@@ -9,8 +25,8 @@ layer axis. No torch, no module classes — params are data, which is what
 """
 
 from .common import ModelConfig, LLAMA_CONFIGS, BERT_CONFIGS, VIT_CONFIGS
-from . import (llama, bert, vit, deepseek_v3, solar_open2, laguna, lfm2,
-               nemotron_h)
+from . import (llama, bert, vit, moe, blocks, hybrid_cache, deepseek_v3,
+               solar_open2, laguna, lfm2, nemotron_h)
 
 
 def family(cfg: ModelConfig):
@@ -36,5 +52,6 @@ def family(cfg: ModelConfig):
 
 
 __all__ = ["ModelConfig", "LLAMA_CONFIGS", "BERT_CONFIGS", "VIT_CONFIGS",
-           "llama", "bert", "vit", "deepseek_v3", "solar_open2", "laguna",
-           "lfm2", "nemotron_h", "family"]
+           "llama", "bert", "vit", "moe", "blocks", "hybrid_cache",
+           "deepseek_v3", "solar_open2", "laguna", "lfm2", "nemotron_h",
+           "family"]

@@ -262,6 +262,35 @@ VIT_CONFIGS = {
 }
 
 
+def refused_options(reasons: dict[str, str], *, mesh=None,
+                    paged_blocks: int = 0, kvcache=None,
+                    spec_decode_k: int = 0, lora_adapters: int = 0,
+                    kv_dtype=None, serving_role: str | None = None
+                    ) -> list[tuple[str, str]]:
+    """(engine option, reason) for every serving option that is asked for
+    and that a family does not run yet, in the engine constructor's own
+    names and order. The engine raises on any of them at start-up: never
+    a fall-through to the Llama cache. ``reasons``: the family's own
+    words, option -> why not; ``kv_dtype``'s is said of an int8 row (a
+    family that caches one has no such entry), ``serving_role``'s after
+    the role that was asked for."""
+    asked = {
+        "mesh": mesh is not None,
+        "paged_blocks": bool(paged_blocks),
+        "kvcache": kvcache is not None and (kvcache.host_mb > 0
+                                            or kvcache.redis is not None),
+        "spec_decode_k": bool(spec_decode_k),
+        "lora_adapters": bool(lora_adapters),
+        "kv_dtype": kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8,
+        "serving_role": serving_role not in (None, "", "fused"),
+    }
+    said = dict(reasons)
+    if "serving_role" in said:
+        said["serving_role"] = f"{serving_role}: {said['serving_role']}"
+    return [(option, said[option]) for option, is_asked in asked.items()
+            if is_asked and option in said]
+
+
 def dense_init(key, shape, dtype, scale: float | None = None) -> jnp.ndarray:
     """Truncated-normal fan-in init."""
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]

@@ -25,27 +25,10 @@ stream, RMSNorm before attention and before the feed-forward):
     them (the published code first gathers even and odd dims; with
     seeded weights that is a permutation of the projection's columns).
   - feed-forward: the first ``n_dense_layers`` layers are SwiGLU of
-    width ``ffn_dim``; the others route: ``s = sigmoid(x W_g)`` in
-    float32, selection by ``s + bias`` limited to the ``topk_groups``
-    best of ``n_expert_groups`` groups (a group's score: its two
-    largest), top ``experts_per_token`` inside them, weights
-    ``s_i / sum s_j * routed_scaling`` (the bias selects, it does not
-    weigh), plus ``n_shared_experts`` shared experts always on.
-  - the chip's share: the layer holds experts ``0 .. n_experts_held-1``
-    of the ``n_experts`` the router scores, routes exactly as published
-    and sums over the HELD experts a token chose; what the absent ones
-    would add is left out, and that partial sum goes on. Nothing stands
-    in for the other chips.
-
-Expert dispatch (``_experts``): the (token, held expert) assignments
-stand expert by expert in row blocks of ``block`` rows, each block one
-expert's (their rows counted, not sorted: ``_tables``; the buffer filled
-by a 0/1 matmul: ``_fill``), and the blocks THAT EXIST run one SwiGLU
-each (one kernel
-over them, ``ops/moe_experts.py``, or a loop where that cannot run), so
-FLOPs follow the assignments. No capacity, no token dropped,
-and a token's result does not depend on what else is in the batch: a
-block's rows are independent rows of one matmul.
+    width ``ffn_dim``; the others route through ``models/moe.py``
+    (grouped sigmoid router, ``n_shared_experts`` shared experts always
+    on, ``n_experts_held`` of ``n_experts`` held here: the equations,
+    the chip's share and the dispatch are said there).
 
 Layers are two stacks, ``params["dense_layers"]`` and
 ``params["layers"]`` (the routed ones), each scanned; cache layer ``l``
@@ -54,22 +37,20 @@ is dense layer ``l`` or routed layer ``l - n_dense_layers``.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from ..ops import mla, moe_experts
-from ..ops.flash import interpret_env
+from ..ops import mla
 from ..ops.norms import rms_norm
 from ..ops.quant import QuantizedLinear, qmatmul
 from ..ops.rope import apply_rope, rope_frequencies, yarn_softmax_scale
-from .common import ModelConfig, dense_init
-from .llama import _logits
+from . import llama, moe
+from .blocks import embed, experts_apart, prompt_rows
+from .common import ModelConfig, dense_init, refused_options
 
-# the leaves of a routed expert [Ls, Eh, ...]: all three a SwiGLU, the
-# last two a two-matrix relu^2 expert (``expert_stacks``)
-EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 _LANES = 128
 _ROPE_CACHE: dict[tuple, tuple] = {}
 RECOMPUTABLE = True  # rows, as llama's: see models.family
@@ -99,9 +80,6 @@ def stored_width(cfg: ModelConfig) -> int:
     tiles of 128 lanes (576 -> 640; ops/mla.py says why)."""
     return -(-row_width(cfg) // _LANES) * _LANES
 
-
-def n_held(cfg: ModelConfig) -> int:
-    return cfg.n_experts_held or cfg.n_experts
 
 
 class LatentCache(NamedTuple):
@@ -135,64 +113,25 @@ def decode_kv_block(cfg: ModelConfig, cache: LatentCache, mesh=None):
     return mla.decode_block(cache.rows, cfg.kv_lora_rank)
 
 
-def unsupported_options(*, mesh=None, paged_blocks: int = 0, kvcache=None,
-                        spec_decode_k: int = 0, lora_adapters: int = 0,
-                        kv_dtype=None, serving_role: str | None = None
-                        ) -> list[tuple[str, str]]:
-    """(engine option, reason) for every serving option this family does
-    not run yet, in the engine constructor's own names. The engine raises
-    on any of them at start-up: never a fall-through to the Llama cache."""
-    refused = []
-    if mesh is not None:
-        refused.append(("mesh", "the latent row is shared by all heads and "
-                        "the expert share has no exchange across chips; "
-                        "the family runs on one chip"))
-    if paged_blocks:
-        refused.append(("paged_blocks", "the block pool holds K and V a "
-                        "head, not latent rows"))
-    if kvcache is not None and (kvcache.host_mb > 0
-                                or kvcache.redis is not None):
-        refused.append(("kvcache", "the host and Redis tiers frame K and V "
-                        "a head"))
-    if spec_decode_k:
-        refused.append(("spec_decode_k", "there is no verify pass over "
-                        "latent rows"))
-    if lora_adapters:
-        refused.append(("lora_adapters", "adapters target wq/wk/wv/wo, "
-                        "which this family does not have"))
-    if kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8:
-        refused.append(("kv_dtype", "int8: the latent row is cached in the "
-                        "model's type (bfloat16)"))
-    if serving_role not in (None, "", "fused"):
-        refused.append(("serving_role", f"{serving_role}: KV shipping "
-                        "frames K and V a head"))
-    return refused
+# the serving options this family does not run yet, and why (the engine
+# raises on any of them at start-up)
+REFUSED = {
+    "mesh": "the latent row is shared by all heads and the expert share "
+            "has no exchange across chips; the family runs on one chip",
+    "paged_blocks": "the block pool holds K and V a head, not latent rows",
+    "kvcache": "the host and Redis tiers frame K and V a head",
+    "spec_decode_k": "there is no verify pass over latent rows",
+    "lora_adapters": "adapters target wq/wk/wv/wo, which this family does "
+                     "not have",
+    "kv_dtype": "int8: the latent row is cached in the model's type "
+                "(bfloat16)",
+    "serving_role": "KV shipping frames K and V a head",
+}
+unsupported_options = functools.partial(refused_options, REFUSED)
 
-
-def init_routed(keys, cfg: ModelConfig, L: int) -> dict:
-    """Random-init leaves of ``L`` routed feed-forwards (what ``moe_ffn``
-    reads), one key of ``keys`` a leaf in this order: the router
-    ``n_experts`` wide and its bias whole, ``n_held`` experts in their
-    form (``expert_stacks``) and width (``expert_width``), the shared
-    experts' leaves where the configuration has any, and the latent's
-    two projections where it has one."""
-    dt, D, Dx = cfg.jdtype, cfg.dim, expert_width(cfg)
-    E, Eh, Fm = cfg.n_experts, n_held(cfg), cfg.moe_ffn_dim
-    Fs = cfg.shared_ffn_dim or Fm * cfg.n_shared_experts
-    w = {"router": dense_init(next(keys), (L, D, E), dt),
-         "router_bias": 0.01 * jax.random.normal(next(keys), (L, E),
-                                                 jnp.float32)}
-    for name in expert_stacks(cfg):
-        shape = (L, Eh, Fm, Dx) if name == "w_down" else (L, Eh, Dx, Fm)
-        w[name] = dense_init(next(keys), shape, dt)
-    if Fs:
-        for name in expert_stacks(cfg):
-            shape = (L, Fs, D) if name == "w_down" else (L, D, Fs)
-            w["ws" + name[1:]] = dense_init(next(keys), shape, dt)
-    if cfg.moe_latent_dim:
-        w.update(w_latent_down=dense_init(next(keys), (L, D, Dx), dt),
-                 w_latent_up=dense_init(next(keys), (L, Dx, D), dt))
-    return w
+# what ``GenerationEngine.stats()`` says of this family: the decode step's
+# expert dispatch
+serving_stats = moe.serving_stats
 
 
 def init(cfg: ModelConfig, key) -> dict:
@@ -230,300 +169,13 @@ def init(cfg: ModelConfig, key) -> dict:
         },
         "layers": {
             **attn(ns),
-            **init_routed(k, cfg, ns),
+            **moe.init_routed(k, cfg, ns),
         },
         "final_norm": jnp.ones((D,), dt),
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(next(k), (D, V), dt)
     return params
-
-
-# -- the expert layer ----------------------------------------------------------
-
-@jax.named_scope("moe/route")
-def route(hf, router, bias, cfg: ModelConfig):
-    """hf [T, D] -> (expert ids [T, k], weights [T, k] float32), over all
-    ``n_experts`` as published: float32 sigmoid scores; ``s + bias``
-    selects (groups by the sum of their two best, then the top k inside
-    the kept groups); the weights are the unbiased scores, renormalised
-    and scaled."""
-    T = hf.shape[0]
-    E, G = cfg.n_experts, cfg.n_expert_groups
-    s = jax.nn.sigmoid(jnp.dot(hf.astype(jnp.float32),
-                               router.astype(jnp.float32),
-                               precision=jax.lax.Precision.HIGHEST))
-    sel = s + bias.astype(jnp.float32)
-    group = jnp.sum(jax.lax.top_k(sel.reshape(T, G, E // G), 2)[0], -1)
-    kept = jnp.sum(jax.nn.one_hot(jax.lax.top_k(group, cfg.topk_groups)[1],
-                                  G, dtype=jnp.bool_), axis=1)     # [T, G]
-    sel = jnp.where(jnp.repeat(kept, E // G, axis=1), sel, -jnp.inf)
-    topi = jax.lax.top_k(sel, cfg.experts_per_token)[1]
-    w = jnp.take_along_axis(s, topi, axis=1)
-    w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20) * cfg.routed_scaling
-    return topi, w
-
-
-def _swiglu(x, gate, up, down):
-    return qmatmul(jax.nn.silu(qmatmul(x, gate)) * qmatmul(x, up), down)
-
-
-def _relu2(x, up, down):
-    """The two-matrix expert: ``W2 relu(W1 x)^2``, no gate."""
-    return qmatmul(jnp.square(jax.nn.relu(qmatmul(x, up))), down)
-
-
-def expert_width(cfg: ModelConfig) -> int:
-    """The width the routed experts read and write, and so the width of
-    the dispatch: a latent's where the configuration has one, else the
-    model's."""
-    return cfg.moe_latent_dim or cfg.dim
-
-
-def expert_stacks(cfg: ModelConfig) -> tuple[str, ...]:
-    """The leaves of one routed expert, by its form."""
-    return EXPERT_STACKS if cfg.expert_act == "swiglu" else EXPERT_STACKS[1:]
-
-
-def expert_dispatch(cfg: ModelConfig, tokens: int) -> tuple[int, int]:
-    """(rows of a dispatch block, rows of the padded dispatch buffer) for
-    ``tokens`` tokens. A block is one bfloat16 sublane tile for a decode
-    batch (a few tokens an expert), more where a prompt brings many; the
-    buffer holds at most min(k, held) held assignments a token and less
-    than a block of padding an expert."""
-    bm = 16 if tokens <= 128 else 64
-    Eh = n_held(cfg)
-    nb_max = (tokens * min(cfg.experts_per_token, Eh)
-              + Eh * (bm - 1) + bm - 1) // bm
-    return bm, nb_max * bm
-
-
-def experts_on_kernel(cfg: ModelConfig, dtype=None) -> bool:
-    """Whether ``_experts`` runs its blocks through the kernel of
-    ``ops/moe_experts.py`` (chosen from backend, widths and the
-    activations' type) or through the jnp loop it is tested against."""
-    return moe_experts.kernel_ok(expert_width(cfg), cfg.moe_ffn_dim,
-                                 dtype or cfg.jdtype,
-                                 len(expert_stacks(cfg)))
-
-
-def serving_stats(cfg: ModelConfig, slots: int) -> dict:
-    """What ``GenerationEngine.stats()`` says of the programs of a family
-    that routes through ``moe_ffn``: the decode step's expert dispatch
-    shapes (the device operations that tall are the routed experts':
-    benchmarks/metrics reads them here), the path its blocks take and
-    how the dispatch tables are built (``_tables``)."""
-    bm, rows = expert_dispatch(cfg, slots)
-    said = {"block_rows": bm, "buffer_rows": rows,
-            "width": expert_width(cfg), "path": "loop",
-            "tables": "counted"}
-    if experts_on_kernel(cfg):
-        # columns of the expert width a grid step takes, by the weights'
-        # type: fewer than the width where an expert's tiles are over
-        # the kernel's budget
-        said.update(path="kernel", tile_columns={
-            name: moe_experts.tile_columns(expert_width(cfg),
-                                           cfg.moe_ffn_dim, size,
-                                           len(expert_stacks(cfg)))
-            for name, size in (("int8", 1),
-                               (cfg.dtype, cfg.jdtype.itemsize))})
-    return {"moe_decode_dispatch": said}
-
-
-def _blocks_loop(xs, blk_expert, n_blocks, stacks, li, bm: int):
-    """The dispatch buffer's live blocks through their experts, one loop
-    turn a block: xs [rows, D] -> [rows, D], zero past ``n_blocks``."""
-    def one(a, e):  # a [Ls, Eh, ...] -> a[li, e]
-        return jax.lax.dynamic_index_in_dim(
-            a.reshape((-1,) + a.shape[2:]), li * a.shape[1] + e, 0,
-            keepdims=False)
-
-    def at(leaf, e):
-        if isinstance(leaf, QuantizedLinear):
-            return QuantizedLinear(one(leaf.w, e), one(leaf.scale, e))
-        return one(leaf, e)
-
-    def body(j, out):
-        e = blk_expert[j]
-        x = jax.lax.dynamic_slice_in_dim(xs, j * bm, bm, axis=0)
-        up, down = at(stacks["w_up"], e), at(stacks["w_down"], e)
-        y = _swiglu(x, at(stacks["w_gate"], e), up, down) \
-            if "w_gate" in stacks else _relu2(x, up, down)
-        return jax.lax.dynamic_update_slice_in_dim(out, y, j * bm, axis=0)
-
-    return jax.lax.fori_loop(0, n_blocks, body, jnp.zeros_like(xs))
-
-
-def _blocks_kernel(xs, blk_expert, n_blocks, stacks, li, bm: int,
-                   tile: int | None = None):
-    """``_blocks_loop`` as one kernel over the blocks that exist (``tile``:
-    columns of the expert width a grid step takes, from the shapes
-    unless a test says)."""
-    # a stack without a gate hands the kernel None in its place
-    leaves = [stacks.get(name) for name in EXPERT_STACKS]
-    scales = ()
-    if isinstance(leaves[-1], QuantizedLinear):
-        scales = tuple(None if leaf is None else leaf.scale
-                       for leaf in leaves)
-        leaves = [None if leaf is None else leaf.w for leaf in leaves]
-    return moe_experts.expert_blocks_stacked(
-        xs, blk_expert, n_blocks, li, *leaves, *scales, block_rows=bm,
-        tile=tile, interpret=interpret_env())
-
-
-# tokens one triangular matmul counts: a decode batch or a 512-token chunk
-# is one pass, and no [T, T] matrix is ever larger than half a megabyte
-_COUNT_ROWS = 512
-
-
-def _counted(chose):
-    """chose [T, E1] 0/1, a row a token and a column a key -> seen
-    [T, E1] int32: the tokens 0..t that chose the column's key, token t
-    counted. Chunks of ``_COUNT_ROWS`` tokens against a lower-triangular
-    0/1 matrix on the matrix unit (0/1 in bfloat16, float32 sums of at
-    most a chunk's rows: exact), and the chunks before a chunk added in
-    int32: no scan down the rows, and one chunk is the whole of it."""
-    T, E1 = chose.shape
-    c = min(T, _COUNT_ROWS)
-    C = -(-T // c)
-    # rows past T chose nothing: they count for nobody
-    chunks = jnp.pad(chose, ((0, C * c - T), (0, 0))).reshape(C, c, E1)
-    i, j = jnp.arange(c), jnp.arange(C)
-    inside = jnp.einsum(
-        "ij,cje->cie", (i[None, :] <= i[:, None]).astype(jnp.bfloat16),
-        chunks.astype(jnp.bfloat16),
-        preferred_element_type=jnp.float32).astype(jnp.int32)
-    before = jnp.sum(jnp.where((j[None, :] < j[:, None])[..., None],
-                               inside[None, :, -1], 0), axis=1)   # [C, E1]
-    return (inside + before[:, None]).reshape(C * c, E1)[:T]
-
-
-@jax.named_scope("tables")
-def _tables(topi, valid, Eh: int, bm: int, nb_max: int):
-    """Where each assignment goes in the padded dispatch buffer, by
-    counting: topi [T, K], a token's K experts all different as a top-k
-    gives them (one at or past ``Eh`` is not held), valid [T] bool or
-    None -> (assignments a held expert [Eh], blocks that hold rows, each
-    block's expert [nb_max], dest [T, K]: the assignment's row of the
-    buffer, at or past ``nb_max * bm`` where it is not dispatched).
-
-    An assignment's key is its held expert (``Eh``: none), and its row is
-    its expert's offset, a multiple of ``bm``, plus the earlier tokens
-    that chose the same: what a stable sort by key gives, with no sort
-    and no gather from a table. ``offset[key]`` and ``seen[t, key]`` are
-    picked by the key's one-hot row inside one reduction."""
-    held = topi < Eh
-    if valid is not None:
-        held = held & valid[:, None]
-    key = jnp.where(held, topi, Eh).astype(jnp.int32)      # [T, K]
-    onehot = key[..., None] == jnp.arange(Eh + 1, dtype=jnp.int32)
-    seen = _counted(jnp.any(onehot, axis=1))               # [T, Eh + 1]
-    counts = seen[-1, :Eh]
-    nblk = jax.lax.div(counts + (bm - 1), bm)              # none negative
-    e = jnp.arange(Eh)
-    # a running sum as a masked [Eh, Eh] reduction: it fuses with what
-    # reads it, where a cumsum is a reduce-window and a copy of their own
-    blk_end = jnp.sum(jnp.where(e[None, :] <= e[:, None], nblk[None, :], 0),
-                      axis=1)
-    pad_start = (blk_end - nblk) * bm                      # buffer offset
-    n_blocks = jnp.sum(nblk)
-    # a block's expert: the experts that end at or before it, and a block
-    # past the last expert's end is the last expert's
-    blk_expert = jnp.sum(
-        blk_end[None, :-1] <= jnp.arange(nb_max)[:, None],
-        axis=1).astype(jnp.int32)                          # [nb_max]
-    # the key that is no expert starts past the buffer's end
-    offset = jnp.concatenate(
-        [pad_start, jnp.full((1,), nb_max * bm, jnp.int32)])
-    dest = jnp.sum(jnp.where(onehot, (seen - 1 + offset)[:, None], 0),
-                   axis=2)
-    return counts, n_blocks, blk_expert, dest
-
-
-@jax.named_scope("fill")
-def _fill(hf, dest, valid, rows: int):
-    """The dispatch buffer [rows, D]: row ``dest[t, k]`` is token t's
-    ``hf[t]``, every other row zeros. A 0/1 matrix [rows, T] times ``hf``
-    on the matrix unit: one 1 a row at most and float32 accumulation, so
-    a row arrives bit for bit (a row that is no token is zeroed first:
-    whatever an idle slot holds meets only zeros)."""
-    r = jnp.arange(rows, dtype=jnp.int32)
-    put = jnp.any(dest[None] == r[:, None, None], axis=2)  # [rows, T]
-    if valid is not None:
-        hf = jnp.where(valid[:, None], hf, 0)
-    return jnp.dot(put.astype(hf.dtype), hf,
-                   precision=jax.lax.Precision.HIGHEST,
-                   preferred_element_type=jnp.float32).astype(hf.dtype)
-
-
-@jax.named_scope("moe/experts")
-def _experts(hf, topi, w, stacks, li, cfg: ModelConfig, valid=None):
-    """Sum over the HELD experts each token chose, weighted.
-
-    hf [T, D]: what the experts read, D the dispatch's width (the
-    model's, or a latent's: ``expert_width``); topi/w [T, k] from
-    ``route``; stacks: the routed stack's expert weights WHOLE,
-    [Ls, Eh, ...] (with ``w_gate``: SwiGLU; without: relu^2), and ``li``
-    the layer's index in
-    them (a block's matmul reads expert (li, e) in place; handed the
-    layer's slice, the layer loop copies all Eh experts out of the stack
-    every layer, every step: 18.7 of a 36.5 ms step, PERF.md Findings
-    PR 28); valid [T] bool: rows that are tokens (padding and idle slots
-    are not dispatched). Returns (y [T, D], assignments a held expert
-    [Eh] int32, blocks run: int32 scalar).
-
-    The assignments stand in a padded buffer by expert, in the order
-    they come within one (``_tables``: counted, not sorted; absent
-    experts and invalid rows nowhere); expert e's rows start at a
-    multiple of ``block``, so every block of it is one expert's; the
-    blocks that hold rows run one expert each, in one kernel
-    (``ops.moe_experts.expert_blocks_stacked``) or, where that cannot
-    run (``experts_on_kernel``), a while loop of the same arithmetic."""
-    bm, rows = expert_dispatch(cfg, hf.shape[0])
-    counts, n_blocks, blk_expert, dest = _tables(topi, valid, n_held(cfg),
-                                                 bm, rows // bm)
-    xs = _fill(hf, dest, valid, rows)
-    if experts_on_kernel(cfg, hf.dtype):
-        out = _blocks_kernel(xs, blk_expert, n_blocks, stacks, li, bm)
-    else:
-        out = _blocks_loop(xs, blk_expert, n_blocks, stacks, li, bm)
-    # an assignment that was not dispatched reads the last row, times 0
-    y = out[jnp.minimum(dest, rows - 1)].astype(jnp.float32) \
-        * jnp.where(dest < rows, w, 0.0)[..., None]
-    return jnp.sum(y, axis=1).astype(hf.dtype), counts, n_blocks
-
-
-def moe_ffn(h, lw, cfg: ModelConfig, valid=None):
-    """The routed feed-forward of one layer: h [B, S, D] ->
-    (y [B, S, D], assignments a held expert [Eh]). ``lw["experts"]`` is
-    (the expert stacks whole, this layer's index in them). The router
-    reads the full width; where the layer has a latent
-    (``w_latent_down``/``w_latent_up``) the experts read it, projected
-    down once a token before the dispatch, and their weighted sum is
-    projected up once a token after it."""
-    B, S, D = h.shape
-    hf = h.reshape(B * S, D)
-    topi, w = route(hf, lw["router"], lw["router_bias"], cfg)
-    xe = hf
-    if "w_latent_down" in lw:
-        with jax.named_scope("moe/latent_down"):
-            xe = qmatmul(hf, lw["w_latent_down"])
-    y, counts, _ = _experts(xe, topi, w, *lw["experts"], cfg,
-                            None if valid is None else valid.reshape(B * S))
-    if "w_latent_up" in lw:
-        with jax.named_scope("moe/latent_up"):
-            y = qmatmul(y, lw["w_latent_up"])
-    if "ws_up" in lw:     # n_shared_experts 0: no leaves, nothing added
-        with jax.named_scope("moe/shared"):
-            y = y + (_swiglu(hf, lw["ws_gate"], lw["ws_up"], lw["ws_down"])
-                     if "ws_gate" in lw
-                     else _relu2(hf, lw["ws_up"], lw["ws_down"]))
-    return y.reshape(B, S, D), counts
-
-
-def dense_ffn(h, lw, cfg: ModelConfig, valid=None):
-    with jax.named_scope("dense_mlp"):
-        return _swiglu(h, lw["w_gate"], lw["w_up"], lw["w_down"]), None
 
 
 # -- attention -----------------------------------------------------------------
@@ -619,10 +271,10 @@ def _two_stacks(params, cfg: ModelConfig, x, body, per_layer):
 
     def run(x, stack, lo, hi, ffn):
         extra = jax.tree_util.tree_map(lambda a: a[lo:hi], per_layer)
-        # the expert stacks stay whole beside the scan (``_experts``)
-        whole = {k: v for k, v in params[stack].items()
-                 if k in EXPERT_STACKS and ffn is moe_ffn}
-        sliced = {k: v for k, v in params[stack].items() if k not in whole}
+        # the expert stacks stay whole beside the scan (``layer_at``
+        # says why); a dense stack's leaves of the same names are sliced
+        whole, sliced = experts_apart(params[stack]) \
+            if ffn is moe.moe_ffn else ({}, params[stack])
 
         def step(x, xs):
             lw, ex, i = xs
@@ -633,8 +285,8 @@ def _two_stacks(params, cfg: ModelConfig, x, body, per_layer):
         return jax.lax.scan(step, x, (sliced, extra,
                                       jnp.arange(hi - lo, dtype=jnp.int32)))
 
-    x, ys_d = run(x, "dense_layers", 0, nd, dense_ffn)
-    x, ys_s = run(x, "layers", nd, cfg.n_layers, moe_ffn)
+    x, ys_d = run(x, "dense_layers", 0, nd, moe.dense_ffn)
+    x, ys_s = run(x, "layers", nd, cfg.n_layers, moe.moe_ffn)
     return x, ys_d, ys_s
 
 
@@ -646,12 +298,9 @@ def prefill_kv(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     """Causal forward over [B, S] tokens (right-padded), attention
     expanded. Returns (logits [B, S, V] float32, or [B, 1, V] with
     ``logit_pos``; rows [L, B, S, stored_width]; lengths [B])."""
-    B, S = tokens.shape
-    if lengths is None:
-        lengths = jnp.full((B,), S, jnp.int32)
-    cos, sin = rope_tables or get_rope_tables(cfg, rope_max or S)
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    valid = positions < lengths[:, None]
+    lengths, positions, valid = prompt_rows(tokens, lengths)
+    cos, sin = rope_tables or get_rope_tables(cfg,
+                                              rope_max or tokens.shape[1])
 
     def attend(q, row, w_kvb):
         k_nope, k_pe, v = _expand(row, w_kvb, cfg)
@@ -662,14 +311,10 @@ def prefill_kv(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                            valid)
         return x, row
 
-    with jax.named_scope("embed"):
-        x = params["embedding"][tokens].astype(cfg.jdtype)
-    x, rows_d, rows_s = _two_stacks(params, cfg, x, body, None)
-    if logit_pos is not None:
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)
-    return (_logits(params, cfg, x), jnp.concatenate([rows_d, rows_s]),
-            lengths)
+    x, rows_d, rows_s = _two_stacks(params, cfg, embed(params, cfg, tokens),
+                                    body, None)
+    return (llama.logits_at(params, cfg, x, logit_pos),
+            jnp.concatenate([rows_d, rows_s]), lengths)
 
 
 def forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -713,17 +358,13 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
         x, row, _ = _layer(x, lw, cfg, cos, sin, positions, attend, ffn)
         return x, row
 
-    with jax.named_scope("embed"):
-        x = params["embedding"][tokens].astype(cfg.jdtype)
-    x, rows_d, rows_s = _two_stacks(params, cfg, x, body, cache.rows)
+    x, rows_d, rows_s = _two_stacks(params, cfg, embed(params, cfg, tokens),
+                                    body, cache.rows)
     cache = write_kv(cache, jnp.concatenate([rows_d, rows_s]),
                      (0, 0, start, 0), cache.lengths)
     if not compute_logits:
         return None, cache
-    if logit_pos is not None:
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)
-    return _logits(params, cfg, x), cache
+    return llama.logits_at(params, cfg, x, logit_pos), cache
 
 
 def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -758,10 +399,9 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                                 ffn, valid)
         return x, (row[:, 0], counts)
 
-    with jax.named_scope("embed"):
-        x = params["embedding"][tokens[:, None]].astype(cfg.jdtype)
     x, (rows_d, _), (rows_s, counts) = _two_stacks(
-        params, cfg, x, body, jnp.arange(cfg.n_layers, dtype=jnp.int32))
+        params, cfg, embed(params, cfg, tokens[:, None]), body,
+        jnp.arange(cfg.n_layers, dtype=jnp.int32))
     with jax.named_scope("kv_write"):
         # one update a (layer, slot) with the row as its window: with the
         # layer axis in the window too (``.at[:, slots, lengths]``) the
@@ -774,4 +414,4 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                           jnp.arange(B)[None, :],
                           lengths[None, :]].set(rows, mode="drop"),
             lengths + 1)
-    return _logits(params, cfg, x[:, 0]), new, counts
+    return llama.logits(params, cfg, x[:, 0]), new, counts

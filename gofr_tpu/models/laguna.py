@@ -25,45 +25,41 @@ stream, pre-norm blocks: ``x += Attn(RMSNorm(x)); x += FFN(RMSNorm(x))``.
     (``ops.flash_decode.ring_rows``), through the same kernel as the
     full layers.
   - both kinds gate the heads' outputs where ``head_gate``:
-    ``y = W_o concat(sigmoid(W_g h)_head * o_head)``, one value a head.
+    ``y = W_o concat(sigmoid(W_g h)_head * o_head)``, one value a head
+    (``blocks.attention``, which the conv family's full layers run too).
   - the first ``n_dense_layers`` layers' feed-forward is SwiGLU of width
-    ``ffn_dim``; every other layer's is ``deepseek_v3``'s expert layer
-    (``moe_ffn``: sigmoid router, a shared expert), imported and not
-    copied.
+    ``ffn_dim``; every other layer's is the routed one of
+    ``models/moe.py`` (``moe_ffn``: sigmoid router, a shared expert).
 
 Weights are stacked a kind of attention (``params["full"]``,
 ``params["window"]``) and a kind of feed-forward (``params["dense"]``,
-``params["moe"]``); the stacks stay whole and a layer's weights are
-indexed where they are used. The periods that hold a dense layer run one
-after another; the rest are scanned a period at a time, so compile time
-stays flat in depth past them.
+``params["moe"]``) and run by ``blocks.period_stack``: the stacks stay
+whole and a layer's weights are indexed where they are used; the periods
+that hold a dense layer run one after another, the rest are scanned a
+period at a time.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from ..ops import flash_decode
-from ..ops.attention import (causal_attention, chunk_attention,
-                             decode_attention_appended, ring_chunk_attention,
-                             ring_held)
+from ..ops.attention import (chunk_attention, decode_attention_appended,
+                             ring_chunk_attention, ring_held)
 from ..ops.flash import interpret_env
 from ..ops.norms import rms_norm
-from ..ops.quant import qmatmul
-from ..ops.rope import apply_rope_part
-from . import deepseek_v3, llama
-from .common import ModelConfig, dense_init
-from .deepseek_v3 import EXPERT_STACKS, dense_ffn, moe_ffn
-from .llama import _logits
+from . import llama, moe
+from .blocks import attention, embed, period_stack, prompt_attend, prompt_rows
+from .common import ModelConfig, dense_init, refused_options
 
 # a ring row that has been overwritten is gone: the chunk lattice runs
 # left-aligned, and a prefix-pool row is usable only at the position its
 # rings were taken
 RECOMPUTABLE = False
-F32 = jnp.float32
 KINDS = ("full", "window")
 
 
@@ -144,49 +140,33 @@ def _row_bytes(cfg: ModelConfig) -> int:
 
 def serving_stats(cfg: ModelConfig, slots: int) -> dict:
     """What ``GenerationEngine.stats()`` says of this family: the decode
-    step's expert dispatch shapes and path (the latent family's word),
+    step's expert dispatch shapes and path (``moe.serving_stats``),
     the rows of a ring and the bytes a slot's rings take whatever its
     length, and the bytes a cached token takes in the full layers, in
     the model's type (benchmarks/metrics reads them here)."""
     n = counts(cfg)
-    return {**deepseek_v3.serving_stats(cfg, slots),
+    return {**moe.serving_stats(cfg, slots),
             "window_rows": cfg.window_size,
             "window_bytes_per_slot": n["window"] * cfg.window_size
             * _row_bytes(cfg),
             "kv_bytes_per_token": n["full"] * _row_bytes(cfg)}
 
 
-def unsupported_options(*, mesh=None, paged_blocks: int = 0, kvcache=None,
-                        spec_decode_k: int = 0, lora_adapters: int = 0,
-                        kv_dtype=None, serving_role: str | None = None
-                        ) -> list[tuple[str, str]]:
-    """(engine option, reason) for every serving option that takes a
-    slot's memory to be whole rows; the engine raises on any of them at
-    start-up."""
-    refused = []
-    if mesh is not None:
-        refused.append(("mesh", "the rings and the expert layer have no "
-                        "sharding rule; the family runs on one chip"))
-    if paged_blocks:
-        refused.append(("paged_blocks", "the block pool holds whole K and "
-                        "V rows, not a ring"))
-    if kvcache is not None and (kvcache.host_mb > 0
-                                or kvcache.redis is not None):
-        refused.append(("kvcache", "the host and Redis tiers frame whole K "
-                        "and V rows; a ring would not travel with them"))
-    if spec_decode_k:
-        refused.append(("spec_decode_k", "a rejected draft's rows have "
-                        "already overwritten the ring's oldest"))
-    if lora_adapters:
-        refused.append(("lora_adapters", "adapters target the llama "
-                        "block's projections"))
-    if kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8:
-        refused.append(("kv_dtype", "int8: rows and rings are cached in "
-                        "the model's type"))
-    if serving_role not in (None, "", "fused"):
-        refused.append(("serving_role", f"{serving_role}: KV shipping "
-                        "frames whole K and V rows, not a ring"))
-    return refused
+# the serving options that take a slot's memory to be whole rows, and why
+# not (the engine raises on any of them at start-up)
+REFUSED = {
+    "mesh": "the rings and the expert layer have no sharding rule; the "
+            "family runs on one chip",
+    "paged_blocks": "the block pool holds whole K and V rows, not a ring",
+    "kvcache": "the host and Redis tiers frame whole K and V rows; a ring "
+               "would not travel with them",
+    "spec_decode_k": "a rejected draft's rows have already overwritten "
+                     "the ring's oldest",
+    "lora_adapters": "adapters target the llama block's projections",
+    "kv_dtype": "int8: rows and rings are cached in the model's type",
+    "serving_role": "KV shipping frames whole K and V rows, not a ring",
+}
+unsupported_options = functools.partial(refused_options, REFUSED)
 
 
 def init(cfg: ModelConfig, key) -> dict:
@@ -220,7 +200,7 @@ def init(cfg: ModelConfig, key) -> dict:
             "w_up": dense_init(next(ks), (nd, D, cfg.ffn_dim), dt),
             "w_down": dense_init(next(ks), (nd, cfg.ffn_dim, D), dt)},
         "moe": {"ffn_norm": jnp.ones((ns, D), dt),
-                **deepseek_v3.init_routed(ks, cfg, ns)},
+                **moe.init_routed(ks, cfg, ns)},
         "final_norm": jnp.ones((D,), dt),
     }
     if not cfg.tie_embeddings:
@@ -230,108 +210,16 @@ def init(cfg: ModelConfig, key) -> dict:
 
 # -- one layer -----------------------------------------------------------------
 
-def _attention(x, lw, cfg: ModelConfig, kind: str, rope, positions, attend):
-    """x [B, S, D] -> (y [B, S, D], (k, v) [B, S, KV, hd] of these
-    tokens). ``attend(q, k, v) -> [B, S, H, hd]``."""
-    B, S = x.shape[:2]
-    H, KV, hd = heads(cfg, kind), cfg.n_kv_heads, cfg.head_dim
-    with jax.named_scope("attn_qkv"):
-        h = rms_norm(x, lw["attn_norm"], cfg.norm_eps)
-        q, k, v = (qmatmul(h, lw[n]) for n in ("wq", "wk", "wv"))
-        # the projections read their weights as the stacks store them:
-        # the heads-major layout the reshape and the rope want stays on
-        # this side (llama._layer says what it costs without)
-        q, k, v = jax.lax.optimization_barrier((q, k, v))
-        q, k = q.reshape(B, S, H, hd), k.reshape(B, S, KV, hd)
-        if cfg.qk_norm:     # a head at a time, before the rotation
-            with jax.named_scope("attn/qk_norm"):
-                q = rms_norm(q, lw["q_head_norm"], cfg.norm_eps)
-                k = rms_norm(k, lw["k_head_norm"], cfg.norm_eps)
-        q = apply_rope_part(q, *rope[kind], positions)
-        k = apply_rope_part(k, *rope[kind], positions)
-        v = v.reshape(B, S, KV, hd)
-    a = attend(q, k, v)
-    with jax.named_scope("attn_out"):
-        if cfg.head_gate:
-            gate = jax.nn.sigmoid(qmatmul(h, lw["head_gate"]).astype(F32))
-            a = (a.astype(F32) * gate[..., None]).astype(x.dtype)
-        return qmatmul(a.reshape(B, S, H * hd), lw["wo"]), (k, v)
-
-
 def _layer(x, lw, cfg: ModelConfig, kind: str, rope, positions, attend,
            valid):
     """One block: (x, (k, v) of these tokens, the expert layer's
     assignments a held expert or None)."""
-    y, kv = _attention(x, lw, cfg, kind, rope, positions, attend)
+    y, kv = attention(x, lw, cfg, heads(cfg, kind), rope[kind], positions,
+                      attend)
     x = x + y
     y, n = lw["ffn"](rms_norm(x, lw["ffn_norm"], cfg.norm_eps), lw, cfg,
                      valid)
     return x + y, kv, n
-
-
-# -- the stack -----------------------------------------------------------------
-
-def _stack(params, cfg: ModelConfig, x, layer):
-    """Run the layers. ``layer(x, lw, kind, i) -> (x, rows, n)`` runs one,
-    ``i`` its index among its kind, ``lw`` its weights (and ``lw["ffn"]``
-    its feed-forward). Returns (x, {kind: rows stacked [Lkind, ...]}, the
-    routed layers' n stacked [Ls, ...])."""
-    pat, nd = cfg.layer_pattern, cfg.n_dense_layers
-    period, P = len(pat), cfg.n_layers // len(pat)
-    # the kinds are the pattern's own (models/lfm2.py runs its stack
-    # here too, ``params[kind]`` a stack a kind)
-    per = {k: pat.count(k) for k in dict.fromkeys(pat)}
-    unrolled = min(-(-nd // period), P)
-    # the expert stacks go on whole to deepseek_v3._experts, which reads
-    # expert (layer, e) in place
-    experts = {k: v for k, v in params["moe"].items() if k in EXPERT_STACKS}
-    routed = {k: v for k, v in params["moe"].items() if k not in experts}
-
-    def at(tree, i):
-        return jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-            tree)
-
-    def stack(ys):
-        return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ys)
-
-    def run(x, p):
-        """Period ``p``: a python int (a dense layer's feed-forward is
-        chosen here) or the scan's index."""
-        rows = {k: [] for k in per}
-        ns = []
-        for j, kind in enumerate(pat):
-            l = p * period + j
-            i = p * per[kind] + len(rows[kind])
-            if isinstance(l, int) and l < nd:
-                ffn = {**at(params["dense"], l), "ffn": dense_ffn}
-            else:
-                ffn = {**at(routed, l - nd), "experts": (experts, l - nd),
-                       "ffn": moe_ffn}
-            x, kv, n = layer(x, {**at(params[kind], i), **ffn}, kind, i)
-            rows[kind].append(kv)
-            if n is not None:
-                ns.append(n)
-        return x, ({k: stack(v) for k, v in rows.items()},
-                   stack(ns) if ns else None)
-
-    outs = []
-    for p in range(unrolled):
-        x, ys = run(x, p)
-        outs.append(ys)
-    if unrolled < P:
-        x, ys = jax.lax.scan(run, x, jnp.arange(unrolled, P, dtype=jnp.int32))
-        outs.append(jax.tree_util.tree_map(
-            lambda a: a.reshape((-1,) + a.shape[2:]), ys))
-    join = lambda parts: jax.tree_util.tree_map(  # noqa: E731
-        lambda *a: jnp.concatenate(a), *parts)
-    ns = [o[1] for o in outs if o[1] is not None]
-    return x, join([o[0] for o in outs]), join(ns) if ns else None
-
-
-def _embed(params, cfg: ModelConfig, tokens):
-    with jax.named_scope("embed"):
-        return params["embedding"][tokens].astype(cfg.jdtype)
 
 
 # -- the ring's write ----------------------------------------------------------
@@ -383,36 +271,20 @@ def prefill_kv(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     [B, S, V] float32, or [B, 1, V] with ``logit_pos``; the full layers'
     K and V stacks [Lf, B, S, KV, hd]; the window layers' [Lw, B, S, KV,
     hd]; lengths [B])."""
-    B, S = tokens.shape
-    if lengths is None:
-        lengths = jnp.full((B,), S, jnp.int32)
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    valid = positions < lengths[:, None]
+    S = tokens.shape[1]
+    lengths, positions, valid = prompt_rows(tokens, lengths)
     rope = rope_tables or get_rope_tables(cfg, rope_max or S)
     # a band no wider than the prompt's bucket is no band
     band = {"full": 0,
             "window": cfg.window_size if cfg.window_size < S else 0}
 
     def layer(x, lw, kind, i):
-        if flash:
-            from ..ops.flash import causal_attention_auto
+        return _layer(x, lw, cfg, kind, rope, positions, prompt_attend(
+            flash, lengths, valid, mesh, band[kind]), valid)
 
-            def attend(q, k, v):
-                return causal_attention_auto(
-                    q, k, v, lengths=lengths, mask=valid, mesh=mesh,
-                    window=band[kind])
-        else:
-            def attend(q, k, v):
-                return causal_attention(q, k, v, mask=valid,
-                                        window=band[kind])
-        return _layer(x, lw, cfg, kind, rope, positions, attend, valid)
-
-    x, rows, _ = _stack(params, cfg, _embed(params, cfg, tokens), layer)
-    if logit_pos is not None:
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)
-    return (_logits(params, cfg, x), *rows["full"], *rows["window"],
-            lengths)
+    x, rows, _ = period_stack(params, cfg, embed(params, cfg, tokens), layer)
+    return (llama.logits_at(params, cfg, x, logit_pos), *rows["full"],
+            *rows["window"], lengths)
 
 
 def forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -457,7 +329,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
         return _layer(x, lw, cfg, kind, rope, positions, attend, valid)
 
-    x, rows, _ = _stack(params, cfg, _embed(params, cfg, tokens), layer)
+    x, rows, _ = period_stack(params, cfg, embed(params, cfg, tokens), layer)
     full = llama.write_kv(cache.rows, *rows["full"], (0, 0, 0, start, 0),
                           cache.lengths)
     with jax.named_scope("kv_write"):
@@ -467,10 +339,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                         lengths=cache.lengths)
     if not compute_logits:
         return None, cache
-    if logit_pos is not None:
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)
-    return _logits(params, cfg, x), cache
+    return llama.logits_at(params, cfg, x, logit_pos), cache
 
 
 def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -518,16 +387,16 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
         return _layer(x, lw, cfg, kind, rope, positions, attend,
                       act[:, None])
 
-    x, rows, n = _stack(params, cfg, _embed(params, cfg, tokens[:, None]),
-                        layer)
+    x, rows, n = period_stack(params, cfg,
+                              embed(params, cfg, tokens[:, None]), layer)
     with jax.named_scope("kv_write"):
-        full = llama._write_rows(cache.rows, *rows["full"], positions,
-                                 lengths + 1, cfg.n_heads, mesh)
+        full = llama.write_rows(cache.rows, *rows["full"], positions,
+                                lengths + 1, cfg.n_heads, mesh)
         # a cursor at capacity (a slot parked while its prompt is
         # chunk-written) must drop its row here as it does there
         at = jnp.where(positions < cache.k.shape[3], positions % W, W)
-        ring = llama._write_rows(cache.rings, *rows["window"], at,
-                                 lengths + 1, heads(cfg, "window"), mesh)
-    return (_logits(params, cfg, x[:, 0]),
+        ring = llama.write_rows(cache.rings, *rows["window"], at,
+                                lengths + 1, heads(cfg, "window"), mesh)
+    return (llama.logits(params, cfg, x[:, 0]),
             WindowCache(k=full.k, v=full.v, wk=ring.k, wv=ring.v,
                         lengths=lengths + 1), n)

@@ -21,7 +21,7 @@ blocks: ``x += Op(RMSNorm(x)); x += FFN(RMSNorm(x))``.
   - a FULL layer is softmax attention over every cached position,
     ``n_heads`` query heads on ``n_kv_heads`` KV heads of ``head_dim``,
     q and k RMS-normed a head before the rotation where ``qk_norm``
-    (``laguna._attention``, called and not copied). Its cache is llama's
+    (``blocks.attention``, the window family's too). Its cache is llama's
     K and V rows, and where a head is narrower than a lane row (64
     values) two KV heads share a row, [Lf, B, KV/2, Smax, 2 hd]
     (``ops.attention.pair_rows``): a 64-wide row alone is padded to the
@@ -30,13 +30,13 @@ blocks: ``x += Op(RMSNorm(x)); x += FFN(RMSNorm(x))``.
     the half that is not its KV head's, and ``flash_decode_stacked``,
     ``append_rows_stacked`` and the jnp forms run as at 128.
   - the first ``n_dense_layers`` layers' feed-forward is SwiGLU of width
-    ``ffn_dim``; every other layer's is ``deepseek_v3``'s expert layer
-    (``moe_ffn``, which ``laguna._stack`` hands each routed layer), here
-    with no shared expert.
+    ``ffn_dim``; every other layer's is the routed one of
+    ``models/moe.py`` (``moe_ffn``, which ``blocks.period_stack`` hands
+    each routed layer), here with no shared expert.
 
 Weights are stacked a kind of operator (``params["conv"]``,
 ``params["full"]``) and of feed-forward (``params["dense"]``,
-``params["moe"]``) and run by ``laguna._stack``: the periods that hold a
+``params["moe"]``) and run by ``blocks.period_stack``: the periods that hold a
 dense layer one after another, the rest scanned a period at a time.
 
 What a padded position, a new tenant or an idle slot may do to a tail:
@@ -49,23 +49,22 @@ places; a decode step moves the tails of the ACTIVE slots alone.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from ..ops import flash_decode
-from ..ops.attention import (causal_attention, chunk_attention,
-                             decode_attention_appended, pair_queries,
-                             pair_rows, unpair_heads)
+from ..ops.attention import (chunk_attention, decode_attention_appended,
+                             pair_queries, pair_rows, unpair_heads)
 from ..ops.flash import interpret_env
 from ..ops.kda import conv_taps
 from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
-from . import deepseek_v3, llama
-from .common import ModelConfig, dense_init
-from .laguna import _attention, _embed, _stack
-from .llama import _logits
+from . import llama, moe
+from .blocks import attention, embed, period_stack, prompt_attend, prompt_rows
+from .common import ModelConfig, dense_init, refused_options
 
 # a tail holds the last inputs and no earlier ones: the chunk lattice
 # runs left-aligned, and a prefix-pool row is usable only at the
@@ -151,13 +150,13 @@ def tail_bytes_per_slot(cfg: ModelConfig) -> int:
 
 def serving_stats(cfg: ModelConfig, slots: int) -> dict:
     """What ``GenerationEngine.stats()`` says of this family: the decode
-    step's expert dispatch shapes and path (the latent family's word),
+    step's expert dispatch shapes and path (``moe.serving_stats``),
     the layers of each kind, the bytes a slot's tails take whatever its
     length (``state_bytes_per_slot``: the engine's word for a slot's
     memory that is not rows) and those a cached token takes in the full
     layers, in the model's type (benchmarks/metrics reads them here)."""
     n = counts(cfg)
-    return {**deepseek_v3.serving_stats(cfg, slots),
+    return {**moe.serving_stats(cfg, slots),
             "layers": n,
             "state_bytes_per_slot": tail_bytes_per_slot(cfg),
             "kv_bytes_per_token": n["full"] * 2 * cfg.n_kv_heads
@@ -165,37 +164,22 @@ def serving_stats(cfg: ModelConfig, slots: int) -> dict:
             "kv_heads_per_row": 2 if paired(cfg) else 1}
 
 
-def unsupported_options(*, mesh=None, paged_blocks: int = 0, kvcache=None,
-                        spec_decode_k: int = 0, lora_adapters: int = 0,
-                        kv_dtype=None, serving_role: str | None = None
-                        ) -> list[tuple[str, str]]:
-    """(engine option, reason) for every serving option that would
-    restore or rewind a slot from rows alone; the engine raises on any
-    of them at start-up."""
-    refused = []
-    if mesh is not None:
-        refused.append(("mesh", "the tails and the expert layer have no "
-                        "sharding rule; the family runs on one chip"))
-    if paged_blocks:
-        refused.append(("paged_blocks", "the block pool holds K and V "
-                        "rows, not a convolution's tail"))
-    if kvcache is not None and (kvcache.host_mb > 0
-                                or kvcache.redis is not None):
-        refused.append(("kvcache", "the host and Redis tiers frame K and V "
-                        "rows; a tail would not travel with them"))
-    if spec_decode_k:
-        refused.append(("spec_decode_k", "a rejected draft has already "
-                        "shifted the tail"))
-    if lora_adapters:
-        refused.append(("lora_adapters", "adapters target the llama "
-                        "block's projections"))
-    if kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8:
-        refused.append(("kv_dtype", "int8: a cache row holds two KV heads "
-                        "and the shared kernels take one scale a row"))
-    if serving_role not in (None, "", "fused"):
-        refused.append(("serving_role", f"{serving_role}: KV shipping "
-                        "frames K and V rows, not a tail"))
-    return refused
+# the serving options that would restore or rewind a slot from rows alone,
+# and why not (the engine raises on any of them at start-up)
+REFUSED = {
+    "mesh": "the tails and the expert layer have no sharding rule; the "
+            "family runs on one chip",
+    "paged_blocks": "the block pool holds K and V rows, not a "
+                    "convolution's tail",
+    "kvcache": "the host and Redis tiers frame K and V rows; a tail would "
+               "not travel with them",
+    "spec_decode_k": "a rejected draft has already shifted the tail",
+    "lora_adapters": "adapters target the llama block's projections",
+    "kv_dtype": "int8: a cache row holds two KV heads and the shared "
+                "kernels take one scale a row",
+    "serving_role": "KV shipping frames K and V rows, not a tail",
+}
+unsupported_options = functools.partial(refused_options, REFUSED)
 
 
 def init(cfg: ModelConfig, key) -> dict:
@@ -236,7 +220,7 @@ def init(cfg: ModelConfig, key) -> dict:
             "w_up": dense_init(next(ks), (nd, D, cfg.ffn_dim), dt),
             "w_down": dense_init(next(ks), (nd, cfg.ffn_dim, D), dt)},
         "moe": {"ffn_norm": jnp.ones((ns, D), dt),
-                **deepseek_v3.init_routed(ks, cfg, ns)},
+                **moe.init_routed(ks, cfg, ns)},
         "final_norm": jnp.ones((D,), dt),
     }
     if not cfg.tie_embeddings:
@@ -275,7 +259,7 @@ def _layer(x, lw, cfg: ModelConfig, op, valid):
 
 def _cached(attend_rows, cfg: ModelConfig):
     """``attend(q, k, v)`` of a full layer over cached rows, as
-    ``laguna._attention`` calls it: ``attend_rows(q, k, v, scale)`` sees
+    ``blocks.attention`` calls it: ``attend_rows(q, k, v, scale)`` sees
     q, k and v as the cache holds rows (paired, or as they are)."""
     scale = cfg.head_dim ** -0.5
     if not paired(cfg):
@@ -307,11 +291,12 @@ def _run(params, cfg: ModelConfig, tokens, lengths, tails, attend, rope,
                           lambda x, lw: _conv(x, lw, cfg, tail, lengths),
                           valid)
         x, kv, n = _layer(
-            x, lw, cfg, lambda x, lw: _attention(
-                x, lw, cfg, "full", rope, positions, attend(i)), valid)
+            x, lw, cfg, lambda x, lw: attention(
+                x, lw, cfg, cfg.n_heads, rope["full"], positions, attend(i)),
+            valid)
         return x, _as_stored(kv, cfg), n
 
-    x, kept, n = _stack(params, cfg, _embed(params, cfg, tokens), layer)
+    x, kept, n = period_stack(params, cfg, embed(params, cfg, tokens), layer)
     return x, *kept["full"], kept["conv"], n
 
 
@@ -326,28 +311,13 @@ def prefill_kv(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     stores a token; the tails [Lc, B, W - 1, D] as they stand after each
     row's last token; lengths [B])."""
     B, S = tokens.shape
-    if lengths is None:
-        lengths = jnp.full((B,), S, jnp.int32)
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    valid = positions < lengths[:, None]
+    lengths, positions, valid = prompt_rows(tokens, lengths)
     rope = rope_tables or get_rope_tables(cfg, rope_max or S)
-    if flash:
-        from ..ops.flash import causal_attention_auto
-
-        def attend(q, k, v):
-            return causal_attention_auto(q, k, v, lengths=lengths,
-                                         mask=valid, mesh=mesh)
-    else:
-        def attend(q, k, v):
-            return causal_attention(q, k, v, mask=valid)
-
+    attend = prompt_attend(flash, lengths, valid, mesh)
     x, k, v, tails, _ = _run(params, cfg, tokens, lengths,
                              _empty_tails(cfg, B), lambda i: attend, rope,
                              positions, valid)
-    if logit_pos is not None:
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)
-    return _logits(params, cfg, x), k, v, tails, lengths
+    return llama.logits_at(params, cfg, x, logit_pos), k, v, tails, lengths
 
 
 def forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -407,10 +377,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     cache = ConvCache(k=rows.k, v=rows.v, conv=tails, lengths=cache.lengths)
     if not compute_logits:
         return None, cache
-    if logit_pos is not None:
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)
-    return _logits(params, cfg, x), cache
+    return llama.logits_at(params, cfg, x, logit_pos), cache
 
 
 def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -451,10 +418,10 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
         params, cfg, tokens[:, None], None, cache.conv, attend, rope,
         positions, act[:, None])
     with jax.named_scope("kv_write"):
-        rows = llama._write_rows(cache.rows, k_rows, v_rows, positions,
-                                 lengths + 1, cfg.n_heads, mesh)
+        rows = llama.write_rows(cache.rows, k_rows, v_rows, positions,
+                                lengths + 1, cfg.n_heads, mesh)
         conv = jnp.where(act[None, :, None, None],
                          tails.astype(cache.conv.dtype), cache.conv)
-    return (_logits(params, cfg, x[:, 0]),
+    return (llama.logits(params, cfg, x[:, 0]),
             ConvCache(k=rows.k, v=rows.v, conv=conv, lengths=rows.lengths),
             n, jnp.sum(act, dtype=jnp.int32) * counts(cfg)["conv"])

@@ -402,7 +402,7 @@ def _lora(h, layer_w, name: str, adapter):
     return jnp.einsum("bsr,bro->bso", ha, b[adapter].astype(h.dtype))
 
 
-def _layer(x, layer_w, cfg: ModelConfig, cos, sin, positions,
+def layer(x, layer_w, cfg: ModelConfig, cos, sin, positions,
            kv_write, attend, valid=None, adapter=None, mesh=None):
     """One transformer block. ``kv_write(k_new, v_new) -> (k_all, v_all)``
     handles cache interaction; ``attend(q, k, v)`` runs attention.
@@ -462,7 +462,7 @@ def _layer(x, layer_w, cfg: ModelConfig, cos, sin, positions,
 
 
 @jax.named_scope("lm_head")
-def _logits(params, cfg: ModelConfig, x):
+def logits(params, cfg: ModelConfig, x):
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if cfg.tie_embeddings:
         return jnp.dot(x, params["embedding"].T,
@@ -470,9 +470,19 @@ def _logits(params, cfg: ModelConfig, x):
     return qmatmul(x, params["lm_head"]).astype(jnp.float32)
 
 
+def logits_at(params, cfg: ModelConfig, x, logit_pos):
+    """``logits`` of x [B, S, D], or with ``logit_pos`` [B] of ONE
+    position a row, [B, 1, V]: the gather precedes the projection
+    (``prefill_kv`` says what it costs after it)."""
+    if logit_pos is not None:
+        x = jnp.take_along_axis(x, logit_pos[:, None, None]
+                                .astype(jnp.int32), axis=1)  # [B, 1, D]
+    return logits(params, cfg, x)
+
+
 def logits_dtype(cfg: ModelConfig):
-    """The type ``_logits``' float32 values are exact in, for every
-    family (all project through ``_logits``): the activations' own,
+    """The type ``logits``' float32 values are exact in, for every
+    family (all project through ``logits``): the activations' own,
     which ``qmatmul`` returns and the cast only widens; float32 where
     tied embeddings give a float32 dot. A consumer that must hand the
     logits across a program boundary (a ``cond``'s branch) narrows them
@@ -536,9 +546,9 @@ def _causal_scan(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
         x = constrain(params["embedding"][tokens].astype(cfg.jdtype))
 
     def body(x, layer_w):
-        x, kv, probs = _layer(x, layer_w, cfg, cos_g, sin_g, None,
-                              kv_write=lambda k, v: (k, v), attend=attend,
-                              valid=valid, adapter=adapter, mesh=mesh)
+        x, kv, probs = layer(x, layer_w, cfg, cos_g, sin_g, None,
+                             kv_write=lambda k, v: (k, v), attend=attend,
+                             valid=valid, adapter=adapter, mesh=mesh)
         # Training drops the per-layer k/v so the scan never materializes
         # the [L,B,S,KV,hd] stacks it would otherwise carry.
         return constrain(x), (kv if collect_kv else None,
@@ -568,13 +578,10 @@ def forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                                   attend_override=attend_override,
                                   collect_router=return_router_probs,
                                   adapter=adapter, mesh=mesh)
-    if logit_pos is not None:
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)  # [B, 1, D]
-    logits = _logits(params, cfg, x)
+    out = logits_at(params, cfg, x, logit_pos)
     if return_router_probs:
-        return logits, probs
-    return logits
+        return out, probs
+    return out
 
 
 def prefill(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -600,7 +607,7 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     if S > cache.capacity:
         raise ValueError(f"prompt length {S} exceeds cache capacity {cache.capacity}")
     cache = write_kv(cache, k_stack, v_stack, (0, 0, 0, 0, 0), lengths)
-    return _logits(params, cfg, x), cache
+    return logits(params, cfg, x), cache
 
 
 @jax.named_scope("kv_write")
@@ -652,10 +659,7 @@ def prefill_kv(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
         params, cfg, tokens, lengths, rope_max or tokens.shape[1],
         rope_tables, constrain=None, collect_kv=True, flash=flash,
         adapter=adapter, mesh=mesh)
-    if logit_pos is not None:
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)  # [B, 1, D]
-    return _logits(params, cfg, x), k_stack, v_stack, lengths
+    return logits_at(params, cfg, x, logit_pos), k_stack, v_stack, lengths
 
 
 def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -695,9 +699,9 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             return chunk_attention(q, k_layer, v_layer, k_new, v_new, start,
                                    ks_layer, vs_layer)
 
-        x, kv, _ = _layer(x, layer_w, cfg, cos, sin, positions,
-                          kv_write=lambda k, v: (k, v), attend=attend,
-                          adapter=adapter, mesh=mesh)
+        x, kv, _ = layer(x, layer_w, cfg, cos, sin, positions,
+                         kv_write=lambda k, v: (k, v), attend=attend,
+                         adapter=adapter, mesh=mesh)
         return x, kv
 
     x, (k_chunk, v_chunk) = jax.lax.scan(
@@ -707,10 +711,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                      cache.lengths)
     if not compute_logits:
         return None, cache
-    if logit_pos is not None:  # sample-one-position path: see prefill_kv
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)  # [B, 1, D]
-    return _logits(params, cfg, x), cache
+    return logits_at(params, cfg, x, logit_pos), cache
 
 
 def verify_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -757,9 +758,9 @@ def verify_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                                              v_new, lengths, ks_layer,
                                              vs_layer)
 
-        x, kv, _ = _layer(x, layer_w, cfg, cos, sin, positions,
-                          kv_write=lambda k, v: (k, v), attend=attend,
-                          adapter=adapter, mesh=mesh)
+        x, kv, _ = layer(x, layer_w, cfg, cos, sin, positions,
+                         kv_write=lambda k, v: (k, v), attend=attend,
+                         adapter=adapter, mesh=mesh)
         return x, kv
 
     x, (k_w, v_w) = jax.lax.scan(
@@ -768,9 +769,9 @@ def verify_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     # all layers and window rows at once: [L, B, W, KV, hd] ->
     # cache[:, b, :, lengths[b] + j]
     with jax.named_scope("kv_write"):
-        new = _write_rows(cache, k_w, v_w, positions, lengths, cfg.n_heads,
-                          mesh)
-    return _logits(params, cfg, x), new
+        new = write_rows(cache, k_w, v_w, positions, lengths, cfg.n_heads,
+                         mesh)
+    return logits(params, cfg, x), new
 
 
 EOS_PAD = -1  # unused entries of a per-slot on-device stop set
@@ -830,7 +831,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     (the current token's k/v ride alongside, see
     ``decode_attention_appended``), and the per-layer new-token k/v, the
     only novel data, [L, B, KV, hd], is written by ONE scatter into the
-    donated buffers after the loop (``_write_rows``). Emitting updated
+    donated buffers after the loop (``write_rows``). Emitting updated
     cache slices as scan outputs instead would rewrite the entire cache
     every token.
 
@@ -868,10 +869,10 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     with jax.named_scope("embed"):
         x = params["embedding"][tokens[:, None]].astype(cfg.jdtype)  # [B,1,D]
 
-    def layer(x, layer_w, attend):
-        x, kv_tok, _ = _layer(x, layer_w, cfg, cos, sin, positions,
-                              kv_write=lambda k, v: (k, v), attend=attend,
-                              adapter=adapter, mesh=mesh)
+    def block(x, layer_w, attend):
+        x, kv_tok, _ = layer(x, layer_w, cfg, cos, sin, positions,
+                             kv_write=lambda k, v: (k, v), attend=attend,
+                             adapter=adapter, mesh=mesh)
         return x, kv_tok
 
     block_s = flash_decode.kernel_block(cfg.n_heads, cache.k, mesh)
@@ -880,7 +881,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
         def body(x, xs):
             layer_w, li = xs
-            return layer(x, layer_w, lambda q, k_new, v_new:
+            return block(x, layer_w, lambda q, k_new, v_new:
                          flash_decode.decode_attention_auto(
                              q, cache.k, cache.v, k_new, v_new, live, li,
                              cache.k_scale, cache.v_scale, block_s=block_s,
@@ -890,7 +891,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     else:
         def body(x, xs):
             layer_w, k_layer, v_layer, ks_layer, vs_layer = xs
-            return layer(x, layer_w, lambda q, k_new, v_new:
+            return block(x, layer_w, lambda q, k_new, v_new:
                          decode_attention_appended(
                              q, k_layer, v_layer, k_new, v_new, lengths,
                              ks_layer, vs_layer))
@@ -899,12 +900,12 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
               cache.v_scale)
     x, (k_toks, v_toks) = jax.lax.scan(body, x, xs)
     with jax.named_scope("kv_write"):
-        new = _write_rows(cache, k_toks, v_toks, positions, lengths + 1,
-                          cfg.n_heads, mesh)
-    return _logits(params, cfg, x[:, 0]), new
+        new = write_rows(cache, k_toks, v_toks, positions, lengths + 1,
+                         cfg.n_heads, mesh)
+    return logits(params, cfg, x[:, 0]), new
 
 
-def _write_rows(cache: KVCache, k_rows, v_rows, positions, lengths,
+def write_rows(cache: KVCache, k_rows, v_rows, positions, lengths,
                 n_heads: int, mesh=None) -> KVCache:
     """The rows a step made, [L, B, W, KV, hd] for all layers (W = 1: a
     decode step; a verify window's W), to cache[:, b, :, positions[b, j]],

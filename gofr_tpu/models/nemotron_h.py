@@ -24,8 +24,8 @@ and a layer is a norm and one block, ``x += Block_l(RMSNorm(x))``:
     ``n_heads`` query heads on ``n_kv_heads`` KV heads of ``head_dim``,
     NOT rotated (``use_rope`` must be false). Its cache is llama's: K
     and V rows [La, B, KV, Smax, hd].
-  - a MOE layer is ``deepseek_v3``'s expert layer (``moe_ffn``, imported
-    and not copied) in the form the configuration gives: the router over
+  - a MOE layer is the routed feed-forward of ``models/moe.py``
+    (``moe_ffn``) in the form the configuration gives: the router over
     the full width, the routed experts two-matrix relu^2 experts in a
     latent of ``moe_latent_dim`` behind one projection down and one up
     a token, a shared relu^2 expert of ``shared_ffn_dim`` on the full
@@ -50,20 +50,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import flash_decode, ssd
-from ..ops.attention import (causal_attention, chunk_attention,
-                             decode_attention_appended)
+from ..ops import ssd
+from ..ops.attention import chunk_attention
 from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
-from . import deepseek_v3, llama
+from . import llama, moe
+from .blocks import embed, layer_at, prompt_attend, prompt_rows
 from .common import ModelConfig, dense_init
-from .deepseek_v3 import EXPERT_STACKS, moe_ffn
-from .llama import _logits
-# the cache (rows, state, tail), its write after a prefill and what a
-# state cannot do yet are the hybrid family's
-from .solar_open2 import (HybridCache, decode_kv_block,  # noqa: F401
-                          get_rope_tables, kv_layout, unsupported_options,
-                          write_kv)
+# the cache (rows, state, tail), and the entry points of ``models.family``
+# that follow from it alone, handed on (``x as x``) as ``hybrid_cache``
+# has them
+from .hybrid_cache import (HybridCache, decode_attend, decode_kv_block,
+                           get_rope_tables as get_rope_tables,
+                           kv_layout as kv_layout,
+                           unsupported_options as unsupported_options,
+                           write_kv as write_kv)
 
 RECOMPUTABLE = False     # a state: see models.family
 KINDS = ("mamba", "moe", "attn")
@@ -134,7 +135,7 @@ def state_bytes_per_slot(cfg: ModelConfig) -> int:
 def serving_stats(cfg: ModelConfig, slots: int) -> dict:
     """What ``GenerationEngine.stats()`` says of this family (as the
     hybrid family's: benchmarks/metrics reads them here)."""
-    return {**deepseek_v3.serving_stats(cfg, slots),
+    return {**moe.serving_stats(cfg, slots),
             "state_bytes_per_slot": state_bytes_per_slot(cfg),
             "kv_bytes_per_token": counts(cfg)[2] * 2 * cfg.n_kv_heads
             * cfg.head_dim * cfg.jdtype.itemsize}
@@ -175,7 +176,7 @@ def init(cfg: ModelConfig, key) -> dict:
     params = {"embedding": dense_init(next(ks), (V, D), dt, scale=0.02),
               "norm": jnp.ones((cfg.n_layers, D), dt),
               "mamba": mamba, "attn": attn,
-              "moe": deepseek_v3.init_routed(ks, cfg, Le),
+              "moe": moe.init_routed(ks, cfg, Le),
               "final_norm": jnp.ones((D,), dt)}
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(next(ks), (D, V), dt)
@@ -230,7 +231,7 @@ def _attn_block(u, lw, cfg: ModelConfig, attend):
     B, S = u.shape[:2]
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     with jax.named_scope("attn_qkv"):
-        # llama._layer's barrier: the heads-major layout the reshape
+        # llama.layer's barrier: the heads-major layout the reshape
         # wants must not travel back into the matmuls, or the decode
         # block transposes the whole wq stack every dispatch (PERF.md,
         # Findings PR 33; tests/test_kernels_compile_v5e.py holds it)
@@ -245,18 +246,6 @@ def _attn_block(u, lw, cfg: ModelConfig, attend):
 
 
 # -- the stack: one scan, a switch on the layer's kind -------------------------
-
-def _layer(stack, i):
-    """Layer ``i`` of a kind's stack: its leaves indexed where they are
-    used, the expert stacks whole beside the index (``_experts`` reads
-    expert (layer, e) in place: PERF.md, Findings PR 28 and 32)."""
-    whole = {k: v for k, v in stack.items() if k in EXPERT_STACKS}
-    rest = {k: v for k, v in stack.items() if k not in whole}
-    lw = jax.tree_util.tree_map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-        rest)
-    return {**lw, "experts": (whole, i)} if whole else lw
-
 
 def _put(stack, value, i):
     """``value`` at index ``i`` of a stack a kind, every array of it."""
@@ -273,15 +262,15 @@ def _rest(c):
             "conv": ssd.untouched(c["conv"])}
 
 
-def _stack(params, cfg: ModelConfig, x, carry, mamba, moe, attn):
-    """Scan the layers. Each of ``mamba``, ``moe``, ``attn`` runs one
+def _stack(params, cfg: ModelConfig, x, carry, mamba, routed, attn):
+    """Scan the layers. Each of ``mamba``, ``routed``, ``attn`` runs one
     block of its kind, ``(u, lw, i, carry) -> (y, carry)``: ``u`` the
     normed stream, ``lw`` the layer's weights, ``i`` its index among its
     kind, ``carry`` whatever the layers leave behind (the same tree out
     of every kind). Returns (x, carry)."""
     kind, index = _plan(cfg)
-    blocks = [lambda u, i, c, f=f, k=k: f(u, _layer(params[k], i), i, c)
-              for k, f in zip(KINDS, (mamba, moe, attn))]
+    blocks = [lambda u, i, c, f=f, k=k: f(u, layer_at(params[k], i), i, c)
+              for k, f in zip(KINDS, (mamba, routed, attn))]
 
     def body(c, xs):
         x, carry = c
@@ -294,11 +283,6 @@ def _stack(params, cfg: ModelConfig, x, carry, mamba, moe, attn):
         body, (x, carry), (params["norm"], jnp.asarray(kind),
                            jnp.asarray(index)))
     return x, carry
-
-
-def _embed(params, cfg: ModelConfig, tokens):
-    with jax.named_scope("embed"):
-        return params["embedding"][tokens].astype(cfg.jdtype)
 
 
 def _prefill(params, cfg: ModelConfig, tokens, lengths, state, conv,
@@ -317,8 +301,8 @@ def _prefill(params, cfg: ModelConfig, tokens, lengths, state, conv,
                 {**c, "state": _put(c["state"], s1, i),
                  "conv": _put(c["conv"], tail, i)})
 
-    def moe(u, lw, i, c):
-        return moe_ffn(u, lw, cfg, moe_valid)[0], _rest(c)
+    def routed(u, lw, i, c):
+        return moe.moe_ffn(u, lw, cfg, moe_valid)[0], _rest(c)
 
     def attn(u, lw, i, c):
         y, kv = _attn_block(u, lw, cfg, lambda q, k, v: attend(q, k, v, i))
@@ -326,9 +310,9 @@ def _prefill(params, cfg: ModelConfig, tokens, lengths, state, conv,
 
     row = jnp.zeros((counts(cfg)[2], B, S, cfg.n_kv_heads, cfg.head_dim),
                     cfg.jdtype)
-    x, c = _stack(params, cfg, _embed(params, cfg, tokens),
+    x, c = _stack(params, cfg, embed(params, cfg, tokens),
                   {"state": state, "conv": conv, "kv": (row, row)},
-                  mamba, moe, attn)
+                  mamba, routed, attn)
     return x, *c["kv"], c["state"], c["conv"]
 
 
@@ -342,27 +326,13 @@ def prefill_kv(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     ``logit_pos``; K and V stacks [La, B, S, KV, hd]; the state
     [Lm, B, G, N, R] and the convolution's tail [Lm, B, (W - 1) C] as they
     stand after each row's last token; lengths [B])."""
-    B, S = tokens.shape
-    if lengths is None:
-        lengths = jnp.full((B,), S, jnp.int32)
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    valid = positions < lengths[:, None]
-    if flash:
-        from ..ops.flash import causal_attention_auto
-
-        def attend(q, k, v, _):
-            return causal_attention_auto(q, k, v, lengths=lengths,
-                                         mask=valid, mesh=mesh)
-    else:
-        def attend(q, k, v, _):
-            return causal_attention(q, k, v, mask=valid)
-
+    lengths, _, valid = prompt_rows(tokens, lengths)
+    attend = prompt_attend(flash, lengths, valid, mesh)
     x, k, v, state, conv = _prefill(
-        params, cfg, tokens, lengths, *_empty_state(cfg, B), attend, valid)
-    if logit_pos is not None:
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)
-    return _logits(params, cfg, x), k, v, state, conv, lengths
+        params, cfg, tokens, lengths, *_empty_state(cfg, tokens.shape[0]),
+        lambda q, k, v, _: attend(q, k, v), valid)
+    return (llama.logits_at(params, cfg, x, logit_pos), k, v, state, conv,
+            lengths)
 
 
 def forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -393,10 +363,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     conv = jnp.where(fresh, jnp.zeros((), cache.conv.dtype), cache.conv)
 
     def attend(q, k_new, v_new, i):
-        k_l, v_l, ks_l, vs_l = (
-            None if a is None else jax.lax.dynamic_index_in_dim(
-                a, i, 0, keepdims=False)
-            for a in (cache.k, cache.v, cache.k_scale, cache.v_scale))
+        k_l, v_l, ks_l, vs_l = cache.layer_rows(i)
         return chunk_attention(q, k_l, v_l, k_new, v_new, start, ks_l, vs_l)
 
     x, k, v, state, conv = _prefill(params, cfg, tokens, lengths, state,
@@ -406,10 +373,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     cache = cache.with_rows(rows, state=state, conv=conv)
     if not compute_logits:
         return None, cache
-    if logit_pos is not None:
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)
-    return _logits(params, cfg, x), cache
+    return llama.logits_at(params, cfg, x, logit_pos), cache
 
 
 def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -431,7 +395,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     act = jnp.ones((B,), bool) if active is None else active
     live = jnp.where(act, lengths, 0)
     valid = act[:, None]
-    block_s = flash_decode.kernel_block(cfg.n_heads, cache.k, mesh)
+    block_s = decode_kv_block(cfg, cache, mesh)
 
     def mamba(u, lw, i, c):
         old = jax.lax.dynamic_index_in_dim(c["conv"], i, 0, keepdims=False)
@@ -442,39 +406,25 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
         return (_ssm_out(y[:, None], x, z, lw, cfg, u.dtype),
                 {**c, "state": state, "conv": _put(c["conv"], tail, i)})
 
-    def moe(u, lw, i, c):
-        y, n = moe_ffn(u, lw, cfg, valid)
+    def routed(u, lw, i, c):
+        y, n = moe.moe_ffn(u, lw, cfg, valid)
         return y, {**_rest(c), "n": _put(c["n"], n, i)}
 
     def attn(u, lw, i, c):
-        if block_s:
-            def attend(q, k_new, v_new):
-                return flash_decode.decode_attention_auto(
-                    q, cache.k, cache.v, k_new, v_new, live, i,
-                    cache.k_scale, cache.v_scale, block_s=block_s, mesh=mesh)
-        else:
-            def attend(q, k_new, v_new):
-                k_l, v_l, ks_l, vs_l = (
-                    None if a is None else jax.lax.dynamic_index_in_dim(
-                        a, i, 0, keepdims=False)
-                    for a in (cache.k, cache.v, cache.k_scale,
-                              cache.v_scale))
-                return decode_attention_appended(
-                    q, k_l, v_l, k_new, v_new, lengths, ks_l, vs_l)
-
-        y, kv = _attn_block(u, lw, cfg, attend)
+        y, kv = _attn_block(u, lw, cfg, decode_attend(
+            cache, i, lengths, live, block_s, mesh))
         return y, {**_rest(c), "kv": _put(c["kv"], kv, i)}
 
     row = jnp.zeros((La, B, 1, cfg.n_kv_heads, cfg.head_dim), cfg.jdtype)
     x, c = _stack(
-        params, cfg, _embed(params, cfg, tokens[:, None]),
+        params, cfg, embed(params, cfg, tokens[:, None]),
         {"state": cache.state, "conv": cache.conv, "kv": (row, row),
-         "n": jnp.zeros((Le, deepseek_v3.n_held(cfg)), jnp.int32)},
-        mamba, moe, attn)
+         "n": jnp.zeros((Le, moe.n_held(cfg)), jnp.int32)},
+        mamba, routed, attn)
     with jax.named_scope("kv_write"):
-        rows = llama._write_rows(cache.rows, *c["kv"], positions,
-                                 lengths + 1, cfg.n_heads, mesh)
+        rows = llama.write_rows(cache.rows, *c["kv"], positions,
+                                lengths + 1, cfg.n_heads, mesh)
     updated = jnp.sum(act, dtype=jnp.int32) * Lm
-    return (_logits(params, cfg, x[:, 0]),
+    return (llama.logits(params, cfg, x[:, 0]),
             cache.with_rows(rows, state=c["state"], conv=c["conv"]),
             c["n"], updated)

@@ -4,7 +4,7 @@ The contiguous ``llama.KVCache`` reserves [B, Smax] rows per slot; HBM
 capacity caps the decode batch long before the MXU or the weight stream
 does (8B int8 at batch 128 x 1024: ~9.7 GB KV on top of 8 GB weights —
 over a v5e's 16 GB). This module keeps the same model math (the layer
-scan calls the SAME ``llama._layer``) but stores KV in a shared pool of
+scan calls the SAME ``llama.layer``) but stores KV in a shared pool of
 fixed T-token blocks with a per-slot block table:
 
     k_pool/v_pool  [L, N, T, KV, hd]   (int8 with [L, N, T, KV] scales)
@@ -43,8 +43,8 @@ import jax.numpy as jnp
 from ..ops.paged_attention import paged_attention_auto
 from . import llama
 from .common import ModelConfig
-from .llama import (_layer, _logits, get_rope_tables,
-                    multi_request_serving_config, quantize_kv)
+from .llama import (get_rope_tables, multi_request_serving_config,
+                    quantize_kv)
 
 
 class PagedKVCache(NamedTuple):
@@ -149,9 +149,9 @@ def paged_decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             return attn(q, k_layer, v_layer, k_new, v_new, table,
                         lengths, ks_layer, vs_layer)
 
-        x, kv_tok, _ = _layer(x, layer_w, cfg, cos, sin, positions,
-                              kv_write=lambda k, v: (k, v), attend=attend,
-                              adapter=adapter)
+        x, kv_tok, _ = llama.layer(x, layer_w, cfg, cos, sin, positions,
+                                   kv_write=lambda k, v: (k, v),
+                                   attend=attend, adapter=adapter)
         return x, kv_tok
 
     x, (k_toks, v_toks) = jax.lax.scan(
@@ -176,7 +176,7 @@ def paged_decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             v=cache.v.at[:, blk, off].set(v_tok.astype(cache.v.dtype),
                                           mode="drop"),
             lengths=lengths + 1)
-    return _logits(params, cfg, x[:, 0]), new
+    return llama.logits(params, cfg, x[:, 0]), new
 
 
 def _reference_attention(q, k_pool, v_pool, k_new, v_new, table, lengths,
@@ -236,9 +236,9 @@ def paged_verify_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                                      table, lengths, ks_layer, vs_layer,
                                      mesh=mesh)
 
-        x, kv, _ = _layer(x, layer_w, cfg, cos, sin, positions,
-                          kv_write=lambda k, v: (k, v), attend=attend,
-                          adapter=adapter)
+        x, kv, _ = llama.layer(x, layer_w, cfg, cos, sin, positions,
+                               kv_write=lambda k, v: (k, v), attend=attend,
+                               adapter=adapter)
         return x, kv
 
     x, (k_w, v_w) = jax.lax.scan(
@@ -260,7 +260,7 @@ def paged_verify_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             k=cache.k.at[:, blk, off].set(k_w.astype(cache.k.dtype)),
             v=cache.v.at[:, blk, off].set(v_w.astype(cache.v.dtype)),
             lengths=lengths)
-    return _logits(params, cfg, x), new
+    return llama.logits(params, cfg, x), new
 
 
 def write_prompt_blocks(cache: PagedKVCache, k_stack, v_stack,

@@ -27,9 +27,9 @@ stream, pre-norm blocks: ``x += Mixer(RMSNorm(x)); x += MoE(RMSNorm(x))``.
     ``conv_kernel - 1`` inputs [Lk, B, W - 1, 3 H dk]: a slot's memory
     does not grow with its length, and a position cannot be recomputed
     on top of a state that already holds it (``RECOMPUTABLE``).
-  - every layer's feed-forward is ``deepseek_v3``'s expert layer
+  - every layer's feed-forward is the routed one of ``models/moe.py``
     (``moe_ffn``: sigmoid router over ``n_experts``, ``n_experts_held``
-    of them here, a shared expert), imported and not copied.
+    of them here, a shared expert).
 
 Layers are stacked a kind (``params["full"]``, ``params["linear"]``) and
 scanned a PERIOD at a time, so compile time stays flat in depth; the
@@ -43,20 +43,22 @@ the decode kernel does not touch a slot that is not active.
 
 from __future__ import annotations
 
-from typing import NamedTuple
-
 import jax
 import jax.numpy as jnp
 
-from ..ops import flash_decode, kda
-from ..ops.attention import (causal_attention, chunk_attention,
-                             decode_attention_appended)
+from ..ops import kda
+from ..ops.attention import chunk_attention
 from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
-from . import deepseek_v3, llama
+from . import llama, moe
+from .blocks import embed, layer_at, prompt_attend, prompt_rows
 from .common import ModelConfig, dense_init
-from .deepseek_v3 import EXPERT_STACKS, moe_ffn
-from .llama import _logits
+# the cache, and the entry points of ``models.family`` that follow from
+# it alone, handed on (``x as x``) as ``hybrid_cache`` has them
+from .hybrid_cache import (HybridCache, decode_attend, decode_kv_block,
+                           get_rope_tables, kv_layout as kv_layout,
+                           unsupported_options as unsupported_options,
+                           write_kv as write_kv)
 
 # a cached position of a linear layer cannot be computed again on top of
 # the state that holds it: the chunk lattice runs left-aligned, and a
@@ -81,34 +83,6 @@ def conv_channels(cfg: ModelConfig) -> int:
     return 3 * cfg.linear_heads * cfg.linear_head_dim
 
 
-class HybridCache(NamedTuple):
-    """The slots' memory of both kinds. ``k``/``v`` as llama.KVCache (and
-    int8 with scale planes); every array but ``lengths`` is [L, B, ...],
-    which is all the engine's row helpers ask."""
-
-    k: jnp.ndarray        # [Lf, B, KV, Smax, hd]
-    v: jnp.ndarray
-    state: jnp.ndarray    # [Lk, B, H, dk, dv] float32
-    conv: jnp.ndarray     # [Lk, B, W - 1, 3 H dk]
-    lengths: jnp.ndarray  # [B] int32
-    k_scale: jnp.ndarray | None = None
-    v_scale: jnp.ndarray | None = None
-
-    @property
-    def quantized(self) -> bool:
-        return self.k_scale is not None
-
-    @property
-    def rows(self) -> llama.KVCache:
-        """The full layers' part, as llama's helpers take it."""
-        return llama.KVCache(self.k, self.v, self.lengths, self.k_scale,
-                             self.v_scale)
-
-    def with_rows(self, kv: llama.KVCache, **kw) -> "HybridCache":
-        return self._replace(k=kv.k, v=kv.v, lengths=kv.lengths,
-                             k_scale=kv.k_scale, v_scale=kv.v_scale, **kw)
-
-
 def _empty_state(cfg: ModelConfig, batch: int):
     """(state, conv) of ``batch`` slots that have seen no token."""
     P, _, nl = counts(cfg)
@@ -128,19 +102,6 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int | None = None,
                        conv=conv)
 
 
-def get_rope_tables(cfg: ModelConfig, max_seq: int):
-    """(cos, sin) for the full layers, None where they do not rotate."""
-    return llama.get_rope_tables(cfg, max_seq) if cfg.use_rope else None
-
-
-def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
-    return cfg.n_kv_heads, cfg.head_dim
-
-
-def decode_kv_block(cfg: ModelConfig, cache: HybridCache, mesh=None):
-    return flash_decode.kernel_block(cfg.n_heads, cache.k, mesh)
-
-
 def state_bytes_per_slot(cfg: ModelConfig) -> int:
     """Bytes a slot's recurrent memory takes, whatever its length."""
     P, _, nl = counts(cfg)
@@ -152,45 +113,14 @@ def state_bytes_per_slot(cfg: ModelConfig) -> int:
 
 def serving_stats(cfg: ModelConfig, slots: int) -> dict:
     """What ``GenerationEngine.stats()`` says of this family: the decode
-    step's expert dispatch shapes and path (the latent family's word),
+    step's expert dispatch shapes and path (``moe.serving_stats``),
     the bytes a slot's state takes and those a cached token takes in
     the model's type (benchmarks/metrics reads them here)."""
     P, nf, _ = counts(cfg)
-    return {**deepseek_v3.serving_stats(cfg, slots),
+    return {**moe.serving_stats(cfg, slots),
             "state_bytes_per_slot": state_bytes_per_slot(cfg),
             "kv_bytes_per_token": P * nf * 2 * cfg.n_kv_heads
             * cfg.head_dim * cfg.jdtype.itemsize}
-
-
-def unsupported_options(*, mesh=None, paged_blocks: int = 0, kvcache=None,
-                        spec_decode_k: int = 0, lora_adapters: int = 0,
-                        kv_dtype=None, serving_role: str | None = None
-                        ) -> list[tuple[str, str]]:
-    """(engine option, reason) for every serving option that would
-    restore or rewind a slot from rows alone; the engine raises on any
-    of them at start-up."""
-    refused = []
-    if mesh is not None:
-        refused.append(("mesh", "the recurrent state and the expert share "
-                        "have no sharding rule; the family runs on one "
-                        "chip"))
-    if paged_blocks:
-        refused.append(("paged_blocks", "the block pool holds K and V "
-                        "rows, not a recurrent state"))
-    if kvcache is not None and (kvcache.host_mb > 0
-                                or kvcache.redis is not None):
-        refused.append(("kvcache", "the host and Redis tiers frame K and V "
-                        "rows; a state would not travel with them"))
-    if spec_decode_k:
-        refused.append(("spec_decode_k", "a rejected draft cannot be taken "
-                        "back out of a state"))
-    if lora_adapters:
-        refused.append(("lora_adapters", "adapters target the llama "
-                        "block's projections"))
-    if serving_role not in (None, "", "fused"):
-        refused.append(("serving_role", f"{serving_role}: KV shipping "
-                        "frames K and V rows, not a state"))
-    return refused
 
 
 def init(cfg: ModelConfig, key) -> dict:
@@ -205,7 +135,7 @@ def init(cfg: ModelConfig, key) -> dict:
 
     def ffn(L):
         return {"ffn_norm": jnp.ones((L, D), dt),
-                **deepseek_v3.init_routed(ks, cfg, L)}
+                **moe.init_routed(ks, cfg, L)}
 
     Lf, Lk = P * nf, P * nl
     full = {
@@ -321,7 +251,7 @@ def _linear_out(x, h, o, lw, cfg: ModelConfig):
 
 def _ffn(x, lw, cfg: ModelConfig, valid):
     h = rms_norm(x, lw["ffn_norm"], cfg.norm_eps)
-    y, n = moe_ffn(h, lw, cfg, valid)
+    y, n = moe.moe_ffn(h, lw, cfg, valid)
     return x + y, n
 
 
@@ -345,19 +275,6 @@ def _stack(params, cfg: ModelConfig, x, carry, full_layer, linear_layer,
     def at(tree, j):
         return jax.tree_util.tree_map(lambda a: a[j], tree)
 
-    def layer(stack, i):
-        # every stack stays whole beside the scan and a layer's weights
-        # are indexed where they are used: sliced by the scan, a period's
-        # three [4096, 8192] projections are copied out of the stack and
-        # then each layer's out of that copy, every step (PERF.md,
-        # Findings PR 32); the expert stacks go on whole to
-        # deepseek_v3._experts, which reads expert (layer, e) in place
-        whole = {k: v for k, v in stack.items() if k in EXPERT_STACKS}
-        rest = {k: v for k, v in stack.items() if k not in whole}
-        return {**jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-            rest), "experts": (whole, i)}
-
     def body(c, xs):
         x, carry = c
         ex_f, ex_l, p = xs
@@ -366,14 +283,14 @@ def _stack(params, cfg: ModelConfig, x, carry, full_layer, linear_layer,
         for kind in cfg.layer_pattern:
             if kind == "full":
                 i = p * nf + jf
-                x, ys = full_layer(x, layer(params["full"], i), i,
+                x, ys = full_layer(x, layer_at(params["full"], i), i,
                                    at(ex_f, jf))
                 ys_f.append(ys)
                 jf += 1
             else:
                 i = p * nl + jl
-                x, ys, carry = linear_layer(x, layer(params["linear"], i), i,
-                                            at(ex_l, jl), carry)
+                x, ys, carry = linear_layer(
+                    x, layer_at(params["linear"], i), i, at(ex_l, jl), carry)
                 ys_l.append(ys)
                 jl += 1
         stack = lambda ys: jax.tree_util.tree_map(  # noqa: E731
@@ -387,11 +304,6 @@ def _stack(params, cfg: ModelConfig, x, carry, full_layer, linear_layer,
     flat = lambda t: jax.tree_util.tree_map(  # noqa: E731
         lambda a: a.reshape((-1,) + a.shape[2:]), t)
     return x, carry, flat(ys_f), flat(ys_l)
-
-
-def _embed(params, cfg: ModelConfig, tokens):
-    with jax.named_scope("embed"):
-        return params["embedding"][tokens].astype(cfg.jdtype)
 
 
 def _prefill(params, cfg: ModelConfig, tokens, lengths, state, conv,
@@ -415,7 +327,7 @@ def _prefill(params, cfg: ModelConfig, tokens, lengths, state, conv,
         return x, (s1, tail), carry
 
     x, _, (k, v), (state, conv) = _stack(
-        params, cfg, _embed(params, cfg, tokens), None, full_layer,
+        params, cfg, embed(params, cfg, tokens), None, full_layer,
         linear_layer, per_full, (state, conv))
     return x, k, v, state, conv
 
@@ -431,29 +343,15 @@ def prefill_kv(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     [Lk, B, H, dk, dv] and the convolutions' tail [Lk, B, W - 1, C] as
     they stand after each row's last token; lengths [B])."""
     B, S = tokens.shape
-    if lengths is None:
-        lengths = jnp.full((B,), S, jnp.int32)
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    valid = positions < lengths[:, None]
+    lengths, positions, valid = prompt_rows(tokens, lengths)
     rope = (rope_tables or get_rope_tables(cfg, rope_max or S)) \
         if cfg.use_rope else None
-    if flash:
-        from ..ops.flash import causal_attention_auto
-
-        def attend(q, k, v, _):
-            return causal_attention_auto(q, k, v, lengths=lengths,
-                                         mask=valid, mesh=mesh)
-    else:
-        def attend(q, k, v, _):
-            return causal_attention(q, k, v, mask=valid)
-
+    attend = prompt_attend(flash, lengths, valid, mesh)
     x, k, v, state, conv = _prefill(
-        params, cfg, tokens, lengths, *_empty_state(cfg, B), attend, None,
-        rope, positions, valid)
-    if logit_pos is not None:
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)
-    return _logits(params, cfg, x), k, v, state, conv, lengths
+        params, cfg, tokens, lengths, *_empty_state(cfg, B),
+        lambda q, k, v, _: attend(q, k, v), None, rope, positions, valid)
+    return (llama.logits_at(params, cfg, x, logit_pos), k, v, state, conv,
+            lengths)
 
 
 def forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -461,22 +359,6 @@ def forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             logit_pos: jnp.ndarray | None = None):
     """Cache-free forward -> [B, S, V] float32 logits (``score``)."""
     return prefill_kv(params, cfg, tokens, lengths, logit_pos=logit_pos)[0]
-
-
-@jax.named_scope("kv_write")
-def write_kv(cache: HybridCache, k_stack, v_stack, state, conv, index,
-             lengths) -> HybridCache:
-    """Write what ``prefill_kv`` made for B' rows at batch row
-    ``index[1]``: K and V stacks from position ``index[3]`` (llama's
-    write), state and tail whole."""
-    rows = llama.write_kv(cache.rows, k_stack, v_stack, index, lengths)
-    slot = index[1]
-    return cache.with_rows(
-        rows,
-        state=jax.lax.dynamic_update_slice_in_dim(
-            cache.state, state.astype(F32), slot, axis=1),
-        conv=jax.lax.dynamic_update_slice_in_dim(
-            cache.conv, conv.astype(cache.conv.dtype), slot, axis=1))
 
 
 def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -516,10 +398,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     cache = cache.with_rows(rows, state=state, conv=conv)
     if not compute_logits:
         return None, cache
-    if logit_pos is not None:
-        x = jnp.take_along_axis(x, logit_pos[:, None, None]
-                                .astype(jnp.int32), axis=1)
-    return _logits(params, cfg, x), cache
+    return llama.logits_at(params, cfg, x, logit_pos), cache
 
 
 def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -545,25 +424,11 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     valid = act[:, None]
     rope = (rope_tables or get_rope_tables(cfg, cache.k.shape[3])) \
         if cfg.use_rope else None
-    block_s = flash_decode.kernel_block(cfg.n_heads, cache.k, mesh)
+    block_s = decode_kv_block(cfg, cache, mesh)
 
     def full_layer(x, lw, i, extra):
-        if block_s:
-            def attend(q, k_new, v_new):
-                return flash_decode.decode_attention_auto(
-                    q, cache.k, cache.v, k_new, v_new, live, i,
-                    cache.k_scale, cache.v_scale, block_s=block_s, mesh=mesh)
-        else:
-            def attend(q, k_new, v_new):
-                k_l, v_l, ks_l, vs_l = (
-                    None if a is None else jax.lax.dynamic_index_in_dim(
-                        a, i, 0, keepdims=False)
-                    for a in (cache.k, cache.v, cache.k_scale,
-                              cache.v_scale))
-                return decode_attention_appended(
-                    q, k_l, v_l, k_new, v_new, lengths, ks_l, vs_l)
-
-        y, kv = _full_mixer(x, lw, cfg, rope, positions, attend)
+        y, kv = _full_mixer(x, lw, cfg, rope, positions, decode_attend(
+            cache, i, lengths, live, block_s, mesh))
         x, n = _ffn(x + y, lw, cfg, valid)
         return x, (kv, n)
 
@@ -578,14 +443,14 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
         return x, (tail, n), state
 
     x, state, ((k_rows, v_rows), n_f), (tails, n_l) = _stack(
-        params, cfg, _embed(params, cfg, tokens[:, None]), cache.state,
+        params, cfg, embed(params, cfg, tokens[:, None]), cache.state,
         full_layer, linear_layer)
     with jax.named_scope("kv_write"):
-        rows = llama._write_rows(cache.rows, k_rows, v_rows, positions,
-                                 lengths + 1, cfg.n_heads, mesh)
+        rows = llama.write_rows(cache.rows, k_rows, v_rows, positions,
+                                lengths + 1, cfg.n_heads, mesh)
         conv = jnp.where(act[None, :, None, None],
                          tails.astype(cache.conv.dtype), cache.conv)
     updated = jnp.sum(act, dtype=jnp.int32) * (P * nl)
-    return (_logits(params, cfg, x[:, 0]),
+    return (llama.logits(params, cfg, x[:, 0]),
             cache.with_rows(rows, state=state, conv=conv),
             jnp.concatenate([n_f, n_l]), updated)

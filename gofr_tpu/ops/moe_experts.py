@@ -3,9 +3,9 @@
 the stacks hold no gate), over the blocks that exist, the next block's
 weights in flight while this one multiplies.
 
-``models.deepseek_v3._experts`` puts a step's (token, held expert)
+``models.moe.experts`` puts a step's (token, held expert)
 assignments, expert by expert and in the order they come within one
-(their places counted, not sorted: ``deepseek_v3._tables``), into a
+(their places counted, not sorted: ``moe.tables``), into a
 padded buffer ``xs [rows, D]`` of ``block_rows``-row
 blocks, each one expert's (``blk_expert [rows / block_rows]``), of which
 the first ``n_blocks`` hold rows. Its jnp form runs a ``fori_loop`` of

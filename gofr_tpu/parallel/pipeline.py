@@ -60,9 +60,9 @@ def _stage_apply(layers_local: Any, x: jnp.ndarray, cfg: ModelConfig,
     """Run this stage's local layer stack over one microbatch (shard)."""
 
     def body(x, layer_w):
-        x, _, probs = llama._layer(x, layer_w, cfg, cos, sin, positions,
-                                   kv_write=lambda k, v: (k, v),
-                                   attend=attend, valid=valid)
+        x, _, probs = llama.layer(x, layer_w, cfg, cos, sin, positions,
+                                  kv_write=lambda k, v: (k, v),
+                                  attend=attend, valid=valid)
         return x, probs  # [mb, S, E] per layer for MoE, else None
 
     x, probs = jax.lax.scan(body, x, layers_local)
@@ -174,7 +174,7 @@ def make_pp_loss_fn(cfg: ModelConfig, mesh: Mesh, *, n_microbatches: int,
                     probs * vmask, axis=(1, 2))
             j_out = t - last               # microbatch draining at the
             if 0 <= j_out < n_micro:       # last stage this tick (static)
-                logits = llama._logits(params, cfg, y)  # final_norm inside
+                logits = llama.logits(params, cfg, y)  # final_norm inside
                 n, m = loss_parts_local(logits, toks_mb[j_out], lens_in,
                                         g0, S)
                 on_last = (stage == last).astype(jnp.float32)

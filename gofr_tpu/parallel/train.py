@@ -220,7 +220,8 @@ def make_train_step(cfg: ModelConfig, optimizer: optax.GradientTransformation,
             attend_override = make_ring_attention(
                 mesh, axis_name=AXIS_SP, batch_axes=DATA_AXES)
 
-        # mesh: the expert layer's one collective (llama._combine_experts)
+        # mesh: the expert layer's one collective (models/llama.py's
+        # ``_combine_experts``)
         fwd = functools.partial(llama.forward, mesh=mesh)
         if remat:
             fwd = jax.checkpoint(fwd, static_argnums=(1, 5, 6, 7))

@@ -12,7 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from gofr_tpu.models import LLAMA_CONFIGS, deepseek_v3 as ds, family, llama
+from gofr_tpu.models import (LLAMA_CONFIGS, deepseek_v3 as ds, family, llama,
+                             moe)
 from gofr_tpu.ops import mla, rope
 from gofr_tpu.ops.quant import QuantizedLinear
 from gofr_tpu.tpu import GenerationEngine
@@ -233,7 +234,7 @@ def test_the_router_against_an_enumeration(bias_std):
     h = jax.random.normal(k1, (40, CFG.dim))
     router = jax.random.normal(k2, (CFG.dim, CFG.n_experts)) * 0.3
     bias = jax.random.normal(k3, (CFG.n_experts,)) * bias_std
-    topi, w = ds.route(h, router, bias, CFG)
+    topi, w = moe.route(h, router, bias, CFG)
     want_i, want_w = _route_numpy(np.asarray(h), np.asarray(router),
                                   np.asarray(bias), CFG)
     order = np.argsort(np.asarray(topi), axis=1)
@@ -254,7 +255,7 @@ def test_the_bias_selects_and_does_not_weigh():
     router = jax.random.normal(k2, (CFG.dim, CFG.n_experts)) * 0.3
     none = jnp.zeros((CFG.n_experts,))
     push = none.at[5].set(10.0)       # expert 5 always wins the selection
-    topi, w = ds.route(h, router, push, CFG)
+    topi, w = moe.route(h, router, push, CFG)
     assert (np.asarray(topi) == 5).any(axis=1).all()
     s5 = jax.nn.sigmoid(h @ router)[:, 5]
     w5 = np.asarray(w)[np.asarray(topi) == 5]
@@ -269,7 +270,7 @@ def _layer_w(params, i=0):
     """Layer ``i``'s weights, and the expert stacks whole with its index
     (``_experts`` reads expert (i, e) in place)."""
     lw = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
-    return lw, ({k: params["layers"][k] for k in ds.EXPERT_STACKS},
+    return lw, ({k: params["layers"][k] for k in moe.EXPERT_STACKS},
                 jnp.int32(i))
 
 
@@ -281,10 +282,10 @@ def test_every_token_to_one_expert_loses_none(params):
     # expert 2, then only experts the chip does not hold
     topi = jnp.tile(jnp.array([[2, 8, 9, 12]], jnp.int32), (T, 1))
     w = jnp.full((T, 4), 0.625)
-    y, counts, blocks = ds._experts(h, topi, w, *stacks, CFG)
+    y, counts, blocks = moe.experts(h, topi, w, *stacks, CFG)
     assert counts.tolist() == [0, 0, T, 0]
-    assert int(blocks) == math.ceil(T / ds.expert_dispatch(CFG, T)[0])
-    one = ds._swiglu(h, lw["w_gate"][2], lw["w_up"][2], lw["w_down"][2])
+    assert int(blocks) == math.ceil(T / moe.expert_dispatch(CFG, T)[0])
+    one = moe._swiglu(h, lw["w_gate"][2], lw["w_up"][2], lw["w_down"][2])
     assert np.abs(np.asarray(y - 0.625 * one)).max() < 1e-5
 
 
@@ -293,26 +294,26 @@ def test_expert_flops_follow_the_assignments(params):
     nothing for an expert no token chose, nothing at all where every
     token chose absent experts, and no row for an invalid token."""
     lw, stacks = _layer_w(params)
-    T, bm = 40, ds.expert_dispatch(CFG, 40)[0]
+    T, bm = 40, moe.expert_dispatch(CFG, 40)[0]
     h = jax.random.normal(jax.random.PRNGKey(8), (T, CFG.dim))
     w = jnp.full((T, 4), 0.625)
     absent = jnp.tile(jnp.array([[8, 9, 12, 13]], jnp.int32), (T, 1))
-    y, counts, blocks = ds._experts(h, absent, w, *stacks, CFG)
+    y, counts, blocks = moe.experts(h, absent, w, *stacks, CFG)
     assert int(blocks) == 0 and counts.sum() == 0 and not np.asarray(y).any()
-    topi, wr = ds.route(h, lw["router"], lw["router_bias"], CFG)
-    y, counts, blocks = ds._experts(h, topi, wr, *stacks, CFG)
+    topi, wr = moe.route(h, lw["router"], lw["router_bias"], CFG)
+    y, counts, blocks = moe.experts(h, topi, wr, *stacks, CFG)
     held = np.asarray(topi)[np.asarray(topi) < CFG.n_experts_held]
     assert counts.tolist() == np.bincount(held, minlength=4).tolist()
     assert int(blocks) == sum(math.ceil(c / bm) for c in counts.tolist())
     valid = jnp.arange(T) < 10
-    _, counts_v, _ = ds._experts(h, topi, wr, *stacks, CFG, valid)
+    _, counts_v, _ = moe.experts(h, topi, wr, *stacks, CFG, valid)
     held_v = np.asarray(topi)[:10][np.asarray(topi)[:10] < 4]
     assert counts_v.tolist() == np.bincount(held_v, minlength=4).tolist()
     # the compiled layer holds no [tokens, experts, width] product
-    hlo = jax.jit(lambda h: ds._experts(h, topi, wr, *stacks, CFG)[0]) \
+    hlo = jax.jit(lambda h: moe.experts(h, topi, wr, *stacks, CFG)[0]) \
         .lower(h).compile().as_text()
     assert f"[{T},{CFG.n_experts_held},{CFG.moe_ffn_dim}]" not in hlo
-    assert "capacity" not in ds._experts.__code__.co_names
+    assert "capacity" not in moe.experts.__code__.co_names
 
 
 def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
@@ -340,12 +341,12 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
         perm[0:4], perm[4 * j:4 * j + 4] = np.arange(4 * j, 4 * j + 4), \
             np.arange(4)
         lw = {k: v[0] for k, v in layers.items()
-              if k not in ds.EXPERT_STACKS}
+              if k not in moe.EXPERT_STACKS}
         lw.update(router=lw["router"][:, perm],
                   router_bias=lw["router_bias"][perm],
                   experts=({k: layers[k][:, 4 * j:4 * j + 4]
-                            for k in ds.EXPERT_STACKS}, jnp.int32(0)))
-        got, counts = ds.moe_ffn(h[None], lw, CFG)
+                            for k in moe.EXPERT_STACKS}, jnp.int32(0)))
+        got, counts = moe.moe_ffn(h[None], lw, CFG)
         assert np.abs(np.asarray(got[0]) - np.asarray(ref_share + shared)) \
             .max() < 1e-4
         total = total + np.asarray(ref_share)

@@ -63,6 +63,11 @@ def test_table_yields_the_same_program_names(monkeypatch, paged, meshed):
         return fn
 
     prog = _programs(_Owner(), paged=paged, mesh=_mesh(meshed))
+    # the paged programs' modules decorate with jax.jit as they are
+    # imported, and build() imports them late: import them before jit is
+    # replaced, whatever test this worker ran first (alone, the paged
+    # case failed on a TypeError from ops/paged_attention.py)
+    from gofr_tpu.models import paged_llama  # noqa: F401
     monkeypatch.setattr(programs.jax, "jit", record)
     # only a contiguous engine has a prefix pool, so only it offloads
     got = {attr: fn.__name__

@@ -195,7 +195,7 @@ def test_append_rows_writes_the_row_and_nothing_else(cache, kv):
 @pytest.mark.parametrize("kv_dtype", [None, jnp.int8])
 @pytest.mark.parametrize("w", [1, 3])
 def test_write_rows_kernel_arm_is_the_scatter(kv_dtype, w, monkeypatch):
-    """llama._write_rows, a decode step's row and a verify window's
+    """llama.write_rows, a decode step's row and a verify window's
     three: the kernel arm (interpreted) leaves the cache the reference
     arm's scatter leaves, scales included, a parked slot untouched."""
     b, smax = 4, 64
@@ -206,10 +206,10 @@ def test_write_rows_kernel_arm_is_the_scatter(kv_dtype, w, monkeypatch):
     v_rows = jax.random.normal(ks[1], shape, jnp.float32)
     lengths = jnp.asarray([0, 17, smax - w, smax], jnp.int32)
     positions = lengths[:, None] + jnp.arange(w)[None, :]
-    want = llama._write_rows(cache, k_rows, v_rows, positions, lengths + w,
+    want = llama.write_rows(cache, k_rows, v_rows, positions, lengths + w,
                              TINY.n_heads)
     monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
-    got = llama._write_rows(cache, k_rows, v_rows, positions, lengths + w,
+    got = llama.write_rows(cache, k_rows, v_rows, positions, lengths + w,
                             TINY.n_heads)
     for a, e in zip(got, want):
         assert (a is None) == (e is None)

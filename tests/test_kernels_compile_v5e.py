@@ -254,7 +254,7 @@ def _lowered(monkeypatch, sharding, program, layers):
 @pytest.mark.parametrize("program", ["decode block", "prefill 256"])
 def test_qk_projections_read_their_weights_in_place(one_chip, monkeypatch,
                                                     program):
-    """``llama._layer``'s barrier, read off the compiled program. Two
+    """``llama.layer``'s barrier, read off the compiled program. Two
     layers show what 32 do: without it the decode block transposes the
     whole wq and wk stacks once a dispatch (a ``copy`` of an int8 stack,
     49 MB of temporaries here, 0.67 GB at 32 layers) and every program
@@ -379,7 +379,7 @@ def test_window_family_reads_weights_and_rings_in_place(one_chip, monkeypatch,
     ``while`` left is the layer scan's), copies an int8
     weight stack, stages a layer's slice of one in VMEM, or copies a ring
     or the rows out of place (PERF.md, Findings PR 33 and section 7 item
-    9: the barrier is in ``laguna._attention`` from the start); and the
+    9: the barrier is in ``blocks.attention`` from the start); and the
     whole engine fits the chip."""
     compiled = _lowered_window(monkeypatch, one_chip, program).compile()
     text = compiled.as_text()
@@ -612,7 +612,7 @@ def _own_instructions(text):
     ("nemotron-3-super-120b-int8-ep4", 96, 1)])
 def test_dispatch_tables_are_counted_not_sorted(one_chip, monkeypatch, cell,
                                                 slots, kernels):
-    """``deepseek_v3._tables``, read off LFM2's and nemotron's compiled
+    """``moe.tables``, read off LFM2's and nemotron's compiled
     decode blocks: nothing under ``moe/experts`` is a ``sort`` (the
     router's top-k, under ``moe/route``, is the only one a layer), and
     the tables are ten instructions a routed layer (the key, the tokens'

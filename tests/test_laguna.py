@@ -14,8 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from gofr_tpu.models import (LLAMA_CONFIGS, deepseek_v3 as ds, family,
-                             laguna as lg, llama, solar_open2 as so)
+from gofr_tpu.models import (LLAMA_CONFIGS, blocks, deepseek_v3 as ds, family,
+                             laguna as lg, llama, moe, solar_open2 as so)
 from gofr_tpu.ops import attention, flash_decode
 from gofr_tpu.tpu import GenerationEngine
 
@@ -253,14 +253,14 @@ def test_a_bfloat16_shortcut_fails_the_tolerance(params, monkeypatch, what):
     not: the tolerance holds both."""
     bf16 = lambda x: x.astype(jnp.bfloat16).astype(x.dtype)  # noqa: E731
     if what == "scores":
-        real = lg.causal_attention
+        real = blocks.causal_attention
         monkeypatch.setattr(
-            lg, "causal_attention",
+            blocks, "causal_attention",
             lambda q, k, v, **kw: real(bf16(q), bf16(k), v, **kw))
     else:
-        real = ds.route
+        real = moe.route
         monkeypatch.setattr(
-            ds, "route", lambda hf, router, bias, cfg: real(
+            moe, "route", lambda hf, router, bias, cfg: real(
                 bf16(hf), bf16(router), bias, cfg))
     toks = _tokens(11, 24)
     logits = lg.forward(params, CFG, jnp.asarray(toks[None]))
@@ -350,9 +350,13 @@ def test_engine_says_its_rings_and_counts_their_rows(params):
     m = Manager()
     register_framework_metrics(m)
     obs = Observe(metrics=m, timeline=Timeline(capacity=256))
+    # one block in flight: each block's stop is reaped before the next
+    # dispatch, so 13 tokens are the prefill's and exactly three blocks
+    # (at depth 2 a fourth can be queued before the third's stop is seen:
+    # what is counted here is rings and rows, not depth)
     eng = GenerationEngine(CFG, params, slots=2, max_seq=64,
                            prompt_buckets=(16,), observe=obs, metrics=m,
-                           decode_block=4)
+                           decode_block=4, decode_pipeline=1)
     try:
         eng.generate([3, 4, 5], max_new_tokens=13).tokens()
         stats = eng.stats()

@@ -15,8 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from gofr_tpu.models import (LLAMA_CONFIGS, deepseek_v3 as ds, family,
-                             lfm2, llama, solar_open2 as so)
+from gofr_tpu.models import (LLAMA_CONFIGS, family, lfm2, llama, moe,
+                             solar_open2 as so)
 from gofr_tpu.ops import attention, flash_decode, kda
 from gofr_tpu.ops.quant import quantize_int8
 from gofr_tpu.tpu import GenerationEngine
@@ -272,18 +272,18 @@ def test_the_expert_layer_with_and_without_a_shared_expert(shared):
     leaves, and the routed sum alone."""
     cfg = LLAMA_CONFIGS["tiny-swa-moe"].with_(n_shared_experts=shared)
     keys = iter(jax.random.split(jax.random.PRNGKey(2), 8))
-    lw = ds.init_routed(keys, cfg, 2)
+    lw = moe.init_routed(keys, cfg, 2)
     assert ("ws_gate" in lw) == bool(shared)
-    stacks = {k: lw.pop(k) for k in ds.EXPERT_STACKS}
+    stacks = {k: lw.pop(k) for k in moe.EXPERT_STACKS}
     lw = {**{k: v[1] for k, v in lw.items()}, "experts": (stacks, 1)}
     h = jax.random.normal(jax.random.PRNGKey(3), (2, 5, cfg.dim))
-    y, counts = ds.moe_ffn(h, lw, cfg)
+    y, counts = moe.moe_ffn(h, lw, cfg)
     hf = h.reshape(10, cfg.dim)
-    was, _, _ = ds._experts(hf, *ds.route(hf, lw["router"],
+    was, _, _ = moe.experts(hf, *moe.route(hf, lw["router"],
                                           lw["router_bias"], cfg),
                             stacks, 1, cfg)
     if shared:
-        was = was + ds._swiglu(hf, lw["ws_gate"], lw["ws_up"], lw["ws_down"])
+        was = was + moe._swiglu(hf, lw["ws_gate"], lw["ws_up"], lw["ws_down"])
     assert np.array_equal(np.asarray(y), np.asarray(was.reshape(h.shape)))
     assert int(counts.sum()) == 10 * cfg.experts_per_token
 
@@ -439,8 +439,8 @@ def test_the_experts_kernel_two_tiles_wide_equals_the_loop(monkeypatch):
     blk = jnp.asarray([0, 0, 3, 7, 7, 7], jnp.int32)
     xs = jax.random.normal(keys[3], (6 * bm, D))
     n, li = jnp.int32(5), jnp.int32(0)
-    want = ds._blocks_loop(xs, blk, n, stacks, li, bm)
-    got = ds._blocks_kernel(xs, blk, n, stacks, li, bm, 768)
+    want = moe.blocks_loop(xs, blk, n, stacks, li, bm)
+    got = moe.blocks_kernel(xs, blk, n, stacks, li, bm, 768)
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
     assert not np.asarray(got[5 * bm:]).any()
 

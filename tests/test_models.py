@@ -207,9 +207,9 @@ def test_qkv_barrier_changes_no_value(dtype, quant, lora):
             seen.extend((q, k, v))
             return q
 
-        llama._layer(x, layer_w, cfg, cos, sin, positions,
-                     kv_write=lambda k, v: (k, v), attend=attend,
-                     adapter=adapter)
+        llama.layer(x, layer_w, cfg, cos, sin, positions,
+                    kv_write=lambda k, v: (k, v), attend=attend,
+                    adapter=adapter)
         return tuple(seen)
 
     got = jax.jit(through_layer)(x, layer_w)

@@ -1,5 +1,5 @@
 """The routed experts' kernel (ops/moe_experts.py), interpreted on the
-CPU, against the jnp loop it replaces (deepseek_v3._blocks_loop) on the
+CPU, against the jnp loop it replaces (moe.blocks_loop) on the
 same inputs: the dispatch buffer's blocks directly, then the whole
 ``_experts`` with the kernel switched on by GOFR_FLASH_INTERPRET."""
 
@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from gofr_tpu.models import deepseek_v3 as ds
+from gofr_tpu.models import moe
 from gofr_tpu.models.common import ModelConfig
 from gofr_tpu.ops import moe_experts
 from gofr_tpu.ops.quant import quantize_int8
@@ -42,7 +42,7 @@ def _stacks(n_held: int, quant: bool, dtype=jnp.float32, seed: int = 0,
 
 @pytest.fixture
 def interpreted(monkeypatch):
-    """``deepseek_v3._blocks_kernel`` runs the kernel interpreted."""
+    """``moe.blocks_kernel`` runs the kernel interpreted."""
     monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
 
 
@@ -69,8 +69,8 @@ def test_blocks_through_the_kernel_equal_the_loop(buffer, quant, bm, tile,
     xs = jax.random.normal(jax.random.PRNGKey(nb), (nb * bm, FORMS[form][1]))
     blk = jnp.array(experts, jnp.int32)
     n, li = jnp.int32(live), jnp.int32(1)
-    want = ds._blocks_loop(xs, blk, n, stacks, li, bm)
-    got = ds._blocks_kernel(xs, blk, n, stacks, li, bm, tile)
+    want = moe.blocks_loop(xs, blk, n, stacks, li, bm)
+    got = moe.blocks_kernel(xs, blk, n, stacks, li, bm, tile)
     assert got.shape == want.shape and got.dtype == want.dtype
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
     assert not np.asarray(got[live * bm:]).any()      # dead blocks read 0
@@ -84,8 +84,8 @@ def test_scales_come_in_groups_of_eight_experts(interpreted):
     bm, experts = 16, [0, 7, 8, 9, 15]
     xs = jax.random.normal(jax.random.PRNGKey(1), (len(experts) * bm, D))
     blk, n, li = jnp.array(experts, jnp.int32), jnp.int32(5), jnp.int32(0)
-    want = ds._blocks_loop(xs, blk, n, stacks, li, bm)
-    got = ds._blocks_kernel(xs, blk, n, stacks, li, bm, 128)
+    want = moe.blocks_loop(xs, blk, n, stacks, li, bm)
+    got = moe.blocks_kernel(xs, blk, n, stacks, li, bm, 128)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
@@ -97,13 +97,13 @@ def test_bfloat16_rounds_no_coarser_than_the_loop(interpreted):
     bm, blk = 16, jnp.array([0, 1, 3], jnp.int32)
     x32 = jax.random.normal(jax.random.PRNGKey(2), (3 * bm, D))
     n, li = jnp.int32(3), jnp.int32(1)
-    exact = np.asarray(ds._blocks_loop(x32.astype(jnp.bfloat16).astype(
+    exact = np.asarray(moe.blocks_loop(x32.astype(jnp.bfloat16).astype(
         jnp.float32), blk, n, stacks, li, bm))
     xs = x32.astype(jnp.bfloat16)
-    loop = np.asarray(ds._blocks_loop(xs, blk, n, stacks, li, bm),
+    loop = np.asarray(moe.blocks_loop(xs, blk, n, stacks, li, bm),
                       np.float32)
     for tile in (None, 128):
-        got = ds._blocks_kernel(xs, blk, n, stacks, li, bm, tile)
+        got = moe.blocks_kernel(xs, blk, n, stacks, li, bm, tile)
         assert got.dtype == jnp.bfloat16
         err = np.abs(np.asarray(got, np.float32) - exact).max()
         assert err <= np.abs(loop - exact).max() + 1e-3
@@ -185,32 +185,32 @@ def test_expert_layer_on_the_kernel_equals_the_loop(layer, quant, form,
     T, chosen, n_valid = LAYERS[layer]
     stacks = _stacks(4, quant, seed=7, form=form)
     CFG, width = CFGS[form], FORMS[form][1]
-    assert ds.expert_width(CFG) == width
-    assert ds.expert_stacks(CFG) == FORMS[form][0]
+    assert moe.expert_width(CFG) == width
+    assert moe.expert_stacks(CFG) == FORMS[form][0]
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(T), 3)
     h = jax.random.normal(k1, (T, width))      # what the experts read
     if chosen is None:
         # the router reads the model's width whatever the experts'
         router = jax.random.normal(k2, (D, CFG.n_experts)) * 0.3
-        topi, w = ds.route(jax.random.normal(k3, (T, D)), router,
+        topi, w = moe.route(jax.random.normal(k3, (T, D)), router,
                            jnp.zeros((CFG.n_experts,)), CFG)
     else:
         topi = jnp.tile(jnp.array([chosen], jnp.int32), (T, 1))
         w = jnp.full((T, 4), 0.625)
     valid = None if n_valid is None else jnp.arange(T) < n_valid
     li = jnp.int32(1)
-    assert ds.expert_dispatch(CFG, T)[0] == (16 if T <= 128 else 64)
+    assert moe.expert_dispatch(CFG, T)[0] == (16 if T <= 128 else 64)
 
     monkeypatch.delenv("GOFR_FLASH_INTERPRET", raising=False)
-    assert not ds.experts_on_kernel(CFG)
-    said = ds.serving_stats(CFG, 8)["moe_decode_dispatch"]
+    assert not moe.experts_on_kernel(CFG)
+    said = moe.serving_stats(CFG, 8)["moe_decode_dispatch"]
     assert (said["path"], said["width"]) == ("loop", width)
-    want, counts, blocks = ds._experts(h, topi, w, stacks, li, CFG, valid)
+    want, counts, blocks = moe.experts(h, topi, w, stacks, li, CFG, valid)
     monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
-    assert ds.experts_on_kernel(CFG)
-    assert ds.serving_stats(CFG, 8)["moe_decode_dispatch"]["path"] \
+    assert moe.experts_on_kernel(CFG)
+    assert moe.serving_stats(CFG, 8)["moe_decode_dispatch"]["path"] \
         == "kernel"
-    got, counts_k, blocks_k = ds._experts(h, topi, w, stacks, li, CFG, valid)
+    got, counts_k, blocks_k = moe.experts(h, topi, w, stacks, li, CFG, valid)
 
     assert got.shape == (T, width)
     assert counts_k.tolist() == counts.tolist()
@@ -291,15 +291,15 @@ def test_counted_tables_equal_the_stable_sort(case, valid_given):
     hf = rng.standard_normal((T, 32)).astype(np.float32)
     cfg = CFG.with_(n_experts=max(E, Eh + K), n_experts_held=Eh,
                     experts_per_token=K)
-    bm, rows = ds.expert_dispatch(cfg, T)
-    assert bm == (16 if T <= 128 else 64) and ds.n_held(cfg) == Eh
+    bm, rows = moe.expert_dispatch(cfg, T)
+    assert bm == (16 if T <= 128 else 64) and moe.n_held(cfg) == Eh
     want = _sorted_tables(hf, topi, valid, Eh, bm, rows // bm)
 
     v = jnp.asarray(valid) if valid_given else None
     counts, n_blocks, blk_expert, dest = jax.jit(
-        lambda t, v: ds._tables(t, v, Eh, bm, rows // bm))(
+        lambda t, v: moe.tables(t, v, Eh, bm, rows // bm))(
             jnp.asarray(topi, jnp.int32), v)
-    xs = jax.jit(lambda h, d, v: ds._fill(h, d, v, rows))(
+    xs = jax.jit(lambda h, d, v: moe._fill(h, d, v, rows))(
         jnp.asarray(hf), dest, v)
     dest, sent = np.asarray(dest), want[4] >= 0
     np.testing.assert_array_equal(np.asarray(xs), want[0])
@@ -313,5 +313,5 @@ def test_counted_tables_equal_the_stable_sort(case, valid_given):
         assert want[2] == 0 and not sent.any()
     bf16 = jnp.asarray(hf).astype(jnp.bfloat16)            # bit for bit
     np.testing.assert_array_equal(
-        np.asarray(ds._fill(bf16, jnp.asarray(dest), v, rows), np.float32),
+        np.asarray(moe._fill(bf16, jnp.asarray(dest), v, rows), np.float32),
         np.asarray(jnp.asarray(want[0]).astype(jnp.bfloat16), np.float32))

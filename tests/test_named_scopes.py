@@ -107,10 +107,10 @@ def test_stats_say_how_the_dispatch_tables_are_built(routed_engine):
     """``stats()["moe_decode_dispatch"]``: what the benchmark's readers
     key on (the block's and the buffer's rows, the width, the path) and
     how the tables are built."""
-    from gofr_tpu.models import deepseek_v3
+    from gofr_tpu.models import moe
 
     said = routed_engine.stats()["moe_decode_dispatch"]
-    bm, rows = deepseek_v3.expert_dispatch(routed_engine.cfg, 2)
+    bm, rows = moe.expert_dispatch(routed_engine.cfg, 2)
     assert said == {"block_rows": bm, "buffer_rows": rows,
                     "width": routed_engine.cfg.dim, "path": "loop",
                     "tables": "counted"}

@@ -13,8 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from gofr_tpu.models import (LLAMA_CONFIGS, deepseek_v3 as ds, family,
+from gofr_tpu.models import (LLAMA_CONFIGS, family, moe,
                              nemotron_h as nh, solar_open2 as so)
+from gofr_tpu.models.blocks import layer_at
 from gofr_tpu.ops import ssd
 from gofr_tpu.ops.quant import qmatmul
 from gofr_tpu.tpu import GenerationEngine
@@ -81,9 +82,9 @@ def test_the_family_is_chosen_by_fields_not_by_name():
     assert all(CFG.layer_pattern != CFG.layer_pattern[:p] * (11 // p)
                for p in range(1, 11))
     assert CFG.ssm_groups < CFG.ssm_heads
-    assert ds.n_held(CFG) < CFG.n_experts
-    assert ds.expert_width(CFG) == 24 != CFG.dim
-    assert ds.expert_stacks(CFG) == ("w_up", "w_down")
+    assert moe.n_held(CFG) < CFG.n_experts
+    assert moe.expert_width(CFG) == 24 != CFG.dim
+    assert moe.expert_stacks(CFG) == ("w_up", "w_down")
     with pytest.raises(ValueError, match="does not name each"):
         nh.counts(CFG.with_(n_layers=12))
     with pytest.raises(ValueError, match="not rotated"):
@@ -91,8 +92,8 @@ def test_the_family_is_chosen_by_fields_not_by_name():
     # every configuration that was there reads as before
     for name, cfg in LLAMA_CONFIGS.items():
         if name != "tiny-ssm-moe":
-            assert ds.expert_width(cfg) == cfg.dim
-            assert ds.expert_stacks(cfg) == ds.EXPERT_STACKS
+            assert moe.expert_width(cfg) == cfg.dim
+            assert moe.expert_stacks(cfg) == moe.EXPERT_STACKS
 
 
 def test_full_forward_against_the_reference(params, tokens):
@@ -226,9 +227,9 @@ def test_each_of_these_fails_the_comparison(params, tokens, monkeypatch,
             return cache._replace(conv=jnp.roll(
                 cache.conv, nh.conv_channels(CFG), axis=2))
     elif fault == "a gate on the expert":
-        monkeypatch.setattr(ds, "_relu2", _gated)
+        monkeypatch.setattr(moe, "_relu2", _gated)
     elif fault == "SiLU for relu2":
-        monkeypatch.setattr(ds, "_relu2", _silu)
+        monkeypatch.setattr(moe, "_relu2", _silu)
     elif fault == "the norm before the gate":
         monkeypatch.setattr(nh, "_ssm_out", _norm_before_the_gate)
     elif fault == "a head reading the wrong group":
@@ -248,8 +249,8 @@ def test_the_latent_goes_up_once_a_token_after_the_weighted_sum(params):
     too, and the experts' blocks are the latent wide."""
     T = 6
     u = jax.random.normal(jax.random.PRNGKey(3), (1, T, CFG.dim))
-    lw = nh._layer(params["moe"], jnp.int32(1))
-    jaxpr = jax.make_jaxpr(lambda u: ds.moe_ffn(u, lw, CFG)[0])(u)
+    lw = layer_at(params["moe"], jnp.int32(1))
+    jaxpr = jax.make_jaxpr(lambda u: moe.moe_ffn(u, lw, CFG)[0])(u)
 
     def dots(jp, found):
         for eqn in jp.eqns:
@@ -264,7 +265,7 @@ def test_the_latent_goes_up_once_a_token_after_the_weighted_sum(params):
     assert ((T, D), (D, lat)) in shapes          # down: once a token
     assert ((T, lat), (lat, D)) in shapes        # up: once a token
     assert not [s for s in shapes if s[1] == (lat, D) and s[0][0] != T]
-    bm, _ = ds.expert_dispatch(CFG, T)
+    bm, _ = moe.expert_dispatch(CFG, T)
     assert ((bm, lat), (lat, CFG.moe_ffn_dim)) in shapes
 
 
@@ -403,12 +404,12 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
         perm[0:4], perm[4 * j:4 * j + 4] = np.arange(4 * j, 4 * j + 4), \
             np.arange(4)
         lw = {k: v[1] for k, v in layers.items()
-              if k not in ds.EXPERT_STACKS}
+              if k not in moe.EXPERT_STACKS}
         lw.update(router=lw["router"][:, perm],
                   router_bias=lw["router_bias"][perm],
                   experts=({k: layers[k][:, 4 * j:4 * j + 4]
-                            for k in ds.expert_stacks(CFG)}, jnp.int32(1)))
-        got, _ = ds.moe_ffn(h[None], lw, CFG)
+                            for k in moe.expert_stacks(CFG)}, jnp.int32(1)))
+        got, _ = moe.moe_ffn(h[None], lw, CFG)
         assert np.abs(np.asarray(got[0]) - np.asarray(ref_share + shared)) \
             .max() < 1e-4
         total = total + np.asarray(ref_share)
@@ -535,7 +536,7 @@ def test_engine_counts_states_and_says_their_bytes(params):
     said = stats["moe_decode_dispatch"]
     assert (said["block_rows"], said["width"], said["path"]) == (16, 24,
                                                                   "loop")
-    assert said["buffer_rows"] == ds.expert_dispatch(CFG, 2)[1]
+    assert said["buffer_rows"] == moe.expert_dispatch(CFG, 2)[1]
     assert stats["moe"]["expert_tokens"] > 0
     # decode events: the expert layers' two counts, then the states: one
     # slot, five mamba layers, a state a step while it decodes

@@ -117,7 +117,7 @@ def test_sample_gives_the_unconditional_bits(case):
 
 @pytest.mark.parametrize("head", ["plain", "int8", "tied"])
 def test_logits_are_exact_in_the_type_the_branches_take(head):
-    """``llama.logits_dtype`` is a promise about ``llama._logits``, which
+    """``llama.logits_dtype`` is a promise about ``llama.logits``, which
     every family projects through: narrowing the float32 logits to it
     loses nothing, so ``_sample`` may hand its branches the narrow
     array."""
@@ -128,7 +128,7 @@ def test_logits_are_exact_in_the_type_the_branches_take(head):
         params = maybe_quantize(params, True)
     x = jax.random.normal(jax.random.PRNGKey(3), (SLOTS, cfg.dim),
                           jnp.bfloat16)
-    logits = llama._logits(params, cfg, x)
+    logits = llama.logits(params, cfg, x)
     dtype = llama.logits_dtype(cfg)
     assert dtype == (jnp.float32 if head == "tied" else jnp.bfloat16)
     assert logits.dtype == jnp.float32

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from gofr_tpu.models import (LLAMA_CONFIGS, deepseek_v3 as ds, family, llama,
-                             solar_open2 as so)
+                             moe, solar_open2 as so)
 from gofr_tpu.ops import kda
 from gofr_tpu.tpu import GenerationEngine
 from gofr_tpu.tpu.checkpoint import maybe_quantize
@@ -252,12 +252,12 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
         perm[0:4], perm[4 * j:4 * j + 4] = np.arange(4 * j, 4 * j + 4), \
             np.arange(4)
         lw = {k: v[1] for k, v in layers.items()
-              if k not in ds.EXPERT_STACKS}
+              if k not in moe.EXPERT_STACKS}
         lw.update(router=lw["router"][:, perm],
                   router_bias=lw["router_bias"][perm],
                   experts=({k: layers[k][:, 4 * j:4 * j + 4]
-                            for k in ds.EXPERT_STACKS}, jnp.int32(1)))
-        got, _ = ds.moe_ffn(h[None], lw, CFG)
+                            for k in moe.EXPERT_STACKS}, jnp.int32(1)))
+        got, _ = moe.moe_ffn(h[None], lw, CFG)
         assert np.abs(np.asarray(got[0]) - np.asarray(ref_share + shared)) \
             .max() < 1e-4
         total = total + np.asarray(ref_share)
