@@ -405,6 +405,74 @@ def kernel_cases(interpret: bool = False):
             return max(_max_err(y, want_y), _max_err(s1, want_s))
         return run
 
+    def cursors(smax, slots=128):
+        # under, at and over a block's edge and the table's end, dead
+        # slots between
+        some = [0, 1, 255, 256, 257, 511, 512, 513, 750, 1023, 1500, 2047,
+                2048, 2049, 3000, smax - 1]
+        return jnp.minimum(jnp.asarray(some * (slots // len(some)),
+                                       jnp.int32), smax - 1)
+
+    def latent_inputs(layers, heads, width, smax, seed, slots=128):
+        # queries scaled so that a softmax over thousands of rows is
+        # neither flat nor one-hot
+        return (rand(seed, (slots, heads, width)) * 0.05,
+                rand(seed + 1, (layers, slots, smax, width)),
+                rand(seed + 2, (slots, width)))
+
+    def latent_kept():
+        # the masked walk of the sparse-latent family's full layers at
+        # its cell's shapes (3 x 128 slots x 4,096 rows of 640 lanes,
+        # 128 heads, rank 512): a mask that keeps two rows in three, the
+        # token's own row kept or not
+        from gofr_tpu.ops import mla
+        q, rows, new = latent_inputs(3, 128, 640, 4096, 70)
+        lengths = cursors(4096)
+        keep = jax.random.bernoulli(jax.random.PRNGKey(73), 0.66,
+                                    (128, 4096))
+        # a slot that keeps no cached row keeps its own, as the selection
+        # does (fewer candidates than index_topk are all kept)
+        below = jnp.arange(4096)[None] < lengths[:, None]
+        own = (jnp.arange(128) % 3 != 0) | ~jnp.any(keep & below, axis=-1)
+        got = mla.decode_attention_kept(
+            q, rows, new, lengths, jnp.int32(1), keep, own, rank=512,
+            block_s=mla.decode_block(rows, 512), interpret=interpret)
+        ref = mla.decode_attention_reference(q, rows[1], new, lengths, 512,
+                                             keep, own)
+        return _max_err(got, ref)
+
+    def latent_ring():
+        # the same kernel over the window layers' rings (6 x 128 slots x
+        # 512 rows of 1,152 lanes, 64 heads, rank 1,024)
+        from gofr_tpu.ops import mla
+        q, rings, new = latent_inputs(6, 64, 1152, 512, 74)
+        lengths = jnp.asarray([0, 1, 255, 256, 511, 512, 513, 767, 768,
+                               1023, 1024, 1025, 1500, 2046, 0, 700] * 8,
+                              jnp.int32)
+        got = mla.decode_attention_ring(
+            q, rings, new, lengths, jnp.int32(4), rank=1024,
+            block_s=mla.decode_block(rings, 1024), interpret=interpret)
+        ref = mla.decode_attention_reference(
+            q, rings[4], new, jnp.minimum(lengths, 512), 1024)
+        return _max_err(got, ref)
+
+    def index_scores():
+        # the indexer's score pass (3 x 128 slots x 4,096 keys of 128,
+        # 64 index heads): dead blocks and the rows past a cursor read
+        # NEG_INF on both sides, so the error is over the live rows
+        from gofr_tpu.ops import dsa
+        slots, hi, d, smax = 128, 64, 128, 4096
+        lengths = cursors(smax)
+        q = rand(78, (slots, hi, d))
+        w = jax.random.normal(jax.random.PRNGKey(79), (slots, hi),
+                              jnp.float32) * (hi * d) ** -0.5
+        keys = rand(80, (3, slots, smax, d))
+        got = dsa.index_scores_stacked(
+            q, w, keys, lengths, jnp.int32(2),
+            block_s=dsa.scores_block(keys), interpret=interpret)
+        ref = dsa.decode_scores_reference(q, w, keys[2], lengths)
+        return _max_err(got, ref)
+
     def experts(layers, held, dim, ffn, top_k=8, slots=128, gated=True):
         def run():
             # the routed experts' kernel at a cell's decode shapes (128
@@ -455,7 +523,12 @@ def kernel_cases(interpret: bool = False):
             return max(_max_err(got, ref), dead)
         return run
 
-    return [("expert_blocks_stacked[int8,7x256x2048x512]",
+    return [("decode_attention_kept[bf16,3x128x4096x640,H=128]", latent_kept),
+            ("decode_attention_ring[bf16,6x128x512x1152,H=64]", latent_ring),
+            ("index_scores_stacked[bf16,3x128x4096x128,Hi=64]", index_scores),
+            ("expert_blocks_stacked[int8,8x32x5120x1536]",
+             experts(8, 32, 5120, 1536)),
+            ("expert_blocks_stacked[int8,7x256x2048x512]",
              experts(7, 256, 2048, 512)),
             ("expert_blocks_stacked[int8,8x40x4096x1280]",
              experts(8, 40, 4096, 1280)),

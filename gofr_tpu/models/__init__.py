@@ -1,17 +1,20 @@
-"""Model zoo: six decoder families behind one seam (``family``), BERT
+"""Model zoo: seven decoder families behind one seam (``family``), BERT
 (embeddings), ViT (vision).
 
 The decoders: ``llama`` (dense GQA and Mixtral's eight experts; the row
 cache and the dense block every family builds on), ``deepseek_v3``
 (latent attention), ``solar_open2`` (gated delta-rule layers beside full
 ones), ``laguna`` (sliding-window layers on a ring), ``lfm2`` (gated
-short convolutions), ``nemotron_h`` (Mamba-2 state-space layers). What
-they share has an owner that is no family: ``moe`` (the routed
-feed-forward of the five sparse ones), ``blocks`` (embedding, the gather
-before the logits, a layer out of a stack, the window and conv families'
-attention block and stack), ``hybrid_cache`` (a state beside rows) and
-``common`` (the configuration and the one list of serving options a
-family may refuse). A family imports those, ``llama`` and ``ops/``, never
+short convolutions), ``nemotron_h`` (Mamba-2 state-space layers),
+``dots3_note`` (latent attention at two widths: full layers that select
+the rows they read, window layers on a ring of latent rows). What they
+share has an owner that is no family: ``moe`` (the routed feed-forward
+of the six sparse ones), ``latent`` (the latent row's cache and the
+absorbed form's algebra, the two latent families'), ``blocks``
+(embedding, the gather before the logits, a layer out of a stack, the
+window and conv families' attention block and stack), ``hybrid_cache``
+(a state beside rows) and ``common`` (the configuration and the one list
+of serving options a family may refuse). A family imports those, ``llama`` and ``ops/``, never
 a sibling family nor another module's private name
 (tests/test_models_layering.py; docs/tpu/serving-engine.md says what a
 new family touches).
@@ -25,8 +28,9 @@ layer axis. No torch, no module classes — params are data, which is what
 """
 
 from .common import ModelConfig, LLAMA_CONFIGS, BERT_CONFIGS, VIT_CONFIGS
-from . import (llama, bert, vit, moe, blocks, hybrid_cache, deepseek_v3,
-               solar_open2, laguna, lfm2, nemotron_h)
+from . import (llama, bert, vit, moe, latent, blocks, hybrid_cache,
+               deepseek_v3, solar_open2, laguna, lfm2, nemotron_h,
+               dots3_note)
 
 
 def family(cfg: ModelConfig):
@@ -45,13 +49,14 @@ def family(cfg: ModelConfig):
     if "linear" in cfg.layer_pattern:
         return solar_open2
     if "window" in cfg.layer_pattern:
-        return laguna
+        # latent rows on the ring, or K and V heads
+        return dots3_note if cfg.kv_lora_rank > 0 else laguna
     if "conv" in cfg.layer_pattern:
         return lfm2
     return deepseek_v3 if cfg.kv_lora_rank > 0 else llama
 
 
 __all__ = ["ModelConfig", "LLAMA_CONFIGS", "BERT_CONFIGS", "VIT_CONFIGS",
-           "llama", "bert", "vit", "moe", "blocks", "hybrid_cache",
-           "deepseek_v3", "solar_open2", "laguna", "lfm2", "nemotron_h",
-           "family"]
+           "llama", "bert", "vit", "moe", "latent", "blocks",
+           "hybrid_cache", "deepseek_v3", "solar_open2", "laguna", "lfm2",
+           "nemotron_h", "dots3_note", "family"]

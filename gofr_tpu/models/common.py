@@ -123,6 +123,30 @@ class ModelConfig:
     moe_latent_dim: int = 0
     expert_act: str = "swiglu"
     shared_ffn_dim: int = 0
+    # latent attention at two widths with a learned selection
+    # (models/dots3_note.py; kv_lora_rank > 0 WITH a "window" entry in
+    # layer_pattern selects that family). A window layer is latent
+    # attention at its own sizes, window_heads heads of
+    # window_qk_nope_head_dim + window_qk_rope_head_dim on a latent of
+    # window_kv_lora_rank behind a query latent of window_q_lora_rank,
+    # values of window_v_head_dim (each 0 = the full layers' size),
+    # rotated by window_rope_theta; it sees window_size positions, the
+    # token's own among them, and caches window_size - 1 rows on a ring.
+    # A full layer has an indexer of index_heads heads of index_head_dim
+    # that scores every cached position and keeps the index_topk best:
+    # the softmax runs over those alone. lora_rescale (the published
+    # switch): the normed query and key-value latents are multiplied by
+    # (dim / their rank)^1/2. The family refuses index_topk 0, head_gate
+    # or lora_rescale false: they are what it is
+    window_q_lora_rank: int = 0
+    window_kv_lora_rank: int = 0
+    window_qk_nope_head_dim: int = 0
+    window_qk_rope_head_dim: int = 0
+    window_v_head_dim: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    lora_rescale: bool = False
 
     def __post_init__(self):
         # a configuration file gives the pattern as a list
@@ -241,6 +265,26 @@ LLAMA_CONFIGS = {
         routed_scaling=2.5, n_shared_experts=1, moe_ffn_dim=40,
         n_experts_held=4, moe_latent_dim=24, expert_act="relu2",
         shared_ffn_dim=56),
+    # the sparse-latent family at test size: a dense full layer, then two
+    # periods of (full, window, window, window): full layers keep 16 of
+    # up to 128 cached rows, window layers see 9 positions (a ring of 8
+    # that every test prompt wraps), every width of one kind differs
+    # from the other kind's, 4 of 16 experts held
+    "tiny-dsa-moe": ModelConfig(
+        name="tiny-dsa-moe", vocab_size=256, dim=64, n_layers=9, n_heads=4,
+        n_kv_heads=4, ffn_dim=160, max_seq=128, rope_theta=8e7,
+        norm_eps=1e-5, dtype="float32",
+        layer_pattern=("full", "full", "window", "window", "window",
+                       "full", "window", "window", "window"),
+        q_lora_rank=48, kv_lora_rank=36, qk_nope_head_dim=24,
+        qk_rope_head_dim=8, v_head_dim=20, window_size=9, window_heads=2,
+        window_rope_theta=50000.0, window_q_lora_rank=40,
+        window_kv_lora_rank=44, window_qk_nope_head_dim=28,
+        window_qk_rope_head_dim=4, window_v_head_dim=12, head_gate=True,
+        index_heads=3, index_head_dim=16, index_topk=16, lora_rescale=True,
+        n_experts=16, experts_per_token=4, n_expert_groups=1, topk_groups=1,
+        routed_scaling=1.0, n_shared_experts=1, moe_ffn_dim=40,
+        n_dense_layers=1, n_experts_held=4),
 }
 
 BERT_CONFIGS = {

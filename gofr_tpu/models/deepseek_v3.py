@@ -38,20 +38,19 @@ is dense layer ``l`` or routed layer ``l - n_dense_layers``.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from ..ops import mla
 from ..ops.norms import rms_norm
-from ..ops.quant import QuantizedLinear, qmatmul
-from ..ops.rope import apply_rope, rope_frequencies, yarn_softmax_scale
-from . import llama, moe
+from ..ops.quant import qmatmul
+from ..ops.rope import apply_rope, rope_frequencies
+from . import latent, llama, moe
 from .blocks import embed, experts_apart, prompt_rows
 from .common import ModelConfig, dense_init, refused_options
+from .latent import LatentCache
 
-_LANES = 128
 _ROPE_CACHE: dict[tuple, tuple] = {}
 RECOMPUTABLE = True  # rows, as llama's: see models.family
 
@@ -70,41 +69,19 @@ def get_rope_tables(cfg: ModelConfig, max_seq: int):
     return _ROPE_CACHE[key]
 
 
-def row_width(cfg: ModelConfig) -> int:
-    """Values a cached row holds: the latent and the shared rotated key."""
-    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
-
-
-def stored_width(cfg: ModelConfig) -> int:
-    """Lanes a cached row takes: ``row_width`` rounded up to whole HBM
-    tiles of 128 lanes (576 -> 640; ops/mla.py says why)."""
-    return -(-row_width(cfg) // _LANES) * _LANES
-
-
-
-class LatentCache(NamedTuple):
-    """Preallocated decode cache of latent rows, per-slot cursors."""
-
-    rows: jnp.ndarray     # [L, B, Smax, stored_width]
-    lengths: jnp.ndarray  # [B] int32: valid rows a slot
-
-    @property
-    def quantized(self) -> bool:
-        return False
-
-
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int | None = None,
                dtype=None) -> LatentCache:
     return LatentCache(
         rows=jnp.zeros((cfg.n_layers, batch, max_seq or cfg.max_seq,
-                        stored_width(cfg)), dtype or cfg.jdtype),
+                        latent.sizes(cfg).stored_width),
+                       dtype or cfg.jdtype),
         lengths=jnp.zeros((batch,), jnp.int32))
 
 
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     """(heads, values a head) of a cached token, for the prefix index's
     shape contract: one shared row."""
-    return 1, stored_width(cfg)
+    return 1, latent.sizes(cfg).stored_width
 
 
 def decode_kv_block(cfg: ModelConfig, cache: LatentCache, mesh=None):
@@ -180,60 +157,6 @@ def init(cfg: ModelConfig, key) -> dict:
 
 # -- attention -----------------------------------------------------------------
 
-def softmax_scale(cfg: ModelConfig) -> float:
-    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 \
-        * yarn_softmax_scale(cfg.rope_scaling)
-
-
-def _split_kvb(w_kvb, cfg: ModelConfig):
-    """``W_kvb`` [rank, H * (dn + dv)] a head: (W_UK [rank, H, dn], its
-    output-channel scale [H, dn] or None, W_UV [rank, H, dv], scale)."""
-    H, dn, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
-    if isinstance(w_kvb, QuantizedLinear):
-        w = w_kvb.w.reshape(-1, H, dn + dv)
-        s = w_kvb.scale.reshape(H, dn + dv)
-        return w[..., :dn], s[:, :dn], w[..., dn:], s[:, dn:]
-    w = w_kvb.reshape(-1, H, dn + dv)
-    return w[..., :dn], None, w[..., dn:], None
-
-
-@jax.named_scope("mla/q_absorb")
-def _absorb(q, w_kvb, cfg: ModelConfig):
-    """q [B, S, H, dn + dr] (scaled) -> q_cat [B, S, H, stored_width]:
-    ``[q_nope W_UK^T | q_pe | 0]``. An int8 ``W_UK``'s output-channel
-    scale folds into ``q_nope``."""
-    dn = cfg.qk_nope_head_dim
-    w_uk, s_uk, _, _ = _split_kvb(w_kvb, cfg)
-    q_nope = q[..., :dn]
-    if s_uk is not None:
-        q_nope = (q_nope.astype(jnp.float32) * s_uk).astype(q.dtype)
-    q_abs = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk.astype(q.dtype),
-                       preferred_element_type=jnp.float32).astype(q.dtype)
-    pad = stored_width(cfg) - row_width(cfg)
-    return jnp.pad(jnp.concatenate([q_abs, q[..., dn:]], -1),
-                   ((0, 0), (0, 0), (0, 0), (0, pad)))
-
-
-def _unabsorb(o_lat, w_kvb, cfg: ModelConfig, dtype):
-    """o_lat [B, S, H, rank] -> [B, S, H, dv]: through ``W_UV``, whose
-    int8 output-channel scale folds into the result."""
-    _, _, w_uv, s_uv = _split_kvb(w_kvb, cfg)
-    o = jnp.einsum("bshr,rhd->bshd", o_lat.astype(dtype), w_uv.astype(dtype),
-                   preferred_element_type=jnp.float32)
-    if s_uv is not None:
-        o = o * s_uv
-    return o.astype(dtype)
-
-
-def _expand(row, w_kvb, cfg: ModelConfig):
-    """Keys and values a head over the chunk's own rows [B, S, width]:
-    (k_nope [B, S, H, dn], k_pe [B, S, dr], v [B, S, H, dv])."""
-    R, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    B, S = row.shape[:2]
-    kv = qmatmul(row[..., :R], w_kvb).reshape(B, S, cfg.n_heads, -1)
-    return kv[..., :dn], row[..., R:], kv[..., dn:]
-
-
 def _layer(x, lw, cfg: ModelConfig, cos, sin, positions, attend, ffn,
            valid=None):
     """One block. ``attend(q, row, w_kvb) -> [B, S, H, dv]``: q is rotated
@@ -248,7 +171,8 @@ def _layer(x, lw, cfg: ModelConfig, cos, sin, positions, attend, ffn,
         q = qmatmul(c_q, lw["w_qb"]).reshape(B, S, H, dn + dr)
         q = jnp.concatenate(
             [q[..., :dn], apply_rope(q[..., dn:], cos, sin, positions)], -1)
-        q = (q.astype(jnp.float32) * softmax_scale(cfg)).astype(x.dtype)
+        q = (q.astype(jnp.float32) * latent.softmax_scale(
+            latent.sizes(cfg), cfg.rope_scaling)).astype(x.dtype)
         kva = qmatmul(h, lw["w_kva"])
         c_kv = rms_norm(kva[..., :R], lw["kv_norm"], cfg.norm_eps)
         k_pe = apply_rope(kva[..., None, R:], cos, sin, positions)[..., 0, :]
@@ -258,8 +182,7 @@ def _layer(x, lw, cfg: ModelConfig, cos, sin, positions, attend, ffn,
         x = x + qmatmul(o.reshape(B, S, H * cfg.v_head_dim), lw["wo"])
     h = rms_norm(x, lw["ffn_norm"], cfg.norm_eps)
     y, counts = ffn(h, lw, cfg, valid)
-    pad = stored_width(cfg) - row_width(cfg)
-    return x + y, jnp.pad(row, ((0, 0), (0, 0), (0, pad))), counts
+    return x + y, latent.pad_row(row, latent.sizes(cfg)), counts
 
 
 def _two_stacks(params, cfg: ModelConfig, x, body, per_layer):
@@ -301,9 +224,10 @@ def prefill_kv(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     lengths, positions, valid = prompt_rows(tokens, lengths)
     cos, sin = rope_tables or get_rope_tables(cfg,
                                               rope_max or tokens.shape[1])
+    sz = latent.sizes(cfg)
 
     def attend(q, row, w_kvb):
-        k_nope, k_pe, v = _expand(row, w_kvb, cfg)
+        k_nope, k_pe, v = latent.expand(row, w_kvb, sz)
         return mla.prefill_attention(q, k_nope, k_pe, v, mask=valid)
 
     def body(x, lw, _, ffn):
@@ -345,15 +269,15 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     cos, sin = rope_tables or get_rope_tables(cfg, cache.rows.shape[2])
     positions = start + jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32),
                                          (B, C))
-    R = cfg.kv_lora_rank
+    sz = latent.sizes(cfg)
 
     def body(x, lw, layer_rows, ffn):
         def attend(q, row, w_kvb):
-            k_nope, k_pe, v = _expand(row, w_kvb, cfg)
+            k_nope, k_pe, v = latent.expand(row, w_kvb, sz)
             o_lat, o_new = mla.chunk_attention(
-                _absorb(q, w_kvb, cfg), q, layer_rows, start, k_nope, k_pe,
-                v, R)
-            return _unabsorb(o_lat, w_kvb, cfg, q.dtype) + o_new
+                latent.absorb(q, w_kvb, sz), q, layer_rows, start, k_nope,
+                k_pe, v, sz.rank)
+            return latent.unabsorb(o_lat, w_kvb, sz, q.dtype) + o_new
 
         x, row, _ = _layer(x, lw, cfg, cos, sin, positions, attend, ffn)
         return x, row
@@ -380,6 +304,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     assignments a routed layer a held expert [Ls, Eh] int32)."""
     B = tokens.shape[0]
     cos, sin = rope_tables or get_rope_tables(cfg, cache.rows.shape[2])
+    sz = latent.sizes(cfg)
     lengths = cache.lengths
     positions = lengths[:, None]
     live = lengths if active is None else jnp.where(active, lengths, 0)
@@ -388,12 +313,11 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
     def body(x, lw, li, ffn):
         def attend(q, row, w_kvb):
-            pad = stored_width(cfg) - row_width(cfg)
             o_lat = mla.decode_attention(
-                _absorb(q, w_kvb, cfg)[:, 0], cache.rows,
-                jnp.pad(row[:, 0], ((0, 0), (0, pad))), live, li,
-                rank=cfg.kv_lora_rank, block_s=block_s)
-            return _unabsorb(o_lat[:, None], w_kvb, cfg, q.dtype)
+                latent.absorb(q, w_kvb, sz)[:, 0], cache.rows,
+                latent.pad_row(row[:, 0], sz), live, li,
+                rank=sz.rank, block_s=block_s)
+            return latent.unabsorb(o_lat[:, None], w_kvb, sz, q.dtype)
 
         x, row, counts = _layer(x, lw, cfg, cos, sin, positions, attend,
                                 ffn, valid)

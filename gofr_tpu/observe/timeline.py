@@ -129,7 +129,8 @@ class Timeline:
                      touched: int | None = None,
                      states: int | None = None,
                      ring: int | None = None,
-                     sampled: int | None = None) -> None:
+                     sampled: int | None = None,
+                     kept: tuple[int, int] | None = None) -> None:
         """One fused decode dispatch->reap: ``slots`` is the tuple of
         active slot indices as dispatched, ``steps`` the block size,
         ``live`` the KV positions those slots held at dispatch (what
@@ -149,10 +150,15 @@ class Timeline:
         last, on a block with a slot that draws, ``sampled``: what it
         asked of the sampler (bit 0 a slot draws, bit 1 one draws from
         its top-k: generator._sampling_flag; an all-greedy block says
-        nothing). A field keeps its place: states without an expert
-        layer come after two Nones, ring rows without states after a
-        None."""
-        tail = [assigned, touched, states, ring, sampled]
+        nothing); and after that, where its full layers select the rows
+        they read, ``kept``: (the rows the block's steps kept, the rows
+        they chose among: the cached ones and each token's own), both
+        summed over those layers, the steps and the active slots (beside
+        ``live``, which is what one such layer held for one step at
+        dispatch). A
+        field keeps its place: states without an expert layer come after
+        two Nones, ring rows without states after a None."""
+        tail = [assigned, touched, states, ring, sampled, kept]
         while tail and tail[-1] is None:
             tail.pop()
         if 0 < len(tail) < 2:
@@ -360,7 +366,8 @@ class Timeline:
                                           **{k: v for k, v in zip(
                                               ("moe_assigned", "moe_touched",
                                                "states_updated",
-                                               "ring_rows", "sampled"),
+                                               "ring_rows", "sampled",
+                                               "rows_kept"),
                                               more)
                                              if v is not None}}})
             elif kind == "prefill":

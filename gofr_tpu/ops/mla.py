@@ -70,21 +70,84 @@ from .flash_decode import _LANES, _SUBLANES, _work_list, block_size
 
 
 @jax.named_scope("mla/prefill_attn")
-def prefill_attention(q, k_nope, k_pe, v, mask=None):
+def prefill_attention(q, k_nope, k_pe, v, mask=None, keep=None):
     """Expanded causal attention. q [B, S, H, dn + dr] (scaled);
     k_nope [B, S, H, dn]; k_pe [B, S, dr], one for all heads;
-    v [B, S, H, dv]; mask [B, S] valid tokens. Returns [B, S, H, dv]."""
+    v [B, S, H, dv]; mask [B, S] valid tokens; keep [B or 1, S, S]: the
+    positions each query may see beside the causal rule (a window's
+    band, a learned selection). Returns [B, S, H, dv]."""
     s = q.shape[1]
     dn = k_nope.shape[-1]
     scores = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :dn], k_nope,
                          preferred_element_type=jnp.float32)
               + jnp.einsum("bqhd,bkd->bhqk", q[..., dn:], k_pe,
                            preferred_element_type=jnp.float32))
-    keep = jnp.tril(jnp.ones((s, s), bool))[None, None]
+    causal = jnp.tril(jnp.ones((s, s), bool))[None, None]
+    keep = causal if keep is None else causal & keep[:, None]
     if mask is not None:
         keep = keep & mask[:, None, None, :]
     probs = jax.nn.softmax(jnp.where(keep, scores, NEG_INF), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
+
+
+# cached rows a trip of ``chunk_attention_kept``'s walk scores
+_CHUNK_BLOCK = 512
+
+
+@jax.named_scope("mla/chunk_attn_kept")
+def chunk_attention_kept(q_cat, q, rows, k_nope, k_pe, v, rank: int,
+                         keep_cache, keep_new, live):
+    """``chunk_attention`` with a mask a query in place of the cursor:
+    keep_cache [B or 1, C, T] over the cached rows ``rows`` [B, T, width]
+    (a ring's rows in the order they lie, or a slot's rows from 0),
+    keep_new [B or 1, C, C] over the chunk's own tokens (the causal rule
+    included). ``live`` (a traced scalar): the leading rows of ``rows``
+    that ``keep_cache`` can keep, the chunk's start or what of it a ring
+    holds. The cached rows are walked a block of ``_CHUNK_BLOCK`` at a
+    time up to ``live`` under a running softmax that starts from the
+    chunk's own tokens, so a reserved row costs nothing and the float32
+    scores held at once are heads x C x block (all T of them are 1 GB a
+    layer at 128 x 512 x 4,096). Returns (o_lat [B, C, H, rank] float32,
+    o_new [B, C, H, dv]) as ``chunk_attention`` does."""
+    from .flash import fit_block
+
+    t, dn = rows.shape[1], k_nope.shape[-1]
+    block = fit_block(t, _CHUNK_BLOCK)
+    rows = rows.astype(q_cat.dtype)
+    s_new = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :dn], k_nope,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bqhd,bkd->bhqk", q[..., dn:], k_pe,
+                          preferred_element_type=jnp.float32))
+    s_new = jnp.where(keep_new[:, None], s_new, NEG_INF)
+    # a query whose selection kept none of the chunk's tokens starts from
+    # NEG_INF and weights of 1: the first cached row it does keep scales
+    # them to nothing
+    m = jnp.max(s_new, -1, keepdims=True)                    # [B, H, C, 1]
+    p = jnp.exp(s_new - m)
+    o_new = jnp.einsum("bhqk,bkhd->bhqd", p.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32)
+
+    def fold(j, carry):
+        m, l, o_lat, o_new = carry
+        tile = jax.lax.dynamic_slice_in_dim(rows, j * block, block, 1)
+        keep = jax.lax.dynamic_slice_in_dim(keep_cache, j * block, block, 2)
+        s = jnp.einsum("bqhw,btw->bhqt", q_cat, tile,
+                       preferred_element_type=jnp.float32)
+        s = jnp.where(keep[:, None], s, NEG_INF)
+        m_next = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
+        scale, p = jnp.exp(m - m_next), jnp.exp(s - m_next)
+        o_lat = o_lat * scale + jnp.einsum(
+            "bhqt,btr->bhqr", p.astype(rows.dtype), tile[..., :rank],
+            preferred_element_type=jnp.float32)
+        return (m_next, l * scale + jnp.sum(p, -1, keepdims=True), o_lat,
+                o_new * scale)
+
+    _, l, o_lat, o_new = jax.lax.fori_loop(
+        0, (live + block - 1) // block, fold,
+        (m, jnp.sum(p, -1, keepdims=True),
+         jnp.zeros(m.shape[:3] + (rank,), jnp.float32), o_new))
+    return (jnp.swapaxes(o_lat / l, 1, 2),
+            jnp.swapaxes(o_new / l, 1, 2).astype(v.dtype))
 
 
 @jax.named_scope("mla/chunk_attn")
@@ -119,19 +182,27 @@ def chunk_attention(q_cat, q, rows, start, k_nope, k_pe, v, rank: int):
     return o_lat, o_new
 
 
-def decode_attention_reference(q_cat, rows, row_new, lengths, rank: int):
+def decode_attention_reference(q_cat, rows, row_new, lengths, rank: int,
+                               keep=None, keep_new=None):
     """One token a slot over every reserved position, masked by the
     cursor. q_cat [B, H, width] scaled; rows [B, Smax, width];
     row_new [B, width]: this token's row, not in the cache yet;
-    lengths [B] excluding it. Returns o_lat [B, H, rank] in q's dtype."""
+    lengths [B] excluding it; keep [B, Smax] and keep_new [B]: the
+    cached rows below the cursor, and whether the token's own row, that
+    a learned selection kept (None: all). Returns o_lat [B, H, rank] in
+    q's dtype."""
     smax = rows.shape[1]
     rows = rows.astype(q_cat.dtype)
     s_cache = jnp.einsum("bhw,btw->bht", q_cat, rows,
                          preferred_element_type=jnp.float32)
-    s_cache = jnp.where(jnp.arange(smax)[None, None] < lengths[:, None, None],
-                        s_cache, NEG_INF)
+    live = jnp.arange(smax)[None, None] < lengths[:, None, None]
+    if keep is not None:
+        live = live & keep[:, None]
+    s_cache = jnp.where(live, s_cache, NEG_INF)
     s_new = jnp.einsum("bhw,bw->bh", q_cat, row_new,
                        preferred_element_type=jnp.float32)[..., None]
+    if keep_new is not None:
+        s_new = jnp.where(keep_new[:, None, None], s_new, NEG_INF)
     probs = jax.nn.softmax(jnp.concatenate([s_cache, s_new], -1), axis=-1)
     o = (jnp.einsum("bht,btr->bhr", probs[..., :smax].astype(rows.dtype),
                     rows[..., :rank], preferred_element_type=jnp.float32)
@@ -143,12 +214,16 @@ _CHAINS = 4             # items a trip of the kernel's loop folds, a chain each
 _N_BUF = 3 * _CHAINS    # tiles in VMEM: a trip's, two more trips' in flight
 
 
-def _decode_kernel(layer_ref, n_ref, slot_ref, blk_ref, len_ref, q_ref,
-                   new_ref, rows_hbm, o_ref, buf, m_ref, l_ref, acc_ref,
-                   first_ref, sem, *, block_s: int, rank: int):
+def _decode_kernel(layer_ref, n_ref, slot_ref, blk_ref, len_ref, *refs,
+                   block_s: int, rank: int, masked: bool = False):
     """One layer: walk the (slot, block) work list ``_CHAINS`` items a
     trip, the next two trips' tiles in flight. A row tile serves the
     score matmul whole and the value matmul by its first ``rank`` lanes.
+
+    ``masked``: a learned selection goes with the cursor. ``own_ref``
+    [B] (scalars) says whether a slot's appended row is kept,
+    ``keep_ref`` [B * blocks a slot, block_s] which of a block's rows
+    are; a row left out scores NEG_INF, as one past the cursor does.
 
     A trip's items are one straight line of code whatever their slots:
     every score matmul first, then each item's softmax update starting
@@ -156,6 +231,13 @@ def _decode_kernel(layer_ref, n_ref, slot_ref, blk_ref, len_ref, q_ref,
     slot, so that one item's matmuls are issued while another's softmax
     runs. What needs a branch stands before the line (a slot's first
     score) or after it (a slot's answer)."""
+    if masked:
+        own_ref, q_ref, new_ref, keep_ref, rows_hbm, o_ref, buf, m_ref, \
+            l_ref, acc_ref, first_ref, sem = refs
+        per_slot = keep_ref.shape[0] // len_ref.shape[0]
+    else:
+        q_ref, new_ref, rows_hbm, o_ref, buf, m_ref, l_ref, acc_ref, \
+            first_ref, sem = refs
     layer = layer_ref[0]
     n = n_ref[0]
 
@@ -168,8 +250,9 @@ def _decode_kernel(layer_ref, n_ref, slot_ref, blk_ref, len_ref, q_ref,
     def first_score(slot):
         # the appended row is the recurrence's first element
         new = new_ref[slot].astype(jnp.float32)              # [1, width]
-        return jnp.sum(q_ref[slot].astype(jnp.float32) * new, axis=-1,
-                       keepdims=True)                        # [H, 1]
+        s = jnp.sum(q_ref[slot].astype(jnp.float32) * new, axis=-1,
+                    keepdims=True)                           # [H, 1]
+        return jnp.where(own_ref[slot] != 0, s, NEG_INF) if masked else s
 
     def first_acc(slot):
         return jnp.broadcast_to(
@@ -195,7 +278,11 @@ def _decode_kernel(layer_ref, n_ref, slot_ref, blk_ref, len_ref, q_ref,
                 preferred_element_type=jnp.float32)
             pos = blks[j] * block_s + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_s), 1)
-            scored.append((tile, jnp.where(pos < lens[j], s, NEG_INF)))
+            seen = pos < lens[j]
+            if masked:
+                seen = seen & (keep_ref[pl.ds(
+                    slots[j] * per_slot + blks[j], 1), :] != 0)
+            scored.append((tile, jnp.where(seen, s, NEG_INF)))
         m, l, acc = m_ref[:, :1], l_ref[:, :1], acc_ref[...]
         answers = []
         for j, (tile, s) in enumerate(scored):               # s [H, BS]
@@ -249,10 +336,13 @@ def _decode_kernel(layer_ref, n_ref, slot_ref, blk_ref, len_ref, q_ref,
 @functools.partial(jax.jit, static_argnames=("rank", "block_s", "interpret"))
 def decode_attention_stacked(q_cat, rows, row_new, lengths, layer, *,
                              rank: int, block_s: int,
-                             interpret: bool = False):
+                             interpret: bool = False, keep=None,
+                             keep_new=None):
     """``decode_attention_reference`` over layer ``layer`` of the stacked
     cache ``rows`` [L, B, Smax, width], reading only what ``lengths``
-    (0 for a slot whose cache must not be read) says is live."""
+    (0 for a slot whose cache must not be read) says is live. With
+    ``keep`` [B, Smax] and ``keep_new`` [B] the blocks up to the cursor
+    are fetched all the same and the rows left out are masked."""
     b, h, width = q_cat.shape
     smax = rows.shape[2]
     h_pad = -(-h // _SUBLANES) * _SUBLANES
@@ -260,11 +350,21 @@ def decode_attention_stacked(q_cat, rows, row_new, lengths, layer, *,
     n, slot, blk = _work_list(lengths, smax, block_s)
     qp = jnp.pad(q_cat, ((0, 0), (0, h_pad - h), (0, 0)))
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    masked = keep is not None
+    scalars = (jnp.reshape(layer, (1,)).astype(jnp.int32), n, slot, blk,
+               lengths)
+    operands = (qp.astype(rows.dtype),
+                row_new[:, None, :].astype(rows.dtype))
+    if masked:
+        scalars += (keep_new.astype(jnp.int32),)
+        operands += (keep.astype(jnp.int32).reshape(-1, block_s),)
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_s=block_s, rank=rank),
+        functools.partial(_decode_kernel, block_s=block_s, rank=rank,
+                          masked=masked),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5, grid=(1,),
-            in_specs=[vmem, vmem, pl.BlockSpec(memory_space=pl.ANY)],
+            num_scalar_prefetch=len(scalars), grid=(1,),
+            in_specs=[vmem] * len(operands)
+            + [pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=vmem,
             scratch_shapes=[
                 pltpu.VMEM((_N_BUF, block_s, width), rows.dtype),
@@ -277,13 +377,41 @@ def decode_attention_stacked(q_cat, rows, row_new, lengths, layer, *,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=96 * 1024 * 1024),
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), n, slot, blk, lengths,
-      qp.astype(rows.dtype), row_new[:, None, :].astype(rows.dtype), rows)
+    )(*scalars, *operands, rows)
     # a slot with no item never reached the kernel's write: its answer is
     # the softmax of one element, the appended row's latent
     alone = jnp.broadcast_to(row_new[:, None, :rank], (b, h, rank))
     return jnp.where((lengths > 0)[:, None, None], out[:, :h],
                      alone.astype(out.dtype)).astype(q_cat.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "block_s", "interpret"))
+def decode_attention_kept(q_cat, rows, row_new, lengths, layer, keep,
+                          keep_new, *, rank: int, block_s: int,
+                          interpret: bool = False):
+    """``decode_attention_stacked`` over the rows a learned selection
+    kept (``keep`` [B, Smax], ``keep_new`` [B]: ops/dsa.py). A program of
+    its own name, so that a device trace tells a selecting layer's
+    kernel from one that reads every row."""
+    return decode_attention_stacked.__wrapped__(
+        q_cat, rows, row_new, lengths, layer, rank=rank, block_s=block_s,
+        interpret=interpret, keep=keep, keep_new=keep_new)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "block_s", "interpret"))
+def decode_attention_ring(q_cat, rings, row_new, lengths, layer, *,
+                          rank: int, block_s: int, interpret: bool = False):
+    """``decode_attention_stacked`` over layer ``layer`` of stacked RINGS
+    [L, B, W, width], position p at row p % W, for slots that have
+    ``lengths`` [B] positions cached (0: a slot that must not be read):
+    each attends to its new token and the W positions before it, which
+    are the rows the ring holds, min(length, W) of them from row 0, in
+    whatever order they lie (a softmax does not ask). The new token's
+    row overwrites the oldest after the step. A program of its own
+    name, as ``decode_attention_kept``."""
+    return decode_attention_stacked.__wrapped__(
+        q_cat, rings, row_new, jnp.minimum(lengths, rings.shape[2]), layer,
+        rank=rank, block_s=block_s, interpret=interpret)
 
 
 def decode_block(rows, rank: int) -> int | None:
@@ -317,3 +445,35 @@ def decode_attention(q_cat, rows, row_new, lengths, layer, *, rank: int,
     layer_rows = jax.lax.dynamic_index_in_dim(rows, layer, 0, keepdims=False)
     return decode_attention_reference(q_cat, layer_rows, row_new, lengths,
                                       rank)
+
+
+@jax.named_scope("mla/sparse_decode_attn")
+def sparse_decode_attention(q_cat, rows, row_new, lengths, layer, keep,
+                            keep_new, *, rank: int, block_s: int | None):
+    """``decode_attention`` over the rows a learned selection kept."""
+    if block_s:
+        from .flash import interpret_env
+
+        return decode_attention_kept(q_cat, rows, row_new, lengths, layer,
+                                     keep, keep_new, rank=rank,
+                                     block_s=block_s,
+                                     interpret=interpret_env())
+    layer_rows = jax.lax.dynamic_index_in_dim(rows, layer, 0, keepdims=False)
+    return decode_attention_reference(q_cat, layer_rows, row_new, lengths,
+                                      rank, keep, keep_new)
+
+
+@jax.named_scope("mla/window_decode_attn")
+def ring_decode_attention(q_cat, rings, row_new, lengths, layer, *,
+                          rank: int, block_s: int | None):
+    """``decode_attention`` over layer ``layer`` of stacked rings
+    [L, B, W, width] (``decode_attention_ring`` says what a step reads)."""
+    if block_s:
+        from .flash import interpret_env
+
+        return decode_attention_ring(q_cat, rings, row_new, lengths, layer,
+                                     rank=rank, block_s=block_s,
+                                     interpret=interpret_env())
+    ring = jax.lax.dynamic_index_in_dim(rings, layer, 0, keepdims=False)
+    return decode_attention_reference(
+        q_cat, ring, row_new, jnp.minimum(lengths, ring.shape[1]), rank)

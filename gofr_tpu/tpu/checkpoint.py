@@ -35,7 +35,12 @@ _QUANT_LEAVES = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
                  "w_attn_gate",
                  # the state-space family (models/nemotron_h.py): the
                  # mamba layer's two projections, the latent's pair
-                 "w_ssm_in", "w_ssm_out", "w_latent_down", "w_latent_up"}
+                 "w_ssm_in", "w_ssm_out", "w_latent_down", "w_latent_up",
+                 # the sparse-latent family's indexer
+                 # (models/dots3_note.py): its query and key projections;
+                 # the key's norm, the heads' weights and the gates stay
+                 # in the model's type
+                 "w_iq", "w_ik"}
 
 
 def _flatten(tree: Any, prefix: str = "") -> dict[str, np.ndarray]:
@@ -156,8 +161,11 @@ def random_params(init_fn, cfg, *, quant: bool = False, mesh=None,
     them (XLA dead-code-eliminates the other leaves from each program).
     Quantized projections are drawn directly as uniform int8 with the
     constant per-channel scale that gives fan-in variance — there is no
-    bf16 original to quantize."""
+    bf16 original to quantize. A family whose ``init`` draws a leaf at
+    another fan-in than its contraction axis says so as
+    ``init_fn.fan_in(cfg, leaf name)`` (None: the axis)."""
     key = jax.random.PRNGKey(seed)
+    fan_in = getattr(init_fn, "fan_in", None)
 
     def build(k):
         return maybe_quantize(init_fn(cfg, k), quant)
@@ -166,7 +174,10 @@ def random_params(init_fn, cfg, *, quant: bool = False, mesh=None,
         return isinstance(x, QuantizedLinear)
 
     abstract = jax.eval_shape(build, key)
-    structs, treedef = jax.tree_util.tree_flatten(abstract, is_leaf=is_q)
+    paths, treedef = jax.tree_util.tree_flatten_with_path(abstract,
+                                                          is_leaf=is_q)
+    structs = [st for _, st in paths]
+    names = [getattr(path[-1], "key", "") for path, _ in paths]
     if mesh is not None:
         from ..parallel import shardings_for
 
@@ -177,8 +188,9 @@ def random_params(init_fn, cfg, *, quant: bool = False, mesh=None,
     def leaf(i, struct, sharding):
         if is_q(struct):
             shape = struct.w.shape
+            fan = fan_in and fan_in(cfg, names[i]) or shape[-2]
             # uniform int8 has std 127/sqrt(3); fan-in variance overall
-            scale = (shape[-2] ** -0.5) * (3.0 ** 0.5) / 127.0
+            scale = (fan ** -0.5) * (3.0 ** 0.5) / 127.0
 
             def make(k):
                 return QuantizedLinear(

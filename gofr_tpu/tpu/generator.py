@@ -4172,7 +4172,13 @@ class GenerationEngine:
         touched = None if moe is None else int(np.count_nonzero(moe))
         # a family with recurrent layers: the (layer, slot) states the
         # block's steps updated in place (each is read and written once)
-        states = int(counters[1].sum()) if len(counters) > 1 else None
+        states = int(counters[1].sum()) \
+            if len(counters) > 1 and counters[1] is not None else None
+        # a family whose full layers select the rows they read: the rows
+        # the block's steps kept and the rows they chose among, over
+        # those layers and the active slots
+        kept = tuple(int(n) for n in counters[2].reshape(-1, 2).sum(0)) \
+            if len(counters) > 2 else None
         if self._tl is not None:
             # one ring event per fused block, fanned out to per-slot
             # slices only at export time — the hot path pays one append
@@ -4180,7 +4186,7 @@ class GenerationEngine:
                 t0, time.monotonic(),
                 tuple(int(i) for i in np.flatnonzero(snap_active)),
                 self.decode_block, live, fetched, assigned, touched, states,
-                ring, sampled or None)
+                ring, sampled or None, kept)
         if ring is not None:
             self._ring_live = ring
             if self.metrics is not None:

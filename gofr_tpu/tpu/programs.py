@@ -585,7 +585,8 @@ class EnginePrograms:
         comes back stacked a step as the program's last output, for the
         reap's one fetch. The counters have fixed places: first the
         expert layer's assignments (None where the family has no such
-        layer), then the recurrent states updated.
+        layer), then the recurrent states updated (None likewise), then
+        the rows a learned selection kept and chose among.
 
         ``pack`` [B, W] int32 is the coalesced host dispatch state (one
         h2d when dirty — see _dispatch_pack); ``carry`` is the device

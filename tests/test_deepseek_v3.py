@@ -12,8 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from gofr_tpu.models import (LLAMA_CONFIGS, deepseek_v3 as ds, family, llama,
-                             moe)
+from gofr_tpu.models import (LLAMA_CONFIGS, deepseek_v3 as ds, family,
+                             latent, llama, moe)
 from gofr_tpu.ops import mla, rope
 from gofr_tpu.ops.quant import QuantizedLinear
 from gofr_tpu.tpu import GenerationEngine
@@ -81,7 +81,7 @@ def test_prefill_then_decode_through_the_cache(params, tokens):
     want = _ref_logprobs(params, CFG, tokens)
     cache = ds.init_cache(CFG, 2, 64)
     _, rows, _ = ds.prefill_kv(params, CFG, tokens[:, :10], rope_max=64)
-    assert rows.shape == (CFG.n_layers, 2, 10, ds.stored_width(CFG))
+    assert rows.shape == (CFG.n_layers, 2, 10, latent.sizes(CFG).stored_width)
     cache = ds.write_kv(cache, rows, (0, 0, 0, 0),
                         jnp.array([10, 10], jnp.int32))
     for t in range(10, 24):

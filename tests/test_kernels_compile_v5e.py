@@ -576,6 +576,70 @@ def test_state_space_family_leaves_states_and_stacks_where_they_lie(
         < 14.3e9
 
 
+# -- the sparse-latent family's programs at the published widths ---------------
+
+@pytest.mark.parametrize("program,kernels", [("decode block", 20),
+                                             ("chunk 512", 7)])
+def test_sparse_latent_family_compiles_and_leaves_its_tables_where_they_lie(
+        one_chip, monkeypatch, program, kernels):
+    """``benchmarks/configs/dots3-note-prev-int8-ep8.json`` as its cell
+    runs it: nine layers, 128 slots x 4,096, bfloat16 rows, index keys
+    and rings. Mosaic takes the three new kernels at the cell's shapes:
+    the decode block runs the masked walk over the rows kept three times
+    (rank 512 in 640 lanes, 128 heads), the ring walk six times (rank
+    1,024 in 1,152 lanes, 64 heads, a ring of 512), the index score pass
+    three times (64 heads of 128 over 4,096 keys) and the routed experts'
+    kernel eight times; the 512-token chunk program is jnp but for the
+    experts (seven: a middle chunk yields no logits, so its last layer's
+    feed-forward is dead code). Neither copies a table of the cache or
+    an int8 stack, and the whole engine fits the chip beside the
+    reference check."""
+    import sys
+
+    monkeypatch.setattr(sys.modules[__name__], "SMAX", 4096)
+    cfg = _cell_config("dots3-note-prev-int8-ep8")
+    compiled = _engine_lowered(monkeypatch, one_chip, cfg, 128, None,
+                               program).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"^\s*%?[\w.\-]+ = .*custom_call_target="
+                          r"\"tpu_custom_call\"", text, re.M)) == kernels
+    assert len(re.findall(r"%expert_blocks_stacked[\w.]* = ", text)) \
+        == (8 if program == "decode block" else 7)
+    if program == "decode block":
+        assert len(re.findall(r"%decode_attention_kept[\w.]* = ", text)) == 3
+        assert len(re.findall(r"%decode_attention_ring[\w.]* = ", text)) == 6
+        assert len(re.findall(r"%index_scores_stacked[\w.]* = ", text)) == 3
+    results = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = ((?:s8|bf16)\[[\d,]+\]\S*) ([\w\-]+)\(",
+        text, re.M)
+    assert results                      # the pattern still reads this HLO
+
+    def elements(shape):
+        n = 1
+        for d in shape.split("[")[1].split("]")[0].split(","):
+            n *= int(d)
+        return n
+
+    # rows [3,128,4096,640], keys [3,128,4096,128], rings
+    # [6,128,512,1152], or one layer's or one slot's of them; a stack of
+    # int8 weights (the smallest, the full layers' w_qb, is 75 M)
+    moved = [r for r in results if r[1] in ("copy", "transpose")
+             and (re.match(r"bf16\[(3,)?(128|1),4096,(640|128)\]", r[0])
+                  or re.match(r"bf16\[(6,)?(128|1),512,1152\]", r[0])
+                  or r[0].startswith("s8[") and elements(r[0]) >= 1 << 26)]
+    # the one that is left, once a dispatch and not once a step: the
+    # window layers' w_kvb (a head is [nope 192 | value 128], and a split
+    # at 192 of 320 is no lane tile's edge: XLA re-lays the int8 stack,
+    # 126 MB, before it slices W_UK and W_UV out of it; PERF.md section 7)
+    assert [r[0].split("{")[0] for r in moved] \
+        == ["s8[6,1024,20480]"][:program == "decode block"]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (768 << 20)
+    # 7.9 GB of weights, 3.3 GB of cache, and what a step needs
+    assert 11e9 < mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 12e9
+
+
 def _computations(text):
     """A compiled module's text as ({computation: its instruction
     lines}, the entry computation's name)."""

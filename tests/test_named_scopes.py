@@ -114,3 +114,63 @@ def test_stats_say_how_the_dispatch_tables_are_built(routed_engine):
     assert said == {"block_rows": bm, "buffer_rows": rows,
                     "width": routed_engine.cfg.dim, "path": "loop",
                     "tables": "counted"}
+
+
+# -- the sparse-latent family's spans: the indexer, the selection, the two
+# -- decode attentions, the gates -------------------------------------------
+
+@pytest.fixture(scope="module")
+def selecting_engine():
+    from gofr_tpu.models import dots3_note
+
+    cfg = LLAMA_CONFIGS["tiny-dsa-moe"]
+    eng = GenerationEngine(cfg, dots3_note.init(cfg, jax.random.PRNGKey(0)),
+                           slots=2, max_seq=64, prompt_buckets=(8, 32))
+    yield eng
+    eng.close()
+
+
+def _scoped(text: str, scope: str) -> bool:
+    names = [line for line in text.splitlines() if "loc(" in line]
+    return any(f'{scope}"' in line or f"{scope}/" in line for line in names)
+
+
+@pytest.mark.parametrize("scope", [
+    "mla/q_proj", "mla/q_absorb", "dsa/index_proj", "dsa/select",
+    "dsa/select/dsa/index_scores", "dsa/select/dsa/top_k",
+    "mla/sparse_decode_attn", "mla/window_decode_attn", "mla/head_gate",
+    "kv_write"])
+def test_the_selecting_familys_decode_scopes(selecting_engine, scope):
+    """A device trace tells the indexer's projections, its score pass,
+    the top-k, the attention over the rows kept, the ring attention and
+    the gates apart by these names (benchmarks/metrics reads the kernels
+    by their jitted functions' names, the rest by these)."""
+    assert _scoped(_lowered(selecting_engine, "decode"), scope), scope
+
+
+@pytest.mark.parametrize("which,scope", [
+    ("prefill", "dsa/index_proj"), ("chunk", "dsa/index_proj"),
+    ("chunk", "dsa/select"), ("chunk", "mla/chunk_attn_kept"),
+    ("chunk", "mla/window_chunk"), ("prefill", "mla/prefill_attn"),
+    ("prefill", "mla/head_gate")])
+def test_the_selecting_familys_prompt_scopes(selecting_engine, which, scope):
+    assert _scoped(_lowered(selecting_engine, which), scope), scope
+
+
+def test_a_bucket_that_cannot_pass_index_topk_computes_no_score(
+        selecting_engine):
+    """The 8-token prefill of a model that keeps 16 rows: the keys are
+    made and cached, no score and no top-k is in the program; the
+    32-token one selects."""
+    i32 = jnp.int32
+    eng = selecting_engine
+
+    def prefill(bucket):
+        return eng._prefill_jit.lower(
+            eng.cache, eng.params, jnp.zeros((1, bucket), i32), i32(5),
+            i32(0), jnp.float32(0.0), i32(0), eng._key, i32(0), i32(0),
+            None).as_text(debug_info=True)
+
+    assert not _scoped(prefill(8), "dsa/select")
+    assert _scoped(prefill(8), "dsa/index_proj")
+    assert _scoped(prefill(32), "dsa/select")
