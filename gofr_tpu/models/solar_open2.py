@@ -36,7 +36,7 @@ scanned a PERIOD at a time, so compile time stays flat in depth; the
 stacks stay whole beside the scan and a layer's weights are indexed where
 they are used. What a
 padded position or an idle slot may do to a state is nothing: prefill
-makes positions at or past a row's length the identity (``alpha = 1``,
+makes positions at or past a row's length the identity (``g = 0``,
 ``beta = 0``, the convolution's tail taken at the last valid input) and
 the decode kernel does not touch a slot that is not active.
 """
@@ -114,10 +114,17 @@ def state_bytes_per_slot(cfg: ModelConfig) -> int:
 def serving_stats(cfg: ModelConfig, slots: int) -> dict:
     """What ``GenerationEngine.stats()`` says of this family: the decode
     step's expert dispatch shapes and path (``moe.serving_stats``),
-    the bytes a slot's state takes and those a cached token takes in
-    the model's type (benchmarks/metrics reads them here)."""
+    which way a bucket or a chunk of whole sub-blocks goes through the
+    delta rule (``kda.prefill_path``: ``chunkwise`` is the kernel,
+    ``recurrence`` the jnp form a token at a time, which any other
+    number of tokens takes too), the bytes a slot's state takes and
+    those a cached token takes in the model's type (benchmarks/metrics
+    reads them here)."""
     P, nf, _ = counts(cfg)
+    d = cfg.linear_head_dim
     return {**moe.serving_stats(cfg, slots),
+            "kda_prefill": {"path": kda.prefill_path(d, d, cfg.linear_heads),
+                            "sub_block": kda.SUB_BLOCK},
             "state_bytes_per_slot": state_bytes_per_slot(cfg),
             "kv_bytes_per_token": P * nf * 2 * cfg.n_kv_heads
             * cfg.head_dim * cfg.jdtype.itemsize}
@@ -210,11 +217,14 @@ def _l2(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + _L2_EPS)
 
 
-def _linear_inputs(h, lw, cfg: ModelConfig, tail, lengths):
+def _linear_inputs(h, lw, cfg: ModelConfig, tail, lengths, log_decay):
     """The recurrence's inputs from the normed stream h [B, S, D] and the
-    convolutions' tail [B, W - 1, C]: (q scaled, k, v, alpha, beta) in
-    float32 a head, and the tail after the last valid input. Positions
-    at or past ``lengths`` [B] (None: none) come out as the identity."""
+    convolutions' tail [B, W - 1, C]: (q scaled, k, v, decay, beta) in
+    float32 a head, and the tail after the last valid input. The decay
+    is ``g = log alpha`` where ``log_decay`` (what the prefill kernel
+    takes: ``log(exp(g))`` would lose a channel that underflowed), else
+    ``alpha``. Positions at or past ``lengths`` [B] (None: none) come
+    out as the identity: ``g = 0``, ``beta = 0``."""
     B, S = h.shape[:2]
     H, d = cfg.linear_heads, cfg.linear_head_dim
     with jax.named_scope("kda/qkv"):
@@ -229,13 +239,14 @@ def _linear_inputs(h, lw, cfg: ModelConfig, tail, lengths):
             + lw["a_bias"]
         g = -jnp.exp(lw["a_log"])[:, None] * jax.nn.softplus(
             a.reshape(B, S, H, d))
-        alpha = jnp.exp(g)
+        decay = g if log_decay else jnp.exp(g)
         beta = 2.0 * jax.nn.sigmoid(qmatmul(h, lw["w_beta"]).astype(F32))
         if lengths is not None:
             valid = (jnp.arange(S)[None, :] < lengths[:, None])
-            alpha = jnp.where(valid[..., None, None], alpha, 1.0)
+            decay = jnp.where(valid[..., None, None], decay,
+                              0.0 if log_decay else 1.0)
             beta = jnp.where(valid[..., None], beta, 0.0)
-    return (q, k, v, alpha, beta), tail
+    return (q, k, v, decay, beta), tail
 
 
 def _linear_out(x, h, o, lw, cfg: ModelConfig):
@@ -321,7 +332,8 @@ def _prefill(params, cfg: ModelConfig, tokens, lengths, state, conv,
     def linear_layer(x, lw, i, extra, carry):
         s0, tail = extra
         h = rms_norm(x, lw["attn_norm"], cfg.norm_eps)
-        inputs, tail = _linear_inputs(h, lw, cfg, tail, lengths)
+        inputs, tail = _linear_inputs(h, lw, cfg, tail, lengths,
+                                       log_decay=True)
         o, s1 = kda.prefill_auto(*inputs, s0)
         x, _ = _ffn(x + _linear_out(x, h, o, lw, cfg), lw, cfg, moe_valid)
         return x, (s1, tail), carry
@@ -435,7 +447,8 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     def linear_layer(x, lw, i, extra, state):
         h = rms_norm(x, lw["attn_norm"], cfg.norm_eps)
         tail = jax.lax.dynamic_index_in_dim(cache.conv, i, 0, keepdims=False)
-        (q, k, v, alpha, beta), tail = _linear_inputs(h, lw, cfg, tail, None)
+        (q, k, v, alpha, beta), tail = _linear_inputs(
+            h, lw, cfg, tail, None, log_decay=False)
         o, state = kda.decode_auto(state, i, q[:, 0], k[:, 0], v[:, 0],
                                    alpha[:, 0], beta[:, 0], act)
         x, n = _ffn(x + _linear_out(x, h, o[:, None], lw, cfg), lw, cfg,

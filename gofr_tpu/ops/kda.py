@@ -10,9 +10,9 @@ One head keeps ``S`` [dk, dv] float32. A token brings a query and a key
     o_t = S_t^T q_t
 
 which is ``S_t = (I - beta k k^T) Diag(alpha) S_{t-1} + beta k v^T``
-multiplied out. Nothing here is approximated or reordered across tokens:
-a position with ``alpha = 1`` and ``beta = 0`` is the identity, which is
-how the callers mask padding.
+multiplied out. Nothing here is approximated: a position with
+``alpha = 1`` and ``beta = 0`` is the identity, which is how the callers
+mask padding.
 
 ``kda_decode`` (scope ``kda/decode``; a device trace names a kernel after
 its jitted function, and the benchmark finds these two by those names):
@@ -26,18 +26,40 @@ stays bit for bit what it was (a slot whose prompt is half-way through
 its chunks must not see a decode step).
 
 ``kda_prefill`` (scope ``kda/prefill``): the same recurrence over a bucket
-or a chunk from the slot's state, token by token with the state held in
-VMEM: it touches HBM once a chunk. The chunkwise form (sub-chunks solved
-as triangular systems and applied with matmuls) would put the work on
-the matrix unit; with a decay a channel it needs exp(g_t - g_i) a pair
-of tokens a channel and is left as the next step (PERF.md section 7).
+or a chunk of a prompt from the slot's state, CHUNK BY CHUNK with
+matmuls, the state held in VMEM from chunk to chunk (it touches HBM once
+a call). It takes the LOG-decay ``g = log alpha`` (<= 0; padding is
+``g = 0``, ``beta = 0``). With ``G`` the running sum of g inside a chunk
+of C tokens, ``kb = beta k`` and ``u_t = v_t - (Diag(alpha_t) S_{t-1})^T
+k_t``:
+
+    (I + tril(A, -1)) U = V - (K e^G) S_0     A[t,i] = sum_c k_t kb_i e^(G_t - G_i)
+    O   = (Q e^G) S_0 + tril(P) U              P[t,i] = sum_c q_t kb_i e^(G_t - G_i)
+    S_C = Diag(e^(G_C)) S_0 + (Kb e^(G_C - G))^T U
+
+Every exponent is of a sum of log-decays of one sign, never of a
+difference of two running sums: ``e^(-G_i)`` alone overflows float32
+once a channel has decayed by e^88 inside a chunk. Inside a sub-block
+of 16 tokens the pairs (t, i) take their ``e^(G_t - G_i)`` a distance
+``t - i`` at a time on the vector unit (the exponent grows by one
+token's g a distance); between sub-blocks the product is split at the
+later block's first row into two factors <= 1 and goes to the matrix
+unit; the unit-lower-triangular system is solved by forward
+substitution, sub-block by sub-block. Every dot that feeds the state or
+the output is float32 (``Precision.HIGHEST``); nothing is approximated,
+clamped or dropped, and float32 rounding in another order is the only
+difference from ``recurrent_ref``: fewer roundings, in fact, since a
+chunk takes one ``exp`` of a sum where the recurrence multiplies 128
+decays (on the chip, whose ``exp`` is good to 5e-6, the kernel is 25
+times nearer the float64 recurrence than the jnp one: PERF.md).
 
 The state's rows are the key's channels (sublanes) and its lanes the
-value's, so both reductions over the key run down the sublanes (vector
-adds) and the value and the output are rows as the projections give
-them; alpha, k, beta k and q are needed as columns and are transposed
-in the kernel a few tokens (prefill) or a block of heads (decode) at a
-time.
+value's, so the decode kernel's reductions over the key run down the
+sublanes (vector adds) and the value and the output are rows as the
+projections give them; its alpha, k, beta k and q are needed as columns
+and are transposed in the kernel a block of heads at a time. The
+prefill kernel's tokens are rows [T, dk] a head, as the matmuls want
+them.
 """
 
 from __future__ import annotations
@@ -50,11 +72,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
-_GROUP = 8           # tokens transposed together in the prefill kernel
+SUB_BLOCK = 16            # tokens of a prefill sub-block
+_CHUNKS = (128, 64, 32, 16)   # tokens of a prefill chunk: the most that divide T
 _DECODE_HEADS = 16   # heads a decode work item holds: 1 MB of state
-_PREFILL_HEADS = 4   # heads a prefill grid step holds
+_PREFILL_HEADS = 2   # heads a prefill grid step holds
 _VMEM = 64 * 1024 * 1024
 F32 = jnp.float32
+_HI = jax.lax.Precision.HIGHEST
 
 
 def kernel_ok(dk: int, dv: int, heads: int) -> bool:
@@ -84,14 +108,12 @@ def recurrent_ref(q, k, v, alpha, beta, state):
     kernels and the path where they do not run. q, k, alpha [B, T, H, dk];
     v [B, T, H, dv]; beta [B, T, H]; state [B, H, dk, dv]. Returns
     (o [B, T, H, dv] float32, state after the last token)."""
-    hi = jax.lax.Precision.HIGHEST
-
     def step(S, xs):
         q_t, k_t, v_t, a_t, b_t = xs
         S = S * a_t[..., None]
-        u = jnp.einsum("bhk,bhkv->bhv", k_t, S, precision=hi)
+        u = jnp.einsum("bhk,bhkv->bhv", k_t, S, precision=_HI)
         S = S + (k_t * b_t[..., None])[..., None] * (v_t - u)[:, :, None, :]
-        return S, jnp.einsum("bhk,bhkv->bhv", q_t, S, precision=hi)
+        return S, jnp.einsum("bhk,bhkv->bhv", q_t, S, precision=_HI)
 
     xs = tuple(jnp.moveaxis(x.astype(F32), 1, 0)
                for x in (q, k, v, alpha, beta))
@@ -204,44 +226,166 @@ def decode_auto(state, layer, q, k, v, alpha, beta, active):
 
 # -- prefill ------------------------------------------------------------------
 
-def _prefill_kernel(a_ref, k_ref, kb_ref, q_ref, v_ref, s_in, o_ref, s_out,
-                    *, heads: int, groups: int):
-    for h in range(heads):
-        def group(g, S):
-            at = pl.ds(pl.multiple_of(g * _GROUP, _GROUP), _GROUP)
-            # [8 tokens, dk] -> columns [dk, 8]
-            a, k, kb, q = (r[0, h, at, :].T
-                           for r in (a_ref, k_ref, kb_ref, q_ref))
-            v = v_ref[0, h, at, :]
-            rows = []
-            for t in range(_GROUP):
-                S, o = _token(S, a[:, t:t + 1], k[:, t:t + 1],
-                              kb[:, t:t + 1], q[:, t:t + 1], v[t:t + 1])
-                rows.append(o)
-            o_ref[0, h, at, :] = jnp.concatenate(rows, axis=0)
-            return S
+def chunk_tokens(T: int) -> int:
+    """Tokens of a prefill chunk: the most of ``_CHUNKS`` that T is whole
+    chunks of (0: T is not whole sub-blocks)."""
+    return next((c for c in _CHUNKS if T % c == 0), 0)
 
-        s_out[0, h] = jax.lax.fori_loop(0, groups, group, s_in[0, h])
+
+def _dot(a, b, contract=(1, 0)):
+    """The float32 product of two matrices over ``a``'s axis
+    ``contract[0]`` and ``b``'s ``contract[1]``: ``a b`` as it stands,
+    ``a b^T`` with (1, 1), ``a^T b`` with (0, 0)."""
+    return jax.lax.dot_general(
+        a, b, (((contract[0],), (contract[1],)), ((), ())),
+        preferred_element_type=F32, precision=_HI)
+
+
+def _column(row):
+    """A row [1, N] as a column [N, 1]."""
+    return jnp.broadcast_to(row, (8, row.shape[1])).T[:, 0:1]
+
+
+def _chunk_masks(C: int, dk: int):
+    """What every chunk of C tokens shares. ``scans`` [2 C, C], 0/1: its
+    product with a chunk's log-decays [C, dk] is their running sums
+    inside a sub-block, up to and with row t and after row t. ``back``
+    [C, C]: how far row t lies behind column i where both are of one
+    sub-block, -1 elsewhere. ``col`` [16, C] and ``token`` [C, dk]: the
+    column and the row."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    same = (row // SUB_BLOCK) == (col // SUB_BLOCK)
+    scans = jnp.concatenate([(same & m).astype(jnp.bfloat16)
+                             for m in (col <= row, col > row)])
+    return (scans, jnp.where(same, row - col, -1),
+            jax.lax.broadcasted_iota(jnp.int32, (SUB_BLOCK, C), 1),
+            jax.lax.broadcasted_iota(jnp.int32, (C, dk), 0))
+
+
+def _sums(scans, g):
+    """``scans @ g`` in float32 from three bfloat16 passes: g is split
+    into three bfloat16 parts that add up to it, and a 0/1 matrix is
+    exact in bfloat16, so every product is exact and the sums are
+    float32's."""
+    parts, rest = [], g
+    for _ in range(3):
+        parts.append(rest.astype(jnp.bfloat16))
+        rest = rest - parts[-1].astype(F32)
+    return sum(jnp.dot(scans, p, preferred_element_type=F32) for p in parts)
+
+
+def _chunk(q, k, kb, g, v, S, scans, back, col, token):
+    """One chunk of one head from the state S [dk, dv] at its start:
+    q (scaled), k, kb = beta k and the log-decay g [C, dk]; v [C, dv].
+    Returns (o [C, dv], the state after the chunk's last token).
+
+    With G the running sum of g, the rows u_t = v_t - (Diag(alpha_t)
+    S_(t-1))^T k_t solve ``(I + tril(A, -1)) U = V - (K e^G) S`` where
+    ``A[t, i] = sum_c k_t[c] kb_i[c] e^(G_t[c] - G_i[c])``; then
+    ``O = (Q e^G) S + tril(P) U`` with q_t in k_t's place in P, and the
+    state is ``Diag(e^(G_C)) S + (Kb e^(G_C - G))^T U``. No exponent is
+    a difference of two running sums (a channel that decays by e^-88
+    inside a chunk would overflow float32 in e^(-G_i), and a difference
+    of two long sums has lost the short one's digits): inside a
+    sub-block of 16 it is summed a distance at a time, a pair of tokens
+    a channel on the vector unit; across sub-blocks it is split at the
+    later block's first row into two sums that are both <= 0, and the
+    product goes to the matrix unit."""
+    C, n = k.shape[0], SUB_BLOCK
+    blocks = [slice(b * n, (b + 1) * n) for b in range(C // n)]
+    sums = _sums(scans, g)
+    pre, post = sums[:C], sums[C:]     # inside the sub-block: to t, after t
+    total = [pre[r.stop - 1:r.stop] for r in blocks]
+    # from the chunk's start to row t, and after row t to its end
+    run = jnp.concatenate([pre[r] + sum(total[:b])
+                           for b, r in enumerate(blocks)])
+    left = jnp.concatenate([post[r] + sum(total[b + 1:])
+                            for b, r in enumerate(blocks)])
+
+    # the diagonal sub-blocks, a distance d = t - i at a time
+    A = jnp.zeros((C, C), F32)
+    P = jnp.where(back == 0, jnp.sum(q * kb, axis=1, keepdims=True), 0.0)
+    span, g_d, kb_d = jnp.zeros_like(g), g, kb
+    for d in range(1, n):
+        span = span + g_d              # g_t + ... + g_(t - d + 1)
+        g_d, kb_d = (pltpu.roll(x, 1, 0) for x in (g_d, kb_d))
+        m = kb_d * jnp.exp(span)       # row t: token t - d, decayed to t
+        hit = back == d                # never a row that wrapped
+        A = jnp.where(hit, jnp.sum(k * m, axis=1, keepdims=True), A)
+        P = jnp.where(hit, jnp.sum(q * m, axis=1, keepdims=True), P)
+
+    e_run = jnp.exp(run)
+    from_s = _dot(jnp.concatenate([k * e_run, q * e_run]), S)
+    rhs = v - from_s[:C]
+    us, p_rows, reach = [], [], post
+    for b, r in enumerate(blocks):
+        u, p = rhs[r], P[r]
+        if b:
+            # the keys before this sub-block decayed up to the row before
+            # its first (rows from there on hold what is masked out), and
+            # its own rows from there on
+            if b > 1:
+                reach = jnp.where(token < (b - 1) * n,
+                                  reach + total[b - 1], post)
+            e = jnp.exp(pre[r])
+            ap = _dot(jnp.concatenate([k[r] * e, q[r] * e]),
+                      kb * jnp.exp(reach), (1, 1))
+            u = u - _dot(ap[:n, :b * n], jnp.concatenate(us))
+            p = jnp.where(col < b * n, ap[n:], p)
+        # forward substitution: row i is final once the rows above are
+        # taken out of it, and is then taken out of the rows below
+        Ab = A[r, r]
+        for i in range(n - 1):
+            u = u - Ab[:, i:i + 1] * u[i:i + 1]
+        us.append(u)
+        p_rows.append(p)
+    U = jnp.concatenate(us)
+    S = S * jnp.exp(_column(run[C - 1:C])) + _dot(kb * jnp.exp(left), U, (0, 0))
+    return from_s[C:] + _dot(jnp.concatenate(p_rows), U), S
+
+
+def _prefill_kernel(q_ref, k_ref, kb_ref, g_ref, v_ref, s_in, o_ref, s_out,
+                    *, heads: int, chunk: int):
+    masks = _chunk_masks(chunk, q_ref.shape[3])
+    s_out[...] = s_in[...]
+
+    def step(c, carry):
+        at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        # the heads' chains are independent: side by side in one body
+        for h in range(heads):
+            o, S = _chunk(*(r[0, h, at, :] for r in (
+                q_ref, k_ref, kb_ref, g_ref, v_ref)), s_out[0, h], *masks)
+            o_ref[0, h, at, :] = o
+            s_out[0, h] = S
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[2] // chunk, step, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def kda_prefill(q, k, v, alpha, beta, state, *, interpret: bool = False):
-    """The recurrence over T tokens from ``state``. q (scaled), k, alpha
-    [B, T, H, dk]; v [B, T, H, dv]; beta [B, T, H]; state [B, H, dk, dv]
-    float32; T a multiple of 8. Returns (o [B, T, H, dv] float32, the
-    state after token T - 1)."""
+def kda_prefill(q, k, v, g, beta, state, *, interpret: bool = False):
+    """The recurrence over T tokens from ``state``, chunk by chunk. q
+    (scaled), k and the LOG-decay g = log alpha (<= 0) [B, T, H, dk];
+    v [B, T, H, dv]; beta [B, T, H]; state [B, H, dk, dv] float32; T
+    whole sub-blocks of 16. Returns (o [B, T, H, dv] float32, the state
+    after token T - 1)."""
     B, T, H, dk = q.shape
     dv = v.shape[-1]
     hb = min(_PREFILL_HEADS, H)
+    chunk = chunk_tokens(T)
+    if not chunk or H % hb:
+        raise ValueError(f"{T} tokens of {H} heads are not whole "
+                         f"sub-blocks of {SUB_BLOCK} and groups of {hb} heads")
     kb = k * beta[..., None]
     # a head's tokens contiguous: [B, H, T, d]
-    a_, k_, kb_, q_, v_ = (jnp.swapaxes(x.astype(F32), 1, 2)
-                           for x in (alpha, k, kb, q, v))
+    q_, k_, kb_, g_, v_ = (jnp.swapaxes(x.astype(F32), 1, 2)
+                           for x in (q, k, kb, g, v))
     seq = pl.BlockSpec((1, hb, T, dk), lambda b, j: (b, j, 0, 0))
     seq_v = pl.BlockSpec((1, hb, T, dv), lambda b, j: (b, j, 0, 0))
     blk = pl.BlockSpec((1, hb, dk, dv), lambda b, j: (b, j, 0, 0))
     o, state = pl.pallas_call(
-        functools.partial(_prefill_kernel, heads=hb, groups=T // _GROUP),
+        functools.partial(_prefill_kernel, heads=hb, chunk=chunk),
         grid=(B, H // hb),
         in_specs=[seq, seq, seq, seq, seq_v, blk],
         out_specs=[seq_v, blk],
@@ -251,22 +395,29 @@ def kda_prefill(q, k, v, alpha, beta, state, *, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_VMEM),
-    )(a_, k_, kb_, q_, v_, state.astype(F32))
+    )(q_, k_, kb_, g_, v_, state.astype(F32))
     return jnp.swapaxes(o, 1, 2), state
 
 
+def prefill_path(dk: int, dv: int, heads: int, tokens: int = SUB_BLOCK) -> str:
+    """Which way ``prefill_auto`` takes ``tokens`` tokens: ``chunkwise``
+    (the kernel) where ``kernel_ok`` and they are whole sub-blocks,
+    else ``recurrence`` (jnp, a token at a time)."""
+    return "chunkwise" if kernel_ok(dk, dv, heads) and chunk_tokens(tokens) \
+        and heads % min(_PREFILL_HEADS, heads) == 0 else "recurrence"
+
+
 @jax.named_scope("kda/prefill")
-def prefill_auto(q, k, v, alpha, beta, state):
-    """``kda_prefill`` where ``kernel_ok`` and the tokens are whole groups,
-    the jnp recurrence elsewhere."""
+def prefill_auto(q, k, v, g, beta, state):
+    """``kda_prefill`` where ``prefill_path`` says so, the jnp recurrence
+    on ``alpha = exp(g)`` elsewhere."""
     from .flash import interpret_env
 
-    H, dk, dv = q.shape[2], q.shape[3], v.shape[3]
-    if kernel_ok(dk, dv, H) and q.shape[1] % _GROUP == 0 \
-            and H % min(_PREFILL_HEADS, H) == 0:
-        return kda_prefill(q, k, v, alpha, beta, state,
+    if prefill_path(q.shape[3], v.shape[3], q.shape[2],
+                    q.shape[1]) == "chunkwise":
+        return kda_prefill(q, k, v, g, beta, state,
                            interpret=interpret_env())
-    return recurrent_ref(q, k, v, alpha, beta, state)
+    return recurrent_ref(q, k, v, jnp.exp(g), beta, state)
 
 
 # -- the short convolution ----------------------------------------------------

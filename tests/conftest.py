@@ -115,6 +115,24 @@ def pytest_runtest_protocol(item, nextitem):
         faulthandler.cancel_dump_traceback_later()
 
 
+def _memory_maps() -> tuple[int, int]:
+    """(memory maps this process holds, the most it may)."""
+    try:
+        with open("/proc/self/maps") as f:
+            n_maps = sum(1 for _ in f)
+        with open("/proc/sys/vm/max_map_count") as f:
+            return n_maps, int(f.read())
+    except OSError:
+        return 0, 1 << 31
+
+
+def _engine_threads_alive() -> bool:
+    import threading
+
+    return any(t.name == "gofr-tpu-gen" and t.is_alive()
+               for t in threading.enumerate())
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _release_compiled_executables_between_modules():
     """Cap the process's memory-map count. Every compiled XLA executable
@@ -129,7 +147,6 @@ def _release_compiled_executables_between_modules():
     module anyway."""
     yield
     import gc
-    import threading
     import time
 
     # Clear ONLY under real map-count pressure: on boxes with an
@@ -138,16 +155,11 @@ def _release_compiled_executables_between_modules():
     # it segfaults nondeterministically inside weakref-cache clearing
     # after engine-heavy modules (observed reliably after test_paged,
     # test_examples). Where the cap is real (the 65530 box this guard
-    # was written for) the 50% threshold still fires long before mmap
-    # starts failing inside the compiler.
-    try:
-        with open("/proc/self/maps") as f:
-            n_maps = sum(1 for _ in f)
-        with open("/proc/sys/vm/max_map_count") as f:
-            cap = int(f.read())
-    except OSError:
-        n_maps, cap = 0, 1 << 31
-    if n_maps < 0.5 * cap:
+    # was written for) a third of it leaves an engine module (35,000
+    # maps a worker at the worst: PR 47 lost a worker twice in
+    # test_laguna with the threshold at half) room to its end.
+    n_maps, cap = _memory_maps()
+    if n_maps < cap // 3:
         return
 
     # A gofr-tpu-gen loop thread may still be winding down INSIDE a
@@ -156,13 +168,26 @@ def _release_compiled_executables_between_modules():
     # out from under that running dispatch — drain those threads first,
     # compile-sized bound, like pytest_sessionfinish below.
     deadline = time.monotonic() + 120.0
-    while time.monotonic() < deadline and any(
-            t.name == "gofr-tpu-gen" and t.is_alive()
-            for t in threading.enumerate()):
+    while time.monotonic() < deadline and _engine_threads_alive():
         time.sleep(0.2)
 
     jax.clear_caches()
     gc.collect()
+
+
+@pytest.fixture(autouse=True)
+def _release_compiled_executables_inside_a_module():
+    """The same guard after every test, at half the limit, for a module
+    whose own programs outgrow what the module boundary left it: only
+    while no engine's loop thread lives (a module-scoped engine holds
+    its programs until the module ends, and may be inside a dispatch)."""
+    yield
+    n_maps, cap = _memory_maps()
+    if n_maps >= cap // 2 and not _engine_threads_alive():
+        import gc
+
+        jax.clear_caches()
+        gc.collect()
 
 
 def pytest_sessionfinish(session, exitstatus):

@@ -155,19 +155,29 @@ def test_kda_decode_compiles_in_place(one_chip):
     assert mem.temp_size_in_bytes < state_bytes // 64
 
 
-@pytest.mark.parametrize("tokens", [32, 512])
+@pytest.mark.parametrize("tokens", [32, 64, 128, 256, 512])
 def test_kda_prefill_compiles(one_chip, tokens):
-    """The smallest bucket and a whole chunk of one prompt."""
+    """Every engine bucket, the smallest to a whole chunk of one prompt:
+    the chunkwise kernel is in the program and fits the VMEM it asks
+    for."""
+    import re
+
     from gofr_tpu.ops import kda
 
     def arr(shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
     seq = arr((1, tokens, KDA_H, D))
-    compiled = kda.kda_prefill.lower(
+    text = kda.kda_prefill.lower(
         seq, seq, seq, seq, arr((1, tokens, KDA_H)),
-        arr((1, KDA_H, D, D))).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        arr((1, KDA_H, D, D))).compile().as_text()
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line and "kda_prefill" in line)
+    asked, used = (int(re.search(
+        key + r'":\[\{"memory_space":"1","offset":"0","size":"(\d+)"',
+        call).group(1))
+        for key in ("scoped_memory_configs", "used_scoped_memory_configs"))
+    assert 0 < used < asked == 64 * 1024 * 1024
 
 
 # -- the llama family's programs: every projection reads its stack in place ----

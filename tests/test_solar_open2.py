@@ -175,19 +175,105 @@ def _recurrence_inputs(B, T, H, dk, dv, seed=0):
 
 @pytest.mark.parametrize("T", [8, 40])
 def test_the_prefill_kernel_equals_the_token_recurrence(T):
-    q, k, v, a, b, s0 = _recurrence_inputs(2, T, 4, 16, 24)
+    """The kernel on T + 8 tokens (whole sub-blocks of 16: one chunk of
+    16, three of 16) and ``prefill_auto`` on T (no whole sub-blocks: the
+    jnp recurrence must still take them), from ``g = log alpha``."""
+    q, k, v, a, b, s0 = _recurrence_inputs(2, T + 8, 4, 16, 24)
+    g = jnp.log(a)
     o_ref, s_ref = kda.recurrent_ref(q, k, v, a, b, s0)
-    o, s = kda.kda_prefill(q, k, v, a, b, s0, interpret=True)
+    o, s = kda.kda_prefill(q, k, v, g, b, s0, interpret=True)
     assert np.abs(np.asarray(o - o_ref)).max() < KERNEL_TOL
     assert np.abs(np.asarray(s - s_ref)).max() < KERNEL_TOL
-    # masked positions are the identity: alpha 1, beta 0
-    live = jnp.arange(T) < T - 3
-    a_m = jnp.where(live[None, :, None, None], a, 1.0)
+    # masked positions are the identity: g 0, beta 0
+    live = jnp.arange(T + 8) < T + 5
+    g_m = jnp.where(live[None, :, None, None], g, 0.0)
     b_m = jnp.where(live[None, :, None], b, 0.0)
-    _, s_m = kda.kda_prefill(q, k, v, a_m, b_m, s0, interpret=True)
-    _, s_cut = kda.recurrent_ref(q[:, :T - 3], k[:, :T - 3], v[:, :T - 3],
-                                 a[:, :T - 3], b[:, :T - 3], s0)
+    _, s_m = kda.kda_prefill(q, k, v, g_m, b_m, s0, interpret=True)
+    _, s_cut = kda.recurrent_ref(q[:, :T + 5], k[:, :T + 5], v[:, :T + 5],
+                                 a[:, :T + 5], b[:, :T + 5], s0)
     assert np.abs(np.asarray(s_m - s_cut)).max() < KERNEL_TOL
+    assert kda.prefill_path(16, 24, 4, T) == "recurrence"
+    o_t, s_t = kda.prefill_auto(q[:, :T], k[:, :T], v[:, :T], g[:, :T],
+                                b[:, :T], s0)
+    o_r, s_r = kda.recurrent_ref(q[:, :T], k[:, :T], v[:, :T], a[:, :T],
+                                 b[:, :T], s0)
+    assert np.abs(np.asarray(o_t - o_r)).max() < KERNEL_TOL
+    assert np.abs(np.asarray(s_t - s_r)).max() < KERNEL_TOL
+
+
+def _recurrence_f64(q, k, v, g, beta, s0):
+    """The recurrence in float64 numpy: one row's tokens [T, H, ...]."""
+    q, k, v, g, beta, S = (np.asarray(x, np.float64)
+                           for x in (q, k, v, g, beta, s0))
+    o = np.zeros(v.shape)
+    for t in range(q.shape[0]):
+        S = S * np.exp(g[t])[..., None]
+        u = v[t] - np.einsum("hk,hkv->hv", k[t], S)
+        S = S + (k[t] * beta[t][:, None])[..., None] * u[:, None, :]
+        o[t] = np.einsum("hk,hkv->hv", q[t], S)
+    return o, S
+
+
+def _stress_inputs(T, decay, step, H=2, dk=16, dv=24):
+    """Two rows of T - 3 and T // 2 + 1 tokens in a bucket of T, the
+    padding masked as the model masks it; a state that is not empty."""
+    q, k, v, _, _, s0 = _recurrence_inputs(2, T, H, dk, dv, seed=T)
+    ks = jax.random.split(jax.random.PRNGKey(T + 1), 4)
+    u = jax.random.uniform(ks[0], (2, T, H, dk))
+    if decay == "mild":
+        g = -0.02 * u
+    elif decay == "strong":
+        # down to e^-30 a token: a channel is past float32's smallest
+        # number three tokens into a sub-block
+        g = -30.0 * u
+    else:
+        # a channel: strong for every token, or mild but for a token in
+        # three, so that short sums stand beside long ones
+        always = jax.random.bernoulli(ks[1], 0.5, (1, 1, H, dk))
+        now = jax.random.bernoulli(ks[2], 0.3, (2, T, H, 1))
+        g = jnp.where(always | now, -30.0, -0.02) * u
+    beta = {"zero": jnp.zeros((2, T, H)),
+            "near2": 2.0 - 1e-3 * jax.random.uniform(ks[3], (2, T, H)),
+            "random": 2 * jax.nn.sigmoid(jax.random.normal(ks[3], (2, T, H)))
+            }[step]
+    lengths = np.asarray([T - 3, T // 2 + 1])
+    live = jnp.arange(T)[None, :] < lengths[:, None]
+    return (q, k, v, jnp.where(live[..., None, None], g, 0.0),
+            jnp.where(live[..., None], beta, 0.0), s0), lengths
+
+
+@pytest.mark.parametrize("step", ["zero", "near2", "random"])
+@pytest.mark.parametrize("decay", ["mild", "strong", "mixed"])
+@pytest.mark.parametrize("T", [32, 64, 256, 512])
+def test_the_chunkwise_kernel_is_the_recurrence(T, decay, step):
+    """The kernel, interpreted, against the recurrence in float64: no
+    farther from it than four times what the float32 jnp recurrence is
+    (a few ulp of the largest value where that one is exact), nothing
+    NaN or inf, a padded row's state the recurrence cut at its length;
+    and 512 tokens in one call are 256 + 256 through the state (what
+    ``_chunk_mid`` then ``_chunk_final`` do)."""
+    args, lengths = _stress_inputs(T, decay, step)
+    q, k, v, g, beta, s0 = args
+    o, s = (np.asarray(x) for x in kda.kda_prefill(*args, interpret=True))
+    assert np.isfinite(o).all() and np.isfinite(s).all()
+    o32, s32 = (np.asarray(x) for x in kda.recurrent_ref(
+        q, k, v, jnp.exp(g), beta, s0))
+    ulp = np.finfo(np.float32).eps
+    for row, n in enumerate(lengths):
+        o64, s64 = _recurrence_f64(*(x[row, :n] for x in args[:5]), s0[row])
+        for got, ref, want in ((o[row, :n], o32[row, :n], o64),
+                               (s[row], s32[row], s64)):
+            # a state that has all but decayed away (1e-18 of inputs of
+            # order 1) is held to 1e-12, not to its own last digits
+            floor = 4 * ulp * np.abs(want).max()
+            assert np.abs(got - want).max() \
+                <= 4 * max(np.abs(ref - want).max(), floor) + 1e-12
+    if T == 512:
+        half = [x[:, :256] for x in args[:5]], [x[:, 256:] for x in args[:5]]
+        o_a, s_a = kda.kda_prefill(*half[0], s0, interpret=True)
+        o_b, s_b = kda.kda_prefill(*half[1], s_a, interpret=True)
+        assert np.abs(np.concatenate([o_a, o_b], axis=1) - o).max() < 1e-6
+        assert np.abs(np.asarray(s_b) - s).max() < 1e-6
 
 
 @pytest.mark.parametrize("active", [
