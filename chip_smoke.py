@@ -136,6 +136,7 @@ def kernel_cases(interpret: bool = False):
     CPU; the smoke itself never sets it."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from gofr_tpu.ops import attention, flash, flash_decode, paged_attention
     from gofr_tpu.ops.quant import quantize_kv
@@ -185,20 +186,33 @@ def kernel_cases(interpret: bool = False):
         def run():
             # the step's write: a row a slot, all layers and KV heads, at
             # word, tile and cache edges, one slot at capacity (dropped);
-            # every byte of both caches against XLA's scatter
+            # every byte of both caches against XLA's scatter, and every
+            # bit of an int8 cache's two scale tables against the select
+            # over the whole table that the kernel's visit replaced
             pos = jnp.asarray([0, 1, 127, 128, 129, 500, smax - 1, smax],
                               jnp.int32)
             kc, vc, kr, vr = (rand(14, (2, b, KV, smax, D)),
                               rand(15, (2, b, KV, smax, D)),
                               rand(16, (2, b, KV, D)), rand(17, (2, b, KV, D)))
+            tables = new = ()
             if quant:
-                kc, vc, kr, vr = (quantize_kv(x)[0] for x in (kc, vc, kr, vr))
-            got = flash_decode.append_rows_stacked(kc, vc, kr, vr, pos,
-                                                   interpret=interpret)
+                (kc, ks), (vc, vs), (kr, ksr), (vr, vsr) = (
+                    quantize_kv(x) for x in (kc, vc, kr, vr))
+                tables, new = (ks, vs), (ksr, vsr)
+            got = flash_decode.append_rows_stacked(
+                kc, vc, kr, vr, pos, *tables, *new, interpret=interpret)
             slots = jnp.arange(b)
-            return max(_max_err(g, c.at[:, slots, :, pos].set(
+            err = max(_max_err(g, c.at[:, slots, :, pos].set(
                 jnp.moveaxis(r, 1, 0), mode="drop"))
                 for g, c, r in zip(got, (kc, vc), (kr, vr)))
+            here = (jnp.arange(smax)[None, :] == pos[:, None])[None, :, None]
+            for g, table, r in zip(got[2:], tables, new):
+                want = jnp.where(here, r[..., None], table)
+                bits = [np.asarray(x).view(np.int32).astype(np.int64)
+                        for x in (g, want)]
+                assert (np.asarray(want) != np.asarray(table)).any()
+                err = max(err, float(np.abs(bits[0] - bits[1]).max()))
+            return err
         return run
 
     def paged(w):

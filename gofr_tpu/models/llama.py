@@ -909,19 +909,23 @@ def write_rows(cache: KVCache, k_rows, v_rows, positions, lengths,
                 n_heads: int, mesh=None) -> KVCache:
     """The rows a step made, [L, B, W, KV, hd] for all layers (W = 1: a
     decode step; a verify window's W), to cache[:, b, :, positions[b, j]],
-    quantized on the way for int8 caches; the cache with ``lengths``
-    replaced. A position at or past capacity is dropped.
+    quantized on the way for int8 caches, their scales [L, B, W, KV] to
+    the scale tables at the same places; the cache with ``lengths``
+    replaced. A position at or past capacity is dropped, row and scales.
 
     A position is one row of a KV head's (Smax, hd) tiles, and XLA writes
     it only through a copy of the whole cache to a layout with that axis
     major and one back (ops.flash_decode.append_rows_stacked, which is
     the write wherever ``kernel_block`` answers: in place, the tiles
-    around each cursor read, merged and written). Elsewhere (the CPU, a
-    shape the kernels refuse) it is one scatter. The scales [L, B, W, KV]
-    go by a select over the scale arrays on every path: a scatter's
-    window is not theirs either (two layout copies of 0.6 ms each a
-    step at 32 x 40 x 2,048 x 8, where the select takes 0.5; PERF.md,
-    Findings PR 25)."""
+    around each cursor read, merged and written, once a window
+    position). The scales go with the rows, through the same visit: the
+    lane tile of each table around the cursor, 21 MB a step at 32 x 40 x
+    8 x 2,048. Until PR 48 they went by a select over both whole tables
+    on every path, 336 MB and 0.5 ms of a 13 ms step, which had been the
+    cheaper of two: a scatter's window is not theirs either, two layout
+    copies of 0.6 ms each (PERF.md, Findings PR 25 and PR 48). Elsewhere
+    (the CPU, a shape the kernels refuse) the rows are one scatter and
+    the scales that select."""
     from ..ops import flash_decode
 
     def where_scales(scale, rows):      # [L, B, KV, Smax] <- [L, B, W, KV]
@@ -931,25 +935,28 @@ def write_rows(cache: KVCache, k_rows, v_rows, positions, lengths,
             scale = jnp.where(here, rows[:, :, j, :, None], scale)
         return scale
 
-    scales = {}
+    sk = sv = None
     if cache.quantized:
         k_rows, sk = quantize_kv(k_rows)
         v_rows, sv = quantize_kv(v_rows)
-        scales = dict(k_scale=where_scales(cache.k_scale, sk),
-                      v_scale=where_scales(cache.v_scale, sv))
     k_rows = k_rows.astype(cache.k.dtype)
     v_rows = v_rows.astype(cache.v.dtype)
+    k, v, k_scale, v_scale = cache.k, cache.v, cache.k_scale, cache.v_scale
     if flash_decode.kernel_block(n_heads, cache.k, mesh):
-        k, v = cache.k, cache.v
         for j in range(positions.shape[1]):
-            k, v = flash_decode.append_rows(
+            scales = (sk[:, :, j], sv[:, :, j]) if cache.quantized else ()
+            k, v, k_scale, v_scale = flash_decode.append_rows(
                 k, v, k_rows[:, :, j], v_rows[:, :, j], positions[:, j],
-                n_heads=n_heads, mesh=mesh)
+                k_scale, v_scale, *scales, n_heads=n_heads, mesh=mesh)
     else:
         # advanced indices around a slice: the result leads with [B, W]
         b_idx = jnp.arange(positions.shape[0])[:, None]
-        k = cache.k.at[:, b_idx, :, positions].set(
+        k = k.at[:, b_idx, :, positions].set(
             jnp.transpose(k_rows, (1, 2, 0, 3, 4)), mode="drop")
-        v = cache.v.at[:, b_idx, :, positions].set(
+        v = v.at[:, b_idx, :, positions].set(
             jnp.transpose(v_rows, (1, 2, 0, 3, 4)), mode="drop")
-    return KVCache(k=k, v=v, lengths=lengths, **scales)
+        if cache.quantized:
+            k_scale = where_scales(k_scale, sk)
+            v_scale = where_scales(v_scale, sv)
+    return KVCache(k=k, v=v, lengths=lengths, k_scale=k_scale,
+                   v_scale=v_scale)

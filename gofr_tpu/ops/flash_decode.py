@@ -50,6 +50,18 @@ still, 13.3 against 4.7 ms, PERF.md Findings PR 25). The staging buffer,
 the strided read and the refusal of fewer than four int8 KV heads a
 chip all went with the layout (PERF.md, Findings PR 31).
 
+The step's write (``append_rows``, below the attention): the row every
+layer and KV head made goes into the cache in place, a slot's tiles
+around its cursor fetched, merged and written back, and with it, since
+PR 48, an int8 cache's two scales: the slot's [L, KV, 128] lane tile of
+each float32 table [L, B, KV, Smax] rides the same three buffers, the
+step's scale put on the cursor's lane. Two readings led there: XLA's
+scatter into [.., KV, Smax] costs two layout copies of a table, 0.6 ms
+each a step at 32 x 40 x 8 x 2,048, and the select over both whole
+tables that replaced it 0.5 ms (336 MB to change 20,480 values; PERF.md,
+Findings PR 25 and PR 48). The select is still the write of the scales
+where the kernels do not run (llama.write_rows).
+
 Sharding: a pallas_call is opaque to the GSPMD partitioner, so on a mesh
 the kernels run under ``shard_map`` over the tp (and data) axes: every
 device walks its local [KV/tp] head shard of the stacked cache, no
@@ -417,22 +429,34 @@ def decode_attention_auto(q, cache_k, cache_v, k_new, v_new, lengths, layer,
 _APPEND_BUF = 3    # slot b+1 read and slot b-1 written while slot b merges
 
 
-def _append_kernel(pos_ref, keep_ref, kn_ref, vn_ref, k_in, v_in, k_hbm,
-                   v_hbm, kbuf, vbuf, sem, *, rows: int, per: int):
+def _append_kernel(pos_ref, keep_ref, *refs, rows: int, per: int):
     """Every slot: fetch the ``rows`` positions around its cursor, all
     layers and KV heads, put the new row's bits into its 32-bit words,
-    write the tiles back."""
-    del k_in, v_in                       # the outputs alias them
+    write the tiles back. ``refs``: what the step made, the tables it
+    goes into, the same as outputs, a buffer each, the semaphores; the
+    tables are K and V or, for a quantized cache, their two scale
+    tables too, whose tile, the lanes around the cursor, makes the same
+    trip: the step's scale goes to the cursor's lane."""
+    *refs, sem = refs
+    n = len(refs) // 4                   # tables: 2, or 4 with the scales'
+    news, _, hbm, bufs = (refs[i * n:(i + 1) * n] for i in range(4))
+    kbuf, vbuf, *sbufs = bufs            # (the outputs alias the inputs)
     nb = pos_ref.shape[0]
     n_kv, d = kbuf.shape[2], kbuf.shape[4]
+    lanes = sbufs[0].shape[3] if sbufs else 0
     sub = jax.lax.broadcasted_iota(jnp.int32, (1, rows // per, d), 1)
+    if sbufs:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, lanes), 2)
 
     def dmas(b, read: bool):
         buf = b % _APPEND_BUF
         here = pl.ds(pl.multiple_of(pos_ref[b] // rows * rows, rows), rows)
+        tile = pl.ds(pl.multiple_of(pos_ref[b] // lanes * lanes, lanes),
+                     lanes) if lanes else None
         out = []
-        for i, (hbm, scratch) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
-            pair = (hbm.at[:, b, :, here], scratch.at[buf])
+        for i, scratch in enumerate(bufs):
+            pair = (hbm[i].at[:, b, :, here if i < 2 else tile],
+                    scratch.at[buf])
             out.append(pltpu.make_async_copy(
                 *(pair if read else pair[::-1]), sem.at[int(read), i, buf]))
         return out
@@ -456,13 +480,23 @@ def _append_kernel(pos_ref, keep_ref, kn_ref, vn_ref, k_in, v_in, k_hbm,
         buf = b % _APPEND_BUF
         word = (pos_ref[b] % rows) // per
         keep = keep_ref[b]
-        for scratch, new_ref in ((kbuf, kn_ref), (vbuf, vn_ref)):
+        for scratch, new_ref in zip((kbuf, vbuf), news):
             for kv in range(n_kv):
                 old = pltpu.bitcast(scratch[buf, :, kv], jnp.int32)
                 new = new_ref[b, :, kv:kv + 1, :]            # [L, 1, D]
                 scratch[buf, :, kv] = pltpu.bitcast(
                     jnp.where(sub == word, (old & keep) | new, old),
                     scratch.dtype)
+        if sbufs:
+            # the step's scales come a slot a lane, [B / lanes, L, KV,
+            # lanes]: slot b's turned to its cursor's lane; a dropped row
+            # (nothing of its word kept out) drops its scales
+            at = pos_ref[b] % lanes
+            hit = (lane == at) & (keep != -1)
+            turn = (at - b % lanes + lanes) % lanes
+            for scratch, new_ref in zip(sbufs, news[2:]):
+                new = pltpu.roll(new_ref[b // lanes], turn, 2)
+                scratch[buf] = jnp.where(hit, new, scratch[buf])
         for c in dmas(b, False):
             c.start()
 
@@ -473,11 +507,14 @@ def _append_kernel(pos_ref, keep_ref, kn_ref, vn_ref, k_in, v_in, k_hbm,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def append_rows_stacked(cache_k, cache_v, k_rows, v_rows, positions, *,
-                        interpret: bool = False):
+def append_rows_stacked(cache_k, cache_v, k_rows, v_rows, positions,
+                        k_scale=None, v_scale=None, k_scale_rows=None,
+                        v_scale_rows=None, *, interpret: bool = False):
     """cache[:, b, :, positions[b]] = rows[:, b], every layer and KV
-    head, in place on donated caches; a position at or past ``Smax`` is
-    dropped like a scatter's.
+    head, in place on donated caches, and for a quantized cache
+    scale[:, b, :, positions[b]] = scale_rows[:, b] in place on the two
+    donated scale tables; a position at or past ``Smax`` is dropped like
+    a scatter's, row and scales.
 
     XLA cannot do this write where the cache is: a position is one row
     of the (Smax, hd) tiles, a quarter of a 32-bit sublane at int8, and
@@ -489,8 +526,22 @@ def append_rows_stacked(cache_k, cache_v, k_rows, v_rows, positions, *,
     writes them back; three buffers keep a read, a merge and a write in
     flight across slots.
 
+    The scales [L, B, KV, Smax] float32 have the position on lanes, and
+    XLA has no in-place write there either: a scatter costs two layout
+    copies of a table, 0.6 ms each a step at 32 x 40 x 8 x 2,048, and
+    the select over both whole tables that stood in for it until PR 48
+    moved 336 MB to change 20,480 values, 0.5 ms of Mistral's 13 ms step
+    (PERF.md, Findings PR 25 and PR 48). Here the slot's lane tile of
+    each table ([L, KV, 128] around the cursor, 131 KB at eight KV
+    heads) rides the rows' three buffers: read, the step's scale put on
+    the cursor's lane, written back, 21 MB a step. The step's scales
+    arrive a slot a lane ([L, KV, 128 slots], one tile a table in VMEM),
+    and a lane rotation brings slot b's to its cursor's lane.
+
     cache_k/cache_v: [L, B, KV, Smax, D]; k_rows/v_rows: [L, B, KV, D] in
-    the caches' dtype; positions: [B] int32. Returns (cache_k, cache_v).
+    the caches' dtype; positions: [B] int32; k_scale/v_scale:
+    [L, B, KV, Smax] float32 with k_scale_rows/v_scale_rows [L, B, KV],
+    or None. Returns (cache_k, cache_v, k_scale, v_scale).
     """
     from .flash import fit_block
 
@@ -518,49 +569,78 @@ def append_rows_stacked(cache_k, cache_v, k_rows, v_rows, positions, *,
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     tile = pltpu.VMEM((_APPEND_BUF, n_l, n_kv, rows, d), cache_k.dtype)
-    return pl.pallas_call(
+    keep = as_i32(~mask)
+    news, tables, tiles = [words(k_rows), words(v_rows)], [], []
+    if k_scale is not None:
+        lanes = fit_block(smax, _LANES)
+        pad = -b % lanes
+
+        def by_lane(x):    # [L, B, KV] -> [B / lanes, L, KV, lanes]
+            x = jnp.pad(jnp.moveaxis(x, 1, 2), ((0, 0), (0, 0), (0, pad)))
+            return jnp.moveaxis(
+                x.reshape(n_l, n_kv, (b + pad) // lanes, lanes), 2, 0)
+
+        news += [by_lane(k_scale_rows), by_lane(v_scale_rows)]
+        tables = [k_scale, v_scale]
+        tiles = [pltpu.VMEM((_APPEND_BUF, n_l, n_kv, lanes), jnp.float32)] * 2
+    caches = [cache_k, cache_v, *tables]
+    first = 2 + len(news)                # after the two scalar operands
+    out = pl.pallas_call(
         functools.partial(_append_kernel, rows=rows, per=per),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(1,),
-            in_specs=[vmem, vmem, hbm, hbm], out_specs=[hbm, hbm],
-            scratch_shapes=[tile, tile,
-                            pltpu.SemaphoreType.DMA((2, 2, _APPEND_BUF))]),
-        out_shape=[jax.ShapeDtypeStruct(cache_k.shape, cache_k.dtype),
-                   jax.ShapeDtypeStruct(cache_v.shape, cache_v.dtype)],
-        input_output_aliases={4: 0, 5: 1},
+            in_specs=[vmem] * len(news) + [hbm] * len(caches),
+            out_specs=[hbm] * len(caches),
+            scratch_shapes=[tile, tile, *tiles, pltpu.SemaphoreType.DMA(
+                (2, len(caches), _APPEND_BUF))]),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in caches],
+        input_output_aliases={first + i: i for i in range(len(caches))},
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024),
-    )(pos, as_i32(~mask), words(k_rows), words(v_rows), cache_k, cache_v)
+    )(pos, keep, *news, *caches)
+    return (*out, None, None)[:4]
 
 
-def append_rows_sharded(cache_k, cache_v, k_rows, v_rows, positions, *,
-                        mesh, n_heads: int, interpret: bool = False):
+def append_rows_sharded(cache_k, cache_v, k_rows, v_rows, positions,
+                        k_scale=None, v_scale=None, k_scale_rows=None,
+                        v_scale_rows=None, *, mesh, n_heads: int,
+                        interpret: bool = False):
     """shard_map'd append_rows_stacked: each device writes its local KV
-    heads' (and slots') rows, the caches staying where
-    parallel.kv_cache_specs placed them."""
+    heads' (and slots') rows and scales, the caches and the scale tables
+    staying where parallel.kv_cache_specs placed them."""
+    from jax.sharding import PartitionSpec as P
+
     from ..parallel.sharding import attention_shard_axes
 
     _, cspec, rspec, lspec = _shard_specs(*attention_shard_axes(
         mesh, cache_k.shape[1], n_heads, cache_k.shape[2]))
+    specs, args = (cspec, cspec, rspec, rspec, lspec), ()
+    out_specs = (cspec, cspec, None, None)
+    if k_scale is not None:    # the tables like a step's rows, [L, B, KV, .]
+        specs += (rspec, rspec, P(*rspec[:3]), P(*rspec[:3]))
+        args = (k_scale, v_scale, k_scale_rows, v_scale_rows)
+        out_specs = (cspec, cspec, rspec, rspec)
     run = functools.partial(append_rows_stacked, interpret=interpret)
     return jax.shard_map(
-        run, mesh=mesh, in_specs=(cspec, cspec, rspec, rspec, lspec),
-        out_specs=(cspec, cspec), check_vma=False)(
-            cache_k, cache_v, k_rows, v_rows, positions)
+        run, mesh=mesh, in_specs=specs, out_specs=out_specs,
+        check_vma=False)(cache_k, cache_v, k_rows, v_rows, positions, *args)
 
 
 @jax.named_scope("kv_append")
-def append_rows(cache_k, cache_v, k_rows, v_rows, positions, *,
+def append_rows(cache_k, cache_v, k_rows, v_rows, positions, k_scale=None,
+                v_scale=None, k_scale_rows=None, v_scale_rows=None, *,
                 n_heads: int, mesh=None):
-    """The step's rows into the stacked cache, in place, under shard_map
-    where ``mesh`` shards heads or batch: the caller has asked
-    ``kernel_block`` for these shapes, and takes XLA's scatter where it
-    is None."""
+    """The step's rows into the stacked cache, and a quantized cache's
+    scales into its scale tables, in place, under shard_map where
+    ``mesh`` shards heads or batch: the caller has asked ``kernel_block``
+    for these shapes, and takes XLA's scatter (and a select for the
+    scales) where it is None. Returns (cache_k, cache_v, k_scale,
+    v_scale), the last two None for a cache without scales."""
     from .flash import interpret_env
 
+    args = (cache_k, cache_v, k_rows, v_rows, positions, k_scale, v_scale,
+            k_scale_rows, v_scale_rows)
     if mesh is not None:
-        return append_rows_sharded(cache_k, cache_v, k_rows, v_rows,
-                                   positions, mesh=mesh, n_heads=n_heads,
+        return append_rows_sharded(*args, mesh=mesh, n_heads=n_heads,
                                    interpret=interpret_env())
-    return append_rows_stacked(cache_k, cache_v, k_rows, v_rows, positions,
-                               interpret=interpret_env())
+    return append_rows_stacked(*args, interpret=interpret_env())

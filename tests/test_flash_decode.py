@@ -219,6 +219,59 @@ def test_write_rows_kernel_arm_is_the_scatter(kv_dtype, w, monkeypatch):
     assert not np.asarray(got.k[:, 3]).any()
 
 
+@pytest.mark.parametrize("kv,smax,cursors,w", [
+    # a slot of length 0, a tile's last lane and the next tile's first,
+    # two slots on one tile (128, 130), the last position, at capacity
+    # and past it
+    (8, 256, [0, 127, 128, 130, 255, 256, 300], 1),
+    (2, 256, [0, 127, 128, 130, 255, 256, 300], 1),
+    # a verify window of two: astride a tile's edge, astride capacity
+    (8, 256, [0, 127, 128, 254, 255, 256], 2),
+    (2, 256, [0, 127, 128, 254, 255, 256], 2),
+    # more slots than a tile has lanes: the step's scales in two groups
+    (2, 32, [(7 * i) % 34 for i in range(40)], 1),
+])
+def test_append_rows_writes_the_scales_the_select_writes(kv, smax, cursors,
+                                                         w, monkeypatch):
+    """The scale tables of an int8 cache after ``append_rows``
+    (interpreted: the lane tile around each cursor read, the step's scale
+    put on the cursor's lane, written back) are the tables
+    ``write_rows``' select leaves on the path without kernels, bit for
+    bit: every other value of both tables is what it was, and a
+    position at or past capacity drops its scales with its row."""
+    b, n_l = len(cursors), 2
+    ks = jax.random.split(jax.random.PRNGKey(kv + w), 6)
+    rand = jax.random.normal
+    cache = llama.KVCache(
+        k=(rand(ks[0], (n_l, b, kv, smax, D)) * 40).astype(jnp.int8),
+        v=(rand(ks[1], (n_l, b, kv, smax, D)) * 40).astype(jnp.int8),
+        lengths=jnp.asarray(cursors, jnp.int32),
+        k_scale=jnp.abs(rand(ks[2], (n_l, b, kv, smax))) + 1.0,
+        v_scale=jnp.abs(rand(ks[3], (n_l, b, kv, smax))) + 1.0)
+    k_rows = rand(ks[4], (n_l, b, w, kv, D))
+    v_rows = rand(ks[5], (n_l, b, w, kv, D))
+    positions = cache.lengths[:, None] + jnp.arange(w)[None, :]
+    args = (cache, k_rows, v_rows, positions, cache.lengths + w, 4 * kv)
+    want = llama.write_rows(*args)
+    appends, real = [], fd.append_rows
+
+    def counted(*a, **kw):
+        appends.append(a)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(fd, "append_rows", counted)
+    monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
+    got = llama.write_rows(*args)
+    assert len(appends) == w and all(len(a) == 9 for a in appends)
+    for a, e in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(e))
+    # the select's tables are the old ones but at the cursors under Smax
+    old, new = np.asarray(cache.k_scale), np.asarray(got.k_scale)
+    moved = {(slot, int(p)) for _, slot, _, p in zip(*np.nonzero(old != new))}
+    assert moved == {(i, c + j) for i, c in enumerate(cursors)
+                     for j in range(w) if c + j < smax}
+
+
 # -- selection: what the code can observe, no setting -------------------------
 
 def _cache_shape(kv=8, d=128, smax=2048, dtype=jnp.int8):

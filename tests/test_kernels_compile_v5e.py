@@ -80,6 +80,23 @@ def _shapes(sharding, kv, dtype):
     return arr, cache, scale
 
 
+def _made(text, shape):
+    """The opcodes of the instructions of a compiled module's text, its
+    fused computations' among them, whose result is an ``f32[shape]``."""
+    return set(re.findall(
+        rf"^\s*(?:ROOT )?%?[\w.\-]+ = f32\[{shape}\]\S* ([\w\-]+)\(",
+        text, re.M))
+
+
+# what a program that writes the step's scales where they lie holds of
+# no scale table: a select over it (until PR 48 the write), a copy, a
+# broadcast or a fusion of its shape. (The compiler's own prefetch of a
+# table it chose to keep in VMEM across the layer loop, a tp=4 shard's
+# 21 MB, is a copy-start and a copy-done and no change of layout.)
+_TABLE_MOVES = {"select", "copy", "broadcast", "fusion", "transpose",
+                "scatter", "dynamic-update-slice"}
+
+
 @pytest.mark.parametrize("kv,dtype", [(8, jnp.int8), (2, jnp.int8),
                                       (1, jnp.int8), (8, jnp.bfloat16)])
 def test_decode_kernel_compiles(one_chip, kv, dtype):
@@ -94,20 +111,35 @@ def test_decode_kernel_compiles(one_chip, kv, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("kv,dtype", [(8, jnp.int8), (2, jnp.int8),
-                                      (1, jnp.int8), (8, jnp.bfloat16)])
-def test_append_kernel_compiles_in_place(one_chip, kv, dtype):
+@pytest.mark.parametrize("kv,dtype,scaled", [
+    (8, jnp.int8, False), (2, jnp.int8, False), (1, jnp.int8, False),
+    (8, jnp.bfloat16, False), (8, jnp.int8, True), (2, jnp.int8, True)])
+def test_append_kernel_compiles_in_place(one_chip, kv, dtype, scaled):
     """And the caches it returns are the caches it was given: donated,
-    the program holds no second copy of either."""
-    arr, cache, _ = _shapes(one_chip, kv, dtype)
+    the program holds no second copy of either. With a quantized cache's
+    scale tables (``scaled``) it is four buffers that come back as they
+    went in, and a slot's [L, KV, 128] lane tile of a table is whole
+    sublane tiles at eight KV heads and a quarter of one at the two a
+    tp=4 shard of Mixtral holds: Mosaic takes both, and the rotation of
+    the step's scales along lanes by a cursor it reads at run time."""
+    arr, cache, scale = _shapes(one_chip, kv, dtype)
     rows = arr((L, B, kv, D), dtype)
-    compiled = jax.jit(fd.append_rows_stacked, donate_argnums=(0, 1)).lower(
-        cache, cache, rows, rows, arr((B,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    tables = (scale, scale, arr((L, B, kv), jnp.float32),
+              arr((L, B, kv), jnp.float32)) if scaled else ()
+    compiled = jax.jit(fd.append_rows_stacked,
+                       donate_argnums=(0, 1, 5, 6)[:4 if scaled else 2]).lower(
+        cache, cache, rows, rows, arr((B,), jnp.int32), *tables).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
     mem = compiled.memory_analysis()
     cache_bytes = 2 * L * B * kv * SMAX * D * jnp.dtype(dtype).itemsize
-    assert mem.alias_size_in_bytes >= cache_bytes
+    table_bytes = 2 * L * B * kv * SMAX * 4 if scaled else 0
+    assert mem.alias_size_in_bytes >= cache_bytes + table_bytes
     assert mem.temp_size_in_bytes < cache_bytes // 8
+    # no operation but the kernel makes a table: none is copied or selected
+    made = _made(text, f"{L},{B},{kv},{SMAX}")
+    assert made <= {"parameter", "get-tuple-element"}
+    assert bool(made) == scaled
 
 
 def test_latent_decode_kernel_compiles(one_chip):
@@ -285,6 +317,11 @@ def test_qk_projections_read_their_weights_in_place(one_chip, monkeypatch,
     assert not stack_copies
     assert not staged
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    if program == "decode block":
+        # the step's scales go in through the append kernel's visit: no
+        # select, copy or broadcast of a scale table is left
+        tables = _made(text, f"2,{B},8,{SMAX}")
+        assert tables and not tables & _TABLE_MOVES
 
 
 # -- Mixtral's shard on the four chips: the experts' one reduction --------------
@@ -365,6 +402,11 @@ def test_experts_are_combined_before_they_cross_the_chips(tp4, monkeypatch,
             if (i.dtype, i.dims) == ("s8", [2, 8, 3584, 4096])]
     assert not [i[:3] for i in stacks if i.dtype == "bf16"
                 and math.prod(i.dims) >= expert_slice]
+    if program == "decode block":
+        # a chip's two KV heads' scale tables: written where they lie,
+        # as on one chip (test_qk_projections_read_their_weights_in_place)
+        tables = _made(text, f"2,{B},2,{SMAX}")
+        assert tables and not tables & _TABLE_MOVES
 
 # -- the window family's programs at the published widths ----------------------
 

@@ -392,9 +392,10 @@ def test_the_row_write_at_a_head_of_64_lands_in_its_half(dtype):
     kr, vr = (jnp.asarray(rng.normal(size=(L, B, KV, D)), dtype)
               for _ in range(2))
     pos = jnp.asarray([0, 37, S])
-    got_k, got_v = flash_decode.append_rows_stacked(
+    got_k, got_v, *no_scales = flash_decode.append_rows_stacked(
         k, v, attention.pair_rows(kr), attention.pair_rows(vr), pos,
         interpret=True)
+    assert no_scales == [None, None]
     for got, old, new in ((got_k, k, kr), (got_v, v, vr)):
         want = np.array(old)
         for b, p in enumerate([0, 37]):

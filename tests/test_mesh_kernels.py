@@ -136,17 +136,25 @@ def test_flash_decode_sharded_matches_reference(tp, dp, kv, quant):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("dtype", [jnp.int8, jnp.bfloat16])
+@pytest.mark.parametrize("dtype,scaled", [(jnp.int8, False),
+                                          (jnp.bfloat16, False),
+                                          (jnp.int8, True)])
 @pytest.mark.parametrize("tp,dp,kv", [(2, 4, 4), (4, 1, 8)])
-def test_append_rows_sharded_matches_scatter(tp, dp, kv, dtype):
+def test_append_rows_sharded_matches_scatter(tp, dp, kv, dtype, scaled):
     """The step's write under shard_map: every device merges its own KV
     heads' (and slots') rows into the tiles around the cursors, a
     cursor at capacity dropped, and the gathered caches are the
-    scatter's, byte for byte."""
+    scatter's, byte for byte. A quantized cache's scale tables
+    (``scaled``) go through the same visit, sharded as the cache is, and
+    come back as the unsharded select's (``llama.write_rows`` on the
+    path without kernels), bit for bit."""
     import numpy as np
 
+    from gofr_tpu.models import llama
+    from gofr_tpu.ops.quant import quantize_kv
+
     n_l, b, s, h, d = 2, 8, 64, 8, 128
-    ks = jax.random.split(jax.random.PRNGKey(kv), 4)
+    ks = jax.random.split(jax.random.PRNGKey(kv), 8)
 
     def rand(key, shape):
         x = jax.random.normal(key, shape, jnp.float32) * 40
@@ -155,16 +163,33 @@ def test_append_rows_sharded_matches_scatter(tp, dp, kv, dtype):
     k, v = rand(ks[0], (n_l, b, kv, s, d)), rand(ks[1], (n_l, b, kv, s, d))
     k_rows, v_rows = rand(ks[2], (n_l, b, kv, d)), rand(ks[3], (n_l, b, kv, d))
     pos = jnp.asarray([0, 1, 31, 32, 33, 63, 64, 5], jnp.int32)
+    tables = rows = ()
+    if scaled:      # the rows and their scales as write_rows makes them
+        tables = tuple(jnp.abs(jax.random.normal(key, (n_l, b, kv, s))) + 1
+                       for key in ks[4:6])
+        real = [jax.random.normal(key, (n_l, b, 1, kv, d)) for key in ks[6:8]]
+        (k_rows, sk), (v_rows, sv) = (quantize_kv(x[:, :, 0]) for x in real)
+        rows = (sk, sv)
     mesh = make_mesh(tp=tp, dp=dp, devices=jax.devices()[:tp * dp])
-    got_k, got_v = flash_decode.append_rows_sharded(
-        k, v, k_rows, v_rows, pos, mesh=mesh, n_heads=h, interpret=True)
+    got_k, got_v, *got_tables = flash_decode.append_rows_sharded(
+        k, v, k_rows, v_rows, pos, *tables, *rows, mesh=mesh, n_heads=h,
+        interpret=True)
     slots = jnp.arange(b)
-    for got, cache, rows in ((got_k, k, k_rows), (got_v, v, v_rows)):
-        want = cache.at[:, slots, :, pos].set(jnp.moveaxis(rows, 1, 0),
+    for got, cache, new in ((got_k, k, k_rows), (got_v, v, v_rows)):
+        want = cache.at[:, slots, :, pos].set(jnp.moveaxis(new, 1, 0),
                                               mode="drop")
         np.testing.assert_array_equal(
             np.asarray(got.astype(jnp.float32)),
             np.asarray(want.astype(jnp.float32)))
+    if not scaled:
+        assert got_tables == [None, None]
+        return
+    # the select's tables: write_rows where no kernel answers
+    want = llama.write_rows(llama.KVCache(k, v, pos, *tables), *real,
+                            pos[:, None], pos + 1, h)
+    for got, e, old in zip(got_tables, (want.k_scale, want.v_scale), tables):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(e))
+        assert (np.asarray(got) != np.asarray(old)).sum() == n_l * 7 * kv
 
 
 # -- token exactness: contiguous engine ---------------------------------------
