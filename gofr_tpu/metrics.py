@@ -427,6 +427,11 @@ def register_framework_metrics(m: Manager) -> None:
                 "bytes of recurrent state or convolution tails the last "
                 "decode block's active slots hold (a family with "
                 "linear-attention or short-convolution layers)")
+    m.new_gauge("app_tpu_kv_live_bytes",
+                "bytes of K and V rows the last decode block's active slots "
+                "hold, at the family's bytes a cached token (a family that "
+                "says them: rows beside a state or a ring, or a row of "
+                "loop_steps x n_layers tables)")
     m.new_gauge("app_tpu_kv_window_live_bytes",
                 "bytes of K and V the last decode block's active slots hold "
                 "on their rings (a family with sliding-window layers)")

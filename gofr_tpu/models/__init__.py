@@ -1,4 +1,4 @@
-"""Model zoo: seven decoder families behind one seam (``family``), BERT
+"""Model zoo: eight decoder families behind one seam (``family``), BERT
 (embeddings), ViT (vision).
 
 The decoders: ``llama`` (dense GQA and Mixtral's eight experts; the row
@@ -7,7 +7,9 @@ cache and the dense block every family builds on), ``deepseek_v3``
 ones), ``laguna`` (sliding-window layers on a ring), ``lfm2`` (gated
 short convolutions), ``nemotron_h`` (Mamba-2 state-space layers),
 ``dots3_note`` (latent attention at two widths: full layers that select
-the rows they read, window layers on a ring of latent rows). What they
+the rows they read, window layers on a ring of latent rows), ``ouro``
+(one dense stack run several times a token, each pass with row tables of
+its own, four norms a layer). What they
 share has an owner that is no family: ``moe`` (the routed feed-forward
 of the six sparse ones), ``latent`` (the latent row's cache and the
 absorbed form's algebra, the two latent families'), ``blocks``
@@ -30,7 +32,7 @@ layer axis. No torch, no module classes — params are data, which is what
 from .common import ModelConfig, LLAMA_CONFIGS, BERT_CONFIGS, VIT_CONFIGS
 from . import (llama, bert, vit, moe, latent, blocks, hybrid_cache,
                deepseek_v3, solar_open2, laguna, lfm2, nemotron_h,
-               dots3_note)
+               dots3_note, ouro)
 
 
 def family(cfg: ModelConfig):
@@ -40,7 +42,9 @@ def family(cfg: ModelConfig):
     has never heard of). Every family gives the generator the same entry
     points: ``init``, ``init_cache``, ``get_rope_tables``, ``prefill_kv``,
     ``write_kv``, ``prefill_chunk``, ``decode_step``, ``decode_kv_block``,
-    ``kv_layout``, ``unsupported_options``, ``serving_stats``, ``forward``,
+    ``kv_layout``, ``kv_tables`` (the row tables a cached token has:
+    the depth, but for a stack that is run several times),
+    ``unsupported_options``, ``serving_stats``, ``forward``,
     and ``RECOMPUTABLE``: whether a cached position can be computed
     again and give the same memory (rows can; a recurrent or
     state-space state, a ring of rows and a convolution's tail cannot)."""
@@ -53,10 +57,12 @@ def family(cfg: ModelConfig):
         return dots3_note if cfg.kv_lora_rank > 0 else laguna
     if "conv" in cfg.layer_pattern:
         return lfm2
+    if cfg.loop_steps > 1 or cfg.sandwich_norm:
+        return ouro
     return deepseek_v3 if cfg.kv_lora_rank > 0 else llama
 
 
 __all__ = ["ModelConfig", "LLAMA_CONFIGS", "BERT_CONFIGS", "VIT_CONFIGS",
            "llama", "bert", "vit", "moe", "latent", "blocks",
            "hybrid_cache", "deepseek_v3", "solar_open2", "laguna", "lfm2",
-           "nemotron_h", "dots3_note", "family"]
+           "nemotron_h", "dots3_note", "ouro", "family"]

@@ -147,10 +147,29 @@ class ModelConfig:
     index_head_dim: int = 0
     index_topk: int = 0
     lora_rescale: bool = False
+    # a stack run several times a token (models/ouro.py; loop_steps > 1
+    # or sandwich_norm selects that family): the n_layers layers are run
+    # loop_steps times with the same weights, the final norm after every
+    # pass and its output fed to the next; each (pass, layer) keeps K and
+    # V rows of its own, so a token's cache is loop_steps x n_layers
+    # tables. sandwich_norm: a norm before AND after each of attention
+    # and feed-forward, the second inside the residual branch.
+    # early_exit_threshold: the cumulated exit probability at which a
+    # token leaves the loop; 1 (the published value) runs every pass, and
+    # anything under it is refused here: a step's passes would differ by
+    # slot
+    loop_steps: int = 1
+    sandwich_norm: bool = False
+    early_exit_threshold: float = 1.0
 
     def __post_init__(self):
         # a configuration file gives the pattern as a list
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        if self.early_exit_threshold < 1.0:
+            raise ValueError(
+                f"early_exit_threshold {self.early_exit_threshold} < 1: "
+                "every decode step runs all loop_steps passes for every "
+                "slot; an exit before the last pass is not implemented")
 
     @property
     def head_dim(self) -> int:
@@ -285,6 +304,13 @@ LLAMA_CONFIGS = {
         n_experts=16, experts_per_token=4, n_expert_groups=1, topk_groups=1,
         routed_scaling=1.0, n_shared_experts=1, moe_ffn_dim=40,
         n_dense_layers=1, n_experts_held=4),
+    # the looped family at test size: two layers run three times (six
+    # tables a token), four norms a layer, no grouping (a KV head a query
+    # head, as published), untied head
+    "tiny-loop": ModelConfig(
+        name="tiny-loop", vocab_size=256, dim=64, n_layers=2, n_heads=4,
+        n_kv_heads=4, ffn_dim=96, max_seq=128, rope_theta=1e6,
+        norm_eps=1e-6, dtype="float32", loop_steps=3, sandwich_norm=True),
 }
 
 BERT_CONFIGS = {

@@ -78,6 +78,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int | None = None,
         lengths=jnp.zeros((batch,), jnp.int32))
 
 
+kv_tables = llama.kv_tables      # one table a layer (models.family)
+
+
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     """(heads, values a head) of a cached token, for the prefix index's
     shape contract: one shared row."""

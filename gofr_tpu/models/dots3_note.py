@@ -158,6 +158,9 @@ def get_rope_tables(cfg: ModelConfig, max_seq: int) -> dict:
                       rope_scaling=None), max_seq)}
 
 
+kv_tables = llama.kv_tables      # one table a layer (models.family)
+
+
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     """(heads, values a head) of a cached token, for the prefix index's
     shape contract: one shared row."""

@@ -63,6 +63,9 @@ def get_rope_tables(cfg: ModelConfig, max_seq: int):
     return llama.get_rope_tables(cfg, max_seq) if cfg.use_rope else None
 
 
+kv_tables = llama.kv_tables      # one table a layer (models.family)
+
+
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     return cfg.n_kv_heads, cfg.head_dim
 

@@ -125,6 +125,9 @@ def get_rope_tables(cfg: ModelConfig, max_seq: int) -> dict:
                       rope_scaling=None), max_seq)}
 
 
+kv_tables = llama.kv_tables      # one table a layer (models.family)
+
+
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     return cfg.n_kv_heads, cfg.head_dim
 

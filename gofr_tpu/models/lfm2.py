@@ -92,6 +92,9 @@ def paired(cfg: ModelConfig) -> bool:
     return cfg.head_dim < _LANES and cfg.n_kv_heads % 2 == 0
 
 
+kv_tables = llama.kv_tables      # one table a layer (models.family)
+
+
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     """(rows, values a row) of a cached token's K (and V), as stored."""
     if paired(cfg):

@@ -102,6 +102,13 @@ def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     return cfg.n_kv_heads, cfg.head_dim
 
 
+def kv_tables(cfg: ModelConfig) -> int:
+    """Row tables a cached token has, the leading axis of what leaves the
+    device ([tables, plen, KV, hd]): one a layer, in every family whose
+    token passes each layer once."""
+    return cfg.n_layers
+
+
 def decode_kv_block(cfg: ModelConfig, cache: KVCache, mesh=None):
     """Cache positions a decode work item covers, None on the reference
     path (ops.flash_decode.kernel_block)."""

@@ -62,7 +62,7 @@ from .common import ModelConfig, dense_init
 # has them
 from .hybrid_cache import (HybridCache, decode_attend, decode_kv_block,
                            get_rope_tables as get_rope_tables,
-                           kv_layout as kv_layout,
+                           kv_layout as kv_layout, kv_tables as kv_tables,
                            unsupported_options as unsupported_options,
                            write_kv as write_kv)
 

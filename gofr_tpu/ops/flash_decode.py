@@ -427,11 +427,34 @@ def decode_attention_auto(q, cache_k, cache_v, k_new, v_new, lengths, layer,
 # -- the step's write ---------------------------------------------------------
 
 _APPEND_BUF = 3    # slot b+1 read and slot b-1 written while slot b merges
+# what one visit's buffers and the step's rows may take of VMEM, under
+# the 64 MiB the kernel is given: Mistral's 32 tables x 8 KV heads x 40
+# slots take 17.8 MB whole; 192 x 16 (a stack run four times) would take
+# 110 MB and go a share of 48 at a time, 27.5 MB (``append_tables``)
+_APPEND_VMEM = 32 * 1024 * 1024
 
 
-def _append_kernel(pos_ref, keep_ref, *refs, rows: int, per: int):
+def append_tables(n_l: int, b: int, n_kv: int, rows: int, d: int,
+                  item: int, lanes: int) -> int:
+    """Tables one call of the append kernel visits: all ``n_l`` where
+    their tiles fit ``_APPEND_VMEM``, else the largest divisor of
+    ``n_l`` that does, the calls one after another over the table axis,
+    each in place on what the one before it left. A table costs its K
+    and V tiles a buffer ([KV, rows, d]), the step's rows as 32-bit
+    words ([B, KV, d]) and, where the cache has scales (``lanes`` > 0),
+    their lane tiles a buffer and the step's a slot a lane."""
+    a_table = 2 * n_kv * (_APPEND_BUF * rows * d * item + b * d * 4)
+    if lanes:
+        a_table += 2 * n_kv * lanes * 4 * (_APPEND_BUF + -(-b // lanes))
+    fit = max(_APPEND_VMEM // a_table, 1)
+    return max(n for n in range(1, n_l + 1) if n_l % n == 0 and n <= fit)
+
+
+def _append_kernel(pos_ref, keep_ref, *refs, rows: int, per: int,
+                   tables=slice(None)):
     """Every slot: fetch the ``rows`` positions around its cursor, all
-    layers and KV heads, put the new row's bits into its 32-bit words,
+    layers (or the share of them ``tables`` names: ``append_tables``)
+    and KV heads, put the new row's bits into its 32-bit words,
     write the tiles back. ``refs``: what the step made, the tables it
     goes into, the same as outputs, a buffer each, the semaphores; the
     tables are K and V or, for a quantized cache, their two scale
@@ -455,7 +478,7 @@ def _append_kernel(pos_ref, keep_ref, *refs, rows: int, per: int):
                      lanes) if lanes else None
         out = []
         for i, scratch in enumerate(bufs):
-            pair = (hbm[i].at[:, b, :, here if i < 2 else tile],
+            pair = (hbm[i].at[tables, b, :, here if i < 2 else tile],
                     scratch.at[buf])
             out.append(pltpu.make_async_copy(
                 *(pair if read else pair[::-1]), sem.at[int(read), i, buf]))
@@ -538,6 +561,13 @@ def append_rows_stacked(cache_k, cache_v, k_rows, v_rows, positions,
     arrive a slot a lane ([L, KV, 128 slots], one tile a table in VMEM),
     and a lane rotation brings slot b's to its cursor's lane.
 
+    Where a slot's tiles of ALL the tables would not fit the kernel's
+    buffers (192 tables of 16 KV heads, a stack run four times: 12.6 MB a
+    buffer, 75 MB over K, V and three buffers), the visit is cut over
+    the table axis: the same kernel a share of the tables at a time
+    (``append_tables``: 48 there, a pass), each call in place on what the
+    one before it left.
+
     cache_k/cache_v: [L, B, KV, Smax, D]; k_rows/v_rows: [L, B, KV, D] in
     the caches' dtype; positions: [B] int32; k_scale/v_scale:
     [L, B, KV, Smax] float32 with k_scale_rows/v_scale_rows [L, B, KV],
@@ -568,9 +598,8 @@ def append_rows_stacked(cache_k, cache_v, k_rows, v_rows, positions,
 
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    tile = pltpu.VMEM((_APPEND_BUF, n_l, n_kv, rows, d), cache_k.dtype)
     keep = as_i32(~mask)
-    news, tables, tiles = [words(k_rows), words(v_rows)], [], []
+    news, tables, lanes = [words(k_rows), words(v_rows)], [], 0
     if k_scale is not None:
         lanes = fit_block(smax, _LANES)
         pad = -b % lanes
@@ -582,23 +611,32 @@ def append_rows_stacked(cache_k, cache_v, k_rows, v_rows, positions,
 
         news += [by_lane(k_scale_rows), by_lane(v_scale_rows)]
         tables = [k_scale, v_scale]
-        tiles = [pltpu.VMEM((_APPEND_BUF, n_l, n_kv, lanes), jnp.float32)] * 2
     caches = [cache_k, cache_v, *tables]
     first = 2 + len(news)                # after the two scalar operands
-    out = pl.pallas_call(
-        functools.partial(_append_kernel, rows=rows, per=per),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(1,),
-            in_specs=[vmem] * len(news) + [hbm] * len(caches),
-            out_specs=[hbm] * len(caches),
-            scratch_shapes=[tile, tile, *tiles, pltpu.SemaphoreType.DMA(
-                (2, len(caches), _APPEND_BUF))]),
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in caches],
-        input_output_aliases={first + i: i for i in range(len(caches))},
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024),
-    )(pos, keep, *news, *caches)
-    return (*out, None, None)[:4]
+    # all the tables in one visit a slot, or a share of them a call
+    share = append_tables(n_l, b, n_kv, rows, d, item, lanes)
+    tile = pltpu.VMEM((_APPEND_BUF, share, n_kv, rows, d), cache_k.dtype)
+    tiles = [pltpu.VMEM((_APPEND_BUF, share, n_kv, lanes), jnp.float32)] \
+        * len(tables)
+    for at in range(0, n_l, share):
+        part = {} if share == n_l else {"tables": pl.ds(at, share)}
+        mine = news if share == n_l else [x[:, at:at + share] for x in news]
+        caches = pl.pallas_call(
+            functools.partial(_append_kernel, rows=rows, per=per, **part),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(1,),
+                in_specs=[vmem] * len(news) + [hbm] * len(caches),
+                out_specs=[hbm] * len(caches),
+                scratch_shapes=[tile, tile, *tiles, pltpu.SemaphoreType.DMA(
+                    (2, len(caches), _APPEND_BUF))]),
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                       for x in caches],
+            input_output_aliases={first + i: i for i in range(len(caches))},
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=64 * 1024 * 1024),
+        )(pos, keep, *mine, *caches)
+    return (*caches, None, None)[:4]
 
 
 def append_rows_sharded(cache_k, cache_v, k_rows, v_rows, positions,

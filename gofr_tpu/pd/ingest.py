@@ -40,6 +40,7 @@ import time
 import numpy as np
 
 from .. import chaos
+from ..models import family
 from ..resilience import Deadline
 from ..tpu.kvcache import KVLayout
 from ..tpu.kvcache.quant import concat_blocks, decode_block
@@ -85,7 +86,8 @@ class KVIngestServer:
         self.window_bytes = int(window_bytes)
         cache = generator.cache
         self.layout = KVLayout(
-            generator.cfg.n_layers, generator.cfg.n_kv_heads,
+            family(generator.cfg).kv_tables(generator.cfg),
+            generator.cfg.n_kv_heads,
             generator.cfg.head_dim, cache.k_scale is not None,
             np.dtype(str(cache.k.dtype)), generator.max_seq)
         self._hello = p.hello_payload(fingerprint, self.layout)

@@ -202,11 +202,13 @@ class PDPrefill:
         self.resume_wait_s = float(resume_wait_s)
         import numpy as np
 
+        from ..models import family
         from ..tpu.kvcache import KVLayout
 
         cache = generator.cache
         self.layout = KVLayout(
-            generator.cfg.n_layers, generator.cfg.n_kv_heads,
+            family(generator.cfg).kv_tables(generator.cfg),
+            generator.cfg.n_kv_heads,
             generator.cfg.head_dim, cache.k_scale is not None,
             np.dtype(str(cache.k.dtype)), generator.max_seq)
         self._hello = p.hello_payload(fingerprint, self.layout)

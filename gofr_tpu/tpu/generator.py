@@ -553,6 +553,14 @@ class GenerationEngine:
         self._ring_row_bytes = said.get("window_bytes_per_slot", 0) \
             // max(self._ring_rows, 1)
         self._ring_live = 0
+        # a family that runs its stack several times a token says so
+        # (0: once, and stats() says nothing): the tokens its decode
+        # blocks emitted, each through every pass, for stats()["loop"];
+        # and the bytes a cached token takes where a family says them,
+        # for app_tpu_kv_live_bytes
+        self._loop_steps = said.get("loop_steps", 0)
+        self._loop_tokens = 0
+        self._kv_token_bytes = said.get("kv_bytes_per_token", 0)
         # In-flight admission poll cadence (seconds). While a decode
         # block runs on device, the serving loop waits on the submit
         # event in slices of this length and admits new arrivals
@@ -842,7 +850,8 @@ class GenerationEngine:
                 self._prog.describe("pool", prefix_cache_slots,
                                     self._hbm_pool_reclaim)
                 self._pool = self._prog.allocate("pool")
-                layout = KVLayout(cfg.n_layers, *self._fam.kv_layout(cfg),
+                layout = KVLayout(self._fam.kv_tables(cfg),
+                                  *self._fam.kv_layout(cfg),
                                   self._pool.quantized,
                                   np.dtype(str(self._pool[0].dtype)),
                                   self.max_seq)
@@ -1401,6 +1410,12 @@ class GenerationEngine:
             # phases, warm-up records, cache misses (observe/startup.py)
             "startup": self._startup.stats(),
         }
+        if self._loop_steps:
+            # tokens the decode blocks emitted and the passes over the
+            # stack they took (every token runs every pass: ModelConfig
+            # refuses an exit threshold under 1)
+            out["loop"] = {"tokens": self._loop_tokens,
+                           "passes": self._loop_tokens * self._loop_steps}
         if self._ring_rows:
             # rows the active slots hold: of the full layers (a layer),
             # and of a window layer's rings at the last decode block
@@ -2718,11 +2733,12 @@ class GenerationEngine:
                 f"ingest KV covers {kv.plen} tokens but the prompt has "
                 f"{len(prompt)} — the transfer is incomplete")
         cfg = self.cfg
-        if (kv.k.shape[0] != cfg.n_layers
+        tables = self._fam.kv_tables(cfg)
+        if (kv.k.shape[0] != tables
                 or kv.k.shape[2:] != (cfg.n_kv_heads, cfg.head_dim)):
             raise GenerationError(
                 f"ingest KV layout {kv.k.shape} does not match this "
-                f"engine ({cfg.n_layers} layers, {cfg.n_kv_heads} KV "
+                f"engine ({tables} row tables, {cfg.n_kv_heads} KV "
                 f"heads, head_dim {cfg.head_dim})")
         quant = self.cache.k_scale is not None
         if quant and kv.k_scale is None:
@@ -4187,6 +4203,12 @@ class GenerationEngine:
                 tuple(int(i) for i in np.flatnonzero(snap_active)),
                 self.decode_block, live, fetched, assigned, touched, states,
                 ring, sampled or None, kept)
+        if self._loop_steps:
+            self._loop_tokens += int(emit_np.sum())
+        if self._kv_token_bytes and live is not None \
+                and self.metrics is not None:
+            self.metrics.set_gauge("app_tpu_kv_live_bytes",
+                                   float(live * self._kv_token_bytes))
         if ring is not None:
             self._ring_live = ring
             if self.metrics is not None:

@@ -102,8 +102,16 @@ def model_fingerprint(cfg, params=None, extra: str = "") -> str:
     often share a frozen/tied embedding table, typically first in tree
     order) — still without hashing gigabytes; on any failure the
     config-only hash still isolates architectures."""
+    from ...models import ModelConfig, family
+
+    # the row tables a cached token has, not the depth: a row of a
+    # one-pass model must never be restored into one that runs its stack
+    # several times (the same number wherever a token passes a layer
+    # once, and for a bare description of sizes that is no ModelConfig)
+    tables = family(cfg).kv_tables(cfg) if isinstance(cfg, ModelConfig) \
+        else cfg.n_layers
     h = hashlib.sha256()
-    h.update(repr((cfg.name, cfg.vocab_size, cfg.dim, cfg.n_layers,
+    h.update(repr((cfg.name, cfg.vocab_size, cfg.dim, tables,
                    cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
                    cfg.rope_theta)).encode())
     h.update(extra.encode())

@@ -812,3 +812,115 @@ def test_window_family_draws_only_inside_a_conditional(one_chip, monkeypatch):
     f32 = [ln for ln in wide if " = f32[" in ln or " = (f32[" in ln]
     assert all("log_softmax" in ln for ln in f32), [ln[:200] for ln in f32]
     assert compiled.memory_analysis().temp_size_in_bytes <= 157_064_192
+
+
+# -- the looped family: 192 tables of 16 KV heads, a group of one -----------------
+
+OURO_TABLES, OURO_B, OURO_KV, OURO_SMAX = 192, 7, 16, 1536
+
+
+def _ouro_shapes(sharding):
+    def arr(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    cache = arr((OURO_TABLES, OURO_B, OURO_KV, OURO_SMAX, D), jnp.int8)
+    scale = arr((OURO_TABLES, OURO_B, OURO_KV, OURO_SMAX), jnp.float32)
+    return arr, cache, scale
+
+
+def test_decode_kernel_compiles_at_a_group_of_one(one_chip):
+    """16 query heads on 16 KV heads over one of 192 tables of a 7 x 1,536
+    int8 cache: an item is 16 KV heads' [256, 128] tiles, 1 MB of K and V
+    where Mistral's is half that, three buffers of it."""
+    arr, cache, scale = _ouro_shapes(one_chip)
+    q = arr((OURO_B, 1, OURO_KV, D), jnp.bfloat16)
+    compiled = fd.flash_decode_stacked.lower(
+        q, cache, cache, q, q, arr((OURO_B,), jnp.int32), arr((), jnp.int32),
+        scale, scale, block_s=fd.block_size(OURO_SMAX)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert fd.block_size(OURO_SMAX) == 256
+
+
+def test_append_kernel_compiles_cut_over_the_tables(one_chip):
+    """The step's write at 192 x 16 tables: four calls of 48 tables (a
+    slot's tiles of all 192 would be 12.6 MB a buffer, 75 MB over K, V
+    and three buffers, past the kernel's VMEM), each in place on the
+    caches and the scale tables the one before it left: all four come
+    back donated, no second copy of a 4.2 GB table, no operation but the
+    kernels makes a scale table."""
+    arr, cache, scale = _ouro_shapes(one_chip)
+    rows = arr((OURO_TABLES, OURO_B, OURO_KV, D), jnp.int8)
+    srows = arr((OURO_TABLES, OURO_B, OURO_KV), jnp.float32)
+    assert fd.append_tables(OURO_TABLES, OURO_B, OURO_KV, 32, D, 1,
+                            128) == 48
+    compiled = jax.jit(fd.append_rows_stacked,
+                       donate_argnums=(0, 1, 5, 6)).lower(
+        cache, cache, rows, rows, arr((OURO_B,), jnp.int32), scale, scale,
+        srows, srows).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 4
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * OURO_TABLES * OURO_B * OURO_KV * OURO_SMAX * D
+    table_bytes = 2 * OURO_TABLES * OURO_B * OURO_KV * OURO_SMAX * 4
+    assert mem.alias_size_in_bytes >= cache_bytes + table_bytes
+    assert mem.temp_size_in_bytes < (64 << 20)
+    made = _made(text, f"{OURO_TABLES},{OURO_B},{OURO_KV},{OURO_SMAX}")
+    assert made and made <= {"parameter", "get-tuple-element"}
+
+
+def _lowered_loop(monkeypatch, sharding, program):
+    """``benchmarks/configs/ouro-2.6b-int8.json`` as its cell runs it: 48
+    layers four times, an int8 cache of 192 tables, 7 slots x 1,536."""
+    import sys
+
+    monkeypatch.setattr(sys.modules[__name__], "SMAX", OURO_SMAX)
+    cfg = _cell_config("ouro-2.6b-int8")
+    return _engine_lowered(monkeypatch, sharding, cfg, OURO_B, jnp.int8,
+                           program)
+
+
+@pytest.mark.parametrize("program,kernels", [("decode block", 5),
+                                             ("chunk 512", 0),
+                                             ("prefill 256", 1)])
+def test_looped_family_streams_its_stack_and_leaves_its_tables(
+        one_chip, monkeypatch, program, kernels):
+    """The decode block holds ONE decode kernel (the scan over passes
+    and the scan over layers are loops, the table index their product)
+    and the write's four calls; a pass is a ``while`` inside a ``while``
+    inside the block's own. No int8 weight stack is copied or staged, no
+    cache table or scale table is copied, selected or scattered: the
+    block's temporaries are megabytes beside 11.5 GB of arguments. The
+    512-token chunk program's are the slot's view (programs.py slices it
+    out: 1.2 GB) and the chunk's rows of 192 tables; the whole engine
+    fits the chip with the pool's one row beside it."""
+    compiled = _lowered_loop(monkeypatch, one_chip, program).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == kernels
+    results = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = ((?:s8|f32)\[[\d,]+\]\S*) ([\w\-]+)\(",
+        text, re.M)
+    assert results                      # the pattern still reads this HLO
+    stack_copies = [r for r in results if r[1] in ("copy", "transpose")
+                    and re.match(r"s8\[48,(2048|5632),", r[0])]
+    staged = [r for r in results if r[1] == "fusion"
+              and re.match(r"s8\[1,(2048|5632),", r[0]) and "S(1)" in r[0]]
+    whole = [r for r in results if r[1] in _TABLE_MOVES - {"fusion"}
+             and re.match(r"(s8|f32)\[192,7,16,1536", r[0])
+             and r[1] != "dynamic-update-slice"]
+    assert not stack_copies
+    assert not staged
+    assert not whole
+    mem = compiled.memory_analysis()
+    # 2.77 GB of weights and 8.72 GB of cache
+    assert 11.3e9 < mem.argument_size_in_bytes < 11.6e9
+    if program == "decode block":
+        assert len(re.findall(r" while\(", text)) == 3
+        assert len(re.findall(r"%flash_decode_stacked[\w.]* = ", text)) == 1
+        assert len(re.findall(r"%append_rows_stacked[\w.]* = ", text)) == 4
+        assert mem.temp_size_in_bytes < (32 << 20)
+    else:
+        # beside the pool's row (1.25 GB) the chip's 17.18 GB hold it
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            + 1.25e9 < 16.2e9

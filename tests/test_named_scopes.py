@@ -174,3 +174,46 @@ def test_a_bucket_that_cannot_pass_index_topk_computes_no_score(
     assert not _scoped(prefill(8), "dsa/select")
     assert _scoped(prefill(8), "dsa/index_proj")
     assert _scoped(prefill(32), "dsa/select")
+
+
+# -- the looped family: the passes, the four norms --------------------------------
+
+@pytest.fixture(scope="module")
+def looped_engine():
+    from gofr_tpu.models import ouro
+
+    cfg = LLAMA_CONFIGS["tiny-loop"]
+    eng = GenerationEngine(cfg, ouro.init(cfg, jax.random.PRNGKey(0)),
+                           slots=2, max_seq=64, prompt_buckets=(8, 16),
+                           decode_block=2, kv_dtype=jnp.int8)
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill", "chunk"])
+@pytest.mark.parametrize("scope", [
+    "embed", "attn_qkv", "attn_out", "mlp", "kv_write", "lm_head",
+    "loop/final_norm", "norm/post_attn", "norm/post_mlp"])
+def test_the_looped_familys_scopes(looped_engine, which, scope):
+    """A device trace tells the pass's end (the final norm between
+    passes) and the two norms inside the residual branches from the
+    blocks every dense family names (benchmarks/metrics reads the
+    kernels by their jitted functions' names)."""
+    assert _scoped(_lowered(looped_engine, which), scope), scope
+
+
+def test_the_looped_familys_decode_attention_is_scoped(looped_engine,
+                                                       monkeypatch):
+    """On the reference path the attention sits under ``attn``; with the
+    kernels on (interpreted here) it is ``attn/flash_decode`` and the
+    write ``kv_write/kv_append``, as in every family that runs them."""
+    from gofr_tpu.models import ouro
+
+    eng = looped_engine
+    assert "decode_attention_appended" in _lowered(eng, "decode")
+    monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
+    text = jax.jit(lambda p, t, c: ouro.decode_step(p, eng.cfg, t, c)).lower(
+        eng.params, jnp.zeros((2,), jnp.int32), eng.cache).as_text(
+        debug_info=True)
+    assert _scoped(text, "attn/flash_decode")
+    assert _scoped(text, "kv_write/kv_append")
