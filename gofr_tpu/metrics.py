@@ -128,15 +128,16 @@ class Manager:
             )
 
     def increment_counter(self, name: str, exemplar: str | None = None,
-                          **labels: str) -> None:
+                          by: float = 1.0, **labels: str) -> None:
         """``exemplar``: optional trace id attached to this series'
         OpenMetrics ``_total`` sample (shed/error counters pass the
-        ambient span so a dashboard count links to an exact trace)."""
+        ambient span so a dashboard count links to an exact trace).
+        ``by``: what one call adds (a count of tokens or positions)."""
         m = self._get(name, "counter")
         self._check_cardinality(m, labels)
         key = _label_key(labels)
         with m.lock:
-            m.series[key] = float(m.series.get(key, 0.0)) + 1.0
+            m.series[key] = float(m.series.get(key, 0.0)) + by
             if exemplar:
                 m.exemplars.setdefault(key, {})[-1] = (
                     str(exemplar), 1.0, time.time())
@@ -506,6 +507,17 @@ def register_framework_metrics(m: Manager) -> None:
                   "mid-chunk dispatches of chunked prefills (each one "
                   "is a bounded slice of a long prompt interleaved "
                   "with decode/admission; serving-scheduler.md)")
+    m.new_counter("app_tpu_prefill_split_total",
+                  "admissions whose prompt ran as two dispatches (a whole "
+                  "bucket, then the rest) instead of one padded bucket, "
+                  "by the engine's measured table (serving-scheduler.md)")
+    m.new_counter("app_tpu_prefill_positions_total",
+                  "positions the prompt programs ran: buckets and chunks "
+                  "as dispatched, padding and overlap counted")
+    m.new_counter("app_tpu_prefill_prompt_tokens_total",
+                  "prompt tokens the prompt programs computed (a prefix "
+                  "hit's restored tokens are not among them); 1 - this / "
+                  "app_tpu_prefill_positions_total is the padded share")
     m.new_counter("app_tpu_brownout_capped_total",
                   "generation requests whose max_new_tokens was capped by "
                   "the brownout band")

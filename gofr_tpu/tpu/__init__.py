@@ -182,6 +182,10 @@ Config keys (reference config style, pkg/gofr/config/config.go:3):
   TPU_BROWNOUT_MAX_NEW token cap applied in brownout (default 32)
   TPU_BATCH_BUCKETS   csv of predict batch buckets (default 1,2,4,8)
   TPU_SEQ_BUCKETS     csv of token-length buckets  (default 32..512)
+                      (a warmed engine bounds a bucket's padding by
+                      the next bucket down plus the rest's, where its
+                      measured table says two dispatches are cheaper:
+                      docs/advanced-guide/serving-scheduler.md)
   TPU_MAX_BATCH_DELAY coalescing window in seconds (default 0.004)
   TPU_SHARDING        "tp=8" / "tp=4,dp=2" mesh axes for sharded serving
                       (axes from gofr_tpu.parallel; weights get

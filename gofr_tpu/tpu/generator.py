@@ -49,7 +49,7 @@ from ..resilience import (SLO_LATENCY, SLO_THROUGHPUT, DecodePipelinePolicy,
 from ..tenancy.fair import WeightedFairLine
 from ..tenancy.registry import current_tenant
 from ..wire import PushStream, burst
-from . import hbm, programs
+from . import hbm, prefill_plan, programs
 from .batcher import pad_bucket
 from .kvcache import HostKV, ShardedHostKV, clamp_restore_len, dense_hostkv
 
@@ -606,6 +606,16 @@ class GenerationEngine:
                 f"max_seq {self.max_seq} must be whole prefill chunks of "
                 f"{self._chunk} for the model family of {cfg.name!r}: its "
                 "last chunk is padded, not overlapped")
+        # a prompt within the chunk budget may run as two dispatches
+        # instead of one padded bucket (_admit_prefill): the seconds a
+        # prompt program takes, measured at the end of a warm-up, and
+        # for each prompt length the bucket its first dispatch runs (0:
+        # one bucket). An engine that was not warmed has neither and
+        # pads as before. Beside them, what the prompt programs ran.
+        self._prefill_costs: dict | None = None
+        self._split_first: list[int] | None = None
+        self._prefill_n = dict.fromkeys(
+            ("admissions", "split", "prompt_tokens", "positions"), 0)
 
         # Paged (block-pool) KV cache: slots share a pool of fixed
         # T-token blocks via a host-owned block table instead of owning
@@ -963,6 +973,9 @@ class GenerationEngine:
         offload = kvc is not None and (kvc.wants_offload or kvc.shares)
         for attr, prog in self._prog.build(needs, offload=offload).items():
             setattr(self, attr, prog)
+        if needs is None:
+            # other programs: what was measured of the old ones is void
+            self._prefill_costs = self._split_first = None
 
     @property
     def _kv_shards(self) -> int:
@@ -1400,6 +1413,7 @@ class GenerationEngine:
                 "queued_throughput":
                     self._pending.qsize_class(SLO_THROUGHPUT),
                 "pipeline": self._pipeline_stats(),
+                "prefill": self._prefill_stats(),
             },
             **self._fam.serving_stats(self.cfg, self.n_slots),
             # which of the sampler's branches the decode blocks asked
@@ -1473,6 +1487,29 @@ class GenerationEngine:
             }
         return out
 
+    def _prefill_stats(self) -> dict:
+        """What the prompt programs ran and how a prompt is cut to them:
+        the measured table (ms, both timings of each program; None on an
+        engine that was not warmed), the plan made from it (for each
+        bucket, the lengths that leave it for two dispatches and into
+        what: prefill_plan.ranges), the admissions that ran a prompt
+        program and those of them that split, and the share of the
+        positions run that held no prompt token."""
+        n = dict(self._prefill_n)
+        costs = self._prefill_costs
+        return {
+            "costs_ms": costs and {
+                prog: {b: [round(t * 1e3, 3) for t in ts]
+                       for b, ts in table.items()}
+                for prog, table in costs.items()},
+            "plan": prefill_plan.ranges(self._split_first or (),
+                                        self.prompt_buckets),
+            **n,
+            "padded_pct": (round(100 * (1 - n["prompt_tokens"]
+                                        / n["positions"]), 2)
+                           if n["positions"] else None),
+        }
+
     def _pipeline_stats(self) -> dict:
         """Decode-pipeline observability (also the deterministic probe
         the depth tests poll): the configured ceiling, the depth the
@@ -1528,9 +1565,51 @@ class GenerationEngine:
                 for attr, shape, run in plan:
                     with acct.call(attr, shape):
                         run()
+                if free is not None and not self._paged \
+                        and self._prefill_costs is None:
+                    self._time_prefills(free, plan)
                 # restore cursors dirtied by the dummy dispatches
                 self.cache = self.cache._replace(
                     lengths=jnp.asarray(cursors))
+
+    def _time_prefills(self, free: int, plan: list[tuple]) -> None:
+        """Measure the seconds each prompt program the warm-up just
+        compiled takes, twice, and make the split plan from them
+        (prefill_plan.first_buckets; _admit_prefill follows it). The
+        plan's own calls pass zeros and a length of 1: every position
+        of a routed model would go to the same experts, and a kernel
+        may skip what lies past the length. These pass varied tokens at
+        the full length, the final chunk behind as many rows as it
+        holds, into the free slot the plan wrote."""
+        i32 = jnp.int32
+        names = {"_prefill_jit": "prefill", "_chunk_final_jit": "chunk_final"}
+        costs: dict[str, dict[int, list[float]]] = {
+            name: {} for name in names.values()}
+        rng = np.random.default_rng(0)
+        for attr, shape, _ in plan:
+            if attr not in names:
+                continue
+            b = shape[1]
+            start = max(0, min(b, self.max_seq - b))
+            tokens = jnp.asarray(
+                rng.integers(1, self.cfg.vocab_size, (1, b)), i32)
+            tail = (jnp.float32(0.0), i32(0), self._key, i32(0), i32(0),
+                    self._adapter1(None))
+            where = ((i32(b), i32(free)) if attr == "_prefill_jit" else
+                     (i32(start), i32(free), i32(start + b), i32(b - 1)))
+            for _ in range(2):
+                t0 = time.perf_counter()
+                # the sync is the measurement: a call's seconds, in set-up
+                *_, self._key, self.cache = jax.block_until_ready(  # noqa: GL101
+                    getattr(self, attr)(self.cache, self.params, tokens,
+                                        *where, *tail))
+                costs[names[attr]].setdefault(b, []).append(
+                    time.perf_counter() - t0)
+        self._prefill_costs = costs
+        self._split_first = prefill_plan.first_buckets(
+            self.prompt_buckets, self._chunk, costs["prefill"],
+            costs["chunk_final"], overlapped=self._rewind,
+            max_seq=self.max_seq)
 
     def _warm_plan(self, free: int | None) -> list[tuple]:
         """What a warm-up calls, in order: (the engine attribute of the
@@ -2099,7 +2178,9 @@ class GenerationEngine:
         return (first sampled token, its logprob).
 
         Prompts within the bucket lattice go through one padded prefill
-        dispatch. Longer prompts run CHUNKED: full chunks of the largest
+        dispatch, or through two (_split_prefill) where the plan made
+        from the engine's measured table says so. Longer prompts run
+        CHUNKED: full chunks of the largest
         bucket size C from position 0, then a final chunk of bucket size
         Sb that ENDS exactly at the prompt end — it may overlap the tail
         of the last full chunk (those positions recompute to identical
@@ -2111,18 +2192,64 @@ class GenerationEngine:
         self._slot_adapter[idx] = req.adapter
         self._touch("adapters")
         pos = self._prefix_restore(idx, req, L, C)
-        if pos == 0 and L <= self._chunk:
-            Sb = pad_bucket(L, self.prompt_buckets)
-            padded = np.zeros((1, Sb), np.int32)
-            padded[0, :L] = req.prompt
-            tok, lp, self._key, self.cache = self._run(
-                self._prefill_jit,
-                self.cache, self.params, jnp.asarray(padded), jnp.int32(L),
-                jnp.int32(idx), jnp.float32(req.temperature),
-                jnp.int32(req.top_k), self._key, jnp.int32(req.seed),
-                jnp.int32(req.pos_base), self._adapter1(req))
-            return self._first_token(tok, lp)
-        return self._chunk_lattice("cache", idx, req, pos)
+        if pos or L > self._chunk:
+            return self._chunk_lattice("cache", idx, req, pos)
+        b1 = self._split_first[L] if self._split_first else 0
+        if b1:
+            return self._split_prefill(idx, req, b1)
+        Sb = pad_bucket(L, self.prompt_buckets)
+        padded = np.zeros((1, Sb), np.int32)
+        padded[0, :L] = req.prompt
+        tok, lp, self._key, self.cache = self._run(
+            self._prefill_jit,
+            self.cache, self.params, jnp.asarray(padded), jnp.int32(L),
+            jnp.int32(idx), jnp.float32(req.temperature),
+            jnp.int32(req.top_k), self._key, jnp.int32(req.seed),
+            jnp.int32(req.pos_base), self._adapter1(req))
+        self._count_prefill(L, Sb)
+        return self._first_token(tok, lp)
+
+    def _split_prefill(self, idx: int, req: _Request,
+                       b1: int) -> tuple[int, float]:
+        """A prompt within the chunk budget as two dispatches, where the
+        measured table says they are cheaper than the one padded bucket
+        (prefill_plan): the whole bucket ``b1`` through the prefill
+        program, whose sampled token is dropped, then the rest through
+        the final-chunk program in the form the lattice's last chunk
+        has (_final_chunk). Back to back: no decode block, admission
+        pass or fetch between them, so this is one admission to
+        everything around it (an in-flight one stays in flight, and
+        cancellation and expiry were looked at before the first)."""
+        tl = self._tl
+        _, _, self._key, self.cache = self._run(
+            self._prefill_jit, self.cache, self.params,
+            jnp.asarray(req.prompt[None, :b1]), jnp.int32(b1),
+            jnp.int32(idx), jnp.float32(0.0), jnp.int32(0), self._key,
+            jnp.int32(0), jnp.int32(0), self._adapter1(req))
+        t0c = time.monotonic() if tl is not None else 0.0
+        tok, lp, Sr = self._final_chunk("cache", idx, req, b1)
+        if tl is not None:
+            tl.chunk(t0c, time.monotonic(), idx, 0, Sr,
+                     req.stream.request_id)
+        self._count_prefill(len(req.prompt), b1 + Sr, split=True)
+        return self._first_token(tok, lp)
+
+    def _count_prefill(self, tokens: int, positions: int,
+                       split: bool = False) -> None:
+        """One admission's prompt programs: the prompt tokens they
+        computed and the positions they ran (padding and an overlap
+        counted), stats()["scheduler"]["prefill"] and three counters."""
+        n = self._prefill_n
+        n["admissions"] += 1
+        n["split"] += split
+        n["prompt_tokens"] += tokens
+        n["positions"] += positions
+        if self.metrics is not None:
+            inc = self.metrics.increment_counter
+            inc("app_tpu_prefill_prompt_tokens_total", by=tokens)
+            inc("app_tpu_prefill_positions_total", by=positions)
+            if split:
+                inc("app_tpu_prefill_split_total")
 
     def _first_token(self, tok, lp) -> tuple[int, float]:
         """Fetch the token an admission's last program sampled. The
@@ -2237,6 +2364,7 @@ class GenerationEngine:
         against scratch row 0 but serve slot ``idx``)."""
         L = len(req.prompt)
         T = self._chunk
+        pos0 = pos
         tslot = slot if track_slot is None else track_slot
         ship_cap = L
         if req.kv_sink is not None:
@@ -2315,6 +2443,19 @@ class GenerationEngine:
             return 0, 0.0
         if self._expire_mid_lattice(req, pos):
             return 0, 0.0
+        if not self._rewind:
+            # the memory as it stands here, at a chunk boundary, is
+            # what a prefix hit can use
+            self._lattice_snapshot(slot, req, pos)
+        tok, lp, Sb = self._final_chunk(attr, slot, req, pos)
+        self._count_prefill(L - pos0, pos - pos0 + Sb)
+        return self._first_token(tok, lp)
+
+    def _final_chunk(self, attr: str, slot: int, req: _Request, pos: int):
+        """Dispatch the final-chunk program for ``req.prompt[pos:]``
+        into row ``slot`` of the cache at ``attr``: the device's
+        (token, logprob) and the bucket it ran."""
+        L = len(req.prompt)
         rem = L - pos
         Sb = pad_bucket(rem, self.prompt_buckets)
         if self._rewind:
@@ -2324,9 +2465,7 @@ class GenerationEngine:
         else:
             # a state cannot be rewound: the final chunk starts where
             # the last one ended and is padded (the family masks what
-            # lies past the sampled position); the memory as it stands
-            # here, at a chunk boundary, is what a prefix hit can use
-            self._lattice_snapshot(slot, req, pos)
+            # lies past the sampled position)
             begin, final = pos, np.zeros((Sb,), np.int32)
             final[:rem] = req.prompt[pos:]
         tok, lp, self._key, new_cache = self._run(
@@ -2337,7 +2476,7 @@ class GenerationEngine:
             jnp.int32(req.top_k), self._key, jnp.int32(req.seed),
             jnp.int32(req.pos_base), self._adapter1(req))
         setattr(self, attr, new_cache)
-        return self._first_token(tok, lp)
+        return tok, lp, Sb
 
     def _expire_mid_lattice(self, req: _Request, pos: int) -> bool:
         """Deadline check between chunk dispatches: a half-prefilled
@@ -2434,6 +2573,7 @@ class GenerationEngine:
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
                 self._key, jnp.int32(req.seed), jnp.int32(req.pos_base),
                 self._adapter1(req))
+            self._count_prefill(L, Sb)
             # the row goes in AFTER the fetch: the block _first_token
             # queues behind the prefill holds this slot inactive at
             # cursor L, and through an installed row its garbage write

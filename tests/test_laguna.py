@@ -14,6 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _prefill_split
+
 from gofr_tpu.models import (LLAMA_CONFIGS, blocks, deepseek_v3 as ds, family,
                              laguna as lg, llama, moe, solar_open2 as so)
 from gofr_tpu.ops import attention, flash_decode
@@ -445,3 +447,14 @@ def test_start_up_from_config_refuses_by_name():
         assert eng.predict("score", [1, 2, 3]).shape == (CFG.vocab_size,)
     finally:
         eng.close()
+
+
+# -- a prompt as two dispatches -------------------------------------------------
+
+@pytest.mark.parametrize("kv_dtype,tol", [(None, F32_TOL)])
+def test_a_split_admission_is_the_one_bucket_admission(params, kv_dtype, tol):
+    """A prompt admitted as a whole bucket and the rest (left-aligned: this
+    family's last chunk) against the same prompt in one padded bucket:
+    the same greedy tokens, logprobs and cache arrays to the chunked
+    tests' tolerance, and the positions counted (tests/_prefill_split.py)."""
+    _prefill_split.check(CFG, params, tol=tol, kv_dtype=kv_dtype)

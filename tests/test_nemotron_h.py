@@ -13,6 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _prefill_split
+
 from gofr_tpu.models import (LLAMA_CONFIGS, family, moe,
                              nemotron_h as nh, solar_open2 as so)
 from gofr_tpu.models.blocks import layer_at
@@ -575,3 +577,17 @@ def test_start_up_from_config_refuses_by_name():
     with pytest.raises(ValueError, match="whole prefill chunks"):
         GenerationEngine(CFG, nh.init(CFG, jax.random.PRNGKey(1)), slots=2,
                          max_seq=72, prompt_buckets=(16, 32))
+
+
+# -- a prompt as two dispatches -------------------------------------------------
+
+@pytest.mark.parametrize("kv_dtype,tol", [(None, F32_TOL), (jnp.int8, 0.05)])
+def test_a_split_admission_is_the_one_bucket_admission(params, kv_dtype, tol):
+    """A prompt admitted as a whole bucket and the rest (left-aligned: this
+    family's last chunk) against the same prompt in one padded bucket:
+    the same greedy tokens, logprobs and cache arrays to the chunked
+    tests' tolerance, and the positions counted (tests/_prefill_split.py).
+    With int8 rows the rest attends over the first part's rows as the
+    cache holds them, quantized, which is what decode reads: the bound
+    is the int8 engine test's."""
+    _prefill_split.check(CFG, params, tol=tol, kv_dtype=kv_dtype)

@@ -14,6 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _prefill_split
+
 from gofr_tpu.models import LLAMA_CONFIGS, ModelConfig, family, llama, ouro
 from gofr_tpu.ops.quant import QuantizedLinear
 from gofr_tpu.tpu import GenerationEngine
@@ -415,3 +417,17 @@ def test_the_fingerprint_tells_a_looped_model_from_a_one_pass_one():
     tiny = LLAMA_CONFIGS["tiny"]
     assert model_fingerprint(tiny) == model_fingerprint(
         tiny.with_(norm_eps=1e-6))
+
+
+# -- a prompt as two dispatches -------------------------------------------------
+
+@pytest.mark.parametrize("kv_dtype,tol", [(None, F32_TOL), (jnp.int8, 0.05)])
+def test_a_split_admission_is_the_one_bucket_admission(params, kv_dtype, tol):
+    """A prompt admitted as a whole bucket and the rest (overlapped: this
+    family's last chunk) against the same prompt in one padded bucket:
+    the same greedy tokens, logprobs and cache arrays to the chunked
+    tests' tolerance, and the positions counted (tests/_prefill_split.py).
+    With int8 rows the rest attends over the first part's rows as the
+    cache holds them, quantized, which is what decode reads: the bound
+    is the int8 engine test's."""
+    _prefill_split.check(CFG, params, tol=tol, kv_dtype=kv_dtype)
