@@ -646,6 +646,19 @@ def register_framework_metrics(m: Manager) -> None:
     m.new_gauge("app_tpu_pipeline_depth",
                 "fused decode blocks in flight on the device stream "
                 "after the last pipeline top-up")
+    # the stall watchdog's (observe/stall.py): written from its own
+    # thread, never the loop's. The two stall counters carry a sample of
+    # 0 a phase from the engine's start; the loop's CPU has no sample
+    # where /proc cannot be read
+    m.new_counter("app_tpu_loop_stall_total",
+                  "phases of the generation loop (other than park) that "
+                  "outlasted TPU_STALL_MS, by phase; each left a record "
+                  "behind /debug/stalls and a WARN log line")
+    m.new_counter("app_tpu_loop_stall_seconds_total",
+                  "seconds the generation loop stood in such phases")
+    m.new_counter("app_tpu_loop_cpu_seconds_total",
+                  "CPU seconds the generation thread used (utime + stime "
+                  "of its /proc stat, read every 50 ms)")
     m.new_gauge("app_tpu_startup_seconds",
                 "seconds the engine's start-up spent in each phase "
                 "(configure / weights / allocate / programs / warmup), "

@@ -11,6 +11,8 @@ right now?" without attaching a debugger:
                               ?n= ?event= ?request_id=)
   /debug/vars                 config + device topology + engine/batcher
                               state as JSON (expvar style)
+  /debug/stalls               the last 32 stall records of the generation
+                              loop (observe/stall.py), newest last
   /debug/timeline?fleet=1     clock-aligned merge of every reachable
                               peer's timeline (observe/fleet.py)
   /debug/request?trace_id=..  one request's cross-process wide-event
@@ -130,6 +132,9 @@ def install_debug_routes(router, app) -> None:
             '<li><a href="/debug/timeline?last_ms=2000">'
             "/debug/timeline</a> — serving timeline "
             "(Chrome-trace JSON; load in Perfetto)</li>"
+            '<li><a href="/debug/stalls">/debug/stalls</a> — what the '
+            "device queue and the process's threads did while the "
+            "generation loop stood still</li>"
             '<li><a href="/debug/timeline?fleet=1">'
             "/debug/timeline?fleet=1</a> — clock-aligned merge of "
             "every reachable peer's timeline</li>"
@@ -239,6 +244,16 @@ def install_debug_routes(router, app) -> None:
         if req.param("fleet"):
             return _json(w, _fleet_trace(last_ms))
         _json(w, tl.chrome_trace(last_ms=last_ms))
+
+    def stalls_page(req, w) -> None:
+        """What the stall watchdog wrote down (observe/stall.py): the
+        last records, whole, and the running totals."""
+        gen = getattr(getattr(app.container, "tpu", None), "generator", None)
+        watch = getattr(gen, "stall_watch", None)
+        if watch is None:
+            return _json(w, {"enabled": False})
+        _json(w, {"enabled": True, **watch.stats(),
+                  "records": watch.records()})
 
     def _fleet_timeout() -> float:
         try:
@@ -424,6 +439,7 @@ def install_debug_routes(router, app) -> None:
     router.add("GET", "/debug/requests", requests_page)
     router.add("GET", "/debug/events", events_page)
     router.add("GET", "/debug/timeline", timeline_page)
+    router.add("GET", "/debug/stalls", stalls_page)
     router.add("GET", "/debug/request", request_page)
     router.add("GET", "/debug/vars", vars_page)
     router.add("GET", "/debug/cache", cache_page)

@@ -29,7 +29,11 @@ vLLM/SGLang-class schedulers became debuggable with:
     stream when its first message reaches the socket, and a ``compile``
     mark per backend compile (``compile_cache.py``). Before all of that
     the engine accounts for its start-up (``startup.py``): one
-    ``startup`` interval a phase and a warm-up call.
+    ``startup`` interval a phase and a warm-up call. The stall
+    watchdog (``stall.py``) writes one ``stall`` per phase that outlasts
+    its threshold, with the whole record, and once a second a ``host``
+    counter sample: the CPU the loop's thread used, and how late the
+    watchdog's own ticks woke.
   - ``chrome_trace()`` renders the ring as Chrome-trace JSON ("JSON
     Array Format" with ``traceEvents``) that Perfetto / chrome://tracing
     load directly: one track per decode slot, a scheduler track for
@@ -231,6 +235,22 @@ class Timeline:
         program and shape; for an ``allocate`` the buffer's tag)."""
         self.append("startup", t0, t1 - t0, phase, detail)
 
+    def stall(self, t0: float, t1: float, phase: str, site: str,
+              cause: str, record_id: int, record: dict) -> None:
+        """One phase of the generation thread that outlasted the stall
+        threshold (observe/stall.py): the ``loop`` event of the same
+        interval says that it happened, this one what every other party
+        did meanwhile (``record``: the watchdog's record, whole)."""
+        self.append("stall", t0, t1 - t0, phase, site, cause, record_id,
+                    record)
+
+    def host(self, ts: float, loop_cpu, late) -> None:
+        """The host's side over the last second, each in seconds a
+        second: CPU the loop's thread used (None where /proc did not
+        say), and how late the stall watchdog's 50 ms ticks woke in sum
+        (a thread of this process that asked for a core on time)."""
+        self.append("host", ts, None, loop_cpu, late)
+
     def admit(self, slot: int, slo_class: str, wait_s: float,
               request_id, trace_id: str = "") -> None:
         self.append("admit", time.monotonic(), None, slot, slo_class,
@@ -273,6 +293,25 @@ class Timeline:
             cut = time.monotonic() - last_ms / 1e3
             snap = [e for e in snap if e[1] >= cut]
         return snap
+
+    def last(self, kind: str, before: float) -> "tuple | None":
+        """The newest event of ``kind`` that began before ``before``:
+        one pass over the ring, no sort."""
+        best = None
+        for e in list(self._buf):
+            if e is not None and e[3] == kind and e[1] < before \
+                    and (best is None or e[1] > best[1]):
+                best = e
+        return best
+
+    def since(self, kind: str, t: float) -> list[tuple]:
+        """The events of ``kind`` that ended at ``t`` or later, oldest
+        first: one pass over the ring."""
+        out = [e for e in list(self._buf)
+               if e is not None and e[3] == kind
+               and e[1] + (e[2] or 0.0) >= t]
+        out.sort(key=lambda e: e[1])
+        return out
 
     def stats(self) -> dict:
         # itertools.count has no non-consuming peek: derive the total
@@ -422,6 +461,18 @@ class Timeline:
                              "name": f"loop:{a}", "cat": "loop", "ts": us,
                              "dur": max(dur, 0.0) * 1e6,
                              "args": {"n": b, "seq": seq}})
+            elif kind == "stall":
+                body.append({"ph": "X", "pid": 1, "tid": _TID_LOOP,
+                             "name": f"stall:{a} {c}", "cat": "stall",
+                             "ts": us, "dur": max(dur, 0.0) * 1e6,
+                             "args": {"phase": a, "site": b, "cause": c,
+                                      "id": d, "seq": seq,
+                                      "record": more[0] if more else None}})
+            elif kind == "host":
+                body.append({"ph": "C", "pid": 1, "name": "host", "ts": us,
+                             "args": {k: v for k, v in (
+                                 ("loop_cpu", a), ("late", b))
+                                 if v is not None}})
             elif kind == "store":
                 body.append({"ph": "X", "pid": 1, "tid": slot_tid(a),
                              "name": f"store {c} ({b} tok)", "cat": "store",

@@ -66,6 +66,13 @@ Config keys (reference config style, pkg/gofr/config/config.go:3):
                       2 — decode blocks dispatch async and new requests
                       are admitted while one runs, their prefill
                       queueing behind it on the device stream)
+  TPU_STALL_MS        a phase of the generation loop other than its idle
+                      park that lasts this long (default 1000) leaves a
+                      stall record: the queue's readiness seen from
+                      outside the blocked thread, per-thread CPU,
+                      how late the watchdog's own ticks woke, stacks, a
+                      cause (observe/stall.py, /debug/stalls; no
+                      watchdog with TPU_TIMELINE=0)
   TPU_PREFILL_CHUNK   chunked-prefill interleave budget in tokens
                       (docs/advanced-guide/serving-scheduler.md):
                       prompts longer than the budget admit as bounded
@@ -470,7 +477,8 @@ def new_engine_from_config(cfg, logger=None, metrics=None,
             lora_rank=cfg.get_int("TPU_LORA_RANK", 16),
             paged_blocks=cfg.get_int("TPU_PAGED_BLOCKS", 0),
             paged_block_size=cfg.get_int("TPU_PAGED_BLOCK", 128),
-            serving_role=(cfg.get("TPU_SERVING_ROLE") or "").strip().lower())
+            serving_role=(cfg.get("TPU_SERVING_ROLE") or "").strip().lower(),
+            stall_ms=cfg.get_float("TPU_STALL_MS", 1000.0))
 
         # scoring program: next-token logits at the prompt end (the
         # non-streaming sibling of generate, e.g. for classification

@@ -43,6 +43,7 @@ from .. import chaos, compile_cache
 from ..errors import DeadlineExceeded, UnsupportedOptions
 from ..models import family, llama
 from ..models.common import ModelConfig
+from ..observe.stall import StallWatch
 from ..observe.startup import StartupAccount
 from ..resilience import (SLO_LATENCY, SLO_THROUGHPUT, DecodePipelinePolicy,
                           current_deadline, current_slo_class)
@@ -280,12 +281,16 @@ class _Inflight:
     """A dispatched-but-unreaped device tick. ``arrays``: the dispatch's
     output futures (readiness probe); ``reap``: fetch results and
     deliver tokens — must run under the engine's device lock (through
-    ``GenerationEngine._reap``, which accounts the loop's time)."""
-    __slots__ = ("arrays", "reap")
+    ``GenerationEngine._reap``, which accounts the loop's time).
+    ``kind`` and ``t0`` (what was dispatched, and when) are for the stall
+    watchdog, which looks at the pipe from outside the loop's thread."""
+    __slots__ = ("arrays", "reap", "kind", "t0")
 
-    def __init__(self, arrays, reap):
+    def __init__(self, arrays, reap, kind: str, t0: float):
         self.arrays = arrays
         self.reap = reap
+        self.kind = kind
+        self.t0 = t0
 
 
 class _LoopAccount:
@@ -433,7 +438,8 @@ class GenerationEngine:
                  prefill_chunk: int | None = None,
                  slo_throughput_share: float = 0.25,
                  slo_latency_slots: int = 1,
-                 serving_role: str | None = None):
+                 serving_role: str | None = None,
+                 stall_ms: float = 1000.0):
         self.cfg = cfg
         # the model family: its programs and its cache row layout. This
         # is the one place the engine learns it; every call below goes
@@ -953,6 +959,16 @@ class GenerationEngine:
         self._thread = threading.Thread(target=self._loop, name="gofr-tpu-gen",
                                         daemon=True)
         self._thread.start()
+        # the one reader of the loop's account besides the loop: what
+        # the queue, the threads and the machine did while a phase
+        # lasted (observe/stall.py); with the timeline, or not at all
+        self.stall_watch = None
+        if self._tl is not None:
+            self.stall_watch = StallWatch(
+                self._acct, self._pipe, self._thread, self._tl,
+                metrics=metrics, logger=logger,
+                threshold_s=stall_ms / 1e3)
+            self.stall_watch.start()
 
     def install_tenancy(self, plane) -> None:
         """Attach the multi-tenant serving plane (tenancy.TenantPlane).
@@ -1414,6 +1430,9 @@ class GenerationEngine:
                     self._pending.qsize_class(SLO_THROUGHPUT),
                 "pipeline": self._pipeline_stats(),
                 "prefill": self._prefill_stats(),
+                # phases that outlasted TPU_STALL_MS (observe/stall.py)
+                **({} if self.stall_watch is None
+                   else {"stalls": self.stall_watch.stats()}),
             },
             **self._fam.serving_stats(self.cfg, self.n_slots),
             # which of the sampler's branches the decode blocks asked
@@ -1837,6 +1856,8 @@ class GenerationEngine:
             self._closed = True
         self._work.set()
         self._thread.join(timeout=10.0)
+        if self.stall_watch is not None:
+            self.stall_watch.stop()
         # the registry must not keep claiming bytes for a closed engine
         # (hbmwatch reconciles accounted vs live bytes; the buffers
         # themselves die with this instance's last reference)
@@ -4190,9 +4211,10 @@ class GenerationEngine:
         # was re-admitted mid-flight must not receive them.
         snap_active = self._active.copy()
         snap_reqs = [s.request for s in self._slots]
+        t_dispatch = time.monotonic()
         return _Inflight((toks, lps, emit), functools.partial(
             self._verify_reap, toks, lps, emit, snap_active, snap_reqs,
-            time.monotonic()))
+            t_dispatch), "verify", t_dispatch)
 
     # invoked through _Inflight.reap, always under the engine's device
     # lock (see _loop) — the partial hides that from static call-graph
@@ -4307,7 +4329,8 @@ class GenerationEngine:
         snap_reqs = [s.request for s in self._slots]
         return _Inflight((toks, lps, emitted), functools.partial(
             self._decode_reap, toks, lps, emitted, snap_active, snap_reqs,
-            t_dispatch, live, fetched, counters, ring, sampled))
+            t_dispatch, live, fetched, counters, ring, sampled),
+            "decode", t_dispatch)
 
     # invoked through _Inflight.reap, always under the engine's device
     # lock (see _loop)  # gl: holds self._device_lock

@@ -392,10 +392,11 @@ def test_append_cost_is_sub_microsecond_scale():
 # -- the generation thread's own account of its time --------------------------
 
 @pytest.mark.parametrize("kind", ["loop", "store", "first", "compile", "gap",
-                                  "decode"])
+                                  "decode", "stall", "host"])
 def test_loop_account_events_round_trip(kind):
     """loop, store, first and compile events (and the gap's slack, the
-    decode block's live tokens) come back from events() as written and
+    decode block's live tokens, the watchdog's stall record and its
+    once-a-second host sample) come back from events() as written and
     render on their tracks in chrome_trace()."""
     tl = Timeline(capacity=64)
     t = 200.0
@@ -406,6 +407,14 @@ def test_loop_account_events_round_trip(kind):
     tl.compile(1.25)
     tl.dispatch_gap(t + 0.010, t + 0.012, 0.0005)
     tl.decode_block(t + 0.020, t + 0.030, (0, 1), 4, 1234)
+    record = {"id": 3, "phase": "fetch", "cause": "fetch_late",
+              "queue": [{"kind": "decode", "ready_after": 0.2}]}
+    tl.stall(t + 0.040, t + 2.040, "fetch", "generator.py:_first_token",
+             "fetch_late", 3, record)
+    tl.host(t + 1.0, 0.25, 0.001)
+    tl.host(t + 2.0, None, 0.0)
+    assert tl.last("gap", before=t + 1.0)[1] == t + 0.010
+    assert tl.last("gap", before=t) is None
     ev = next(e for e in tl.events() if e[3] == kind)
     tr = tl.chrome_trace()
     json.dumps(tr)
@@ -441,6 +450,24 @@ def test_loop_account_events_round_trip(kind):
         row = next(e for e in rows if e.get("cat") == "compile")
         assert tracks[row["tid"]] == "host loop" and row["ph"] == "i"
         assert row["args"]["seconds"] == 1.25
+    elif kind == "stall":
+        assert ev[1:3] == (t + 0.040, pytest.approx(2.0))
+        assert ev[4:8] == ("fetch", "generator.py:_first_token",
+                           "fetch_late", 3)
+        assert ev[8] is record
+        row = next(e for e in rows if e.get("cat") == "stall")
+        assert row["ph"] == "X" and tracks[row["tid"]] == "host loop"
+        assert row["name"] == "stall:fetch fetch_late"
+        assert row["dur"] == pytest.approx(2.0e6)
+        assert row["args"]["record"] == record
+        assert row["args"]["site"] == "generator.py:_first_token"
+    elif kind == "host":
+        assert ev[2] is None and ev[4:6] == (0.25, 0.001)
+        first, second = (e for e in rows if e.get("name") == "host")
+        assert first["ph"] == "C"   # a counter track; what /proc did not
+        # say is left out, not drawn at zero
+        assert first["args"] == {"loop_cpu": 0.25, "late": 0.001}
+        assert second["args"] == {"late": 0.0}
     elif kind == "gap":
         assert ev[4] == 0.0005
         row = next(e for e in rows if e.get("cat") == "gap")

@@ -161,6 +161,13 @@ def _validate_trace(trace: dict, served) -> list[str]:
     for tid, ts in by_tid.items():
         if ts != sorted(ts):
             failures.append(f"track {tid} slices out of order")
+    # a stall (observe/stall.py) is a slice over the loop phase it
+    # describes, with the watchdog's record as its args
+    for e in cats.get("stall", []):
+        if e.get("tid") != 3 or not (e["args"].get("record") or {}).get(
+                "cause"):
+            failures.append(f"stall slice off the host loop track or "
+                            f"without its record: {e.get('name')}")
     names = {e["args"]["name"] for e in ev
              if e.get("ph") == "M" and e["name"] == "thread_name"}
     if "slot 0" not in names:
@@ -216,6 +223,13 @@ def run_bench(smoke: bool) -> dict:
         trace = obs.timeline.chrome_trace()
         art["events_recorded"] = obs.timeline.stats()["total_recorded"]
         art["trace_events"] = len(trace.get("traceEvents", []))
+        # the watchdog's two kinds: stalls (a compile of a second counts)
+        # and the once-a-second host counter samples
+        art["stall_slices"] = sum(1 for e in trace["traceEvents"]
+                                  if e.get("cat") == "stall")
+        art["host_samples"] = sum(1 for e in trace["traceEvents"]
+                                  if e.get("ph") == "C"
+                                  and e.get("name") == "host")
         failures += _validate_trace(trace, served)
         cadence_on = _decode_cadence_ms(eng_on, 64 if smoke else 256)
     finally:
