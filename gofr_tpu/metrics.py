@@ -518,6 +518,14 @@ def register_framework_metrics(m: Manager) -> None:
                   "prompt tokens the prompt programs computed (a prefix "
                   "hit's restored tokens are not among them); 1 - this / "
                   "app_tpu_prefill_positions_total is the padded share")
+    m.new_counter("app_tpu_chunk_rows_walked_total",
+                  "cached rows the chunk programs' attention fetched: the "
+                  "blocks under each chunk's start (a chunk at position 0 "
+                  "fetches none; serving-scheduler.md)")
+    m.new_counter("app_tpu_chunk_rows_reserved_total",
+                  "rows the slots of those chunk dispatches reserve "
+                  "(max_seq a dispatch); app_tpu_chunk_rows_walked_total "
+                  "/ this is the share of a slot a chunk reads")
     m.new_counter("app_tpu_brownout_capped_total",
                   "generation requests whose max_new_tokens was capped by "
                   "the brownout band")

@@ -44,6 +44,8 @@ def family(cfg: ModelConfig):
     ``write_kv``, ``prefill_chunk``, ``decode_step``, ``decode_kv_block``,
     ``kv_layout``, ``kv_tables`` (the row tables a cached token has:
     the depth, but for a stack that is run several times),
+    ``chunk_block`` (the block of cached rows a chunk's attention walks
+    up to its start; 0 where it walks under no cursor),
     ``unsupported_options``, ``serving_stats``, ``forward``,
     and ``RECOMPUTABLE``: whether a cached position can be computed
     again and give the same memory (rows can; a recurrent or

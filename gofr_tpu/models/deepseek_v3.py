@@ -81,6 +81,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int | None = None,
 kv_tables = llama.kv_tables      # one table a layer (models.family)
 
 
+def chunk_block(cfg: ModelConfig, max_seq: int) -> int:
+    """``llama.chunk_block``, of latent rows (``mla.chunk_attention``)."""
+    return mla.chunk_block(max_seq)
+
+
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     """(heads, values a head) of a cached token, for the prefix index's
     shape contract: one shared row."""

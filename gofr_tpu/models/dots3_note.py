@@ -161,6 +161,13 @@ def get_rope_tables(cfg: ModelConfig, max_seq: int) -> dict:
 kv_tables = llama.kv_tables      # one table a layer (models.family)
 
 
+def chunk_block(cfg: ModelConfig, max_seq: int) -> int:
+    """0: a chunk walks its rows under a mask a query (what the
+    selection kept, what a ring holds), not under the one cursor
+    ``llama.chunk_block`` counts by."""
+    return 0
+
+
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     """(heads, values a head) of a cached token, for the prefix index's
     shape contract: one shared row."""

@@ -64,6 +64,7 @@ def get_rope_tables(cfg: ModelConfig, max_seq: int):
 
 
 kv_tables = llama.kv_tables      # one table a layer (models.family)
+chunk_block = llama.chunk_block  # a cursor walk (models.family)
 
 
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:

@@ -128,6 +128,13 @@ def get_rope_tables(cfg: ModelConfig, max_seq: int) -> dict:
 kv_tables = llama.kv_tables      # one table a layer (models.family)
 
 
+def chunk_block(cfg: ModelConfig, max_seq: int) -> int:
+    """``llama.chunk_block`` where a full layer walks a slot's rows; a
+    ring is read whole, under no cursor."""
+    return llama.chunk_block(cfg, max_seq) if "full" in cfg.layer_pattern \
+        else 0
+
+
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     return cfg.n_kv_heads, cfg.head_dim
 

@@ -93,6 +93,7 @@ def paired(cfg: ModelConfig) -> bool:
 
 
 kv_tables = llama.kv_tables      # one table a layer (models.family)
+chunk_block = llama.chunk_block  # a cursor walk (models.family)
 
 
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:

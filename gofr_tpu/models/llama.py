@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import (causal_attention, chunk_attention,
+                             chunk_block as walk_block,
                              decode_attention_appended,
                              window_attention_appended)
 from ..ops.norms import rms_norm
@@ -107,6 +108,14 @@ def kv_tables(cfg: ModelConfig) -> int:
     device ([tables, plen, KV, hd]): one a layer, in every family whose
     token passes each layer once."""
     return cfg.n_layers
+
+
+def chunk_block(cfg: ModelConfig, max_seq: int) -> int:
+    """The block of cached rows a chunk program's attention walks up to
+    the chunk's start (``ops.attention.chunk_attention``); 0 in a family
+    whose chunk program walks under no cursor. What the engine counts a
+    chunk dispatch's fetched rows in."""
+    return walk_block(max_seq)
 
 
 def decode_kv_block(cfg: ModelConfig, cache: KVCache, mesh=None):

@@ -60,7 +60,8 @@ from .common import ModelConfig, dense_init
 # the cache (rows, state, tail), and the entry points of ``models.family``
 # that follow from it alone, handed on (``x as x``) as ``hybrid_cache``
 # has them
-from .hybrid_cache import (HybridCache, decode_attend, decode_kv_block,
+from .hybrid_cache import (HybridCache, chunk_block as chunk_block,
+                           decode_attend, decode_kv_block,
                            get_rope_tables as get_rope_tables,
                            kv_layout as kv_layout, kv_tables as kv_tables,
                            unsupported_options as unsupported_options,

@@ -73,6 +73,7 @@ BRANCH_GAIN = 0.125
 get_rope_tables = llama.get_rope_tables
 kv_layout = llama.kv_layout
 decode_kv_block = llama.decode_kv_block
+chunk_block = llama.chunk_block  # a cursor walk (models.family)
 
 
 def kv_tables(cfg: ModelConfig) -> int:

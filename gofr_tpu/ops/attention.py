@@ -234,6 +234,18 @@ def window_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
     return out.reshape(b, w, h, d)
 
 
+# cached rows a trip of ``chunk_attention``'s walk scores
+_CHUNK_BLOCK = 256
+
+
+def chunk_block(smax: int) -> int:
+    """The block ``chunk_attention`` walks a slot of ``smax`` rows in:
+    ``_CHUNK_BLOCK``, fitted to a slot it does not divide."""
+    from .flash import fit_block
+
+    return fit_block(smax, _CHUNK_BLOCK)
+
+
 @jax.named_scope("chunk_attention")
 def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                     k_new: jnp.ndarray, v_new: jnp.ndarray,
@@ -247,10 +259,18 @@ def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     fixed-size chunks so arbitrary prompt lengths serve from a small
     lattice of compiled shapes.
 
+    One softmax over both, taken as a running one: it starts from the
+    chunk's own tokens and the cached rows are walked a block of
+    ``_CHUNK_BLOCK`` at a time up to ``start`` (a traced scalar), so a
+    reserved row costs nothing (a chunk at position 0 fetches none) and
+    the float32 scores held at once are heads x C x block, not heads x
+    C x Smax (168 MB a layer at Mistral's 32 x 512 x 2,560).
+
     q: [B, C, H, D]; k_cache/v_cache: [B, KV, Smax, D];
     k_new/v_new: [B, C, KV, D]; start: scalar int32.
     ``k_scale``/``v_scale`` [B, KV, Smax]: per-vector scales for int8
-    caches (see decode_attention_appended — same fused-dequant scheme).
+    caches (see decode_attention_appended — same fused-dequant scheme,
+    a block at a time).
     Trailing padding inside the chunk is harmless: causality means padded
     positions are never attended BY valid ones. ``scale``: the softmax
     scale where it is not D^-1/2 (paired heads). Returns [B, C, H, D].
@@ -258,29 +278,45 @@ def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     b, c, h, d = q.shape
     n_kv, smax = k_cache.shape[1], k_cache.shape[2]
     scale = scale or d ** -0.5
+    block = chunk_block(smax)
+    vdt = q.dtype if v_scale is not None else v_cache.dtype
 
     qg = _repeat_kv_shape(q * scale, n_kv)  # [B,C,KV,G,D]
-    scores_c = jnp.einsum("bskgd,bktd->bkgst", qg, k_cache.astype(qg.dtype),
-                          preferred_element_type=jnp.float32)  # [B,KV,G,C,Smax]
-    if k_scale is not None:
-        scores_c = scores_c * k_scale[:, :, None, None, :]
-    in_prefix = jnp.arange(smax)[None, :] < start  # [1,Smax]
-    scores_c = jnp.where(in_prefix[None, None, None], scores_c, NEG_INF)
     scores_n = jnp.einsum("bskgd,btkd->bkgst", qg, k_new,
                           preferred_element_type=jnp.float32)  # [B,KV,G,C,C]
     causal = jnp.tril(jnp.ones((c, c), dtype=bool))
     scores_n = jnp.where(causal[None, None, None], scores_n, NEG_INF)
-    probs = jax.nn.softmax(
-        jnp.concatenate([scores_c, scores_n], axis=-1), axis=-1)
-    probs_c = probs[..., :smax]
-    if v_scale is not None:
-        probs_c = probs_c * v_scale[:, :, None, None, :]
-    vdt = q.dtype if v_scale is not None else v_cache.dtype
-    out = (jnp.einsum("bkgst,bktd->bskgd",
-                      probs_c.astype(vdt), v_cache.astype(vdt))
-           + jnp.einsum("bkgst,btkd->bskgd",
-                        probs[..., smax:].astype(v_new.dtype), v_new))
-    return out.reshape(b, c, h, d)
+    m = jnp.max(scores_n, -1, keepdims=True)                 # [B,KV,G,C,1]
+    p = jnp.exp(scores_n - m)
+    acc = jnp.einsum("bkgst,btkd->bkgsd", p.astype(v_new.dtype), v_new,
+                     preferred_element_type=jnp.float32)
+
+    def fold(j, carry):
+        m, l, acc = carry
+
+        def tile(x):
+            return jax.lax.dynamic_slice_in_dim(x, j * block, block, 2)
+
+        s = jnp.einsum("bskgd,bktd->bkgst", qg, tile(k_cache).astype(qg.dtype),
+                       preferred_element_type=jnp.float32)  # [B,KV,G,C,block]
+        if k_scale is not None:
+            s = s * tile(k_scale)[:, :, None, None, :]
+        s = jnp.where(j * block + jnp.arange(block) < start, s, NEG_INF)
+        m_next = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
+        corr, p = jnp.exp(m - m_next), jnp.exp(s - m_next)
+        l = l * corr + jnp.sum(p, -1, keepdims=True)
+        if v_scale is not None:
+            p = p * tile(v_scale)[:, :, None, None, :]
+        acc = acc * corr + jnp.einsum(
+            "bkgst,bktd->bkgsd", p.astype(vdt), tile(v_cache).astype(vdt),
+            preferred_element_type=jnp.float32)
+        return m_next, l, acc
+
+    _, l, acc = jax.lax.fori_loop(
+        0, (start + block - 1) // block, fold,
+        (m, jnp.sum(p, -1, keepdims=True), acc))
+    out = (acc / l).astype(jnp.result_type(vdt, v_new.dtype))
+    return jnp.moveaxis(out, 3, 1).reshape(b, c, h, d)
 
 
 def ring_held(rows: int, end) -> jnp.ndarray:

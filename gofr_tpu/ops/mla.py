@@ -14,7 +14,9 @@ Three shapes of it, all float32 scores and softmax:
 ``prefill_attention``  expanded, causal, a whole prompt (keys and values
     a head materialised over the prompt only): the reference form.
 ``chunk_attention``    a chunk attends absorbed to the rows cached
-    before it and expanded to its own tokens, one softmax over both.
+    before it and expanded to its own tokens, one softmax over both,
+    run from the chunk's tokens over the blocks of rows under its
+    start (``chunk_attention_kept`` is the walk; a mask a query there).
 ``decode_attention``   one token a slot, absorbed, the appended row
     riding beside the cache. On a TPU, for shapes the kernel takes
     (``decode_block``), the Pallas kernel below is handed the whole
@@ -94,12 +96,21 @@ def prefill_attention(q, k_nope, k_pe, v, mask=None, keep=None):
 _CHUNK_BLOCK = 512
 
 
+def chunk_block(rows: int) -> int:
+    """The block ``chunk_attention_kept`` walks ``rows`` cached rows in:
+    ``_CHUNK_BLOCK``, fitted to a table it does not divide."""
+    from .flash import fit_block
+
+    return fit_block(rows, _CHUNK_BLOCK)
+
+
 @jax.named_scope("mla/chunk_attn_kept")
 def chunk_attention_kept(q_cat, q, rows, k_nope, k_pe, v, rank: int,
                          keep_cache, keep_new, live):
     """``chunk_attention`` with a mask a query in place of the cursor:
-    keep_cache [B or 1, C, T] over the cached rows ``rows`` [B, T, width]
-    (a ring's rows in the order they lie, or a slot's rows from 0),
+    keep_cache [B or 1, C or 1, T] over the cached rows ``rows``
+    [B, T, width] (a ring's rows in the order they lie, or a slot's
+    rows from 0),
     keep_new [B or 1, C, C] over the chunk's own tokens (the causal rule
     included). ``live`` (a traced scalar): the leading rows of ``rows``
     that ``keep_cache`` can keep, the chunk's start or what of it a ring
@@ -109,10 +120,8 @@ def chunk_attention_kept(q_cat, q, rows, k_nope, k_pe, v, rank: int,
     scores held at once are heads x C x block (all T of them are 1 GB a
     layer at 128 x 512 x 4,096). Returns (o_lat [B, C, H, rank] float32,
     o_new [B, C, H, dv]) as ``chunk_attention`` does."""
-    from .flash import fit_block
-
     t, dn = rows.shape[1], k_nope.shape[-1]
-    block = fit_block(t, _CHUNK_BLOCK)
+    block = chunk_block(t)
     rows = rows.astype(q_cat.dtype)
     s_new = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :dn], k_nope,
                         preferred_element_type=jnp.float32)
@@ -153,7 +162,9 @@ def chunk_attention_kept(q_cat, q, rows, k_nope, k_pe, v, rank: int,
 @jax.named_scope("mla/chunk_attn")
 def chunk_attention(q_cat, q, rows, start, k_nope, k_pe, v, rank: int):
     """A chunk of C tokens at [start, start + C): absorbed over the rows
-    cached before it, expanded and causal within itself.
+    cached before it, expanded and causal within itself; one softmax
+    over both, by ``chunk_attention_kept``'s walk with the cursor as its
+    mask, so that the blocks under ``start`` are fetched and no others.
 
     q_cat [B, C, H, width]: [q_abs | q_pe], scaled; q [B, C, H, dn + dr],
     scaled; rows [B, Smax, width]; k_nope/k_pe/v: the chunk's own, as in
@@ -161,25 +172,10 @@ def chunk_attention(q_cat, q, rows, start, k_nope, k_pe, v, rank: int):
     cached rows' part, still latent; o_new [B, C, H, dv]: the chunk's
     part). The caller adds ``o_lat . W_UV`` and ``o_new``."""
     c = q.shape[1]
-    smax = rows.shape[1]
-    dn = k_nope.shape[-1]
-    rows = rows.astype(q_cat.dtype)
-    s_cache = jnp.einsum("bqhw,btw->bhqt", q_cat, rows,
-                         preferred_element_type=jnp.float32)
-    s_cache = jnp.where((jnp.arange(smax) < start)[None, None, None],
-                        s_cache, NEG_INF)
-    s_new = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :dn], k_nope,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bqhd,bkd->bhqk", q[..., dn:], k_pe,
-                          preferred_element_type=jnp.float32))
-    s_new = jnp.where(jnp.tril(jnp.ones((c, c), bool))[None, None], s_new,
-                      NEG_INF)
-    probs = jax.nn.softmax(jnp.concatenate([s_cache, s_new], -1), axis=-1)
-    o_lat = jnp.einsum("bhqt,btr->bqhr", probs[..., :smax].astype(rows.dtype),
-                       rows[..., :rank], preferred_element_type=jnp.float32)
-    o_new = jnp.einsum("bhqk,bkhd->bqhd", probs[..., smax:].astype(v.dtype),
-                       v)
-    return o_lat, o_new
+    return chunk_attention_kept(
+        q_cat, q, rows, k_nope, k_pe, v, rank,
+        (jnp.arange(rows.shape[1]) < start)[None, None],
+        jnp.tril(jnp.ones((c, c), bool))[None], start)
 
 
 def decode_attention_reference(q_cat, rows, row_new, lengths, rank: int,

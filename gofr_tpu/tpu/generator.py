@@ -617,11 +617,16 @@ class GenerationEngine:
         # prompt program takes, measured at the end of a warm-up, and
         # for each prompt length the bucket its first dispatch runs (0:
         # one bucket). An engine that was not warmed has neither and
-        # pads as before. Beside them, what the prompt programs ran.
+        # pads as before. Beside them, what the prompt programs ran,
+        # and of the rows a slot reserves those a chunk program's
+        # attention fetched: the blocks under its start, in the block
+        # the family says its walk has (0: it walks under no cursor)
         self._prefill_costs: dict | None = None
         self._split_first: list[int] | None = None
         self._prefill_n = dict.fromkeys(
-            ("admissions", "split", "prompt_tokens", "positions"), 0)
+            ("admissions", "split", "prompt_tokens", "positions",
+             "cache_rows_walked", "cache_rows_reserved"), 0)
+        self._walk_block = self._fam.chunk_block(cfg, self.max_seq)
 
         # Paged (block-pool) KV cache: slots share a pool of fixed
         # T-token blocks via a host-owned block table instead of owning
@@ -1512,8 +1517,10 @@ class GenerationEngine:
         engine that was not warmed), the plan made from it (for each
         bucket, the lengths that leave it for two dispatches and into
         what: prefill_plan.ranges), the admissions that ran a prompt
-        program and those of them that split, and the share of the
-        positions run that held no prompt token."""
+        program and those of them that split, the share of the
+        positions run that held no prompt token, and of the rows the
+        chunk programs' slots reserve the share their attention walked
+        (_count_chunk)."""
         n = dict(self._prefill_n)
         costs = self._prefill_costs
         return {
@@ -1527,6 +1534,9 @@ class GenerationEngine:
             "padded_pct": (round(100 * (1 - n["prompt_tokens"]
                                         / n["positions"]), 2)
                            if n["positions"] else None),
+            "walked_pct": (round(100 * n["cache_rows_walked"]
+                                 / n["cache_rows_reserved"], 2)
+                           if n["cache_rows_reserved"] else None),
         }
 
     def _pipeline_stats(self) -> dict:
@@ -1599,7 +1609,13 @@ class GenerationEngine:
         of a routed model would go to the same experts, and a kernel
         may skip what lies past the length. These pass varied tokens at
         the full length, the final chunk behind as many rows as it
-        holds, into the free slot the plan wrote."""
+        holds, into the free slot the plan wrote. A final chunk's
+        attention walks the blocks of cached rows under its start
+        (ops.attention.chunk_attention), so its cost depends on where
+        it runs: a split's rest runs behind its first bucket, at most
+        half the chunk budget, and the chunk timed for it behind its
+        own bucket; both walk ONE block of the family's 256 rows or
+        more (chunk_block), so the table prices what a split runs."""
         i32 = jnp.int32
         names = {"_prefill_jit": "prefill", "_chunk_final_jit": "chunk_final"}
         costs: dict[str, dict[int, list[float]]] = {
@@ -2272,6 +2288,24 @@ class GenerationEngine:
             if split:
                 inc("app_tpu_prefill_split_total")
 
+    def _count_chunk(self, start: int) -> None:
+        """One dispatch of a chunk program at ``start``: the cached
+        rows its attention fetched (the blocks under its start) beside
+        the rows the slot reserves, stats()["scheduler"]["prefill"] and
+        two counters. A family whose chunk program walks under no
+        cursor counts neither."""
+        block = self._walk_block
+        if not block:
+            return
+        walked = -(-start // block) * block
+        n = self._prefill_n
+        n["cache_rows_walked"] += walked
+        n["cache_rows_reserved"] += self.max_seq
+        if self.metrics is not None:
+            inc = self.metrics.increment_counter
+            inc("app_tpu_chunk_rows_walked_total", by=walked)
+            inc("app_tpu_chunk_rows_reserved_total", by=self.max_seq)
+
     def _first_token(self, tok, lp) -> tuple[int, float]:
         """Fetch the token an admission's last program sampled. The
         copy blocks until every program queued before it is done (the
@@ -2416,6 +2450,7 @@ class GenerationEngine:
                 jnp.int32(slot), jnp.int32(0), jnp.int32(0),
                 jnp.float32(0.0), jnp.int32(0), self._key,
                 jnp.int32(0), jnp.int32(0), self._adapter1(req)))
+            self._count_chunk(pos)
             pos += T
             req.stream.chunks += 1
             if self._tl is not None:
@@ -2497,6 +2532,7 @@ class GenerationEngine:
             jnp.int32(req.top_k), self._key, jnp.int32(req.seed),
             jnp.int32(req.pos_base), self._adapter1(req))
         setattr(self, attr, new_cache)
+        self._count_chunk(begin)
         return tok, lp, Sb
 
     def _expire_mid_lattice(self, req: _Request, pos: int) -> bool:

@@ -924,3 +924,63 @@ def test_looped_family_streams_its_stack_and_leaves_its_tables(
         # beside the pool's row (1.25 GB) the chip's 17.18 GB hold it
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             + 1.25e9 < 16.2e9
+
+
+# -- a chunk's attention walks the blocks of cached rows under its start --------
+
+def _over_the_slot(text, rows):
+    """The float32 arrays of a compiled module's text that have a
+    512-token chunk's positions as one dimension and ``rows`` as
+    another, in whatever order the compiler left them."""
+    shapes = {tuple(s.split(",")) for s in re.findall(r"f32\[([\d,]+)\]", text)}
+    return sorted(s for s in shapes if "512" in s
+                  and any(str(r) in s for r in rows))
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_chunk_program_scores_no_reserved_row(request, monkeypatch, chips):
+    """``ops.attention.chunk_attention`` in the compiled 512-token chunk
+    program, at Mistral's eight KV heads on one chip and at the two a
+    tp=4 shard of Mixtral holds: the walk is a ``while`` inside the
+    layer scan's, a block's float32 scores [.., 512, 512] are the widest
+    it holds, and no float32 array spans the slot's 2,048 rows, or the
+    2,560 of the one softmax it replaced (``f32[1,8,4,512,2560]``: 168 MB
+    a layer, 317 MB of temporaries where these are 18)."""
+    if chips == 1:
+        lowered = _lowered(monkeypatch, request.getfixturevalue("one_chip"),
+                           "chunk 512", layers=2)
+    else:
+        lowered = _lowered_tp4(monkeypatch, request.getfixturevalue("tp4"),
+                               "chunk 512")
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert re.search(r'op_name="[^"]*chunk_attention/while', text)
+    assert len(re.findall(r" while\(", text)) >= 2
+    assert _over_the_slot(text, (512,))  # the pattern still reads this HLO
+    assert not _over_the_slot(text, (SMAX, SMAX + 512))
+    assert compiled.memory_analysis().temp_size_in_bytes < (64 << 20)
+
+
+@pytest.mark.parametrize("family,rows", [
+    ("looped", r"s8\[(?:\d+,)*16,(?:1536|512),128\]"),
+    ("conv", r"bf16\[(?:\d+,)*4,(?:2048|512),128\]")], ids=["looped", "conv"])
+def test_chunk_walk_moves_no_slot_and_no_block(one_chip, monkeypatch, family,
+                                               rows):
+    """Ouro's and LFM2's 512-token chunk programs hold the walk and read
+    a slot's K and V rows where they lie: no ``copy`` or ``transpose`` of
+    a slot's rows (LFM2's one-softmax form had one, of the paired rows
+    ``bf16[5,1,4,2048,128]``) and none of a block of them a trip."""
+    lowered = {"looped": _lowered_loop, "conv": _lowered_conv}[family](
+        monkeypatch, one_chip, "chunk 512")
+    text = lowered.compile().as_text()
+    assert re.search(r'op_name="[^"]*chunk_attention/while', text)
+    results = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = ((?:s8|bf16)\[[\d,]+\]\S*) ([\w\-]+)\(",
+        text, re.M)
+    assert [r for r in results if re.match(rows, r[0])]  # they are named
+    assert not [r for r in results if r[1] in ("copy", "transpose")
+                and re.match(rows, r[0])]
+    # (the model is 2,048 wide in both: a slot's 1,536 rows in the one,
+    # the old softmax's 2,560 in the other, name no activation)
+    assert not _over_the_slot(text, (1536,) if family == "looped"
+                              else (2560,))

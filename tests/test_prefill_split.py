@@ -246,3 +246,82 @@ def test_the_lattice_counts_its_chunks_and_its_overlap(observed):
     assert after["positions"] == before["positions"] + 80
     assert after["prompt_tokens"] == before["prompt_tokens"] + 70
     assert after["split"] == before["split"]
+
+
+# -- a chunk's attention walks the blocks of cached rows under its start --------
+
+def _walking_engine(params, monkeypatch, block, **kw):
+    """An engine whose chunk programs walk a slot's 128 rows ``block`` at
+    a time (the one constant, read when a program is traced and when the
+    engine asks its family what a dispatch fetched)."""
+    from gofr_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_CHUNK_BLOCK", block)
+    m = Manager()
+    register_framework_metrics(m)
+    eng = GenerationEngine(TINY, params, slots=2, max_seq=128,
+                           prompt_buckets=(16, 32), metrics=m, **kw)
+    assert eng._walk_block == min(block, 128)
+    return eng, m
+
+
+def test_chunk_dispatches_count_the_rows_they_walked(params, monkeypatch):
+    eng, m = _walking_engine(params, monkeypatch, 16)
+    rows = ("cache_rows_walked", "cache_rows_reserved")
+
+    def counted():
+        pre = eng.stats()["scheduler"]["prefill"]
+        n = tuple(pre[k] for k in rows)
+        assert n == (_counter(m, "app_tpu_chunk_rows_walked_total"),
+                     _counter(m, "app_tpu_chunk_rows_reserved_total"))
+        return n + (pre["walked_pct"],)
+
+    try:
+        assert counted() == (0, 0, None)
+        # one bucket: the prefill program, no chunk program, no row
+        eng.generate(list(range(1, 21)), max_new_tokens=2).tokens()
+        assert counted() == (0, 0, None)
+        # a split, 20 = 16 + the last 16: the rest starts at 4, one block
+        eng._split_first = [0] * 17 + [16] * 16
+        eng.generate(list(range(1, 21)), max_new_tokens=2).tokens()
+        assert counted() == (16, 128, 12.5)
+        # a lattice, 70 = 32 at 0, 32 at 32, the last 16 at 54: no block,
+        # two, four of the slot's eight
+        eng.generate(list(range(1, 71)), max_new_tokens=2).tokens()
+        assert counted() == (16 + 0 + 32 + 64, 128 * 4, 21.88)
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("kv_dtype,tol", [(None, 2e-4), (jnp.int8, 0.05)])
+def test_the_walk_serves_the_same_whatever_its_block(params, monkeypatch,
+                                                     kv_dtype, tol):
+    """A lattice, a split and a prefix hit that resumes the lattice past
+    position 0, on an engine whose one block spans the slot (every
+    reserved row scored and masked, as the one softmax did) and on one
+    that walks blocks of 16: the same greedy tokens, logprobs to the
+    tolerance the chunked and split tests hold."""
+    rng = np.random.default_rng(52)
+    long = rng.integers(1, TINY.vocab_size, 70).tolist()
+    hit = long[:40] + rng.integers(1, TINY.vocab_size, 45).tolist()
+    short = rng.integers(1, TINY.vocab_size, 20).tolist()
+    served = {}
+    for block in (128, 16):
+        eng, _ = _walking_engine(params, monkeypatch, block, kv_dtype=kv_dtype,
+                                 prefix_cache_slots=2, prefix_store_min=16)
+        try:
+            eng._split_first = [0] * 17 + [16] * 16
+            served[block] = [
+                list(eng.generate(p, max_new_tokens=8, logprobs=True))
+                for p in (long, hit, short)]
+            assert eng.stats()["prefix_cache"]["hits"] >= 1
+            pre = eng.stats()["scheduler"]["prefill"]
+            assert pre["split"] == 1 and pre["cache_rows_reserved"] >= 128 * 5
+            if block == 128:      # a chunk past position 0 fetches the slot
+                assert pre["walked_pct"] > 60
+        finally:
+            eng.close()
+    for one, walked in zip(served[128], served[16]):
+        assert [int(t) for t, _ in one] == [int(t) for t, _ in walked]
+        assert max(abs(float(a[1]) - float(b[1]))
+                   for a, b in zip(one, walked)) < tol
