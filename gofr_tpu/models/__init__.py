@@ -5,7 +5,8 @@ The decoders: ``llama`` (dense GQA and Mixtral's eight experts; the row
 cache and the dense block every family builds on), ``deepseek_v3``
 (latent attention), ``solar_open2`` (gated delta-rule layers beside full
 ones), ``laguna`` (sliding-window layers on a ring), ``lfm2`` (gated
-short convolutions), ``nemotron_h`` (Mamba-2 state-space layers),
+short convolutions), ``nemotron_h`` (Mamba-2 state-space layers, one
+block a layer or a mixer and a dense feed-forward a layer),
 ``dots3_note`` (latent attention at two widths: full layers that select
 the rows they read, window layers on a ring of latent rows), ``ouro``
 (one dense stack run several times a token, each pass with row tables of

@@ -12,7 +12,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import causal_attention
+from ..ops import flash_decode
+from ..ops.attention import (causal_attention, chunk_attention,
+                             decode_attention_appended, pair_queries,
+                             pair_rows, unpair_heads)
 from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
 from ..ops.rope import apply_rope_part
@@ -24,7 +27,10 @@ F32 = jnp.float32
 
 def embed(params, cfg: ModelConfig, tokens):
     with jax.named_scope("embed"):
-        return params["embedding"][tokens].astype(cfg.jdtype)
+        x = params["embedding"][tokens]
+        if cfg.embedding_multiplier != 1.0:    # rounded once, after it
+            x = x.astype(F32) * cfg.embedding_multiplier
+        return x.astype(cfg.jdtype)
 
 
 def prompt_rows(tokens, lengths):
@@ -113,6 +119,87 @@ def attention(x, lw, cfg: ModelConfig, n_heads: int, rope, positions,
             gate = jax.nn.sigmoid(qmatmul(h, lw["head_gate"]).astype(F32))
             a = (a.astype(F32) * gate[..., None]).astype(x.dtype)
         return qmatmul(a.reshape(B, S, H * hd), lw["wo"]), (k, v)
+
+
+# -- heads of half a lane row: two KV heads a cache row ------------------------
+# (ops.attention.pair_rows says why; the conv family's full layers and
+# the state-space family's attention layers at a head of 64)
+
+_LANES = 128
+
+
+def paired(cfg: ModelConfig, whole_rows: bool = False) -> bool:
+    """Whether a cache of K and V heads holds two of them a row: heads
+    narrower than a lane row, an even count of them. ``whole_rows``: only
+    where a pair fills the lane row exactly, the one width at which the
+    decode kernels run on it (the families whose rows may be int8 ask so:
+    a narrower head stays a row of its own and keeps its scale a row)."""
+    if cfg.head_dim >= _LANES or cfg.n_kv_heads % 2:
+        return False
+    return 2 * cfg.head_dim == _LANES or not whole_rows
+
+
+def row_layout(cfg: ModelConfig, pair: bool) -> tuple[int, int]:
+    """(rows, values a row) of a cached token's K (and V), as stored."""
+    if pair:
+        return cfg.n_kv_heads // 2, 2 * cfg.head_dim
+    return cfg.n_kv_heads, cfg.head_dim
+
+
+def rows_attend(attend_rows, cfg: ModelConfig, pair: bool):
+    """``attend(q, k, v)`` of a layer over cached rows, as an attention
+    block calls it: ``attend_rows(q, k, v, scale)`` sees q, k and v as
+    the cache holds rows (paired, or as they are) and the softmax scale
+    of the true width."""
+    scale = cfg.head_dim ** -0.5
+    if not pair:
+        return lambda q, k, v: attend_rows(q, k, v, scale)
+    KV = cfg.n_kv_heads
+    return lambda q, k, v: unpair_heads(
+        attend_rows(pair_queries(q, KV), pair_rows(k), pair_rows(v), scale),
+        KV)
+
+
+def _layer_rows(rows, i):
+    """Layer ``i`` of each table of ``rows`` (k, v, k_scale, v_scale), a
+    scale None where the rows are not int8."""
+    return tuple(None if a is None else jax.lax.dynamic_index_in_dim(
+        a, i, 0, keepdims=False) for a in rows)
+
+
+def decode_rows_attend(rows, i, lengths, live, block_s, mesh,
+                       cfg: ModelConfig, pair: bool):
+    """``attend(q, k_new, v_new)`` of a decode step over layer ``i`` of
+    the cached ``rows`` (k, v [L, B, rows, Smax, values], k_scale,
+    v_scale or None): the kernel over the live blocks where they lie
+    (``block_s``: ``flash_decode.kernel_block``'s answer; ``live`` [B],
+    0 for a slot that is not read), or the reference on the layer's
+    slice up to ``lengths``; on paired rows where ``pair``."""
+    def over_rows(q, k_new, v_new, scale):
+        if block_s:
+            return flash_decode.decode_attention_auto(
+                q, rows[0], rows[1], k_new, v_new, live, i, rows[2],
+                rows[3], block_s=block_s, mesh=mesh, scale=scale)
+        k_l, v_l, ks_l, vs_l = _layer_rows(rows, i)
+        return decode_attention_appended(q, k_l, v_l, k_new, v_new, lengths,
+                                         ks_l, vs_l, scale=scale)
+    return rows_attend(over_rows, cfg, pair)
+
+
+def chunk_rows_attend(rows, i, start, cfg: ModelConfig, pair: bool):
+    """``attend(q, k_new, v_new)`` of a chunk program over layer ``i`` of
+    the cached ``rows``: the rows before ``start`` and the chunk within
+    itself; on paired rows where ``pair``."""
+    def over_rows(q, k_new, v_new, scale):
+        k_l, v_l, ks_l, vs_l = _layer_rows(rows, i)
+        return chunk_attention(q, k_l, v_l, k_new, v_new, start, ks_l, vs_l,
+                               scale=scale)
+    return rows_attend(over_rows, cfg, pair)
+
+
+def as_stored(kv, pair: bool):
+    """The (k, v) a layer made, [.., KV, hd], as the cache rows."""
+    return tuple(pair_rows(a) for a in kv) if pair else kv
 
 
 # -- their stack: the dense periods one by one, the rest scanned ---------------

@@ -123,6 +123,21 @@ class ModelConfig:
     moe_latent_dim: int = 0
     expert_act: str = "swiglu"
     shared_ffn_dim: int = 0
+    # the same family with a layer of TWO halves (``model_type:
+    # granitemoehybrid``, dense): layer_ffn says every layer is a mixer
+    # (``layer_pattern[l]``: "mamba" or "attn", no "moe") and THEN a
+    # gated feed-forward of ffn_dim, W_out (silu(g) * h) with
+    # [g | h] = W_in N2(x), each half behind a norm of its own and added
+    # to the stream times residual_multiplier. The embedding is
+    # multiplied by embedding_multiplier, the attention layers' softmax
+    # runs at scale attention_multiplier (0 = head_dim^-1/2) and the
+    # logits are divided by logits_scaling, in every family that reads
+    # them (models/blocks.py:embed, models/llama.py:logits)
+    layer_ffn: bool = False
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
     # latent attention at two widths with a learned selection
     # (models/dots3_note.py; kv_lora_rank > 0 WITH a "window" entry in
     # layer_pattern selects that family). A window layer is latent
@@ -284,6 +299,21 @@ LLAMA_CONFIGS = {
         routed_scaling=2.5, n_shared_experts=1, moe_ffn_dim=40,
         n_experts_held=4, moe_latent_dim=24, expert_act="relu2",
         shared_ffn_dim=56),
+    # the state-space family's layer of two halves at test size: six
+    # layers, a mixer and a gated feed-forward each, two attention layers
+    # among four mamba layers of ONE group (8 heads of 16), attention
+    # heads of 64 (not dim // n_heads: two KV heads a cache row), every
+    # multiplier away from 1, the attention's scale a power of two times
+    # head_dim^-1/2 as the published one is, tied head
+    "tiny-ssm-dense": ModelConfig(
+        name="tiny-ssm-dense", vocab_size=256, dim=64, n_layers=6,
+        n_heads=4, n_kv_heads=2, ffn_dim=96, max_seq=128, norm_eps=1e-5,
+        tie_embeddings=True, dtype="float32",
+        layer_pattern=("mamba", "attn", "mamba", "mamba", "attn", "mamba"),
+        use_rope=False, attn_head_dim=64, conv_kernel=4, ssm_heads=8,
+        ssm_head_dim=16, ssm_groups=1, ssm_state=16, ssm_chunk=8,
+        layer_ffn=True, embedding_multiplier=3.0, residual_multiplier=0.4,
+        attention_multiplier=0.03125, logits_scaling=2.0),
     # the sparse-latent family at test size: a dense full layer, then two
     # periods of (full, window, window, window): full layers keep 16 of
     # up to 128 cached rows, window layers see 9 positions (a ring of 8

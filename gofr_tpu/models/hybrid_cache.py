@@ -17,8 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import flash_decode
-from ..ops.attention import decode_attention_appended
-from . import llama
+from . import blocks, llama
 from .common import ModelConfig, refused_options
 
 
@@ -27,7 +26,7 @@ class HybridCache(NamedTuple):
     int8 with scale planes); every array but ``lengths`` is [L, B, ...],
     which is all the engine's row helpers ask."""
 
-    k: jnp.ndarray        # [La, B, KV, Smax, hd]
+    k: jnp.ndarray        # [La, B, rows, Smax, values]: ``kv_layout``
     v: jnp.ndarray
     state: jnp.ndarray    # [Ls, B, ...] float32
     conv: jnp.ndarray     # [Ls, B, ...]: the convolutions' last inputs
@@ -45,13 +44,6 @@ class HybridCache(NamedTuple):
         return llama.KVCache(self.k, self.v, self.lengths, self.k_scale,
                              self.v_scale)
 
-    def layer_rows(self, i):
-        """(k, v, k_scale, v_scale) of attention layer ``i``, a scale
-        None where the rows are not int8."""
-        return tuple(None if a is None else jax.lax.dynamic_index_in_dim(
-            a, i, 0, keepdims=False)
-            for a in (self.k, self.v, self.k_scale, self.v_scale))
-
     def with_rows(self, kv: llama.KVCache, **kw) -> "HybridCache":
         return self._replace(k=kv.k, v=kv.v, lengths=kv.lengths,
                              k_scale=kv.k_scale, v_scale=kv.v_scale, **kw)
@@ -67,8 +59,28 @@ kv_tables = llama.kv_tables      # one table a layer (models.family)
 chunk_block = llama.chunk_block  # a cursor walk (models.family)
 
 
+# two KV heads a row at a head of exactly half a lane row (blocks.paired
+# says why a narrower head beside a state stays a row of its own)
+paired = functools.partial(blocks.paired, whole_rows=True)
+
+
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
-    return cfg.n_kv_heads, cfg.head_dim
+    """(rows, values a row) of a cached token's K (and V), as stored."""
+    return blocks.row_layout(cfg, paired(cfg))
+
+
+def init_rows(cfg: ModelConfig, n_layers: int, batch: int,
+              max_seq: int | None = None, dtype=None) -> llama.KVCache:
+    """The rows of ``n_layers`` attention layers, as ``kv_layout`` lays a
+    token out."""
+    rows, values = kv_layout(cfg)
+    if paired(cfg) and dtype is not None and jnp.dtype(dtype) == jnp.int8:
+        raise ValueError(
+            f"an int8 cache for {cfg.name!r}: a cache row holds two KV "
+            "heads and the shared kernels take one scale a row")
+    return llama.init_cache(
+        cfg.with_(n_layers=n_layers, n_kv_heads=rows, attn_head_dim=values),
+        batch, max_seq, dtype)
 
 
 def decode_kv_block(cfg: ModelConfig, cache: HybridCache, mesh=None):
@@ -91,21 +103,25 @@ REFUSED = {
 unsupported_options = functools.partial(refused_options, REFUSED)
 
 
-def decode_attend(cache: HybridCache, i, lengths, live, block_s, mesh):
-    """``attend(q, k_new, v_new)`` of attention layer ``i``'s decode step:
-    the kernel over the live blocks of the rows where they lie
-    (``block_s``: ``decode_kv_block``'s answer), or the reference on the
-    layer's slice."""
-    if block_s:
-        return lambda q, k_new, v_new: flash_decode.decode_attention_auto(
-            q, cache.k, cache.v, k_new, v_new, live, i, cache.k_scale,
-            cache.v_scale, block_s=block_s, mesh=mesh)
+def _tables(cache: HybridCache):
+    return cache.k, cache.v, cache.k_scale, cache.v_scale
 
-    def attend(q, k_new, v_new):
-        k_l, v_l, ks_l, vs_l = cache.layer_rows(i)
-        return decode_attention_appended(
-            q, k_l, v_l, k_new, v_new, lengths, ks_l, vs_l)
-    return attend
+
+def decode_attend(cache: HybridCache, i, lengths, live, block_s, mesh,
+                  cfg: ModelConfig):
+    """``attend(q, k_new, v_new)`` of attention layer ``i``'s decode step
+    (``blocks.decode_rows_attend`` over this cache's rows, paired where
+    it holds them so; ``block_s``: ``decode_kv_block``'s answer)."""
+    return blocks.decode_rows_attend(_tables(cache), i, lengths, live,
+                                     block_s, mesh, cfg, paired(cfg))
+
+
+def chunk_attend(cache: HybridCache, i, start, cfg: ModelConfig):
+    """``attend(q, k_new, v_new)`` of attention layer ``i`` in a chunk
+    program: the cached rows before ``start`` and the chunk within
+    itself."""
+    return blocks.chunk_rows_attend(_tables(cache), i, start, cfg,
+                                    paired(cfg))
 
 
 @jax.named_scope("kv_write")

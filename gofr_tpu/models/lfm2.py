@@ -56,13 +56,10 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import flash_decode
-from ..ops.attention import (chunk_attention, decode_attention_appended,
-                             pair_queries, pair_rows, unpair_heads)
-from ..ops.flash import interpret_env
 from ..ops.kda import conv_taps
 from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
-from . import llama, moe
+from . import blocks, llama, moe
 from .blocks import attention, embed, period_stack, prompt_attend, prompt_rows
 from .common import ModelConfig, dense_init, refused_options
 
@@ -72,7 +69,6 @@ from .common import ModelConfig, dense_init, refused_options
 RECOMPUTABLE = False
 F32 = jnp.float32
 KINDS = ("conv", "full")
-_LANES = 128
 
 
 def counts(cfg: ModelConfig) -> dict[str, int]:
@@ -86,10 +82,7 @@ def counts(cfg: ModelConfig) -> dict[str, int]:
     return {k: cfg.n_layers // len(pat) * pat.count(k) for k in KINDS}
 
 
-def paired(cfg: ModelConfig) -> bool:
-    """Whether the cache holds two KV heads a row: heads narrower than a
-    lane row, an even count of them."""
-    return cfg.head_dim < _LANES and cfg.n_kv_heads % 2 == 0
+paired = blocks.paired    # two KV heads a cache row at any head under 128
 
 
 kv_tables = llama.kv_tables      # one table a layer (models.family)
@@ -98,9 +91,7 @@ chunk_block = llama.chunk_block  # a cursor walk (models.family)
 
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     """(rows, values a row) of a cached token's K (and V), as stored."""
-    if paired(cfg):
-        return cfg.n_kv_heads // 2, 2 * cfg.head_dim
-    return cfg.n_kv_heads, cfg.head_dim
+    return blocks.row_layout(cfg, paired(cfg))
 
 
 class ConvCache(NamedTuple):
@@ -261,24 +252,6 @@ def _layer(x, lw, cfg: ModelConfig, op, valid):
     return x + y, kept, n
 
 
-def _cached(attend_rows, cfg: ModelConfig):
-    """``attend(q, k, v)`` of a full layer over cached rows, as
-    ``blocks.attention`` calls it: ``attend_rows(q, k, v, scale)`` sees
-    q, k and v as the cache holds rows (paired, or as they are)."""
-    scale = cfg.head_dim ** -0.5
-    if not paired(cfg):
-        return lambda q, k, v: attend_rows(q, k, v, scale)
-    KV = cfg.n_kv_heads
-    return lambda q, k, v: unpair_heads(
-        attend_rows(pair_queries(q, KV), pair_rows(k), pair_rows(v), scale),
-        KV)
-
-
-def _as_stored(kv, cfg: ModelConfig):
-    """The (k, v) a full layer made, [.., KV, hd], as the cache rows."""
-    return tuple(pair_rows(a) for a in kv) if paired(cfg) else kv
-
-
 # -- the programs --------------------------------------------------------------
 
 def _run(params, cfg: ModelConfig, tokens, lengths, tails, attend, rope,
@@ -298,7 +271,7 @@ def _run(params, cfg: ModelConfig, tokens, lengths, tails, attend, rope,
             x, lw, cfg, lambda x, lw: attention(
                 x, lw, cfg, cfg.n_heads, rope["full"], positions, attend(i)),
             valid)
-        return x, _as_stored(kv, cfg), n
+        return x, blocks.as_stored(kv, paired(cfg)), n
 
     x, kept, n = period_stack(params, cfg, embed(params, cfg, tokens), layer)
     return x, *kept["full"], kept["conv"], n
@@ -367,12 +340,8 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                       jnp.zeros((), cache.conv.dtype), cache.conv)
 
     def attend(i):
-        def over_rows(q, k_new, v_new, scale):
-            k_l, v_l = (jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
-                        for a in (cache.k, cache.v))
-            return chunk_attention(q, k_l, v_l, k_new, v_new, start,
-                                   scale=scale)
-        return _cached(over_rows, cfg)
+        return blocks.chunk_rows_attend((cache.k, cache.v, None, None), i,
+                                        start, cfg, paired(cfg))
 
     x, k, v, tails, _ = _run(params, cfg, tokens, lengths, tails, attend,
                              rope, positions, valid)
@@ -405,18 +374,9 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     block_s = flash_decode.kernel_block(cfg.n_heads, cache.k, mesh)
 
     def attend(i):
-        def over_rows(q, k_new, v_new, scale):
-            with jax.named_scope("attn/full_decode"):
-                if block_s:
-                    return flash_decode.flash_decode_stacked(
-                        q, cache.k, cache.v, k_new, v_new, live, i,
-                        block_s=block_s, interpret=interpret_env(),
-                        scale=scale)
-                k_l, v_l = (jax.lax.dynamic_index_in_dim(
-                    a, i, 0, keepdims=False) for a in (cache.k, cache.v))
-                return decode_attention_appended(
-                    q, k_l, v_l, k_new, v_new, live, scale=scale)
-        return _cached(over_rows, cfg)
+        return jax.named_scope("attn/full_decode")(blocks.decode_rows_attend(
+            (cache.k, cache.v, None, None), i, live, live, block_s, mesh,
+            cfg, paired(cfg)))
 
     x, k_rows, v_rows, tails, n = _run(
         params, cfg, tokens[:, None], None, cache.conv, attend, rope,

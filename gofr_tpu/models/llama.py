@@ -480,9 +480,16 @@ def layer(x, layer_w, cfg: ModelConfig, cos, sin, positions,
 @jax.named_scope("lm_head")
 def logits(params, cfg: ModelConfig, x):
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if cfg.logits_scaling != 1.0:
+        return _head(params, cfg, x) / cfg.logits_scaling
+    return _head(params, cfg, x)
+
+
+def _head(params, cfg: ModelConfig, x):
     if cfg.tie_embeddings:
-        return jnp.dot(x, params["embedding"].T,
-                       preferred_element_type=jnp.float32)
+        with jax.named_scope("tied"):
+            return jnp.dot(x, params["embedding"].T,
+                           preferred_element_type=jnp.float32)
     return qmatmul(x, params["lm_head"]).astype(jnp.float32)
 
 

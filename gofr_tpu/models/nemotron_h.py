@@ -1,5 +1,7 @@
-"""The state-space family (``model_type: nemotron_h``): layers of three
-kinds, ONE block a layer, a Mamba-2 state beside KV rows in one cache.
+"""The state-space family (``model_type: nemotron_h`` and, with a layer
+of two halves, ``granitemoehybrid``): layers of three kinds, ONE block a
+layer, or a mixer and a dense feed-forward a layer, a Mamba-2 state
+beside KV rows in one cache.
 
 The generator picks this module where ``cfg.layer_pattern`` names a
 ``"mamba"`` layer (``models.family``) and calls it through the same
@@ -31,12 +33,28 @@ and a layer is a norm and one block, ``x += Block_l(RMSNorm(x))``:
     a token, a shared relu^2 expert of ``shared_ffn_dim`` on the full
     width, ``n_experts_held`` of ``n_experts`` held here.
 
+With ``cfg.layer_ffn`` a layer is TWO halves (``granitemoehybrid``,
+dense): its mixer, a MAMBA or an ATTN block as above (no MOE layer), and
+then a gated feed-forward of ``ffn_dim``, each behind a norm of its own
+and added times ``residual_multiplier``:
+``x += r Mixer_l(N1_l(x)); x += r W_out (silu(g) * h), [g | h] = W_in
+N2_l(x)``. The feed-forward stands OUTSIDE the switch on the kind: the
+scan has as many steps as the stack has layers, and a step hands the
+states through one branch, not two. The embedding is multiplied by
+``embedding_multiplier`` (``blocks.embed``), the logits divided by
+``logits_scaling`` (``llama.logits``), and the attention's softmax runs
+at ``attention_multiplier``: q is scaled by ``attention_multiplier
+head_dim^1/2`` before the kernels, which all take ``head_dim^-1/2`` (a
+power of two at the published sizes, so exact in bfloat16). Heads of 64
+are cached two KV heads a row (``hybrid_cache.paired``).
+
 The weights are stacked a kind (``params["mamba"]``, ``["moe"]``,
 ``["attn"]``; the norms a layer, ``params["norm"]``). The stack is ONE
 ``lax.scan`` over the layers whose body switches on the layer's kind
 (``lax.switch``: one branch runs, so a layer streams its own weights
-alone) with the layer's index among its kind: three layer bodies to
-compile whatever the depth and whatever the order. What a layer leaves
+alone) with the layer's index among its kind: a layer body a kind the
+stack has to compile whatever the depth and whatever the order (a
+layer's second half, ``params["ffn"]`` stacked a layer, rides the scan). What a layer leaves
 behind (a state, a tail, a token's rows, the experts' counts) is
 written at its index into a stack a kind that rides the scan's carry;
 the decode step's state is updated where it lies, for the ACTIVE slots
@@ -51,19 +69,20 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import ssd
-from ..ops.attention import chunk_attention
 from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
 from . import llama, moe
-from .blocks import embed, layer_at, prompt_attend, prompt_rows
+from .blocks import as_stored, embed, layer_at, prompt_attend, prompt_rows
 from .common import ModelConfig, dense_init
 # the cache (rows, state, tail), and the entry points of ``models.family``
 # that follow from it alone, handed on (``x as x``) as ``hybrid_cache``
 # has them
-from .hybrid_cache import (HybridCache, chunk_block as chunk_block,
-                           decode_attend, decode_kv_block,
+from .hybrid_cache import (HybridCache, chunk_attend,
+                           chunk_block as chunk_block,
+                           decode_attend, decode_kv_block, init_rows,
                            get_rope_tables as get_rope_tables,
                            kv_layout as kv_layout, kv_tables as kv_tables,
+                           paired,
                            unsupported_options as unsupported_options,
                            write_kv as write_kv)
 
@@ -81,16 +100,27 @@ def counts(cfg: ModelConfig) -> tuple[int, int, int]:
     if cfg.use_rope:
         raise ValueError("this family's attention layers are not rotated: "
                          "use_rope must be false")
+    if cfg.layer_ffn and "moe" in pat:
+        raise ValueError("layer_ffn: every layer's second half is the "
+                         "dense feed-forward; layer_pattern names its "
+                         f"mixer, 'mamba' or 'attn', not {pat!r}")
     return tuple(pat.count(k) for k in KINDS)
 
 
+def _kinds(cfg: ModelConfig) -> tuple[str, ...]:
+    """The kinds the stack has, in ``KINDS``' order: the switch's
+    branches."""
+    return tuple(k for k in KINDS if k in cfg.layer_pattern)
+
+
 def _plan(cfg: ModelConfig):
-    """A layer's kind (its place in ``KINDS``) and its index among the
+    """A layer's kind (its place in ``_kinds``) and its index among the
     layers of its kind, [L] int32 each."""
-    seen = dict.fromkeys(KINDS, 0)
+    kinds = _kinds(cfg)
+    seen = dict.fromkeys(kinds, 0)
     kind, index = [], []
     for k in cfg.layer_pattern:
-        kind.append(KINDS.index(k))
+        kind.append(kinds.index(k))
         index.append(seen[k])
         seen[k] += 1
     return np.asarray(kind, np.int32), np.asarray(index, np.int32)
@@ -107,6 +137,19 @@ def conv_channels(cfg: ModelConfig) -> int:
     return H * P + 2 * G * N
 
 
+def in_width(cfg: ModelConfig) -> int:
+    """Columns of ``w_ssm_in``: [z | xBC | dt] and, where that is not
+    whole lane rows while the model's width is (4,096 + 4,352 + 64 =
+    8,512 from 2,048), columns nothing reads up to the next one. Such a
+    stack is kept by the chip with its INPUT axis minor, the one of the
+    two that tiles, and the decode block re-lays all of it out every
+    dispatch (627 MB at 36 layers: tests/test_kernels_compile_v5e.py
+    holds the compiled text to no such copy)."""
+    H, P, _, _, _ = _ssm_dims(cfg)
+    w = H * P + conv_channels(cfg) + H
+    return w if cfg.dim % 128 or not w % 128 else -(-w // 128) * 128
+
+
 def _empty_state(cfg: ModelConfig, batch: int):
     """(state, conv) of ``batch`` slots that have seen no token."""
     Lm = counts(cfg)[0]
@@ -118,8 +161,7 @@ def _empty_state(cfg: ModelConfig, batch: int):
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int | None = None,
                dtype=None) -> HybridCache:
-    kv = llama.init_cache(cfg.with_(n_layers=counts(cfg)[2]), batch, max_seq,
-                          dtype)
+    kv = init_rows(cfg, counts(cfg)[2], batch, max_seq, dtype)
     state, conv = _empty_state(cfg, batch)
     return HybridCache(k=kv.k, v=kv.v, lengths=kv.lengths,
                        k_scale=kv.k_scale, v_scale=kv.v_scale, state=state,
@@ -135,11 +177,28 @@ def state_bytes_per_slot(cfg: ModelConfig) -> int:
 
 def serving_stats(cfg: ModelConfig, slots: int) -> dict:
     """What ``GenerationEngine.stats()`` says of this family (as the
-    hybrid family's: benchmarks/metrics reads them here)."""
-    return {**moe.serving_stats(cfg, slots),
+    hybrid family's: benchmarks/metrics reads them here); a stack with
+    no moe layer says nothing of a dispatch, and one whose rows are
+    paired says so."""
+    said = {**(moe.serving_stats(cfg, slots) if counts(cfg)[1] else {}),
             "state_bytes_per_slot": state_bytes_per_slot(cfg),
             "kv_bytes_per_token": counts(cfg)[2] * 2 * cfg.n_kv_heads
             * cfg.head_dim * cfg.jdtype.itemsize}
+    if paired(cfg):
+        said["kv_heads_per_row"] = 2
+    return said
+
+
+def _qk_fan(cfg: ModelConfig):
+    """The fan-in ``wq`` and ``wk`` are drawn at under an
+    ``attention_multiplier``: a softmax at 1/64 over heads of 64 reads
+    fan-in weights' scores at a standard deviation of 1/8, every row
+    alike, and a wrong scale would move nothing a check could see; drawn
+    at ``dim x attention_multiplier x head_dim^1/2`` the scores stand at
+    1, as fan-in weights' do at ``head_dim^-1/2``."""
+    if not cfg.attention_multiplier:
+        return None
+    return max(1.0, cfg.dim * cfg.attention_multiplier * cfg.head_dim ** 0.5)
 
 
 def init(cfg: ModelConfig, key) -> dict:
@@ -153,7 +212,7 @@ def init(cfg: ModelConfig, key) -> dict:
     C = conv_channels(cfg)
     mamba = {
         # [z | xBC | dt]
-        "w_ssm_in": dense_init(next(ks), (Lm, D, H * P + C + H), dt),
+        "w_ssm_in": dense_init(next(ks), (Lm, D, in_width(cfg)), dt),
         # depthwise taps [W, x | B | C channels]; tap W - 1 meets the
         # current input
         "conv": dense_init(next(ks), (Lm, W, C), dt, scale=W ** -0.5),
@@ -168,20 +227,64 @@ def init(cfg: ModelConfig, key) -> dict:
         "ssm_norm": jnp.ones((Lm, H * P), dt),
         "w_ssm_out": dense_init(next(ks), (Lm, H * P, D), dt),
     }
+    fan = _qk_fan(cfg)
+    qk = fan and fan ** -0.5
     attn = {
-        "wq": dense_init(next(ks), (La, D, Hq * hd), dt),
-        "wk": dense_init(next(ks), (La, D, KV * hd), dt),
+        "wq": dense_init(next(ks), (La, D, Hq * hd), dt, scale=qk),
+        "wk": dense_init(next(ks), (La, D, KV * hd), dt, scale=qk),
         "wv": dense_init(next(ks), (La, D, KV * hd), dt),
         "wo": dense_init(next(ks), (La, Hq * hd, D), dt),
     }
-    params = {"embedding": dense_init(next(ks), (V, D), dt, scale=0.02),
+    table, final = _head_draw(cfg)
+    params = {"embedding": dense_init(next(ks), (V, D), dt, scale=table),
               "norm": jnp.ones((cfg.n_layers, D), dt),
               "mamba": mamba, "attn": attn,
-              "moe": moe.init_routed(ks, cfg, Le),
-              "final_norm": jnp.ones((D,), dt)}
+              "final_norm": jnp.full((D,), final, dt)}
+    if Le:
+        params["moe"] = moe.init_routed(ks, cfg, Le)
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(next(ks), (D, V), dt)
+    if cfg.layer_ffn:
+        F = cfg.ffn_dim
+        params["ffn"] = {
+            "norm": jnp.ones((cfg.n_layers, D), dt),
+            # [gate | up]
+            "w_ffn_in": dense_init(next(ks), (cfg.n_layers, D, 2 * F), dt),
+            "w_ffn_out": dense_init(next(ks), (cfg.n_layers, F, D), dt)}
     return params
+
+
+def _head_draw(cfg: ModelConfig) -> tuple[float, float]:
+    """(the embedding's standard deviation, the final norm's weight) of
+    random weights. Under an ``embedding_multiplier`` the table is TIED
+    and a token enters at ``multiplier x table``: at 0.02 x 12 the
+    embedded token is a sixth of the final stream, the tied head reads it
+    back 7 standard deviations over every other logit, and the model
+    repeats its input with probability 1 to a millionth of a nat
+    whatever the layers do (PERF.md, Findings PR 53: the first chip run's
+    check read 1e-6 nats and tested nothing). So the token enters at
+    0.03, a seventh of one scaled branch and under 2% of the final
+    stream, and the final norm's weight is what spreads the logits half
+    a nat under ``logits_scaling`` (35 at the published sizes): a fault
+    in a layer then moves a log-probability. Half a nat and not more:
+    a greedy token of 100,352 stands 4.4 deviations over the mean, and
+    what a bfloat16 stream of 80 adds has lost by the head (3% of it)
+    is read times that distance; at a spread of 2 the chip's check read
+    a worst 0.25-0.44 nats in seven weight seeds, against a limit of
+    0.5."""
+    if cfg.embedding_multiplier == 1.0:
+        return 0.02, 1.0
+    table = 0.03 / cfg.embedding_multiplier
+    return table, 0.5 * cfg.logits_scaling / (table * cfg.dim ** 0.5)
+
+
+def _fan_in(cfg: ModelConfig, name: str):
+    """``tpu.random_params``' question: the fan-in an int8 leaf is drawn
+    at where it is not its contraction axis."""
+    return _qk_fan(cfg) if name in ("wq", "wk") else None
+
+
+init.fan_in = _fan_in
 
 
 # -- the three blocks ----------------------------------------------------------
@@ -196,7 +299,9 @@ def _ssm_inputs(u, lw, cfg: ModelConfig, tail, lengths):
     H, P, G, N, R = _ssm_dims(cfg)
     with jax.named_scope("ssm/in"):
         zxd = qmatmul(u, lw["w_ssm_in"])
-        z, xbc, dt = (zxd[..., :H * P], zxd[..., H * P:-H], zxd[..., -H:])
+        C = conv_channels(cfg)      # past dt: ``in_width``'s padding
+        z, xbc, dt = (zxd[..., :H * P], zxd[..., H * P:H * P + C],
+                      zxd[..., H * P + C:H * P + C + H])
     xbc, tail = ssd.conv(xbc, tail, lw["conv"], lw["conv_bias"], lengths)
     with jax.named_scope("ssm/dt"):
         x = xbc[..., :H * P].reshape(B, S, G, R)
@@ -239,11 +344,37 @@ def _attn_block(u, lw, cfg: ModelConfig, attend):
         q, k, v = jax.lax.optimization_barrier(tuple(
             qmatmul(u, lw[name]) for name in ("wq", "wk", "wv")))
         q = q.reshape(B, S, H, hd)
+        if cfg.attention_multiplier:
+            # every kernel and jnp form scales by head_dim^-1/2
+            q = q * (cfg.attention_multiplier * hd ** 0.5)
         k, v = k.reshape(B, S, KV, hd), v.reshape(B, S, KV, hd)
     with jax.named_scope("attn"):
         a = attend(q, k, v).reshape(B, S, H * hd)
     with jax.named_scope("attn_out"):
-        return qmatmul(a.astype(u.dtype), lw["wo"]), (k, v)
+        return (qmatmul(a.astype(u.dtype), lw["wo"]),
+                as_stored((k, v), paired(cfg)))
+
+
+def _ffn(x, lw, cfg: ModelConfig):
+    """A layer's second half on the stream x [B, S, D]: the gated
+    feed-forward behind its own norm."""
+    F = cfg.ffn_dim
+    u = rms_norm(x, lw["norm"], cfg.norm_eps)
+    with jax.named_scope("ffn/in"):
+        gh = qmatmul(u, lw["w_ffn_in"])
+        a = (jax.nn.silu(gh[..., :F].astype(F32))
+             * gh[..., F:].astype(F32)).astype(x.dtype)
+    with jax.named_scope("ffn/out"):
+        return qmatmul(a, lw["w_ffn_out"])
+
+
+def _add(x, y, cfg: ModelConfig):
+    """The stream plus a branch times ``residual_multiplier``, rounded
+    once."""
+    if cfg.residual_multiplier == 1.0:
+        return x + y
+    return (x.astype(F32) + cfg.residual_multiplier * y.astype(F32)) \
+        .astype(x.dtype)
 
 
 # -- the stack: one scan, a switch on the layer's kind -------------------------
@@ -271,18 +402,23 @@ def _stack(params, cfg: ModelConfig, x, carry, mamba, routed, attn):
     of every kind). Returns (x, carry)."""
     kind, index = _plan(cfg)
     blocks = [lambda u, i, c, f=f, k=k: f(u, layer_at(params[k], i), i, c)
-              for k, f in zip(KINDS, (mamba, routed, attn))]
+              for k, f in zip(KINDS, (mamba, routed, attn))
+              if k in _kinds(cfg)]
 
     def body(c, xs):
         x, carry = c
-        w, k, i = xs
+        w, k, i, *ffn = xs
         y, carry = jax.lax.switch(k, blocks,
                                   rms_norm(x, w, cfg.norm_eps), i, carry)
-        return (x + y, carry), None
+        x = _add(x, y, cfg)
+        if ffn:     # the layer's second half, outside the switch
+            x = _add(x, _ffn(x, ffn[0], cfg), cfg)
+        return (x, carry), None
 
     (x, carry), _ = jax.lax.scan(
         body, (x, carry), (params["norm"], jnp.asarray(kind),
-                           jnp.asarray(index)))
+                           jnp.asarray(index))
+        + ((params["ffn"],) if cfg.layer_ffn else ()))
     return x, carry
 
 
@@ -309,8 +445,7 @@ def _prefill(params, cfg: ModelConfig, tokens, lengths, state, conv,
         y, kv = _attn_block(u, lw, cfg, lambda q, k, v: attend(q, k, v, i))
         return y, {**_rest(c), "kv": _put(c["kv"], kv, i)}
 
-    row = jnp.zeros((counts(cfg)[2], B, S, cfg.n_kv_heads, cfg.head_dim),
-                    cfg.jdtype)
+    row = jnp.zeros((counts(cfg)[2], B, S) + kv_layout(cfg), cfg.jdtype)
     x, c = _stack(params, cfg, embed(params, cfg, tokens),
                   {"state": state, "conv": conv, "kv": (row, row)},
                   mamba, routed, attn)
@@ -364,8 +499,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     conv = jnp.where(fresh, jnp.zeros((), cache.conv.dtype), cache.conv)
 
     def attend(q, k_new, v_new, i):
-        k_l, v_l, ks_l, vs_l = cache.layer_rows(i)
-        return chunk_attention(q, k_l, v_l, k_new, v_new, start, ks_l, vs_l)
+        return chunk_attend(cache, i, start, cfg)(q, k_new, v_new)
 
     x, k, v, state, conv = _prefill(params, cfg, tokens, lengths, state,
                                     conv, attend, valid)
@@ -387,8 +521,9 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     ACTIVE slots alone, and so is its tail.
 
     Returns (logits [B, V] float32, the cache with lengths + 1, the
-    expert layers' assignments a layer a held expert [Le, Eh] int32, the
-    (layer, slot) states updated: int32 scalar)."""
+    expert layers' assignments a layer a held expert [Le, Eh] int32 (None
+    of a stack with no such layer), the (layer, slot) states updated:
+    int32 scalar)."""
     B = tokens.shape[0]
     Lm, Le, La = counts(cfg)
     lengths = cache.lengths
@@ -413,14 +548,16 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
     def attn(u, lw, i, c):
         y, kv = _attn_block(u, lw, cfg, decode_attend(
-            cache, i, lengths, live, block_s, mesh))
+            cache, i, lengths, live, block_s, mesh, cfg))
         return y, {**_rest(c), "kv": _put(c["kv"], kv, i)}
 
-    row = jnp.zeros((La, B, 1, cfg.n_kv_heads, cfg.head_dim), cfg.jdtype)
+    row = jnp.zeros((La, B, 1) + kv_layout(cfg), cfg.jdtype)
     x, c = _stack(
         params, cfg, embed(params, cfg, tokens[:, None]),
         {"state": cache.state, "conv": cache.conv, "kv": (row, row),
-         "n": jnp.zeros((Le, moe.n_held(cfg)), jnp.int32)},
+         # the expert layers' counts, of a stack that has such layers
+         **({"n": jnp.zeros((Le, moe.n_held(cfg)), jnp.int32)} if Le
+            else {})},
         mamba, routed, attn)
     with jax.named_scope("kv_write"):
         rows = llama.write_rows(cache.rows, *c["kv"], positions,
@@ -428,4 +565,4 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     updated = jnp.sum(act, dtype=jnp.int32) * Lm
     return (llama.logits(params, cfg, x[:, 0]),
             cache.with_rows(rows, state=c["state"], conv=c["conv"]),
-            c["n"], updated)
+            c.get("n"), updated)

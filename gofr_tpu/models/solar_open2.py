@@ -442,7 +442,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
     def full_layer(x, lw, i, extra):
         y, kv = _full_mixer(x, lw, cfg, rope, positions, decode_attend(
-            cache, i, lengths, live, block_s, mesh))
+            cache, i, lengths, live, block_s, mesh, cfg))
         x, n = _ffn(x + y, lw, cfg, valid)
         return x, (kv, n)
 

@@ -338,7 +338,8 @@ def flash_decode_ring(q, ring_k, ring_v, k_new, v_new, lengths, layer, *,
 def flash_decode_sharded(q, cache_k, cache_v, k_new, v_new, lengths, layer,
                          k_scale=None, v_scale=None, *, mesh,
                          batch_axes=(), head_axis=None, block_s: int,
-                         interpret: bool = False) -> jnp.ndarray:
+                         interpret: bool = False,
+                         scale: float | None = None) -> jnp.ndarray:
     """shard_map'd flash_decode_stacked: each device walks its local
     [KV/tp] head shard of the stacked cache (and its local batch shard on
     data-parallel meshes). The specs mirror parallel.kv_cache_specs, so
@@ -354,7 +355,7 @@ def flash_decode_sharded(q, cache_k, cache_v, k_new, v_new, lengths, layer,
         specs += (sspec, sspec)
         args += (k_scale, v_scale)
     run = functools.partial(flash_decode_stacked, block_s=block_s,
-                            interpret=interpret)
+                            interpret=interpret, scale=scale)
     return jax.shard_map(run, mesh=mesh, in_specs=specs, out_specs=qspec,
                          check_vma=False)(*args)
 
@@ -402,11 +403,12 @@ def kernel_block(n_heads: int, cache_k, mesh=None) -> int | None:
 @jax.named_scope("flash_decode")
 def decode_attention_auto(q, cache_k, cache_v, k_new, v_new, lengths, layer,
                           k_scale=None, v_scale=None, *, block_s: int,
-                          mesh=None) -> jnp.ndarray:
+                          mesh=None, scale: float | None = None) -> jnp.ndarray:
     """The kernel over layer ``layer`` of the stacked cache, under
     shard_map where ``mesh`` shards heads or batch. ``block_s`` is
     ``kernel_block``'s answer for these shapes: the caller asks first,
-    and takes ops.attention.decode_attention_appended where it is None."""
+    and takes ops.attention.decode_attention_appended where it is None.
+    ``scale``: the softmax scale where it is not D^-1/2 (paired heads)."""
     from .flash import interpret_env
 
     interpret = interpret_env()
@@ -418,10 +420,10 @@ def decode_attention_auto(q, cache_k, cache_v, k_new, v_new, lengths, layer,
         return flash_decode_sharded(
             q, cache_k, cache_v, k_new, v_new, lengths, layer, k_scale,
             v_scale, mesh=mesh, batch_axes=batch_axes, head_axis=head_axis,
-            block_s=block_s, interpret=interpret)
+            block_s=block_s, interpret=interpret, scale=scale)
     return flash_decode_stacked(q, cache_k, cache_v, k_new, v_new, lengths,
                                 layer, k_scale, v_scale, block_s=block_s,
-                                interpret=interpret)
+                                interpret=interpret, scale=scale)
 
 
 # -- the step's write ---------------------------------------------------------

@@ -16,8 +16,9 @@ is the identity, which is how they mask padding.
 
 Layout. The state is stored a GROUP at a time with N down the sublanes
 and the group's heads side by side along the lanes: ``[..., G, N, R]``,
-``R = (H / G) P`` (8 x 128 x 1024 at the published sizes: the bytes of
-[H, P, N], 4.19 MB a slot a layer). So x, dx, a and y are the rows the
+``R = (H / G) P`` (8 x 128 x 1024 at one published size, 4.19 MB a slot
+a layer; 1 x 128 x 4096 at another, ONE group of 64 heads, 2.10 MB: the
+bytes of [H, P, N]). So x, dx, a and y are the rows the
 projections give ([G, R] is [H P] reshaped), B and C are one column a
 group, the write is ``column x row`` and the read is a sum DOWN the
 sublanes (vector adds); stored [H, P, N] the read would be a reduction
@@ -34,7 +35,11 @@ of a live state a step and not a byte of an idle slot's.
 ``ssd_prefill`` (scope ``ssm/scan/chunk``): the same recurrence over a
 bucket or a chunk of a prompt from the slot's state, in the state-space-
 duality form over chunks of ``chunk`` tokens (the published
-``chunk_size``, 128), the state held in VMEM from chunk to chunk. With
+``chunk_size``, 128 or 256; a bucket under one chunk is one chunk of its
+own length), the state held in VMEM from chunk to chunk. A program is a
+(slot, group, cut of the group's lanes): the heads of a group share
+``C B^T`` and nothing else, so a group of 4,096 lanes runs as four cuts
+of 1,024, the first of which makes ``C B^T`` for the others. With
 ``cum_t`` the running sum of ``la`` inside a chunk,
 
     Y     = ((C B^T) .* L_h) (Delta X)_h + exp(cum) .* (C S_prev)
@@ -59,7 +64,14 @@ from . import kda
 _LANES = 128
 _SUBLANES = 8
 _DECODE_GROUPS = 2   # groups a decode work item holds: 1 MB of state
+#                      (one group where there is one: 2.1 MB at 4,096 lanes)
 _SLAB = 256          # lanes of a group's state updated at a time
+# lanes of a group a prefill program holds: every chunk of its Delta x,
+# its two scaled copies and y, 2.1 MB each at 512 tokens; a group wider
+# than this (ONE group of 4,096 lanes: 8.4 MB each, 33.5 MB before double
+# buffering) is cut along the lanes, whose heads share C B^T and nothing
+# else
+_PREFILL_LANES = 1024
 _VMEM = 64 * 1024 * 1024
 F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
@@ -295,14 +307,18 @@ def untouched(x):
 # -- prefill ------------------------------------------------------------------
 
 def _prefill_kernel(dx_ref, dxw_ref, e_ref, tot_ref, bt_ref, c_ref, cumc_ref,
-                    cumr_ref, s_in, y_ref, s_out, *, chunks: int, heads: int,
-                    p: int, width: int):
+                    cumr_ref, s_in, y_ref, s_out, *cb_ref, chunks: int,
+                    heads: int, p: int, width: int):
+    """One (slot, group, cut of the group's lanes): ``heads`` heads.
+    ``cb_ref`` (a group cut in several): C B^T of every chunk, made by
+    the group's first cut and read by the others."""
     Q = dx_ref.shape[3]
     per = width // p                       # heads a slab of lanes holds
     tril = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) \
         >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     lane_head = jax.lax.broadcasted_iota(jnp.int32, (Q, width), 1) // p
     s_out[0, 0] = s_in[0, 0]
+    first_cut = pl.program_id(2) == 0 if cb_ref else None
 
     def dot(a, b):
         return jnp.dot(a, b, preferred_element_type=F32, precision=_HI)
@@ -312,7 +328,13 @@ def _prefill_kernel(dx_ref, dxw_ref, e_ref, tot_ref, bt_ref, c_ref, cumc_ref,
         S = s_out[0, 0]                                   # [N, R]
         inter = dot(C, S) * e_ref[0, 0, c]                # [Q, R]
         s_out[0, 0] = S * tot_ref[0, 0, c] + dot(BT, dxw_ref[0, 0, c])
-        CB = dot(C, BT)                                   # [Q, Q]
+        if cb_ref:      # once a chunk a group, not once a cut
+            @pl.when(first_cut)
+            def _first_cut():
+                cb_ref[0][c] = dot(C, BT)
+            CB = cb_ref[0][c]
+        else:
+            CB = dot(C, BT)                               # [Q, Q]
         cumc, cumr = cumc_ref[0, 0, c], cumr_ref[0, 0, c]  # [Q, Hg], [Hg, Q]
         for s in range(heads // per):
             at = slice(s * width, (s + 1) * width)
@@ -330,54 +352,81 @@ def _prefill_kernel(dx_ref, dxw_ref, e_ref, tot_ref, bt_ref, c_ref, cumc_ref,
     jax.lax.fori_loop(0, chunks, chunk, 0)
 
 
+def prefill_cuts(r: int, p: int) -> int:
+    """Cuts of a group's ``r`` lanes (heads of ``p``) a prefill program
+    each: the fewest whose share is whole heads, whole lane rows and at
+    most ``_PREFILL_LANES``; 1 where none is (the group whole, as it
+    always ran)."""
+    for j in range(1, r // _LANES + 1):
+        rb = r // j
+        if r % j == 0 and rb <= _PREFILL_LANES and rb % p == 0 \
+                and rb % _LANES == 0:
+            return j
+    return 1
+
+
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_prefill(dx, la, bm, cm, state, *, chunk: int = 128,
                 interpret: bool = False):
     """The recurrence over T tokens from ``state``, a chunk of ``chunk``
     tokens at a time. dx [B, T, G, R]; la [B, T, H]; bm, cm [B, T, G, N];
     state [B, G, N, R] float32. T is padded to whole chunks with
-    identity positions. Returns (y [B, T, G, R] float32, the state after
-    token T - 1)."""
+    identity positions; a T under one chunk (whole sublanes) runs as ONE
+    chunk of its own length, which the chunk form is exact at. A group
+    wider than ``_PREFILL_LANES`` is cut along them (``prefill_cuts``):
+    the grid is (slot, group, cut), the cut innermost, and a group's
+    C B^T is made once. Returns (y [B, T, G, R] float32, the state after token T - 1)."""
     B, T, G, R = dx.shape
     H, N = la.shape[-1], bm.shape[-1]
     Hg = H // G
     P = R // Hg
-    Q = chunk
+    Q = chunk if T >= chunk or T % _SUBLANES else T
     pad = -T % Q
     dx, la, bm, cm = _pad_tokens((dx, la, bm, cm), pad)
     nC = (T + pad) // Q
-    # [B, T, ...] -> a group's chunks contiguous: [B, G, nC, Q, ...]
+    J = prefill_cuts(R, P)
+    Rb, Hb = R // J, Hg // J     # lanes and heads a cut
+
+    # [B, T, ...] -> a group's (a cut's) chunks contiguous:
+    # [B, G (J), nC, Q, ...]
     def chunks(a):
         a = a.astype(F32).reshape((B, nC, Q) + a.shape[2:])
         return jnp.moveaxis(a, 3, 1)
 
     cum = jnp.cumsum(la.astype(F32).reshape(B, nC, Q, H), axis=2)
-    cum_g = jnp.moveaxis(cum.reshape(B, nC, Q, G, Hg), 3, 1)  # [B,G,nC,Q,Hg]
+    # heads lie group-major and a cut is whole heads: [B, G J, nC, Q, Hb]
+    cum_g = jnp.moveaxis(cum.reshape(B, nC, Q, G * J, Hb), 3, 1)
     last = cum_g[:, :, :, -1:, :]
-    lanes = lambda a: jnp.repeat(a, P, axis=-1)  # noqa: E731  [.., Hg]->[.., R]
-    dx_g = chunks(dx)
-    width = P * max(1, min(_LANES, R) // P)
+    wide = lambda a: jnp.repeat(a, P, axis=-1)  # noqa: E731  [.., Hb]->[.., Rb]
+    dx_g = chunks(dx.reshape(B, nC * Q, G * J, Rb))
+    width = P * max(1, min(_LANES, Rb) // P)
 
-    def spec(*tail):
+    def spec(*tail):      # of a cut's own rows
         return pl.BlockSpec((1, 1, nC) + tail,
-                            lambda b, g: (b, g, 0) + (0,) * len(tail))
+                            lambda b, g, j: (b, g * J + j, 0)
+                            + (0,) * len(tail))
 
-    blk = pl.BlockSpec((1, 1, N, R), lambda b, g: (b, g, 0, 0))
+    def shared(*tail):    # of the group's B and C, every cut's
+        return pl.BlockSpec((1, 1, nC) + tail,
+                            lambda b, g, j: (b, g, 0) + (0,) * len(tail))
+
+    blk = pl.BlockSpec((1, 1, N, Rb), lambda b, g, j: (b, g, 0, j))
     y, state = pl.pallas_call(
-        functools.partial(_prefill_kernel, chunks=nC, heads=Hg, p=P,
+        functools.partial(_prefill_kernel, chunks=nC, heads=Hb, p=P,
                           width=width),
-        grid=(B, G),
-        in_specs=[spec(Q, R), spec(Q, R), spec(Q, R), spec(1, R),
-                  spec(N, Q), spec(Q, N), spec(Q, Hg), spec(Hg, Q), blk],
-        out_specs=[spec(Q, R), blk],
-        out_shape=[jax.ShapeDtypeStruct((B, G, nC, Q, R), F32),
+        grid=(B, G, J),
+        in_specs=[spec(Q, Rb), spec(Q, Rb), spec(Q, Rb), spec(1, Rb),
+                  shared(N, Q), shared(Q, N), spec(Q, Hb), spec(Hb, Q), blk],
+        out_specs=[spec(Q, Rb), blk],
+        out_shape=[jax.ShapeDtypeStruct((B, G * J, nC, Q, Rb), F32),
                    jax.ShapeDtypeStruct(state.shape, F32)],
+        scratch_shapes=[pltpu.VMEM((nC, Q, Q), F32)] if J > 1 else [],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM),
-    )(dx_g, dx_g * lanes(jnp.exp(last - cum_g)), lanes(jnp.exp(cum_g)),
-      lanes(jnp.exp(last)), jnp.swapaxes(chunks(bm), 3, 4), chunks(cm),
+    )(dx_g, dx_g * wide(jnp.exp(last - cum_g)), wide(jnp.exp(cum_g)),
+      wide(jnp.exp(last)), jnp.swapaxes(chunks(bm), 3, 4), chunks(cm),
       cum_g, jnp.swapaxes(cum_g, 3, 4), state.astype(F32))
     y = jnp.moveaxis(y, 1, 3).reshape(B, nC * Q, G, R)
     return y[:, :T], state
@@ -390,11 +439,11 @@ def prefill_auto(dx, la, bm, cm, state, chunk: int):
     from .flash import interpret_env
 
     G, R = dx.shape[2:]
+    T = dx.shape[1]
     if kernel_ok(G, bm.shape[-1], R) and (interpret_env()
                                           or chunk % _LANES == 0):
         return ssd_prefill(dx, la, bm, cm, state, chunk=chunk,
                            interpret=interpret_env())
-    T = dx.shape[1]
     # a bucket that is not whole chunks: identity positions
     dx, la, bm, cm = _pad_tokens((dx, la, bm, cm), -T % min(chunk, T))
     y, state = chunked_ref(dx, la, bm, cm, state, chunk)

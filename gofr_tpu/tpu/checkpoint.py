@@ -36,6 +36,8 @@ _QUANT_LEAVES = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
                  # the state-space family (models/nemotron_h.py): the
                  # mamba layer's two projections, the latent's pair
                  "w_ssm_in", "w_ssm_out", "w_latent_down", "w_latent_up",
+                 # its layer of two halves: the gated feed-forward's pair
+                 "w_ffn_in", "w_ffn_out",
                  # the sparse-latent family's indexer
                  # (models/dots3_note.py): its query and key projections;
                  # the key's norm, the heads' weights and the gates stay

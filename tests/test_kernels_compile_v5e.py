@@ -567,8 +567,8 @@ def test_ssd_decode_compiles_in_place(one_chip):
 
 @pytest.mark.parametrize("tokens", [32, 512])
 def test_ssd_prefill_compiles(one_chip, tokens):
-    """The smallest bucket (padded to one chunk of 128) and a whole chunk
-    of a prompt (four of them)."""
+    """The smallest bucket (one chunk of its own 32 tokens) and a whole
+    chunk of a prompt (four chunks of 128)."""
     from gofr_tpu.ops import ssd
 
     def arr(shape):
@@ -626,6 +626,135 @@ def test_state_space_family_leaves_states_and_stacks_where_they_lie(
     # 9.2 GB of weights, 4.5 GB of cache, and what a step needs
     assert 13.5e9 < mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < 14.3e9
+
+
+# -- the state-space family's layer of two halves at the published widths ------
+
+G1_L, G1_B, G1_N, G1_R, G1_H = 36, 96, 128, 4096, 64
+
+
+def test_ssd_decode_compiles_in_place_at_one_group(one_chip):
+    """64 heads x 64 x 128 float32 in ONE group of 4,096 lanes, 96 slots,
+    36 mamba layers: a work item is the whole 2.1 MB state of a (layer,
+    slot), and the 7.25 GB of states it returns are the ones it was
+    given."""
+    from gofr_tpu.ops import ssd
+
+    def arr(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(ssd.ssd_decode, donate_argnums=(0,)).lower(
+        arr((G1_L, G1_B, 1, G1_N, G1_R)), arr((), jnp.int32),
+        arr((G1_B, 1, G1_R)), arr((G1_B, G1_H)), arr((G1_B, 1, G1_N)),
+        arr((G1_B, 1, G1_N)), arr((G1_B,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    state_bytes = G1_L * G1_B * G1_N * G1_R * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 64
+
+
+@pytest.mark.parametrize("tokens", [32, 64, 128, 256, 512])
+def test_ssd_prefill_compiles_at_one_group(one_chip, tokens):
+    """Every engine bucket at chunks of 256: a bucket under a chunk is
+    one chunk of its own length; the group's 4,096 lanes go a cut of
+    1,024 a program (four of them), C B^T made by the first."""
+    from gofr_tpu.ops import ssd
+
+    def arr(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    assert ssd.prefill_cuts(G1_R, 64) == 4
+    compiled = ssd.ssd_prefill.lower(
+        arr((1, tokens, 1, G1_R)), arr((1, tokens, G1_H)),
+        arr((1, tokens, 1, G1_N)), arr((1, tokens, 1, G1_N)),
+        arr((1, 1, G1_N, G1_R)), chunk=256).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_cells_cache_keeps_a_float32_state():
+    """What ``granite-4.0-h-micro-int8.reason-sat`` allocates under its
+    own ``env`` (96 slots x 2,048, ``TPU_KV_DTYPE`` bfloat16): 36 states
+    of ONE group a slot in float32, whatever type the rows and the tails
+    have. A narrower state is a different result, not a faster one
+    (ISSUE 53), and at the published widths the cell's numerical check
+    does not see it: this does."""
+    from gofr_tpu.models import family
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "granite-4.0-h-micro-int8.json")) as f:
+        env = json.load(f)["env"]
+    cfg = _cell_config("granite-4.0-h-micro-int8")
+    cache = jax.eval_shape(lambda: family(cfg).init_cache(
+        cfg, int(env["TPU_SLOTS"]), int(env["TPU_MAX_SEQ"]),
+        dtype=jnp.dtype(env["TPU_KV_DTYPE"])))
+    assert cache.state.shape == (G1_L, G1_B, 1, G1_N, G1_R)
+    assert cache.state.dtype == jnp.float32
+    assert cache.k.dtype == cache.conv.dtype == jnp.bfloat16
+    assert cache.k.shape == (4, G1_B, 4, 2048, 128)     # paired rows
+
+
+@pytest.mark.parametrize("program", ["decode block", "chunk 512",
+                                     "prefill 256"])
+def test_layer_of_two_halves_leaves_states_stacks_and_table_where_they_lie(
+        one_chip, monkeypatch, program):
+    """``benchmarks/configs/granite-4.0-h-micro-int8.json`` as its cell
+    runs it: 40 layers in ONE scan, a switch between two mixers and the
+    feed-forward outside it, 96 slots. The attn branch hands the 7.25 GB
+    of states and the tails back through ``ssd_untouched``; no int8
+    stack is copied (``w_ssm_in`` is 8,576 columns wide for that), nor
+    the tied table; the attention layers' rows of two 64-wide KV heads
+    are read by ``flash_decode_stacked`` and written by
+    ``append_rows_stacked``, and a 256-token prefill runs the flash
+    kernel on paired heads; the engine fits the chip."""
+    cfg = _cell_config("granite-4.0-h-micro-int8")
+    compiled = _engine_lowered(monkeypatch, one_chip, cfg, 96, None,
+                               program).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" conditional\(", text)) >= 1
+    # states and tails, in the one branch that is no mamba layer
+    assert len(re.findall(r"%ssd_untouched[\w.]* = ", text)) == 2
+    if program == "decode block":
+        assert len(re.findall(r"%ssd_decode[\w.]* = ", text)) == 1
+        assert len(re.findall(r"%flash_decode_stacked[\w.]* = ", text)) == 1
+        assert len(re.findall(r"%append_rows_stacked[\w.]* = ", text)) == 1
+        assert "decode_attention_appended" not in text
+        # the state the decode kernel rewrites where it lies is the
+        # configuration's: float32, whole. The cell's numerical check
+        # cannot tell a bfloat16 state from this at these widths
+        # (PERF.md section 7, item 18(g)), so its type is held here
+        kernel, = re.findall(r"^.*%ssd_decode[\w.]* = .*$", text, re.M)
+        assert "f32[36,96,1,128,4096]" in kernel.split(" custom-call(")[0]
+        assert "f32[36,96,1,128,4096]" in kernel.split(" custom-call(")[1]
+    else:
+        assert len(re.findall(r"%ssd_prefill[\w.]* = ", text)) >= 1
+        assert ("%flash_causal_prefill" in text) == (program == "prefill 256")
+    assert not re.search(r"(?:bf16|f16|s8)\[36,96,1,128,4096\]", text)
+    results = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = ((?:s8|bf16|f32)\[[\d,]+\]\S*) "
+        r"([\w\-]+)\(", text, re.M)
+    assert results                      # the pattern still reads this HLO
+
+    def elements(shape):
+        n = 1
+        for d in shape.split("[")[1].split("]")[0].split(","):
+            n *= int(d)
+        return n
+
+    moved = [r for r in results if r[1] in ("copy", "transpose")
+             and (r[0].startswith("s8[") and elements(r[0]) >= 1 << 22
+                  or re.match(r"f32\[36,96,1,128,4096\]", r[0])
+                  or re.match(r"bf16\[36,96,13056\]", r[0])
+                  or re.match(r"bf16\[100352,2048\]", r[0])
+                  or re.match(r"bf16\[2048,100352\]", r[0])
+                  or re.match(r"bf16\[4,96,4,2048,128\]", r[0]))]
+    assert not moved
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (256 << 20)
+    # 3.4 GB of weights, 8.95 GB of cache, and what a step needs
+    assert 12.0e9 < mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 12.8e9
 
 
 # -- the sparse-latent family's programs at the published widths ---------------

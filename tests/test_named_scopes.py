@@ -217,3 +217,46 @@ def test_the_looped_familys_decode_attention_is_scoped(looped_engine,
         debug_info=True)
     assert _scoped(text, "attn/flash_decode")
     assert _scoped(text, "kv_write/kv_append")
+
+
+# -- the state-space family's layer of two halves ---------------------------------
+
+@pytest.fixture(scope="module")
+def two_halves_engine():
+    from gofr_tpu.models import nemotron_h
+
+    cfg = LLAMA_CONFIGS["tiny-ssm-dense"]
+    eng = GenerationEngine(cfg, nemotron_h.init(cfg, jax.random.PRNGKey(0)),
+                           slots=2, max_seq=64, prompt_buckets=(8, 16),
+                           decode_block=2)
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill", "chunk"])
+@pytest.mark.parametrize("scope", [
+    "embed", "ssm/in", "ssm/conv", "ssm/dt", "ssm/norm", "ssm/out",
+    "attn_qkv", "attn_out", "ffn/in", "ffn/out", "kv_write", "lm_head/tied"])
+def test_the_layer_of_two_halves_scopes(two_halves_engine, which, scope):
+    """The ``ssm/*`` scopes as the state-space family has them, a scope
+    on each of the feed-forward's two products and one on the tied
+    head's (benchmarks/metrics tells the feed-forward's and the head's
+    operations by their shapes, a device trace's reader by these)."""
+    assert _scoped(_lowered(two_halves_engine, which), scope), scope
+
+
+@pytest.mark.parametrize("which,scope", [("decode", "ssm/scan/decode"),
+                                         ("prefill", "ssm/scan/chunk"),
+                                         ("chunk", "ssm/scan/chunk")])
+def test_the_layer_of_two_halves_scan_scopes(two_halves_engine, which,
+                                             scope):
+    assert _scoped(_lowered(two_halves_engine, which), scope), scope
+
+
+def test_stats_say_a_slots_state_a_tokens_rows_and_paired_rows(
+        two_halves_engine):
+    stats = two_halves_engine.stats()
+    assert stats["state_bytes_per_slot"] == 4 * (8 * 16 * 16 * 4
+                                                 + 3 * 160 * 4)
+    assert stats["kv_bytes_per_token"] == 2 * 2 * 2 * 64 * 4
+    assert stats["kv_heads_per_row"] == 2
