@@ -518,6 +518,11 @@ def register_framework_metrics(m: Manager) -> None:
                   "prompt tokens the prompt programs computed (a prefix "
                   "hit's restored tokens are not among them); 1 - this / "
                   "app_tpu_prefill_positions_total is the padded share")
+    m.new_counter("app_tpu_moe_routed_positions_total",
+                  "positions of app_tpu_prefill_positions_total that ran in "
+                  "a prompt program whose experts go through the routed "
+                  "block dispatch (an expert model's programs past 128 "
+                  "positions; the rest run every expert on every token)")
     m.new_counter("app_tpu_chunk_rows_walked_total",
                   "cached rows the chunk programs' attention fetched: the "
                   "blocks under each chunk's start (a chunk at position 0 "

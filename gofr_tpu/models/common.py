@@ -31,8 +31,11 @@ class ModelConfig:
     # mixture-of-experts (0 experts = dense FFN)
     n_experts: int = 0
     experts_per_token: int = 2
-    # 0 = dense dispatch (every expert computes every token; exact, best
-    # below ~8 experts); > 0 = capacity-based grouped dispatch with
+    # 0 = nothing is dropped: dense dispatch (every expert computes every
+    # token) in the trainer, the decode block and prompt programs of up
+    # to 128 tokens, the routed block dispatch of models/moe.py in the
+    # serving prompt programs past it (llama.routes); > 0 =
+    # capacity-based grouped dispatch with
     # per-expert buffer capacity factor*T*k/E (tokens over capacity drop
     # — the standard Switch/Mixtral trade at scale)
     moe_capacity_factor: float = 0.0

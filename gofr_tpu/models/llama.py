@@ -30,6 +30,8 @@ from ..ops.attention import (causal_attention, chunk_attention,
 from ..ops.norms import rms_norm
 from ..ops.quant import QuantizedLinear, qmatmul, quantize_kv
 from ..ops.rope import apply_rope, rope_frequencies
+from . import moe
+from .blocks import experts_apart
 from .common import ModelConfig, dense_init
 
 
@@ -131,9 +133,36 @@ def unsupported_options(**_) -> list:
     return []
 
 
+def routes(cfg: ModelConfig, tokens: int) -> bool:
+    """Whether a serving prompt program of ``tokens`` positions runs its
+    experts through the routed dispatch (``_routed_experts``): a rule of
+    shapes. Up to ``moe.DENSE_TOKENS`` tokens every expert's stream
+    hides its rows and the dense dispatch costs nothing more (the decode
+    block, the small buckets); past it the dense dispatch multiplies
+    E/k times too much. A capacity factor asks for the grouped
+    dispatch, which is not this one."""
+    return (cfg.n_experts > 0 and cfg.moe_capacity_factor <= 0
+            and tokens > moe.DENSE_TOKENS)
+
+
 def serving_stats(cfg: ModelConfig, slots: int) -> dict:
-    """Nothing of these programs is said in the engine's stats."""
-    return {}
+    """What ``GenerationEngine.stats()`` says of these programs: where a
+    configuration has experts, the dispatch its prompt programs run past
+    ``routed_from_tokens`` positions (``routes``), the rows of a block
+    and of the buffer by the program's positions (``moe.expert_dispatch``)
+    and the path its blocks take (the loop: a float32 share leaves it).
+    The decode block is a dense dispatch and is not said."""
+    first = moe.DENSE_TOKENS + 1
+    if not routes(cfg, first):
+        return {}
+    sizes = [2 * moe.DENSE_TOKENS]
+    while sizes[-1] * 2 <= cfg.max_seq:
+        sizes.append(sizes[-1] * 2)
+    rows = {t: moe.expert_dispatch(cfg, t) for t in sizes}
+    return {"moe_prompt_dispatch": {
+        "routed_from_tokens": first, "path": "loop",
+        "block_rows": {t: bm for t, (bm, _) in rows.items()},
+        "buffer_rows": {t: n for t, (_, n) in rows.items()}}}
 
 
 def init(cfg: ModelConfig, key) -> dict:
@@ -362,24 +391,82 @@ def _combine_experts(gated, w_down, combine, mesh):
     return jnp.sum(shares, axis=0).astype(gated.dtype)
 
 
+@jax.named_scope("moe_experts_routed")
+def _routed_experts(hf, topi, topv, experts, cfg: ModelConfig, valid, mesh):
+    """The chosen experts' weighted sum by the dropless block dispatch of
+    ``models/moe.py`` (``moe.experts``: tables, fill, a loop over the
+    blocks that exist, the weighted gather), FLOPs by the assignments:
+    hf [T, D], topi/topv [T, k] from ``_route``, ``experts`` (the three
+    stacks WHOLE, [L, E, ...], and the layer's index: a block reads
+    expert (li, e) in place), valid [T] or None -> [T, D].
+
+    As ``_experts_down`` keeps the dense tail, float32 from the down
+    product's accumulator through the scale, the weights and the sum,
+    rounded once. Where ``mesh`` has a ``tp`` axis that splits ``F``,
+    each chip runs the whole dispatch on its slice of the three stacks
+    in a region manual over ``tp`` (and over every axis of one device:
+    ``_combine_experts`` says why) and hands out its float32 share; the
+    shares are summed outside, one all-reduce a layer. Left to GSPMD the
+    reduction lands inside the loop, one a block."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import AXIS_TP
+
+    stacks, li = experts
+
+    def share(hf, topi, topv, stacks, li, valid=None):
+        return moe.experts(hf, topi, topv, stacks, li, cfg, valid,
+                           out_dtype=jnp.float32)[0]
+
+    args = (hf, topi, topv, stacks, li) + (() if valid is None else (valid,))
+    tp = mesh.shape.get(AXIS_TP, 1) if mesh is not None else 1
+    if tp == 1 or cfg.ffn_dim % tp:
+        return share(*args).astype(hf.dtype)
+
+    def on_f(name, leaf):       # [L,E,D,F] / [L,E,F,D], a scale [L,E,out]
+        down = name == "w_down"
+        w = P(None, None, AXIS_TP) if down else P(None, None, None, AXIS_TP)
+        if isinstance(leaf, QuantizedLinear):
+            return QuantizedLinear(w, P() if down else P(None, None, AXIS_TP))
+        return w
+
+    manual = {a for a, n in mesh.shape.items() if a == AXIS_TP or n == 1}
+    specs = (P(), P(), P(), {k: on_f(k, v) for k, v in stacks.items()}, P())
+    shares = jax.shard_map(
+        lambda *a: share(*a)[None], mesh=mesh, axis_names=manual,
+        in_specs=specs + (P(),) * (valid is not None),
+        out_specs=P(AXIS_TP), check_vma=False)(*args)           # [tp,T,D]
+    return jnp.sum(shares, axis=0).astype(hf.dtype)
+
+
 def _moe_ffn(h, layer_w, cfg: ModelConfig, valid=None, mesh=None):
     """Mixture-of-experts SwiGLU FFN: softmax router, top-k expert
-    selection with renormalized weights, dense-dispatch combine.
+    selection with renormalized weights, and one of two dispatches by
+    what the layer is handed.
 
     Dense dispatch (every expert computes every token, combined by a
     [B,S,E] weight matrix that is zero off the top-k) keeps shapes
     static and the whole layer one fused einsum chain — XLA-friendly and
-    exactly correct. It spends E/k times the FLOPs of routed dispatch,
-    which is the right trade below ~8 experts per chip; set
-    ``cfg.moe_capacity_factor > 0`` to switch to capacity-based grouped
-    dispatch (_moe_ffn_grouped) when expert counts grow past what dense
-    dispatch amortizes.
+    exactly correct. It spends E/k times the FLOPs of a routed dispatch,
+    which is free while each expert's weight stream hides its rows (up
+    to ``moe.DENSE_TOKENS`` tokens: the decode block, the small
+    buckets) and is what the trainer differentiates at any size.
+
+    Routed dispatch (``_routed_experts``), where the layer comes with
+    ``layer_w["experts"]`` = (the expert stacks whole, the layer's
+    index) instead of its slices of them: the serving prompt programs
+    past ``moe.DENSE_TOKENS`` tokens (``routes``). Nothing is dropped
+    and a token's result does not depend on its batch-mates, as with the
+    dense dispatch; the two share ``_route`` and nothing else.
+
+    ``cfg.moe_capacity_factor > 0`` switches every program to the
+    capacity-based grouped dispatch (_moe_ffn_grouped).
 
     Weights: router [D,E]; w_gate/w_up [E,D,F]; w_down [E,F,D] — dense
     or int8 QuantizedLinear stacks (TPU_QUANT=int8 quantizes experts
     per-output-channel like every other projection).
-    ``mesh``: the jit's mesh, for the one collective of the dense
-    dispatch (_combine_experts).
+    ``mesh``: the jit's mesh, for the one collective of either dispatch
+    (_combine_experts, _routed_experts).
     Returns (ffn_out [B,S,D], router_probs [B,S,E] f32 — the aux
     load-balancing loss input, collected by the training path).
     """
@@ -389,6 +476,11 @@ def _moe_ffn(h, layer_w, cfg: ModelConfig, valid=None, mesh=None):
     probs, topv, topi = _route(h.reshape(B * S, D), layer_w["router"],
                                cfg.experts_per_token)
     probs = probs.reshape(B, S, -1)
+    if "experts" in layer_w:
+        y = _routed_experts(
+            h.reshape(B * S, D), topi, topv, layer_w["experts"], cfg,
+            None if valid is None else valid.reshape(B * S), mesh)
+        return y.reshape(B, S, D), probs
     topv = topv.reshape(B, S, -1)
     topi = topi.reshape(B, S, -1)
     with jax.named_scope("moe_experts"):
@@ -514,11 +606,36 @@ def logits_dtype(cfg: ModelConfig):
     return jnp.dtype(jnp.float32) if cfg.tie_embeddings else cfg.jdtype
 
 
+def _scanned(layers: dict, cfg: ModelConfig, tokens: int):
+    """(what a serving prompt program's layer scan slices, the expert
+    stacks it leaves whole or None) for a program of ``tokens``
+    positions. Where the program routes (``routes``) the three expert
+    stacks stay beside the scan and the layer's index goes through it in
+    their place: a block of the dispatch reads expert (layer, e) where
+    it lies, and handed a layer's slice the scan copies all the experts
+    out of the stack every layer (``moe.experts``)."""
+    if not routes(cfg, tokens):
+        return layers, None
+    whole, rest = experts_apart(layers)
+    index = jnp.arange(layers["router"].shape[0], dtype=jnp.int32)
+    return {**rest, "layer_index": index}, whole
+
+
+def _handed(layer_w: dict, whole):
+    """The layer's weights as ``layer`` takes them: ``_scanned``'s slice,
+    with ``experts`` = (the stacks whole, this layer's index) where they
+    were kept out of the scan."""
+    if whole is None:
+        return layer_w
+    rest = {k: v for k, v in layer_w.items() if k != "layer_index"}
+    return {**rest, "experts": (whole, layer_w["layer_index"])}
+
+
 def _causal_scan(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                  lengths: jnp.ndarray | None, rope_max: int, rope_tables,
                  constrain, collect_kv: bool, flash: bool = False,
                  attend_override=None, collect_router: bool = False,
-                 adapter=None, mesh=None):
+                 adapter=None, mesh=None, serving: bool = False):
     """Shared causal body for forward/prefill: embed, mask, scan layers.
 
     Returns (x [B,S,D], kv  — stacked [L,B,S,KV,hd] pair when
@@ -535,6 +652,11 @@ def _causal_scan(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     ``attend_override(q, k, v, lengths)``: replaces the attention
     entirely — the hook sequence-parallel training uses to route through
     ring attention (ops.ring_attention) on sp>1 meshes.
+
+    ``serving``: a serving prompt program, whose experts route past
+    ``moe.DENSE_TOKENS`` positions (``_scanned``). ``forward`` is not
+    one: the trainer differentiates through it, and the routed
+    dispatch's loop has a traced trip count and no reverse mode.
     """
     B, S = tokens.shape
     if lengths is None:
@@ -568,16 +690,20 @@ def _causal_scan(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     with jax.named_scope("embed"):
         x = constrain(params["embedding"][tokens].astype(cfg.jdtype))
 
+    layers, whole = _scanned(params["layers"], cfg, B * S) if serving \
+        else (params["layers"], None)
+
     def body(x, layer_w):
-        x, kv, probs = layer(x, layer_w, cfg, cos_g, sin_g, None,
-                             kv_write=lambda k, v: (k, v), attend=attend,
-                             valid=valid, adapter=adapter, mesh=mesh)
+        x, kv, probs = layer(x, _handed(layer_w, whole), cfg, cos_g, sin_g,
+                             None, kv_write=lambda k, v: (k, v),
+                             attend=attend, valid=valid, adapter=adapter,
+                             mesh=mesh)
         # Training drops the per-layer k/v so the scan never materializes
         # the [L,B,S,KV,hd] stacks it would otherwise carry.
         return constrain(x), (kv if collect_kv else None,
                               probs if collect_router else None)
 
-    x, (kv, router_probs) = jax.lax.scan(body, x, params["layers"])
+    x, (kv, router_probs) = jax.lax.scan(body, x, layers)
     return x, kv, lengths, router_probs
 
 
@@ -625,7 +751,7 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     x, (k_stack, v_stack), lengths, _ = _causal_scan(
         params, cfg, tokens, lengths, cache.capacity, rope_tables,
         constrain=None, collect_kv=True, flash=flash, adapter=adapter,
-        mesh=mesh)
+        mesh=mesh, serving=True)
     # k_stack: [L, B, S, KV, hd] -> write into the cache's first S slots
     if S > cache.capacity:
         raise ValueError(f"prompt length {S} exceeds cache capacity {cache.capacity}")
@@ -681,7 +807,7 @@ def prefill_kv(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     x, (k_stack, v_stack), lengths, _ = _causal_scan(
         params, cfg, tokens, lengths, rope_max or tokens.shape[1],
         rope_tables, constrain=None, collect_kv=True, flash=flash,
-        adapter=adapter, mesh=mesh)
+        adapter=adapter, mesh=mesh, serving=True)
     return logits_at(params, cfg, x, logit_pos), k_stack, v_stack, lengths
 
 
@@ -704,7 +830,8 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     last chunk. Returns (logits [B, C, V] f32 — or None when
     ``compute_logits`` is False, sparing mid-prompt chunks the lm_head
     matmul — and the cache with KV written). ``mesh``: the jit's mesh,
-    for the expert layer's collective (_combine_experts).
+    for the expert layer's collective (_combine_experts,
+    _routed_experts: a chunk past ``moe.DENSE_TOKENS`` tokens routes).
     """
     B, C = tokens.shape
     cos, sin = rope_tables or get_rope_tables(cfg, cache.capacity)
@@ -715,6 +842,8 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
         x = params["embedding"][tokens].astype(cfg.jdtype)
 
+    layers, whole = _scanned(params["layers"], cfg, B * C)
+
     def body(x, xs):
         layer_w, k_layer, v_layer, ks_layer, vs_layer = xs
 
@@ -722,14 +851,13 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             return chunk_attention(q, k_layer, v_layer, k_new, v_new, start,
                                    ks_layer, vs_layer)
 
-        x, kv, _ = layer(x, layer_w, cfg, cos, sin, positions,
-                         kv_write=lambda k, v: (k, v), attend=attend,
-                         adapter=adapter, mesh=mesh)
+        x, kv, _ = layer(x, _handed(layer_w, whole), cfg, cos, sin,
+                         positions, kv_write=lambda k, v: (k, v),
+                         attend=attend, adapter=adapter, mesh=mesh)
         return x, kv
 
     x, (k_chunk, v_chunk) = jax.lax.scan(
-        body, x, (params["layers"], cache.k, cache.v,
-                  cache.k_scale, cache.v_scale))
+        body, x, (layers, cache.k, cache.v, cache.k_scale, cache.v_scale))
     cache = write_kv(cache, k_chunk, v_chunk, (0, 0, 0, start, 0),
                      cache.lengths)
     if not compute_logits:

@@ -1,7 +1,10 @@
 """The routed feed-forward of the sparse families: one module that no
-family owns (the latent, hybrid, window, conv and state-space families
-all run it; ``models/llama.py``'s dense dispatch for eight experts is
-Mixtral's and the trainer's and shares no logic with it).
+family owns. The latent, hybrid, window, conv and state-space families
+run all of it (``moe_ffn``); the llama family keeps its own router
+(softmax, ``llama._route``) and hands its prompt programs past
+``DENSE_TOKENS`` tokens to the expert dispatch here (``experts``), each
+chip on its slice of ``F``; its decode block, its small buckets and the
+trainer stay on ``models/llama.py``'s dense dispatch.
 
 By equation, ``h`` the normed residual stream: ``s = sigmoid(h W_g)`` in
 float32, selection by ``s + bias`` limited to the ``topk_groups`` best
@@ -96,13 +99,14 @@ def route(hf, router, bias, cfg: ModelConfig):
     return topi, w
 
 
-def _swiglu(x, gate, up, down):
-    return qmatmul(jax.nn.silu(qmatmul(x, gate)) * qmatmul(x, up), down)
+def _swiglu(x, gate, up, down, out_dtype=None):
+    return qmatmul(jax.nn.silu(qmatmul(x, gate)) * qmatmul(x, up), down,
+                   out_dtype)
 
 
-def _relu2(x, up, down):
+def _relu2(x, up, down, out_dtype=None):
     """The two-matrix expert: ``W2 relu(W1 x)^2``, no gate."""
-    return qmatmul(jnp.square(jax.nn.relu(qmatmul(x, up))), down)
+    return qmatmul(jnp.square(jax.nn.relu(qmatmul(x, up))), down, out_dtype)
 
 
 def expert_width(cfg: ModelConfig) -> int:
@@ -117,13 +121,37 @@ def expert_stacks(cfg: ModelConfig) -> tuple[str, ...]:
     return EXPERT_STACKS if cfg.expert_act == "swiglu" else EXPERT_STACKS[1:]
 
 
+# tokens up to which a dense dispatch (every expert on every token) costs
+# what a routed one does: an int8 weight byte streamed buys the chip about
+# 240 FLOPs and a row through it takes 2, so each expert's stream hides
+# about 120 rows. Past it a prompt program routes, and its blocks grow
+DENSE_TOKENS = 128
+
+
 def expert_dispatch(cfg: ModelConfig, tokens: int) -> tuple[int, int]:
     """(rows of a dispatch block, rows of the padded dispatch buffer) for
     ``tokens`` tokens. A block is one bfloat16 sublane tile for a decode
-    batch (a few tokens an expert), more where a prompt brings many; the
-    buffer holds at most min(k, held) held assignments a token and less
-    than a block of padding an expert."""
-    bm = 16 if tokens <= 128 else 64
+    batch (a few tokens an expert) and 64 rows where a prompt brings
+    many. It is ``DENSE_TOKENS`` rows where an expert's assignments
+    (``tokens * k / n_experts`` on average) are half such a block or
+    more: a loop turn pays for its expert whole whatever rows it holds
+    (on a v5e about 65 us for 44 MB of int8 beside 0.42 us a row), so an
+    expert's rows should stand in few blocks. At eight experts that is a
+    prompt of 256 tokens or more (a layer's experts alone, one chip's
+    quarter: 0.81 ms at 256 tokens where 64 rows take 1.00 and the dense
+    dispatch 1.17). At 64 experts and more, whose experts get 13-32 of a
+    512-token chunk and fit one block of 64, no prompt. Blocks cut to
+    five quarters of the average (80 and 160 rows) were faster still
+    with a router that spreads its tokens evenly and no faster or slower
+    in the served model, whose router does not (PERF.md, Findings PR
+    54). The buffer holds at most min(k, held) held assignments a token
+    and less than a block of padding an expert."""
+    if tokens <= DENSE_TOKENS:
+        bm = 16
+    elif 2 * tokens * cfg.experts_per_token < DENSE_TOKENS * cfg.n_experts:
+        bm = 64
+    else:
+        bm = DENSE_TOKENS
     Eh = n_held(cfg)
     nb_max = (tokens * min(cfg.experts_per_token, Eh)
               + Eh * (bm - 1) + bm - 1) // bm
@@ -162,9 +190,12 @@ def serving_stats(cfg: ModelConfig, slots: int) -> dict:
     return {"moe_decode_dispatch": said}
 
 
-def blocks_loop(xs, blk_expert, n_blocks, stacks, li, bm: int):
+def blocks_loop(xs, blk_expert, n_blocks, stacks, li, bm: int,
+                out_dtype=None):
     """The dispatch buffer's live blocks through their experts, one loop
-    turn a block: xs [rows, D] -> [rows, D], zero past ``n_blocks``."""
+    turn a block: xs [rows, D] -> [rows, D], zero past ``n_blocks``, in
+    ``out_dtype`` (xs' own unless given: a float32 result is the down
+    product as it was accumulated and scaled, not rounded)."""
     def one(a, e):  # a [Ls, Eh, ...] -> a[li, e]
         return jax.lax.dynamic_index_in_dim(
             a.reshape((-1,) + a.shape[2:]), li * a.shape[1] + e, 0,
@@ -175,15 +206,19 @@ def blocks_loop(xs, blk_expert, n_blocks, stacks, li, bm: int):
             return QuantizedLinear(one(leaf.w, e), one(leaf.scale, e))
         return one(leaf, e)
 
+    # a caller that asks for no type calls the expert as it always did
+    asked = {} if out_dtype is None else {"out_dtype": out_dtype}
+
     def body(j, out):
         e = blk_expert[j]
         x = jax.lax.dynamic_slice_in_dim(xs, j * bm, bm, axis=0)
         up, down = at(stacks["w_up"], e), at(stacks["w_down"], e)
-        y = _swiglu(x, at(stacks["w_gate"], e), up, down) \
-            if "w_gate" in stacks else _relu2(x, up, down)
+        y = _swiglu(x, at(stacks["w_gate"], e), up, down, **asked) \
+            if "w_gate" in stacks else _relu2(x, up, down, **asked)
         return jax.lax.dynamic_update_slice_in_dim(out, y, j * bm, axis=0)
 
-    return jax.lax.fori_loop(0, n_blocks, body, jnp.zeros_like(xs))
+    return jax.lax.fori_loop(0, n_blocks, body,
+                             jnp.zeros_like(xs, dtype=out_dtype))
 
 
 def blocks_kernel(xs, blk_expert, n_blocks, stacks, li, bm: int,
@@ -289,7 +324,8 @@ def _fill(hf, dest, valid, rows: int):
 
 
 @jax.named_scope("moe/experts")
-def experts(hf, topi, w, stacks, li, cfg: ModelConfig, valid=None):
+def experts(hf, topi, w, stacks, li, cfg: ModelConfig, valid=None,
+            out_dtype=None):
     """Sum over the HELD experts each token chose, weighted.
 
     hf [T, D]: what the experts read, D the dispatch's width (the
@@ -302,7 +338,12 @@ def experts(hf, topi, w, stacks, li, cfg: ModelConfig, valid=None):
     every layer, every step: 18.7 of a 36.5 ms step, PERF.md Findings
     PR 28); valid [T] bool: rows that are tokens (padding and idle slots
     are not dispatched). Returns (y [T, D], assignments a held expert
-    [Eh] int32, blocks run: int32 scalar).
+    [Eh] int32, blocks run: int32 scalar). ``out_dtype``: the result's
+    type where it is not ``hf``'s. float32 is for a caller that holds a
+    slice of ``F`` and adds its result to other chips' before it rounds
+    (``llama._routed_experts``): the blocks' down products stay float32
+    through the weights and the sum over k, on the loop (the kernel
+    writes the activations' type).
 
     The assignments stand in a padded buffer by expert, in the order
     they come within one (``tables``: counted, not sorted; absent
@@ -315,14 +356,15 @@ def experts(hf, topi, w, stacks, li, cfg: ModelConfig, valid=None):
     counts, n_blocks, blk_expert, dest = tables(topi, valid, n_held(cfg),
                                                  bm, rows // bm)
     xs = _fill(hf, dest, valid, rows)
-    if experts_on_kernel(cfg, hf.dtype):
+    if out_dtype is None and experts_on_kernel(cfg, hf.dtype):
         out = blocks_kernel(xs, blk_expert, n_blocks, stacks, li, bm)
     else:
-        out = blocks_loop(xs, blk_expert, n_blocks, stacks, li, bm)
+        out = blocks_loop(xs, blk_expert, n_blocks, stacks, li, bm,
+                          out_dtype)
     # an assignment that was not dispatched reads the last row, times 0
     y = out[jnp.minimum(dest, rows - 1)].astype(jnp.float32) \
         * jnp.where(dest < rows, w, 0.0)[..., None]
-    return jnp.sum(y, axis=1).astype(hf.dtype), counts, n_blocks
+    return jnp.sum(y, axis=1).astype(out_dtype or hf.dtype), counts, n_blocks
 
 
 def moe_ffn(h, lw, cfg: ModelConfig, valid=None):

@@ -32,21 +32,24 @@ def quantize_int8(w: jnp.ndarray, axis: int = 0) -> QuantizedLinear:
 
 
 @jax.named_scope("qmatmul")
-def qmatmul(x: jnp.ndarray, qw: "QuantizedLinear | jnp.ndarray") -> jnp.ndarray:
+def qmatmul(x: jnp.ndarray, qw: "QuantizedLinear | jnp.ndarray",
+            out_dtype=None) -> jnp.ndarray:
     """x @ w for quantized or plain weights.
 
-    x: [..., in]; returns [..., out] in x.dtype. For QuantizedLinear the
-    int8 tensor is upcast in-register (fused by XLA) and scaled after the
-    contraction, keeping the accumulation in f32.
+    x: [..., in]; returns [..., out] in x.dtype (``out_dtype`` where
+    given: float32 hands out the accumulator unrounded). For
+    QuantizedLinear the int8 tensor is upcast in-register (fused by XLA)
+    and scaled after the contraction, keeping the accumulation in f32.
     """
+    out_dtype = out_dtype or x.dtype
     if isinstance(qw, QuantizedLinear):
         y = jax.lax.dot_general(
             x, qw.w.astype(x.dtype),
             dimension_numbers=(((x.ndim - 1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        return (y * qw.scale).astype(x.dtype)
-    return jnp.dot(x, qw, preferred_element_type=jnp.float32).astype(x.dtype)
+        return (y * qw.scale).astype(out_dtype)
+    return jnp.dot(x, qw, preferred_element_type=jnp.float32).astype(out_dtype)
 
 
 def dequantize(qw: QuantizedLinear, dtype=jnp.bfloat16) -> jnp.ndarray:

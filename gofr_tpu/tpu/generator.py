@@ -625,8 +625,13 @@ class GenerationEngine:
         self._split_first: list[int] | None = None
         self._prefill_n = dict.fromkeys(
             ("admissions", "split", "prompt_tokens", "positions",
-             "cache_rows_walked", "cache_rows_reserved"), 0)
+             "routed_positions", "cache_rows_walked",
+             "cache_rows_reserved"), 0)
         self._walk_block = self._fam.chunk_block(cfg, self.max_seq)
+        # positions from which a prompt program of this family routes
+        # its experts (None: it has none, or no such rule)
+        self._routed_from = said.get("moe_prompt_dispatch", {}).get(
+            "routed_from_tokens")
 
         # Paged (block-pool) KV cache: slots share a pool of fixed
         # T-token blocks via a host-owned block table instead of owning
@@ -1518,9 +1523,10 @@ class GenerationEngine:
         bucket, the lengths that leave it for two dispatches and into
         what: prefill_plan.ranges), the admissions that ran a prompt
         program and those of them that split, the share of the
-        positions run that held no prompt token, and of the rows the
-        chunk programs' slots reserve the share their attention walked
-        (_count_chunk)."""
+        positions run that held no prompt token, the share that ran in a
+        program whose experts route (_count_program; None where the
+        family has no such rule), and of the rows the chunk programs'
+        slots reserve the share their attention walked (_count_chunk)."""
         n = dict(self._prefill_n)
         costs = self._prefill_costs
         return {
@@ -1534,6 +1540,9 @@ class GenerationEngine:
             "padded_pct": (round(100 * (1 - n["prompt_tokens"]
                                         / n["positions"]), 2)
                            if n["positions"] else None),
+            "routed_pct": (round(100 * n["routed_positions"]
+                                 / n["positions"], 2)
+                           if n["positions"] and self._routed_from else None),
             "walked_pct": (round(100 * n["cache_rows_walked"]
                                  / n["cache_rows_reserved"], 2)
                            if n["cache_rows_reserved"] else None),
@@ -2243,6 +2252,7 @@ class GenerationEngine:
             jnp.int32(idx), jnp.float32(req.temperature),
             jnp.int32(req.top_k), self._key, jnp.int32(req.seed),
             jnp.int32(req.pos_base), self._adapter1(req))
+        self._count_program(Sb)
         self._count_prefill(L, Sb)
         return self._first_token(tok, lp)
 
@@ -2263,6 +2273,7 @@ class GenerationEngine:
             jnp.asarray(req.prompt[None, :b1]), jnp.int32(b1),
             jnp.int32(idx), jnp.float32(0.0), jnp.int32(0), self._key,
             jnp.int32(0), jnp.int32(0), self._adapter1(req))
+        self._count_program(b1)
         t0c = time.monotonic() if tl is not None else 0.0
         tok, lp, Sr = self._final_chunk("cache", idx, req, b1)
         if tl is not None:
@@ -2288,12 +2299,25 @@ class GenerationEngine:
             if split:
                 inc("app_tpu_prefill_split_total")
 
-    def _count_chunk(self, start: int) -> None:
-        """One dispatch of a chunk program at ``start``: the cached
-        rows its attention fetched (the blocks under its start) beside
-        the rows the slot reserves, stats()["scheduler"]["prefill"] and
-        two counters. A family whose chunk program walks under no
-        cursor counts neither."""
+    def _count_program(self, positions: int) -> None:
+        """One dispatch of a prompt program of ``positions`` positions:
+        where the family says its programs of that size route their
+        experts (stats()["moe_prompt_dispatch"]), they are routed
+        positions, stats()["scheduler"]["prefill"] and a counter."""
+        if self._routed_from and positions >= self._routed_from:
+            self._prefill_n["routed_positions"] += positions
+            if self.metrics is not None:
+                self.metrics.increment_counter(
+                    "app_tpu_moe_routed_positions_total", by=positions)
+
+    def _count_chunk(self, start: int, positions: int) -> None:
+        """One dispatch of a chunk program of ``positions`` positions at
+        ``start`` (_count_program): the cached rows its attention
+        fetched (the blocks under its start) beside the rows the slot
+        reserves, stats()["scheduler"]["prefill"] and two counters. A
+        family whose chunk program walks under no cursor counts
+        neither."""
+        self._count_program(positions)
         block = self._walk_block
         if not block:
             return
@@ -2450,7 +2474,7 @@ class GenerationEngine:
                 jnp.int32(slot), jnp.int32(0), jnp.int32(0),
                 jnp.float32(0.0), jnp.int32(0), self._key,
                 jnp.int32(0), jnp.int32(0), self._adapter1(req)))
-            self._count_chunk(pos)
+            self._count_chunk(pos, T)
             pos += T
             req.stream.chunks += 1
             if self._tl is not None:
@@ -2532,7 +2556,7 @@ class GenerationEngine:
             jnp.int32(req.top_k), self._key, jnp.int32(req.seed),
             jnp.int32(req.pos_base), self._adapter1(req))
         setattr(self, attr, new_cache)
-        self._count_chunk(begin)
+        self._count_chunk(begin, Sb)
         return tok, lp, Sb
 
     def _expire_mid_lattice(self, req: _Request, pos: int) -> bool:
@@ -2630,6 +2654,7 @@ class GenerationEngine:
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
                 self._key, jnp.int32(req.seed), jnp.int32(req.pos_base),
                 self._adapter1(req))
+            self._count_program(Sb)
             self._count_prefill(L, Sb)
             # the row goes in AFTER the fetch: the block _first_token
             # queues behind the prefill holds this slot inactive at

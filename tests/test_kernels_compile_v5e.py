@@ -227,8 +227,8 @@ def _cell_config(name, **cut):
 
 def _engine_lowered(monkeypatch, sharding, cfg, slots, kv_dtype, program,
                     mesh=None):
-    """``EnginePrograms``' own decode block, 256-token prefill or
-    512-token chunk program for ``cfg``, lowered from shapes alone
+    """``EnginePrograms``' own decode block, prefill ("prefill 256") or
+    mid-chunk program ("chunk 512") for ``cfg``, lowered from shapes alone
     (nothing is allocated): int8 weights, ``slots`` x 2,048, the kernels
     on (a CPU process answers no to ``tpu_backend_ok``). On ``sharding``,
     or on ``mesh`` with weights and cache sharded as an engine places
@@ -273,9 +273,10 @@ def _engine_lowered(monkeypatch, sharding, cfg, slots, kv_dtype, program,
             cache, params,
             arr((slots, programs.PACK_EXTRA + programs.EOS_MAX)),
             (b, arr((slots,), jnp.bool_), b, b), key)
-    if program == "chunk 512":
+    if program.startswith("chunk"):
         return jits["_chunk_mid_jit"].lower(
-            cache, params, arr((1, 512)), arr(()), arr(()), arr(()), arr(()),
+            cache, params, arr((1, int(program.split()[1]))), arr(()),
+            arr(()), arr(()), arr(()),
             arr((), jnp.float32), arr(()), key, arr(()), arr(()), None)
     return jits["_prefill_jit"].lower(
         cache, params, arr((1, int(program.split()[1]))), arr(()), arr(()),
@@ -345,11 +346,13 @@ def _lowered_tp4(monkeypatch, mesh, program):
 
 
 @pytest.mark.parametrize("program,tokens", [("decode block", 40),
-                                            ("prefill 512", 512),
-                                            ("chunk 512", 512)])
+                                            ("prefill 128", 128),
+                                            ("chunk 128", 128)])
 def test_experts_are_combined_before_they_cross_the_chips(tp4, monkeypatch,
                                                           program, tokens):
-    """``llama._combine_experts``, read off the compiled programs. (a) No
+    """``llama._combine_experts``, read off the compiled programs that
+    keep the dense dispatch (up to 128 tokens: the prompt programs past
+    it route, ``test_prompt_programs_route_their_experts_in_place``). (a) No
     all-reduce carries the expert axis (the parent's was
     ``f32[40,1,4096,8]``, 5.24 MB a layer where the sum is 0.66), and the
     expert layer's one all-reduce adds float32 shares of [tokens, 4096]
@@ -407,6 +410,76 @@ def test_experts_are_combined_before_they_cross_the_chips(tp4, monkeypatch,
         # as on one chip (test_qk_projections_read_their_weights_in_place)
         tables = _made(text, f"2,{B},2,{SMAX}")
         assert tables and not tables & _TABLE_MOVES
+
+@pytest.mark.parametrize("program", ["prefill 512", "chunk 512"])
+def test_prompt_programs_route_their_experts_in_place(tp4, monkeypatch,
+                                                      program):
+    """``llama._routed_experts`` on the four chips, read off Mixtral's
+    512-position programs. (a) The block loop (``moe.blocks_loop``, a
+    while inside the layer loop) holds no collective; the expert layer
+    has one, outside it: the all-reduce of float32 shares of
+    [512, 4096], added in float32 (the compiler folds the cast to
+    bfloat16 into its output). (b) A block reads its expert where it
+    lies: no instruction of its own copies, transposes or converts an
+    int8 expert (a chip's 4096 x 3584 of one) or the stack, and nothing
+    that large is bfloat16: the slice and the convert are inside the
+    matmul's fusion. (c) The down product leaves the loop in float32:
+    the loop carries the dispatch buffer's result as
+    ``f32[2048, 4096]`` (128-row blocks: 16 of them at most)."""
+    compiled = _lowered_tp4(monkeypatch, tp4, program).compile()
+    text = compiled.as_text()
+    comps, _ = _computations(text)
+    _, own, _ = _outside_conditionals(text)
+    inst = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = \(?(\w+)\[([\d,]*)\]\S* "
+                      r"([\w\-]+)\(%?([\w.\-]*)")
+
+    def parsed(lines):
+        return [_Inst(m[2], [int(d) for d in m[3].split(",") if d], m[4],
+                      m[5], ln) for ln in lines if (m := inst.match(ln))]
+
+    # the block loop's body: the while under moe/experts
+    loops = [ln for ln in own if " while(" in ln
+             and "/moe_experts_routed/" in ln]
+    assert len(loops) == 1
+    body = re.search(r"body=%?([\w.\-]+)", loops[0]).group(1)
+    turn = parsed(comps[body])
+    assert len(turn) > 5
+    # (a)
+    collective = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+                  "collective-permute")
+    assert not [i.line[:120] for i in turn
+                if i.opcode.startswith(collective)]
+    insts = {m[1]: _Inst(m[2], [int(d) for d in m[3].split(",") if d],
+                         m[4], m[5], ln)
+             for ln in own if (m := inst.match(ln))}
+    sums = [i for i in insts.values() if i.opcode.startswith("all-reduce")
+            and "/moe_experts_routed/" in i.line]
+    assert len(sums) == 1 and sums[0].dims == [512, 4096]
+    shares = insts[sums[0].operand]
+    assert shares.dtype == "f32" and shares.dims == [512, 4096]
+    region = re.search(r"to_apply=%?([\w.\-]+)", sums[0].line).group(1)
+    assert re.search(rf"^%?{re.escape(region)} \([\w.]+: f32\[\], "
+                     rf"[\w.]+: f32\[\]\) -> f32\[\]", text, re.M)
+    # (b)
+    one_expert = 4096 * 3584
+    moved = [i[:3] for i in insts.values() if i.dtype in ("s8", "bf16")
+             and 3584 in i.dims and math.prod(i.dims) >= one_expert
+             and i.opcode in ("copy", "transpose", "fusion", "convert")]
+    assert not moved
+    types = dict(re.findall(r"^\s*%?([\w.\-]+) = (\w+\[[\d,]*\])",
+                            "\n".join(comps[body]), re.M))
+    stacks = {"s8[16,4096,3584]", "s8[16,3584,4096]"}   # [L * E, ...]
+    reads = [i for i in turn if i.opcode == "fusion" and stacks & {
+        types.get(o) for o in re.findall(
+            r"%([\w.\-]+)", re.search(r" fusion\(([^)]*)\)", i.line)[1])}]
+    assert len(reads) == 3          # gate, up, down: each takes the stack
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    # (c)
+    written = [i for i in turn if i.dims == [2048, 4096]
+               and i.opcode not in ("get-tuple-element", "parameter",
+                                    "bitcast")]
+    assert written and {i.dtype for i in written} == {"f32"}
+
 
 # -- the window family's programs at the published widths ----------------------
 

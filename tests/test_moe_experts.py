@@ -254,14 +254,17 @@ def _sorted_tables(hf, topi, valid, Eh, bm, nb_max):
 # share of the tokens): the five cells' decode shapes cut small (LFM2 all
 # 64 held, nemotron 22 of 512 with a quarter held, Laguna 256 held for
 # 128 slots, solar an eighth, gigachat a sixteenth), a 512-token chunk in
-# blocks of 64, more tokens than one count's chunk, and the edges
+# blocks of 64 (48 assignments an expert) and one of eight held experts
+# in blocks of 128 (ten scored: 102 an expert), more tokens than one
+# count's chunk (blocks of 128 too), and the edges
 TABLES = {
     "lfm2_decode": (24, 4, 16, 1.0, 0.9),
     "nemotron_decode": (24, 6, 8, 0.25, 0.9),
     "laguna_decode": (32, 4, 64, 1.0, 0.9),
     "solar_decode": (32, 4, 5, 0.125, 0.9),
     "gigachat_decode": (32, 4, 2, 0.0625, 0.9),
-    "a_512_token_chunk": (512, 6, 8, 0.25, 0.95),
+    "a_512_token_chunk": (512, 6, 8, 0.125, 0.95),
+    "a_512_token_chunk_of_eight_experts": (512, 2, 8, 1.0, 0.95),
     "more_tokens_than_one_count": (640, 2, 4, 0.5, 1.0),
     "every_token_on_one_expert": (40, 4, 8, "one", 1.0),
     "no_token_on_a_held_expert": (40, 4, 8, "none", 1.0),
@@ -292,7 +295,11 @@ def test_counted_tables_equal_the_stable_sort(case, valid_given):
     cfg = CFG.with_(n_experts=max(E, Eh + K), n_experts_held=Eh,
                     experts_per_token=K)
     bm, rows = moe.expert_dispatch(cfg, T)
-    assert bm == (16 if T <= 128 else 64) and moe.n_held(cfg) == Eh
+    assert moe.n_held(cfg) == Eh
+    assert bm == {"a_512_token_chunk": 64,
+                  "a_512_token_chunk_of_eight_experts": 128,
+                  "more_tokens_than_one_count": 128}.get(
+                      case, 16 if T <= 128 else None)
     want = _sorted_tables(hf, topi, valid, Eh, bm, rows // bm)
 
     v = jnp.asarray(valid) if valid_given else None
