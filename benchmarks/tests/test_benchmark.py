@@ -138,13 +138,51 @@ def test_a_block_as_long_as_the_phase_is_one_stratum():
     assert sorted(v for s in dealt for v in s) == [1, 2, 3, 4, 5, 6, 7]
 
 
+def _requests(mix: str) -> tuple[dict, list[dict]]:
+    params = traffic.load(os.path.join(BENCH, "traffic", mix + ".json"))
+    return params, traffic.build(params, 5, 45)["requests"]
+
+
+def _cut_short(requests: list[dict], max_seq: int) -> list[dict]:
+    """The requests that a cache of ``max_seq`` positions would cut short.
+    The engine refuses a prompt over ``max_seq - 1`` (``tpu/generator.py``:
+    ``limit = self.max_seq - 1``) and retires a slot once prompt + generated
+    reaches ``max_seq - 1``, so a request gets every token it asked for
+    while prompt + output < ``max_seq``."""
+    return [r for r in requests if r["prompt"] + r["output"] >= max_seq]
+
+
 def test_lengths_stay_inside_their_clip_and_the_cache():
-    for mix in MIXES:
-        params = traffic.load(os.path.join(BENCH, "traffic", mix + ".json"))
-        for r in traffic.build(params, 5, 45)["requests"]:
+    """A cell's mix is held to that cell's configuration: every request
+    of the list gets its whole output from a cache of the configuration's
+    ``model_config.max_seq`` (``env.TPU_MAX_SEQ``, held equal below).
+    A traffic file that no cell uses is held to nothing, and fails."""
+    bench = _bench()
+    files = {c["name"]: cfg for c, cfg in _configs()}
+    used = set()
+    for cell in bench["workloads"]:
+        used.add(cell["traffic"])
+        max_seq = files[cell["config"]]["model_config"]["max_seq"]
+        params, requests = _requests(cell["traffic"])
+        for r in requests:
             assert r["prompt"] <= params["prompt_tokens"]["max"]
             assert r["output"] <= params["output_tokens"]["max"]
-            assert r["prompt"] + r["output"] < 2048
+        assert not _cut_short(requests, max_seq), cell["name"]
+    assert used == set(MIXES)
+
+
+def test_a_mix_that_passes_its_cells_cache_is_caught():
+    """The bound is the cell's own: ``long-mixed`` fits the 16,384
+    positions of the configuration it is paired with and would not fit
+    its sibling's 4,096; ``batch-sat-14``'s longest request is cut by a
+    cache exactly as long as it is and not by one position more."""
+    _, long_mixed = _requests("long-mixed")
+    assert not _cut_short(long_mixed, 16384)
+    cut = _cut_short(long_mixed, 4096)
+    assert cut and all(r["prompt"] + r["output"] >= 4096 for r in cut)
+    _, small = _requests("batch-sat-14")
+    longest = max(r["prompt"] + r["output"] for r in small)
+    assert _cut_short(small, longest) and not _cut_short(small, longest + 1)
 
 
 def test_prompts_are_seeded_and_distinct():

@@ -79,9 +79,16 @@ def test_a_configuration_that_names_no_module_gets_the_default_reference():
     from benchmarks import reference
 
     assert run.reference_forward(SPEC) is reference.forward_logprobs
+    default = []
     for name in os.listdir(os.path.join(BENCH, "configs")):
         with open(os.path.join(BENCH, "configs", name)) as f:
-            assert "module" not in json.load(f)["reference"], name
+            spec = json.load(f)["reference"]
+        if "module" not in spec:
+            default.append(name)
+            assert run.reference_forward(spec) is reference.forward_logprobs
+    # the two of the default family name none; a family of its own does
+    assert {"mistral-7b-int8.json", "mixtral-8x7b-int8-tp4.json"} \
+        <= set(default)
 
 
 @pytest.fixture
@@ -148,14 +155,16 @@ def test_a_model_config_the_program_cannot_build_exits_at_once(tree):
     """Before JAX looks for a device: on a CPU a configuration the program
     CAN build exits 2 (no TPU), this one 1, with nothing on stdout."""
     root, edit = tree
-    edit(lambda c: c["model_config"].update(kv_lora_rank=512))
+    # a name no family will take: ``kv_lora_rank``, the field this test
+    # was written with, is one the program has had since PR 28
+    edit(lambda c: c["model_config"].update(a_field_no_family_has=512))
     t0 = time.monotonic()
     got = _run(root, "--trace", "0", timeout=120)
     assert got.returncode == 1, got.stderr[-2000:]
     assert got.stdout == ""
-    assert "kv_lora_rank" in got.stderr
+    assert "a_field_no_family_has" in got.stderr
     assert time.monotonic() - t0 < 60
-    edit(lambda c: c["model_config"].pop("kv_lora_rank"))
+    edit(lambda c: c["model_config"].pop("a_field_no_family_has"))
     got = _run(root, "--trace", "0", timeout=120)
     assert got.returncode == run.EXIT_NO_DEVICE and got.stdout == ""
 

@@ -118,13 +118,18 @@ def test_the_parts_and_the_ramp_come_to_setup_s():
     assert ctx.setup_s - (parts + 20.0) == pytest.approx(1.5 + 1.0 + 1.0)
 
 
-def test_the_new_metrics_are_the_last_entries_of_per_layer():
+def test_the_new_metrics_are_appended_to_per_layer_in_this_order():
+    """Nine entries in a row, after what was there before them (Laguna's
+    readers); later PRs append behind them."""
     import json
 
     with open(os.path.join(os.path.dirname(os.path.dirname(HERE)),
                            "BENCHMARK.json")) as f:
         bench = json.load(f)
-    last = bench["per_layer"][-9:]
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index("setup.before_engine_s")
+    assert first == names.index("decode_step_roofline.laguna") + 1
+    last = bench["per_layer"][first:first + 9]
     assert [m["name"] for m in last] == [
         "setup.before_engine_s", "setup.weights_s", "setup.allocate_s",
         "setup.warmup_s", "setup.warmup_compile_s", "setup.cache_misses",

@@ -38,6 +38,11 @@ A traffic file (``benchmarks/traffic/<mix>.json``) holds:
                   clients of a quantile, so the slots start out of step
                   instead of finishing together
 
+A mix is held to the cell it is paired with, not to one length for all:
+every request of the list must get its whole output from a cache of that
+cell's configuration's ``max_seq``, prompt + output < ``max_seq``
+(``tests/test_benchmark.py``), and a traffic file that no cell uses fails.
+
 These two loops are all it builds. Arrivals in bursts, sessions of several
 turns, prompts that share a prefix, a replay of recorded requests: each
 needs a new branch here and in loadgen.py, which only a PR that defines
