@@ -531,6 +531,14 @@ def register_framework_metrics(m: Manager) -> None:
                   "rows the slots of those chunk dispatches reserve "
                   "(max_seq a dispatch); app_tpu_chunk_rows_walked_total "
                   "/ this is the share of a slot a chunk reads")
+    m.new_counter("app_tpu_diffusion_passes_total",
+                  "slot-passes the decode dispatches of a block-diffusion "
+                  "family ran: a slot's denoise passes and its commit "
+                  "passes (docs/tpu/block-diffusion.md)")
+    m.new_counter("app_tpu_diffusion_tokens_total",
+                  "tokens those passes delivered (a committed block's "
+                  "generated positions); this / "
+                  "app_tpu_diffusion_passes_total is the tokens a pass")
     m.new_counter("app_tpu_brownout_capped_total",
                   "generation requests whose max_new_tokens was capped by "
                   "the brownout band")

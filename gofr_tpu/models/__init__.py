@@ -1,4 +1,4 @@
-"""Model zoo: eight decoder families behind one seam (``family``), BERT
+"""Model zoo: nine decoder families behind one seam (``family``), BERT
 (embeddings), ViT (vision).
 
 The decoders: ``llama`` (dense GQA and Mixtral's eight experts; the row
@@ -10,9 +10,11 @@ block a layer or a mixer and a dense feed-forward a layer),
 ``dots3_note`` (latent attention at two widths: full layers that select
 the rows they read, window layers on a ring of latent rows), ``ouro``
 (one dense stack run several times a token, each pass with row tables of
-its own, four norms a layer). What they
+its own, four norms a layer), ``sdar`` (generation by diffusion over
+blocks of positions: a decode step is a pass over a slot's whole block,
+attention is block-causal, a prefill yields no token). What they
 share has an owner that is no family: ``moe`` (the routed feed-forward
-of the six sparse ones), ``latent`` (the latent row's cache and the
+of the seven sparse ones), ``latent`` (the latent row's cache and the
 absorbed form's algebra, the two latent families'), ``blocks``
 (embedding, the gather before the logits, a layer out of a stack, the
 window and conv families' attention block and stack), ``hybrid_cache``
@@ -33,7 +35,7 @@ layer axis. No torch, no module classes — params are data, which is what
 from .common import ModelConfig, LLAMA_CONFIGS, BERT_CONFIGS, VIT_CONFIGS
 from . import (llama, bert, vit, moe, latent, blocks, hybrid_cache,
                deepseek_v3, solar_open2, laguna, lfm2, nemotron_h,
-               dots3_note, ouro)
+               dots3_note, ouro, sdar)
 
 
 def family(cfg: ModelConfig):
@@ -50,7 +52,12 @@ def family(cfg: ModelConfig):
     ``unsupported_options``, ``serving_stats``, ``forward``,
     and ``RECOMPUTABLE``: whether a cached position can be computed
     again and give the same memory (rows can; a recurrent or
-    state-space state, a ring of rows and a convolution's tail cannot)."""
+    state-space state, a ring of rows and a convolution's tail cannot).
+    The block-diffusion family also gives ``candidates``, ``commits``
+    and ``logits``, which its decode program alone asks
+    (``programs._block_decode_scan``)."""
+    if cfg.block_length > 0:
+        return sdar
     if "mamba" in cfg.layer_pattern:
         return nemotron_h
     if "linear" in cfg.layer_pattern:
@@ -68,4 +75,4 @@ def family(cfg: ModelConfig):
 __all__ = ["ModelConfig", "LLAMA_CONFIGS", "BERT_CONFIGS", "VIT_CONFIGS",
            "llama", "bert", "vit", "moe", "latent", "blocks",
            "hybrid_cache", "deepseek_v3", "solar_open2", "laguna", "lfm2",
-           "nemotron_h", "dots3_note", "ouro", "family"]
+           "nemotron_h", "dots3_note", "ouro", "sdar", "family"]

@@ -43,17 +43,20 @@ def prompt_rows(tokens, lengths):
     return lengths, positions, positions < lengths[:, None]
 
 
-def prompt_attend(flash: bool, lengths, valid, mesh, window: int = 0):
+def prompt_attend(flash: bool, lengths, valid, mesh, window: int = 0,
+                  block: int = 0):
     """``attend(q, k, v)`` of a whole prompt within itself: the flash
     kernel where ``flash`` and backend and shapes allow (``ops.flash``),
-    the jnp reference otherwise; banded where ``window``."""
+    the jnp reference otherwise; banded where ``window``, block-causal
+    where ``block``."""
     if flash:
         from ..ops.flash import causal_attention_auto
 
         return lambda q, k, v: causal_attention_auto(
-            q, k, v, lengths=lengths, mask=valid, mesh=mesh, window=window)
+            q, k, v, lengths=lengths, mask=valid, mesh=mesh, window=window,
+            block=block)
     return lambda q, k, v: causal_attention(q, k, v, mask=valid,
-                                            window=window)
+                                            window=window, block=block)
 
 
 # -- a layer's weights out of a stack a kind -----------------------------------
@@ -160,7 +163,7 @@ def rows_attend(attend_rows, cfg: ModelConfig, pair: bool):
         KV)
 
 
-def _layer_rows(rows, i):
+def layer_rows(rows, i):
     """Layer ``i`` of each table of ``rows`` (k, v, k_scale, v_scale), a
     scale None where the rows are not int8."""
     return tuple(None if a is None else jax.lax.dynamic_index_in_dim(
@@ -180,20 +183,22 @@ def decode_rows_attend(rows, i, lengths, live, block_s, mesh,
             return flash_decode.decode_attention_auto(
                 q, rows[0], rows[1], k_new, v_new, live, i, rows[2],
                 rows[3], block_s=block_s, mesh=mesh, scale=scale)
-        k_l, v_l, ks_l, vs_l = _layer_rows(rows, i)
+        k_l, v_l, ks_l, vs_l = layer_rows(rows, i)
         return decode_attention_appended(q, k_l, v_l, k_new, v_new, lengths,
                                          ks_l, vs_l, scale=scale)
     return rows_attend(over_rows, cfg, pair)
 
 
-def chunk_rows_attend(rows, i, start, cfg: ModelConfig, pair: bool):
+def chunk_rows_attend(rows, i, start, cfg: ModelConfig, pair: bool,
+                      block: int = 0):
     """``attend(q, k_new, v_new)`` of a chunk program over layer ``i`` of
     the cached ``rows``: the rows before ``start`` and the chunk within
-    itself; on paired rows where ``pair``."""
+    itself (block-causally where ``block``); on paired rows where
+    ``pair``."""
     def over_rows(q, k_new, v_new, scale):
-        k_l, v_l, ks_l, vs_l = _layer_rows(rows, i)
+        k_l, v_l, ks_l, vs_l = layer_rows(rows, i)
         return chunk_attention(q, k_l, v_l, k_new, v_new, start, ks_l, vs_l,
-                               scale=scale)
+                               scale=scale, block=block)
     return rows_attend(over_rows, cfg, pair)
 
 

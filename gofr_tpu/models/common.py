@@ -179,6 +179,29 @@ class ModelConfig:
     loop_steps: int = 1
     sandwich_norm: bool = False
     early_exit_threshold: float = 1.0
+    # generation by diffusion over blocks (models/sdar.py; block_length
+    # > 0 selects that family): a slot generates block_length positions
+    # at a time. A denoise pass runs the stack over the whole block, a
+    # masked position carrying the embedding of mask_token_id, each
+    # position attending every cached row and all of the block, and
+    # commits some of the masked positions from the head's
+    # distributions at their own rows; when none is masked a commit pass
+    # runs the stack over the block's final tokens and writes its rows.
+    # Attention is block-causal everywhere: position i sees j iff
+    # j // block_length <= i // block_length. commit_order picks what a
+    # pass commits, k = block_length // denoise_passes positions of it:
+    # "sequential" (the leftmost k masked), "low_confidence_static" (the
+    # k most confident) or "low_confidence_dynamic" (every position
+    # whose confidence passes confidence_threshold, the most confident
+    # one where none does). router_score: what a routed layer's router
+    # scores with, "sigmoid" (models/moe.py's first families) or
+    # "softmax" over all experts, the chosen renormalised
+    block_length: int = 0
+    mask_token_id: int = 0
+    denoise_passes: int = 0
+    commit_order: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
+    router_score: str = "sigmoid"
 
     def __post_init__(self):
         # a configuration file gives the pattern as a list
@@ -188,6 +211,18 @@ class ModelConfig:
                 f"early_exit_threshold {self.early_exit_threshold} < 1: "
                 "every decode step runs all loop_steps passes for every "
                 "slot; an exit before the last pass is not implemented")
+        if self.block_length:
+            passes = self.denoise_passes or self.block_length
+            if self.block_length % passes or self.commit_order not in (
+                    "sequential", "low_confidence_static",
+                    "low_confidence_dynamic") \
+                    or not 0 <= self.mask_token_id < self.vocab_size:
+                raise ValueError(
+                    f"block_length {self.block_length}: denoise_passes "
+                    f"{self.denoise_passes} must divide it, commit_order "
+                    f"{self.commit_order!r} be one of the three published "
+                    f"and mask_token_id {self.mask_token_id} a row of the "
+                    "vocabulary")
 
     @property
     def head_dim(self) -> int:
@@ -344,6 +379,18 @@ LLAMA_CONFIGS = {
         name="tiny-loop", vocab_size=256, dim=64, n_layers=2, n_heads=4,
         n_kv_heads=4, ffn_dim=96, max_seq=128, rope_theta=1e6,
         norm_eps=1e-6, dtype="float32", loop_steps=3, sandwich_norm=True),
+    # the block-diffusion family at test size: blocks of four positions
+    # committed two a pass, groups of three query heads a KV head, a q/k
+    # norm a head, a softmax router over eight experts all held, no
+    # shared expert, untied head; the mask token is an id the tests also
+    # send as a real token
+    "tiny-diffusion-moe": ModelConfig(
+        name="tiny-diffusion-moe", vocab_size=256, dim=64, n_layers=3,
+        n_heads=6, n_kv_heads=2, ffn_dim=96, max_seq=128, rope_theta=1e6,
+        norm_eps=1e-6, dtype="float32", attn_head_dim=16, qk_norm=True,
+        n_experts=8, experts_per_token=2, moe_ffn_dim=40,
+        router_score="softmax", block_length=4, mask_token_id=255,
+        denoise_passes=2, commit_order="sequential"),
 }
 
 BERT_CONFIGS = {

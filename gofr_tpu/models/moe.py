@@ -79,16 +79,20 @@ def init_routed(keys, cfg: ModelConfig, L: int) -> dict:
 @jax.named_scope("moe/route")
 def route(hf, router, bias, cfg: ModelConfig):
     """hf [T, D] -> (expert ids [T, k], weights [T, k] float32), over all
-    ``n_experts`` as published: float32 sigmoid scores; ``s + bias``
-    selects (groups by the sum of their two best, then the top k inside
+    ``n_experts`` as published: float32 sigmoid scores (softmax where
+    ``router_score`` says so); ``s + bias`` selects (``bias`` None: the
+    scores do; groups by the sum of their two best, then the top k inside
     the kept groups); the weights are the unbiased scores, renormalised
     and scaled."""
     T = hf.shape[0]
     E, G = cfg.n_experts, cfg.n_expert_groups
-    s = jax.nn.sigmoid(jnp.dot(hf.astype(jnp.float32),
-                               router.astype(jnp.float32),
-                               precision=jax.lax.Precision.HIGHEST))
-    sel = s + bias.astype(jnp.float32)
+    s = jnp.dot(hf.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+    # ``router_score``: a softmax over all the experts (the chosen are
+    # renormalised below, as the sigmoid's are), or a sigmoid each
+    s = jax.nn.softmax(s, axis=-1) if cfg.router_score == "softmax" \
+        else jax.nn.sigmoid(s)
+    sel = s if bias is None else s + bias.astype(jnp.float32)
     group = jnp.sum(jax.lax.top_k(sel.reshape(T, G, E // G), 2)[0], -1)
     kept = jnp.sum(jax.nn.one_hot(jax.lax.top_k(group, cfg.topk_groups)[1],
                                   G, dtype=jnp.bool_), axis=1)     # [T, G]
@@ -377,7 +381,7 @@ def moe_ffn(h, lw, cfg: ModelConfig, valid=None):
     projected up once a token after it."""
     B, S, D = h.shape
     hf = h.reshape(B * S, D)
-    topi, w = route(hf, lw["router"], lw["router_bias"], cfg)
+    topi, w = route(hf, lw["router"], lw.get("router_bias"), cfg)
     xe = hf
     if "w_latent_down" in lw:
         with jax.named_scope("moe/latent_down"):

@@ -134,7 +134,8 @@ class Timeline:
                      states: int | None = None,
                      ring: int | None = None,
                      sampled: int | None = None,
-                     kept: tuple[int, int] | None = None) -> None:
+                     kept: tuple[int, int] | None = None,
+                     passes: tuple[int, int, int] | None = None) -> None:
         """One fused decode dispatch->reap: ``slots`` is the tuple of
         active slot indices as dispatched, ``steps`` the block size,
         ``live`` the KV positions those slots held at dispatch (what
@@ -159,10 +160,13 @@ class Timeline:
         they chose among: the cached ones and each token's own), both
         summed over those layers, the steps and the active slots (beside
         ``live``, which is what one such layer held for one step at
-        dispatch). A
+        dispatch); and last of all, where a step is a PASS over a block
+        of positions a slot (``steps`` then counts passes), ``passes``:
+        (the slot-passes the dispatch ran, the tokens it delivered, the
+        cache rows a layer it wrote: a commit pass writes a block's). A
         field keeps its place: states without an expert layer come after
         two Nones, ring rows without states after a None."""
-        tail = [assigned, touched, states, ring, sampled, kept]
+        tail = [assigned, touched, states, ring, sampled, kept, passes]
         while tail and tail[-1] is None:
             tail.pop()
         if 0 < len(tail) < 2:

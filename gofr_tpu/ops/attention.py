@@ -63,16 +63,25 @@ def unpair_heads(o: jnp.ndarray, n_kv: int) -> jnp.ndarray:
                      axis=3).reshape(b, s, h, d2 // 2)
 
 
+def block_causal(s: int, block: int) -> jnp.ndarray:
+    """[s, s] bool: position i sees j iff ``j // block <= i // block``
+    (a block's positions see each other both ways, and every block
+    before theirs); ``block`` 0 or 1: the lower triangle."""
+    at = jnp.arange(s) // max(block, 1)
+    return at[None, :] <= at[:, None]
+
+
 @jax.named_scope("causal_attention")
 def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      mask: jnp.ndarray | None = None,
-                     window: int = 0) -> jnp.ndarray:
+                     window: int = 0, block: int = 0) -> jnp.ndarray:
     """Causal self-attention for prefill.
 
     q: [B, S, H, D]; k, v: [B, S, KV, D] (KV may divide H for GQA).
     mask: optional [B, S] validity mask (1 = real token, 0 = padding).
     window > 0: a band, position p sees the ``window`` positions
-    (p - window, p]. Returns [B, S, H, D].
+    (p - window, p]. block > 1: block-causal (``block_causal``).
+    Returns [B, S, H, D].
     """
     b, s, h, d = q.shape
     n_kv = k.shape[2]
@@ -82,7 +91,8 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # scores: [B, KV, G, S, S]
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, k,
                         preferred_element_type=jnp.float32)
-    causal = jnp.tril(jnp.ones((s, s), dtype=bool))
+    causal = block_causal(s, block) if block > 1 \
+        else jnp.tril(jnp.ones((s, s), dtype=bool))
     if window:
         causal &= ~jnp.tril(jnp.ones((s, s), dtype=bool), -window)
     scores = jnp.where(causal[None, None, None], scores, NEG_INF)
@@ -193,7 +203,9 @@ def window_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
                               v_cache: jnp.ndarray, k_new: jnp.ndarray,
                               v_new: jnp.ndarray, lengths: jnp.ndarray,
                               k_scale: jnp.ndarray | None = None,
-                              v_scale: jnp.ndarray | None = None) -> jnp.ndarray:
+                              v_scale: jnp.ndarray | None = None,
+                              within: jnp.ndarray | None = None,
+                              scale: float | None = None) -> jnp.ndarray:
     """decode_attention_appended generalized to a W-token window — the
     speculative-decoding verify pass: window query j attends the cache
     prefix (positions < lengths[b], per slot) plus window positions <= j,
@@ -205,10 +217,14 @@ def window_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
     k_new/v_new: [B, W, KV, D]; lengths: [B] valid cache entries
     (EXCLUDING the window). Returns [B, W, H, D]. Int8 cache scales are
     applied score/prob-side exactly as in decode_attention_appended.
+    ``within`` [W, W] bool: which window positions a window query sees
+    (None: those at or before it; all of them for a block that is
+    denoised as a whole, ``block_causal(W, W)``). ``scale``: the softmax
+    scale where it is not D^-1/2.
     """
     b, w, h, d = q.shape
     n_kv, smax = k_cache.shape[1], k_cache.shape[2]
-    scale = d ** -0.5
+    scale = scale or d ** -0.5
 
     qg = _repeat_kv_shape(q * scale, n_kv)  # [B,W,KV,G,D]
     scores_c = jnp.einsum("bwkgd,bktd->bkgwt", qg, k_cache.astype(qg.dtype),
@@ -219,7 +235,7 @@ def window_attention_appended(q: jnp.ndarray, k_cache: jnp.ndarray,
     scores_c = jnp.where(valid[:, None, None, None, :], scores_c, NEG_INF)
     scores_s = jnp.einsum("bwkgd,btkd->bkgwt", qg, k_new,
                           preferred_element_type=jnp.float32)  # [B,KV,G,W,W]
-    causal = jnp.tril(jnp.ones((w, w), bool))
+    causal = jnp.tril(jnp.ones((w, w), bool)) if within is None else within
     scores_s = jnp.where(causal[None, None, None], scores_s, NEG_INF)
     probs = jax.nn.softmax(jnp.concatenate([scores_c, scores_s], axis=-1),
                            axis=-1)
@@ -252,7 +268,8 @@ def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                     start: jnp.ndarray,
                     k_scale: jnp.ndarray | None = None,
                     v_scale: jnp.ndarray | None = None,
-                    scale: float | None = None) -> jnp.ndarray:
+                    scale: float | None = None,
+                    block: int = 0) -> jnp.ndarray:
     """Chunked-prefill attention: a block of C new tokens at positions
     [start, start+C) attends to the cache prefix (positions < start) plus
     causally within the chunk — the long-prompt path, processing prompts in
@@ -273,18 +290,23 @@ def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     a block at a time).
     Trailing padding inside the chunk is harmless: causality means padded
     positions are never attended BY valid ones. ``scale``: the softmax
-    scale where it is not D^-1/2 (paired heads). Returns [B, C, H, D].
+    scale where it is not D^-1/2 (paired heads). ``block`` > 1: the
+    chunk's tokens see each other block-causally (``block_causal``; the
+    chunk starts on a block's first position, so no block is cut by its
+    edge, and the padding is whole blocks or ends one that no valid
+    block comes after). Returns [B, C, H, D].
     """
     b, c, h, d = q.shape
     n_kv, smax = k_cache.shape[1], k_cache.shape[2]
     scale = scale or d ** -0.5
-    block = chunk_block(smax)
+    walk = chunk_block(smax)
     vdt = q.dtype if v_scale is not None else v_cache.dtype
 
     qg = _repeat_kv_shape(q * scale, n_kv)  # [B,C,KV,G,D]
     scores_n = jnp.einsum("bskgd,btkd->bkgst", qg, k_new,
                           preferred_element_type=jnp.float32)  # [B,KV,G,C,C]
-    causal = jnp.tril(jnp.ones((c, c), dtype=bool))
+    causal = block_causal(c, block) if block > 1 \
+        else jnp.tril(jnp.ones((c, c), dtype=bool))
     scores_n = jnp.where(causal[None, None, None], scores_n, NEG_INF)
     m = jnp.max(scores_n, -1, keepdims=True)                 # [B,KV,G,C,1]
     p = jnp.exp(scores_n - m)
@@ -295,13 +317,13 @@ def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
         m, l, acc = carry
 
         def tile(x):
-            return jax.lax.dynamic_slice_in_dim(x, j * block, block, 2)
+            return jax.lax.dynamic_slice_in_dim(x, j * walk, walk, 2)
 
         s = jnp.einsum("bskgd,bktd->bkgst", qg, tile(k_cache).astype(qg.dtype),
-                       preferred_element_type=jnp.float32)  # [B,KV,G,C,block]
+                       preferred_element_type=jnp.float32)  # [B,KV,G,C,walk]
         if k_scale is not None:
             s = s * tile(k_scale)[:, :, None, None, :]
-        s = jnp.where(j * block + jnp.arange(block) < start, s, NEG_INF)
+        s = jnp.where(j * walk + jnp.arange(walk) < start, s, NEG_INF)
         m_next = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
         corr, p = jnp.exp(m - m_next), jnp.exp(s - m_next)
         l = l * corr + jnp.sum(p, -1, keepdims=True)
@@ -313,7 +335,7 @@ def chunk_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
         return m_next, l, acc
 
     _, l, acc = jax.lax.fori_loop(
-        0, (start + block - 1) // block, fold,
+        0, (start + walk - 1) // walk, fold,
         (m, jnp.sum(p, -1, keepdims=True), acc))
     out = (acc / l).astype(jnp.result_type(vdt, v_new.dtype))
     return jnp.moveaxis(out, 3, 1).reshape(b, c, h, d)

@@ -78,7 +78,7 @@ def fit_block(n: int, block: int) -> int:
 def _flash_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *,
                   block_q: int, block_k: int, scale: float,
-                  window: int = 0):
+                  window: int = 0, block: int = 0):
     """One (batch, head, q-block, k-block) step of the online softmax.
 
     m/l/acc scratch persists across the innermost (k-block) grid dim:
@@ -115,6 +115,11 @@ def _flash_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         seen = (k_pos <= q_pos) & (k_pos < length)
+        if block:
+            # block-causal: a block's positions see each other both
+            # ways. ``block`` divides both tiles, so a block lies inside
+            # one diagonal tile and the causal skip above is unchanged
+            seen = (k_pos // block <= q_pos // block) & (k_pos < length)
         if window:
             seen &= k_pos > q_pos - window
         s = jnp.where(seen, s, NEG_INF)
@@ -144,12 +149,13 @@ def _flash_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
-                                             "interpret", "window", "scale"))
+                                             "interpret", "window", "scale",
+                                             "block"))
 def flash_causal_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          lengths: jnp.ndarray, *, block_q: int = 128,
                          block_k: int = 128, interpret: bool = False,
-                         window: int = 0,
-                         scale: float | None = None) -> jnp.ndarray:
+                         window: int = 0, scale: float | None = None,
+                         block: int = 0) -> jnp.ndarray:
     """Causal prefill attention without S² materialization.
 
     q: [B, S, H, D]; k, v: [B, S, KV, D] (KV divides H); lengths: [B]
@@ -159,12 +165,19 @@ def flash_causal_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     back to the jnp reference otherwise). ``window`` > 0: a band, position
     p sees (p - window, p]; k blocks wholly below it are skipped.
     ``scale``: the softmax scale where it is not D^-1/2 (paired heads).
+    ``block`` > 1: block-causal, position p sees every position of its
+    own block of ``block`` and of the blocks before (it must divide both
+    tiles: only the tiles on the diagonal change).
     Returns [B, S, H, D] in q.dtype.
     """
     b, s, h, d = q.shape
     kv = k.shape[2]
     if s % block_q or s % block_k:
         raise ValueError(f"S={s} not divisible by blocks "
+                         f"({block_q}, {block_k})")
+    if block > 1 and (block_q % block or block_k % block
+                      or block_q != block_k):
+        raise ValueError(f"block-causal blocks of {block} do not tile "
                          f"({block_q}, {block_k})")
     scale = scale or d ** -0.5
     grid = (b, h, s // block_q, s // block_k)
@@ -180,7 +193,8 @@ def flash_causal_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     kernel = functools.partial(_flash_kernel, block_q=block_q,
                                block_k=block_k, scale=scale,
-                               **({"window": window} if window else {}))
+                               **({"window": window} if window else {}),
+                               **({"block": block} if block > 1 else {}))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -298,7 +312,8 @@ def causal_attention_auto(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           mask: jnp.ndarray | None = None, *,
                           block_q: int = 128, block_k: int = 128,
                           interpret: bool = False,
-                          mesh=None, window: int = 0) -> jnp.ndarray:
+                          mesh=None, window: int = 0,
+                          block: int = 0) -> jnp.ndarray:
     """Flash kernel when the backend+shapes allow, jnp reference otherwise.
 
     Accepts ``lengths`` [B] or a PREFIX validity ``mask`` [B, S]
@@ -311,7 +326,8 @@ def causal_attention_auto(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     axes (flash_prefill_sharded); the reference — which GSPMD partitions
     fine on its own — remains the fallback when tp would split a KV head
     or the shapes fail the kernel gate. ``window`` > 0 bands the mask
-    (inference only, one device: no mesh form and no gradient).
+    (inference only, one device: no mesh form and no gradient);
+    ``block`` > 1 makes it block-causal, on the same terms.
     """
     interpret = interpret or interpret_env()
     if lengths is None and mask is not None:
@@ -326,6 +342,15 @@ def causal_attention_auto(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if interpret:
         block_q = fit_block(q.shape[1], block_q)
         block_k = fit_block(q.shape[1], block_k)
+    if block > 1:
+        if mesh is None and block_q == block_k and not block_q % block \
+                and (interpret or _kernel_ok(q, block_q, block_k)):
+            return flash_causal_prefill(
+                q, k, v, lengths.astype(jnp.int32), block_q=block_q,
+                block_k=block_k, interpret=interpret, window=window,
+                block=block)
+        return causal_attention(q, k, v, mask=mask, window=window,
+                                block=block)
     if _pairs_ok(q, k, block_q, block_k, interpret, mesh, window):
         # heads of half a lane row: two KV heads a row, as the cache
         # holds them (ops.attention.pair_rows), and the kernel as at 128

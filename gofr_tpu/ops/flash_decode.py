@@ -30,6 +30,15 @@ compute dtype in registers and into a [G, hd] x [hd, block_s] score
 matmul and a [G, block_s] x [block_s, hd] value matmul, with its scale
 row [1, block_s] from the scales' own [.., KV, Smax] order.
 
+W query positions a slot (``flash_decode_block``; a family that denoises
+a block of positions as a whole, models/sdar.py): the same kernel with
+the KV head's tile W x G query rows high, W times the arithmetic a
+fetched byte. The W new rows, which every one of them sees, are folded
+in jnp (W scores a query row) and handed over as the recurrence's first
+element, their log-sum-exp as m, l = 1 and their softmax-weighed values
+as acc, which is what the one new row is to W = 1; W = 1 itself is
+``flash_decode_stacked``, whose program this branch leaves as it was.
+
 History, for whoever wants another A/B: v1 looped KV heads over 4-row
 matmuls on sub-tile slices and lost 1.8x to XLA; v2/v3 expanded q
 block-diagonally to one dense [H, KV*hd] matmul a tile on a
@@ -129,8 +138,12 @@ _KV_BUF = 3    # items w+1 and w+2 in flight while item w is folded: see
 
 
 def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool,
-                   ring: bool = False):
-    """The whole layer: walk the work list, fold each item."""
+                   ring: bool = False, window: bool = False):
+    """The whole layer: walk the work list, fold each item. ``window``:
+    a slot brings W query positions (its group's rows W times over) and
+    W new rows they all see; what those give among themselves arrives
+    folded, as the recurrence's first element (``flash_decode_block``),
+    in the places of the one new row's k and v."""
     layer_ref, n_ref, slot_ref, blk_ref, len_ref = refs[:5]
     if ring:      # one more scalar a slot: the row it must not read
         skip_ref, refs = refs[5], refs[:5] + refs[6:]
@@ -182,6 +195,13 @@ def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool,
 
         @pl.when(blk == 0)
         def _init():
+            if window:
+                # the block within itself: its scores' log-sum-exp on
+                # every lane, and its values weighed by their softmax
+                m_ref[...] = kn_ref[slot]
+                l_ref[...] = jnp.ones_like(l_ref)
+                acc_ref[...] = vn_ref[slot]
+                return
             # the appended token is the recurrence's first element
             s_new = jnp.sum(q_ref[slot].astype(jnp.float32)
                             * kn_ref[slot].astype(jnp.float32),
@@ -236,6 +256,50 @@ def _decode_kernel(*refs, block_s: int, n_kv: int, quant: bool,
     jax.lax.fori_loop(0, n, item, None)
 
 
+def _walk(q_rows, first_m, first_acc, cache_k, cache_v, work, lengths,
+          layer, k_scale, v_scale, skip=(), *, block_s: int,
+          interpret: bool, **kernel):
+    """The kernel's call over layer ``layer``: ``q_rows`` [B, KV, Gp, D]
+    the scaled query rows of each KV head, ``first_m`` / ``first_acc`` the
+    operands of the recurrence's first element (one new row's k and v a
+    query row, or a block's folded own rows: ``_decode_kernel``),
+    ``work`` the slots' work list (``_work_list``), ``lengths`` [B]
+    int32, ``skip`` () or (the ring's row not to read,).
+    Returns the attention [B, KV, Gp, D] float32; a slot of length 0 is
+    not written."""
+    b, n_kv, g_pad, d = q_rows.shape
+    quant = k_scale is not None
+    n, slot, blk = work
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    operands = [q_rows, first_m, first_acc, cache_k, cache_v]
+    in_specs = [vmem, vmem, vmem, hbm, hbm]
+    scratch = [pltpu.VMEM((_KV_BUF, n_kv, block_s, d), cache_k.dtype),
+               pltpu.VMEM((_KV_BUF, n_kv, block_s, d), cache_v.dtype)]
+    if quant:
+        # a [KV, BS] tile has a KV head's scales in one row, positions
+        # along lanes like its scores
+        operands += [k_scale, v_scale]
+        in_specs += [hbm, hbm]
+        scratch += [pltpu.VMEM((_KV_BUF, n_kv, block_s), jnp.float32),
+                    pltpu.VMEM((_KV_BUF, n_kv, block_s), jnp.float32)]
+    scratch += [pltpu.VMEM((n_kv, g_pad, _LANES), jnp.float32),
+                pltpu.VMEM((n_kv, g_pad, _LANES), jnp.float32),
+                pltpu.VMEM((n_kv, g_pad, d), jnp.float32),
+                pltpu.SemaphoreType.DMA((4, _KV_BUF))]
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, block_s=block_s, n_kv=n_kv,
+                          quant=quant, **kernel),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5 + len(skip), grid=(1,), in_specs=in_specs,
+            out_specs=vmem, scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, g_pad, d), jnp.float32),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), n, slot, blk, lengths,
+      *skip, *operands)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("block_s", "interpret", "scale"))
 def flash_decode_stacked(q, cache_k, cache_v, k_new, v_new, lengths, layer,
@@ -262,50 +326,72 @@ def flash_decode_stacked(q, cache_k, cache_v, k_new, v_new, lengths, layer,
     n_kv, smax = cache_k.shape[2], cache_k.shape[3]
     g = h // n_kv
     g_pad = -(-g // _SUBLANES) * _SUBLANES
-    quant = k_scale is not None
     lengths = lengths.astype(jnp.int32)
     skip = () if exclude is None else (exclude.astype(jnp.int32),)
-    n, slot, blk = _work_list(lengths, smax, block_s)
+    work = _work_list(lengths, smax, block_s)
     qg = (q[:, 0] * (scale or d ** -0.5)).reshape(b, n_kv, g, d)
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
-    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
 
     def per_group(x):  # [B, 1, KV, D] -> [B, KV, Gp, D], a row a q head
         return jnp.broadcast_to(x[:, 0, :, None, :], (b, n_kv, g_pad, d))
 
-    operands = [qg, per_group(k_new), per_group(v_new), cache_k, cache_v]
-    in_specs = [vmem, vmem, vmem, hbm, hbm]
-    scratch = [pltpu.VMEM((_KV_BUF, n_kv, block_s, d), cache_k.dtype),
-               pltpu.VMEM((_KV_BUF, n_kv, block_s, d), cache_v.dtype)]
-    if quant:
-        # a [KV, BS] tile has a KV head's scales in one row, positions
-        # along lanes like its scores
-        operands += [k_scale, v_scale]
-        in_specs += [hbm, hbm]
-        scratch += [pltpu.VMEM((_KV_BUF, n_kv, block_s), jnp.float32),
-                    pltpu.VMEM((_KV_BUF, n_kv, block_s), jnp.float32)]
-    scratch += [pltpu.VMEM((n_kv, g_pad, _LANES), jnp.float32),
-                pltpu.VMEM((n_kv, g_pad, _LANES), jnp.float32),
-                pltpu.VMEM((n_kv, g_pad, d), jnp.float32),
-                pltpu.SemaphoreType.DMA((4, _KV_BUF))]
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_s=block_s, n_kv=n_kv,
-                          quant=quant, **({"ring": True} if skip else {})),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5 + len(skip), grid=(1,), in_specs=in_specs,
-            out_specs=vmem, scratch_shapes=scratch),
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g_pad, d), jnp.float32),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024),
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), n, slot, blk, lengths,
-      *skip, *operands)
+    out = _walk(qg, per_group(k_new), per_group(v_new), cache_k, cache_v,
+                work, lengths, layer, k_scale, v_scale, skip,
+                block_s=block_s, interpret=interpret,
+                **({"ring": True} if skip else {}))
     out = out[:, :, :g].reshape(b, h, d)
     # a slot with no item never reached the kernel's write: its answer is
     # the softmax of one element, the appended token's value
     v_rep = jnp.repeat(v_new[:, 0], g, axis=1).astype(jnp.float32)
     out = jnp.where((lengths > 0)[:, None, None], out, v_rep)
     return out.astype(q.dtype).reshape(b, 1, h, d)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_s", "interpret", "scale"))
+def flash_decode_block(q, cache_k, cache_v, k_new, v_new, lengths, layer,
+                       k_scale=None, v_scale=None, *, block_s: int,
+                       interpret: bool = False,
+                       scale: float | None = None) -> jnp.ndarray:
+    """``flash_decode_stacked`` for W query positions a slot that are not
+    in the cache yet and see each other BOTH ways (a block that is
+    denoised as a whole): each attends the slot's ``lengths`` cached rows
+    and all W new ones.
+
+    q: [B, W, H, D]; k_new/v_new: [B, W, KV, D]; the rest as
+    ``flash_decode_stacked``. Returns [B, W, H, D] in q.dtype.
+
+    The same kernel: a KV head's tile is its group's rows W times over
+    ([W G, D] against each fetched block of K and V: W times the
+    arithmetic a fetched byte), and what the W new rows give among
+    themselves, W scores a query row, is folded here in jnp and handed
+    over as the recurrence's first element: m = their log-sum-exp, l = 1,
+    acc = their values weighed by their softmax. A slot of length 0
+    never reaches the kernel's write and keeps that."""
+    b, w, h, d = q.shape
+    n_kv, smax = cache_k.shape[2], cache_k.shape[3]
+    g = h // n_kv
+    rows = w * g
+    g_pad = -(-rows // _SUBLANES) * _SUBLANES
+    lengths = lengths.astype(jnp.int32)
+    work = _work_list(lengths, smax, block_s)
+    # [B, W, KV, G, D] -> [B, KV, W G, D]: a KV head's rows together
+    qg = (q * (scale or d ** -0.5)).reshape(b, w, n_kv, g, d)
+    qg = jnp.moveaxis(qg, 1, 2).reshape(b, n_kv, rows, d)
+    s_new = jnp.einsum("bkrd,btkd->bkrt", qg, k_new,
+                       preferred_element_type=jnp.float32)  # [B,KV,WG,W]
+    m0 = jax.nn.logsumexp(s_new, axis=-1, keepdims=True)
+    acc0 = jnp.einsum("bkrt,btkd->bkrd", jnp.exp(s_new - m0),
+                      v_new.astype(jnp.float32))
+    pad = ((0, 0), (0, 0), (0, g_pad - rows), (0, 0))
+    qg, acc0 = jnp.pad(qg, pad), jnp.pad(acc0, pad)
+    m0 = jnp.broadcast_to(jnp.pad(m0, pad), (b, n_kv, g_pad, _LANES))
+    out = _walk(qg, m0, acc0, cache_k, cache_v, work, lengths, layer,
+                k_scale, v_scale, block_s=block_s, interpret=interpret,
+                window=True)
+    out = jnp.where((lengths > 0)[:, None, None, None], out, acc0)
+    out = out[:, :, :rows].reshape(b, n_kv, w, g, d)
+    return jnp.moveaxis(out, 2, 1).reshape(b, w, h, d).astype(q.dtype)
 
 
 def ring_rows(lengths, rows: int):
@@ -398,6 +484,21 @@ def kernel_block(n_heads: int, cache_k, mesh=None) -> int | None:
     if d % _LANES or smax % _LANES or n_heads % n_kv or not tpu_backend_ok():
         return None
     return block_s
+
+
+@jax.named_scope("flash_decode_block")
+def block_attention_auto(q, cache_k, cache_v, k_new, v_new, lengths, layer,
+                         k_scale=None, v_scale=None, *, block_s: int,
+                         scale: float | None = None) -> jnp.ndarray:
+    """``flash_decode_block`` over layer ``layer`` of the stacked cache
+    (one device: the family that denoises blocks refuses a mesh).
+    ``block_s``: ``kernel_block``'s answer for these shapes; the caller
+    takes ``ops.attention.window_attention_appended`` where it is None."""
+    from .flash import interpret_env
+
+    return flash_decode_block(q, cache_k, cache_v, k_new, v_new, lengths,
+                              layer, k_scale, v_scale, block_s=block_s,
+                              interpret=interpret_env(), scale=scale)
 
 
 @jax.named_scope("flash_decode")

@@ -221,7 +221,8 @@ class GenStream(PushStream):
 
 
 class _Request:
-    __slots__ = ("stream", "prompt", "max_new", "temperature", "top_k",
+    __slots__ = ("stream", "prompt", "given", "max_new", "temperature",
+                 "top_k",
                  "eos_id", "adapter", "enqueued_at", "lattice_peek",
                  "kv_match", "deadline", "slo_class", "kv_sink",
                  "kv_shipped", "ingest", "seed", "pos_base", "tenant",
@@ -234,9 +235,16 @@ class _Request:
     def __init__(self, stream: GenStream, prompt: np.ndarray, max_new: int,
                  temperature: float, top_k: int, eos_id: int | None,
                  adapter: int = 0, deadline=None,
-                 slo_class: str = SLO_LATENCY):
+                 slo_class: str = SLO_LATENCY, block: int = 0):
         self.stream = stream
-        self.prompt = prompt
+        # a family that generates a block of ``block`` positions at a
+        # time prefills a prompt's whole blocks; the tokens left over
+        # open its first generated block as given positions
+        # (``given``; empty for every other family). Everything that
+        # runs, stores or restores a prompt sees the whole blocks alone
+        # and so stays on block boundaries
+        cut = len(prompt) - len(prompt) % block if block else len(prompt)
+        self.prompt, self.given = prompt[:cut], prompt[cut:]
         self.max_new = max_new
         self.temperature = temperature
         self.top_k = top_k
@@ -566,6 +574,17 @@ class GenerationEngine:
         # for app_tpu_kv_live_bytes
         self._loop_steps = said.get("loop_steps", 0)
         self._loop_tokens = 0
+        # a family whose decode step is a PASS over a block of W
+        # positions a slot says so (0: a step is a token): a prefill
+        # yields no token (_first_token), a dispatch is ``decode_block``
+        # passes that deliver up to W tokens a commit, the cursor moves
+        # by whole blocks at the reap. Its account: slot-passes that
+        # denoised and that committed, positions committed, tokens
+        # emitted (the device's counters, _decode_reap)
+        self._diffusion = said.get("diffusion") or {}
+        self._dblock = self._diffusion.get("block_length", 0)
+        self._diff_n = dict.fromkeys(
+            ("denoise_passes", "commit_passes", "committed", "emitted"), 0)
         self._kv_token_bytes = said.get("kv_bytes_per_token", 0)
         # In-flight admission poll cadence (seconds). While a decode
         # block runs on device, the serving loop waits on the submit
@@ -607,6 +626,14 @@ class GenerationEngine:
         # the last one padded, and a prefix hit resumes only where the
         # pool holds the state (_chunk_lattice, _resume_at)
         self._rewind = self._fam.RECOMPUTABLE
+        if self._dblock and any(n % self._dblock for n in (
+                self.max_seq, *self.prompt_buckets)):
+            raise ValueError(
+                f"max_seq {self.max_seq} and the prompt buckets "
+                f"{self.prompt_buckets} must be whole blocks of "
+                f"{self._dblock} for the model family of {cfg.name!r}: a "
+                "block cut by a chunk's edge would attend rows that are "
+                "not computed yet")
         if not self._rewind and self.max_seq % self._chunk:
             raise ValueError(
                 f"max_seq {self.max_seq} must be whole prefill chunks of "
@@ -795,6 +822,10 @@ class GenerationEngine:
         # (pos_base + delivered count) — see _resume_keys
         self._slot_seed = np.zeros((slots,), np.int32)
         self._pos_abs = np.zeros((slots,), np.int32)
+        # a block family's first block: the tokens the prompt gives it
+        # and how many (the pack's last columns, beside the cursor)
+        self._given = np.zeros((slots, self._dblock), np.int32)
+        self._given_n = np.zeros((slots,), np.int32)
         # auto-seed counter for sampled requests submitted without an
         # explicit seed: deterministic per engine (same engine seed +
         # same request order -> same streams), and surfaced on the
@@ -1329,7 +1360,9 @@ class GenerationEngine:
         # dense scratch row, then land it in the pool); the only hard
         # limit is cache capacity minus one position for the first
         # generated token.
-        limit = self.max_seq - 1
+        # (a block family delivers no token from its prefill: its first
+        # one has to lie under the capacity stop too)
+        limit = self.max_seq - 1 - bool(self._dblock)
         if len(prompt) > limit:
             stream._q.put(GenerationError(
                 f"prompt length {len(prompt)} exceeds serving limit {limit}"))
@@ -1400,7 +1433,7 @@ class GenerationEngine:
                 req = _Request(stream, prompt, max_new_tokens,
                                temperature, top_k, eos_id,
                                adapter=int(adapter), deadline=deadline,
-                               slo_class=slo_class)
+                               slo_class=slo_class, block=self._dblock)
                 req.kv_sink = kv_sink
                 req.ingest = ingest
                 req.seed = 0 if seed is None else seed
@@ -1453,6 +1486,16 @@ class GenerationEngine:
             # phases, warm-up records, cache misses (observe/startup.py)
             "startup": self._startup.stats(),
         }
+        if self._dblock:
+            # the slot-passes the decode dispatches ran, by what a slot
+            # did in them, and what they committed and delivered
+            n = self._diff_n
+            ran = n["denoise_passes"] + n["commit_passes"]
+            out["diffusion"] = {
+                **self._diffusion, **n, "passes": ran,
+                "rows_written": n["commit_passes"] * self._dblock,
+                "tokens_per_pass": round(n["emitted"] / ran, 4)
+                if ran else None}
         if self._loop_steps:
             # tokens the decode blocks emitted and the passes over the
             # stack they took (every token runs every pass: ModelConfig
@@ -1911,7 +1954,8 @@ class GenerationEngine:
     # -- the serving loop ----------------------------------------------------
     def _pack_width(self) -> int:
         return (self._PACK_EXTRA + self.EOS_MAX
-                + (self._mb if self._paged else 0))
+                + (self._mb if self._paged else 0)
+                + (self._dblock + 2 if self._dblock else 0))
 
     def _warm_pack(self):
         """All-inactive dispatch pack for warmup: host_wins set so the
@@ -1931,7 +1975,8 @@ class GenerationEngine:
         return (jnp.asarray(np.array(self._last_tokens)),
                 jnp.asarray(np.array(self._active)),
                 jnp.asarray(np.array(self._budgets)),
-                jnp.asarray(np.array(self._pos_abs)))
+                jnp.asarray(np.array(self._pos_abs)),
+                *self._prog.carry_tail(self.n_slots))
 
     def _sampling_flag(self) -> int:
         """What the block about to be dispatched asks of the sampler
@@ -1978,6 +2023,10 @@ class GenerationEngine:
             p[:, self._PACK_EXTRA:self._PACK_EXTRA + E] = self._eos_mat
             if self._paged:
                 p[:, self._PACK_EXTRA + E:] = self._table
+            if self._dblock:
+                p[:, self._PACK_EXTRA + E] = self._cursors
+                p[:, self._PACK_EXTRA + E + 1] = self._given_n
+                p[:, self._PACK_EXTRA + E + 2:] = self._given
             self._pack = jnp.asarray(p)
             self._pack_dirty = False
         return self._pack
@@ -2237,6 +2286,10 @@ class GenerationEngine:
         C = self.prompt_buckets[-1]
         self._slot_adapter[idx] = req.adapter
         self._touch("adapters")
+        if not L:
+            # a block family's prompt of less than a block: all of it
+            # is given to the first block, and no program runs
+            return None, 0.0
         pos = self._prefix_restore(idx, req, L, C)
         if pos or L > self._chunk:
             return self._chunk_lattice("cache", idx, req, pos)
@@ -2345,11 +2398,19 @@ class GenerationEngine:
         the next dispatch pack) runs while that block computes instead
         of with the stream dry. The admitted slot is not in that block
         (it joins the next through host_wins), so its second token
-        comes one block later than it would from a dry stream."""
+        comes one block later than it would from a dry stream.
+
+        The one place that asks whether the family's prefill yields a
+        token: one whose step is a pass over a block does not (its
+        programs sample from zeros), and (None, 0.0) tells _start to
+        deliver nothing and hand the slot to its first pass. The fetch
+        stays: it is what makes an admission wait for its prompt."""
         self._trail()
         prev = self._acct.phase("fetch")
         try:
             tok, lp = jax.device_get((tok, lp))  # one round trip, not two
+            if self._dblock:
+                return None, 0.0
             return int(tok), float(lp)
         finally:
             self._acct.phase(prev)
@@ -2412,6 +2473,10 @@ class GenerationEngine:
                 return 0
             return m
         m = clamp_restore_len(mt.matched_len, L)
+        if self._dblock:
+            # a block's rows hold all of its tokens: whole matched
+            # blocks alone are the matched tokens' own
+            m -= m % self._dblock
         if m < self.prompt_buckets[0] \
                 or not self._lattice_resume_valid(L, m):
             # less than the smallest bucket: the copy would not remove a
@@ -3757,7 +3822,18 @@ class GenerationEngine:
         self._touch("temps", "top_ks", "seeds")
         if self._spec_k:
             self._hist_append(idx, int(first))
-        self._deliver(idx, slot, first, first_lp)
+        if first is None:
+            # the prefill yielded no token (_first_token): the slot's
+            # first block opens with the prompt's last tokens given
+            n = len(req.given)
+            self._given[idx] = 0
+            self._given[idx, :n] = req.given
+            self._given_n[idx] = n
+            first = 0
+            if req.stream.cancelled.is_set():
+                self._retire(idx, slot)
+        else:
+            self._deliver(idx, slot, first, first_lp)
         if slot.request is not None:  # not finished by the first token
             self._last_tokens[idx] = first
             self._active[idx] = True
@@ -4369,7 +4445,10 @@ class GenerationEngine:
             counters = self._run(self._step_jit, self.cache, self.params,
                                  pack, self._last_dev, self._key)
         if not self._paged:
-            self._cursors[self._active] += self.decode_block
+            # (a block family's cursors move at the reap, by as many
+            # whole blocks as it finds committed)
+            if not self._dblock:
+                self._cursors[self._active] += self.decode_block
         else:
             # advance bounded by each slot's device stop cursor: the
             # scan freezes a slot there (budget/capacity), so the host
@@ -4418,7 +4497,12 @@ class GenerationEngine:
         # the block's steps kept and the rows they chose among, over
         # those layers and the active slots
         kept = tuple(int(n) for n in counters[2].reshape(-1, 2).sum(0)) \
-            if len(counters) > 2 else None
+            if len(counters) > 2 and counters[2] is not None else None
+        # a family whose step is a pass over a block: the slot-passes
+        # that denoised and that committed, the positions committed and
+        # the tokens emitted, over the dispatch's passes
+        passes = tuple(int(n) for n in counters[3].sum(0)) \
+            if len(counters) > 3 else None
         if self._tl is not None:
             # one ring event per fused block, fanned out to per-slot
             # slices only at export time — the hot path pays one append
@@ -4426,7 +4510,18 @@ class GenerationEngine:
                 t0, time.monotonic(),
                 tuple(int(i) for i in np.flatnonzero(snap_active)),
                 self.decode_block, live, fetched, assigned, touched, states,
-                ring, sampled or None, kept)
+                ring, sampled or None, kept,
+                None if passes is None else (
+                    passes[0] + passes[1], passes[3],
+                    passes[1] * self._dblock))
+        if passes is not None:
+            for name, n in zip(self._diff_n, passes):
+                self._diff_n[name] += n
+            if self.metrics is not None:
+                inc = self.metrics.increment_counter
+                inc("app_tpu_diffusion_passes_total",
+                    by=passes[0] + passes[1])
+                inc("app_tpu_diffusion_tokens_total", by=passes[3])
         if self._loop_steps:
             self._loop_tokens += int(emit_np.sum())
         if self._kv_token_bytes and live is not None \
@@ -4464,9 +4559,16 @@ class GenerationEngine:
         toks_l, lps_l = toks_np.tolist(), lps_np.tolist()
         emit_l = emit_np.tolist()
         counts = emit_np.sum(axis=0)  # real tokens per slot this block
+        if self._dblock:
+            # a pass that emitted for a slot committed its block: the
+            # slot's cursor moved by one
+            W = self._dblock
+            commits = emit_np.reshape(-1, W, self.n_slots).any(1).sum(0)
         for idx, slot in enumerate(self._slots):
             if snap_active[idx] and self._active[idx] \
                     and slot.request is snap_reqs[idx]:
+                if self._dblock:
+                    self._cursors[idx] += W * int(commits[idx])
                 if self._expire_decoding(idx, slot):
                     continue
                 if counts[idx]:

@@ -47,7 +47,10 @@ EOS_MAX = 8
 # 3 temp (f32 bits), 4 top_k, 5 adapter, 6 host_wins, 7 seed, 8 pos
 # (absolute generated-token index of the slot's NEXT sample — the
 # host-side truth the carry merge reads under host_wins), 9.. EOS set,
-# then (paged) the block-table row
+# then (paged) the block-table row; a family whose step is a pass over
+# a block (``cfg.block_length`` = W > 0, never paged) has in its place
+# the slot's cursor, the positions of its first block that the prompt
+# gives and that block's W tokens (``_block_decode_scan``)
 PACK_EXTRA = 9
 
 
@@ -673,7 +676,165 @@ class EnginePrograms:
         lengths = stepped.lengths + emit
         return greedy, lps, emit, stepped._replace(lengths=lengths)
 
+    def carry_tail(self, slots: int) -> tuple:
+        """What a block family's slot-state carry holds after the four
+        every family chains (last token, active, budget, position), as a
+        first dispatch's host-built stand-in: the block's tokens [B, W],
+        which of them are known, their logprobs, and the count the
+        prompt gave. Every slot of a first dispatch is idle or freshly
+        admitted, and the pack wins for those."""
+        W = self.cfg.block_length
+        if not W:
+            return ()
+        return (jnp.zeros((slots, W), jnp.int32),
+                jnp.zeros((slots, W), bool),
+                jnp.zeros((slots, W), jnp.float32),
+                jnp.zeros((slots,), jnp.int32))
+
+    def _block_decode_scan(self, cache, params, pack, carry, key):
+        """``decode_block`` PASSES over the slots' blocks (a family whose
+        step is a pass over ``W = cfg.block_length`` positions, not a
+        token); one dispatch returns [K W, B] tokens and an emitted
+        mask, a pass's W rows one after another, which the host replays
+        as it does a token step's.
+
+        A slot holds the block at its cursor: tokens, which of them are
+        ``known`` (given by the prompt, or committed by an earlier
+        pass: state, never ``token == mask_token_id``) and the known
+        ones' logprobs. Each pass every active slot is in one of two
+        states. With a masked position left it DENOISES: the stack over
+        the block (``decode_step``), the head over the positions the
+        order can commit (``candidates``), a token and its probability
+        each (``_sample``: greedy, or the request's temperature and
+        top-k, keyed on the token's absolute generated index), and the
+        order's pick among them becomes known (``commits``). With none
+        left it COMMITS: the same stack over the final tokens, the
+        block's W rows a layer written at the cursor, which moves by W;
+        the block's generated tokens are emitted in order, cut at the
+        budget, at an EOS and at capacity, and the slot starts on the
+        next block, all masked. Slots are at their own passes; the head
+        runs where any slot denoises.
+
+        The carry after the four of every family: the block's tokens,
+        ``known``, logprobs [B, W], and ``given`` [B], the positions of
+        the slot's FIRST block that the prompt holds (its ``n mod W``
+        last tokens; a prefill covers whole blocks and yields no token).
+        Where ``host_wins`` those come from the pack with the slot's
+        cursor, which the device's lengths take: a prompt shorter than
+        a block runs no prefill to set it.
+
+        The counters a pass: the expert layers' assignments first, as
+        every family's, then (None, None and) four sums over the slots:
+        those that denoised, those that committed, positions committed,
+        tokens emitted."""
+        fam, cfg = self._fam, self.cfg
+        W, E = cfg.block_length, self.EOS_MAX
+        B = pack.shape[0]
+        host_active = pack[:, 1].astype(bool)
+        host_budget = pack[:, 2]
+        temps = jax.lax.bitcast_convert_type(pack[:, 3], jnp.float32)
+        top_ks = pack[:, 4]
+        host_wins = pack[:, 6].astype(bool)
+        seeds = pack[:, 7]
+        host_pos = pack[:, 8]
+        eos_ids = pack[:, self._PACK_EXTRA:self._PACK_EXTRA + E]
+        lo = self._PACK_EXTRA + E
+        host_cursor, host_given = pack[:, lo], pack[:, lo + 1]
+        host_block = pack[:, lo + 2:lo + 2 + W]
+        _, dev_active, dev_budget, dev_pos, dev_block, dev_known, dev_lps, \
+            dev_given = carry
+        col = jnp.arange(W, dtype=jnp.int32)[None, :]
+        wins = host_wins[:, None]
+        block0 = jnp.where(wins, host_block, dev_block)
+        known0 = jnp.where(wins, col < host_given[:, None], dev_known)
+        lps0 = jnp.where(wins, 0.0, dev_lps)
+        given0 = jnp.where(host_wins, host_given, dev_given)
+        active0 = jnp.where(host_wins, host_active, dev_active)
+        budget0 = jnp.where(host_wins, host_budget, dev_budget)
+        pos0 = jnp.where(host_wins, host_pos, dev_pos)
+        cache = cache._replace(lengths=jnp.where(
+            host_wins & host_active, host_cursor, cache.lengths))
+        # the last position a slot delivers (_deliver's at_capacity)
+        cap = jnp.int32(self.max_seq - 2)
+        c = fam.candidates_width(cfg)
+
+        def rep(a):     # a slot's setting for each of its candidates
+            return jnp.repeat(a, c)
+
+        def body(carry, _):
+            block, known, lps, given, active, budget, pos, cache = carry
+            whole = jnp.all(known, axis=1)
+            commit, denoise = active & whole, active & ~whole
+            cursor = cache.lengths
+            with jax.named_scope("diffusion/denoise"):
+                x, stepped, moe_n = fam.decode_step(
+                    params, cfg, block, cache, rope_tables=self.rope_tables,
+                    mesh=self.mesh, active=active, known=known,
+                    commit=commit)
+                cand, masked = fam.candidates(cfg, known)        # [B, c]
+
+                @jax.named_scope("diffusion/sample")
+                def sample():
+                    rows = jnp.take_along_axis(x, cand[..., None], axis=1)
+                    logits = fam.logits(params, cfg, rows)       # [B, c, V]
+                    at = pos[:, None] + cand - given[:, None]
+                    tok, lp = self._sample(
+                        logits.reshape(B * c, -1), rep(temps), rep(seeds),
+                        at.reshape(-1), rep(top_ks), rep(denoise))
+                    return tok.reshape(B, c), lp.reshape(B, c)
+
+                tok, lp = jax.lax.cond(
+                    jnp.any(denoise), sample,
+                    lambda: (jnp.zeros((B, c), jnp.int32),
+                             jnp.zeros((B, c), jnp.float32)))
+                pick_c = fam.commits(cfg, jnp.exp(lp),
+                                     masked & denoise[:, None])  # [B, c]
+                # the candidates' picks back at their block positions
+                put = (cand[..., None] == col[:, None, :]) \
+                    & pick_c[..., None]                          # [B, c, W]
+                pick = jnp.any(put, axis=1)
+                block = jnp.where(pick, jnp.sum(
+                    jnp.where(put, tok[..., None], 0), axis=1), block)
+                lps = jnp.where(pick, jnp.sum(
+                    jnp.where(put, lp[..., None], 0.0), axis=1), lps)
+                known = known | pick
+            with jax.named_scope("diffusion/emit"):
+                # a committed block's generated tokens, in order: not
+                # what the prompt gave, not past the budget, not past
+                # the first EOS, not past capacity
+                nth = col - given[:, None]
+                ok = commit[:, None] & (nth >= 0) & (nth < budget[:, None]) \
+                    & (cursor[:, None] + col <= cap)
+                eos = ok & jnp.any(block[..., None] == eos_ids[:, None, :],
+                                   axis=-1)
+                emit = ok & (jnp.cumsum(eos, axis=1) - eos == 0)
+                n_emit = jnp.sum(emit, axis=1, dtype=jnp.int32)
+                budget = budget - n_emit
+                pos = pos + n_emit
+                stop = commit & ((budget <= 0) | jnp.any(eos & emit, axis=1)
+                                 | (stepped.lengths > cap))
+                out = (block.T, lps.T, emit.T)
+                # the next block: all masked, nothing given
+                known = known & ~commit[:, None]
+                given = jnp.where(commit, 0, given)
+            counts = jnp.stack([jnp.sum(denoise), jnp.sum(commit),
+                                jnp.sum(pick), jnp.sum(n_emit)]
+                               ).astype(jnp.int32)
+            return (block, known, lps, given, active & ~stop, budget, pos,
+                    stepped), (*out, (moe_n, None, None, counts))
+
+        (block, known, lps, given, active, budget, pos, cache), \
+            (toks, tlps, emitted, counters) = jax.lax.scan(
+                body, (block0, known0, lps0, given0, active0, budget0, pos0,
+                       cache), None, length=self.decode_block)
+        flat = (toks.reshape(-1, B), tlps.reshape(-1, B),
+                emitted.reshape(-1, B))
+        return (*flat, (block[:, 0], active, budget, pos, block, known, lps,
+                        given), key, cache, counters)
+
     def _step_fn(self, cache, params, pack, carry, key):
+        if self.cfg.block_length:
+            return self._block_decode_scan(cache, params, pack, carry, key)
         adapter = pack[:, 5] if self._n_adapters else None
 
         def step_model(tokens, cache, active):

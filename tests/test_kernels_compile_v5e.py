@@ -16,6 +16,7 @@ one process at a time may load the TPU's library, and a worker that
 collects this file must not load it while it imports.
 """
 
+import functools
 import json
 import math
 import os
@@ -269,10 +270,16 @@ def _engine_lowered(monkeypatch, sharding, cfg, slots, kv_dtype, program,
     key = arr((2,), jnp.uint32)
     if program == "decode block":
         b = arr((slots,))
+        # a family whose step is a pass over a block packs the cursor,
+        # the given count and the block's tokens, and carries the block
+        W = cfg.block_length
+        tail = tuple(arr(a.shape, a.dtype) for a in jax.eval_shape(
+            lambda: prog.carry_tail(slots)))
         return jits["_step_jit"].lower(
             cache, params,
-            arr((slots, programs.PACK_EXTRA + programs.EOS_MAX)),
-            (b, arr((slots,), jnp.bool_), b, b), key)
+            arr((slots, programs.PACK_EXTRA + programs.EOS_MAX
+                 + (W + 2 if W else 0))),
+            (b, arr((slots,), jnp.bool_), b, b, *tail), key)
     if program.startswith("chunk"):
         return jits["_chunk_mid_jit"].lower(
             cache, params, arr((1, int(program.split()[1]))), arr(()),
@@ -611,6 +618,79 @@ def test_conv_family_reads_weights_rows_and_tails_in_place(one_chip,
     # 11.6 GB of weights, 2.03 GB of cache, and what a step needs
     assert 13.5e9 < mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < 14.2e9
+
+
+# -- the block-diffusion family: a pass over the slots' blocks -------------------
+
+def _lowered_block(monkeypatch, sharding, program):
+    """``benchmarks/configs/sdar-30b-a3b-int8-pp4.json`` as its cell runs
+    it: twelve layers of 128 experts, bfloat16 rows, 96 slots."""
+    cfg = _cell_config("sdar-30b-a3b-int8-pp4")
+    return _engine_lowered(monkeypatch, sharding, cfg, 96, None, program)
+
+
+def test_block_decode_kernel_compiles(one_chip):
+    """The W-row branch of the decode kernel at the cell's shapes: four
+    query positions a slot, a KV head's tile of 32 query rows, bfloat16
+    rows of four KV heads; and the W = 1 kernel beside it unchanged."""
+    arr, cache, _ = _shapes(one_chip, 4, jnp.bfloat16)
+    q = arr((B, 4, 32, D), jnp.bfloat16)
+    new = arr((B, 4, 4, D), jnp.bfloat16)
+    text = jax.jit(functools.partial(
+        fd.flash_decode_block, block_s=256)).lower(
+            q, cache, cache, new, new, arr((B,), jnp.int32),
+            arr((), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 1
+
+
+@pytest.mark.parametrize("program,kernels", [("decode block", 6),
+                                             ("prefill 256", 2),
+                                             ("chunk 512", 1)])
+def test_block_family_reads_weights_and_rows_in_place(one_chip, monkeypatch,
+                                                      program, kernels):
+    """A dispatch of four passes holds, in its scan's body, the W-row
+    decode kernel, the routed experts' kernel and one row append a block
+    position (four); a prompt program the block-causal flash kernel
+    (the chunk program attends in jnp) and the experts' kernel. None
+    copies an int8 weight stack or an expert stack, stages a layer's
+    slice of one, or moves the K and V rows; the whole engine fits the
+    chip."""
+    compiled = _lowered_block(monkeypatch, one_chip, program).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"^\s*%?[\w.\-]+ = .*custom_call_target="
+                          r"\"tpu_custom_call\"", text, re.M)) == kernels
+    assert len(re.findall(r"%expert_blocks_stacked[\w.]* = ", text)) == 1
+    if program == "decode block":
+        assert len(re.findall(r"%flash_decode_block[\w.]* = ", text)) == 1
+        assert len(re.findall(r"%append_rows_stacked[\w.]* = ", text)) == 4
+    elif program == "prefill 256":
+        assert len(re.findall(r"%flash_causal_prefill[\w.]* = ", text)) == 1
+    results = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = ((?:s8|bf16)\[[\d,]+\]\S*) ([\w\-]+)\(",
+        text, re.M)
+    assert results                      # the pattern still reads this HLO
+
+    def elements(shape):
+        n = 1
+        for d in shape.split("[")[1].split("]")[0].split(","):
+            n *= int(d)
+        return n
+
+    stack_copies = [r for r in results if r[1] in ("copy", "transpose")
+                    and r[0].startswith("s8[") and elements(r[0]) >= 1 << 22]
+    staged = [r for r in results if r[1] == "fusion"
+              and r[0].startswith("s8[1,2048,") and "S(1)" in r[0]]
+    moved = [r for r in results if r[1] in ("copy", "transpose")
+             and re.match(r"bf16\[12,(96|1),4,2048,128\]", r[0])]
+    assert not stack_copies, stack_copies
+    assert not staged, staged
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    # 8.44 GB of weights (a prompt program, which yields no token,
+    # takes no head: 0.31 GB less), 4.83 GB of cache, and what a pass
+    # or a prompt needs
+    assert 12.9e9 < mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 14.6e9, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
 
 
 # -- the state-space family: its kernels and programs at the published widths --

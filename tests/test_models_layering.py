@@ -13,7 +13,15 @@ PKG = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "gofr_tpu")
 MODELS = {f[:-3] for f in os.listdir(os.path.join(PKG, "models"))
           if f.endswith(".py") and f != "__init__.py"}
-FAMILIES = {"deepseek_v3", "solar_open2", "laguna", "lfm2", "nemotron_h"}
+FAMILIES = {"deepseek_v3", "solar_open2", "laguna", "lfm2", "nemotron_h",
+            "dots3_note", "ouro", "sdar"}
+# what the engine asks of every family through ``models.family(cfg)``
+# (docs/tpu/serving-engine.md, "What a new family touches")
+ENTRY_POINTS = ("init", "init_cache", "get_rope_tables", "prefill_kv",
+                "write_kv", "prefill_chunk", "decode_step",
+                "decode_kv_block", "kv_layout", "kv_tables", "chunk_block",
+                "unsupported_options", "serving_stats", "forward",
+                "RECOMPUTABLE")
 
 
 def _sources():
@@ -131,3 +139,26 @@ def _functions_that_walk_the_serving_options():
     ids=lambda f: f.__name__.strip("_"))
 def test_the_model_layer_keeps_its_rule(breaches):
     assert breaches() == []
+
+
+@pytest.mark.parametrize("name", sorted(FAMILIES | {"llama"}))
+def test_a_family_gives_the_engine_every_entry_point(name):
+    import importlib
+
+    fam = importlib.import_module(f"gofr_tpu.models.{name}")
+    assert [n for n in ENTRY_POINTS if not hasattr(fam, n)] == []
+
+
+def test_only_the_block_family_says_a_step_is_a_pass():
+    """``serving_stats()["diffusion"]`` is how a family tells the engine
+    that its decode step is a pass over a block and that its prefill
+    yields no token; the three names its decode program asks beside the
+    entry points are its own."""
+    from gofr_tpu.models import LLAMA_CONFIGS, family, sdar
+
+    said = {name: family(cfg).serving_stats(cfg, 4).get("diffusion")
+            for name, cfg in LLAMA_CONFIGS.items() if name.startswith("tiny")}
+    assert [n for n, d in said.items() if d] == ["tiny-diffusion-moe"]
+    assert said["tiny-diffusion-moe"]["block_length"] == 4
+    for name in ("candidates", "commits", "logits"):
+        assert callable(getattr(sdar, name))

@@ -260,3 +260,45 @@ def test_stats_say_a_slots_state_a_tokens_rows_and_paired_rows(
                                                  + 3 * 160 * 4)
     assert stats["kv_bytes_per_token"] == 2 * 2 * 2 * 64 * 4
     assert stats["kv_heads_per_row"] == 2
+
+
+# -- the block-diffusion family: a pass over the slots' blocks -----------------
+
+@pytest.fixture(scope="module")
+def block_engine():
+    from gofr_tpu.models import sdar
+
+    cfg = LLAMA_CONFIGS["tiny-diffusion-moe"]
+    eng = GenerationEngine(cfg, sdar.init(cfg, jax.random.PRNGKey(0)),
+                           slots=2, max_seq=64, prompt_buckets=(8, 16),
+                           decode_block=3)
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("scope", [
+    "diffusion/denoise", "diffusion/sample", "diffusion/commit",
+    "diffusion/emit", "attn/block_decode", "moe/experts", "moe/route",
+    "attn/qk_norm", "kv_write", "lm_head", "sampling"])
+def test_the_block_familys_pass_scopes(block_engine, scope):
+    """What a device trace shows beside a pass's operations: the stack,
+    the head and the order's pick under ``diffusion/denoise`` (the
+    block's attention and the experts in the layer scan it holds: a
+    scan's body and a conditional's branch name their operations
+    afresh), the rows' write under
+    ``diffusion/commit``."""
+    text = _lowered(block_engine, "decode")
+    names = [line for line in text.splitlines() if "loc(" in line]
+    assert any(f"{scope}/" in line or f'{scope}"' in line
+               for line in names), scope
+
+
+@pytest.mark.parametrize("which", ["prefill", "chunk"])
+def test_the_block_familys_prompt_programs_run_no_head(block_engine, which):
+    """A prefill yields no token: no ``lm_head`` scope in a prompt
+    program, whose sampler reads zeros; the attention is the
+    block-causal one."""
+    text = _lowered(block_engine, which)
+    assert "lm_head" not in text
+    assert ("chunk_attention" if which == "chunk"
+            else "causal_attention") in text

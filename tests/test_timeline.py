@@ -503,7 +503,16 @@ def test_loop_events_cover_the_generation_threads_time(tiny, paged):
         outs = _burst(eng)
         t1 = time.monotonic()
         assert all(len(o) == 12 for o in outs)
-        time.sleep(0.12)  # let the thread park, which closes its last phase
+        # let the thread park, which closes its last phase: the phase that
+        # was open at t1 is written when the next one begins (0.12 s as a
+        # rule; on a machine six test workers share it has taken longer,
+        # and the uncovered tail then read as 4% of the burst)
+        waited = time.monotonic() + 5.0
+        time.sleep(0.12)
+        while time.monotonic() < waited and not any(
+                e[3] == "loop" and e[1] + e[2] >= t1
+                for e in obs.timeline.events()):
+            time.sleep(0.02)
     finally:
         eng.close()
     loops = sorted((e for e in obs.timeline.events() if e[3] == "loop"),
