@@ -531,6 +531,12 @@ def register_framework_metrics(m: Manager) -> None:
                   "rows the slots of those chunk dispatches reserve "
                   "(max_seq a dispatch); app_tpu_chunk_rows_walked_total "
                   "/ this is the share of a slot a chunk reads")
+    m.new_counter("app_tpu_chunk_walk_kernel_total",
+                  "chunk dispatches whose program walks its cached latent "
+                  "rows in the kernel (ops/mla.py:chunk_walk_latent); of "
+                  "stats()[\"scheduler\"][\"prefill\"][\"chunks\"] "
+                  "dispatches, the share that took it (0 on a CPU and in a "
+                  "family that caches K and V rows)")
     m.new_counter("app_tpu_diffusion_passes_total",
                   "slot-passes the decode dispatches of a block-diffusion "
                   "family ran: a slot's denoise passes and its commit "

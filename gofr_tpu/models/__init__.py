@@ -55,7 +55,10 @@ def family(cfg: ModelConfig):
     state-space state, a ring of rows and a convolution's tail cannot).
     The block-diffusion family also gives ``candidates``, ``commits``
     and ``logits``, which its decode program alone asks
-    (``programs._block_decode_scan``)."""
+    (``programs._block_decode_scan``); the two latent families give
+    ``chunk_walk_kernel`` (whether a chunk program of a size walks its
+    cached rows in ``ops.mla.chunk_walk_latent``), by which the engine
+    counts its chunk dispatches."""
     if cfg.block_length > 0:
         return sdar
     if "mamba" in cfg.layer_pattern:

@@ -86,6 +86,14 @@ def chunk_block(cfg: ModelConfig, max_seq: int) -> int:
     return mla.chunk_block(max_seq)
 
 
+def chunk_walk_kernel(cfg: ModelConfig, max_seq: int, chunk: int) -> bool:
+    """Whether a chunk program of ``chunk`` positions walks its cached
+    rows in ``mla.chunk_walk_latent`` (``mla.chunk_tile`` says from
+    backend and shapes); the engine counts its dispatches by it."""
+    return bool(latent.walk_tile(latent.sizes(cfg), chunk, max_seq,
+                                 cfg.jdtype))
+
+
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     """(heads, values a head) of a cached token, for the prefix index's
     shape contract: one shared row."""

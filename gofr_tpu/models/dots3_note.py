@@ -168,6 +168,16 @@ def chunk_block(cfg: ModelConfig, max_seq: int) -> int:
     return 0
 
 
+def chunk_walk_kernel(cfg: ModelConfig, max_seq: int, chunk: int) -> bool:
+    """Whether a chunk program of ``chunk`` positions walks its cached
+    rows in ``mla.chunk_walk_latent``, a full layer's rows and a window
+    layer's ring both (``mla.chunk_tile`` says from backend and shapes);
+    the engine counts its dispatches by it."""
+    return all(latent.walk_tile(sizes(cfg, kind), chunk, table, cfg.jdtype)
+               for kind, table in (("full", max_seq),
+                                   ("window", ring_rows(cfg))))
+
+
 def kv_layout(cfg: ModelConfig) -> tuple[int, int]:
     """(heads, values a head) of a cached token, for the prefix index's
     shape contract: one shared row."""

@@ -22,6 +22,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..ops import mla
 from ..ops.quant import QuantizedLinear, qmatmul
 from ..ops.rope import yarn_softmax_scale
 from .common import ModelConfig
@@ -50,6 +51,14 @@ class Sizes(NamedTuple):
         """Lanes a cached row takes: ``row_width`` rounded up to whole
         HBM tiles of 128 lanes (576 -> 640; ops/mla.py says why)."""
         return -(-self.row_width // LANES) * LANES
+
+
+def walk_tile(sz: Sizes, chunk: int, table: int, dtype) -> int | None:
+    """``mla.chunk_tile`` for a chunk of ``chunk`` positions of this kind
+    of latent attention over a table of ``table`` cached rows: the
+    queries a tile of the chunk walk's kernel, None on the jnp loop."""
+    return mla.chunk_tile(chunk, sz.heads, table, sz.stored_width, sz.rank,
+                          dtype)
 
 
 def sizes(cfg: ModelConfig) -> Sizes:

@@ -94,6 +94,8 @@ def prefill_attention(q, k_nope, k_pe, v, mask=None, keep=None):
 
 # cached rows a trip of ``chunk_attention_kept``'s walk scores
 _CHUNK_BLOCK = 512
+# score rows (queries x heads) a tile of ``chunk_walk_latent`` holds
+_CHUNK_TILE_ROWS = 1024
 
 
 def chunk_block(rows: int) -> int:
@@ -102,6 +104,161 @@ def chunk_block(rows: int) -> int:
     from .flash import fit_block
 
     return fit_block(rows, _CHUNK_BLOCK)
+
+
+def chunk_tile(chunk: int, heads: int, table: int, width: int, rank: int,
+               dtype) -> int | None:
+    """The queries a tile of ``chunk_walk_latent`` scores (with all their
+    heads) where backend and shapes allow the kernel, None where the
+    walk stays the jnp loop: not a TPU, operands that are not bfloat16,
+    a rank, a stored width or a block of rows that is not whole lanes,
+    heads that are not whole sublanes, a chunk the tile does not divide.
+    ``GOFR_FLASH_INTERPRET=1`` runs the kernel interpreted on any
+    backend, at whatever tile divides the chunk."""
+    from .flash import fit_block, interpret_env, tpu_backend_ok
+
+    tile = max(_SUBLANES, _CHUNK_TILE_ROWS // heads // _SUBLANES * _SUBLANES)
+    if interpret_env():
+        return fit_block(chunk, tile)
+    if (chunk % tile or heads % _SUBLANES or rank % _LANES or width % _LANES
+            or chunk_block(table) % _LANES
+            or jnp.dtype(dtype) != jnp.bfloat16 or not tpu_backend_ok()):
+        return None
+    return tile
+
+
+def _chunk_walk_kernel(n_ref, q_ref, keep_hbm, rows_hbm, o_ref, m_out, l_out,
+                       rows_buf, keep_buf, m_ref, l_ref, acc_ref, sem, *,
+                       block: int, rank: int, heads: int):
+    """One tile of queries (all heads of each, folded into the matmuls'
+    rows: the keys are one for all heads) over the first ``n_ref[0]``
+    blocks of a slot's cached rows, under a running softmax whose scores
+    stay in VMEM. The blocks every tile walks are the same, so the tiles
+    of rows and of the mask are ONE stream over the whole grid, the next
+    item in flight while this one is folded: a tile's first block was
+    started by the tile before it."""
+    b, i = pl.program_id(0), pl.program_id(1)
+    nb, nq = pl.num_programs(0), pl.num_programs(1)
+    n = n_ref[0]
+    tq = keep_buf.shape[1]
+    first = (b * nq + i) * n
+
+    def copies(b, i, j, slot):
+        at = pl.ds(pl.multiple_of(j * block, block), block)
+        return (pltpu.make_async_copy(rows_hbm.at[b, at], rows_buf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(
+                    keep_hbm.at[b, pl.ds(pl.multiple_of(i * tq, tq), tq), at],
+                    keep_buf.at[slot], sem.at[1, slot]))
+
+    @pl.when((first == 0) & (n > 0))
+    def _open():
+        for c in copies(b, i, 0, 0):
+            c.start()
+
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def fold(j, _):
+        slot = (first + j) % 2
+        for c in copies(b, i, j, slot):
+            c.wait()
+
+        @pl.when(j + 1 < n)
+        def _next_block():
+            for c in copies(b, i, j + 1, 1 - slot):
+                c.start()
+
+        @pl.when((j + 1 == n) & ((i + 1 < nq) | (b + 1 < nb)))
+        def _next_tile():
+            wrap = i + 1 == nq
+            for c in copies(jnp.where(wrap, b + 1, b),
+                            jnp.where(wrap, 0, i + 1), 0, 1 - slot):
+                c.start()
+
+        tile = rows_buf[slot]                                # [block, width]
+        s = jax.lax.dot_general(q_ref[0], tile, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        keep = keep_buf[slot] != 0                           # [tq, block]
+        # one row of the mask serves a query's heads
+        s = jnp.concatenate(
+            [jnp.where(keep[a:a + 1], s[a * heads:(a + 1) * heads], NEG_INF)
+             for a in range(tq)], 0)
+        m = m_ref[:, :1]
+        m_next = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p, scale = jnp.exp(s - m_next), jnp.exp(m - m_next)
+        l_ref[...] = jnp.broadcast_to(
+            l_ref[:, :1] * scale + jnp.sum(p, axis=-1, keepdims=True),
+            l_ref.shape)
+        acc_ref[...] = acc_ref[...] * scale + jax.lax.dot_general(
+            p.astype(tile.dtype), tile[:, :rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_next, m_ref.shape)
+
+    jax.lax.fori_loop(0, n, fold, None)
+    o_ref[0] = acc_ref[...]
+    # a query's [heads, 1] column as the [1, heads] row the caller reads:
+    # the diagonal of its broadcast, summed over sublanes
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (heads, heads), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (heads, heads), 1))
+    for ref, out in ((m_ref, m_out), (l_ref, l_out)):
+        for a in range(tq):
+            col = ref[a * heads:(a + 1) * heads, :1]
+            out[0, a:a + 1, :] = jnp.sum(jnp.where(eye, col, 0.0), axis=0,
+                                         keepdims=True)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("rank", "block", "tile", "interpret"))
+def chunk_walk_latent(q_cat, rows, keep_cache, live, *, rank: int,
+                      block: int, tile: int, interpret: bool = False):
+    """The cached part of ``chunk_attention_kept`` as one kernel: for
+    every query and head the running maximum ``m``, the sum ``l`` and the
+    unnormalised latent output of a softmax over the rows ``keep_cache``
+    keeps among the first ``live``, fetched a block of ``block`` rows at
+    a time and no block past ``live``'s. q_cat [B, C, H, width];
+    rows [B, T, width]; keep_cache [B or 1, C or 1, T]. Returns
+    (o [B, C, H, rank] float32, m [B, C, H], l [B, C, H]); a query that
+    keeps nothing, and every query at ``live`` 0, answers NEG_INF for
+    ``m`` with an ``o`` that is finite, which its weight in the caller's
+    merge brings to nothing."""
+    b, c, h, width = q_cat.shape
+    t = rows.shape[1]
+    n = jnp.minimum((live + block - 1) // block, t // block)
+    stat = jax.ShapeDtypeStruct((b, c, h), jnp.float32)
+    stats = pl.BlockSpec((1, tile, h), lambda b, i, n: (b, i, 0))
+    o, m, l = pl.pallas_call(
+        functools.partial(_chunk_walk_kernel, block=block, rank=rank,
+                          heads=h),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, c // tile),
+            in_specs=[pl.BlockSpec((1, tile * h, width),
+                                   lambda b, i, n: (b, i, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((1, tile * h, rank),
+                                    lambda b, i, n: (b, i, 0)),
+                       stats, stats],
+            scratch_shapes=[
+                pltpu.VMEM((2, block, width), rows.dtype),
+                pltpu.VMEM((2, tile, block), jnp.int32),
+                pltpu.VMEM((tile * h, _LANES), jnp.float32),
+                pltpu.VMEM((tile * h, _LANES), jnp.float32),
+                pltpu.VMEM((tile * h, rank), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=[jax.ShapeDtypeStruct((b, c * h, rank), jnp.float32),
+                   stat, stat],
+        interpret=interpret,
+        # the stream of row tiles runs from one grid step into the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=96 * 1024 * 1024),
+        name="chunk_walk_latent",
+    )(jnp.reshape(n, (1,)).astype(jnp.int32),
+      q_cat.reshape(b, c * h, width).astype(rows.dtype),
+      jnp.broadcast_to(keep_cache, (b, c, t)).astype(jnp.int32), rows)
+    return o.reshape(b, c, h, rank), m, l
 
 
 @jax.named_scope("mla/chunk_attn_kept")
@@ -115,10 +272,13 @@ def chunk_attention_kept(q_cat, q, rows, k_nope, k_pe, v, rank: int,
     included). ``live`` (a traced scalar): the leading rows of ``rows``
     that ``keep_cache`` can keep, the chunk's start or what of it a ring
     holds. The cached rows are walked a block of ``_CHUNK_BLOCK`` at a
-    time up to ``live`` under a running softmax that starts from the
-    chunk's own tokens, so a reserved row costs nothing and the float32
-    scores held at once are heads x C x block (all T of them are 1 GB a
-    layer at 128 x 512 x 4,096). Returns (o_lat [B, C, H, rank] float32,
+    time up to ``live`` under a running softmax, so a reserved row costs
+    nothing and no score leaves the chip's VMEM where ``chunk_tile``
+    hands the walk to ``chunk_walk_latent`` (the chunk's own tokens then
+    meet its answer by their maxima and sums); elsewhere the walk is a
+    jnp loop that starts from the chunk's own tokens and holds heads x C
+    x block float32 scores at once (all T of them are 1 GB a layer at
+    128 x 512 x 4,096). Returns (o_lat [B, C, H, rank] float32,
     o_new [B, C, H, dv]) as ``chunk_attention`` does."""
     t, dn = rows.shape[1], k_nope.shape[-1]
     block = chunk_block(t)
@@ -133,8 +293,23 @@ def chunk_attention_kept(q_cat, q, rows, k_nope, k_pe, v, rank: int,
     # them to nothing
     m = jnp.max(s_new, -1, keepdims=True)                    # [B, H, C, 1]
     p = jnp.exp(s_new - m)
+    l = jnp.sum(p, -1, keepdims=True)
     o_new = jnp.einsum("bhqk,bkhd->bhqd", p.astype(v.dtype), v,
                        preferred_element_type=jnp.float32)
+    queries = chunk_tile(q.shape[1], q.shape[2], t, rows.shape[-1], rank,
+                         rows.dtype)
+    if queries:
+        from .flash import interpret_env
+
+        o_lat, m_c, l_c = chunk_walk_latent(
+            q_cat, rows, keep_cache, live, rank=rank, block=block,
+            tile=queries, interpret=interpret_env())
+        m_c, l_c = (jnp.swapaxes(a, 1, 2)[..., None] for a in (m_c, l_c))
+        m_all = jnp.maximum(m, m_c)
+        w_new, w_c = jnp.exp(m - m_all), jnp.exp(m_c - m_all)
+        l = l * w_new + l_c * w_c
+        return (o_lat * jnp.swapaxes(w_c / l, 1, 2),
+                jnp.swapaxes(o_new * (w_new / l), 1, 2).astype(v.dtype))
 
     def fold(j, carry):
         m, l, o_lat, o_new = carry
@@ -153,8 +328,7 @@ def chunk_attention_kept(q_cat, q, rows, k_nope, k_pe, v, rank: int,
 
     _, l, o_lat, o_new = jax.lax.fori_loop(
         0, (live + block - 1) // block, fold,
-        (m, jnp.sum(p, -1, keepdims=True),
-         jnp.zeros(m.shape[:3] + (rank,), jnp.float32), o_new))
+        (m, l, jnp.zeros(m.shape[:3] + (rank,), jnp.float32), o_new))
     return (jnp.swapaxes(o_lat / l, 1, 2),
             jnp.swapaxes(o_new / l, 1, 2).astype(v.dtype))
 

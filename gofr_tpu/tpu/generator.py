@@ -652,9 +652,12 @@ class GenerationEngine:
         self._split_first: list[int] | None = None
         self._prefill_n = dict.fromkeys(
             ("admissions", "split", "prompt_tokens", "positions",
-             "routed_positions", "cache_rows_walked",
-             "cache_rows_reserved"), 0)
+             "routed_positions", "chunks", "chunks_on_walk_kernel",
+             "cache_rows_walked", "cache_rows_reserved"), 0)
         self._walk_block = self._fam.chunk_block(cfg, self.max_seq)
+        # a latent family says whether a chunk program of a size walks
+        # its cached rows in the kernel (mla.chunk_walk_latent)
+        self._walk_kernel = getattr(self._fam, "chunk_walk_kernel", None)
         # positions from which a prompt program of this family routes
         # its experts (None: it has none, or no such rule)
         self._routed_from = said.get("moe_prompt_dispatch", {}).get(
@@ -1568,8 +1571,10 @@ class GenerationEngine:
         program and those of them that split, the share of the
         positions run that held no prompt token, the share that ran in a
         program whose experts route (_count_program; None where the
-        family has no such rule), and of the rows the chunk programs'
-        slots reserve the share their attention walked (_count_chunk)."""
+        family has no such rule), the chunk dispatches and those of
+        them whose walk over cached latent rows ran in the kernel, and
+        of the rows the chunk programs' slots reserve the share their
+        attention walked (_count_chunk)."""
         n = dict(self._prefill_n)
         costs = self._prefill_costs
         return {
@@ -2369,13 +2374,22 @@ class GenerationEngine:
         fetched (the blocks under its start) beside the rows the slot
         reserves, stats()["scheduler"]["prefill"] and two counters. A
         family whose chunk program walks under no cursor counts
-        neither."""
+        neither. Before them, whether the program's walk over cached
+        latent rows is the kernel's: of ``chunks`` dispatches,
+        ``chunks_on_walk_kernel`` and a counter."""
         self._count_program(positions)
+        n = self._prefill_n
+        n["chunks"] += 1
+        if self._walk_kernel and self._walk_kernel(self.cfg, self.max_seq,
+                                                   positions):
+            n["chunks_on_walk_kernel"] += 1
+            if self.metrics is not None:
+                self.metrics.increment_counter(
+                    "app_tpu_chunk_walk_kernel_total")
         block = self._walk_block
         if not block:
             return
         walked = -(-start // block) * block
-        n = self._prefill_n
         n["cache_rows_walked"] += walked
         n["cache_rows_reserved"] += self.max_seq
         if self.metrics is not None:

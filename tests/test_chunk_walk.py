@@ -157,36 +157,58 @@ def test_a_small_slot_fits_the_block_to_itself(monkeypatch):
         np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-def _latent_one_softmax(q_cat, q, rows, start, k_nope, k_pe, v, rank):
-    """``mla.chunk_attention`` as it stood before the walk."""
-    c, smax, dn = q.shape[1], rows.shape[1], k_nope.shape[-1]
+def _latent_kept_one_softmax(q_cat, q, rows, k_nope, k_pe, v, rank,
+                             keep_cache, keep_new):
+    """``mla.chunk_attention_kept`` as one softmax over every cached row
+    and the chunk's own tokens, a mask a query over each."""
+    t, dn = rows.shape[1], k_nope.shape[-1]
     rows = rows.astype(q_cat.dtype)
     s_cache = jnp.einsum("bqhw,btw->bhqt", q_cat, rows,
                          preferred_element_type=jnp.float32)
-    s_cache = jnp.where((jnp.arange(smax) < start)[None, None, None],
-                        s_cache, attention.NEG_INF)
+    s_cache = jnp.where(keep_cache[:, None], s_cache, attention.NEG_INF)
     s_new = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :dn], k_nope,
                         preferred_element_type=jnp.float32)
              + jnp.einsum("bqhd,bkd->bhqk", q[..., dn:], k_pe,
                           preferred_element_type=jnp.float32))
-    s_new = jnp.where(jnp.tril(jnp.ones((c, c), bool))[None, None], s_new,
-                      attention.NEG_INF)
+    s_new = jnp.where(keep_new[:, None], s_new, attention.NEG_INF)
     probs = jax.nn.softmax(jnp.concatenate([s_cache, s_new], -1), axis=-1)
-    o_lat = jnp.einsum("bhqt,btr->bqhr", probs[..., :smax].astype(rows.dtype),
+    o_lat = jnp.einsum("bhqt,btr->bqhr", probs[..., :t].astype(rows.dtype),
                        rows[..., :rank], preferred_element_type=jnp.float32)
-    o_new = jnp.einsum("bhqk,bkhd->bqhd", probs[..., smax:].astype(v.dtype),
-                       v)
+    o_new = jnp.einsum("bhqk,bkhd->bqhd", probs[..., t:].astype(v.dtype), v)
     return o_lat, o_new
 
 
+def _latent_one_softmax(q_cat, q, rows, start, k_nope, k_pe, v, rank):
+    """``mla.chunk_attention`` as it stood before the walk."""
+    c = q.shape[1]
+    return _latent_kept_one_softmax(
+        q_cat, q, rows, k_nope, k_pe, v, rank,
+        (jnp.arange(rows.shape[1]) < start)[None, None],
+        jnp.tril(jnp.ones((c, c), bool))[None])
+
+
+def _on(monkeypatch, path):
+    """The walk's path: the jnp loop a CPU takes, or the kernel
+    interpreted at its smallest tile, 8 queries, so that the stream of
+    row tiles runs from one tile into the next and from one slot into
+    the next."""
+    monkeypatch.setattr(mla, "_CHUNK_BLOCK", BLOCK)
+    if path == "kernel":
+        monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
+        monkeypatch.setattr(mla, "_CHUNK_TILE_ROWS", 64)
+    else:
+        monkeypatch.delenv("GOFR_FLASH_INTERPRET", raising=False)
+
+
+@pytest.mark.parametrize("path", ["jnp", "kernel"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("start", STARTS)
 def test_the_latent_walk_equals_one_softmax_over_every_row(monkeypatch, start,
-                                                           dtype):
-    b, h, rank, rope, dn, dv = 2, 3, 16, 4, 6, 5
+                                                           dtype, path):
+    b, h, rank, rope, dn, dv = 2, 8, 128, 128, 6, 5
     ks = jax.random.split(jax.random.PRNGKey(start), 6)
-    q_cat = jax.random.normal(ks[0], (b, C, h, rank + rope), dtype)
+    q_cat = jax.random.normal(ks[0], (b, C, h, rank + rope), dtype) * 0.3
     q = jax.random.normal(ks[1], (b, C, h, dn + rope), dtype)
     rows = jax.random.normal(ks[2], (b, SMAX, rank + rope), dtype)
     k_nope = jax.random.normal(ks[3], (b, C, h, dn), dtype)
@@ -199,7 +221,9 @@ def test_the_latent_walk_equals_one_softmax_over_every_row(monkeypatch, start,
                                start, *(x.astype(jnp.float32)
                                         for x in (k_nope, k_pe, v)), rank)
 
-    monkeypatch.setattr(mla, "_CHUNK_BLOCK", BLOCK)
+    _on(monkeypatch, path)
+    assert bool(mla.chunk_tile(C, h, SMAX, rank + rope, rank, dtype)) \
+        == (path == "kernel")
     got = jax.jit(lambda n: mla.chunk_attention(
         q_cat, q, _poison(rows, start, 1), n, k_nope, k_pe, v, rank))(
             jnp.int32(start))
@@ -210,3 +234,94 @@ def test_the_latent_walk_equals_one_softmax_over_every_row(monkeypatch, start,
         g = np.asarray(g, np.float32)
         assert np.isfinite(g).all()
         np.testing.assert_allclose(g, np.asarray(w), atol=atol)
+
+
+# (stored width, rank) in whole lanes: the full layers' 640 / 512 and the
+# window layers' 1,152 / 1,024, reduced
+WIDTHS = [(256, 128), (384, 256)]
+CL = 2 * C       # a chunk of two of the kernel's smallest tiles
+
+
+def _masks(form, start, key, b):
+    """(keep_cache [b or 1, CL or 1, SMAX], keep_new, live) of the three
+    callers: the cursor, a learned selection, a ring's rows by the
+    positions they hold (``dots3_note.prefill_chunk``'s ``seen`` and
+    ``band``, a window of SMAX + 1)."""
+    causal = jnp.tril(jnp.ones((CL, CL), bool))[None]
+    before = jnp.arange(SMAX) < start
+    if form == "cursor":
+        return before[None, None], causal, start
+    if form == "ring":
+        held = attention.ring_held(SMAX, start)
+        positions = start + jnp.arange(CL)
+        seen = (held >= 0) & (held[None, :] >= positions[:, None] - SMAX)
+        band = causal & ~jnp.tril(jnp.ones((CL, CL), bool), -SMAX - 1)[None]
+        return seen[None], band, min(start, SMAX)
+    k1, k2 = jax.random.split(key)
+    keep = jax.random.bernoulli(k1, 0.4, (b, CL, SMAX)) & before
+    keep_new = (jax.random.bernoulli(k2, 0.7, (b, CL, CL)) & causal
+                | jnp.eye(CL, dtype=bool))
+    # query 1 keeps no cached row; query 0, where there is a cached row,
+    # keeps it and none of the chunk's own tokens
+    keep = keep.at[:, 1].set(False)
+    if start:
+        keep = keep.at[:, 0, 0].set(True)
+        keep_new = keep_new.at[:, 0].set(False)
+    return keep, keep_new, start
+
+
+@pytest.mark.parametrize("path", ["jnp", "kernel"])
+@pytest.mark.parametrize("width,rank", WIDTHS, ids=["w256r128", "w384r256"])
+@pytest.mark.parametrize("form,start", [
+    (form, start) for form in ("cursor", "selection", "ring")
+    for start in STARTS] + [("ring", SMAX + 24)])
+def test_the_kept_walk_takes_every_caller_s_mask_at_both_widths(
+        monkeypatch, start, form, width, rank, path):
+    """``chunk_attention_kept`` under the three masks its callers hand
+    it, on the jnp loop and in the kernel: a query that keeps no cached
+    row, one that keeps none of the chunk's own tokens, a ring that has
+    wrapped (``live`` is all of it), NaN in every row past the last
+    block the walk may fetch."""
+    b, h, dn, rope, dv = 2, 8, 6, 4, 5
+    ks = jax.random.split(jax.random.PRNGKey(start + width), 7)
+    q_cat = jax.random.normal(ks[0], (b, CL, h, width)) * 0.3
+    q = jax.random.normal(ks[1], (b, CL, h, dn + rope))
+    rows = jax.random.normal(ks[2], (b, SMAX, width))
+    k_nope = jax.random.normal(ks[3], (b, CL, h, dn))
+    k_pe = jax.random.normal(ks[4], (b, CL, rope))
+    v = jax.random.normal(ks[5], (b, CL, h, dv))
+    keep_cache, keep_new, live = _masks(form, start, ks[6], b)
+    want = _latent_kept_one_softmax(q_cat, q, rows, k_nope, k_pe, v, rank,
+                                    keep_cache, keep_new)
+
+    _on(monkeypatch, path)
+    got = jax.jit(lambda n: mla.chunk_attention_kept(
+        q_cat, q, _poison(rows, live, 1), k_nope, k_pe, v, rank, keep_cache,
+        keep_new, n))(jnp.int32(live))
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(g, w, atol=2e-5)
+
+
+@pytest.mark.parametrize("chunk,heads,table,width,rank,dtype,why", [
+    (512, 128, 16384, 640, 512, jnp.float32, "float32 operands"),
+    (512, 128, 16384, 576, 512, jnp.bfloat16, "a width of 4.5 lane tiles"),
+    (512, 128, 16384, 640, 448, jnp.bfloat16, "a rank of 3.5 lane tiles"),
+    (512, 12, 16384, 640, 512, jnp.bfloat16, "heads of 1.5 sublane tiles"),
+    (20, 128, 16384, 640, 512, jnp.bfloat16, "a chunk of 2.5 tiles"),
+    (512, 128, 320, 640, 512, jnp.bfloat16, "blocks of 64 rows"),
+    (512, 128, 16384, 640, 512, jnp.bfloat16, "a CPU")])
+def test_the_dispatcher_answers_jnp_for_what_the_kernel_refuses(
+        monkeypatch, chunk, heads, table, width, rank, dtype, why):
+    """``chunk_tile`` on a TPU: None for each shape the kernel does not
+    take, and for every shape on a CPU; the served shapes get a tile of
+    1,024 score rows."""
+    from gofr_tpu.ops import flash
+
+    monkeypatch.delenv("GOFR_FLASH_INTERPRET", raising=False)
+    monkeypatch.setattr(flash, "tpu_backend_ok", lambda: why != "a CPU")
+    assert mla.chunk_tile(chunk, heads, table, width, rank, dtype) is None
+    monkeypatch.setattr(flash, "tpu_backend_ok", lambda: True)
+    served = [(512, 128, 16384, 640, 512), (512, 64, 512, 1152, 1024),
+              (64, 64, 2048, 640, 512)]
+    assert [mla.chunk_tile(*s, jnp.bfloat16) for s in served] == [8, 16, 16]

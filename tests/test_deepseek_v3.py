@@ -109,6 +109,34 @@ def test_a_chunked_prompt_against_the_reference(params, tokens, chunk):
     assert np.abs(np.concatenate(got, 1) - want).max() < F32_TOL
 
 
+def test_a_chunk_on_the_interpreted_walk_kernel_is_the_chunk_on_the_loop(
+        params, tokens, monkeypatch):
+    """``prefill_chunk`` with ``GOFR_FLASH_INTERPRET=1`` (the walk under
+    the cursor in ``mla.chunk_walk_latent``, blocks of 16 rows) against
+    ``prefill_chunk`` without, chunk by chunk, and the rows they leave."""
+    monkeypatch.setattr(mla, "_CHUNK_BLOCK", 16)
+
+    def lattice(kernel):
+        if kernel:
+            monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
+        else:
+            monkeypatch.delenv("GOFR_FLASH_INTERPRET", raising=False)
+        assert ds.chunk_walk_kernel(CFG, 64, 8) == kernel
+        run = jax.jit(lambda toks, cache, start: ds.prefill_chunk(
+            params, CFG, toks, cache, start))
+        cache, got = ds.init_cache(CFG, 2, 64), []
+        for s0 in range(0, 24, 8):
+            logits, cache = run(tokens[:, s0:s0 + 8], cache, jnp.int32(s0))
+            got.append(_logprobs(logits))
+        return np.concatenate(got, 1), cache.rows
+
+    want, rows = lattice(False)
+    got, rows_k = lattice(True)
+    assert np.abs(got - want).max() < F32_TOL
+    assert np.abs(got - _ref_logprobs(params, CFG, tokens)).max() < F32_TOL
+    assert np.abs(np.asarray(rows_k) - np.asarray(rows)).max() < 2e-5
+
+
 def test_expanded_and_absorbed_attention_are_the_same_numbers():
     """ops/mla.py alone: a query against rows, through W_UK/W_UV absorbed
     or with keys and values a head materialised."""

@@ -332,6 +332,44 @@ def test_left_aligned_chunks_read_the_ring_before_they_overwrite_it(
     assert L // W >= 2
 
 
+@pytest.mark.parametrize("chunk,L", [(16, 40), (32, 90)])
+def test_a_chunk_on_the_interpreted_walk_kernel_is_the_chunk_on_the_loop(
+        params, monkeypatch, chunk, L):
+    """``prefill_chunk`` with ``GOFR_FLASH_INTERPRET=1`` (every layer's
+    walk in ``mla.chunk_walk_latent``: a full layer's rows under the
+    selection, four blocks a slot; a window layer's ring under ``seen``;
+    two tiles of queries a chunk) against ``prefill_chunk`` without: the
+    logits after the last chunk and the three tables it leaves."""
+    toks = _tokens(chunk + L + 1, L)
+    monkeypatch.setattr(mla, "_CHUNK_BLOCK", 32)
+    monkeypatch.setattr(mla, "_CHUNK_TILE_ROWS", 8)
+
+    def lattice(kernel):
+        if kernel:
+            monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
+        else:
+            monkeypatch.delenv("GOFR_FLASH_INTERPRET", raising=False)
+        assert dn.chunk_walk_kernel(CFG, 128, chunk) == kernel
+        # a program of its own: the module's jitted one holds the path it
+        # was first traced on
+        run = jax.jit(lambda toks, cache, start, logit_pos: dn.prefill_chunk(
+            params, CFG, toks, cache, start, logit_pos=logit_pos))
+        cache = dn.init_cache(CFG, 1, 128)
+        for pos in range(0, L, chunk):
+            piece = np.zeros((1, chunk), np.int32)
+            piece[0, :min(chunk, L - pos)] = toks[pos:pos + chunk]
+            logits, cache = run(jnp.asarray(piece), cache, jnp.int32(pos),
+                                jnp.asarray([min(chunk, L - pos) - 1]))
+        return _logprobs(logits[0, 0]), cache
+
+    want, on_loop = lattice(False)
+    got, on_kernel = lattice(True)
+    assert np.abs(got - want).max() < F32_TOL
+    assert np.abs(got - _ref(params, toks, [L - 1])[0]).max() < F32_TOL
+    for a, b in zip(on_kernel[:3], on_loop[:3]):
+        assert np.abs(np.asarray(a) - np.asarray(b)).max() < 2e-5
+
+
 def test_slots_under_and_over_both_masks_in_one_batch(params):
     """Three slots in one decode batch: 3 positions (under the window
     and the selection), 40 (past both) and an idle one whose tables take

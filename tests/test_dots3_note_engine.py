@@ -127,6 +127,42 @@ def test_engine_says_its_tables_and_counts_the_rows_kept(params):
         and "states_updated" not in args[-1]
 
 
+@pytest.mark.parametrize("kernel", [False, True], ids=["cpu", "interpreted"])
+def test_chunk_dispatches_count_those_on_the_walk_kernel(params, monkeypatch,
+                                                         kernel):
+    """``app_tpu_chunk_walk_kernel_total`` beside the chunk dispatches
+    of ``stats()["scheduler"]["prefill"]``: every one with
+    ``GOFR_FLASH_INTERPRET=1`` (the family answers for both kinds of
+    layer from what ``mla.chunk_tile`` sees), none on a CPU without;
+    and the tokens served are the reference's either way."""
+    from gofr_tpu.metrics import Manager, register_framework_metrics
+
+    if kernel:
+        monkeypatch.setenv("GOFR_FLASH_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("GOFR_FLASH_INTERPRET", raising=False)
+    m = Manager()
+    register_framework_metrics(m)
+    eng = GenerationEngine(CFG, params, slots=2, max_seq=128,
+                           prompt_buckets=(16, 32), metrics=m)
+    try:
+        # one bucket: no chunk program; then 70 = 32 + 32 + the last 16
+        eng.generate(_tokens(3, 20).tolist(), max_new_tokens=2).tokens()
+        assert eng.stats()["scheduler"]["prefill"]["chunks"] == 0
+        prompt = _tokens(70, 70).tolist()
+        served = _generate(eng, prompt, W)
+        said = eng.stats()["scheduler"]["prefill"]
+    finally:
+        eng.close()
+    assert _held_to_the_reference(params, prompt, served) < F32_TOL
+    assert said["chunks"] == 3
+    assert said["chunks_on_walk_kernel"] == (3 if kernel else 0)
+    counted = [float(line.rsplit(" ", 1)[1])
+               for line in m.render_prometheus().splitlines()
+               if line.startswith("app_tpu_chunk_walk_kernel_total")]
+    assert sum(counted) == said["chunks_on_walk_kernel"]
+
+
 @pytest.mark.parametrize("counted,tail", [
     ({"kept": (9, 12)}, (None, None, None, None, None, (9, 12))),
     ({"assigned": 5, "touched": 3, "ring": 8, "kept": (9, 12)},

@@ -164,6 +164,37 @@ def test_latent_decode_kernel_compiles(one_chip):
         < 9 * slots * SMAX * width * 2 // 64
 
 
+@pytest.mark.parametrize("chunk,heads,table,width,rank", [
+    (512, 128, 16384, 640, 512), (512, 64, 512, 1152, 1024),
+    (512, 64, 2048, 640, 512), (32, 128, 4096, 640, 512)],
+    ids=["full_16k", "ring", "gigachat", "bucket_32"])
+def test_latent_chunk_walk_kernel_compiles(one_chip, monkeypatch, chunk,
+                                           heads, table, width, rank):
+    """ops/mla.py's chunk walk at the shapes its three cells call it in
+    (a full layer over a slot of 16,384 rows, a window layer over its
+    ring, gigachat's 64 heads over 2,048) and at the smallest final
+    chunk: the dispatcher takes each, a tile of 1,024 score rows with
+    its scores, probabilities and accumulator fits the VMEM the kernel
+    asks for, and the program holds nothing the size of a block's
+    float32 scores outside it."""
+    from gofr_tpu.ops import flash, mla
+
+    def arr(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    monkeypatch.setattr(flash, "tpu_backend_ok", lambda: True)
+    tile = mla.chunk_tile(chunk, heads, table, width, rank, jnp.bfloat16)
+    assert tile and tile * heads == 1024
+    compiled = mla.chunk_walk_latent.lower(
+        arr((1, chunk, heads, width)), arr((1, table, width)),
+        arr((1, chunk, table), jnp.bool_), arr((), jnp.int32), rank=rank,
+        block=mla.chunk_block(table), tile=tile).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the mask as the kernel reads it, and no [heads, chunk, block] scores
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= chunk * table * 4 + (1 << 20)
+
+
 # -- the delta rule's kernels (ops/kda.py) at Solar-Open2's head sizes ---------
 
 KDA_L, KDA_B, KDA_H = 6, 128, 64
@@ -913,7 +944,7 @@ def test_layer_of_two_halves_leaves_states_stacks_and_table_where_they_lie(
 # -- the sparse-latent family's programs at the published widths ---------------
 
 @pytest.mark.parametrize("program,kernels", [("decode block", 20),
-                                             ("chunk 512", 7)])
+                                             ("chunk 512", 15)])
 def test_sparse_latent_family_compiles_and_leaves_its_tables_where_they_lie(
         one_chip, monkeypatch, program, kernels):
     """``benchmarks/configs/dots3-note-prev-int8-ep8.json`` as its cell
@@ -923,9 +954,11 @@ def test_sparse_latent_family_compiles_and_leaves_its_tables_where_they_lie(
     (rank 512 in 640 lanes, 128 heads), the ring walk six times (rank
     1,024 in 1,152 lanes, 64 heads, a ring of 512), the index score pass
     three times (64 heads of 128 over 4,096 keys) and the routed experts'
-    kernel eight times; the 512-token chunk program is jnp but for the
-    experts (seven: a middle chunk yields no logits, so its last layer's
-    feed-forward is dead code). Neither copies a table of the cache or
+    kernel eight times; the 512-token chunk program walks its cached
+    rows in ``chunk_walk_latent`` eight times (three slots' rows, five
+    rings) beside the experts' seven (a middle chunk yields no logits,
+    so its last layer's attention and feed-forward are dead code) and
+    holds no block's float32 scores. Neither copies a table of the cache or
     an int8 stack, and the whole engine fits the chip beside the
     reference check."""
     import sys
@@ -943,6 +976,10 @@ def test_sparse_latent_family_compiles_and_leaves_its_tables_where_they_lie(
         assert len(re.findall(r"%decode_attention_kept[\w.]* = ", text)) == 3
         assert len(re.findall(r"%decode_attention_ring[\w.]* = ", text)) == 6
         assert len(re.findall(r"%index_scores_stacked[\w.]* = ", text)) == 3
+    else:
+        assert len(re.findall(r"%chunk_walk_latent[\w.]* = ", text)) == 8
+        assert not re.search(r"f32\[(1,)?(128|64),512,512\]\S* fusion\(.*"
+                             r"chunk_attn_kept/while", text)
     results = re.findall(
         r"^\s*(?:ROOT )?%?[\w.\-]+ = ((?:s8|bf16)\[[\d,]+\]\S*) ([\w\-]+)\(",
         text, re.M)
